@@ -38,27 +38,25 @@ import jax as _jax
 _jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: the scan program compiles in tens of
-# seconds on TPU; caching next to the repo cuts warm-up across processes
-# (measured 14.1s -> 8.8s for the 1k x 500 scan).  An explicit
-# JAX_COMPILATION_CACHE_DIR env var wins; failures (read-only install)
-# just skip the cache.
+# seconds on TPU, and every process of a run (server, standalone
+# scheduler, parity gate) should pay that once.  The directory is part of
+# the cache key, so it is a FIXED path: JAX_COMPILATION_CACHE_DIR when the
+# environment sets it (jax reads that variable itself — nothing to
+# configure here), else <checkout>/.jax_cache next to the package.  An
+# installed copy (site-/dist-packages) gets neither: the package manager
+# owns that tree, set the variable there.  A directory that cannot be
+# created is an error at import: a silently cold cache looks like a slow
+# chip.
 import os as _os
+from pathlib import Path as _Path
 
-if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    from pathlib import Path as _Path
-
-    _parent = _Path(__file__).resolve().parent.parent
-    # only for checkout/editable installs (repo marker present) — a
-    # site-packages install must not grow a cache dir the package manager
-    # doesn't own; set JAX_COMPILATION_CACHE_DIR there instead
-    if (_parent / ".git").exists() or (_parent / "bench.py").exists():
-        _cache = _parent / ".jax_cache"
-        try:
-            _cache.mkdir(exist_ok=True)
-            _jax.config.update("jax_compilation_cache_dir", str(_cache))
-            _jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-        except Exception:
-            pass
+_parent = _Path(__file__).resolve().parent.parent
+if not (_os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or {"site-packages", "dist-packages"} & set(_parent.parts)):
+    _cache = _parent / ".jax_cache"
+    _cache.mkdir(exist_ok=True)
+    _jax.config.update("jax_compilation_cache_dir", str(_cache))
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
 
 __version__ = "0.1.0"
 

@@ -1,8 +1,11 @@
 """ctypes loader for the native annotation codec.
 
 Builds annotation_codec.cpp with g++ on first use (cached next to the
-source); falls back to the pure-Python encoder when the toolchain is
-unavailable.  See annotation_codec.cpp for the encoding contract.
+source).  When the build or the load fails the decode ladder still
+serves from the pure-Python encoder (the parity reference, ~25x
+slower) — but loudly: get_lib() prints the compiler's error and counts
+`native_codec_load_failures_total`.  See annotation_codec.cpp for the
+encoding contract.
 """
 
 from __future__ import annotations
@@ -10,6 +13,8 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
+import tempfile
 import threading
 
 _lock = threading.Lock()
@@ -34,12 +39,24 @@ TSAN_FLAGS = ["-g", "-fsanitize=thread"]
 def build_codec(so: str | None = None,
                 extra_flags: list[str] | tuple[str, ...] = ()) -> str:
     """Compile annotation_codec.cpp -> _annotation_codec.so (the recipe
-    `make codec` runs); returns the .so path."""
+    `make codec` runs); returns the .so path.  The compiler writes to a
+    temporary name that is renamed into place, so a process starting
+    beside the builder (server + standalone scheduler) either sees no
+    library and builds its own, or loads a whole one."""
     here = os.path.dirname(os.path.abspath(__file__))
     src = os.path.join(here, "annotation_codec.cpp")
     so = so or os.path.join(here, "_annotation_codec.so")
-    subprocess.run([*BUILD_CMD, *extra_flags, "-o", so, src], check=True,
-                   capture_output=True)
+    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(so), prefix=".codec-",
+                               suffix=".so")
+    os.close(fd)
+    try:
+        subprocess.run([*BUILD_CMD, *extra_flags, "-o", tmp, src],
+                       check=True, capture_output=True)
+        os.chmod(tmp, 0o755)  # mkstemp's 0600 would outlive the rename
+        os.replace(tmp, so)
+    except BaseException:
+        os.unlink(tmp)
+        raise
     return so
 
 
@@ -231,7 +248,8 @@ def peek_string_ascii(addr: int, length: int) -> str:
 
 
 def get_lib():
-    """The loaded codec, or None when native build is unavailable."""
+    """The loaded codec, or None when the native build is unavailable —
+    reported once on stderr and counted, never silent."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
@@ -243,8 +261,16 @@ def get_lib():
                 # PURPOSE: concurrent first users must block until the
                 # one-shot build lands rather than race the compiler
                 _lib = _build_and_load()  # kss-analyze: allow(blocking-under-lock)
-            except Exception:
-                _lib = None
+            except Exception as e:
+                from ..utils.tracing import TRACER
+
+                TRACER.count("native_codec_load_failures_total")
+                stderr = getattr(e, "stderr", None) or b""
+                print("ERROR: native annotation codec unavailable "
+                      f"({type(e).__name__}: {e}); annotations decode in "
+                      "pure Python, ~25x slower\n"
+                      + stderr.decode(errors="replace")[-2000:],
+                      file=sys.stderr, flush=True)
     return _lib
 
 

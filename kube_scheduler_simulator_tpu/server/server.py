@@ -81,6 +81,11 @@ class SimulatorServer:
         else:
             self.manager = SessionManager(default_di=di)
         self.port = port if port is not None else self.manager.cfg.port
+        # a chip belongs to ONE process.  With externalSchedulerEnabled
+        # that process is cmd/scheduler (docs/external-scheduler.md), so
+        # this one must never initialise a JAX backend: no HBM sampler,
+        # no device fingerprint in /api/v1/debug/dump
+        self.owns_device = not self.manager.cfg.external_scheduler_enabled
         self.httpd: ThreadingHTTPServer | None = None
         self.autopilot = None
         # live long-poll/SSE responses across ALL sessions; shutdown()
@@ -98,10 +103,11 @@ class SimulatorServer:
         # device telemetry plane (utils/blackbox.py, docs/metrics.md):
         # the background HBM sampler feeds hbm_* gauges into /metrics;
         # idempotent, a daemon, explicit no-op gauge on stat-less
-        # backends (CPU)
+        # backends (CPU); history leg only where this process owns no
+        # device
         from ..utils.blackbox import TELEMETRY
 
-        TELEMETRY.start()
+        TELEMETRY.start(device=self.owns_device)
         # closed-loop autopilot (control/autopilot.py, docs/autopilot.md):
         # always-on controller thread unless KSS_TPU_AUTOPILOT opts out
         # (off — or unparsable — is the byte-identical static baseline)
@@ -284,6 +290,12 @@ def _make_handler(server: SimulatorServer):
                 if shed:
                     from ..utils.tracing import TRACER
 
+                    # drain the body first: answering while a client is
+                    # still sending a snapshot resets its connection
+                    # (EPIPE instead of the 429), and on keep-alive the
+                    # unread bytes would be parsed as the next request
+                    self.rfile.read(
+                        int(self.headers.get("Content-Length") or 0))
                     TRACER.inc("autopilot_shed_total",
                                session=self.sess.id)
                     return self._json(
@@ -501,7 +513,8 @@ def _make_handler(server: SimulatorServer):
             from ..utils.tracing import TRACER
 
             sid = self._session_filter(url)
-            doc = BLACKBOX.bundle("request", session=sid)
+            doc = BLACKBOX.bundle("request", session=sid,
+                                  device=server.owns_device)
             # counted like every snapshot reason, but NOT stored: a
             # polling client must not scroll real abort dumps out of
             # the bounded recent ring

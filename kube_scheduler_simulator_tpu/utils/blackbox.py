@@ -101,12 +101,35 @@ def describe_exception(exc: BaseException | None) -> dict | None:
     return out
 
 
+NO_DEVICE = {
+    "available": False, "hbm_available": False,
+    "error": "this process runs no engine (externalSchedulerEnabled): "
+             "the device belongs to the scheduler process"}
+
+
+def _runtime_versions() -> dict:
+    from importlib import metadata
+
+    out = {}
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[pkg] = metadata.version(pkg)
+        except metadata.PackageNotFoundError:
+            out[pkg] = None
+    return out
+
+
 def device_fingerprint() -> dict:
     """Per-device state at dump/sample time: platform, kind, and the
     backend's memory_stats() (bytes in use / peak / limit) when the
-    backend exposes them.  `hbm_available` is an EXPLICIT flag: on the
-    CPU backend memory_stats() is absent and the fingerprint says so
-    instead of silently omitting the numbers."""
+    backend exposes them, plus the jax/jaxlib/libtpu versions.
+    `hbm_available` is an EXPLICIT flag: on the CPU backend
+    memory_stats() is absent and the fingerprint says so instead of
+    silently omitting the numbers.
+
+    Calling this INITIALISES the JAX backend, i.e. claims the chip — a
+    chip belongs to one process, so only a process that runs an engine
+    may call it (server/server.py passes NO_DEVICE otherwise)."""
     try:
         import jax
 
@@ -116,7 +139,7 @@ def device_fingerprint() -> dict:
         return {"available": False, "hbm_available": False,
                 "error": f"{type(e).__name__}: {e}"[:200]}
     out = {"available": True, "backend": backend, "hbm_available": False,
-           "devices": []}
+           "versions": _runtime_versions(), "devices": []}
     for d in devs:
         ent = {"id": int(getattr(d, "id", 0)),
                "platform": str(getattr(d, "platform", "")),
@@ -220,8 +243,10 @@ class BlackBox:
     # ------------------------------------------------------------ dump
 
     def bundle(self, reason: str, cause: BaseException | None = None,
-               session: str | None = None) -> dict:
-        """Build (but do not store) a post-mortem bundle."""
+               session: str | None = None, device: bool = True) -> dict:
+        """Build (but do not store) a post-mortem bundle.  device=False
+        (a server whose engine lives in another process) records
+        NO_DEVICE instead of touching the backend."""
         from .faults import current_plan
 
         plan = current_plan()
@@ -252,7 +277,7 @@ class BlackBox:
             "counter_deltas": self.counter_deltas(session),
             "fault_plan": plan.stats() if plan is not None else None,
             "env": _env_knobs(),
-            "device": device_fingerprint(),
+            "device": device_fingerprint() if device else NO_DEVICE,
             # the trailing telemetry-history window (utils/history.py):
             # a wave-abort dump answers "what was trending before this"
             # by itself — p99 creep, spill bursts, autopilot moves.
@@ -702,9 +727,12 @@ class DeviceTelemetry:
         with self._mu:
             return self._last
 
-    def start(self, interval: float | None = None) -> None:
+    def start(self, interval: float | None = None,
+              device: bool = True) -> None:
         """Start the sampler (idempotent).  interval <= 0 (or
-        KSS_TPU_HBM_SAMPLE_S=0) disables the HBM leg; the same thread
+        KSS_TPU_HBM_SAMPLE_S=0) disables the HBM leg, and so does
+        device=False — the caller runs no engine, and sampling would
+        claim the chip from the process that does; the same thread
         also feeds the telemetry history ring every
         KSS_TPU_HISTORY_SAMPLE_S seconds (utils/history.py) — two
         cadences, one thread, each with its own next-due clock.  No
@@ -712,7 +740,9 @@ class DeviceTelemetry:
         runs under the lock so two concurrent start() calls can never
         spawn two samplers, and a fresh stop event per thread means a
         racing stop() never leaves a newly started sampler dead."""
-        if interval is None:
+        if not device:
+            interval = 0.0
+        elif interval is None:
             interval = env_float("KSS_TPU_HBM_SAMPLE_S", 5.0)
         hist_iv = _history.sample_interval() if _history.enabled() else 0.0
         t = None
@@ -757,7 +787,8 @@ class DeviceTelemetry:
 
                     t = self._thread = threading.Thread(
                         target=loop, daemon=True, name="hbm-sampler")
-        self.sample_once()
+        if device:
+            self.sample_once()
         if t is not None:
             t.start()
 

@@ -51,6 +51,8 @@ import threading
 import zlib
 from contextlib import contextmanager
 
+import jax
+
 from .retry import RetryTimeout
 from .tracing import TRACER
 
@@ -324,8 +326,9 @@ def classify_fault(exc: BaseException) -> str:
       * "fatal"      — never retried: non-Exception BaseExceptions
         (interrupts), and RetryTimeout/RetryAborted — an exhausted
         bounded retry must surface, re-retrying multiplies the bound;
-      * "structural" — device-memory exhaustion (MemoryError, XLA
-        RESOURCE_EXHAUSTED, injected OOM): answered by the degradation
+      * "structural" — device-memory exhaustion (MemoryError, a
+        jax.errors.JaxRuntimeError carrying RESOURCE_EXHAUSTED, injected
+        OOM): answered by the degradation
         ladder, not a retry (the wave would just OOM again);
       * "transient"  — everything else: retry the uncommitted suffix
         with bounded backoff.
@@ -338,7 +341,7 @@ def classify_fault(exc: BaseException) -> str:
         return "structural" if exc.structural else "transient"
     if isinstance(exc, MemoryError):
         return "structural"
-    if (type(exc).__name__ == "XlaRuntimeError"
+    if (isinstance(exc, jax.errors.JaxRuntimeError)
             and "RESOURCE_EXHAUSTED" in str(exc)):
         return "structural"
     return "transient"

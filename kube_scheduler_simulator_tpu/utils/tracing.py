@@ -113,6 +113,10 @@ _HELP: dict[str, str] = {
         "Gang groups per vectorized quorum pass, by decision.",
     "decode_path_total":
         "Pods decoded per decoder-ladder path (docs/wave-pipeline.md).",
+    "native_codec_load_failures_total":
+        "Failed builds/loads of the native annotation codec: the process "
+        "decodes in pure Python (the parity reference, ~25x slower) — "
+        "the compiler's error is on stderr.",
     "decode_on_demand_total":
         "Lazy annotation reads by outcome: miss = the read decoded (or "
         "waited on) its chunk, hit = the chunk was already materialized "

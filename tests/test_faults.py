@@ -15,6 +15,7 @@ import json
 import threading
 import time
 
+import jax
 import pytest
 
 from kube_scheduler_simulator_tpu.cluster.store import Conflict, ObjectStore
@@ -148,6 +149,13 @@ def test_classification():
     assert classify_fault(faults.InjectedRuntimeFault("x")) == "transient"
     assert classify_fault(faults.InjectedOOM("x")) == "structural"
     assert classify_fault(MemoryError()) == "structural"
+    # what the installed runtime raises when HBM runs out (the class is
+    # matched by isinstance: its name changed across JAX releases)
+    assert classify_fault(jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm"
+    )) == "structural"
+    assert classify_fault(jax.errors.JaxRuntimeError(
+        "INTERNAL: stream did not block host until done")) == "transient"
     assert classify_fault(RuntimeError()) == "transient"
     assert classify_fault(RetryTimeout()) == "fatal"
     assert classify_fault(KeyboardInterrupt()) == "fatal"
