@@ -184,7 +184,8 @@ class Autopilot:
         while not self._stop.wait(self.interval):
             if sys.is_finalizing():
                 return
-            self.tick()
+            with TRACER.span("autopilot_tick"):
+                self.tick()
 
     # ------------------------------------------------------------ tick
 

@@ -498,6 +498,10 @@ class SchedulerEngine:
         # per-wave node count for the unschedulable condition message
         # (was a full deepcopy store.list per unschedulable pod)
         self._wave_node_count: int | None = None
+        # (namespace, name) -> perf_counter of a pending pod's ADDED
+        # event, stamped by the scheduling loop's watch thread
+        # (note_arrival) and taken by the first wave that takes the pod
+        self._arrivals: dict[tuple[str, str], float] = {}
         self._pending_idx = None
         self.result_store = result_store or ResultStore()
         self.reflector = reflector or StoreReflector(store)
@@ -792,8 +796,6 @@ class SchedulerEngine:
             n_bound += bound
             TRACER.count("pods_scheduled_total", bound)
             TRACER.count("scheduling_waves_total")
-            if retry == "preempted":
-                TRACER.count("preemption_waves_total")
             # drain Permit waiters after EVERY wave (not just the last):
             # a retry wave must never observe a half-resolved waiter —
             # pending_pods would re-schedule a pod whose waiter thread is
@@ -873,24 +875,77 @@ class SchedulerEngine:
         clock), result=scheduled for bound pods, unschedulable for the
         rest of the wave (an approximation: parked gang members and
         gated pods count as unschedulable until they resolve)."""
-        t0 = time.perf_counter()
-        bound, retry = self._profile_wave_run(pending, exclude)
         n = len(pending)
-        if n:
-            # per-session SLO window (rolling p50/p99 wave latency +
-            # cycles/s): one deque append, read by /api/v1/sessions and
-            # /readyz (utils/blackbox.py, docs/metrics.md)
-            from ..utils.blackbox import SLO
+        if not n:
+            # an empty wake-up: no root span, no work-pass count
+            return self._profile_wave_run(pending, exclude)
+        # the root span of a pass (docs/metrics.md span tree): wave_setup,
+        # compile_workload, replay_and_decode_stream, commit_and_reflect
+        # and wave_finish are its children and cover it
+        with TRACER.span("wave", pods=n) as wave_sp:
+            t0 = time.perf_counter()
+            self._count_pass(pending, t0)
+            bound, retry = self._profile_wave_run(pending, exclude)
+            wave_sp.attrs["nodes"] = self._wave_node_count
+            with TRACER.span("wave_finish"):
+                # per-session SLO window (rolling p50/p99 wave latency +
+                # cycles/s): one deque append, read by /api/v1/sessions and
+                # /readyz (utils/blackbox.py, docs/metrics.md)
+                from ..utils.blackbox import SLO
 
-            SLO.observe_wave(self.session, time.perf_counter() - t0, n)
-            per = (time.perf_counter() - t0) / n
-            if bound:
-                TRACER.observe("scheduling_attempt_duration_seconds", per,
-                               n=bound, result="scheduled")
-            if n > bound:
-                TRACER.observe("scheduling_attempt_duration_seconds", per,
-                               n=n - bound, result="unschedulable")
+                SLO.observe_wave(self.session, time.perf_counter() - t0, n)
+                per = (time.perf_counter() - t0) / n
+                if bound:
+                    TRACER.observe("scheduling_attempt_duration_seconds", per,
+                                   n=bound, result="scheduled")
+                if n > bound:
+                    TRACER.observe("scheduling_attempt_duration_seconds", per,
+                                   n=n - bound, result="unschedulable")
         return bound, retry
+
+    def note_arrival(self, pod: dict) -> None:
+        """The scheduling loop's watch thread saw this pending pod's
+        ADDED event: stamp it, so the wave that takes the pod can say how
+        long it queued (queue_wait_* counters)."""
+        meta = pod.get("metadata") or {}
+        self._arrivals[(meta.get("namespace") or "default",
+                        meta.get("name", ""))] = time.perf_counter()
+
+    def forget_arrival(self, pod: dict) -> None:
+        meta = pod.get("metadata") or {}
+        self._arrivals.pop((meta.get("namespace") or "default",
+                            meta.get("name", "")), None)
+
+    def _count_pass(self, pending: list[dict], now: float) -> None:
+        """A wave that takes pods counts itself and them
+        (scheduling_work_passes_total, scheduling_pass_pods_total) and,
+        as queue_wait_*, the time since the loop was handed each pod's
+        ADDED event — the debounce and any pass that was running
+        included.  A pod is counted once, by the first wave that takes
+        it; pods that never passed through the loop's watch (direct
+        engine use) carry no stamp."""
+        TRACER.count("scheduling_work_passes_total")
+        TRACER.count("scheduling_pass_pods_total", len(pending))
+        arrivals = self._arrivals
+        if not arrivals:
+            return
+        total, n, oldest = 0.0, 0, 0.0
+        # one dict pop per pod taken: stamps are keyed by pod, there is
+        # no tensor to fuse this into
+        # kss-analyze: allow(pod-loop)
+        for p in pending:
+            meta = p.get("metadata") or {}
+            t = arrivals.pop((meta.get("namespace") or "default",
+                              meta.get("name", "")), None)
+            if t is not None:
+                wait = max(now - t, 0.0)
+                total += wait
+                n += 1
+                oldest = max(oldest, wait)
+        if n:
+            TRACER.count("queue_wait_seconds_total", total)
+            TRACER.count("queue_wait_pods_total", n)
+            TRACER.count("queue_wait_oldest_seconds_total", oldest)
 
     # ------------------------------------------------ failure protocol
 
@@ -999,20 +1054,21 @@ class SchedulerEngine:
         from .replay import (CompileQuarantined, materialize_failure_streak,
                              reset_materialize_failures)
 
-        # black-box wave marker: records the event AND pins the counter
-        # baseline this wave's post-mortem computes deltas against
-        BLACKBOX.wave_start(self.session, pods=len(pending),
-                            mode=self.result_mode())
-        if (self._effective_residency() == 0
-                and materialize_failure_streak(self.session)
-                >= self._env_int("KSS_TPU_MATERIALIZE_FAIL_LIMIT", 3)):
-            # repeated on-demand D2H failures are a structural device
-            # signal even though they surface on the READ path: step to
-            # host-resident fetch so new waves stop pinning chunks that
-            # cannot come back across.  The streak is per-session: a
-            # neighbor's flaky reads never degrade THIS engine
-            if self._degrade("replay.materialize"):
-                reset_materialize_failures(self.session)
+        with TRACER.span("wave_setup"):
+            # black-box wave marker: records the event AND pins the counter
+            # baseline this wave's post-mortem computes deltas against
+            BLACKBOX.wave_start(self.session, pods=len(pending),
+                                mode=self.result_mode())
+            if (self._effective_residency() == 0
+                    and materialize_failure_streak(self.session)
+                    >= self._env_int("KSS_TPU_MATERIALIZE_FAIL_LIMIT", 3)):
+                # repeated on-demand D2H failures are a structural device
+                # signal even though they surface on the READ path: step to
+                # host-resident fetch so new waves stop pinning chunks that
+                # cannot come back across.  The streak is per-session: a
+                # neighbor's flaky reads never degrade THIS engine
+                if self._degrade("replay.materialize"):
+                    reset_materialize_failures(self.session)
         bound = 0
         retries_left = self._env_int("KSS_TPU_WAVE_MAX_RETRIES", 3)
         delay = 0.02
@@ -1102,56 +1158,57 @@ class SchedulerEngine:
         the rest of the wave is re-run with upstream-sequential state (the
         rejected pod excluded), so later pods never observe the phantom
         bind (upstream scheduleOne semantics)."""
-        if exclude:
-            pending = [
-                p for p in pending
-                if ((p.get("metadata") or {}).get("namespace") or "default",
-                    (p.get("metadata") or {}).get("name", "")) not in exclude
-            ]
-        if self.plugin_config.preenqueues():
-            # SchedulingGates PreEnqueue: gated pods never enter the queue
-            gated = [
-                p for p in pending if (p.get("spec") or {}).get("schedulingGates")
-            ]
-            for p in gated:
-                meta = p.get("metadata") or {}
-                self._mark_gated(meta.get("namespace") or "default", meta.get("name", ""))
-            if gated:
+        with TRACER.span("wave_setup"):
+            if exclude:
                 pending = [
                     p for p in pending
-                    if not (p.get("spec") or {}).get("schedulingGates")
+                    if ((p.get("metadata") or {}).get("namespace") or "default",
+                        (p.get("metadata") or {}).get("name", "")) not in exclude
                 ]
-        if not pending:
-            return 0, None
-        nodes = self._list_shared("nodes")
-        self._wave_node_count = len(nodes)
-        pods_all = self._list_shared("pods")
-        self._gang_wave = None
-        gp = self._gang_plugin()
-        gang_dir = None
-        if gp is not None:
-            pending, gang_dir = self._gang_prescreen(pending, gp, pods_all,
-                                                     nodes)
+            if self.plugin_config.preenqueues():
+                # SchedulingGates PreEnqueue: gated pods never enter the queue
+                gated = [
+                    p for p in pending if (p.get("spec") or {}).get("schedulingGates")
+                ]
+                for p in gated:
+                    meta = p.get("metadata") or {}
+                    self._mark_gated(meta.get("namespace") or "default", meta.get("name", ""))
+                if gated:
+                    pending = [
+                        p for p in pending
+                        if not (p.get("spec") or {}).get("schedulingGates")
+                    ]
             if not pending:
                 return 0, None
-        bound = [
-            (p, p["spec"]["nodeName"]) for p in pods_all
-            if (p.get("spec") or {}).get("nodeName")
-        ]
-        if self.gang_parked:
-            # parked gang members keep their speculative assignments as
-            # assumed binds: their resources stay reserved while the
-            # gang waits for quorum (docs/gang-scheduling.md)
-            bound += self._gang_assumed_bound()
-        # volume manifests for the VolumeBinding/Zone/Restrictions/Limits
-        # family; CSINode is not one of the simulator's 7 synced GVRs
-        # (reference: recorder/recorder.go:45-53), so limits come only from
-        # callers using compile_workload directly
-        volumes = {
-            "pvcs": self._list_shared("persistentvolumeclaims"),
-            "pvs": self._list_shared("persistentvolumes"),
-            "storageclasses": self._list_shared("storageclasses"),
-        }
+            nodes = self._list_shared("nodes")
+            self._wave_node_count = len(nodes)
+            pods_all = self._list_shared("pods")
+            self._gang_wave = None
+            gp = self._gang_plugin()
+            gang_dir = None
+            if gp is not None:
+                pending, gang_dir = self._gang_prescreen(pending, gp, pods_all,
+                                                         nodes)
+                if not pending:
+                    return 0, None
+            bound = [
+                (p, p["spec"]["nodeName"]) for p in pods_all
+                if (p.get("spec") or {}).get("nodeName")
+            ]
+            if self.gang_parked:
+                # parked gang members keep their speculative assignments as
+                # assumed binds: their resources stay reserved while the
+                # gang waits for quorum (docs/gang-scheduling.md)
+                bound += self._gang_assumed_bound()
+            # volume manifests for the VolumeBinding/Zone/Restrictions/Limits
+            # family; CSINode is not one of the simulator's 7 synced GVRs
+            # (reference: recorder/recorder.go:45-53), so limits come only from
+            # callers using compile_workload directly
+            volumes = {
+                "pvcs": self._list_shared("persistentvolumeclaims"),
+                "pvs": self._list_shared("persistentvolumes"),
+                "storageclasses": self._list_shared("storageclasses"),
+            }
         with TRACER.span("compile_workload", pods=len(pending), nodes=len(nodes)):
             from ..state.compile import NodeTableReuse
 
@@ -1188,7 +1245,6 @@ class SchedulerEngine:
             from ..parallel.mesh import can_shard
 
             if not can_shard(cw.n_nodes, mesh):
-                TRACER.count("mesh_fallback_indivisible_nodes_total")
                 mesh = None
 
         from ..store.decode import decode_chunk_into
@@ -1468,6 +1524,11 @@ class SchedulerEngine:
         the replay span APPORTIONED across points and plugins by
         evaluated work (documented estimate; host-path plugins record
         real wall time instead).  Never fails a wave."""
+        with TRACER.span("wave_finish"):
+            self._attribute(rr, replay_seconds, att)
+
+    def _attribute(self, rr, replay_seconds: float,
+                   att: dict | None) -> None:
         try:
             from .replay import plugin_attribution
 

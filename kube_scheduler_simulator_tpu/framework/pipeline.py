@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from ..plugins import (
@@ -439,7 +440,12 @@ def build_step(cw, out_mode: str = "full", pack_mode: str = "p16",
             )
         return new_carry, out
 
-    return step
+    def scan_step(carry: dict[str, Any], sl: dict[str, Any]):
+        # a stable device-side name for the stage (docs/metrics.md)
+        with jax.named_scope("kss_scan_step"):
+            return step(carry, sl)
+
+    return scan_step
 
 
 def build_phased(cw: CompiledWorkload):

@@ -936,6 +936,45 @@ def _compile_quarantine_ttl() -> float:
     return env_float("KSS_TPU_COMPILE_QUARANTINE_S", 300.0)
 
 
+class _FirstCallTimed:
+    """A cached jitted callable that reports, on its FIRST call, what
+    JAX spent compiling it.  jax.jit builds lazily: the registry's
+    builder returns at once and the real tracing, lowering and XLA
+    compile happen inside the first call, on the caller's thread —
+    where utils/hostevents.py hears JAX's own compile events.  Later
+    calls cost one attribute test."""
+
+    __slots__ = ("fn", "key_id", "_timed")
+
+    def __init__(self, fn, key_id: str):
+        self.fn = fn
+        self.key_id = key_id
+        self._timed = False
+
+    def __call__(self, *args, **kwargs):
+        if self._timed:
+            return self.fn(*args, **kwargs)
+        self._timed = True
+        from ..utils.blackbox import BLACKBOX
+        from ..utils.hostevents import thread_compile_seconds
+
+        before = thread_compile_seconds()
+        result = "error"
+        try:
+            out = self.fn(*args, **kwargs)
+            result = "ok"
+            return out
+        finally:
+            dt = thread_compile_seconds() - before
+            TRACER.observe("scan_compile_seconds", dt, key=self.key_id,
+                           result=result)
+            BLACKBOX.record("compile.build", key=self.key_id,
+                            seconds=round(dt, 3), result=result)
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+
 class _ScanCacheRegistry:
     """Process-level LRU registry of jitted scan callables, keyed by
     workload shape (_workload_scan_key).  Concurrent sessions' waves hit
@@ -1009,21 +1048,25 @@ class _ScanCacheRegistry:
                 f"after {self._QUARANTINE_AFTER} consecutive build "
                 f"failures (last: {quarantined_err}); other shapes are "
                 "unaffected")
+        from ..utils import hostevents
         from ..utils.blackbox import BLACKBOX
 
+        # JAX's compile events must be heard before the first call
+        # compiles (idempotent; the server also installs at start)
+        hostevents.install()
         # short stable id for the shape key: a per-key label for the
-        # build-seconds histogram without exploding cardinality (the
+        # compile-seconds histogram without exploding cardinality (the
         # cache itself holds at most max_entries keys)
         key_id = f"{zlib.crc32(repr(key).encode()) & 0xffffffff:08x}"
-        t0 = time.perf_counter()
         try:
             # the jax.jit wrapper builds OUTSIDE the lock (kss-analyze
             # device-under-lock; jit is lazy but build_step touches jnp)
             fault_point("compile.build")
             scan_jit = builder()
         except BaseException as e:
-            dt = time.perf_counter() - t0
-            TRACER.observe("scan_compile_build_seconds", dt, key=key_id,
+            # the build failed before anything could compile: no seconds
+            # to report, the series still counts the failure
+            TRACER.observe("scan_compile_seconds", 0.0, key=key_id,
                            result="error")
             quarantined = False
             with self._mu:
@@ -1043,11 +1086,9 @@ class _ScanCacheRegistry:
                             error=f"{type(e).__name__}: {e}"[:200])
             ev.set()    # waiters retry; they'll become builders
             raise
-        dt = time.perf_counter() - t0
-        TRACER.observe("scan_compile_build_seconds", dt, key=key_id,
-                       result="ok")
-        BLACKBOX.record("compile.build", key=key_id,
-                        seconds=round(dt, 3))
+        if callable(scan_jit):
+            # the compile itself happens in the first call: time it there
+            scan_jit = _FirstCallTimed(scan_jit, key_id)
         with self._mu:
             while len(self._entries) >= self.max_entries:
                 self._entries.popitem(last=False)
@@ -1291,7 +1332,11 @@ def _build_att_fn(chunk: int, n: int, code_bits: int, n_filters: int,
                 fr * bits[None, None, :], axis=-1).astype(jnp.uint8)
         return out
 
-    return fn
+    def attribution_reduction(*args):
+        with jax.named_scope("kss_attribution_reduction"):
+            return fn(*args)
+
+    return attribution_reduction
 
 
 class _DeviceAttribution:
@@ -1480,13 +1525,17 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
                 device_resident: bool = False) -> ReplayResult | None:
     p = cw.n_pods
     chunk = min(chunk, max(p, 1))
-    pack_mode, score_dtypes, score_cols = _compact_plan(cw, wide)
-    scan_jit = _scan_for(cw, chunk, unroll, mesh, pack_mode=pack_mode,
-                         score_dtypes=score_dtypes, wide=wide)
+    # scan_prepare: everything between the replay span's start and the
+    # first dispatch that is not a chunk's own (the scan-cache key with
+    # its statics fingerprint, the carry copy)
+    with TRACER.span("scan_prepare"):
+        pack_mode, score_dtypes, score_cols = _compact_plan(cw, wide)
+        scan_jit = _scan_for(cw, chunk, unroll, mesh, pack_mode=pack_mode,
+                             score_dtypes=score_dtypes, wide=wide)
 
-    # copy: the scan donates its carry argument, and cw.init_carry must
-    # survive for subsequent replays of the same compiled workload
-    carry = jax.tree.map(jnp.array, cw.init_carry)
+        # copy: the scan donates its carry argument, and cw.init_carry must
+        # survive for subsequent replays of the same compiled workload
+        carry = jax.tree.map(jnp.array, cw.init_carry)
     from concurrent.futures import ThreadPoolExecutor
 
     if not collect:
@@ -1530,8 +1579,9 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
         prefilter_reject=prefilter_reject, compact=compact,
     )
     check_overflow = wide != "i64"
-    att_ctx = (_DeviceAttribution(cw, chunk, pack_mode, score_cols)
-               if device_resident else None)
+    with TRACER.span("scan_prepare"):  # the skip masks go to the device
+        att_ctx = (_DeviceAttribution(cw, chunk, pack_mode, score_cols)
+                   if device_resident else None)
     if att_ctx is not None and not att_ctx.enabled:
         att_ctx = None
 
@@ -1603,29 +1653,39 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
     # session-scoped fault rules (and any session-labeled taps) see the
     # owning session at the decision-fetch seam
     wave_session = TRACER.current_session()
+    # ... and the enclosing replay span, so decision_fetch parents under
+    # it across the thread boundary
+    replay_span = TRACER.current_span_id()
 
     def fetch_decisions_scoped(out, att):
-        with TRACER.session_scope(wave_session):
+        with TRACER.session_scope(wave_session), \
+                TRACER.span("decision_fetch", parent=replay_span):
             return _fetch_decisions(out, att)
 
     def fetch_chunk_scoped(out):
-        with TRACER.session_scope(wave_session):
+        with TRACER.session_scope(wave_session), \
+                TRACER.span("decision_fetch", parent=replay_span):
             return _fetch_chunk(out)
 
     with ThreadPoolExecutor(max_workers=3) as pool:
         for lo in range(0, p, chunk):
             hi = min(lo + chunk, p)
             fault_point("replay.scan_dispatch")
-            xs_chunk = _slice_xs(cw.xs, lo, hi, chunk)
-            xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
-            carry, out = scan_jit(carry, xs_chunk)
+            # scan_dispatch: slice the chunk's xs and call the jitted
+            # scan (+ the attribution reduction): tracing, lowering and
+            # the XLA compile on a miss, then the enqueue
+            with TRACER.span("scan_dispatch", lo=lo):
+                xs_chunk = _slice_xs(cw.xs, lo, hi, chunk)
+                xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
+                carry, out = scan_jit(carry, xs_chunk)
+                att_out = (att_ctx.run(out, lo)
+                           if device_resident and att_ctx is not None
+                           else None)
             # dispatch returns immediately; a fetch thread blocks on this
             # chunk's transfer while the device runs later chunks.  In
             # device-resident mode that transfer is the decision rows +
             # the jit'd attribution sums only
             if device_resident:
-                att_out = att_ctx.run(out, lo) if att_ctx is not None \
-                    else None
                 futures.append(pool.submit(fetch_decisions_scoped, out,
                                            att_out))
                 heavy.append(out)

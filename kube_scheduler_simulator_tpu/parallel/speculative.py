@@ -304,15 +304,16 @@ def _oracle_core(packed, prefilter_reject, selected, batch: int):
     crosses to host.  Pad rows sit past the real rows (selected == -1,
     never bound), so a pad conflict can only push K past them — the
     caller clamps to the round's real size."""
-    feas = (packed == 0) & (prefilter_reject == 0)[:, None]
-    bound = selected >= 0                           # [B]
-    cols = jnp.maximum(selected, 0)
-    feas_at_sel = jnp.take(feas, cols, axis=1)      # [B(k), B(j)]
-    before = jnp.tril(jnp.ones((batch, batch), bool), k=-1)
-    conflict = jnp.any(feas_at_sel & bound[None, :] & before,
-                       axis=1)                      # [B]
-    return jnp.where(jnp.any(conflict), jnp.argmax(conflict),
-                     jnp.int32(batch)).astype(jnp.int32)
+    with jax.named_scope("kss_conflict_oracle"):
+        feas = (packed == 0) & (prefilter_reject == 0)[:, None]
+        bound = selected >= 0                           # [B]
+        cols = jnp.maximum(selected, 0)
+        feas_at_sel = jnp.take(feas, cols, axis=1)      # [B(k), B(j)]
+        before = jnp.tril(jnp.ones((batch, batch), bool), k=-1)
+        conflict = jnp.any(feas_at_sel & bound[None, :] & before,
+                           axis=1)                      # [B]
+        return jnp.where(jnp.any(conflict), jnp.argmax(conflict),
+                         jnp.int32(batch)).astype(jnp.int32)
 
 
 def _eval_fn(cw: CompiledWorkload, base_key, batch: int, pack_mode: str,
@@ -339,7 +340,13 @@ def _eval_fn(cw: CompiledWorkload, base_key, batch: int, pack_mode: str,
             _, out = step(carry, sl)
             return out
 
-        return jax.jit(_tiled_vmap(eval_only, batch, (None, 0)))
+        tiled = _tiled_vmap(eval_only, batch, (None, 0))
+
+        def dense_round(carry, xs):
+            with jax.named_scope("kss_speculative_round"):
+                return tiled(carry, xs)
+
+        return jax.jit(dense_round)
 
     return _SCAN_CACHE.get_or_build(key, build)
 
@@ -487,8 +494,9 @@ def _sparse_round_fn(cw: CompiledWorkload, base_key, batch: int,
             return packed, reject, count, raw8, raw16, raw32, ovf, selected
 
         def round_fn(carry, xs):
-            (packed, reject, counts, raw8, raw16, raw32, ovf,
-             selected) = _tiled_vmap(one, batch, (None, 0))(carry, xs)
+            with jax.named_scope("kss_speculative_round"):
+                (packed, reject, counts, raw8, raw16, raw32, ovf,
+                 selected) = _tiled_vmap(one, batch, (None, 0))(carry, xs)
             k_dev = _oracle_core(packed, reject, selected, batch)
             return (packed, reject, counts, raw8, raw16, raw32, ovf,
                     selected, k_dev)
@@ -545,7 +553,11 @@ def _commit_fn(cw: CompiledWorkload, base_key, batch: int):
                 out, _ = jax.lax.scan(body, carry, (xs_batch, sel))
                 return out
 
-        return jax.jit(commit, donate_argnums=(0,))
+        def bind_fold(carry, xs_batch, selected, accept):
+            with jax.named_scope("kss_bind_fold"):
+                return commit(carry, xs_batch, selected, accept)
+
+        return jax.jit(bind_fold, donate_argnums=(0,))
 
     return _SCAN_CACHE.get_or_build(key, build)
 
@@ -1005,14 +1017,16 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
             hi = min(lo + (chunk if aligned else chunk - fill), p)
             m = hi - lo
             fault_point("replay.scan_dispatch")
-            xs_chunk = _slice_xs(cw_scan.xs, lo, hi, chunk)
-            xs_chunk["is_pad"] = (jnp.arange(chunk) >= m)
-            carry, out = scan_jit(carry, xs_chunk)
+            with TRACER.span("scan_dispatch", lo=lo):
+                xs_chunk = _slice_xs(cw_scan.xs, lo, hi, chunk)
+                xs_chunk["is_pad"] = (jnp.arange(chunk) >= m)
+                carry, out = scan_jit(carry, xs_chunk)
             fault_point("replay.decision_fetch")
-            sel = np.asarray(out.selected)
-            fc = np.asarray(out.feasible_count)
-            rej = np.asarray(out.prefilter_reject)
-            ovf = np.asarray(out.raw_overflow)
+            with TRACER.span("decision_fetch"):
+                sel = np.asarray(out.selected)
+                fc = np.asarray(out.feasible_count)
+                rej = np.asarray(out.prefilter_reject)
+                ovf = np.asarray(out.raw_overflow)
             TRACER.count("wave_d2h_bytes_total",
                          sel.nbytes + fc.nbytes + rej.nbytes + ovf.nbytes)
             if check_overflow and ovf[:m].any():
@@ -1044,40 +1058,48 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
         m = hi - lo
         with TRACER.span("speculative_round", batch=m, rung=b):
             fault_point("replay.scan_dispatch")
-            xs = _slice_xs(cw.xs, lo, hi, b)
-            xs["is_pad"] = (jnp.arange(b) >= m)
-            xs = place_batch(xs)
+            # the sequential scan's two seams, under the same two names
+            with TRACER.span("scan_dispatch", lo=lo):
+                xs = _slice_xs(cw.xs, lo, hi, b)
+                xs["is_pad"] = (jnp.arange(b) >= m)
+                xs = place_batch(xs)
             dense = not sparse
             if sparse:
                 # one fused dispatch per round; a wide-feasibility round
                 # (max count past the candidate cap) simply discards the
                 # sparse output and re-runs dense
-                (packed, reject_d, counts_d, raw8, raw16, raw32, ovf_d,
-                 sel_dev, k_dev) = fused_call("round", b, round_for(b),
-                                              carry, xs)
+                with TRACER.span("scan_dispatch", lo=lo):
+                    (packed, reject_d, counts_d, raw8, raw16, raw32, ovf_d,
+                     sel_dev, k_dev) = fused_call("round", b, round_for(b),
+                                                  carry, xs)
                 fault_point("replay.decision_fetch")
-                fc = np.asarray(counts_d)
-                rej = np.asarray(reject_d)
-                if int(fc[:m].max(initial=0)) > kcand:
-                    dense = True  # wide feasibility: this round runs dense
-                else:
-                    sel = np.asarray(sel_dev)
-                    ovf = np.asarray(ovf_d)
-                    rows = {"packed": packed, "raw8": raw8, "raw16": raw16,
-                            "raw32": raw32, "fc": counts_d}
+                with TRACER.span("decision_fetch"):
+                    fc = np.asarray(counts_d)
+                    rej = np.asarray(reject_d)
+                    if int(fc[:m].max(initial=0)) > kcand:
+                        dense = True  # wide feasibility: this round runs dense
+                    else:
+                        sel = np.asarray(sel_dev)
+                        ovf = np.asarray(ovf_d)
+                        rows = {"packed": packed, "raw8": raw8,
+                                "raw16": raw16, "raw32": raw32,
+                                "fc": counts_d}
             if dense:
-                outs, k_dev = fused_call("dense", b, dense_round_for(b),
-                                         carry, xs)
+                with TRACER.span("scan_dispatch", lo=lo):
+                    outs, k_dev = fused_call("dense", b, dense_round_for(b),
+                                             carry, xs)
                 fault_point("replay.decision_fetch")
-                sel = np.asarray(outs.selected)
-                fc = np.asarray(outs.feasible_count)
-                rej = np.asarray(outs.prefilter_reject)
-                ovf = np.asarray(outs.raw_overflow)
+                with TRACER.span("decision_fetch"):
+                    sel = np.asarray(outs.selected)
+                    fc = np.asarray(outs.feasible_count)
+                    rej = np.asarray(outs.prefilter_reject)
+                    ovf = np.asarray(outs.raw_overflow)
                 sel_dev = outs.selected
                 rows = {"packed": outs.packed_filter, "raw8": outs.raw8,
                         "raw16": outs.raw16, "raw32": outs.raw32,
                         "fc": outs.feasible_count}
-            k = min(int(k_dev), m)
+            with TRACER.span("decision_fetch"):
+                k = min(int(k_dev), m)
             TRACER.count("wave_d2h_bytes_total",
                          sel.nbytes + fc.nbytes + rej.nbytes + ovf.nbytes + 4)
             if inter is not None and k > 1:

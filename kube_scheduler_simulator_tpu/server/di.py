@@ -28,7 +28,7 @@ import traceback
 
 from ..utils.tracing import TRACER
 
-from ..cluster.store import ADDED, MODIFIED, ObjectStore
+from ..cluster.store import ADDED, DELETED, MODIFIED, ObjectStore
 from ..config.config import SimulatorConfiguration
 from ..framework.engine import SchedulerEngine
 from ..scenario.runner import ScenarioService
@@ -87,31 +87,45 @@ class SchedulingLoop:
                 return
             _, event_type, obj = ev
             if event_type == ADDED and not ((obj.get("spec") or {}).get("nodeName")):
+                # where the store hands a pending pod to the loop: the
+                # stamp the wave's queue_wait_* counters measure from
+                self.engine.note_arrival(obj)
                 self._wake.set()
+            elif event_type == DELETED:
+                self.engine.forget_arrival(obj)
 
     def _run(self):
-        while not self._stop.is_set():
-            self._wake.wait()
-            if self._stop.is_set():
-                return
-            self._wake.clear()
-            self._stop.wait(self.debounce)  # batch bursts
-            try:
-                self.engine.schedule_pending()
-            except Exception as e:  # keep the loop alive like a crashed-and-restarted pod
-                tb = traceback.format_exc()
-                self.last_crash = {
-                    "time": time.time(),
-                    "error": f"{type(e).__name__}: {e}",
-                    "traceback": tb,
-                }
-                session = getattr(self.engine, "session", None)
-                if session is not None:
-                    TRACER.inc("scheduling_loop_crashes_total",
-                               session=session)
-                else:
-                    TRACER.count("scheduling_loop_crashes_total")
-                traceback.print_exc()
+        # loop_idle / loop_debounce / loop_pass cover this thread end to
+        # end: under a profile every instant of it carries a kss: span
+        with TRACER.session_scope(getattr(self.engine, "session", None)):
+            while not self._stop.is_set():
+                with TRACER.span("loop_idle"):
+                    self._wake.wait()
+                if self._stop.is_set():
+                    return
+                self._wake.clear()
+                with TRACER.span("loop_debounce"):
+                    self._stop.wait(self.debounce)  # batch bursts
+                with TRACER.span("loop_pass"):
+                    self._pass()
+
+    def _pass(self):
+        try:
+            self.engine.schedule_pending()
+        except Exception as e:  # keep the loop alive like a crashed-and-restarted pod
+            tb = traceback.format_exc()
+            self.last_crash = {
+                "time": time.time(),
+                "error": f"{type(e).__name__}: {e}",
+                "traceback": tb,
+            }
+            session = getattr(self.engine, "session", None)
+            if session is not None:
+                TRACER.inc("scheduling_loop_crashes_total",
+                           session=session)
+            else:
+                TRACER.count("scheduling_loop_crashes_total")
+            traceback.print_exc()
 
 
 class DIContainer:

@@ -105,8 +105,11 @@ class SimulatorServer:
         # idempotent, a daemon, explicit no-op gauge on stat-less
         # backends (CPU); history leg only where this process owns no
         # device
+        from ..utils import hostevents
         from ..utils.blackbox import TELEMETRY
 
+        # XLA compiles by function and GC pauses as tracer counters
+        hostevents.install()
         TELEMETRY.start(device=self.owns_device)
         # closed-loop autopilot (control/autopilot.py, docs/autopilot.md):
         # always-on controller thread unless KSS_TPU_AUTOPILOT opts out
@@ -265,6 +268,18 @@ def _make_handler(server: SimulatorServer):
                     TRACER.note_session_trace(sess.id, tid)
                 with TRACER.session_scope(sess.id), \
                         TRACER.trace_scope(self.trace_id):
+                    # the three requests of served traffic each get a
+                    # span, handler entry to last byte written, under
+                    # the request's trace id (docs/metrics.md span tree)
+                    if method == "POST" and path == "/api/v1/pods":
+                        with TRACER.span("http_pod_create"):
+                            return self._dispatch(method, path, url)
+                    if method == "POST" and path == "/api/v1/import":
+                        with TRACER.span("http_import"):
+                            return self._dispatch(method, path, url)
+                    if method == "GET" and path.startswith("/api/v1/pods/"):
+                        with TRACER.span("http_pod_read"):
+                            return self._dispatch(method, path, url)
                     return self._dispatch(method, path, url)
             except ApiError as e:
                 self._error(e)
@@ -490,7 +505,12 @@ def _make_handler(server: SimulatorServer):
             try:
                 if action == "start":
                     log_dir = body.get("logDir") or "/tmp/kss-tpu-profile"
-                    TRACER.start_xla_profile(log_dir)
+                    # "pythonTracer": false drops the profiler's Python
+                    # tracer; the tracer's spans (kss:<name> TraceMe
+                    # events) then label the host side of the trace
+                    TRACER.start_xla_profile(
+                        log_dir,
+                        python_tracer=body.get("pythonTracer") is not False)
                     return self._json(200, {"profiling": True, "logDir": log_dir})
                 if action == "stop":
                     d = TRACER.stop_xla_profile()

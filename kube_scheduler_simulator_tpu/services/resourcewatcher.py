@@ -17,6 +17,7 @@ import json
 import threading
 
 from ..cluster.store import ObjectStore, RESOURCES, ADDED, DEFAULT_GVRS
+from ..utils.tracing import TRACER
 
 # wire protocol: per-kind *LastResourceVersion query params a client passes
 # to resume (reference: server/handler/watcher.go:23-45 form values)
@@ -44,18 +45,21 @@ class StreamWriter:
         # lazy columnar rows (cluster/columnar.LazyManifest) must be
         # materialized explicitly: json's C encoder walks dict storage
         # directly, bypassing the subclass's lazy-read overrides
-        fill = getattr(obj, "fill", None)
-        if fill is not None:
-            fill()
-        data = json.dumps({"kind": kind, "eventType": event_type, "obj": obj})
-        with self._lock:
-            try:
-                self._write(data.encode() if isinstance(data, str) else data)
-                if self._flush:
-                    self._flush()
-                return True
-            except (BrokenPipeError, ConnectionError, OSError):
-                return False
+        with TRACER.span("watch_write"):
+            fill = getattr(obj, "fill", None)
+            if fill is not None:
+                fill()
+            data = json.dumps({"kind": kind, "eventType": event_type,
+                               "obj": obj}).encode()
+            with self._lock:
+                try:
+                    self._write(data)
+                    if self._flush:
+                        self._flush()
+                except (BrokenPipeError, ConnectionError, OSError):
+                    return False
+            TRACER.count("watch_bytes_sent_total", len(data))
+            return True
 
 
 class ResourceWatcherService:

@@ -114,151 +114,177 @@ def compile_workload(
     config = config or reg.PluginSetConfig()
     bound_pods = bound_pods or []
     volumes = volumes or {}
-    # columnar fast path: listings from the columnar store carry their
-    # bank view (cluster/columnar.ColumnarManifestList) — schema
-    # discovery, the node-table identity, and the table build all read
-    # columns instead of walking N manifests
-    cols = getattr(nodes, "columns", None)
-    if cols is not None:
-        schema = ResourceSchema.discover_columnar(
-            pods + [bp for bp, _ in bound_pods], cols)
-        node_key = cols.identity()
-    else:
-        schema = ResourceSchema.discover(
-            pods + [bp for bp, _ in bound_pods], nodes)
-        node_key = tuple(
-            ((n.get("metadata") or {}).get("name", ""),
-             (n.get("metadata") or {}).get("resourceVersion", ""))
-            for n in nodes
-        )
-    table = None
-    if (reuse is not None
-            and tuple(reuse.schema.columns) == tuple(schema.columns)
-            and reuse.schema.n == schema.n):
-        old_key = reuse.host.get("node_key")
-        if old_key == node_key:
-            schema = reuse.schema
-            table = reuse.node_table
-            TRACER.count("node_table_reuse_total")
+    # one child span per phase (docs/metrics.md span tree); a name each,
+    # because span aggregates are by name.  BUILD_SPAN_PLUGINS lists the
+    # plugins that get a cw_build_<Plugin> span
+    with TRACER.span("cw_schema"):
+        # columnar fast path: listings from the columnar store carry their
+        # bank view (cluster/columnar.ColumnarManifestList) — schema
+        # discovery, the node-table identity, and the table build all read
+        # columns instead of walking N manifests
+        cols = getattr(nodes, "columns", None)
+        if cols is not None:
+            schema = ResourceSchema.discover_columnar(
+                pods + [bp for bp, _ in bound_pods], cols)
+            node_key = cols.identity()
         else:
-            delta = _node_delta(old_key, node_key, cols)
-            if delta is not None:
+            schema = ResourceSchema.discover(
+                pods + [bp for bp, _ in bound_pods], nodes)
+            node_key = tuple(
+                ((n.get("metadata") or {}).get("name", ""),
+                 (n.get("metadata") or {}).get("resourceVersion", ""))
+                for n in nodes
+            )
+    with TRACER.span("cw_node_table"):
+        table = None
+        if (reuse is not None
+                and tuple(reuse.schema.columns) == tuple(schema.columns)
+                and reuse.schema.n == schema.n):
+            old_key = reuse.host.get("node_key")
+            if old_key == node_key:
                 schema = reuse.schema
-                if cols is not None:
-                    table = patch_node_table_columnar(
-                        reuse.node_table, cols, delta, schema)
-                else:
-                    table = patch_node_table(
-                        reuse.node_table, nodes, delta, schema)
-                TRACER.count("node_table_delta_patches_total")
-                TRACER.count("node_table_delta_rows_total", len(delta))
-    if table is None:
-        table = (build_node_table_columnar(cols, schema) if cols is not None
-                 else build_node_table(nodes, schema))
-        TRACER.count("node_table_builds_total")
-
-    p = len(pods)
-    requests, nonzero = _pod_requests(pods, schema, pod_columns)
+                table = reuse.node_table
+                TRACER.count("node_table_reuse_total")
+            else:
+                delta = _node_delta(old_key, node_key, cols)
+                if delta is not None:
+                    schema = reuse.schema
+                    if cols is not None:
+                        table = patch_node_table_columnar(
+                            reuse.node_table, cols, delta, schema)
+                    else:
+                        table = patch_node_table(
+                            reuse.node_table, nodes, delta, schema)
+                    TRACER.count("node_table_delta_patches_total")
+                    TRACER.count("node_table_delta_rows_total", len(delta))
+        if table is None:
+            table = (build_node_table_columnar(cols, schema)
+                     if cols is not None else build_node_table(nodes, schema))
+            TRACER.count("node_table_builds_total")
 
     statics: dict[str, Any] = {}
     xs: dict[str, Any] = {}
     init_carry: dict[str, Any] = {}
     host: dict[str, Any] = {"node_table": table, "schema": schema,
                             "node_key": node_key}
-
-    # core resource carry, primed with bound pods
-    name_idx = {name: j for j, name in enumerate(table.names)}
-    req0 = table.initial_requested.copy()
-    nz0 = table.initial_nonzero.copy()
-    np0 = table.initial_num_pods.copy()
-    if bound_pods:
-        b_req, b_nz = _pod_requests(
-            [bp for bp, _ in bound_pods], schema, pod_columns)
-        for bi, (_, node_name) in enumerate(bound_pods):
-            j = name_idx.get(node_name)
-            if j is None:
-                continue
-            req0[j] += b_req[bi]
-            nz0[j] += b_nz[bi]
-            np0[j] += 1
-
+    p = len(pods)
     enabled = set(config.active_plugins())
-    # Fit static/xs double as the core resource tensors even when the Fit
-    # plugin itself is disabled (bind updates always need pod requests).
-    fit_static, fit_xs = noderesources.build_fit(
-        table, schema, requests, nonzero,
-        fit_args=config.args.get("NodeResourcesFit"))
-    statics["core"] = fit_static
-    xs["core"] = fit_xs
-    from ..plugins.base import CoreCarry
+    with TRACER.span("cw_core"):
+        requests, nonzero = _pod_requests(pods, schema, pod_columns)
 
-    init_carry["core"] = CoreCarry(
-        requested=jnp.asarray(req0),
-        nonzero=jnp.asarray(nz0),
-        num_pods=jnp.asarray(np0),
-    )
+        # core resource carry, primed with bound pods
+        name_idx = {name: j for j, name in enumerate(table.names)}
+        req0 = table.initial_requested.copy()
+        nz0 = table.initial_nonzero.copy()
+        np0 = table.initial_num_pods.copy()
+        if bound_pods:
+            b_req, b_nz = _pod_requests(
+                [bp for bp, _ in bound_pods], schema, pod_columns)
+            for bi, (_, node_name) in enumerate(bound_pods):
+                j = name_idx.get(node_name)
+                if j is None:
+                    continue
+                req0[j] += b_req[bi]
+                nz0[j] += b_nz[bi]
+                np0[j] += 1
+
+        # Fit static/xs double as the core resource tensors even when the
+        # Fit plugin itself is disabled (bind updates always need pod
+        # requests).
+        fit_static, fit_xs = noderesources.build_fit(
+            table, schema, requests, nonzero,
+            fit_args=config.args.get("NodeResourcesFit"))
+        statics["core"] = fit_static
+        xs["core"] = fit_xs
+        from ..plugins.base import CoreCarry
+
+        init_carry["core"] = CoreCarry(
+            requested=jnp.asarray(req0),
+            nonzero=jnp.asarray(nz0),
+            num_pods=jnp.asarray(np0),
+        )
 
     if "NodeAffinity" in enabled:
-        st, x = affinity.build(
-            table, pods, args=config.args.get("NodeAffinity"), host_out=host)
-        statics["NodeAffinity"] = st
-        xs["NodeAffinity"] = x
+        with TRACER.span("cw_build_NodeAffinity"):
+            st, x = affinity.build(
+                table, pods, args=config.args.get("NodeAffinity"),
+                host_out=host)
+            statics["NodeAffinity"] = st
+            xs["NodeAffinity"] = x
     if "NodePorts" in enabled:
-        st, x, carry = ports.build(table, pods, bound_pods)
-        statics["NodePorts"] = st
-        xs["NodePorts"] = x
-        init_carry["NodePorts"] = carry
+        with TRACER.span("cw_build_NodePorts"):
+            st, x, carry = ports.build(table, pods, bound_pods)
+            statics["NodePorts"] = st
+            xs["NodePorts"] = x
+            init_carry["NodePorts"] = carry
     if "ImageLocality" in enabled:
-        xs["ImageLocality"] = imagelocality.build(nodes, pods, host_out=host)
+        with TRACER.span("cw_build_ImageLocality"):
+            xs["ImageLocality"] = imagelocality.build(nodes, pods,
+                                                      host_out=host)
     if "TaintToleration" in enabled:
-        xs["TaintToleration"] = taints.build_taints(table, pods, host_out=host)
+        with TRACER.span("cw_build_TaintToleration"):
+            xs["TaintToleration"] = taints.build_taints(table, pods,
+                                                        host_out=host)
     if "NodeUnschedulable" in enabled:
-        xs["NodeUnschedulable"] = taints.build_unschedulable(table, pods)
+        with TRACER.span("cw_build_NodeUnschedulable"):
+            xs["NodeUnschedulable"] = taints.build_unschedulable(table, pods)
     if "NodeName" in enabled:
-        xs["NodeName"] = taints.build_nodename(table, pods)
+        with TRACER.span("cw_build_NodeName"):
+            xs["NodeName"] = taints.build_nodename(table, pods)
     if "PodTopologySpread" in enabled:
-        st, x, counts_dom = topologyspread.build(table, pods)
-        statics["PodTopologySpread"] = st
-        xs["PodTopologySpread"] = x
-        _prime_spread_counts(counts_dom, st, pods, bound_pods, name_idx)
-        init_carry["PodTopologySpread"] = topologyspread.assemble_counts(st, counts_dom)
+        with TRACER.span("cw_build_PodTopologySpread"):
+            st, x, counts_dom = topologyspread.build(table, pods)
+            statics["PodTopologySpread"] = st
+            xs["PodTopologySpread"] = x
+            _prime_spread_counts(counts_dom, st, pods, bound_pods, name_idx)
+            init_carry["PodTopologySpread"] = \
+                topologyspread.assemble_counts(st, counts_dom)
     if any(name in enabled for name in VOLUME_PLUGINS):
-        vt = build_volume_table(
-            table, volumes.get("pvcs"), volumes.get("pvs"),
-            volumes.get("storageclasses"), volumes.get("csinodes"),
-        )
-        host["volume_table"] = vt
+        with TRACER.span("cw_volume_table"):
+            vt = build_volume_table(
+                table, volumes.get("pvcs"), volumes.get("pvs"),
+                volumes.get("storageclasses"), volumes.get("csinodes"),
+            )
+            host["volume_table"] = vt
         # per-pod PreFilter rejects (UnschedulableAndUnresolvable), keyed
         # by the plugin whose PreFilter reports them; the earliest enabled
         # prefilter plugin in DEFAULT_ORDER wins at decode time
         rejects: dict[str, list[str | None]] = {}
         if "VolumeRestrictions" in enabled:
-            st, x, carry = volumerestrictions.build(vt, table, pods, bound_pods)
-            statics["VolumeRestrictions"] = st
-            xs["VolumeRestrictions"] = x
-            init_carry["VolumeRestrictions"] = carry
-            # upstream VolumeRestrictions' PreFilter does the PVC lister
-            # lookup first, so a missing PVC rejects there
-            rejects["VolumeRestrictions"] = [
-                _missing_pvc_message(vt, pod) for pod in pods
-            ]
+            with TRACER.span("cw_build_VolumeRestrictions"):
+                st, x, carry = volumerestrictions.build(vt, table, pods,
+                                                        bound_pods)
+                statics["VolumeRestrictions"] = st
+                xs["VolumeRestrictions"] = x
+                init_carry["VolumeRestrictions"] = carry
+                # upstream VolumeRestrictions' PreFilter does the PVC
+                # lister lookup first, so a missing PVC rejects there
+                rejects["VolumeRestrictions"] = [
+                    _missing_pvc_message(vt, pod) for pod in pods
+                ]
         if "NodeVolumeLimits" in enabled:
-            st, x, carry = nodevolumelimits.build(vt, table, pods, bound_pods)
-            statics["NodeVolumeLimits"] = st
-            xs["NodeVolumeLimits"] = x
-            init_carry["NodeVolumeLimits"] = carry
+            with TRACER.span("cw_build_NodeVolumeLimits"):
+                st, x, carry = nodevolumelimits.build(vt, table, pods,
+                                                      bound_pods)
+                statics["NodeVolumeLimits"] = st
+                xs["NodeVolumeLimits"] = x
+                init_carry["NodeVolumeLimits"] = carry
         if "VolumeBinding" in enabled:
-            st, x, carry, vb_rejects = volumebinding.build(vt, table, pods, bound_pods)
-            statics["VolumeBinding"] = st
-            xs["VolumeBinding"] = x
-            init_carry["VolumeBinding"] = carry
-            rejects["VolumeBinding"] = vb_rejects
-            # VolumeCapacityPriority is off: Score is constant 0 for every
-            # (pod, node) — keep it host-resident (np.zeros is COW-cheap)
-            host.setdefault("static_score_rows", {})["VolumeBinding"] = (
-                np.zeros((p, table.n), dtype=np.int8))
+            with TRACER.span("cw_build_VolumeBinding"):
+                st, x, carry, vb_rejects = volumebinding.build(
+                    vt, table, pods, bound_pods)
+                statics["VolumeBinding"] = st
+                xs["VolumeBinding"] = x
+                init_carry["VolumeBinding"] = carry
+                rejects["VolumeBinding"] = vb_rejects
+                # VolumeCapacityPriority is off: Score is constant 0 for
+                # every (pod, node) — keep it host-resident (np.zeros is
+                # COW-cheap)
+                host.setdefault("static_score_rows", {})["VolumeBinding"] = (
+                    np.zeros((p, table.n), dtype=np.int8))
         if "VolumeZone" in enabled:
-            xs["VolumeZone"] = volumezone.build(vt, table, pods)
+            with TRACER.span("cw_build_VolumeZone"):
+                xs["VolumeZone"] = volumezone.build(vt, table, pods)
         if any(any(m is not None for m in msgs) for msgs in rejects.values()):
             host["prefilter_reject"] = rejects
             xs["force_unsched"] = jnp.asarray(np.asarray([
@@ -270,43 +296,59 @@ def compile_workload(
             continue
         from ..plugins.custom import build_custom
 
-        x, msg_table = build_custom(plugin, table, pods, nodes,
-                                    name=name, host_out=host)
-        xs[name] = x
-        host.setdefault("custom_msgs", {})[name] = msg_table
+        with TRACER.span("cw_build_custom", plugin=name):
+            x, msg_table = build_custom(plugin, table, pods, nodes,
+                                        name=name, host_out=host)
+            xs[name] = x
+            host.setdefault("custom_msgs", {})[name] = msg_table
     if "InterPodAffinity" in enabled:
         # Build the term table over queue + bound pods together so the bound
         # pods' terms (which matter for the symmetric existing-pod checks)
         # share the same term ids; then slice the per-pod xs back to the
         # queue and fold the bound rows into the initial carry.
-        bound_manifests = [bp for bp, _ in bound_pods]
-        st, x_all, dom_mats = interpod.build(
-            table, pods + bound_manifests,
-            hard_weight=int((config.args.get("InterPodAffinity") or {})
-                            .get("hardPodAffinityWeight")
-                            or interpod.DEFAULT_HARD_POD_AFFINITY_WEIGHT),
-            namespaces=namespaces,
-        )
-        statics["InterPodAffinity"] = st
-        xs["InterPodAffinity"] = interpod.InterPodXS(
-            *[v[:p] for v in x_all]
-        )
-        _prime_interpod_counts(dom_mats, st, x_all, len(pods), bound_pods, name_idx)
-        init_carry["InterPodAffinity"] = interpod.assemble_carry(st, dom_mats)
+        with TRACER.span("cw_build_InterPodAffinity"):
+            bound_manifests = [bp for bp, _ in bound_pods]
+            st, x_all, dom_mats = interpod.build(
+                table, pods + bound_manifests,
+                hard_weight=int((config.args.get("InterPodAffinity") or {})
+                                .get("hardPodAffinityWeight")
+                                or interpod.DEFAULT_HARD_POD_AFFINITY_WEIGHT),
+                namespaces=namespaces,
+            )
+            statics["InterPodAffinity"] = st
+            xs["InterPodAffinity"] = interpod.InterPodXS(
+                *[v[:p] for v in x_all]
+            )
+            _prime_interpod_counts(dom_mats, st, x_all, len(pods),
+                                   bound_pods, name_idx)
+            init_carry["InterPodAffinity"] = interpod.assemble_carry(
+                st, dom_mats)
 
-    cw = CompiledWorkload(
-        schema=schema,
-        node_table=table,
-        pods=pods,
-        pod_keys=[_pod_key(pod) for pod in pods],
-        config=config,
-        statics=statics,
-        xs=xs,
-        init_carry=init_carry,
-        host=host,
-    )
-    _collect_host_flags(cw)
+    with TRACER.span("cw_finish"):
+        cw = CompiledWorkload(
+            schema=schema,
+            node_table=table,
+            pods=pods,
+            pod_keys=[_pod_key(pod) for pod in pods],
+            config=config,
+            statics=statics,
+            xs=xs,
+            init_carry=init_carry,
+            host=host,
+        )
+        _collect_host_flags(cw)
     return cw
+
+
+# the plugins whose build (with its carry priming) compile_workload wraps
+# in a span named cw_build_<Plugin>; NodeResourcesFit and
+# NodeResourcesBalancedAllocation build inside cw_core, custom plugins
+# under cw_build_custom
+BUILD_SPAN_PLUGINS = (
+    "NodeAffinity", "NodePorts", "ImageLocality", "TaintToleration",
+    "NodeUnschedulable", "NodeName", "PodTopologySpread",
+    "VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding", "VolumeZone",
+    "InterPodAffinity")
 
 
 def _node_delta(old_key, node_key, cols):
@@ -374,7 +416,6 @@ def _pod_requests(pods: list[dict], schema: ResourceSchema, pod_columns):
                 if col is not None:
                     requests[ok, j] = col[okr]
             nonzero[ok] = bank.nonzero[okr]
-            TRACER.count("compile_requests_gathered_total", int(ok.sum()))
         misses = miss
     for i in misses:
         requests[i], nonzero[i] = pod_resource_request(pods[i], schema)

@@ -768,22 +768,25 @@ class DeviceTelemetry:
                                              0.01)):
                                 return
                             now = time.monotonic()
-                            if now >= next_hbm:
-                                try:
-                                    self.sample_once()
-                                # survive a backend teardown race
-                                # kss-analyze: allow(swallowed-exception)
-                                except Exception:
-                                    pass
-                                next_hbm = now + hbm_iv
-                            if now >= next_hist:
-                                try:
-                                    FEEDER.sample()
-                                # same contract as the HBM leg
-                                # kss-analyze: allow(swallowed-exception)
-                                except Exception:
-                                    pass
-                                next_hist = now + h_iv
+                            # one span a tick: a rhythm in the served
+                            # latency can be laid beside this thread's
+                            with TRACER.span("telemetry_sample"):
+                                if now >= next_hbm:
+                                    try:
+                                        self.sample_once()
+                                    # survive a backend teardown race
+                                    # kss-analyze: allow(swallowed-exception)
+                                    except Exception:
+                                        pass
+                                    next_hbm = now + hbm_iv
+                                if now >= next_hist:
+                                    try:
+                                        FEEDER.sample()
+                                    # same contract as the HBM leg
+                                    # kss-analyze: allow(swallowed-exception)
+                                    except Exception:
+                                        pass
+                                    next_hist = now + h_iv
 
                     t = self._thread = threading.Thread(
                         target=loop, daemon=True, name="hbm-sampler")

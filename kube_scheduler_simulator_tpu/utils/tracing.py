@@ -75,9 +75,9 @@ BUCKETS: dict[str, tuple[float, ...]] = {
     # accept FRACTION per speculative round — a ratio in (0, 1], not a
     # duration: linear decile buckets (docs/metrics.md)
     "speculative_accept_fraction": tuple(i / 10 for i in range(1, 11)),
-    # XLA scan builds run ~0.1s (warm shapes) to tens of seconds (cold
+    # XLA scan compiles run ~0.1s (warm shapes) to tens of seconds (cold
     # giant meshes): a wider exponential ladder than the attempt buckets
-    "scan_compile_build_seconds": _exp_buckets(0.01, 2, 14),
+    "scan_compile_seconds": _exp_buckets(0.01, 2, 14),
     # sessions sharing one fused device dispatch — a small integer
     # (1 = ran solo), not a duration (parallel/fuse.py, docs/metrics.md)
     "fused_sessions_per_dispatch": (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0,
@@ -193,9 +193,47 @@ _HELP: dict[str, str] = {
         "1 when the backend exposes device memory_stats (HBM gauges "
         "are live), 0 as the explicit no-op marker where it does not "
         "(the CPU backend).",
-    "scan_compile_build_seconds":
-        "Wall seconds of one XLA scan build, labeled by the workload "
-        "shape's cache key (key=<crc32 of the shape key>) and result.",
+    "scan_compile_seconds":
+        "Seconds JAX spent tracing, lowering and XLA-compiling a cached "
+        "scan on its FIRST call (JAX's own compile events on the calling "
+        "thread, utils/hostevents.py), labeled by the workload shape's "
+        "cache key (key=<crc32 of the shape key>) and result.",
+    "queue_wait_seconds_total":
+        "Seconds pods waited between the store handing their ADDED event "
+        "to the scheduling loop and the start of the wave that took them, "
+        "summed over pods (debounce and any pass already running "
+        "included).",
+    "queue_wait_pods_total":
+        "Pods whose queue wait was added to queue_wait_seconds_total.",
+    "queue_wait_oldest_seconds_total":
+        "Queue wait of the OLDEST pod of each wave, one term per wave.",
+    "scheduling_work_passes_total":
+        "Scheduling waves that took at least one pod "
+        "(scheduling_waves_total counts empty wake-ups too).",
+    "scheduling_pass_pods_total":
+        "Pods taken by the waves scheduling_work_passes_total counts.",
+    "jax_compile_seconds_total":
+        "Seconds inside JAX's compile stages (stage=trace|lower|"
+        "backend_compile), from jax.monitoring duration events.",
+    "jax_compile_events_total":
+        "JAX compile-stage events by stage; backend_compile counts every "
+        "executable built OR fetched from the persistent cache "
+        "(jax_persistent_cache_total tells them apart).",
+    "jax_compiles_by_function_total":
+        "Backend compiles by jitted function name (fun; past 64 distinct "
+        "names, \"other\") and by the innermost tracer span open on the "
+        "compiling thread (span; \"none\" outside any span).",
+    "jax_persistent_cache_total":
+        "Persistent compilation cache outcomes: request = a compile that "
+        "consulted the cache, hit = served from it, miss = compiled and "
+        "WRITTEN (compiles under the cache's time/size thresholds are "
+        "requests that are neither).",
+    "gc_pause_seconds_total":
+        "Seconds inside CPython garbage collections, by generation "
+        "(gc.callbacks; the collecting thread holds the GIL throughout).",
+    "gc_collections_total": "CPython garbage collections by generation.",
+    "watch_bytes_sent_total":
+        "Bytes of encoded watch events written to list-watch streams.",
     "scan_compile_cache_entries":
         "Compiled scan executables currently held by the process-level "
         "LRU cache (framework/replay._ScanCacheRegistry).",
@@ -241,13 +279,17 @@ class Span:
     """Handle yielded by Tracer.span(): carries the span id (for explicit
     cross-thread parenting) and, after exit, the measured seconds."""
 
-    __slots__ = ("id", "parent_id", "name", "seconds")
+    __slots__ = ("id", "parent_id", "name", "seconds", "attrs")
 
-    def __init__(self, span_id: int, parent_id: int | None, name: str):
+    def __init__(self, span_id: int, parent_id: int | None, name: str,
+                 attrs: dict):
         self.id = span_id
         self.parent_id = parent_id
         self.name = name
         self.seconds = 0.0
+        # the span's event attrs: a caller may add to them until the
+        # span exits (the wave root learns its node count mid-span)
+        self.attrs = attrs
 
 
 class _Hist:
@@ -291,6 +333,16 @@ class Tracer:
         self._hist_bounds: dict[str, tuple[float, ...]] = {}
         self._profile_dir: str | None = None
         self._profile_lock = threading.Lock()
+        # jax.profiler.TraceAnnotation while an XLA profile runs, else
+        # None: span() then also opens a "kss:<name>" TraceMe, so every
+        # program span sits on its thread's line of the host plane of
+        # the same .xplane.pb as the device ops — one clock
+        self._annotate = None
+        # callables run (outside the lock) before every export: sources
+        # that may not take the tracer's lock where they observe — a GC
+        # callback can fire inside any allocation, this lock held —
+        # accumulate on their own and hand their totals over here
+        self._collectors: list = []
         self._epoch = time.time()
         self._perf_epoch = time.perf_counter()
         self._ids = itertools.count(1)
@@ -397,7 +449,14 @@ class Tracer:
 
     def current_span_id(self) -> int | None:
         st = self._stack()
-        return st[-1] if st else None
+        return st[-1].id if st else None
+
+    def current_span_name(self) -> str | None:
+        """Name of the innermost span open on this thread (the JAX
+        compile listener labels a compile with it: a compile is
+        synchronous on the caller's thread)."""
+        st = self._stack()
+        return st[-1].name if st else None
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -406,6 +465,53 @@ class Tracer:
             ent = (len(self._tids) + 1, threading.current_thread().name)
             self._tids[ident] = ent
         return ent[0]
+
+    def annotate(self, name: str):
+        """While an XLA profile runs: an ENTERED kss:<name> TraceMe (the
+        caller exits it, on the same thread); None otherwise — the cost
+        of the one-clock bridge when no profile runs is this test."""
+        annotate = self._annotate
+        if annotate is None:
+            return None
+        annotate = annotate("kss:" + name)
+        annotate.__enter__()
+        return annotate
+
+    def record_span(self, name: str, t0: float, seconds: float,
+                    **attrs) -> None:
+        """A span that was timed elsewhere (t0 on time.perf_counter's
+        clock) enters the ring and the aggregates after the fact: for
+        sources that may not take this lock while they observe (a GC
+        callback; utils/hostevents.py hands its pauses over this way)."""
+        with self._lock:
+            self._record_locked({
+                "name": name, "t": time.time(), "seconds": seconds,
+                "ts": round(t0 - self._perf_epoch, 6),
+                "span_id": next(self._ids), "parent_id": None,
+                "tid": self._tid(), **attrs,
+            }, None)
+
+    def _record_locked(self, event: dict, session: str | None) -> None:
+        """A finished span enters the ring and the per-name aggregates
+        (the caller holds the lock)."""
+        if (self._events.maxlen is not None
+                and len(self._events) == self._events.maxlen):
+            # the ring is full: this append evicts the oldest span
+            # silently — count it so long soaks can see their trace tail
+            # scrolled away (summary(), /metrics
+            # tracer_events_dropped_total)
+            self._counters["tracer_events_dropped_total"] = \
+                self._counters.get("tracer_events_dropped_total", 0) + 1
+        self._events.append(event)
+        aggs = [self._agg]
+        if session is not None:
+            aggs.append(self._sagg.setdefault(session, {}))
+        for agg in aggs:
+            a = agg.setdefault(event["name"], {
+                "count": 0, "total_seconds": 0.0, "max_seconds": 0.0})
+            a["count"] += 1
+            a["total_seconds"] += event["seconds"]
+            a["max_seconds"] = max(a["max_seconds"], event["seconds"])
 
     @contextmanager
     def span(self, name: str, parent: int | None = None, **attrs):
@@ -416,9 +522,9 @@ class Tracer:
         .seconds is set on exit."""
         st = self._stack()
         sp = Span(next(self._ids),
-                  parent if parent is not None else (st[-1] if st else None),
-                  name)
-        st.append(sp.id)
+                  parent if parent is not None else (st[-1].id if st else None),
+                  name, attrs)
+        st.append(sp)
         session = self.current_session()
         if session is not None and "session" not in attrs:
             attrs["session"] = session
@@ -436,6 +542,7 @@ class Tracer:
                     **({"trace_id": trace_id} if trace_id is not None
                        else {}),
                 }
+        annotate = self.annotate(name)
         try:
             yield sp
         except BaseException as exc:
@@ -456,40 +563,19 @@ class Tracer:
                     pass
             raise
         finally:
+            if annotate is not None:
+                annotate.__exit__(None, None, None)
             dt = time.perf_counter() - t0
             sp.seconds = dt
             st.pop()
             with self._lock:
                 self._open.pop(sp.id, None)
-                if (self._events.maxlen is not None
-                        and len(self._events) == self._events.maxlen):
-                    # the ring is full: this append evicts the oldest
-                    # span silently — count it so long soaks can see
-                    # their trace tail scrolled away (summary(),
-                    # /metrics tracer_events_dropped_total)
-                    self._counters["tracer_events_dropped_total"] = \
-                        self._counters.get(
-                            "tracer_events_dropped_total", 0) + 1
-                tid = self._tid()
-                self._events.append({
+                self._record_locked({
                     "name": name, "t": time.time(), "seconds": dt,
                     "ts": round(t0 - self._perf_epoch, 6),
-                    "span_id": sp.id, "parent_id": sp.parent_id, "tid": tid,
-                    **attrs,
-                })
-                a = self._agg.setdefault(
-                    name, {"count": 0, "total_seconds": 0.0, "max_seconds": 0.0}
-                )
-                a["count"] += 1
-                a["total_seconds"] += dt
-                a["max_seconds"] = max(a["max_seconds"], dt)
-                if session is not None:
-                    a = self._sagg.setdefault(session, {}).setdefault(
-                        name,
-                        {"count": 0, "total_seconds": 0.0, "max_seconds": 0.0})
-                    a["count"] += 1
-                    a["total_seconds"] += dt
-                    a["max_seconds"] = max(a["max_seconds"], dt)
+                    "span_id": sp.id, "parent_id": sp.parent_id,
+                    "tid": self._tid(), **attrs,
+                }, session)
 
     # ---------------------------------------------------------- counters
 
@@ -544,12 +630,20 @@ class Tracer:
             return float(self._counters.get(
                 "tracer_events_dropped_total", 0))
 
+    def add_collector(self, fn) -> None:
+        self._collectors.append(fn)
+
+    def _collect(self) -> None:
+        for fn in self._collectors:
+            fn()
+
     def counter_totals(self) -> dict[str, float]:
         """Every counter flattened to one {key: value} dict: plain
         counters under their name, labeled series under
         name{k=v,...}.  The black box captures this at wave start and
         diffs at dump time — the per-wave counter deltas a post-mortem
         carries."""
+        self._collect()
         with self._lock:
             out = dict(self._counters)
             for name, series in self._lcounters.items():
@@ -568,6 +662,12 @@ class Tracer:
         session = self.current_session()
         if session is not None and "session" not in labels:
             labels["session"] = session
+        self.inc_process(name, n, **labels)
+
+    def inc_process(self, name: str, n: float = 1, **labels) -> None:
+        """inc() for events of the PROCESS, not of a session (a garbage
+        collection lands on whichever thread allocated last): no session
+        label is folded in."""
         key = tuple(sorted((k, str(v)) for k, v in labels.items()))
         with self._lock:
             series = self._lcounters.setdefault(name, {})
@@ -663,6 +763,7 @@ class Tracer:
         """Back-compat aggregate view: span aggregates + plain counters
         (the pre-flight-recorder shape; snapshot() adds the labeled
         families)."""
+        self._collect()
         with self._lock:
             spans = {
                 k: {**v, "avg_seconds": v["total_seconds"] / max(v["count"], 1)}
@@ -678,6 +779,7 @@ class Tracer:
         per-session tallies, labeled counters and histograms keep only
         series whose session label matches (docs/metrics.md)."""
         if session is not None:
+            self._collect()
             skey = ("session", str(session))
             with self._lock:
                 sagg = {
@@ -766,6 +868,7 @@ class Tracer:
         validate_exposition(): # HELP/# TYPE per family, sanitized
         metric names, escaped label values, cumulative histogram
         buckets ending at +Inf."""
+        self._collect()
         with self._lock:
             counters = dict(self._counters)
             gauges = dict(self._gauges)
@@ -928,9 +1031,19 @@ class Tracer:
 
     # -------------------------------------------------------- XLA profile
 
-    def start_xla_profile(self, log_dir: str) -> None:
+    def start_xla_profile(self, log_dir: str,
+                          python_tracer: bool = True) -> None:
+        """python_tracer=False turns the profiler's Python tracer off
+        (it makes a served cycle ~3x as long, PERF.md): the program's
+        own spans, as kss:<name> TraceMe events, then label the host
+        side of the trace."""
         import jax
 
+        kwargs = {}
+        if not python_tracer:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            kwargs["profiler_options"] = options
         with self._profile_lock:
             if self._profile_dir is not None:
                 raise ProfileStateError(
@@ -940,12 +1053,13 @@ class Tracer:
                 # lock exists solely to make the is-running check and the
                 # start one transition (409 on double start); nothing on
                 # the scheduling path ever takes it
-                jax.profiler.start_trace(log_dir)  # kss-analyze: allow(device-under-lock)
+                jax.profiler.start_trace(log_dir, **kwargs)  # kss-analyze: allow(device-under-lock)
             except RuntimeError as e:
                 # a profiler session started outside this Tracer — still a
                 # state conflict, not a server error
                 raise ProfileStateError(str(e)) from e
             self._profile_dir = log_dir
+            self._annotate = jax.profiler.TraceAnnotation
 
     def stop_xla_profile(self) -> str:
         import jax
@@ -953,6 +1067,7 @@ class Tracer:
         with self._profile_lock:
             if self._profile_dir is None:
                 raise ProfileStateError("no profile running")
+            self._annotate = None
             try:
                 # same contract as start: _profile_lock serializes only
                 # the profiler state transition itself

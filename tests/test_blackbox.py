@@ -387,13 +387,20 @@ def test_compile_build_histogram_and_cache_gauge():
     engine.schedule_pending()
     engine.close()
     snap = TRACER.snapshot()
-    hist = snap["histograms"].get("scan_compile_build_seconds")
-    assert hist is not None and hist["series"], "no build histogram"
+    # the scan's REAL compile seconds, from JAX's own events round its
+    # first call (not the time to build the lazy jax.jit object)
+    hist = snap["histograms"].get("scan_compile_seconds")
+    assert hist is not None and hist["series"], "no compile histogram"
     assert all("key" in s["labels"] and s["labels"]["result"] == "ok"
                for s in hist["series"])
+    assert sum(s["sum"] for s in hist["series"]) > 0
     assert snap["gauges"].get("scan_compile_cache_entries", 0) >= 1
     builds = [e for e in BLACKBOX.events() if e["kind"] == "compile.build"]
-    assert builds and builds[0]["seconds"] >= 0
+    assert builds and builds[0]["seconds"] > 0 and builds[0]["result"] == "ok"
+    compiled = snap["labeled_counters"]["jax_compiles_by_function_total"]
+    # ... and where it compiled: under the wave's dispatch span
+    assert any(s["labels"]["span"] == "scan_dispatch" for s in compiled), \
+        compiled
 
 
 def test_device_telemetry_explicit_noop_on_cpu():

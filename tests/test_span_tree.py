@@ -1,0 +1,324 @@
+"""The span tree of a served pass and the counters beside it
+(docs/metrics.md "The span tree of a pass"): wave and its children, the
+compile_workload phases, queue wait, JAX compile events by function, GC
+pauses, the loop's and the server's spans, and the same spans as kss:
+TraceMe events in a profile."""
+
+from __future__ import annotations
+
+import gc
+import json
+import sys
+import threading
+import time
+import urllib.request
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from kube_scheduler_simulator_tpu.cluster.store import ObjectStore
+from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
+from kube_scheduler_simulator_tpu.models.workloads import make_nodes, make_pods
+from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
+from kube_scheduler_simulator_tpu.state.compile import BUILD_SPAN_PLUGINS
+from kube_scheduler_simulator_tpu.utils import hostevents
+from kube_scheduler_simulator_tpu.utils.tracing import (
+    TRACER, validate_exposition)
+
+WAVE_CHILDREN = ("wave_setup", "compile_workload", "replay_and_decode_stream",
+                 "commit_and_reflect", "wave_finish")
+SPECULATIVE_SET = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
+                   "NodeAffinity", "TaintToleration", "PodTopologySpread"]
+
+
+def _wave(plugin_config=None, nodes=50, pods=12, seed=31):
+    """One engine pass over a fresh store -> (engine, TRACER.snapshot())."""
+    hostevents.install()
+    store = ObjectStore()
+    for n in make_nodes(nodes, seed=seed):
+        store.create("nodes", n)
+    for p in make_pods(pods, seed=seed + 1):
+        store.create("pods", p)
+    engine = SchedulerEngine(store, plugin_config=plugin_config, chunk=16)
+    TRACER.reset()
+    engine.schedule_pending()
+    snap = TRACER.snapshot()
+    engine.close()
+    return engine, snap
+
+
+def _seconds(snap, names):
+    return sum(snap["spans"].get(n, {}).get("total_seconds", 0.0)
+               for n in names)
+
+
+@pytest.fixture(scope="module")
+def default_wave():
+    """The default profile (every default plugin; the sequential scan)."""
+    return _wave()
+
+
+def test_wave_children_cover_the_sequential_wave(default_wave):
+    _, snap = default_wave
+    spans = snap["spans"]
+    assert spans["wave"]["count"] == 1
+    assert _seconds(snap, WAVE_CHILDREN) >= 0.95 * _seconds(snap, ["wave"])
+    # the two seams of the sequential scan, and the root's attrs
+    assert spans["scan_dispatch"]["count"] >= 1
+    assert spans["decision_fetch"]["count"] >= 1
+    wave = [e for e in TRACER.events(4096) if e["name"] == "wave"]
+    assert wave and wave[-1]["pods"] == 12 and wave[-1]["nodes"] == 50
+    assert snap["counters"]["scheduling_work_passes_total"] == 1
+    assert snap["counters"]["scheduling_pass_pods_total"] == 12
+
+
+def test_wave_children_cover_the_speculative_wave(monkeypatch):
+    monkeypatch.setenv("KSS_TPU_SPECULATIVE", "1")
+    _, snap = _wave(PluginSetConfig(enabled=list(SPECULATIVE_SET)))
+    spans = snap["spans"]
+    assert spans["speculative_round"]["count"] >= 1
+    assert _seconds(snap, WAVE_CHILDREN) >= 0.95 * _seconds(snap, ["wave"])
+    # the speculative round's dispatch and fetch carry the same two names
+    assert spans["scan_dispatch"]["count"] >= 1
+    assert spans["decision_fetch"]["count"] >= 1
+
+
+def test_every_default_plugin_yields_its_build_span(default_wave):
+    engine, snap = default_wave
+    enabled = set(engine.plugin_config.active_plugins())
+    built = [p for p in BUILD_SPAN_PLUGINS if p in enabled]
+    assert len(built) >= 10, built  # the default profile enables them all
+    for plugin in built:
+        assert snap["spans"][f"cw_build_{plugin}"]["count"] == 1, plugin
+    phases = ["cw_schema", "cw_node_table", "cw_core", "cw_volume_table",
+              "cw_finish"] + [f"cw_build_{p}" for p in built]
+    for name in phases:
+        assert name in snap["spans"], name
+    assert _seconds(snap, phases) >= 0.9 * _seconds(snap, ["compile_workload"])
+
+
+def test_jax_listener_counts_one_backend_compile_per_fresh_function():
+    import jax
+
+    hostevents.install()
+
+    @jax.jit
+    def fresh_fn_for_the_listener_test(x):
+        return x * 3 + 1
+
+    x = np.arange(5, dtype=np.int32)
+    key = "jax_compile_events_total{stage=backend_compile}"
+    fun = ("jax_compiles_by_function_total{"
+           "fun=jit(fresh_fn_for_the_listener_test),span=listener_test}")
+    before = TRACER.counter_totals()
+    t0 = hostevents.thread_compile_seconds()
+    with TRACER.span("listener_test"):
+        fresh_fn_for_the_listener_test(x)
+    first = TRACER.counter_totals()
+    assert first.get(key, 0) - before.get(key, 0) == 1
+    assert first.get(fun, 0) - before.get(fun, 0) == 1
+    assert hostevents.thread_compile_seconds() > t0
+    for stage in ("trace", "lower", "backend_compile"):
+        k = f"jax_compile_seconds_total{{stage={stage}}}"
+        assert first.get(k, 0) > before.get(k, 0), stage
+    with TRACER.span("listener_test"):
+        fresh_fn_for_the_listener_test(x)
+    second = TRACER.counter_totals()
+    assert second.get(key, 0) == first.get(key, 0)  # zero on the second call
+    assert second.get(fun, 0) == first.get(fun, 0)
+
+
+def test_jax_listener_caps_function_labels(monkeypatch):
+    monkeypatch.setattr(hostevents, "_fun_labels", set())
+    names = [f"jit(f{i})" for i in range(hostevents.MAX_FUN_LABELS)]
+    assert [hostevents._fun_label(n) for n in names] == names
+    assert hostevents._fun_label("jit(one_too_many)") == "other"
+    assert hostevents._fun_label(names[3]) == names[3]  # known names stay
+    assert hostevents._fun_label(None) == "other"
+
+
+def test_gc_hook_counts_a_full_collection():
+    hostevents.install()
+    before = TRACER.counter_totals()
+    gc.collect(2)
+    after = TRACER.counter_totals()
+    n = "gc_collections_total{generation=2}"
+    s = "gc_pause_seconds_total{generation=2}"
+    assert after.get(n, 0) - before.get(n, 0) >= 1
+    assert after.get(s, 0) > before.get(s, 0)
+    # ... and each full collection is a gc_gen2 span on the timeline
+    assert TRACER.snapshot()["spans"]["gc_gen2"]["count"] >= 1
+
+
+def test_queue_wait_after_a_held_debounce():
+    from kube_scheduler_simulator_tpu.server.di import SchedulingLoop
+
+    store = ObjectStore()
+    store.create("nodes", make_nodes(1, seed=41)[0])
+    engine = SchedulerEngine(
+        store, plugin_config=PluginSetConfig(enabled=["NodeResourcesFit"]))
+    loop = SchedulingLoop(store, engine, debounce=0.3)
+    TRACER.reset()
+    loop.start()
+    try:
+        pod = make_pods(1, seed=42)[0]
+        store.create("pods", pod)
+        meta = pod["metadata"]
+        deadline = time.time() + 60
+        while time.time() < deadline:
+            got = store.get("pods", meta["name"], meta.get("namespace"))
+            if (got.get("spec") or {}).get("nodeName"):
+                break
+            time.sleep(0.02)
+        else:
+            pytest.fail("the loop never bound the pod")
+        # the counters land at wave START, the bind later in the same wave
+        c = TRACER.snapshot()["counters"]
+    finally:
+        loop.stop()
+        engine.close()
+    assert c["queue_wait_pods_total"] == 1
+    assert 0.3 <= c["queue_wait_seconds_total"] < 5.0
+    assert c["queue_wait_oldest_seconds_total"] == c["queue_wait_seconds_total"]
+    assert c["scheduling_work_passes_total"] == 1
+    assert c["scheduling_pass_pods_total"] == 1
+    spans = TRACER.snapshot()["spans"]
+    assert spans["loop_debounce"]["total_seconds"] >= 0.3
+    assert spans["loop_idle"]["count"] >= 1
+    assert not engine._arrivals  # the stamp was taken by the wave
+
+
+def test_profile_holds_the_spans_as_kss_tracemes(tmp_path):
+    """With a profile running, every program span is also a kss:<name>
+    TraceMe in the .xplane.pb, and benchmark/lib/xplane_spans.py finds
+    them there (the CPU backend has no device plane: all of it is idle)."""
+    from jax.profiler import ProfileData
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmark"))
+    from lib import xplane_spans
+
+    store = ObjectStore()
+    for n in make_nodes(5, seed=51):
+        store.create("nodes", n)
+    for p in make_pods(3, seed=52):
+        store.create("pods", p)
+    engine = SchedulerEngine(
+        store, plugin_config=PluginSetConfig(enabled=["NodeResourcesFit"]))
+    TRACER.start_xla_profile(str(tmp_path), python_tracer=False)
+    try:
+        assert TRACER.profiling
+        with TRACER.span("loop_pass"):
+            engine.schedule_pending()
+    finally:
+        TRACER.stop_xla_profile()
+        engine.close()
+    assert TRACER._annotate is None
+    pd = ProfileData.from_file(str(xplane_spans.find_xplane(tmp_path)))
+    names = {e.name for plane in pd.planes for ln in plane.lines
+             for e in ln.events if e.name.startswith("kss:")}
+    assert {"kss:compile_workload", "kss:wave", "kss:cw_core",
+            "kss:scan_dispatch"} <= names, names
+    red = xplane_spans.reduce_spans(pd)
+    assert red["kss_events"] >= 10
+    by_span = dict(red["idle_by_span"])
+    assert "loop_pass" not in by_span or by_span["loop_pass"] < red["idle_s"]
+    assert red["idle_in_spans_s"] == pytest.approx(red["idle_s"], rel=1e-6)
+
+
+@pytest.fixture(scope="module")
+def live_server():
+    from kube_scheduler_simulator_tpu.config.config import (
+        SimulatorConfiguration)
+    from kube_scheduler_simulator_tpu.server.di import DIContainer
+    from kube_scheduler_simulator_tpu.server.server import SimulatorServer
+
+    di = DIContainer(SimulatorConfiguration(port=0), start_scheduler=True)
+    srv = SimulatorServer(di, port=0)
+    srv.start(block=False)
+    yield di, f"http://127.0.0.1:{srv.port}"
+    srv.shutdown()
+
+
+def _http(base, method, path, body=None):
+    req = urllib.request.Request(
+        base + path, method=method,
+        data=json.dumps(body).encode() if body is not None else None,
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=30) as r:
+        raw = r.read()
+        return r.status, json.loads(raw) if raw else None
+
+
+def test_served_requests_and_the_watch_stream_have_spans(live_server):
+    _, base = live_server
+    TRACER.reset()
+    got = threading.Event()
+
+    def watch():
+        with urllib.request.urlopen(base + "/api/v1/listwatchresources",
+                                    timeout=20) as resp:
+            if resp.read1(65536):
+                got.set()
+
+    t = threading.Thread(target=watch, daemon=True)
+    t.start()
+    _http(base, "POST", "/api/v1/import",
+          {"nodes": make_nodes(2, seed=61), "pods": []})
+    pod = make_pods(1, seed=62)[0]
+    _http(base, "POST", "/api/v1/pods", pod)
+    meta = pod["metadata"]
+    ns = meta.get("namespace") or "default"
+    # until the loop has bound the pod: no wave may outlive this module
+    deadline = time.time() + 120
+    while True:
+        _, got_pod = _http(base, "GET", f"/api/v1/pods/{ns}/{meta['name']}")
+        if (got_pod.get("spec") or {}).get("nodeName"):
+            break
+        assert time.time() < deadline, "the loop never bound the pod"
+        time.sleep(0.05)
+    assert got.wait(20), "the watch stream delivered nothing"
+    t.join(timeout=5)
+    snap = TRACER.snapshot()
+    for name in ("http_import", "http_pod_create", "http_pod_read",
+                 "watch_write"):
+        assert snap["spans"][name]["count"] >= 1, name
+    assert snap["counters"]["watch_bytes_sent_total"] > 0
+    # the create's span carries the request's trace id
+    ev = [e for e in TRACER.events(4096) if e["name"] == "http_pod_create"]
+    assert ev and ev[-1].get("trace_id", "").startswith("t-")
+
+
+def test_profile_route_takes_python_tracer_false(live_server, monkeypatch):
+    import jax
+
+    _, base = live_server
+    seen = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: seen.append(kw))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    _http(base, "POST", "/api/v1/profile", {"action": "start"})
+    _http(base, "POST", "/api/v1/profile", {"action": "stop"})
+    _http(base, "POST", "/api/v1/profile",
+          {"action": "start", "pythonTracer": False})
+    _http(base, "POST", "/api/v1/profile", {"action": "stop"})
+    assert seen[0] == {}  # the default stays as it was
+    assert seen[1]["profiler_options"].python_tracer_level == 0
+
+
+def test_exposition_stays_valid_with_the_new_labeled_families(default_wave):
+    hostevents.install()
+    gc.collect(2)
+    import jax
+
+    jax.jit(lambda x: x - 7)(np.arange(3))  # at least one compile event
+    families = validate_exposition(TRACER.prometheus_text())
+    by_fun = families["kss_tpu_jax_compiles_by_function_total"]
+    assert by_fun["type"] == "counter"
+    assert all({"fun", "span"} <= set(labels)
+               for _n, labels, _v in by_fun["samples"])
+    stages = {labels["stage"] for _n, labels, _v in
+              families["kss_tpu_jax_compile_events_total"]["samples"]}
+    assert {"trace", "lower", "backend_compile"} <= stages
+    assert any(labels.get("generation") == "2" for _n, labels, _v in
+               families["kss_tpu_gc_pause_seconds_total"]["samples"])

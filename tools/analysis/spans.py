@@ -31,7 +31,7 @@ _METRIC_NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_NAME_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
 
 TRACER_BASES = {"TRACER", "tracer", "_tracer"}
-METRIC_METHODS = {"count", "inc", "observe"}
+METRIC_METHODS = {"count", "inc", "inc_process", "observe"}
 
 
 class SpanAnalyzer:
