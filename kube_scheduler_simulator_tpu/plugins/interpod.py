@@ -167,10 +167,22 @@ def effective_terms(pod: dict, field: str, preferred: bool,
 
 
 def build(table: NodeTable, pods: list[dict],
+          bound_pods: list[tuple[dict, str]],
           hard_weight: int = DEFAULT_HARD_POD_AFFINITY_WEIGHT,
           namespaces: list[dict] | None = None):
+    """-> (InterPodStatic, InterPodXS of the queue pods, InterPodCarry
+    primed with bound pods).
+
+    The term table is interned over queue + bound pods together, queue
+    first, so the bound pods' terms (which matter for the symmetric
+    existing-pod checks) share the queue's term ids.  The per-pod arrays
+    stay in numpy until they are cut to the queue rows: the bound rows are
+    folded into the carry here and never reach the device, so no shape
+    that does depends on how many pods are bound."""
     labels = table.labels
     n, p = table.n, len(pods)
+    pods = pods + [bp for bp, _ in bound_pods]   # rows p.. are the bound pods
+    rows = len(pods)
 
     # --- unique term table ----------------------------------------------
     terms: dict[tuple, int] = {}
@@ -215,12 +227,12 @@ def build(table: NodeTable, pods: list[dict],
     d_max = max(int(dom_idx.max()) + 1, 1)
 
     # --- pod x term matches + per-pod term weights -----------------------
-    t_matches = np.zeros((p, t_count), dtype=bool)
-    h_req_aff = np.zeros((p, t_count), dtype=np.int32)
-    h_req_anti = np.zeros((p, t_count), dtype=np.int32)
-    h_pref_aff_w = np.zeros((p, t_count), dtype=np.int64)
-    h_pref_anti_w = np.zeros((p, t_count), dtype=np.int64)
-    self_ok = np.zeros(p, dtype=bool)
+    t_matches = np.zeros((rows, t_count), dtype=bool)
+    h_req_aff = np.zeros((rows, t_count), dtype=np.int32)
+    h_req_anti = np.zeros((rows, t_count), dtype=np.int32)
+    h_pref_aff_w = np.zeros((rows, t_count), dtype=np.int64)
+    h_pref_anti_w = np.zeros((rows, t_count), dtype=np.int64)
+    self_ok = np.zeros(rows, dtype=bool)
     for i, pod in enumerate(pods):
         pod_ns = (pod.get("metadata") or {}).get("namespace") or "default"
         pod_labels = {k: str(v) for k, v in ((pod.get("metadata") or {}).get("labels") or {}).items()}
@@ -237,6 +249,8 @@ def build(table: NodeTable, pods: list[dict],
             h_pref_anti_w[i, t_id] += w
         self_ok[i] = all(t_matches[i, t_id] for t_id, _ in e["req_aff"])
 
+    # PreFilter Skip is coarser than upstream's (module docstring): the
+    # bound pods' required anti-affinity terms count too
     any_workload_anti = bool(h_req_anti.any())
     filter_skip = np.array(
         [
@@ -250,26 +264,43 @@ def build(table: NodeTable, pods: list[dict],
 
     static = InterPodStatic(dom_idx=jnp.asarray(dom_idx), hard_weight=jnp.int64(hard_weight))
     xs = InterPodXS(
-        t_matches=jnp.asarray(t_matches),
-        h_req_aff=jnp.asarray(h_req_aff),
-        h_req_anti=jnp.asarray(h_req_anti),
-        h_pref_aff_w=jnp.asarray(h_pref_aff_w),
-        h_pref_anti_w=jnp.asarray(h_pref_anti_w),
-        self_ok=jnp.asarray(self_ok),
+        t_matches=jnp.asarray(t_matches[:p]),
+        h_req_aff=jnp.asarray(h_req_aff[:p]),
+        h_req_anti=jnp.asarray(h_req_anti[:p]),
+        h_pref_aff_w=jnp.asarray(h_pref_aff_w[:p]),
+        h_pref_anti_w=jnp.asarray(h_pref_anti_w[:p]),
+        self_ok=jnp.asarray(self_ok[:p]),
         filter_skip=jnp.asarray(filter_skip),
     )
-    dom_mats = {
-        name: np.zeros((t_count, d_max), dtype=np.int64)
-        for name in ("matched", "have_req_anti", "have_req_aff",
-                     "sym_pref_aff", "sym_pref_anti")
-    }
-    return static, xs, dom_mats
+
+    # --- bound pods -> the carry's per-(term, domain) counts ---------------
+    name_idx = {name: j for j, name in enumerate(table.names)}
+    node_of = np.array([name_idx.get(node_name, -1) for _, node_name in bound_pods],
+                       dtype=np.int64)
+    on_table = np.flatnonzero(node_of >= 0)   # bound to a node of the table
+    dom_of = dom_idx[:, node_of[on_table]]    # [T, B'] each pod's domain per term
+    t_ids, b_ids = np.nonzero(dom_of >= 0)    # the node carries the term's key
+    at = (t_ids, dom_of[t_ids, b_ids])
+    src = (p + on_table[b_ids], t_ids)
+
+    def fold(per_pod_term: np.ndarray) -> np.ndarray:
+        mat = np.zeros((t_count, d_max), dtype=np.int64)
+        np.add.at(mat, at, per_pod_term[src])
+        return mat
+
+    carry = assemble_carry(dom_idx, {
+        "matched": fold(t_matches),
+        "have_req_anti": fold(h_req_anti),
+        "have_req_aff": fold(h_req_aff),
+        "sym_pref_aff": fold(h_pref_aff_w),
+        "sym_pref_anti": fold(h_pref_anti_w),
+    })
+    return static, xs, carry
 
 
-def assemble_carry(static: InterPodStatic, dom_mats: dict) -> InterPodCarry:
-    """[T, D] domain-space numpy mats (build + host priming) -> the
+def assemble_carry(dom: np.ndarray, dom_mats: dict) -> InterPodCarry:
+    """[T, D] domain-space numpy mats over the host dom_idx [T, N] -> the
     node-space device carry (one take_along_axis per mat, on host)."""
-    dom = np.asarray(static.dom_idx)
     safe = np.maximum(dom, 0)
 
     def to_nodes(mat: np.ndarray) -> jnp.ndarray:

@@ -302,27 +302,17 @@ def compile_workload(
             xs[name] = x
             host.setdefault("custom_msgs", {})[name] = msg_table
     if "InterPodAffinity" in enabled:
-        # Build the term table over queue + bound pods together so the bound
-        # pods' terms (which matter for the symmetric existing-pod checks)
-        # share the same term ids; then slice the per-pod xs back to the
-        # queue and fold the bound rows into the initial carry.
         with TRACER.span("cw_build_InterPodAffinity"):
-            bound_manifests = [bp for bp, _ in bound_pods]
-            st, x_all, dom_mats = interpod.build(
-                table, pods + bound_manifests,
+            st, x, carry = interpod.build(
+                table, pods, bound_pods,
                 hard_weight=int((config.args.get("InterPodAffinity") or {})
                                 .get("hardPodAffinityWeight")
                                 or interpod.DEFAULT_HARD_POD_AFFINITY_WEIGHT),
                 namespaces=namespaces,
             )
             statics["InterPodAffinity"] = st
-            xs["InterPodAffinity"] = interpod.InterPodXS(
-                *[v[:p] for v in x_all]
-            )
-            _prime_interpod_counts(dom_mats, st, x_all, len(pods),
-                                   bound_pods, name_idx)
-            init_carry["InterPodAffinity"] = interpod.assemble_carry(
-                st, dom_mats)
+            xs["InterPodAffinity"] = x
+            init_carry["InterPodAffinity"] = carry
 
     with TRACER.span("cw_finish"):
         cw = CompiledWorkload(
@@ -459,34 +449,6 @@ def _spread_groups(pods):
     # constraints incl. matchLabelKeys merge) or bound-pod priming would
     # credit the wrong count groups
     return topologyspread.constraint_groups(pods)
-
-
-def _prime_interpod_counts(dom_mats, st, x_all, n_queue, bound_pods, name_idx):
-    """Fold bound pods (rows n_queue.. of x_all) into the domain-space
-    interpod count mats (in place; interpod.assemble_carry converts to the
-    node-space device carry afterwards)."""
-    if not bound_pods:
-        return
-    dom_idx = np.asarray(st.dom_idx)
-    t_matches = np.asarray(x_all.t_matches)
-    h_req_anti = np.asarray(x_all.h_req_anti)
-    h_req_aff = np.asarray(x_all.h_req_aff)
-    h_pref_aff_w = np.asarray(x_all.h_pref_aff_w)
-    h_pref_anti_w = np.asarray(x_all.h_pref_anti_w)
-    for bi, (_, node_name) in enumerate(bound_pods):
-        j = name_idx.get(node_name)
-        if j is None:
-            continue
-        i = n_queue + bi
-        for t_id in range(dom_idx.shape[0]):
-            dm = dom_idx[t_id, j]
-            if dm < 0:
-                continue
-            dom_mats["matched"][t_id, dm] += bool(t_matches[i, t_id])
-            dom_mats["have_req_anti"][t_id, dm] += int(h_req_anti[i, t_id])
-            dom_mats["have_req_aff"][t_id, dm] += int(h_req_aff[i, t_id])
-            dom_mats["sym_pref_aff"][t_id, dm] += int(h_pref_aff_w[i, t_id])
-            dom_mats["sym_pref_anti"][t_id, dm] += int(h_pref_anti_w[i, t_id])
 
 
 def _collect_host_flags(cw: CompiledWorkload):

@@ -41,6 +41,10 @@ def _wave(plugin_config=None, nodes=50, pods=12, seed=31):
     for p in make_pods(pods, seed=seed + 1):
         store.create("pods", p)
     engine = SchedulerEngine(store, plugin_config=plugin_config, chunk=16)
+    # what a process's first wave imports between two children (~50 ms,
+    # once: _needs_host_path's) is no part of a pass, and the children
+    # are held to 95% of a wave that can be as short as 1.5 s here
+    import kube_scheduler_simulator_tpu.scheduler.debuggable  # noqa: F401
     TRACER.reset()
     engine.schedule_pending()
     snap = TRACER.snapshot()
@@ -322,3 +326,62 @@ def test_exposition_stays_valid_with_the_new_labeled_families(default_wave):
     assert {"trace", "lower", "backend_compile"} <= stages
     assert any(labels.get("generation") == "2" for _n, labels, _v in
                families["kss_tpu_gc_pause_seconds_total"]["samples"])
+
+
+BACKEND_COMPILES = "jax_compile_events_total{stage=backend_compile}"
+
+
+def _one_pod(name, affinity_terms, node_name=None):
+    pod = {"apiVersion": "v1", "kind": "Pod",
+           "metadata": {"name": name, "namespace": "default",
+                        "labels": {"color": "blue"}},
+           "spec": {"containers": [{"name": "c", "resources": {
+               "requests": {"cpu": "100m", "memory": "64Mi"}}}]}}
+    if affinity_terms:
+        pod["spec"]["affinity"] = {"podAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [{
+                "topologyKey": "topology.kubernetes.io/zone",
+                "labelSelector": {"matchLabels": {"color": "blue"}}}]}}
+    if node_name:
+        pod["spec"]["nodeName"] = node_name
+        pod["status"] = {"phase": "Running"}
+    return pod
+
+
+@pytest.mark.parametrize("affinity_terms,prefilled", [(False, 0), (True, 7)],
+                         ids=["no_terms", "required_affinity"])
+def test_a_steady_pass_builds_no_xla_executable(affinity_terms, prefilled):
+    """One pod a pass onto a cluster whose bound set grows by one a pass
+    (the interactive cells' traffic): after the first pass nothing that
+    reaches the device has a shape that depends on the bound-pod count.
+    (The cases start from different bound counts, so neither finds the
+    other's executables in the process's jit cache.)"""
+    hostevents.install()
+    store = ObjectStore()
+    nodes = make_nodes(12, seed=5)
+    for n in nodes:
+        store.create("nodes", n)
+    for b in range(prefilled):
+        store.create("pods", _one_pod(
+            f"bound-{b}", affinity_terms,
+            node_name=nodes[b % len(nodes)]["metadata"]["name"]))
+    engine = SchedulerEngine(store, chunk=16)
+
+    def one_pass(i):
+        store.create("pods", _one_pod(f"steady-{i}", affinity_terms))
+        engine.schedule_pending()
+        assert store.get("pods", f"steady-{i}", "default")["spec"].get(
+            "nodeName"), i
+        return TRACER.counter_totals()
+
+    try:
+        warm = one_pass(0)
+        for i in (1, 2, 3):
+            after = one_pass(i)
+    finally:
+        engine.close()
+    grown = {k: v - warm.get(k, 0) for k, v in after.items()
+             if k.startswith("jax_compiles_by_function_total")
+             and v != warm.get(k, 0)}
+    assert not grown, grown
+    assert after.get(BACKEND_COMPILES, 0) == warm.get(BACKEND_COMPILES, 0)
