@@ -146,6 +146,9 @@ class ObjectStore:
         # KSS_TPU_COLUMNAR=0 pins the pure dict baseline.
         self._columnar = env_bool("KSS_TPU_COLUMNAR", True)
         self._banks: dict = {}
+        # resources that may still hold unfilled LazyManifest rows: only
+        # load_columnar makes them, and one whole-resource fill ends them
+        self._lazy_resources: set[str] = set()
         if self._columnar:
             for resource, factory in _COLUMNAR_BANKS.items():
                 bank = factory()
@@ -228,9 +231,12 @@ class ObjectStore:
                 key = (f"{namespace or 'default'}/{name}"
                        if namespaced else name)
                 LazyManifest.ensure(objs.get(key))
-            else:
+            elif res in self._lazy_resources:
+                # not a walk over every stored object where none is lazy:
+                # the watch stream asks this four times a second
                 with self._lock:
                     vals = list(objs.values())
+                    self._lazy_resources.discard(res)
                 for obj in vals:
                     LazyManifest.ensure(obj)
 
@@ -295,6 +301,12 @@ class ObjectStore:
         except KeyError:
             return None  # bank coverage hole: dict listing only
 
+    def columnar_bank(self, resource: str):
+        """The bank behind `resource` (what a listing's `.columns.bank`
+        is), or None: for readers that gather rows by uid and need no
+        listing."""
+        return self._banks.get(resource)
+
     def load_columnar(self, resource: str, bank) -> int:
         """Bulk-attach a generator-built bank (make_nodes_columnar /
         make_pods_columnar) as `resource`'s population: rows become LAZY
@@ -334,6 +346,7 @@ class ObjectStore:
             bank.created[:n] = [ts] * n
             bank.uid_factory = _new_uid
             self._banks[resource] = bank
+            self._lazy_resources.add(resource)
             objs = self._objects[resource]
             events = []
             for key, row in bank.row_of.items():

@@ -503,6 +503,7 @@ class SchedulerEngine:
         # (note_arrival) and taken by the first wave that takes the pod
         self._arrivals: dict[tuple[str, str], float] = {}
         self._pending_idx = None
+        self._bound_carry = None
         self.result_store = result_store or ResultStore()
         self.reflector = reflector or StoreReflector(store)
         if RESULT_STORE_KEY not in self.reflector.result_stores:
@@ -719,10 +720,31 @@ class SchedulerEngine:
         if self._pending_idx is not None:
             self._pending_idx.close()
             self._pending_idx = None
+        if self._bound_carry is not None:
+            self._bound_carry.close()
+            self._bound_carry = None
         pool = getattr(self, "_reflect_pool", None)
         if pool is not None:
             pool.shutdown(wait=True)
             self._reflect_pool = None
+
+    def _bound_pod_carry(self):
+        """Lazily built BoundCarry fed by the store's pod watch, or None
+        when the store has no atomic list_and_watch surface."""
+        carry = self._bound_carry
+        if carry is None and hasattr(self.store, "list_and_watch"):
+            from ..state.boundcarry import BoundCarry, BoundFeed
+
+            carry = self._bound_carry = BoundCarry(BoundFeed(self.store))
+        return carry
+
+    def _pod_bank(self, pods_all):
+        """The columnar pod bank behind the store, from the listing when
+        the pass made one."""
+        if pods_all is not None:
+            return getattr(pods_all, "columns", None)
+        bank_of = getattr(self.store, "columnar_bank", None)
+        return bank_of("pods") if bank_of is not None else None
 
     def _pending_index(self):
         """Lazily built PendingPodIndex, or None when the store has no
@@ -1182,20 +1204,29 @@ class SchedulerEngine:
                 return 0, None
             nodes = self._list_shared("nodes")
             self._wave_node_count = len(nodes)
-            pods_all = self._list_shared("pods")
             self._gang_wave = None
             gp = self._gang_plugin()
             gang_dir = None
+            # the bound pods: carried from pass to pass and brought up to
+            # date from the store's watch (state/boundcarry.py), so that a
+            # pass does not list every pod.  Listed as before where gangs
+            # need the listing, and where the store has no watch to feed a
+            # carry (the remote HTTP client)
+            carry = self._bound_pod_carry()
+            pods_all = bound = None
+            if gp is not None or carry is None or self.gang_parked:
+                pods_all = self._list_shared("pods")
             if gp is not None:
                 pending, gang_dir = self._gang_prescreen(pending, gp, pods_all,
                                                          nodes)
                 if not pending:
                     return 0, None
-            bound = [
-                (p, p["spec"]["nodeName"]) for p in pods_all
-                if (p.get("spec") or {}).get("nodeName")
-            ]
-            if self.gang_parked:
+            if carry is None or self.gang_parked:
+                carry = None
+                bound = [
+                    (p, p["spec"]["nodeName"]) for p in pods_all
+                    if (p.get("spec") or {}).get("nodeName")
+                ]
                 # parked gang members keep their speculative assignments as
                 # assumed binds: their resources stay reserved while the
                 # gang waits for quorum (docs/gang-scheduling.md)
@@ -1214,11 +1245,12 @@ class SchedulerEngine:
 
             cw = compile_workload(
                 nodes, pending, self.plugin_config, bound_pods=bound,
+                bound_carry=carry,
                 volumes=volumes, reuse=getattr(self, "_last_cw", None),
                 namespaces=self._list_shared("namespaces"),
-                # columnar pod view (when the store lists columnar):
-                # request rows gather from pre-parsed bank columns
-                pod_columns=getattr(pods_all, "columns", None),
+                # columnar pod bank (when the store keeps one): request
+                # rows gather from its pre-parsed columns
+                pod_columns=self._pod_bank(pods_all),
             )
             self._last_cw = NodeTableReuse(cw)
         if self._needs_host_path():

@@ -67,6 +67,10 @@ CODE_AFFINITY, CODE_ANTI, CODE_EXISTING = 1, 2, 3
 
 DEFAULT_HARD_POD_AFFINITY_WEIGHT = 1
 
+# the four kinds of term a pod carries: (name, spec.affinity field, preferred)
+KINDS = (("req_aff", "podAffinity", False), ("req_anti", "podAntiAffinity", False),
+         ("pref_aff", "podAffinity", True), ("pref_anti", "podAntiAffinity", True))
+
 
 class InterPodStatic(NamedTuple):
     dom_idx: jnp.ndarray     # [T, N] int32 (-1: node lacks term's key)
@@ -166,23 +170,35 @@ def effective_terms(pod: dict, field: str, preferred: bool,
     return out
 
 
-def build(table: NodeTable, pods: list[dict],
-          bound_pods: list[tuple[dict, str]],
+def term_key(term: dict) -> tuple:
+    """The identity a term is interned under, over what effective_terms
+    made of it: (topologyKey, selector, namespaces)."""
+    return (term.get("topologyKey", ""),
+            json.dumps(term.get("labelSelector"), sort_keys=True),
+            tuple(term.get("namespaces") or ()))
+
+
+def build(table: NodeTable, pods: list[dict], bound,
           hard_weight: int = DEFAULT_HARD_POD_AFFINITY_WEIGHT,
           namespaces: list[dict] | None = None):
     """-> (InterPodStatic, InterPodXS of the queue pods, InterPodCarry
     primed with bound pods).
 
-    The term table is interned over queue + bound pods together, queue
-    first, so the bound pods' terms (which matter for the symmetric
-    existing-pod checks) share the queue's term ids.  The per-pod arrays
-    stay in numpy until they are cut to the queue rows: the bound rows are
-    folded into the carry here and never reach the device, so no shape
-    that does depends on how many pods are bound."""
+    bound: the bound pods as a state/boundcarry.BoundCarry placed on this
+    table, or a (pod, node name) list (a throw-away carry is made of it).
+    The term table is interned over the queue pods in order, then over the
+    terms only bound pods carry (which matter for the symmetric
+    existing-pod checks), sorted: an order no bound pod's position can
+    move.  Nothing of a bound pod but the carry's per-node sums is read
+    here, and nothing of them reaches the device, so no shape that does
+    depends on how many pods are bound."""
+    if isinstance(bound, list):
+        from ..state.boundcarry import carry_of_list
+
+        bound = carry_of_list(bound, namespaces)
+        bound.place(table.names)
     labels = table.labels
     n, p = table.n, len(pods)
-    pods = pods + [bp for bp, _ in bound_pods]   # rows p.. are the bound pods
-    rows = len(pods)
 
     # --- unique term table ----------------------------------------------
     terms: dict[tuple, int] = {}
@@ -191,28 +207,25 @@ def build(table: NodeTable, pods: list[dict],
     def intern_term(term: dict) -> int:
         # effective_terms already resolved the namespace set and merged
         # matchLabelKeys into the selector
-        nss = tuple(term.get("namespaces") or ())
-        sel = term.get("labelSelector")
-        tk = (term.get("topologyKey", ""), json.dumps(sel, sort_keys=True), nss)
+        tk = term_key(term)
         if tk not in terms:
             terms[tk] = len(term_list)
-            term_list.append((term.get("topologyKey", ""), sel, nss))
+            term_list.append((tk[0], term.get("labelSelector"), tk[2]))
         return terms[tk]
 
     per_pod: list[dict[str, list[tuple[int, int]]]] = []
     for pod in pods:
         entry = {}
-        for kind, field, preferred in (
-            ("req_aff", "podAffinity", False),
-            ("req_anti", "podAntiAffinity", False),
-            ("pref_aff", "podAffinity", True),
-            ("pref_anti", "podAntiAffinity", True),
-        ):
+        for kind, field, preferred in KINDS:
             entry[kind] = [
                 (intern_term(t), w)
                 for t, w in effective_terms(pod, field, preferred, namespaces)
             ]
         per_pod.append(entry)
+    bound_terms = bound.own_terms()
+    for tk in sorted(tk for tk in bound_terms if tk not in terms):
+        terms[tk] = len(term_list)
+        term_list.append(bound_terms[tk])
 
     t_count = max(len(term_list), 1)
 
@@ -227,12 +240,12 @@ def build(table: NodeTable, pods: list[dict],
     d_max = max(int(dom_idx.max()) + 1, 1)
 
     # --- pod x term matches + per-pod term weights -----------------------
-    t_matches = np.zeros((rows, t_count), dtype=bool)
-    h_req_aff = np.zeros((rows, t_count), dtype=np.int32)
-    h_req_anti = np.zeros((rows, t_count), dtype=np.int32)
-    h_pref_aff_w = np.zeros((rows, t_count), dtype=np.int64)
-    h_pref_anti_w = np.zeros((rows, t_count), dtype=np.int64)
-    self_ok = np.zeros(rows, dtype=bool)
+    t_matches = np.zeros((p, t_count), dtype=bool)
+    h_req_aff = np.zeros((p, t_count), dtype=np.int32)
+    h_req_anti = np.zeros((p, t_count), dtype=np.int32)
+    h_pref_aff_w = np.zeros((p, t_count), dtype=np.int64)
+    h_pref_anti_w = np.zeros((p, t_count), dtype=np.int64)
+    self_ok = np.zeros(p, dtype=bool)
     for i, pod in enumerate(pods):
         pod_ns = (pod.get("metadata") or {}).get("namespace") or "default"
         pod_labels = {k: str(v) for k, v in ((pod.get("metadata") or {}).get("labels") or {}).items()}
@@ -251,7 +264,7 @@ def build(table: NodeTable, pods: list[dict],
 
     # PreFilter Skip is coarser than upstream's (module docstring): the
     # bound pods' required anti-affinity terms count too
-    any_workload_anti = bool(h_req_anti.any())
+    any_workload_anti = bool(h_req_anti.any()) or bound.any_required_anti
     filter_skip = np.array(
         [
             not any_workload_anti
@@ -264,38 +277,38 @@ def build(table: NodeTable, pods: list[dict],
 
     static = InterPodStatic(dom_idx=jnp.asarray(dom_idx), hard_weight=jnp.int64(hard_weight))
     xs = InterPodXS(
-        t_matches=jnp.asarray(t_matches[:p]),
-        h_req_aff=jnp.asarray(h_req_aff[:p]),
-        h_req_anti=jnp.asarray(h_req_anti[:p]),
-        h_pref_aff_w=jnp.asarray(h_pref_aff_w[:p]),
-        h_pref_anti_w=jnp.asarray(h_pref_anti_w[:p]),
-        self_ok=jnp.asarray(self_ok[:p]),
+        t_matches=jnp.asarray(t_matches),
+        h_req_aff=jnp.asarray(h_req_aff),
+        h_req_anti=jnp.asarray(h_req_anti),
+        h_pref_aff_w=jnp.asarray(h_pref_aff_w),
+        h_pref_anti_w=jnp.asarray(h_pref_anti_w),
+        self_ok=jnp.asarray(self_ok),
         filter_skip=jnp.asarray(filter_skip),
     )
 
     # --- bound pods -> the carry's per-(term, domain) counts ---------------
-    name_idx = {name: j for j, name in enumerate(table.names)}
-    node_of = np.array([name_idx.get(node_name, -1) for _, node_name in bound_pods],
-                       dtype=np.int64)
-    on_table = np.flatnonzero(node_of >= 0)   # bound to a node of the table
-    dom_of = dom_idx[:, node_of[on_table]]    # [T, B'] each pod's domain per term
-    t_ids, b_ids = np.nonzero(dom_of >= 0)    # the node carries the term's key
-    at = (t_ids, dom_of[t_ids, b_ids])
-    src = (p + on_table[b_ids], t_ids)
+    # per-node sums of the bound pods (matches of the term; multiplicities
+    # and weights of the pods carrying it), folded over each term's domains
+    mats = {name: np.zeros((t_count, d_max), dtype=np.int64)
+            for name in ("matched", "have_req_aff", "have_req_anti",
+                         "sym_pref_aff", "sym_pref_anti")}
+    for tk, t_id in terms.items():
+        keyed = np.flatnonzero(dom_idx[t_id] >= 0)   # nodes with the term's key
+        dom = dom_idx[t_id, keyed]
 
-    def fold(per_pod_term: np.ndarray) -> np.ndarray:
-        mat = np.zeros((t_count, d_max), dtype=np.int64)
-        np.add.at(mat, at, per_pod_term[src])
-        return mat
+        def fold(per_node: np.ndarray) -> np.ndarray:
+            # float64 weights hold these sums exactly (< 2**53)
+            return np.bincount(dom, weights=per_node[keyed],
+                               minlength=d_max).astype(np.int64)
 
-    carry = assemble_carry(dom_idx, {
-        "matched": fold(t_matches),
-        "have_req_anti": fold(h_req_anti),
-        "have_req_aff": fold(h_req_aff),
-        "sym_pref_aff": fold(h_pref_aff_w),
-        "sym_pref_anti": fold(h_pref_anti_w),
-    })
-    return static, xs, carry
+        mats["matched"][t_id] = fold(
+            bound.match_counts(tk[2], term_list[t_id][1]))
+        own = bound.own_sums(tk)
+        if own is not None:
+            for name, row in zip(("have_req_aff", "have_req_anti",
+                                  "sym_pref_aff", "sym_pref_anti"), own):
+                mats[name][t_id] = fold(row)
+    return static, xs, assemble_carry(dom_idx, mats)
 
 
 def assemble_carry(dom: np.ndarray, dom_mats: dict) -> InterPodCarry:

@@ -42,6 +42,7 @@ from ..services.resourcewatcher import ResourceWatcherService
 from ..services.snapshot import SnapshotService
 from ..services.syncer import SyncerService
 from ..store.reflector import StoreReflector
+from ..utils.heap import settle_heap
 
 
 class SchedulingLoop:
@@ -63,6 +64,7 @@ class SchedulingLoop:
         # engine exceptions, but a silently wedged loop is unobservable;
         # /readyz surfaces this and scheduling_loop_crashes_total counts
         self.last_crash: dict | None = None
+        self._heap_settled = False
 
     def start(self):
         self._q = self.store.watch("pods")
@@ -111,7 +113,13 @@ class SchedulingLoop:
 
     def _pass(self):
         try:
-            self.engine.schedule_pending()
+            n_bound = self.engine.schedule_pending()
+            if n_bound and not self._heap_settled:
+                # the session's first pass built the rows of every bound
+                # pod and traced its scan: all of it is kept, none of it
+                # garbage (utils/heap.py)
+                self._heap_settled = True
+                settle_heap()
         except Exception as e:  # keep the loop alive like a crashed-and-restarted pod
             tb = traceback.format_exc()
             self.last_crash = {

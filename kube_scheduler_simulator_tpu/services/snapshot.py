@@ -24,6 +24,7 @@ from dataclasses import dataclass
 
 from ..cluster.store import AlreadyExists, ApiError, ObjectStore
 from ..utils.errgroup import SemaphoredErrGroup
+from ..utils.heap import settle_heap
 
 # JSON field -> store resource, in the apply order of the reference's Load
 _FIELDS = [
@@ -174,3 +175,6 @@ class SnapshotService:
                         continue
                     eg.go(apply, resource, obj)
             eg.wait()
+        # what was loaded stays for the session's life: keep the full
+        # collections of later passes from walking it (utils/heap.py)
+        settle_heap()

@@ -32,7 +32,8 @@ from .nodes import (
     patch_node_table,
     patch_node_table_columnar,
 )
-from .resources import ResourceSchema, pod_resource_request
+from .boundcarry import BoundCarry, carry_of_list, pod_key, pod_request_rows
+from .resources import ResourceSchema
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
 from .volumes import build_volume_table
@@ -66,11 +67,6 @@ class CompiledWorkload:
         return self.node_table.n
 
 
-def _pod_key(pod: dict) -> str:
-    meta = pod.get("metadata") or {}
-    return f"{meta.get('namespace') or 'default'}/{meta.get('name', '')}"
-
-
 class NodeTableReuse:
     """Slim handle for compile_workload(reuse=...): holds ONLY the node
     table + schema (what the reuse path reads), so callers caching it
@@ -93,12 +89,17 @@ def compile_workload(
     reuse: "CompiledWorkload | NodeTableReuse | None" = None,
     namespaces: list[dict] | None = None,
     pod_columns=None,
+    bound_carry: BoundCarry | None = None,
 ) -> CompiledWorkload:
     """Compile (nodes, queue pods, already-bound pods) into device tensors.
 
     bound_pods: (pod manifest, node name) pairs folded into the initial
     carry; they also contribute to topology/affinity counts, like the
     existing cluster pods the reference scheduler sees via informers.
+    bound_carry: instead of bound_pods, the bound pods' rows and per-node
+    aggregates kept from pass to pass (state/boundcarry.py); it is brought
+    up to date here from what its store bound, changed or deleted since.
+    A list is made into a throw-away carry: the tensors are the same.
     volumes: optional {"pvcs": [...], "pvs": [...], "storageclasses": [...],
     "csinodes": [...]} manifest lists backing the volume plugin family.
     reuse: a prior wave's workload — its NodeTable (the expensive per-node
@@ -112,8 +113,12 @@ def compile_workload(
     pre-parsed columns by uid instead of re-parsed per wave.
     """
     config = config or reg.PluginSetConfig()
-    bound_pods = bound_pods or []
     volumes = volumes or {}
+    with TRACER.span("cw_bound_delta"):
+        if bound_carry is None:
+            bound_carry = carry_of_list(bound_pods or [], namespaces)
+        else:
+            bound_carry.pull(namespaces)
     # one child span per phase (docs/metrics.md span tree); a name each,
     # because span aggregates are by name.  BUILD_SPAN_PLUGINS lists the
     # plugins that get a cw_build_<Plugin> span
@@ -125,11 +130,11 @@ def compile_workload(
         cols = getattr(nodes, "columns", None)
         if cols is not None:
             schema = ResourceSchema.discover_columnar(
-                pods + [bp for bp, _ in bound_pods], cols)
+                pods, cols, bound_carry.extended_names())
             node_key = cols.identity()
         else:
             schema = ResourceSchema.discover(
-                pods + [bp for bp, _ in bound_pods], nodes)
+                pods, nodes, bound_carry.extended_names())
             node_key = tuple(
                 ((n.get("metadata") or {}).get("name", ""),
                  (n.get("metadata") or {}).get("resourceVersion", ""))
@@ -162,6 +167,9 @@ def compile_workload(
                      if cols is not None else build_node_table(nodes, schema))
             TRACER.count("node_table_builds_total")
 
+    with TRACER.span("cw_bound_delta"):
+        bound_carry.place(table.names)
+
     statics: dict[str, Any] = {}
     xs: dict[str, Any] = {}
     init_carry: dict[str, Any] = {}
@@ -170,23 +178,13 @@ def compile_workload(
     p = len(pods)
     enabled = set(config.active_plugins())
     with TRACER.span("cw_core"):
-        requests, nonzero = _pod_requests(pods, schema, pod_columns)
+        requests, nonzero = pod_request_rows(pods, schema, pod_columns)
 
         # core resource carry, primed with bound pods
-        name_idx = {name: j for j, name in enumerate(table.names)}
-        req0 = table.initial_requested.copy()
-        nz0 = table.initial_nonzero.copy()
-        np0 = table.initial_num_pods.copy()
-        if bound_pods:
-            b_req, b_nz = _pod_requests(
-                [bp for bp, _ in bound_pods], schema, pod_columns)
-            for bi, (_, node_name) in enumerate(bound_pods):
-                j = name_idx.get(node_name)
-                if j is None:
-                    continue
-                req0[j] += b_req[bi]
-                nz0[j] += b_nz[bi]
-                np0[j] += 1
+        b_req, b_nz, b_np = bound_carry.core_sums(schema, pod_columns)
+        req0 = table.initial_requested + b_req
+        nz0 = table.initial_nonzero + b_nz
+        np0 = table.initial_num_pods + b_np
 
         # Fit static/xs double as the core resource tensors even when the
         # Fit plugin itself is disabled (bind updates always need pod
@@ -213,7 +211,7 @@ def compile_workload(
             xs["NodeAffinity"] = x
     if "NodePorts" in enabled:
         with TRACER.span("cw_build_NodePorts"):
-            st, x, carry = ports.build(table, pods, bound_pods)
+            st, x, carry = ports.build(table, pods, bound_carry.port_rows())
             statics["NodePorts"] = st
             xs["NodePorts"] = x
             init_carry["NodePorts"] = carry
@@ -236,7 +234,7 @@ def compile_workload(
             st, x, counts_dom = topologyspread.build(table, pods)
             statics["PodTopologySpread"] = st
             xs["PodTopologySpread"] = x
-            _prime_spread_counts(counts_dom, st, pods, bound_pods, name_idx)
+            _prime_spread_counts(counts_dom, st, pods, bound_carry)
             init_carry["PodTopologySpread"] = \
                 topologyspread.assemble_counts(st, counts_dom)
     if any(name in enabled for name in VOLUME_PLUGINS):
@@ -250,10 +248,12 @@ def compile_workload(
         # by the plugin whose PreFilter reports them; the earliest enabled
         # prefilter plugin in DEFAULT_ORDER wins at decode time
         rejects: dict[str, list[str | None]] = {}
+        # the bound pods with volumes: none of the family reads another
+        bound_vols = bound_carry.volume_rows()
         if "VolumeRestrictions" in enabled:
             with TRACER.span("cw_build_VolumeRestrictions"):
                 st, x, carry = volumerestrictions.build(vt, table, pods,
-                                                        bound_pods)
+                                                        bound_vols)
                 statics["VolumeRestrictions"] = st
                 xs["VolumeRestrictions"] = x
                 init_carry["VolumeRestrictions"] = carry
@@ -265,14 +265,14 @@ def compile_workload(
         if "NodeVolumeLimits" in enabled:
             with TRACER.span("cw_build_NodeVolumeLimits"):
                 st, x, carry = nodevolumelimits.build(vt, table, pods,
-                                                      bound_pods)
+                                                      bound_vols)
                 statics["NodeVolumeLimits"] = st
                 xs["NodeVolumeLimits"] = x
                 init_carry["NodeVolumeLimits"] = carry
         if "VolumeBinding" in enabled:
             with TRACER.span("cw_build_VolumeBinding"):
                 st, x, carry, vb_rejects = volumebinding.build(
-                    vt, table, pods, bound_pods)
+                    vt, table, pods, bound_vols)
                 statics["VolumeBinding"] = st
                 xs["VolumeBinding"] = x
                 init_carry["VolumeBinding"] = carry
@@ -304,7 +304,7 @@ def compile_workload(
     if "InterPodAffinity" in enabled:
         with TRACER.span("cw_build_InterPodAffinity"):
             st, x, carry = interpod.build(
-                table, pods, bound_pods,
+                table, pods, bound_carry,
                 hard_weight=int((config.args.get("InterPodAffinity") or {})
                                 .get("hardPodAffinityWeight")
                                 or interpod.DEFAULT_HARD_POD_AFFINITY_WEIGHT),
@@ -319,7 +319,7 @@ def compile_workload(
             schema=schema,
             node_table=table,
             pods=pods,
-            pod_keys=[_pod_key(pod) for pod in pods],
+            pod_keys=[pod_key(pod) for pod in pods],
             config=config,
             statics=statics,
             xs=xs,
@@ -374,44 +374,6 @@ def _node_delta(old_key, node_key, cols):
     return np.asarray(changed, dtype=np.int64) if changed else None
 
 
-def _pod_requests(pods: list[dict], schema: ResourceSchema, pod_columns):
-    """[P, R] requests + [P, 2] nonzero rows.  With a columnar pod view,
-    rows are GATHERED from the bank's pre-parsed request columns by uid
-    (one vectorized fancy-index per schema column); pods the bank can't
-    answer (no uid match, opaque rows) fall back to the per-pod parse."""
-    p = len(pods)
-    requests = np.zeros((p, schema.n), dtype=np.int64)
-    nonzero = np.zeros((p, 2), dtype=np.int64)
-    misses = range(p)
-    if pod_columns is not None and p:
-        bank = pod_columns.bank
-        by_uid = bank.row_by_uid
-        rows = np.full(p, -1, dtype=np.int64)
-        miss = []
-        # wave-SETUP uid->row mapping: dict lookups can't vectorize; the
-        # per-schema-column request gather below is the vectorized part
-        # kss-analyze: allow(pod-loop)
-        for i, pod in enumerate(pods):
-            uid = (pod.get("metadata") or {}).get("uid")
-            row = by_uid.get(uid) if uid else None
-            if row is None or bank.opaque[row] or bank.deleted[row]:
-                miss.append(i)
-            else:
-                rows[i] = row
-        ok = rows >= 0
-        if ok.any():
-            okr = rows[ok]
-            for j, rname in enumerate(schema.columns):
-                col = bank.req.get(rname)
-                if col is not None:
-                    requests[ok, j] = col[okr]
-            nonzero[ok] = bank.nonzero[okr]
-        misses = miss
-    for i in misses:
-        requests[i], nonzero[i] = pod_resource_request(pods[i], schema)
-    return requests, nonzero
-
-
 def _missing_pvc_message(vt, pod: dict) -> str | None:
     """upstream volumerestrictions PreFilter: the PVC lister Get fails."""
     from .volumes import pod_pvc_keys
@@ -422,26 +384,23 @@ def _missing_pvc_message(vt, pod: dict) -> str | None:
     return None
 
 
-def _prime_spread_counts(counts_dom, st, pods, bound_pods, name_idx):
+def _prime_spread_counts(counts_dom, st, pods, bound_carry):
     """Fold already-bound pods into the domain-space match counts (in
-    place; topologyspread.assemble_counts converts to node space after)."""
-    if not bound_pods:
+    place; topologyspread.assemble_counts converts to node space after):
+    per count group, the carry's per-node count of the bound pods its
+    selector matches, summed over each domain's nodes."""
+    if not bound_carry.n:
         return
-    from ..state.selectors import label_selector_matches
-
     dom_idx = np.asarray(st.dom_idx)
-    # group selectors were interned during build; recompute matches for the
-    # bound pods (they are not part of the queue, so not in x.pm)
-    groups = _spread_groups(pods)
-    for bp, node_name in bound_pods:
-        j = name_idx.get(node_name)
-        if j is None:
-            continue
-        ns = (bp.get("metadata") or {}).get("namespace") or "default"
-        labels = {k: str(v) for k, v in ((bp.get("metadata") or {}).get("labels") or {}).items()}
-        for c_id, (gns, _, sel) in enumerate(groups):
-            if gns == ns and label_selector_matches(sel, labels) and dom_idx[c_id, j] >= 0:
-                counts_dom[c_id, dom_idx[c_id, j]] += 1
+    # group selectors were interned during build; the bound pods are not
+    # part of the queue, so not in x.pm
+    for c_id, (gns, _, sel) in enumerate(_spread_groups(pods)):
+        keyed = np.flatnonzero(dom_idx[c_id] >= 0)
+        per_node = bound_carry.match_counts((gns,), sel)
+        # float64 weights hold these counts exactly (< 2**53)
+        counts_dom[c_id] += np.bincount(
+            dom_idx[c_id, keyed], weights=per_node[keyed],
+            minlength=counts_dom.shape[1]).astype(np.int64)
 
 
 def _spread_groups(pods):

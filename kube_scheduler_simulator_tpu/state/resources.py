@@ -54,9 +54,11 @@ class ResourceSchema:
         return self.columns.index(name)
 
     @staticmethod
-    def discover(pods: list[dict], nodes: list[dict]) -> "ResourceSchema":
-        """Collect extended resource names used anywhere in the workload."""
-        ext: set[str] = set()
+    def discover(pods: list[dict], nodes: list[dict],
+                 known=()) -> "ResourceSchema":
+        """Collect extended resource names used anywhere in the workload;
+        `known`: names already collected (the bound pods')."""
+        ext: set[str] = set(known)
 
         def scan_res(res: dict):
             for name in res or {}:
@@ -73,10 +75,11 @@ class ResourceSchema:
         return ResourceSchema(tuple(sorted(ext)))
 
     @staticmethod
-    def discover_columnar(pods: list[dict], node_columns) -> "ResourceSchema":
+    def discover_columnar(pods: list[dict], node_columns,
+                          known=()) -> "ResourceSchema":
         """discover() with the node half answered by the columnar view's
         presence columns (exact per live row) instead of a manifest scan."""
-        pod_side = ResourceSchema.discover(pods, ())
+        pod_side = ResourceSchema.discover(pods, (), known)
         ext = set(pod_side.extended) | node_columns.extended_names()
         return ResourceSchema(tuple(sorted(ext)))
 

@@ -212,6 +212,18 @@ _HELP: dict[str, str] = {
         "(scheduling_waves_total counts empty wake-ups too).",
     "scheduling_pass_pods_total":
         "Pods taken by the waves scheduling_work_passes_total counts.",
+    "bound_rows_built_total":
+        "Bound-pod rows compile_workload built anew (state/boundcarry.py): "
+        "in a steady pass, the pods bound or changed since the last one.",
+    "bound_rows_carried_total":
+        "Bound-pod rows compile_workload took over from the pass before "
+        "as they were.",
+    "bound_carry_rebuilds_total":
+        "Full builds of the bound pods' carry, by reason (first, resync, "
+        "nodes, namespaces, schema; uncarried = a build handed a list).",
+    "bound_pods":
+        "Bound pods the last pass's compile_workload saw (rows built + "
+        "rows carried).",
     "jax_compile_seconds_total":
         "Seconds inside JAX's compile stages (stage=trace|lower|"
         "backend_compile), from jax.monitoring duration events.",

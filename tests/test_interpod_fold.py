@@ -87,24 +87,32 @@ def _workload(seed, n_nodes=9, n_queue=5, n_bound=14, term_share=0.45,
 
 def _loop_reference(nodes, queue, bound):
     """What compile_workload must hand the scan for InterPodAffinity, one
-    scalar update at a time: terms interned over queue then bound pods,
-    one row per pod, the bound rows added at (term, the domain of the
+    scalar update at a time: terms interned over the queue pods, then the
+    bound pods' own in sorted order, one row per pod, the bound rows added at (term, the domain of the
     pod's node) and read back at every node of that domain."""
     pods = queue + [bp for bp, _ in bound]
-    term_ids, term_list, per_pod = {}, [], []
-    for pod in pods:
-        entry = {}
-        for kind, field, preferred in KINDS:
-            entry[kind] = []
-            for t, w in effective_terms(pod, field, preferred, None):
-                key = (t.get("topologyKey", ""),
-                       json.dumps(t.get("labelSelector"), sort_keys=True),
-                       tuple(t.get("namespaces") or ()))
-                if key not in term_ids:
-                    term_ids[key] = len(term_list)
-                    term_list.append(t)
-                entry[kind].append((term_ids[key], w))
-        per_pod.append(entry)
+    term_ids, term_list = {}, []
+
+    def terms_of(pod):
+        return {kind: [((t.get("topologyKey", ""),
+                         json.dumps(t.get("labelSelector"), sort_keys=True),
+                         tuple(t.get("namespaces") or ())), t, w)
+                       for t, w in effective_terms(pod, field, preferred, None)]
+                for kind, field, preferred in KINDS}
+
+    keyed = [terms_of(pod) for pod in pods]
+    # the queue's terms in the order met, then the terms only bound pods
+    # carry, sorted: no bound pod's position moves a term id
+    in_order = [kt for e in keyed[:len(queue)] for kind, _, _ in KINDS
+                for kt in e[kind]]
+    bound_only = sorted((kt for e in keyed[len(queue):] for kind, _, _ in KINDS
+                         for kt in e[kind]), key=lambda kt: kt[0])
+    for key, t, _ in in_order + bound_only:
+        if key not in term_ids:
+            term_ids[key] = len(term_list)
+            term_list.append(t)
+    per_pod = [{kind: [(term_ids[key], w) for key, _, w in e[kind]]
+                for kind, _, _ in KINDS} for e in keyed]
     t_count = max(len(term_list), 1)
     rows = {name: [[0] * t_count for _ in pods]
             for name in ("t_matches", "req_aff", "req_anti", "pref_aff", "pref_anti")}

@@ -95,8 +95,8 @@ def test_every_default_plugin_yields_its_build_span(default_wave):
     assert len(built) >= 10, built  # the default profile enables them all
     for plugin in built:
         assert snap["spans"][f"cw_build_{plugin}"]["count"] == 1, plugin
-    phases = ["cw_schema", "cw_node_table", "cw_core", "cw_volume_table",
-              "cw_finish"] + [f"cw_build_{p}" for p in built]
+    phases = ["cw_bound_delta", "cw_schema", "cw_node_table", "cw_core",
+              "cw_volume_table", "cw_finish"] + [f"cw_build_{p}" for p in built]
     for name in phases:
         assert name in snap["spans"], name
     assert _seconds(snap, phases) >= 0.9 * _seconds(snap, ["compile_workload"])
@@ -222,7 +222,7 @@ def test_profile_holds_the_spans_as_kss_tracemes(tmp_path):
     names = {e.name for plane in pd.planes for ln in plane.lines
              for e in ln.events if e.name.startswith("kss:")}
     assert {"kss:compile_workload", "kss:wave", "kss:cw_core",
-            "kss:scan_dispatch"} <= names, names
+            "kss:cw_bound_delta", "kss:scan_dispatch"} <= names, names
     red = xplane_spans.reduce_spans(pd)
     assert red["kss_events"] >= 10
     by_span = dict(red["idle_by_span"])
@@ -376,10 +376,21 @@ def test_a_steady_pass_builds_no_xla_executable(affinity_terms, prefilled):
 
     try:
         warm = one_pass(0)
+        seen = {e["span_id"] for e in TRACER.events(4096)}
         for i in (1, 2, 3):
             after = one_pass(i)
+        events = [e for e in TRACER.events(4096) if e["span_id"] not in seen]
     finally:
         engine.close()
+    # cw_bound_delta (the carry finds and applies what the last pass
+    # bound: one row) sits under compile_workload in every pass
+    cw_ids = {e["span_id"] for e in events if e["name"] == "compile_workload"}
+    deltas = [e for e in events if e["name"] == "cw_bound_delta"]
+    assert len(cw_ids) == 3 and len(deltas) == 6
+    assert all(e["parent_id"] in cw_ids for e in deltas)
+    assert after["bound_rows_built_total"] - warm["bound_rows_built_total"] == 3
+    assert (after["bound_rows_carried_total"]
+            - warm.get("bound_rows_carried_total", 0)) == 3 * prefilled + 0 + 1 + 2
     grown = {k: v - warm.get(k, 0) for k, v in after.items()
              if k.startswith("jax_compiles_by_function_total")
              and v != warm.get(k, 0)}
