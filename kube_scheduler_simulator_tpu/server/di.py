@@ -22,6 +22,8 @@ session's tracer scope.
 
 from __future__ import annotations
 
+import contextlib
+import math
 import threading
 import time
 import traceback
@@ -45,18 +47,51 @@ from ..store.reflector import StoreReflector
 from ..utils.heap import settle_heap
 
 
+# The batching window of the scheduling loop (docs/how-it-works.md).
+# WINDOW_CAP_S is the longest a pending pod waits for a burst to finish
+# arriving, whoever is still writing.  QUIET_S is how long after the last
+# pending pod's ADDED event, with no workload-submitting request in
+# flight, the burst counts as over: above every gap between ADDED events
+# measured inside one writer's burst on the chip's host (the applier:
+# 0.19 ms a pod, longest of 6,000 gaps 1.5 ms; a script's POSTs one after
+# another: 0.94 ms, longest 1.3 ms; PERF.md section 6, PR 27), and a
+# fiftieth of the ~90 ms pass it saves.
+WINDOW_CAP_S = 0.05
+QUIET_S = 0.002
+
+
 class SchedulingLoop:
     """Watches pod events and runs scheduling waves for pending pods —
     the in-process analogue of the always-running debuggable-scheduler
-    container.  Debounces so a burst of creates compiles as ONE batched
-    tensor workload instead of one compile per pod."""
+    container.  Batches so a burst of creates compiles as ONE tensor
+    workload instead of one compile per pod: the first pending pod's
+    ADDED event opens a window, and the pass starts when the window
+    closes —
+
+      settled  no workload-submitting request of this session is in
+               flight (writer_in_flight(), entered by the HTTP handler
+               around every sheddable POST) and no pending pod has
+               arrived for QUIET_S; or
+      cap      window_cap seconds after the window opened, whatever is
+               still in flight.
+
+    Writers that are not HTTP requests (syncer, replayer, importer,
+    scenarios, cmd/scheduler's remote store) never count as in flight:
+    for them the quiet interval alone batches, and once a burst is under
+    way the running pass is the window of the next.  Each close counts
+    loop_window_closed_total{reason}."""
 
     def __init__(self, store: ObjectStore, engine: SchedulerEngine,
-                 debounce: float = 0.05):
+                 window_cap: float = WINDOW_CAP_S):
         self.store = store
         self.engine = engine
-        self.debounce = debounce
-        self._wake = threading.Event()
+        self.window_cap = window_cap
+        # guards the three fields below; notified on an arrival that
+        # opens a window, on the last writer leaving, and on stop
+        self._cond = threading.Condition()
+        self._wake = False  # an arrival (or kick) no pass has taken yet
+        self._in_flight = 0
+        self._last_arrival = 0.0  # time.monotonic() of the last pending ADDED
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._q = None
@@ -74,13 +109,30 @@ class SchedulingLoop:
 
     def stop(self):
         self._stop.set()
+        with self._cond:
+            self._cond.notify_all()
         if self._q is not None:
             self.store.unwatch("pods", self._q)
             self._q.put(None)
-        self._wake.set()
 
     def kick(self):
-        self._wake.set()
+        with self._cond:
+            self._wake = True
+            self._cond.notify_all()
+
+    @contextlib.contextmanager
+    def writer_in_flight(self):
+        """Held by a request that may still create pending pods: the
+        window stays open (up to its cap) until the last one leaves."""
+        with self._cond:
+            self._in_flight += 1
+        try:
+            yield
+        finally:
+            with self._cond:
+                self._in_flight -= 1
+                if not self._in_flight:
+                    self._cond.notify_all()
 
     def _watch(self):
         while not self._stop.is_set():
@@ -92,7 +144,14 @@ class SchedulingLoop:
                 # where the store hands a pending pod to the loop: the
                 # stamp the wave's queue_wait_* counters measure from
                 self.engine.note_arrival(obj)
-                self._wake.set()
+                with self._cond:
+                    self._last_arrival = time.monotonic()
+                    if not self._wake:
+                        # an open window reads _last_arrival when its
+                        # quiet deadline comes; only the arrival that
+                        # opens one has a sleeper to wake
+                        self._wake = True
+                        self._cond.notify_all()
             elif event_type == DELETED:
                 self.engine.forget_arrival(obj)
 
@@ -100,16 +159,37 @@ class SchedulingLoop:
         # loop_idle / loop_debounce / loop_pass cover this thread end to
         # end: under a profile every instant of it carries a kss: span
         with TRACER.session_scope(getattr(self.engine, "session", None)):
-            while not self._stop.is_set():
-                with TRACER.span("loop_idle"):
-                    self._wake.wait()
-                if self._stop.is_set():
-                    return
-                self._wake.clear()
+            while True:
+                with TRACER.span("loop_idle"), self._cond:
+                    self._cond.wait_for(
+                        lambda: self._wake or self._stop.is_set())
                 with TRACER.span("loop_debounce"):
-                    self._stop.wait(self.debounce)  # batch bursts
+                    reason = self._hold_window()
+                if reason is None:
+                    return
+                TRACER.inc("loop_window_closed_total", reason=reason)
                 with TRACER.span("loop_pass"):
                     self._pass()
+
+    def _hold_window(self) -> str | None:
+        """Sleep until the open window closes and say why ("settled" or
+        "cap"; None: the loop was stopped).  _wake is cleared HERE, at the
+        close, just before the pass lists the pending pods: every arrival
+        up to now is in that list, and one that lands later sets _wake
+        again and gets a pass of its own — cleared any earlier, a burst's
+        later arrivals would buy a second window and an empty pass."""
+        cap_at = time.monotonic() + self.window_cap
+        with self._cond:
+            while not self._stop.is_set():
+                now = time.monotonic()
+                settled_at = (math.inf if self._in_flight
+                              else self._last_arrival + QUIET_S)
+                if now >= min(settled_at, cap_at):
+                    self._wake = False
+                    return "settled" if now >= settled_at else "cap"
+                # woken early only by the last writer leaving or stop
+                self._cond.wait(min(settled_at, cap_at) - now)
+        return None
 
     def _pass(self):
         try:

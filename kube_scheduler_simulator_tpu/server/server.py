@@ -56,6 +56,7 @@ instead of leaving handler threads sleeping into a dead simulation.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import re
 import threading
@@ -261,13 +262,18 @@ def _make_handler(server: SimulatorServer):
                 # response, and is noted for the session so the wave
                 # that drains the submitted work claims it
                 # (framework/engine.py schedule_pending)
+                writer = contextlib.nullcontext()
                 if method == "POST" and self._sheddable(path):
                     tid = (self.headers.get("X-KSS-Trace-Id")
                            or f"t-{uuid.uuid4().hex[:16]}")
                     self.trace_id = tid
                     TRACER.note_session_trace(sess.id, tid)
+                    # ... and hold the session's batching window open
+                    # until this request has created what it will create
+                    # (di.py SchedulingLoop; a 429 or an error leaves too)
+                    writer = self.di.scheduling_loop.writer_in_flight()
                 with TRACER.session_scope(sess.id), \
-                        TRACER.trace_scope(self.trace_id):
+                        TRACER.trace_scope(self.trace_id), writer:
                     # the three requests of served traffic each get a
                     # span, handler entry to last byte written, under
                     # the request's trace id (docs/metrics.md span tree)

@@ -162,12 +162,21 @@ def test_queue_wait_after_a_held_debounce():
     store.create("nodes", make_nodes(1, seed=41)[0])
     engine = SchedulerEngine(
         store, plugin_config=PluginSetConfig(enabled=["NodeResourcesFit"]))
-    loop = SchedulingLoop(store, engine, debounce=0.3)
+    # the cap is far off: what holds the window for 0.3 s is a writer
+    # still in flight, as an import's handler is while it creates pods
+    loop = SchedulingLoop(store, engine, window_cap=30.0)
     TRACER.reset()
     loop.start()
     try:
         pod = make_pods(1, seed=42)[0]
-        store.create("pods", pod)
+        with loop.writer_in_flight():
+            store.create("pods", pod)
+            deadline = time.time() + 60
+            while not any(s["name"] == "loop_debounce"
+                          for s in TRACER.open_spans()):
+                assert time.time() < deadline, "no window opened"
+                time.sleep(0.005)
+            time.sleep(0.3)
         meta = pod["metadata"]
         deadline = time.time() + 60
         while time.time() < deadline:
