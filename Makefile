@@ -8,55 +8,22 @@
 PY ?= python
 
 .PHONY: codec native-asan native-tsan test test-asan test-tsan analyze \
-        bench bench-check bench-gang bench-serve bench-spec bench-fuse \
-        bench-multichip bench-scale bench-soak blackbox-smoke obs-smoke \
-        smoke chip-smoke chip-smoke-rehearsal chaos \
+        bench-soak blackbox-smoke obs-smoke \
+        chip-smoke chip-smoke-rehearsal chaos \
         clean \
-        parity-fullscale parity-fullscale-device multichip-scaling \
-        host-probe
+        parity-fullscale parity-fullscale-device
 
-# measurement artifacts (committed under docs/bench/; see BASELINE.md)
+# full-scale byte-parity of BASELINE configs 4 and 5 against the
+# sequential oracle (PARITY.md "The parity protocol"; ~20 min on the
+# CPU); the verdict lands in chiprun_out/, which git ignores
 parity-fullscale:
-	JAX_PLATFORMS=cpu $(PY) docs/bench/parity_fullscale.py
+	JAX_PLATFORMS=cpu $(PY) tools/parity_fullscale.py
 
-# full-scale byte-parity ON the device backend (round-4 verdict #5);
-# needs a chip (the chip tool: chiprun -- make parity-fullscale-device)
+# the same ON the device backend; needs a chip
+# (the chip tool: chiprun -- make parity-fullscale-device)
 parity-fullscale-device:
-	$(PY) docs/bench/parity_fullscale.py \
-	    docs/bench/r05-parity-fullscale-tpu.json --device
-
-multichip-scaling:
-	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
-	    $(PY) docs/bench/multichip_scaling.py
-
-# CI-enforceable multichip gate: run the 8-virtual-device scaling
-# harness on the DEVICE-RESIDENT replay path (the default) and assert it
-# actually sharded with full byte-parity — skipped=true or a parity
-# mismatch exits nonzero (docs/wave-pipeline.md device-residency stage)
-bench-multichip:
-	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
-	    $(PY) docs/bench/multichip_scaling.py /tmp/bench_multichip.json
-	$(PY) -c "import json; d = json.load(open('/tmp/bench_multichip.json')); \
-	    assert not d.get('skipped'), 'multichip harness skipped: %s' % d.get('skip_reason'); \
-	    assert d.get('all_parity_ok') is True, 'sharded parity failed'; \
-	    assert d.get('result_mode') == 'device_resident', d.get('result_mode'); \
-	    print('bench-multichip: ok=true skipped=false (device-resident path, %d devices)' % d['devices'])"
-
-# CI-enforceable columnar scale gate: the 25k/50k/100k-node curve on the
-# columnar data plane (docs/data-plane.md) — every point parity-pinned
-# against the dict plane, the 100k workload build >=3x over the dict
-# baseline (same-process interleaved A/B), and an unchanged node set
-# must reuse the node table, never rebuild it
-bench-scale:
-	JAX_PLATFORMS=cpu $(PY) docs/bench/multichip_scaling.py --scale \
-	    /tmp/bench_scale.json
-	$(PY) -c "import json; d = json.load(open('/tmp/bench_scale.json')); \
-	    assert d['all_parity_ok'], 'columnar-vs-dict parity failed'; \
-	    assert d['never_rebuilt_on_unchanged_nodes'], 'node table rebuilt on an unchanged node set'; \
-	    assert d['all_delta_patched'], 'bounded node delta did not patch'; \
-	    assert d['scale_100k_build_speedup_vs_dict'] >= 3, 'speedup %.2fx < 3x' % d['scale_100k_build_speedup_vs_dict']; \
-	    print('bench-scale: ok=true all_parity_ok=true (100k: %.1fx build, %.1f cycles/s, %.0fMB RSS)' \
-	        % (d['scale_100k_build_speedup_vs_dict'], d['scale_100k_cycles_per_sec'], d['scale_100k_host_rss_mb']))"
+	$(PY) tools/parity_fullscale.py \
+	    chiprun_out/parity_fullscale_device.json --device
 
 # CI-enforceable autopilot soak gate (docs/autopilot.md): sustained
 # multi-session churn + overload against a live server with the
@@ -77,9 +44,6 @@ bench-soak:
 	    print('bench-soak: ok=true (p99 %.3fs, shed rate %.2f, %d decisions, %d evidence rows ring-checked)' \
 	        % (d['soak_p99_wave_seconds'], d['soak_shed_rate'], \
 	           d['autopilot']['decisions'], d['shed_evidence_checked']))"
-
-host-probe:
-	$(PY) docs/bench/host_page_backing.py
 
 codec:
 	$(PY) -c "from kube_scheduler_simulator_tpu.native import build_codec; print(build_codec())"
@@ -143,90 +107,15 @@ chip-smoke-rehearsal:
 test: analyze blackbox-smoke obs-smoke chip-smoke-rehearsal
 	$(PY) -m pytest tests/ -q -m "not slow"
 
-bench:
-	$(PY) bench.py
-
-# compare the newest BENCH_*.json round against the previous one on the
-# key serving metrics; exits nonzero on >15% regression (docs/metrics.md)
-bench-check:
-	$(PY) docs/bench/bench_check.py
-
-# gang-workload shape (docs/gang-scheduling.md): PodGroup co-scheduling
-# through the vectorized quorum pass, printing the gang_* counters so
-# BENCH rounds can track gang throughput
-bench-gang:
-	$(PY) bench.py --gang
-
-# multi-session serving shape (docs/api.md sessions surface): K>=4
-# concurrent isolated sessions on one device, reporting aggregate + p99
-# per-session cycles/s and the cross-session compile-cache hit rate
-# (asserted >= (K-1)/K: each scan shape compiles once per process)
-bench-serve:
-	$(PY) bench.py --serve | tee /tmp/bench_serve.json
-	$(PY) -c "import json; d = [json.loads(l) for l in open('/tmp/bench_serve.json') if l.startswith('{')][-1]; \
-	    s = d['extra']['serve']; cc = s['compile_cache']; \
-	    assert s['sessions'] >= 4, s['sessions']; \
-	    assert cc['hit_rate'] >= cc['floor'], (cc, 'hit rate under (K-1)/K'); \
-	    print('bench-serve: %d sessions, warm aggregate %.0f cycles/s, p99 %.0f, cache hit rate %.2f (floor %.2f)' \
-	        % (s['sessions'], s['warm']['aggregate_cycles_per_sec'], s['warm']['p99_session_cycles_per_sec'], cc['hit_rate'], cc['floor']))"
-
-# speculative-wave A/B (docs/wave-pipeline.md speculative-wave stage):
-# the default speculative wave vs the KSS_TPU_SPECULATIVE=0 sequential
-# scan, same process, at the 10k x 5k shape — low-contention
-# reserved-slot scenario (measured ~1.5x on an idle 2-core geometry;
-# the gate floors at 1.4x so shared-host noise can't flake it, and
-# bench_check gates the committed trajectory) with accept rate >= 0.9,
-# plus the contention-heavy broad-feasibility variant exercising the
-# scan fallback
-bench-spec:
-	$(PY) bench.py --spec | tee /tmp/bench_spec.json
-	$(PY) -c "import json; d = [json.loads(l) for l in open('/tmp/bench_spec.json') if l.startswith('{')][-1]; \
-	    s = d['extra']['speculative']; low = s['low_contention']; \
-	    assert low['speedup'] >= 1.4, (low, 'speculative speedup under the 1.4x noise floor (measured ~1.5x idle)'); \
-	    assert low['accept_rate'] >= 0.9, (low, 'low-contention accept rate under 0.9'); \
-	    assert s['contended']['fallbacks'] >= 1, (s['contended'], 'contended variant never exercised the scan fallback'); \
-	    print('bench-spec: %.1fx vs scan (%.0f vs %.0f cycles/s), accept rate %.2f over %d rounds; contended: %.2fx, accept %.2f, %d fallback(s)' \
-	        % (low['speedup'], low['speculative_cycles_per_sec'], low['sequential_cycles_per_sec'], low['accept_rate'], low['rounds'], \
-	           s['contended']['speedup'], s['contended']['accept_rate'], s['contended']['fallbacks']))"
-
-# cross-session fused dispatch A/B (docs/wave-pipeline.md fused-dispatch
-# stage): K sessions' speculative rounds stacked into one vmapped device
-# call vs KSS_TPU_FUSE=0 time-sharing, asserting byte-identical
-# per-session bindings/annotations in the same run.  The gate enforces
-# the parity bar and that fused batches actually form (>= 1 fused device
-# call per K) — NOT a speedup floor: on the 2-core CPU geometry the
-# time-shared arm already parallelizes K solo calls across cores, so
-# fusion measures ~0.5x at K=4 / ~0.8x at K=8 (docs/wave-pipeline.md
-# states the mesh-dp projection: on a dp-extent mesh the stacked session
-# axis lays over devices and the fused call IS the parallelism, minus
-# K-1 dispatches).  bench_check tracks the committed trajectory.
-bench-fuse:
-	$(PY) bench.py --fuse | tee /tmp/bench_fuse.json
-	$(PY) -c "import json; d = [json.loads(l) for l in open('/tmp/bench_fuse.json') if l.startswith('{')][-1]; \
-	    allk = d['extra']['fuse']; \
-	    ks = {k: v for k, v in allk.items() if 'parity_byte_identical' in v}; \
-	    skipped = {k: v.get('error') for k, v in allk.items() if k not in ks}; \
-	    assert ks, 'no fuse measurements landed'; \
-	    assert all(v['parity_byte_identical'] for v in ks.values()), (ks, 'fused vs time-shared parity violated'); \
-	    assert all(v['fused_device_calls'] >= 1 for v in ks.values()), (ks, 'no fused batches formed'); \
-	    print('\n'.join('bench-fuse %s: SKIPPED (%s)' % kv for kv in skipped.items())); \
-	    print('\n'.join('bench-fuse k=%s: fused %.0f vs time-shared %.0f aggregate cycles/s (%.2fx), p99 %.0f vs %.0f, %d fused calls, parity OK' \
-	        % (k.lstrip('k'), v['fuse_aggregate_cycles_per_sec'], v['timeshared_aggregate_cycles_per_sec'], v['aggregate_speedup'], \
-	           v['fuse_p99_session_cycles_per_sec'], v['timeshared_p99_session_cycles_per_sec'], v['fused_device_calls']) for k, v in sorted(ks.items())))"
-
 # chaos gate (docs/fault-injection.md): concurrent multi-session waves
 # under seeded fault plans at every seam, asserting completion via
 # retry/degradation, bit-identical annotations vs the fault-free run,
 # gang atomicity, per-session isolation, and no lock-order cycles under
 # the runtime witness.  Deterministic: a failure prints the seed and
 # the exact reproducing command.  Also runs as the slow-marked tier-2
-# suite tests/test_chaos.py, and a quick verdict rides every bench
-# round (extra.chaos; bench-check refuses rounds whose chaos failed).
+# suite tests/test_chaos.py.
 chaos:
 	KSS_TPU_LOCK_WITNESS=1 JAX_PLATFORMS=cpu $(PY) -m tools.chaos --seeds 3
-
-smoke:
-	$(PY) bench.py --smoke
 
 clean:
 	rm -f kube_scheduler_simulator_tpu/native/_annotation_codec.so \

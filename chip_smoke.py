@@ -22,8 +22,8 @@ Phases (any failure is a non-zero exit; nothing is reported as null):
            device-resident results).
   gate     after that child has exited, one process owns the chip and
            replays BASELINE configs 1-5 at --gate-scale against a streamed
-           CPU oracle, every annotation of every pod (bench.py
-           stream_oracle_parity).
+           CPU oracle, every annotation of every pod
+           (reference_impl/parity_gate.py stream_oracle_parity).
   external a simulator child with externalSchedulerEnabled (it must not
            touch the chip), then `python -m ...cmd.scheduler --once` as
            the only process on the chip; every pod ends up bound.
@@ -723,11 +723,12 @@ def child_oracle(spec: dict) -> dict:
 
 
 def child_gate(spec: dict) -> dict:
-    """This process owns the chip; bench.stream_oracle_parity starts the
+    """This process owns the chip; stream_oracle_parity starts the
     oracle as a CPU child of its own."""
     import jax
 
-    import bench
+    from kube_scheduler_simulator_tpu.reference_impl.parity_gate import (
+        stream_oracle_parity)
 
     d0 = jax.devices()[0]
     assert d0.platform == spec["platform"], (
@@ -735,7 +736,7 @@ def child_gate(spec: dict) -> dict:
     out = {"device": {"platform": d0.platform, "kind": d0.device_kind},
            "configs": {}}
     for idx in spec["configs"]:
-        r = bench.stream_oracle_parity(idx, spec["scale"], spec["seed"])
+        r = stream_oracle_parity(idx, spec["scale"], spec["seed"])
         print(f"gate config {idx}: ok={r['ok']} pods={r['pods']} "
               f"replay={r['replay_seconds']}s", file=sys.stderr, flush=True)
         out["configs"][str(idx)] = {

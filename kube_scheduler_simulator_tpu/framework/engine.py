@@ -511,7 +511,7 @@ class SchedulerEngine:
         self.plugin_config = plugin_config or PluginSetConfig()
         self.chunk = chunk
         # lax.scan unroll for replay waves: the step's [N] ops are tiny,
-        # so per-iteration overhead matters (bench.py --unroll default)
+        # so per-iteration overhead matters
         self.unroll = unroll
         # optional jax.sharding.Mesh with a "nodes" axis: every batched
         # replay shards the node axis across it (parallel/mesh.py)
@@ -1284,12 +1284,13 @@ class SchedulerEngine:
         if (os.environ.get("KSS_TPU_SPECULATIVE", "1") != "0"
                 and self.extender_service is None
                 and not self._custom_lifecycle_plugins()):
-            # speculative multi-pod rounds are the DEFAULT wave whenever
-            # the active plugin set admits exact batching — a single
-            # device suffices (a mesh additionally fans the batch over
-            # its "dp" axis; this uses the divisibility-checked mesh).
-            # KSS_TPU_SPECULATIVE=0 pins the sequential scan: the parity
-            # baseline the golden suite diffs against.  The engine's
+            # speculative multi-pod rounds, for profiles that admit exact
+            # batching (the stock default profile does not: it enables
+            # the volume family; docs/wave-pipeline.md has the table) — a
+            # single device suffices (a mesh additionally fans the batch
+            # over its "dp" axis; this uses the divisibility-checked
+            # mesh).  KSS_TPU_SPECULATIVE=0 pins the sequential scan: the
+            # parity baseline the golden suite diffs against.  The engine's
             # vectorized gang plugin is ignored by the eligibility check
             # (its PreFilter ran in the prescreen, admission happens in
             # the quorum pass at commit — it neither filters nor scores
@@ -1418,8 +1419,9 @@ class SchedulerEngine:
                           exclude: set[tuple[str, str]] | None,
                           n_nodes: int, ignore: frozenset = frozenset()
                           ) -> tuple[int, str | None]:
-        """The engine's default wave (docs/wave-pipeline.md
-        speculative-wave stage): vmapped rounds of B queued pods against
+        """The wave for profiles that admit exact batching
+        (docs/wave-pipeline.md speculative-wave stage; the stock default
+        profile is not one of them): vmapped rounds of B queued pods against
         the frozen carry, a conflict oracle accepting the provably
         non-interfering prefix, accepted results streamed to the commit
         worker on the standard chunk grid — so lazy decode, device
@@ -1564,7 +1566,6 @@ class SchedulerEngine:
         try:
             from .replay import plugin_attribution
 
-            t0 = time.perf_counter()
             if att is None:
                 # streaming lazy waves pass the worker-accumulated
                 # tallies instead (ChunkAttribution); everything else
@@ -1605,8 +1606,6 @@ class SchedulerEngine:
                     TRACER.observe(
                         "framework_extension_point_duration_seconds", secs,
                         extension_point=point)
-            TRACER.count("wave_attribution_seconds",
-                         round(time.perf_counter() - t0, 6))
         # kss-analyze: allow(swallowed-exception)
         except Exception:
             pass  # attribution is observability; waves never fail on it

@@ -982,7 +982,7 @@ class _ScanCacheRegistry:
     from — lookups are locked, and a miss REGISTERS an in-flight build
     before releasing the lock: a second session racing the same key
     waits for the winner's callable instead of double-compiling (the
-    compile-once guarantee `make bench-serve` measures as its
+    compile-once guarantee /api/v1/sessions reports as its
     (K-1)/K hit rate).  LRU semantics unchanged: pop-and-reinsert on
     hit, so two shapes alternating at capacity never evict each other's
     still-hot compiles.
@@ -1105,8 +1105,7 @@ _SCAN_CACHE = _ScanCacheRegistry()
 
 def scan_cache_stats() -> dict:
     """Process-level compile-cache stats ({entries, hits, misses,
-    hit_rate}) — the /api/v1/sessions surface and `make bench-serve`
-    report these."""
+    hit_rate}) — the /api/v1/sessions surface reports these."""
     return _SCAN_CACHE.stats()
 
 

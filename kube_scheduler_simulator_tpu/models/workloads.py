@@ -1,9 +1,10 @@
 """Synthetic cluster / pod-queue generators for the BASELINE configs.
 
-BASELINE.md defines five benchmark configs (100x10 ... 10k x 5k) with a
-growing plugin set.  The reference publishes no workload generator (it
-replays recorded real clusters); these generators produce deterministic
-manifests in the same shape KWOK fake clusters use, sized per config.
+PARITY.md ("The parity protocol") lists the five parity workloads
+(100x10 ... 10k x 5k) with a growing plugin set.  The reference
+publishes no workload generator (it replays recorded real clusters);
+these generators produce deterministic manifests in the same shape KWOK
+fake clusters use, sized per config.
 """
 
 from __future__ import annotations
@@ -310,9 +311,9 @@ def make_slot_pinned_workload(
     the Tesserae-style placement shape where each job owns a reserved
     node group (PAPERS.md).  Feasibility is SPARSE (slot_size nodes per
     pod) and pods of different slots never interact, which makes this
-    the low-contention headline scenario for the speculative wave
-    (`make bench-spec`): the conflict oracle accepts near-whole batches,
-    so the wave runs in ~ceil(P/B) device steps.  Scoring stays real:
+    the low-contention scenario for the speculative wave
+    (tests/test_speculative_engine.py): the conflict oracle accepts
+    near-whole batches, so the wave runs in ~ceil(P/B) device steps.  Scoring stays real:
     slot_size > 1 keeps feasible_count above the single-node early-out.
     -> (nodes, pods)."""
     nodes = make_nodes(n_nodes, seed=seed)
@@ -416,7 +417,7 @@ def make_churn_workload(
     (Tesserae's placement-under-churn setting — PAPERS.md): a Poisson
     stream of pod arrivals plus Poisson departures of previously
     arrived pods, bucketed into `ticks` discrete steps.  The traffic
-    source for `make bench-soak` (tools/soak.py) and the first seed of
+    source for tools/soak.py (`make bench-soak`) and the first seed of
     the generator family ROADMAP item 3 calls for.
 
     Fully deterministic for a (seed, shape) pair: one
@@ -477,7 +478,7 @@ def make_churn_workload(
     return nodes, schedule
 
 
-# BASELINE.md benchmark configs 1-5
+# the parity suite's workload catalogue (PARITY.md "The parity protocol")
 BASELINE_CONFIGS = {
     1: dict(pods=100, nodes=10, plugins=["NodeResourcesFit"]),
     2: dict(pods=1000, nodes=500, plugins=["NodeResourcesFit", "NodeResourcesBalancedAllocation"]),

@@ -5,8 +5,7 @@ style of the Go reference (one pod at a time, per-node loops, per-plugin
 calls — SURVEY.md §3.2), sharing nothing with the tensor engine except the
 static selector-matching helpers.  Its annotations must be bit-identical
 to store/decode.py over framework/replay.py — that is the correctness gate
-of BASELINE.md — and its wall-clock is the CPU baseline the benchmark
-compares against.
+of PARITY.md ("The parity protocol"; parity_gate.py streams it).
 
 Semantics sources are the same as the tensor kernels' (upstream v1.32
 plugins; recording shim reference:

@@ -461,7 +461,8 @@ def decode_release_batches(rr, lo: int, hi: int, on_pod=None,
                            batch: int = 64) -> None:
     """Decode pods lo..hi in small compact-chunk-aligned batches,
     releasing each batch's annotations after on_pod(i, ann) — the
-    reflector-style consumer (holds nothing, BASELINE.md): holding a
+    reflector-style consumer (the reference's reflector PATCHes the
+    annotations out and holds nothing, storereflector.go:87-161): holding a
     whole replay chunk's strings before releasing pays ~1.3 GB of
     first-touch page faults at the 5k-node shape, a harness transient
     rather than decoder cost.  Batches never straddle a compact chunk.

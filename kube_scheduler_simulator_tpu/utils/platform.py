@@ -42,10 +42,10 @@ def ensure_malloc_hugepages() -> bool:
 
     The annotation product is tens of GB of live strings at the full
     benchmark shape; with 4 KiB pages the first touch of every page is a
-    fault, and this class of host collapses to ~200 MB/s fault bandwidth
-    past ~8 GB resident (docs/bench/r04-host-page-backing.json).  THP
-    cuts faults ~512x: measured 450 -> 575 engine cycles/s at 10k x 5k
-    on the bench host.  The tunable is only read by glibc at process
+    fault, and the one-core CPU host this was tuned on collapsed to
+    ~200 MB/s fault bandwidth past ~8 GB resident.  THP cuts faults
+    ~512x; what it buys the served path on the chip's host: not
+    measured (ROADMAP C5).  The tunable is only read by glibc at process
     start, hence the re-exec; callers must invoke this FIRST in main(),
     before heavy imports.  Returns False when already active or not
     applicable (non-Linux, THP 'never', KSS_NO_HUGEPAGE_REEXEC=1) — on
@@ -86,8 +86,8 @@ def tune_host_allocator() -> bool:
     The annotation product cycles multi-MB JSON strings; above the default
     mmap threshold (128 KiB) each one is mmap'd and munmap'd, so every
     build page-faults fresh pages — ruinous on hosts whose first-touch
-    bandwidth collapses at high resident set (this bench host: ~10x past
-    ~8 GB, docs/bench/r04-host-page-backing.json).  Raising the thresholds
+    bandwidth collapses at high resident set (the one-core CPU host this
+    was tuned on: ~10x past ~8 GB).  Raising the thresholds
     makes the arena REUSE freed pages: steady-state string churn touches
     already-backed memory and never faults.  For BATCH processes (the
     bench, one-shot replays) only — with trim disabled a long-lived

@@ -283,3 +283,106 @@ def test_columnar_sync_fault_leaves_shim_consistent(pin, monkeypatch):
     row = list(cw_a.node_table.names).index("node-00004")
     cpu_col = list(cw_a.schema.columns).index("cpu")
     assert cw_a.node_table.allocatable[row, cpu_col] == 99000
+
+
+# ------------------------------------------- the compile stage on the plane
+
+
+def _tree_equal(a, b) -> bool:
+    import jax
+
+    la, ta = jax.tree_util.tree_flatten(a)
+    lb, tb = jax.tree_util.tree_flatten(b)
+    return ta == tb and all(
+        np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(la, lb))
+
+
+_WAVE_PLUGINS = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
+                 "TaintToleration"]
+
+
+def test_columnar_compile_and_binds_match_dict_plane(monkeypatch):
+    """The workload compiled from the store's column banks is the one
+    compiled from the same rows as plain dicts, tensor for tensor, and a
+    wave over either plane binds every pod to the same node."""
+    import copy
+
+    from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
+    from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
+    from kube_scheduler_simulator_tpu.state.compile import compile_workload
+
+    cfg = PluginSetConfig(enabled=list(_WAVE_PLUGINS))
+    a = make_store(monkeypatch, True)
+    a.load_columnar("nodes", make_nodes_columnar(48, seed=5,
+                                                 taint_fraction=0.1))
+    a.load_columnar("pods", make_pods_columnar(20, seed=6))
+    nodes, _ = a.list("nodes", copy_objects=False)
+    pods, _ = a.list("pods", copy_objects=False)
+    assert getattr(nodes, "columns", None) is not None
+    dict_nodes = [copy.deepcopy(o) for o in nodes]
+    dict_pods = [copy.deepcopy(o) for o in pods]
+    cw_c = compile_workload(nodes, pods, cfg,
+                            pod_columns=getattr(pods, "columns", None))
+    cw_d = compile_workload(dict_nodes, dict_pods, cfg)
+    assert list(cw_c.node_table.names) == list(cw_d.node_table.names)
+    assert np.array_equal(cw_c.node_table.allocatable,
+                          cw_d.node_table.allocatable)
+    for part in ("statics", "xs", "init_carry"):
+        assert _tree_equal(getattr(cw_c, part), getattr(cw_d, part)), part
+
+    b = make_store(monkeypatch, False)
+    for nd in dict_nodes:
+        b.create("nodes", copy.deepcopy(nd))
+    for p in dict_pods:
+        b.create("pods", copy.deepcopy(p))
+
+    def binds(s):
+        assert SchedulerEngine(s, plugin_config=cfg,
+                               chunk=8).schedule_pending() == len(dict_pods)
+        return {p["metadata"]["name"]: p["spec"]["nodeName"]
+                for p in s.list("pods")[0]}
+
+    assert binds(a) == binds(b)
+
+
+@pytest.mark.parametrize("columnar", [True, False], ids=["columnar", "dict"])
+def test_node_table_reused_then_patched_never_rebuilt(monkeypatch, columnar):
+    """Between waves the node table is built once: a wave over an
+    unchanged node set reuses it, and a wave after a bounded node change
+    patches exactly the changed rows (docs/data-plane.md)."""
+    from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
+    from kube_scheduler_simulator_tpu.models.workloads import make_pods
+    from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
+    from kube_scheduler_simulator_tpu.utils.tracing import TRACER
+
+    s = make_store(monkeypatch, columnar)
+    s.load_columnar("nodes", make_nodes_columnar(48, seed=5))
+    s.load_columnar("pods", make_pods_columnar(8, seed=6))
+    engine = SchedulerEngine(
+        s, plugin_config=PluginSetConfig(enabled=list(_WAVE_PLUGINS)), chunk=8)
+
+    def wave(prefix):
+        """Counter deltas of one wave over 4 new pods."""
+        for i, p in enumerate(make_pods(4, seed=97)):
+            p["metadata"]["name"] = f"{prefix}-{i}"
+            s.create("pods", p)
+        before = dict(TRACER.summary()["counters"])
+        assert engine.schedule_pending() == 4
+        after = TRACER.summary()["counters"]
+        return {k: after.get(k, 0) - before.get(k, 0) for k in (
+            "node_table_builds_total", "node_table_reuse_total",
+            "node_table_delta_patches_total", "node_table_delta_rows_total")}
+
+    assert engine.schedule_pending() == 8      # the one build
+    unchanged = wave("again")
+    assert unchanged["node_table_builds_total"] == 0
+    assert unchanged["node_table_reuse_total"] >= 1
+    touched = 3
+    for i in range(touched):
+        nd = s.get("nodes", f"node-{i:05d}")
+        nd["metadata"].setdefault("labels", {})["kss.io/touched"] = "y"
+        s.update("nodes", nd)
+    patched = wave("after-touch")
+    assert patched["node_table_builds_total"] == 0
+    assert patched["node_table_delta_patches_total"] >= 1
+    assert patched["node_table_delta_rows_total"] == touched

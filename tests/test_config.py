@@ -3,6 +3,9 @@ the feature-exclusivity rule (reference: simulator/config/config.go —
 env overrides per field at :148-159, exclusivity at :94-96, initial
 scheduler config load at :232-257)."""
 
+import re
+from pathlib import Path
+
 import pytest
 import yaml
 
@@ -112,3 +115,47 @@ def test_initial_scheduler_config_loads_yaml(clean_env, tmp_path):
     loaded = cfg.initial_scheduler_config()
     assert loaded["profiles"][0]["schedulerName"] == "my-scheduler"
     assert SimulatorConfiguration().initial_scheduler_config() is None
+
+
+# ---------------------------------------------- the documented surface
+
+_REPO = Path(__file__).resolve().parent.parent
+_ENV_NAME = re.compile(r"KSS_TPU_[A-Z0-9_]+")
+
+
+def _env_names_read():
+    names = set()
+    for src in (_REPO / "kube_scheduler_simulator_tpu").rglob("*.py"):
+        names |= set(_ENV_NAME.findall(src.read_text()))
+    return names
+
+
+def _env_names_documented():
+    doc = (_REPO / "docs" / "environment-variables.md").read_text()
+    return set(_ENV_NAME.findall(doc))
+
+
+@pytest.mark.parametrize("have, want, what", [
+    (_env_names_documented, _env_names_read,
+     "read by the package, missing from docs/environment-variables.md"),
+    (_env_names_read, _env_names_documented,
+     "documented, read nowhere in the package"),
+], ids=["every_name_read_is_documented", "every_name_documented_is_read"])
+def test_kss_tpu_env_names_match_their_document(have, want, what):
+    """An option nobody can find, and a document of an option that is
+    gone, are both repaired in the document."""
+    assert sorted(want() - have()) == [], what
+
+
+def test_every_makefile_target_runs_something_that_exists():
+    text = (_REPO / "Makefile").read_text()
+    ran = set(re.findall(r"\$\(PY\) ([\w/]+\.py)", text))
+    ran |= set(re.findall(r"pytest (tests/\w+\.py)", text))
+    ran |= {mod.replace(".", "/")
+            for mod in re.findall(r"\$\(PY\) -m (tools\.\w+)", text)}
+    assert len(ran) >= 9, ran
+    missing = [r for r in sorted(ran)
+               if not ((_REPO / r).is_file()
+                       or (_REPO / (r + ".py")).is_file()
+                       or (_REPO / r / "__main__.py").is_file())]
+    assert missing == []

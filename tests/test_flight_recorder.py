@@ -119,7 +119,7 @@ def test_exposition_validator_rejects(bad):
 # ------------------------------------------------- engine span tree
 
 
-def _pipelined_wave(n_pods=48, n_nodes=6, chunk=16):
+def _pipelined_wave(n_pods=48, n_nodes=6, chunk=16, pipeline=True):
     TRACER.reset()
     store = ObjectStore()
     for n in make_nodes(n_nodes, seed=11):
@@ -128,13 +128,13 @@ def _pipelined_wave(n_pods=48, n_nodes=6, chunk=16):
         store.create("pods", p)
     # no PostFilter in the lineup so the wave takes the streaming-commit
     # path (_can_stream_commit; the default set's preemption forces the
-    # sequential post-pass)
+    # sequential post-pass, as pipeline=False does here)
     cfg = PluginSetConfig(enabled=[
         "NodeResourcesFit", "NodeResourcesBalancedAllocation",
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=chunk,
-                             pipeline_commit=True)
-    assert engine._can_stream_commit()
+                             pipeline_commit=pipeline)
+    assert engine._can_stream_commit() is pipeline
     bound = engine.schedule_pending()
     assert bound > 0
     return TRACER.events(limit=1000)
@@ -155,6 +155,62 @@ def test_span_tree_parents_across_commit_worker_thread():
     # the commit tail parents implicitly on the engine thread
     tails = [e for e in evs if e["name"] == "commit_and_reflect"]
     assert tails and tails[-1]["tid"] == replay_ev["tid"]
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["streamed", "post_pass"])
+def test_wave_counters_by_commit_path(pipeline):
+    """What a wave reports of its commit: the streaming committer counts
+    itself, its overlap and every object it wrote through a batch; the
+    sequential post-pass moves no commit_stream_* counter.  Both
+    attribute the wave per plugin into a snapshot that serialises."""
+    n_pods = 24
+    _pipelined_wave(n_pods=n_pods, chunk=8, pipeline=pipeline)
+    snap = TRACER.snapshot()
+    assert {"replay_and_decode_stream", "commit_and_reflect"} \
+        <= set(snap["spans"])
+    counters = snap["counters"]
+    if pipeline:
+        assert counters["commit_stream_waves_total"] == 1
+        assert "commit_stream_overlap_seconds" in counters
+        # a bind per pod at the least; the reflects defer with the decode
+        assert counters["store_batch_writes_total"] >= n_pods
+    else:
+        assert not [k for k in counters if k.startswith("commit_stream_")]
+    evaluated = snap["labeled_counters"]["plugin_pods_nodes_evaluated_total"]
+    assert {s["labels"]["extension_point"] for s in evaluated} \
+        >= {"filter", "score"}
+    assert "scheduling_attempt_duration_seconds" in snap["histograms"]
+    json.dumps(snap)
+
+
+def test_cold_read_pays_one_decode_and_fetch_for_its_chunk():
+    """Why a cold read is slower than a warm one: the first read of a
+    lazy wave's pod fetches and decodes its whole chunk, once; a
+    chunk-mate's read right after finds the result memoized and opens
+    neither span."""
+    TRACER.reset()
+    store = ObjectStore()
+    for n in make_nodes(6, seed=11):
+        store.create("nodes", n)
+    pods = make_pods(16, seed=12)
+    for p in pods:
+        store.create("pods", p)
+    engine = SchedulerEngine(store, chunk=16)
+    assert engine.schedule_pending() == len(pods)
+    assert engine.reflector._lazy.pending_count() == len(pods)
+
+    def spans():
+        agg = TRACER.summary()["spans"]
+        return (agg.get("decode_lazy", {}).get("count", 0),
+                agg.get("d2h_fetch", {}).get("count", 0))
+
+    assert spans() == (0, 0)
+    # one chunk holds the whole wave, so any two pods are chunk-mates
+    for reads, meta in enumerate((p["metadata"] for p in pods[:2]), start=1):
+        got = store.get("pods", meta["name"], meta.get("namespace"))
+        assert ann.SELECTED_NODE in got["metadata"]["annotations"]
+        assert spans() == (1, 1), f"after read {reads}"
 
 
 def test_perfetto_export_schema_and_pipeline_overlap():

@@ -58,21 +58,32 @@ def test_sharded_dp_mesh_matches_unsharded():
     assert _scan_selections(cw, step) == base_sel
 
 
-def test_sharded_replay_annotations_byte_identical():
-    """The PRODUCTION path under a mesh: replay(cw, mesh=...) over a whole
-    queue (chunked lax.scan with the node axis sharded over 8 virtual
-    devices) must reproduce byte-identical annotations (VERDICT round-1
-    next-step #3: mesh integrated beyond the dryrun)."""
+@pytest.fixture(scope="module")
+def unsharded_replay():
+    """One queue and its unsharded annotations, shared by the shard cases."""
     from kube_scheduler_simulator_tpu.store.decode import decode_pod_result
 
     nodes, pods, cfg = _workload(n_nodes=24, n_pods=10, seed=83)
     base = replay(compile_workload(nodes, pods, cfg), chunk=4)
-    mesh = make_mesh(8, dp=1)
-    sharded = replay(compile_workload(nodes, pods, cfg), chunk=4, mesh=mesh)
-    assert [int(s) for s in sharded.selected] == [int(s) for s in base.selected]
-    for i in range(len(pods)):
-        da, db = decode_pod_result(sharded, i), decode_pod_result(base, i)
-        assert da == db, f"pod {i} annotations diverge under sharding"
+    return (nodes, pods, cfg), [int(s) for s in base.selected], \
+        [decode_pod_result(base, i) for i in range(len(pods))]
+
+
+@pytest.mark.parametrize("shards", [2, 4, 8])
+def test_sharded_replay_annotations_byte_identical(shards, unsharded_replay):
+    """The PRODUCTION path under a mesh: replay(cw, mesh=...) over a whole
+    queue (chunked lax.scan with the node axis sharded over 2, 4 and 8
+    virtual devices) must reproduce byte-identical annotations (VERDICT
+    round-1 next-step #3: mesh integrated beyond the dryrun)."""
+    from kube_scheduler_simulator_tpu.store.decode import decode_pod_result
+
+    workload, base_selected, base_annotations = unsharded_replay
+    sharded = replay(compile_workload(*workload), chunk=4,
+                     mesh=make_mesh(shards, dp=1))
+    assert [int(s) for s in sharded.selected] == base_selected
+    for i, want in enumerate(base_annotations):
+        assert decode_pod_result(sharded, i) == want, \
+            f"pod {i} annotations diverge under sharding"
 
 
 def test_engine_schedules_with_mesh():

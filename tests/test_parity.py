@@ -1,6 +1,6 @@
 """CPU-sequential vs TPU-tensor bit-parity over the BASELINE configs.
 
-The correctness gate of BASELINE.md: every result annotation — most
+The correctness gate of PARITY.md ("The parity protocol"): every result annotation — most
 importantly finalscore-result — must be byte-identical between the scalar
 sequential reference (reference_impl/sequential.py) and the scan engine
 (framework/replay.py + store/decode.py), on every pod of the queue.

@@ -1,0 +1,230 @@
+"""Which wave path serves which profile: the table at the head of
+docs/wave-pipeline.md, one case per row, read off what a wave emits.
+
+The engine chooses scan (speculative rounds / sequential scan / host
+loop), commit (streamed by the chunk worker / sequential post-pass /
+host loop) and result residency (device-resident lazy / host-resident
+lazy / decoded in the wave) from what it observes of the profile, the
+extenders, the reflector and the residency ladder
+(`SchedulerEngine._profile_wave_attempt`, `_speculative_wave`).  Each
+case builds a small engine with one such observation, runs one wave and
+asserts the path from the spans and counters the program already emits.
+A refactor of the shells (ROADMAP C2) must keep every row."""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, field
+from typing import Callable
+
+import pytest
+
+from kube_scheduler_simulator_tpu.cluster.store import ObjectStore
+from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
+from kube_scheduler_simulator_tpu.models.workloads import (
+    make_gang_workload, make_nodes, make_pods)
+from kube_scheduler_simulator_tpu.plugins.coscheduling import (
+    Coscheduling, ensure_podgroup_resource)
+from kube_scheduler_simulator_tpu.plugins.custom import CustomPlugin
+from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
+from kube_scheduler_simulator_tpu.scheduler.debuggable import PluginExtender
+from kube_scheduler_simulator_tpu.scheduler.extender import ExtenderService
+from kube_scheduler_simulator_tpu.utils.tracing import TRACER
+
+# an in-tree set that admits exact batching (speculation_ok) and has no
+# PostFilter: BASELINE config 4's plugins
+BATCHABLE = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
+             "NodeAffinity", "TaintToleration", "PodTopologySpread"]
+
+# the three spans a wave's device work can open, one per wave
+WAVE_SPANS = ("replay_and_decode_stream", "device_replay", "host_path_wave")
+
+
+class Reserver(CustomPlugin):
+    name = "Reserver"
+
+    def reserve(self, pod, node):
+        return None
+
+
+class Normalizer(CustomPlugin):
+    name = "Normalizer"
+    default_weight = 1
+
+    def score(self, pod, node):
+        return 1
+
+    def normalize(self, scores):
+        return scores
+
+
+class Observer(PluginExtender):
+    def after_cycle(self, pod, annotations, result_store):
+        pass
+
+
+class Interceptor(PluginExtender):
+    def before_filter(self, pod, node_name):
+        return None
+
+
+def _webhook(engine):
+    engine.set_extenders(ExtenderService([
+        {"urlPrefix": "http://127.0.0.1:1", "filterVerb": "filter",
+         "ignorable": True}]))
+
+
+def _extender(ext):
+    def tweak(engine):
+        engine.plugin_extenders = {"NodeResourcesFit": ext}
+    return tweak
+
+
+def _no_defer(engine):
+    # what the remote HTTP cluster client's reflector answers
+    engine.reflector.defer_supported = lambda: False
+
+
+def _ladder_rung(rung):
+    def tweak(engine):
+        engine._residency = rung  # where _degrade() leaves the ladder
+    return tweak
+
+
+def _custom(*plugins, base=("NodeResourcesFit",)):
+    return lambda: PluginSetConfig(
+        enabled=list(base) + [p.name for p in plugins],
+        custom={p.name: p for p in plugins})
+
+
+@dataclass
+class Row:
+    id: str
+    # what the engine observes
+    config: Callable[[], PluginSetConfig | None] = lambda: None
+    tweak: Callable | None = None
+    env: dict = field(default_factory=dict)
+    engine_kw: dict = field(default_factory=dict)
+    gang: bool = False
+    # the path it must take
+    span: str = "replay_and_decode_stream"
+    speculative: bool = False
+    commit: str = "post_pass"          # streamed | post_pass | host_loop
+    results: str = "device_lazy"       # device_lazy | host_lazy | in_wave
+    mode: str = "device_resident"
+
+
+def _enabled(names):
+    return lambda: PluginSetConfig(enabled=list(names))
+
+
+ROWS = [
+    # the stock server and every cell of BENCHMARK.json: DefaultPreemption
+    # refuses the streaming committer, the volume family speculation
+    Row("row01_default_profile"),
+    Row("row02_webhook_extenders", tweak=_webhook,
+        span="host_path_wave", commit="host_loop", results="in_wave"),
+    Row("row03_plugin_extender_intercepts_cycle",
+        config=_enabled(["NodeResourcesFit"]), tweak=_extender(Interceptor()),
+        span="host_path_wave", commit="host_loop", results="in_wave"),
+    Row("row04_custom_normalize_score", config=_custom(Normalizer()),
+        span="host_path_wave", commit="host_loop", results="in_wave"),
+    Row("row05_custom_lifecycle_plugin", config=_custom(Reserver()),
+        span="device_replay", results="in_wave"),
+    Row("row06_observer_on_default_profile", tweak=_extender(Observer()),
+        results="in_wave"),
+    Row("row06_observer_on_batchable_profile", config=_enabled(BATCHABLE),
+        tweak=_extender(Observer()), speculative=True, results="in_wave"),
+    Row("row07_postfilter_in_batchable_profile",
+        config=_enabled(BATCHABLE + ["DefaultPreemption"])),
+    Row("row08_batchable_profile", config=_enabled(BATCHABLE),
+        speculative=True, commit="streamed"),
+    Row("row09_volume_family_without_postfilter",
+        config=_enabled(["NodeResourcesFit", "VolumeBinding"]),
+        commit="streamed"),
+    Row("row10_reflector_cannot_defer_default_profile", tweak=_no_defer,
+        results="in_wave"),
+    Row("row10_reflector_cannot_defer_batchable_profile",
+        config=_enabled(BATCHABLE), tweak=_no_defer,
+        speculative=True, commit="streamed", results="in_wave"),
+    Row("row11_gang_plugin_alone_on_batchable_profile",
+        config=_custom(Coscheduling(), base=BATCHABLE), gang=True,
+        speculative=True, commit="streamed"),
+    Row("row12_rung_host_resident", env={"KSS_TPU_HOST_RESIDENT": "1"},
+        results="host_lazy", mode="host_resident"),
+    Row("row12_rung_host_resident_by_degradation", tweak=_ladder_rung(1),
+        results="host_lazy", mode="host_resident"),
+    Row("row13_rung_eager_decode", env={"KSS_TPU_EAGER_DECODE": "1"},
+        results="in_wave", mode="eager_decode"),
+    # the two pins tests and parity baselines set; no server sets them
+    Row("pin_speculative_off", config=_enabled(BATCHABLE),
+        env={"KSS_TPU_SPECULATIVE": "0"}, commit="streamed"),
+    Row("pin_pipeline_commit_off", config=_enabled(BATCHABLE),
+        engine_kw={"pipeline_commit": False}, speculative=True),
+]
+
+
+def _counter(name):
+    return TRACER.summary()["counters"].get(name, 0)
+
+
+def _decoded_in_wave():
+    series = TRACER.snapshot()["labeled_counters"].get("decode_path_total", [])
+    return sum(s["value"] for s in series)
+
+
+@pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
+def test_wave_path(row, monkeypatch):
+    for name in ("KSS_TPU_SPECULATIVE", "KSS_TPU_EAGER_DECODE",
+                 "KSS_TPU_HOST_RESIDENT"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in row.env.items():
+        monkeypatch.setenv(name, value)
+    store = ObjectStore()
+    for n in make_nodes(6, seed=11):
+        store.create("nodes", n)
+    pods = make_pods(12, seed=12)
+    if row.gang:
+        ensure_podgroup_resource(store)
+        pgs, gpods = make_gang_workload(1, 3, seed=13)
+        for pg in pgs:
+            store.create("podgroups", pg)
+        pods += gpods
+    for p in pods:
+        store.create("pods", p)
+    engine = SchedulerEngine(store, plugin_config=row.config(), chunk=8,
+                             **row.engine_kw)
+    if row.tweak is not None:
+        row.tweak(engine)
+
+    TRACER.reset()
+    assert engine.schedule_pending() == len(pods)
+    events = TRACER.events(limit=2000)
+
+    opened = [e for e in events if e["name"] in WAVE_SPANS]
+    assert [e["name"] for e in opened] == [row.span]
+    assert (opened[0].get("mode") == "speculative") is row.speculative
+    assert (_counter("speculative_rounds_total") > 0) is row.speculative
+
+    names = {e["name"] for e in events}
+    assert (_counter("commit_stream_waves_total") == 1) \
+        is (row.commit == "streamed")
+    assert ("commit_stream" in names) is (row.commit == "streamed")
+    # the host loop commits pod by pod inside its own span
+    assert ("commit_and_reflect" in names) is (row.commit != "host_loop")
+    if row.gang:
+        assert _counter("gang_groups_admitted_total") == 1
+
+    assert engine.result_mode() == row.mode
+    lazy = row.results != "in_wave"
+    if row.span == "replay_and_decode_stream":
+        # a lazy wave decodes nothing; the others decode every pod in it
+        assert _decoded_in_wave() == (0 if lazy else len(pods))
+    deferred = getattr(engine.reflector, "_lazy", None)
+    assert (deferred.pending_count() if deferred else 0) \
+        == (len(pods) if lazy else 0)
+    # only a device-resident wave leaves tensors for a cold read to fetch
+    meta = pods[0]["metadata"]
+    annotated = store.get("pods", meta["name"], meta.get("namespace"))
+    assert annotated["metadata"]["annotations"]
+    assert (_counter("d2h_on_demand_bytes_total") > 0) \
+        is (row.results == "device_lazy")

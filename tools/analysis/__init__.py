@@ -75,19 +75,3 @@ def run_analysis(root: str | None = None,
         "lock_edges": lock_edges,
     }
 
-
-def analysis_verdict(root: str | None = None) -> dict:
-    """The analyzer verdict bench.py embeds in each BENCH round's JSON
-    (`extra.analysis`; bench-check refuses rounds with new findings).
-    Never raises — bench must not die because a tree is mid-refactor;
-    an internal failure comes back as {"error": ...}."""
-    try:
-        from .baseline import load_baseline, partition
-
-        result = run_analysis(root=root)
-        new, old, _stale = partition(result["findings"], load_baseline())
-        return {"new_findings": len(new),
-                "grandfathered": len(old),
-                "findings": [f.render() for f in new[:20]]}
-    except Exception as e:
-        return {"error": f"{type(e).__name__}: {e}"[:200]}

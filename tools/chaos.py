@@ -18,9 +18,7 @@ the wave failure protocol's invariants (docs/fault-injection.md):
     (KSS_TPU_LOCK_WITNESS=1 — `make chaos` sets it).
 
 Each seed derives one deterministic plan, so a failure prints the exact
-reproducing command.  The quick single-seed verdict also rides every
-bench round (`extra.chaos`) and `bench_check.py` refuses rounds whose
-chaos run failed.
+reproducing command.
 """
 
 from __future__ import annotations
@@ -165,7 +163,7 @@ def _run_once(seed: int, plan, shape: dict) -> dict:
 
     # set the global to exactly `plan` (None = fault-free reference) and
     # RESTORE the previous plan after: an operator's env-armed
-    # KSS_TPU_FAULT_PLAN must survive a bench-embedded chaos verdict
+    # KSS_TPU_FAULT_PLAN must survive an in-process chaos verdict
     prev = faults.current_plan()
     prev_retries = os.environ.get("KSS_TPU_WAVE_MAX_RETRIES")
     if plan is not None:
@@ -381,19 +379,15 @@ def run_seed(seed: int, shape: dict, witness=None) -> dict:
             "dump": dump_path}
 
 
-QUICK_SHAPE = {"nodes": 5, "pods": 14, "gangs": 1, "gang_members": 3,
-               "chunk": 6}
 FULL_SHAPE = {"nodes": 8, "pods": 26, "gangs": 2, "gang_members": 3,
               "chunk": 8}
 
 
 def chaos_verdict(seeds: int = DEFAULT_SEEDS, seed_base: int = 1,
-                  quick: bool = False, witness=None) -> dict:
-    """The machine-readable verdict `make chaos` gates on and bench
-    rounds embed as extra.chaos."""
-    shape = QUICK_SHAPE if quick else FULL_SHAPE
+                  witness=None) -> dict:
+    """The machine-readable verdict `make chaos` gates on."""
     t0 = time.perf_counter()
-    results = [run_seed(seed_base + i, shape, witness=witness)
+    results = [run_seed(seed_base + i, FULL_SHAPE, witness=witness)
                for i in range(seeds)]
     return {
         "ok": all(r["ok"] for r in results),
@@ -412,8 +406,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="kss-chaos", description=__doc__)
     ap.add_argument("--seeds", type=int, default=DEFAULT_SEEDS)
     ap.add_argument("--seed-base", type=int, default=1)
-    ap.add_argument("--quick", action="store_true",
-                    help="small single-wave shape (the bench embedding)")
     ap.add_argument("--json", dest="json_out", default=None)
     args = ap.parse_args(argv)
 
@@ -425,7 +417,7 @@ def main(argv=None) -> int:
 
         witness = lockwitness.install()
     verdict = chaos_verdict(seeds=args.seeds, seed_base=args.seed_base,
-                            quick=args.quick, witness=witness)
+                            witness=witness)
     print(json.dumps(verdict, indent=2))
     if args.json_out:
         with open(args.json_out, "w", encoding="utf-8") as fh:

@@ -1,26 +1,25 @@
 """Full-scale parity artifact: byte-identical annotations at 10k x 5k.
 
-Round-3 verdict missing #5 (and round-4 #3: run it ON DEVICE): the
-parity gate only ever ran at reduced scale; this script executes configs
-4 and 5 at the FULL benchmark shape (10,000 pods x 5,000 nodes) against
-the sequential CPU oracle and records a committed artifact under
-docs/bench/.
+The parity gates elsewhere run at reduced scale; this script executes
+configs 4 and 5 of models/workloads.py BASELINE_CONFIGS at their FULL
+shape (10,000 pods x 5,000 nodes) against the sequential CPU oracle and
+writes the verdict under chiprun_out/ (git-ignored; the chip tool brings
+it back from a device run).
 
 Every one of the 13 per-pod result annotations (filter-result,
 score-result, finalscore-result, selected-node, ...) must match the
 oracle byte-for-byte for every pod.  Both sides stream
-(bench.stream_oracle_parity): the oracle runs in a separate CPU-forced
-RLIMIT-capped subprocess emitting one pod per line, and the comparison
-holds one pod at a time — the full ~13 GB annotation product is never
-resident, so the script fits a memory-starved host (round 4's
-in-process oracle was OOM-killed on one).
+(reference_impl/parity_gate.py stream_oracle_parity): the oracle runs in
+a separate CPU-forced RLIMIT-capped subprocess emitting one pod per
+line, and the comparison holds one pod at a time — the full ~13 GB
+annotation product is never resident.
 
 By default forces the CPU XLA backend; with --device it uses whatever
 backend jax initializes (the TPU where there is one) so the artifact
-proves DEVICE-layout parity at full scale.  Wall times are recorded but are NOT benchmark
-figures (the run may share the host with other work).
+proves DEVICE-layout parity at full scale.  Wall times are recorded but
+are NOT benchmark figures (the run may share the host with other work).
 
-Usage: python docs/bench/parity_fullscale.py [outfile] [--device]
+Usage: python tools/parity_fullscale.py [outfile] [--device]
        [--configs 4,5] [--scale 1.0]
 """
 
@@ -30,6 +29,7 @@ import argparse
 import json
 import sys
 import time
+from pathlib import Path
 
 sys.path.insert(0, ".")
 
@@ -37,7 +37,7 @@ sys.path.insert(0, ".")
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("outfile", nargs="?",
-                    default="docs/bench/r05-parity-fullscale.json")
+                    default="chiprun_out/parity_fullscale.json")
     ap.add_argument("--device", action="store_true",
                     help="use the default jax backend (TPU where present) "
                          "instead of forcing CPU")
@@ -52,7 +52,8 @@ def main():
         force_cpu()
     import jax
 
-    import bench
+    from kube_scheduler_simulator_tpu.reference_impl.parity_gate import (
+        stream_oracle_parity)
 
     backend = jax.devices()[0].platform
     print(f"backend: {backend} ({jax.devices()})", flush=True)
@@ -67,9 +68,8 @@ def main():
                 _last["n"] = i
                 print(f"  compared {i} pods", flush=True)
 
-        r = bench.stream_oracle_parity(idx, args.scale, args.seed,
-                                       chunk=512, want_digest=True,
-                                       heartbeat=hb)
+        r = stream_oracle_parity(idx, args.scale, args.seed, chunk=512,
+                                 want_digest=True, heartbeat=hb)
         ok = r["ok"]
         print(f"config {idx}: {'BYTE-PARITY OK' if ok else 'FAILED'} "
               f"({r['keys_checked']} annotation values, "
@@ -91,12 +91,13 @@ def main():
     rev = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
                          capture_output=True, text=True).stdout.strip()
     artifact = {"rev": rev, "backend": backend,
-                "protocol": "BASELINE.md measurement protocol, full scale",
+                "protocol": "PARITY.md parity protocol, full scale",
                 "scale": args.scale,
                 "results": results,
                 "all_parity_ok": all(
                     r["mismatches"] == 0 and r["oracle_completed"]
                     for r in results)}
+    Path(args.outfile).parent.mkdir(parents=True, exist_ok=True)
     with open(args.outfile, "w") as f:
         json.dump(artifact, f, indent=2)
     print(f"wrote {args.outfile}; all_parity_ok={artifact['all_parity_ok']}",

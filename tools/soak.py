@@ -21,9 +21,8 @@ controller's per-session memory is pruned while it runs, and the final
 black box must validate (`autopilot.decide` events carry the full
 {effector, session, from, to, reason} shape).
 
-The verdict JSON feeds docs/bench/bench_check.py (SOAK_* rounds):
-soak_p99_wave_seconds and soak_shed_rate must not regress across
-rounds and soak_recovered_to_rung0 must stay true.
+`make bench-soak` asserts the verdict JSON: ok, soak_p99_wave_seconds
+under the target, every shed with Retry-After, soak_recovered_to_rung0.
 """
 
 from __future__ import annotations
