@@ -51,7 +51,7 @@ import numpy as np
 
 from .pipeline import build_step
 from ..control import CONTROLS
-from ..state.compile import CompiledWorkload
+from ..state.compile import CompiledWorkload, statics_digest
 from ..utils.faults import fault_point
 from ..utils.tracing import TRACER
 
@@ -1110,22 +1110,25 @@ def scan_cache_stats() -> dict:
 
 
 def _statics_fingerprint(cw: CompiledWorkload) -> str:
+    """The statics' digest for the scan-cache key.  compile_workload
+    takes it from the host bytes before it uploads them; a workload made
+    without it (hand-built in tests and tools) has its statics fetched
+    back and hashed here, once."""
     fp = cw.host.get("_statics_fp")
-    if fp is not None:
-        return fp
-    import hashlib
-
-    h = hashlib.sha1()
-    for name in sorted(cw.statics):
-        h.update(name.encode())
-        for leaf in jax.tree.leaves(cw.statics[name]):
-            a = np.asarray(leaf)
-            h.update(str(a.shape).encode())
-            h.update(str(a.dtype).encode())
-            h.update(a.tobytes())
-    fp = h.hexdigest()
-    cw.host["_statics_fp"] = fp
+    TRACER.inc("scan_key_statics_total",
+               source="host" if fp is not None else "fetched")
+    if fp is None:
+        fp = cw.host["_statics_fp"] = statics_digest(cw.statics)
     return fp
+
+
+def _leaf_sig(leaf) -> tuple:
+    # an array's own metadata: np.asarray(leaf) would fetch a device
+    # array (and gather a sharded one) to learn what it already says
+    if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
+        return tuple(leaf.shape), str(leaf.dtype)
+    a = np.asarray(leaf)
+    return a.shape, str(a.dtype)
 
 
 def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
@@ -1133,9 +1136,9 @@ def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
 
     mesh_sig = tuple(mesh.shape.items()) if mesh is not None else None
     shapes = tuple(
-        (path_leaf[0].__str__(), tuple(np.shape(path_leaf[1])), str(np.asarray(path_leaf[1]).dtype))
+        (str(path), *_leaf_sig(leaf))
         for tree in (cw.xs, cw.init_carry)
-        for path_leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
     )
     cfg = cw.config
     cfg_sig = (
@@ -1151,6 +1154,17 @@ def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
                      for k, v in cfg.point_disabled.items())),
     )
     return (_statics_fingerprint(cw), mesh_sig, shapes, cfg_sig, chunk)
+
+
+@jax.jit
+def _copy_carry(carry):
+    """A fresh device copy of a carry tree in ONE dispatch (a jnp.array
+    per leaf is 18 of them under the default profile).  jnp.copy puts a
+    real copy into the jaxpr: a jitted identity would forward its input
+    buffers, and the scan's donation would then invalidate the
+    workload's own init_carry.  jax.jit caches by the leaves' shapes,
+    dtypes and shardings, so it compiles once per carry shape."""
+    return jax.tree.map(jnp.copy, carry)
 
 
 class _SlimWorkload:
@@ -1534,7 +1548,7 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
 
         # copy: the scan donates its carry argument, and cw.init_carry must
         # survive for subsequent replays of the same compiled workload
-        carry = jax.tree.map(jnp.array, cw.init_carry)
+        carry = _copy_carry(cw.init_carry)
     from concurrent.futures import ThreadPoolExecutor
 
     if not collect:

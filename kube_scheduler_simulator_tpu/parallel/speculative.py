@@ -112,7 +112,7 @@ import numpy as np
 from ..framework.replay import (
     ReplayResult, _CompactChunks, _compact_plan, _DeviceAttribution,
     _DEVICE_BUDGET, _resolve_device_resident, _scan_for, _SCAN_CACHE,
-    _slice_xs, _SlimWorkload, _workload_scan_key)
+    _copy_carry, _slice_xs, _SlimWorkload, _workload_scan_key)
 from ..control import CONTROLS
 from ..state.compile import CompiledWorkload
 from ..utils.blackbox import BLACKBOX
@@ -886,7 +886,7 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
 
     # copy: the commit/scan fold donates its carry argument, and
     # cw.init_carry must survive for later replays of the same workload
-    carry = jax.tree.map(jnp.array, cw.init_carry)
+    carry = _copy_carry(cw.init_carry)
     stats = _SpecStats()
     cw_scan = None       # mesh-sharded clone, built on first scan round
     scan_jit = None

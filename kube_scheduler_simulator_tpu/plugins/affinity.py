@@ -140,9 +140,10 @@ def build(table: NodeTable, pods: list[dict],
         # decoder emits no annotations for skipped scorers).
         host_out.setdefault("static_score_rows", {})[NAME] = (
             np.ascontiguousarray(np.take(pref_mat, pref_idx, axis=0)))
+    # numpy: compile_workload digests, then uploads (upload_statics)
     static = NodeAffinityStatic(
-        req_rows=jnp.asarray(np.stack(req_pool)),
-        pref_rows=jnp.asarray(pref_mat),
+        req_rows=np.stack(req_pool),
+        pref_rows=pref_mat,
     )
     return static, NodeAffinityXS(
         req_idx=jnp.asarray(req_idx),

@@ -275,7 +275,8 @@ def build(table: NodeTable, pods: list[dict], bound,
         dtype=bool,
     )
 
-    static = InterPodStatic(dom_idx=jnp.asarray(dom_idx), hard_weight=jnp.int64(hard_weight))
+    # numpy: compile_workload digests, then uploads (upload_statics)
+    static = InterPodStatic(dom_idx=dom_idx, hard_weight=np.int64(hard_weight))
     xs = InterPodXS(
         t_matches=jnp.asarray(t_matches),
         h_req_aff=jnp.asarray(h_req_aff),

@@ -116,7 +116,8 @@ def build(vt: VolumeTable, table, pods: list[dict],
     limits = np.stack([vt.csi_limits[d] for d in drivers], axis=1) if drivers else \
         np.zeros((n, 0), dtype=np.int64)
 
-    static = LimitsStatic(driver_onehot=jnp.asarray(onehot), limits=jnp.asarray(limits))
+    # numpy: compile_workload digests, then uploads (upload_statics)
+    static = LimitsStatic(driver_onehot=onehot, limits=limits)
     xs = LimitsXS(pod_vols=jnp.asarray(pod_vols), filter_skip=jnp.asarray(skip))
     carry = LimitsCarry(on_node=jnp.asarray(on_node))
     return static, xs, carry

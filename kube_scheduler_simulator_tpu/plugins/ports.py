@@ -137,7 +137,8 @@ def build(table, pods: list[dict], bound_pods: list[tuple[dict, str]]):
             else:
                 used_spec[j, intern.s_id(proto, port, ip)] = True
 
-    static = PortsStatic(sq=jnp.asarray(np.asarray(intern.sq, dtype=np.int32)))
+    # numpy: compile_workload digests, then uploads (upload_statics)
+    static = PortsStatic(sq=np.asarray(intern.sq, dtype=np.int32))
     xs = PortsXS(
         w_wild=jnp.asarray(w_wild), w_spec=jnp.asarray(w_spec),
         w_any=jnp.asarray(w_any), filter_skip=jnp.asarray(skip),

@@ -281,7 +281,8 @@ def build(table: NodeTable, pods: list[dict]):
         filter_skip[i] = not is_filter[i].any()
         score_skip[i] = not is_score[i].any()
 
-    static = SpreadStatic(dom_idx=jnp.asarray(dom_idx), n_groups=n_groups)
+    # numpy: compile_workload digests, then uploads (upload_statics)
+    static = SpreadStatic(dom_idx=dom_idx, n_groups=n_groups)
     xs = SpreadXS(
         pm=jnp.asarray(pm),
         c_id=jnp.asarray(c_id_arr),

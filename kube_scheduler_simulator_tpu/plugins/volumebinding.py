@@ -201,8 +201,9 @@ def build(vt: VolumeTable, table, pods: list[dict], bound_pods=None):
                         sc, table.labels[j]
                     )
 
+    # numpy: compile_workload digests, then uploads (upload_statics)
     static = BindingStatic(
-        pv_cap=jnp.asarray(vt.pv_cap), pv_node_ok=jnp.asarray(vt.pv_node_ok)
+        pv_cap=np.asarray(vt.pv_cap), pv_node_ok=np.asarray(vt.pv_node_ok)
     )
     xs = BindingXS(
         bound_code=jnp.asarray(bound_code),

@@ -158,7 +158,8 @@ def build(vt: VolumeTable, table, pods: list[dict],
             if not ro:
                 used_rw[j, d] = True
 
-    static = RestrictionsStatic(strict=jnp.asarray(np.asarray(strict, dtype=bool)))
+    # numpy: compile_workload digests, then uploads (upload_statics)
+    static = RestrictionsStatic(strict=np.asarray(strict, dtype=bool))
     xs = RestrictionsXS(
         w_any=jnp.asarray(w_any), w_rw=jnp.asarray(w_rw),
         rwop=jnp.asarray(rwop), filter_skip=jnp.asarray(skip),

@@ -18,9 +18,11 @@ prime the scheduler's NodeInfo snapshots.
 
 from __future__ import annotations
 
+import hashlib
 from dataclasses import dataclass, field
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -327,7 +329,40 @@ def compile_workload(
             host=host,
         )
         _collect_host_flags(cw)
+        # the one upload site of the statics: every build above handed
+        # numpy leaves, so the scan-cache key's digest is taken from the
+        # host bytes and scan_prepare never fetches them back
+        host["_statics_fp"] = statics_digest(statics)
+        cw.statics = upload_statics(statics)
     return cw
+
+
+def statics_digest(statics: dict[str, Any]) -> str:
+    """The statics' part of the scan-cache key (framework/replay.py
+    _workload_scan_key): SHA-1 over name + shape + dtype + bytes of every
+    leaf, plugins in sorted-name order.  Equal statics share a compiled
+    scan (the jitted step closes over them), unequal ones never do.  On
+    host leaves this reads no device; on a workload's device statics it
+    fetches each leaf back (replay's fallback for a workload that
+    compile_workload did not make)."""
+    h = hashlib.sha1()
+    for name in sorted(statics):
+        h.update(name.encode())
+        for leaf in jax.tree.leaves(statics[name]):
+            a = np.asarray(leaf)
+            h.update(str(a.shape).encode())
+            h.update(str(a.dtype).encode())
+            h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def upload_statics(statics: dict[str, Any]) -> dict[str, Any]:
+    """numpy leaves -> device arrays; what is not an array
+    (SpreadStatic.n_groups, a Python int the step reads as a constant)
+    stays as it is."""
+    return jax.tree.map(
+        lambda leaf: jnp.asarray(leaf)
+        if isinstance(leaf, (np.ndarray, np.generic)) else leaf, statics)
 
 
 # the plugins whose build (with its carry priming) compile_workload wraps
