@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from .replay import replay
+from .replay import filter_rejected_rows, replay
 from ..cluster.store import Conflict, NotFound, ObjectStore
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
@@ -313,7 +313,8 @@ class _WaveCommitter:
             from ..store.decode import decode_chunk_into
 
             decode_chunk_into(rr, lo, hi, self.annotations)
-        self._q.put((wave, lo, hi, np.asarray(rr.selected[lo:hi]).copy()))
+        self._q.put((wave, lo, hi, np.asarray(rr.selected[lo:hi]).copy(),
+                     filter_rejected_rows(rr, lo, hi)))
 
     def finish(self) -> tuple[int, None]:
         """Replay drained: commit the remaining chunks, settle reflects,
@@ -369,10 +370,10 @@ class _WaveCommitter:
                     continue  # keep draining so finish() never blocks
                 try:
                     t0 = time.perf_counter()
-                    wave, lo, hi, selected = item
+                    wave, lo, hi, selected, rejected = item
                     with TRACER.span("commit_stream", parent=self.parent_span,
                                      lo=lo, hi=hi):
-                        self._commit(wave, lo, hi, selected)
+                        self._commit(wave, lo, hi, selected, rejected)
                     self._busy.append((t0, time.perf_counter()))
                 except BaseException as e:  # noqa: BLE001 — finish() re-raises
                     self._exc = e
@@ -393,7 +394,7 @@ class _WaveCommitter:
             else None
         return acc.finish() if acc is not None else None
 
-    def _commit(self, wave, lo: int, hi: int, selected) -> None:
+    def _commit(self, wave, lo: int, hi: int, selected, rejected) -> None:
         if wave is not None:
             acc = getattr(wave, "_attr_acc", None)
             if acc is not None:
@@ -403,6 +404,8 @@ class _WaveCommitter:
                 acc.add_chunk(lo // wave.chunk)
         if hi <= self._upto:
             return  # width-tier re-delivery of an already-committed chunk
+        TRACER.count("filter_rejected_nodes_total",
+                     int(rejected[max(lo, self._upto) - lo:].sum()))
         if self.gang is not None:
             self._selected[lo:hi] = selected
             if self._pod_wave is not None:
@@ -1644,6 +1647,10 @@ class SchedulerEngine:
             gang_admit, gang_wait = self._gang_decide(
                 gang, np.asarray(rr.selected, dtype=np.int32), 0,
                 len(pending))
+        # decided pods only: one parked by its gang or by Permit is counted
+        # by the cycle that decides it
+        refused = filter_rejected_rows(rr, 0, len(pending))
+        n_refused = 0
         with TRACER.span("commit_and_reflect", pods=len(pending)) as commit_sp:
             for i, pod in enumerate(pending):
                 meta = pod.get("metadata") or {}
@@ -1695,6 +1702,8 @@ class SchedulerEngine:
                         self.reflector.reflect(ns, name, uid=meta.get("uid"))
                         if exclude is not None:
                             exclude.add((ns, name))
+                        TRACER.count("filter_rejected_nodes_total",
+                                     n_refused + int(refused[i]))
                         return n_bound, "rejected"
                     self._bind(ns, name, cw.node_table.names[sel])
                     self._run_custom_postbind(priv, cw.node_table.names[sel],
@@ -1710,6 +1719,7 @@ class SchedulerEngine:
                                 cw, rr.codes_of(i), i, pod, ns, name):
                             retry = "preempted"
                     self._mark_unschedulable(ns, name)
+                n_refused += int(refused[i])
                 reflects.submit(ns, name, meta.get("uid"))
                 if g >= 0 and i == int(gang.last[g]) and gang_admit[g]:
                     # the group's last wave member landed: release its
@@ -1721,6 +1731,7 @@ class SchedulerEngine:
                         n_bound += 1
                         reflects.submit(rec.ns, rec.name, rec.uid)
             reflects.drain()
+        TRACER.count("filter_rejected_nodes_total", n_refused)
         TRACER.observe("framework_extension_point_duration_seconds",
                        commit_sp.seconds, extension_point="bind")
         return n_bound, retry
@@ -2660,6 +2671,8 @@ class SchedulerEngine:
                     if self._run_postfilter(cw, codes, i, pod, ns, name):
                         retry = "preempted"
                 self._mark_unschedulable(ns, name)
+            TRACER.count("filter_rejected_nodes_total",
+                         int(filter_rejected_rows(rr1, 0, 1)[0]))
             self.reflector.reflect(ns, name, uid=meta.get("uid"))
         return n_bound, retry
 

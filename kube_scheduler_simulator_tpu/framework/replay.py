@@ -829,6 +829,17 @@ class ChunkAttribution:
         return self.out
 
 
+def filter_rejected_rows(rr: ReplayResult, lo: int, hi: int) -> np.ndarray:
+    """[hi-lo] int64: the nodes a Filter plugin refused, for each of pods
+    lo..hi — the nodes less feasible_count, and 0 for a pod whose cycle a
+    PreFilter reject ended before any Filter ran.  Both are decision rows
+    the wave has already fetched: the engine counts
+    filter_rejected_nodes_total from this at commit without a device read."""
+    feasible = np.asarray(rr.feasible_count[lo:hi], dtype=np.int64)
+    ran = np.asarray(rr.prefilter_reject[lo:hi]) == 0
+    return np.where(ran, rr.cw.n_nodes - feasible, 0)
+
+
 def plugin_attribution(rr: ReplayResult) -> dict | None:
     """Per-plugin work attribution reconstructed from the replay tensors
     a wave already holds — no extra device work, no annotation-path

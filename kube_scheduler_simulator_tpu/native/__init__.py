@@ -136,7 +136,7 @@ def _build_and_load():
         P(ctypes.c_uint8), P(ctypes.c_uint8), P(ctypes.c_uint8),
         ctypes.c_int32,
         P(ctypes.c_int64), P(ctypes.c_int64),
-        P(ctypes.c_double),
+        P(ctypes.c_double), P(ctypes.c_int64),
     ]
     lib.chunk_arena_free.restype = None
     lib.chunk_arena_free.argtypes = [ctypes.c_void_p]

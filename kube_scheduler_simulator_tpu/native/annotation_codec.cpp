@@ -705,7 +705,8 @@ inline int64_t read_score(const void* col, int32_t elem, int32_t j) {
 // decode_one: the per-pod body shared by ctx_decode_pod (one C call per
 // pod, the legacy fused path) and ctx_decode_chunk (one C call per replay
 // chunk, pods iterated by the worker pool).  Runs on any thread; all
-// scratch state is thread_local.
+// scratch state is thread_local.  Returns the refusals it rendered into
+// the filter blob (nodes whose entry ends at a failure message).
 int32_t decode_one(
     const Ctx& ctx,
     const void* packed, int32_t pack_elem, int32_t code_bits,
@@ -746,7 +747,7 @@ int32_t decode_one(
                                     &out_lens[0]);
     out_blobs[1] = out_blobs[2] = nullptr;
     out_lens[1] = out_lens[2] = 0;
-    if (!want_scores) return 0;
+    if (!want_scores) return n_fail;
 
     // ---- distinct-tuple pass (hostnorm mirrors) ------------------------
     //
@@ -970,7 +971,7 @@ int32_t decode_one(
     out_lens[1] = (int64_t)(sw - sbuf);
     out_blobs[2] = fbuf;
     out_lens[2] = (int64_t)(fw - fbuf);
-    return 0;
+    return n_fail;
 }
 
 // ---------------------------------------------------------------------------
@@ -1090,6 +1091,7 @@ int32_t ctx_decode_pod(
 //   out_ptrs/out_lens: [c*3] blob addresses/lengths (0 = absent); valid
 //                 until chunk_arena_free of the returned arena
 //   thread_seconds: out, summed worker busy time (tracer counter)
+//   failed_entries: out, refusals rendered into the range's filter blobs
 void* ctx_decode_chunk(
     void* p,
     int32_t c,
@@ -1105,7 +1107,8 @@ void* ctx_decode_chunk(
     int32_t n_threads,
     int64_t* out_ptrs,
     int64_t* out_lens,
-    double* thread_seconds) {
+    double* thread_seconds,
+    int64_t* failed_entries) {
     const Ctx& ctx = *(const Ctx*)p;
     const int32_t n = ctx.n, f = ctx.f, s = ctx.s;
     ChunkArena* arena = new ChunkArena();
@@ -1119,6 +1122,7 @@ void* ctx_decode_chunk(
 
     std::atomic<int32_t> next{0};
     std::atomic<long long> busy_ns{0};
+    std::atomic<long long> failed{0};
     std::mutex merge_m;
 
     auto work = [&](int) {
@@ -1135,7 +1139,7 @@ void* ctx_decode_chunk(
                     : nullptr;
             char* blobs[3];
             int64_t lens[3];
-            decode_one(ctx,
+            failed += decode_one(ctx,
                        (const char*)packed + (size_t)i * n * pack_elem,
                        pack_elem, code_bits,
                        active_rows + (size_t)i * f,
@@ -1164,6 +1168,7 @@ void* ctx_decode_chunk(
 
     decode_pool().run(n_threads, work);
     if (thread_seconds) *thread_seconds = busy_ns.load() / 1e9;
+    if (failed_entries) *failed_entries = failed.load();
     return arena;
 }
 

@@ -26,7 +26,7 @@ Filter (required terms), in upstream check order:
   2. pod anti-affinity: no t with h_req_anti>0 may have matched[t,dom]>0
                                  -> "node(s) didn't match pod anti-affinity rules"
   3. existing pods' anti-affinity: sum_t t_matches[p,t]*have_req_anti[t,dom]
-     must be 0       -> "node(s) didn't satisfy existing pods' anti-affinity rules"
+     must be 0       -> "node(s) didn't satisfy existing pods anti-affinity rules"
 
 Score: raw(n) = sum_t [ (h_pref_aff_w - h_pref_anti_w)[p,t] * matched[t,dom]
                  + t_matches[p,t] * (sym_pref_aff - sym_pref_anti
@@ -61,7 +61,7 @@ from ..state.selectors import label_selector_matches
 NAME = "InterPodAffinity"
 ERR_AFFINITY = "node(s) didn't match pod affinity rules"
 ERR_ANTI_AFFINITY = "node(s) didn't match pod anti-affinity rules"
-ERR_EXISTING_ANTI = "node(s) didn't satisfy existing pods' anti-affinity rules"
+ERR_EXISTING_ANTI = "node(s) didn't satisfy existing pods anti-affinity rules"
 
 CODE_AFFINITY, CODE_ANTI, CODE_EXISTING = 1, 2, 3
 

@@ -778,7 +778,7 @@ class SequentialScheduler:
         # 3. existing pods' required anti-affinity vs this pod
         for (key, val), cnt in st["existing_anti"].items():
             if cnt > 0 and self.labels[j].get(key) == val:
-                return "node(s) didn't satisfy existing pods' anti-affinity rules"
+                return "node(s) didn't satisfy existing pods anti-affinity rules"
         return None
 
     def _interpod_score_state(self, pod) -> dict:

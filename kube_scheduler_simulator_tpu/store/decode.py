@@ -149,8 +149,9 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
         from . import native_decode
 
         feasible_count = int(rr.feasible_count[i])
-        filter_json, score_json, final_json = native_decode.decode_pod_fused(
+        filter_json, score_json, final_json, failed = native_decode.decode_pod_fused(
             native_ctx, rr, i, hi, feasible_count > 1)
+        TRACER.count("decode_filter_failed_entries_total", failed)
         prescore = {}
         if feasible_count > 1:
             for name in cfg.prescorers():
@@ -163,6 +164,11 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
         (f, name) for f, name in enumerate(filter_names) if not fskip[name][hi]
     ]
     codes = rr.codes_of(i)  # [F, N]
+    # the refusals the filter blob below renders: nodes some active plugin
+    # failed (these rungs hold the codes on the host; the fused native
+    # rungs count in C, in the walk that emits the entries)
+    refused = (codes[[f for f, _ in active]] != 0).any(axis=0)
+    TRACER.count("decode_filter_failed_entries_total", int(refused.sum()))
 
     filter_json: str | None = None
     if native_ctx is not None:
@@ -371,13 +377,21 @@ def _decode_chunk_native(rr, lo: int, hi: int, out: list, base: int) -> bool:
     from . import native_decode
 
     with TRACER.span("decode_chunk", lo=lo, hi=hi, path="native_chunk"):
-        triples, thread_s = native_decode.decode_chunk_fused(
+        handle = native_decode.decode_chunk_start(
             ctx, rr, lo, hi, skip=_chunk_skip_mask(rr, lo, hi))
-        TRACER.count("decode_chunk_calls_total")
-        TRACER.count("decode_native_thread_seconds", round(thread_s, 6))
-        TRACER.inc("decode_path_total", hi - lo, path="native_chunk")
+        triples = native_decode.decode_chunk_take(handle)
+        _count_native_chunk(handle, hi - lo)
         _assemble_chunk(rr, lo, hi, triples, out, base)
     return True
+
+
+def _count_native_chunk(handle, pods: int) -> None:
+    """The counters of one ctx_decode_chunk call, from what it reported."""
+    TRACER.count("decode_chunk_calls_total")
+    TRACER.count("decode_native_thread_seconds",
+                 round(handle.thread_seconds, 6))
+    TRACER.count("decode_filter_failed_entries_total", handle.failed_entries)
+    TRACER.inc("decode_path_total", pods, path="native_chunk")
 
 
 def decode_chunk_into(rr, lo: int, hi: int, out: list, base: int = 0) -> None:
@@ -500,10 +514,7 @@ def decode_release_batches(rr, lo: int, hi: int, on_pod=None,
                 handle = fut.result()
                 fut = start(ranges[k + 1]) if k + 1 < len(ranges) else None
                 triples = native_decode.decode_chunk_take(handle)
-                TRACER.count("decode_chunk_calls_total")
-                TRACER.count("decode_native_thread_seconds",
-                             round(handle.thread_seconds, 6))
-                TRACER.inc("decode_path_total", b1 - b0, path="native_chunk")
+                _count_native_chunk(handle, b1 - b0)
                 sink: list = [None] * (b1 - b0)
                 _assemble_chunk(rr, b0, b1, triples, sink, b0)
                 if on_pod is not None:

@@ -249,10 +249,10 @@ class _ChunkHandle:
     arena (callers always pair the two)."""
 
     __slots__ = ("ctx", "arena", "out_ptrs", "out_lens", "skip", "c",
-                 "thread_seconds", "_keep")
+                 "thread_seconds", "failed_entries", "_keep")
 
     def __init__(self, ctx, arena, out_ptrs, out_lens, skip, c,
-                 thread_seconds, keep):
+                 thread_seconds, failed_entries, keep):
         self.ctx = ctx
         self.arena = arena
         self.out_ptrs = out_ptrs
@@ -260,6 +260,8 @@ class _ChunkHandle:
         self.skip = skip
         self.c = c
         self.thread_seconds = thread_seconds
+        # refusals the C side rendered into the range's filter blobs
+        self.failed_entries = failed_entries
         self._keep = keep
 
     def discard(self) -> None:
@@ -349,6 +351,7 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
     out_ptrs = np.zeros(c * 3, np.int64)
     out_lens = np.zeros(c * 3, np.int64)
     tsec = ctypes.c_double()
+    failed = ctypes.c_int64()
     if n_threads is None:
         n_threads = min(8, effective_cpu_count())
     if skip is not None:
@@ -360,9 +363,10 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
         col_base, col_stride, col_elem,
         ig_ptr, _u8p(want), _u8p(skip) if skip is not None else None,
         n_threads,
-        _i64p(out_ptrs), _i64p(out_lens), ctypes.byref(tsec))
+        _i64p(out_ptrs), _i64p(out_lens), ctypes.byref(tsec),
+        ctypes.byref(failed))
     return _ChunkHandle(ctx, arena, out_ptrs, out_lens, skip, c,
-                        float(tsec.value), keep_alive)
+                        float(tsec.value), int(failed.value), keep_alive)
 
 
 def decode_chunk_take(handle: _ChunkHandle) -> list:
@@ -391,20 +395,11 @@ def decode_chunk_take(handle: _ChunkHandle) -> list:
     return triples
 
 
-def decode_chunk_fused(ctx: _NativeCtx, rr, lo: int, hi: int,
-                       skip=None, n_threads: int | None = None):
-    """decode_chunk_start + decode_chunk_take in one call.
-
-    Returns (triples, native_thread_seconds)."""
-    handle = decode_chunk_start(ctx, rr, lo, hi, skip=skip,
-                                n_threads=n_threads)
-    return decode_chunk_take(handle), handle.thread_seconds
-
-
 def decode_pod_fused(ctx: _NativeCtx, rr, i: int, hi: int,
-                     want_scores: bool) -> tuple[str, str | None, str | None]:
-    """(filter-result, score-result, finalscore-result) for pod i straight
-    from the compact replay layout — one C call; no [F,N] code unpack, no
+                     want_scores: bool) -> tuple[str, str | None, str | None, int]:
+    """(filter-result, score-result, finalscore-result, refusals rendered
+    into filter-result) for pod i straight from the compact replay layout
+    — one C call; no [F,N] code unpack, no
     int64 raw/final materialization, normalization computed in C
     (hostnorm mirror, asserted byte-identical by tests/test_native_codec.py).
 
@@ -457,7 +452,7 @@ def decode_pod_fused(ctx: _NativeCtx, rr, i: int, hi: int,
 
     out_blobs = (ctypes.c_void_p * 3)()
     out_lens = (ctypes.c_int64 * 3)()
-    ctx.lib.ctx_decode_pod(
+    failed_entries = ctx.lib.ctx_decode_pod(
         ctx.ptr,
         prow.ctypes.data_as(ctypes.c_void_p), packed.dtype.itemsize, code_bits,
         _u8p(ctx.active_rows[hi]), _u8p(ctx.sskip_rows[hi]),
@@ -470,7 +465,7 @@ def decode_pod_fused(ctx: _NativeCtx, rr, i: int, hi: int,
         score_json = ctx.take(ctx.lib, out_blobs[1], out_lens[1])
     if out_blobs[2]:
         final_json = ctx.take(ctx.lib, out_blobs[2], out_lens[2])
-    return filter_json, score_json, final_json
+    return filter_json, score_json, final_json, failed_entries
 
 
 def encode_string_map(d: dict[str, str]) -> str | None:
