@@ -74,7 +74,11 @@ def calculate_priority(sum_scores: int, num_containers: int) -> int:
 
 def score_for(pod: dict, states, n_nodes: int) -> np.ndarray:
     """[N] int64 ImageLocality score, the scalar/parity formula."""
-    images, num_containers = pod_images(pod)
+    return _score_row(*pod_images(pod), states, n_nodes)
+
+
+def _score_row(images: list[str], num_containers: int, states,
+               n_nodes: int) -> np.ndarray:
     out = np.zeros(n_nodes, dtype=np.int64)
     if not images or num_containers == 0:
         return out
@@ -92,13 +96,24 @@ def score_for(pod: dict, states, n_nodes: int) -> np.ndarray:
     return out
 
 
-def build(nodes: list[dict], pods: list[dict],
+def build(table, nodes: list[dict], pods: list[dict],
           host_out: dict | None = None) -> ImageXS:
-    states = node_image_states(nodes)
+    """table: the NodeTable built from `nodes`; the image states and the
+    score row per (image names, container count) are kept on it (the
+    table's identity covers status.images: node_key holds every node's
+    resourceVersion)."""
+    derived = table.derived
     n = len(nodes)
+
+    def states():
+        return derived.once("image_states", lambda: node_image_states(nodes))
+
     score = np.zeros((len(pods), n), dtype=np.int64)
     for i, pod in enumerate(pods):
-        score[i] = score_for(pod, states, n)
+        images, num_containers = pod_images(pod)
+        score[i] = derived.row(
+            "image_row", (tuple(images), num_containers),
+            lambda: _score_row(images, num_containers, states(), n))
     if host_out is not None:
         # score_kernel is a pure pass-through of this precompiled row: the
         # compact replay keeps it host-resident ("host" group, no D2H)

@@ -197,7 +197,6 @@ def build(table: NodeTable, pods: list[dict], bound,
 
         bound = carry_of_list(bound, namespaces)
         bound.place(table.names)
-    labels = table.labels
     n, p = table.n, len(pods)
 
     # --- unique term table ----------------------------------------------
@@ -230,13 +229,10 @@ def build(table: NodeTable, pods: list[dict], bound,
     t_count = max(len(term_list), 1)
 
     # --- domain indexing per term key ------------------------------------
+    # (NodeTable.domain_row: one row a topology key, kept on the table)
     dom_idx = np.full((t_count, n), -1, dtype=np.int32)
     for t_id, (key, _, _) in enumerate(term_list):
-        vals: dict[str, int] = {}
-        for j in range(n):
-            v = labels[j].get(key)
-            if v is not None:
-                dom_idx[t_id, j] = vals.setdefault(v, len(vals))
+        dom_idx[t_id] = table.domain_row(key)[0]
     d_max = max(int(dom_idx.max()) + 1, 1)
 
     # --- pod x term matches + per-pod term weights -----------------------

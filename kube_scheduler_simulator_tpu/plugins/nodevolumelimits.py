@@ -100,7 +100,7 @@ def build(vt: VolumeTable, table, pods: list[dict],
                 pod_vols[i, c] = True
 
     on_node = np.zeros((n, nc), dtype=bool)
-    name_idx = {name: j for j, name in enumerate(table.names)}
+    name_idx = table.name_idx
     for vols, node_name in bound_vol_lists:
         j = name_idx.get(node_name)
         if j is None:

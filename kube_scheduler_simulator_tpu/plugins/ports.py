@@ -124,7 +124,7 @@ def build(table, pods: list[dict], bound_pods: list[tuple[dict, str]]):
     used_any = np.zeros((n, nq), dtype=bool)
     used_wild = np.zeros((n, nq), dtype=bool)
     used_spec = np.zeros((n, ns), dtype=bool)
-    name_idx = {name: j for j, name in enumerate(table.names)}
+    name_idx = table.name_idx
     for ports, node_name in bound_ports:
         j = name_idx.get(node_name)
         if j is None:

@@ -54,31 +54,35 @@ class NodeNameXS(NamedTuple):
     fail: jnp.ndarray  # [P, N] bool
 
 
+def _taint_rows(table: NodeTable, tols: list) -> tuple[np.ndarray, np.ndarray]:
+    """([N] filter code, [N] intolerable PreferNoSchedule count) of one
+    pod's tolerations against every node's taints."""
+    n = table.n
+    tols_prefer = [t for t in tols if (t.get("effect") or "") in ("", PREFER_NO_SCHEDULE)]
+    crow = np.zeros(n, dtype=np.int16)
+    prow = np.zeros(n, dtype=np.int16)
+    for j in range(n):
+        for ti, (key, value, eff) in enumerate(table.taints[j]):
+            if eff in (NO_SCHEDULE, NO_EXECUTE):
+                if crow[j] == 0 and not tolerations_tolerate(tols, key, value, eff):
+                    crow[j] = 1 + ti
+            elif eff == PREFER_NO_SCHEDULE:
+                if not tolerations_tolerate(tols_prefer, key, value, eff):
+                    prow[j] += 1
+    return crow, prow
+
+
 def build_taints(table: NodeTable, pods: list[dict],
                  host_out: dict | None = None) -> TaintXS:
     n, p = table.n, len(pods)
     code = np.zeros((p, n), dtype=np.int16)
     prefer = np.zeros((p, n), dtype=np.int16)
-    rows: dict[str, tuple[np.ndarray, np.ndarray]] = {}  # unique tolerations -> rows
     for i, pod in enumerate(pods):
         tols = (pod.get("spec") or {}).get("tolerations") or []
-        cache_key = spec_key(tols)
-        cached = rows.get(cache_key)
-        if cached is None:
-            tols_prefer = [t for t in tols if (t.get("effect") or "") in ("", PREFER_NO_SCHEDULE)]
-            crow = np.zeros(n, dtype=np.int16)
-            prow = np.zeros(n, dtype=np.int16)
-            for j in range(n):
-                for ti, (key, value, eff) in enumerate(table.taints[j]):
-                    if eff in (NO_SCHEDULE, NO_EXECUTE):
-                        if crow[j] == 0 and not tolerations_tolerate(tols, key, value, eff):
-                            crow[j] = 1 + ti
-                    elif eff == PREFER_NO_SCHEDULE:
-                        if not tolerations_tolerate(tols_prefer, key, value, eff):
-                            prow[j] += 1
-            cached = (crow, prow)
-            rows[cache_key] = cached
-        code[i], prefer[i] = cached
+        # one (filter_code, prefer_count) row pair per distinct
+        # tolerations, kept on the table
+        code[i], prefer[i] = table.derived.row(
+            "taint_rows", spec_key(tols), lambda: _taint_rows(table, tols))
     if host_out is not None:
         # the raw score IS this precompiled row (taint_score is a pure
         # pass-through): the compact replay keeps it host-resident
@@ -104,7 +108,7 @@ def build_nodename(table: NodeTable, pods: list[dict]) -> NodeNameXS:
     "passed") for every pod, empty nodeName matching every node."""
     n, p = table.n, len(pods)
     fail = np.zeros((p, n), dtype=bool)
-    name_idx = {name: j for j, name in enumerate(table.names)}
+    name_idx = table.name_idx
     for i, pod in enumerate(pods):
         want = (pod.get("spec") or {}).get("nodeName") or ""
         if not want:

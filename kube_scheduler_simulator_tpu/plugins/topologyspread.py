@@ -164,18 +164,22 @@ def _node_affinity_eligible(pod: dict, table: NodeTable) -> np.ndarray:
 def _taints_tolerated_row(pod: dict, table: NodeTable) -> np.ndarray:
     """nodeTaintsPolicy Honor: a node is excluded when it carries a
     NoSchedule/NoExecute taint the incoming pod doesn't tolerate
-    (upstream helper.DoNotScheduleTaintsFilterFunc)."""
+    (upstream helper.DoNotScheduleTaintsFilterFunc).  One row per
+    distinct tolerations, kept on the table."""
     from ..state.selectors import has_untolerated_do_not_schedule_taint
 
     tols = (pod.get("spec") or {}).get("tolerations") or []
-    return np.asarray([
-        not has_untolerated_do_not_schedule_taint(table.taints[j], tols)
-        for j in range(table.n)
-    ], dtype=bool)
+
+    def make():
+        return np.asarray([
+            not has_untolerated_do_not_schedule_taint(table.taints[j], tols)
+            for j in range(table.n)
+        ], dtype=bool)
+
+    return table.derived.row("taints_tolerated", spec_key(tols), make)
 
 
 def build(table: NodeTable, pods: list[dict]):
-    labels = table.labels
     n, p = table.n, len(pods)
 
     # unique count groups + per-pod slots over the effective constraints
@@ -185,28 +189,12 @@ def build(table: NodeTable, pods: list[dict]):
     n_groups = max(len(group_list), 1)
 
     # --- domain indexing per group key -----------------------------------
-    # the domain row depends only on (node labels, topologyKey) — cache it
-    # on the NodeTable so the engine's per-wave rebuild (reuse=NodeTable)
-    # skips the n-iteration Python loop for keys it has already indexed
-    dom_cache = getattr(table, "_tsp_dom_cache", None)
-    if dom_cache is None:
-        dom_cache = {}
-        table._tsp_dom_cache = dom_cache
+    # the row depends only on (node labels, topologyKey): kept on the
+    # table (NodeTable.domain_row), shared with InterPodAffinity's terms
     dom_idx = np.full((n_groups, n), -1, dtype=np.int32)
     n_domains = np.zeros(n_groups, dtype=np.int64)
     for c_id, (_, key, _) in enumerate(group_list):
-        hit = dom_cache.get(key)
-        if hit is None:
-            vals: dict[str, int] = {}
-            row = np.full(n, -1, dtype=np.int32)
-            for j in range(n):
-                v = labels[j].get(key)
-                if v is not None:
-                    row[j] = vals.setdefault(v, len(vals))
-            hit = (row, len(vals))
-            dom_cache[key] = hit
-        dom_idx[c_id] = hit[0]
-        n_domains[c_id] = hit[1]
+        dom_idx[c_id], n_domains[c_id] = table.domain_row(key)
     d_max = max(int(dom_idx.max()) + 1, 1)
 
     # --- pod x group selector matches ------------------------------------

@@ -212,8 +212,8 @@ def build(vt: VolumeTable, table, pods: list[dict], bound_pods=None):
         provision_ok=jnp.asarray(provision_ok),
         filter_skip=jnp.asarray(skip),
     )
-    name_idx = {name: j for j, name in enumerate(table.names)}
-    carry = BindingCarry(claimed=jnp.asarray(prime_claims(vt, bound_pods, name_idx)))
+    carry = BindingCarry(
+        claimed=jnp.asarray(prime_claims(vt, bound_pods, table.name_idx)))
     return static, xs, carry, rejects
 
 

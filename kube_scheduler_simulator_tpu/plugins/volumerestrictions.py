@@ -145,7 +145,7 @@ def build(vt: VolumeTable, table, pods: list[dict],
     used_any = np.zeros((n, nd), dtype=bool)
     used_rw = np.zeros((n, nd), dtype=bool)
     rwop_used = np.zeros(nr, dtype=bool)
-    name_idx = {name: j for j, name in enumerate(table.names)}
+    name_idx = table.name_idx
     for (disks, node_name), keys in zip(bound_disks, bound_rwops):
         j = name_idx.get(node_name)
         for key in keys:

@@ -219,7 +219,7 @@ def compile_workload(
             init_carry["NodePorts"] = carry
     if "ImageLocality" in enabled:
         with TRACER.span("cw_build_ImageLocality"):
-            xs["ImageLocality"] = imagelocality.build(nodes, pods,
+            xs["ImageLocality"] = imagelocality.build(table, nodes, pods,
                                                       host_out=host)
     if "TaintToleration" in enabled:
         with TRACER.span("cw_build_TaintToleration"):
@@ -331,9 +331,13 @@ def compile_workload(
         _collect_host_flags(cw)
         # the one upload site of the statics: every build above handed
         # numpy leaves, so the scan-cache key's digest is taken from the
-        # host bytes and scan_prepare never fetches them back
-        host["_statics_fp"] = statics_digest(statics)
-        cw.statics = upload_statics(statics)
+        # host bytes and scan_prepare never fetches them back.  The
+        # statics are node-side tensors: where the digest is the last
+        # pass's on this table, so are the device arrays (one generation;
+        # the jitted step closes over them, nothing donates or writes one)
+        digest = host["_statics_fp"] = statics_digest(statics)
+        cw.statics = table.derived.generation(
+            "statics_device", digest, lambda: upload_statics(statics))
     return cw
 
 
@@ -505,7 +509,7 @@ def _score_dtype(cw: CompiledWorkload, name: str) -> str:
         return "i8"
     if name == "TaintToleration":
         # raw = count of intolerable PreferNoSchedule taints on the node
-        if max((len(t) for t in cw.node_table.taints), default=0) <= 127:
+        if cw.node_table.max_taints <= 127:
             return "i8"
         return "i16"
     # raws that are fully precompiled per (pod, node) have an exact
@@ -548,7 +552,7 @@ def _max_filter_code(cw: CompiledWorkload) -> int:
         if name == "NodeResourcesFit":
             b = (1 << (cw.schema.n + 1)) - 1
         elif name == "TaintToleration":
-            b = max((len(t) for t in cw.node_table.taints), default=0)
+            b = cw.node_table.max_taints
         elif name == "PodTopologySpread":
             b = 2 * topologyspread.MAX_CONSTRAINTS
         elif name in _FILTER_CODE_BOUNDS:

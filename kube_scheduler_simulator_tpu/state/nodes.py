@@ -15,15 +15,103 @@ tolerate the node.kubernetes.io/unschedulable:NoSchedule taint).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import threading
+from collections import OrderedDict
+from dataclasses import dataclass, field
+from types import MappingProxyType
 
 import numpy as np
 
 from .resources import ResourceSchema
+from ..utils.tracing import TRACER
 
 NO_SCHEDULE = "NoSchedule"
 PREFER_NO_SCHEDULE = "PreferNoSchedule"
 NO_EXECUTE = "NoExecute"
+
+
+class NodeDerived:
+    """What a build derives from a node table alone, or from the table
+    and a hashable fragment of a pod's spec, computed on first use and
+    kept for the table's life.  A table is an immutable snapshot: every
+    node change makes a new NodeTable (build_node_table*,
+    patch_node_table*) and with it an empty memo, so an entry is valid
+    as long as it can be reached.  Two threads compiling against one
+    table may compute an entry twice; both get equal values.
+
+    Kinds (the `kind` label of node_derived_{hits,misses,evictions}_total):
+    image_states, taint_max, name_idx (one value a table); image_row,
+    taint_rows, taints_tolerated, dom_idx (one value a fragment, at most
+    ROW_CAP a kind, least recently used out); statics_device (one
+    generation: the last pass's uploaded statics under their digest).
+    Arrays are handed out read-only: every consumer copies them into its
+    own [P, N] block."""
+
+    ROW_CAP = 256
+
+    __slots__ = ("_values", "_rows", "_lock")
+
+    def __init__(self):
+        self._values: dict[str, object] = {}
+        self._rows: dict[str, OrderedDict] = {}
+        self._lock = threading.Lock()
+
+    def once(self, kind: str, make):
+        """The table's one value of `kind`."""
+        if kind in self._values:
+            TRACER.inc("node_derived_hits_total", kind=kind)
+            return self._values[kind]
+        TRACER.inc("node_derived_misses_total", kind=kind)
+        value = self._values[kind] = _frozen(make())
+        return value
+
+    def row(self, kind: str, fragment, make):
+        """The value of `kind` for one hashable fragment of a pod's spec."""
+        with self._lock:
+            rows = self._rows.setdefault(kind, OrderedDict())
+            value = rows.get(fragment)
+            if value is not None:
+                rows.move_to_end(fragment)
+        if value is not None:
+            TRACER.inc("node_derived_hits_total", kind=kind)
+            return value
+        TRACER.inc("node_derived_misses_total", kind=kind)
+        value = _frozen(make())
+        with self._lock:
+            rows[fragment] = value
+            evicted = len(rows) - self.ROW_CAP
+            for _ in range(evicted):
+                rows.popitem(last=False)
+        if evicted > 0:
+            TRACER.inc("node_derived_evictions_total", evicted, kind=kind)
+        return value
+
+    def generation(self, kind: str, key, make):
+        """One generation of `kind`: the value made under `key`, until a
+        call with another key replaces it."""
+        held = self._values.get(kind)
+        if held is not None and held[0] == key:
+            TRACER.inc("node_derived_hits_total", kind=kind)
+            return held[1]
+        TRACER.inc("node_derived_misses_total", kind=kind)
+        if held is not None:
+            TRACER.inc("node_derived_evictions_total", kind=kind)
+        value = make()
+        self._values[kind] = (key, value)
+        return value
+
+
+def _frozen(value):
+    """numpy arrays (alone or in a tuple) read-only, a dict behind a
+    read-only proxy; anything else as it is."""
+    if isinstance(value, np.ndarray):
+        value.flags.writeable = False
+    elif isinstance(value, tuple):
+        for v in value:
+            _frozen(v)
+    elif isinstance(value, dict):
+        return MappingProxyType(value)
+    return value
 
 
 @dataclass
@@ -41,10 +129,43 @@ class NodeTable:
     labels: "list[dict[str, str]]"
     taints: "list[list[tuple[str, str, str]]]"
     unschedulable: np.ndarray      # [N] bool
+    # the node-derived memo: empty on every new table
+    derived: NodeDerived = field(default_factory=NodeDerived, repr=False,
+                                 compare=False)
 
     @property
     def n(self) -> int:
         return len(self.names)
+
+    @property
+    def name_idx(self) -> "MappingProxyType[str, int]":
+        """node name -> row, for the builders that place bound pods."""
+        return self.derived.once(
+            "name_idx", lambda: {name: j for j, name in enumerate(self.names)})
+
+    @property
+    def max_taints(self) -> int:
+        """The longest taint list of any node: TaintToleration's filter
+        code and raw score are bounded by it."""
+        return self.derived.once(
+            "taint_max", lambda: max((len(t) for t in self.taints), default=0))
+
+    def domain_row(self, key: str) -> tuple[np.ndarray, int]:
+        """(the [N] int32 domain index of every node under topology key
+        `key`, -1 where a node lacks the label; the number of domains),
+        domains numbered in node order.  PodTopologySpread's count groups
+        and InterPodAffinity's terms index the same row."""
+        def make():
+            labels = self.labels
+            vals: dict[str, int] = {}
+            row = np.full(self.n, -1, dtype=np.int32)
+            for j in range(self.n):
+                v = labels[j].get(key)
+                if v is not None:
+                    row[j] = vals.setdefault(v, len(vals))
+            return row, len(vals)
+
+        return self.derived.row("dom_idx", key, make)
 
     @property
     def label_index(self):

@@ -197,7 +197,7 @@ def build_volume_table(
                 )
 
     csi_limits: dict[str, np.ndarray] = {}
-    name_idx = {name: j for j, name in enumerate(node_table.names)}
+    name_idx = node_table.name_idx
     for cn in csinodes or []:
         j = name_idx.get(_meta(cn).get("name", ""))
         if j is None:

@@ -94,7 +94,15 @@ def _serve(dep, pods: list[dict]) -> tuple[list[dict], list[dict]]:
                     break
                 assert time.time() < deadline, f"{name} not decided"
                 time.sleep(0.02)
-            after = TRACER.counter_totals()
+            # the commit counts its refusals after the pod is readable:
+            # give it a moment to catch up with what the read rendered
+            while True:
+                after = TRACER.counter_totals()
+                if (after.get(REJECTED, 0) - before.get(REJECTED, 0)
+                        >= after.get(RENDERED, 0) - before.get(RENDERED, 0)
+                        or time.time() > deadline):
+                    break
+                time.sleep(0.01)
             served.append(got)
             growth.append({k: after.get(k, 0) - before.get(k, 0)
                            for k in (REJECTED, RENDERED)})

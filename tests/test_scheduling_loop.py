@@ -295,7 +295,10 @@ def test_a_refused_post_leaves_no_writer_in_flight(served, refused, status):
                  json.dumps(make_nodes(1, seed=79)[0]).encode()) == 201
     first, second = make_pods(2, seed=80)
     assert refused(di, base, copy.deepcopy(first)) == status
-    assert loop._in_flight == 0
+    # the handler leaves writer_in_flight() after the response is on the
+    # wire: the client can be back here a moment before it has
+    _wait(lambda: loop._in_flight == 0, "the refused writer never left",
+          timeout=5.0)
     # ... so the next pod's window closes settled, not at the 30 s cap
     assert _post(base, "/api/v1/pods", json.dumps(second).encode()) == 201
     _wait(lambda: _bound(di.store, [second]), "the loop never bound the pod")
