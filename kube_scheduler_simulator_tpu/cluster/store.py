@@ -46,7 +46,9 @@ from ..utils.faults import fault_point
 # recorder/recorder.go:45-53 DefaultGVRs — see DEFAULT_GVRS below);
 # PodDisruptionBudgets are additionally storable so PDB-aware preemption
 # can honor them (the real scheduler reads PDBs from the apiserver even
-# though the simulator never syncs them).
+# though the simulator never syncs them).  Services are storable so that a
+# workload that creates them (scheduler_perf's churn ops) has somewhere to
+# put them; nothing in the scheduler reads one yet (docs/SEMANTICS.md).
 RESOURCES: dict[str, tuple[str, bool]] = {
     "namespaces": ("Namespace", False),
     "priorityclasses": ("PriorityClass", False),
@@ -56,6 +58,7 @@ RESOURCES: dict[str, tuple[str, bool]] = {
     "persistentvolumes": ("PersistentVolume", False),
     "pods": ("Pod", True),
     "poddisruptionbudgets": ("PodDisruptionBudget", True),
+    "services": ("Service", True),
 }
 
 # the reference's 7 DefaultGVRs — the watch/record/sync surface

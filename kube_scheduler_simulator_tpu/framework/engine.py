@@ -16,6 +16,7 @@ update, which also carries their result annotations out.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import functools
 import os
@@ -24,6 +25,7 @@ import time
 import numpy as np
 
 from .replay import filter_rejected_rows, replay
+from .unschedulable import pod_key
 from ..cluster.store import Conflict, NotFound, ObjectStore
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
@@ -505,6 +507,14 @@ class SchedulerEngine:
         # event, stamped by the scheduling loop's watch thread
         # (note_arrival) and taken by the first wave that takes the pod
         self._arrivals: dict[tuple[str, str], float] = {}
+        # the caller's unschedulable set inside queued_by() (framework/
+        # unschedulable.py), and the pods the running pass's waves have
+        # taken; both None for direct engine use
+        self._queue = None
+        self._taken: set[tuple[str, str]] | None = None
+        # podInitialBackoffSeconds / podMaxBackoffSeconds of the posted
+        # KubeSchedulerConfiguration (scheduler/service.py sets them)
+        self.pod_backoff_s: tuple[float, float] = (1.0, 10.0)
         self._pending_idx = None
         self._bound_carry = None
         self.result_store = result_store or ResultStore()
@@ -661,6 +671,20 @@ class SchedulerEngine:
         return list_shared(self.store, resource)
 
     def pending_pods(self) -> list[dict]:
+        """The unscheduled pods a pass takes, in queue order: all of them,
+        less — inside queued_by() — the ones parked in the caller's
+        unschedulable set."""
+        pods = self._unscheduled_pods()
+        parked = self._queue.parked_uids() if self._queue is not None else None
+        if not parked:
+            return pods
+        # a parked entry holds for the pod it was made for, not for a
+        # later pod of the same name
+        return [p for p in pods
+                if (k := pod_key(p)) not in parked
+                or parked[k] != (p.get("metadata") or {}).get("uid")]
+
+    def _unscheduled_pods(self) -> list[dict]:
         """Unscheduled pods in queue order: a custom QueueSort plugin's
         less() when one is enabled (upstream allows exactly one,
         wrappedplugin.go:754-771), else PrioritySort.
@@ -782,12 +806,30 @@ class SchedulerEngine:
                 f"got {sorted(found)}")
         return next(iter(found.values()), None)
 
+    @contextlib.contextmanager
+    def queued_by(self, queue):
+        """For the passes run inside: `queue` is the caller's unschedulable
+        set (framework/unschedulable.py).  The pods parked in it are left
+        out of the pending list, and the pods a pass leaves marked
+        Unschedulable without a nominated node are parked in it.  The
+        scheduling loop runs its passes so (server/di.py).  Outside — a
+        scenario's controller, a test, `cmd.scheduler --once` — the caller
+        is the queue: every pending pod is taken, every time."""
+        self._queue = queue
+        try:
+            yield
+        finally:
+            self._queue = None
+
     def schedule_pending(self) -> int:
         """One scheduling wave over all pending pods (plus retry waves for
         pods unblocked by preemption, and re-runs after a custom
         Reserve/Permit/PreBind rejected a speculative placement). Returns
         #bound.  Runs under the owning session's tracer scope (self.session;
         a no-op for direct engine use).
+
+        Inside `queued_by(queue)` the pass leaves the queue's parked pods
+        out and parks the ones it marks Unschedulable.
 
         Pods parked by Permit "wait" do NOT stall the wave: their binding
         cycle finishes on a waiter thread when allowed/rejected/timed out
@@ -811,6 +853,9 @@ class SchedulerEngine:
             return self._schedule_pending_scoped()
 
     def _schedule_pending_scoped(self) -> int:
+        queue = self._queue
+        self._taken = set() if queue is not None else None
+        seq_at_start = queue.move_seq if queue is not None else 0
         n_bound = self._gang_maintain()
         if n_bound:
             TRACER.count("pods_scheduled_total", n_bound)
@@ -838,10 +883,26 @@ class SchedulerEngine:
                 break
         # count unschedulable once per pass, not per retry wave (pods
         # routed to no profile are not ours to count)
-        TRACER.count("pods_unschedulable_total", len([
-            p for p in self.pending_pods() if self._profile_of(p) is not None
-        ]))
+        left = [p for p in self.pending_pods()
+                if self._profile_of(p) is not None]
+        TRACER.count("pods_unschedulable_total", len(left))
+        if queue is not None:
+            self._park_unschedulable(queue, left, seq_at_start)
         return n_bound
+
+    def _park_unschedulable(self, queue, left: list[dict],
+                            seq_at_start: int) -> None:
+        """Of the pods still pending, park the ones this pass took and
+        marked Unschedulable.  A preemptor with a nominated node stays: it
+        keeps its retry wave.  A gated pod (no Unschedulable mark) stays."""
+        for p in left:
+            status = p.get("status") or {}
+            if pod_key(p) not in self._taken or status.get("nominatedNodeName"):
+                continue
+            if any(c.get("type") == "PodScheduled"
+                   and c.get("reason") == "Unschedulable"
+                   for c in status.get("conditions") or ()):
+                queue.park(p, *self.pod_backoff_s, seq_at_start=seq_at_start)
 
     def _profile_of(self, pod: dict) -> str | None:
         """Route a pod to a profile by spec.schedulerName (upstream
@@ -904,6 +965,8 @@ class SchedulerEngine:
         if not n:
             # an empty wake-up: no root span, no work-pass count
             return self._profile_wave_run(pending, exclude)
+        if self._taken is not None:
+            self._taken.update(map(pod_key, pending))
         # the root span of a pass (docs/metrics.md span tree): wave_setup,
         # compile_workload, replay_and_decode_stream, commit_and_reflect
         # and wave_finish are its children and cover it
@@ -2281,22 +2344,26 @@ class SchedulerEngine:
         filter_codes: [F, N] this pod's codes over cw.config.filters()."""
         from .preemption import PLUGIN_NAME, Preemptor, first_fail_plugins
 
-        fskip = cw.host["filter_skip"]
-        filters = cw.config.filters()
-        active_idx = [f for f, n in enumerate(filters) if not fskip[n][pod_idx]]
-        active_names = [filters[f] for f in active_idx]
-        firsts = first_fail_plugins(filter_codes[active_idx], active_names)
-        failed = [
-            (node, firsts[j]) for j, node in enumerate(cw.node_table.names)
-            if firsts[j] is not None
-        ]
-        outcome = Preemptor(
-            self.store, self.plugin_config,
-            extender_service=self.extender_service,
-        ).preempt(pod, failed)
-        self.result_store.add_post_filter_result(
-            ns, name, outcome.nominated_node, PLUGIN_NAME, outcome.evaluated_nodes
-        )
+        with TRACER.span("postfilter", nodes=len(cw.node_table.names)):
+            fskip = cw.host["filter_skip"]
+            filters = cw.config.filters()
+            active_idx = [f for f, n in enumerate(filters)
+                          if not fskip[n][pod_idx]]
+            active_names = [filters[f] for f in active_idx]
+            firsts = first_fail_plugins(filter_codes[active_idx], active_names)
+            failed = [
+                (node, firsts[j]) for j, node in enumerate(cw.node_table.names)
+                if firsts[j] is not None
+            ]
+            outcome = Preemptor(
+                self.store, self.plugin_config,
+                extender_service=self.extender_service,
+                # the dry runs patch this pass's node table
+                reuse=getattr(self, "_last_cw", None),
+            ).preempt(pod, failed)
+            self.result_store.add_post_filter_result(
+                ns, name, outcome.nominated_node, PLUGIN_NAME,
+                outcome.evaluated_nodes)
         if not outcome.nominated_node:
             return False
         for v in outcome.victims:

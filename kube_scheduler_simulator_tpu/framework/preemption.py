@@ -15,10 +15,14 @@ pkg/scheduler/framework/plugins/defaultpreemption (v1.32):
      by node-property plugins (NodeName, NodeUnschedulable, NodeAffinity,
      TaintToleration) are UnschedulableAndUnresolvable upstream and are
      skipped;
-  3. per candidate node: dry-run with ALL lower-priority pods removed; if
-     the pod then fits, reprieve victims most-important-first (priority
-     desc, earlier creation first), keeping each one that still lets the
-     pod fit — the rest are the victim set;
+  3. per candidate node: a node that holds no lower-priority pod is no
+     candidate, without a look (upstream SelectVictimsOnNode: "No
+     preemption victims found for incoming pod"); otherwise dry-run with
+     ALL lower-priority pods removed; if the pod then fits, reprieve
+     victims most-important-first (priority desc, earlier creation
+     first), keeping each one that still lets the pod fit — the rest are
+     the victim set.  The first of those dry runs is made for every node
+     at once (`_screen`, below);
   4. candidate selection (upstream pickOneNodeForPreemption): fewest PDB
      violations first (PodDisruptionBudgets are storable even though they
      are outside the 7 synced GVRs — the real scheduler honors any PDBs
@@ -32,6 +36,17 @@ The dry-run oracle re-runs the *same tensor kernels* as live scheduling
 (compile_workload over the cluster minus the removed pods, one-pod
 replay), so preemption verdicts can never drift from filter semantics.
 
+The screen.  Step 3's "all lower-priority pods removed" hypothesis is one
+compile_workload + one filter-only replay PER NODE when asked node by
+node; asked once for the cluster minus EVERY node's potential victims it
+is one of each over [1, N].  The two hypotheses differ, for node j, only
+in the pods of OTHER nodes, so they agree on every plugin whose verdict
+on node j reads nothing but node j and the pods on it (SCREEN_LOCAL_
+PLUGINS).  A node one of those refuses in the screen is refused under its
+own hypothesis too, and is out — exactly.  A node that passes the screen,
+or that only a plugin outside the set refuses there, goes through the
+per-node dry run and the reprieve loop as before.
+
 Documented divergences from upstream (also in docs/SEMANTICS.md):
 candidate search starts at node 0 instead of a random offset, and the
 terminating-victims eligibility check is skipped (the cluster model has
@@ -43,6 +58,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import numpy as np
+
+from ..utils.tracing import TRACER
 
 # Plugins whose Filter rejection upstream reports as Unschedulable (the
 # preemptible status); all other tensorized filters return
@@ -56,6 +73,34 @@ RESOLVABLE_PLUGINS = {
     "VolumeRestrictions",
     "NodeVolumeLimits",
 }
+
+# Filter plugins whose verdict on node j is a function of node j's own
+# object and of the pods bound to node j, and of nothing else in the
+# cluster — so removing pods from OTHER nodes cannot change it, which is
+# what lets one batched dry run stand in for N per-node ones (module
+# docstring, "The screen").  Stated conservatively: a plugin is here only
+# if that is true of every branch of its Filter.
+SCREEN_LOCAL_PLUGINS = frozenset({
+    # allocatable of node j minus the requests of the pods on node j,
+    # and the count of pods on node j against its pod capacity
+    "NodeResourcesFit",
+    # host ports in use by the pods on node j
+    "NodePorts",
+    # volumes attached by the pods on node j against node j's limits
+    # (PVC / PV / CSINode objects are read, but no pod elsewhere)
+    "NodeVolumeLimits",
+    # node-property plugins: node j's name, spec.unschedulable, labels
+    # and taints against the pod's own spec — no bound pod is read
+    "NodeName",
+    "NodeUnschedulable",
+    "NodeAffinity",
+    "TaintToleration",
+})
+# Left out on purpose: InterPodAffinity and PodTopologySpread (other
+# nodes' pods by construction), VolumeRestrictions (ReadWriteOncePod is
+# cluster-wide), VolumeBinding / VolumeZone (no bound pod is read, but
+# their PreFilter state is the pod's claims against cluster objects and
+# nothing is gained by trusting it here), and every custom plugin.
 
 # upstream DefaultPreemptionArgs defaults
 MIN_CANDIDATE_NODES_PERCENTAGE = 10
@@ -135,22 +180,23 @@ def filter_pods_with_pdb_violation(pods: list[dict], pdbs: list[dict]
 def first_fail_plugins(codes: np.ndarray, active_names: list[str]) -> list[str | None]:
     """Per node, the first filter plugin (upstream order) that rejected it,
     or None if the node passed.  codes: [F, N] over the ACTIVE filters."""
-    out: list[str | None] = []
-    n_nodes = codes.shape[1] if codes.ndim == 2 else 0
-    for n in range(n_nodes):
-        hit = None
-        for f, name in enumerate(active_names):
-            if codes[f, n] != 0:
-                hit = name
-                break
-        out.append(hit)
-    return out
+    if codes.ndim != 2 or not codes.shape[1]:
+        return []
+    if not active_names:
+        return [None] * codes.shape[1]
+    failed = np.asarray(codes) != 0
+    first = np.where(failed.any(axis=0), failed.argmax(axis=0), -1)
+    names = [*active_names, None]  # -1 -> None
+    return [names[f] for f in first.tolist()]
 
 
 class Preemptor:
     """Runs preemption for one unschedulable pod against live store state."""
 
-    def __init__(self, store, plugin_config, extender_service=None):
+    def __init__(self, store, plugin_config, extender_service=None,
+                 reuse=None):
+        """reuse: a NodeTableReuse of the pass that failed, so the first
+        dry run patches that pass's node table instead of building one."""
         self.store = store
         self.plugin_config = plugin_config
         # webhook extenders with a preemptVerb participate in candidate
@@ -170,45 +216,56 @@ class Preemptor:
         self.min_candidate_abs = (
             MIN_CANDIDATE_NODES_ABSOLUTE if abs_ is None else int(abs_))
         self._fit_cache: dict = {}
+        self._fit_cw = reuse
         self._nodes: list[dict] | None = None   # store snapshot, per preempt()
         self._pods_all: list[dict] | None = None
         self._volumes: dict | None = None
 
     # ------------------------------------------------------------ oracle
 
-    def _fits(self, pod: dict, node_name: str, removed: frozenset[str]) -> bool:
-        """Would `pod` pass all Filter plugins on `node_name` with the pods
-        in `removed` (set of ns/name keys) deleted from the cluster?
+    def _dry_run(self, pod: dict, removed: frozenset[str]):
+        """The filters' verdicts on `pod` over every node, with the pods in
+        `removed` (ns/name keys) deleted from the cluster -> (cw, rr).
 
         Each hypothesis recompiles workload tensors (cheap numpy) but the
         jitted scan is shared via replay's content-keyed cache, so only the
         first hypothesis of a given shape pays an XLA compile."""
+        from .replay import replay
+        from ..state.compile import NodeTableReuse, compile_workload
+
+        bound = [
+            (p, p["spec"]["nodeName"]) for p in self._pods_all
+            if (p.get("spec") or {}).get("nodeName") and _pod_key(p) not in removed
+        ]
+        cw = compile_workload(
+            self._nodes, [pod], self.plugin_config, bound_pods=bound,
+            volumes=self._volumes, reuse=self._fit_cw,
+            namespaces=self._namespaces,
+        )
+        self._fit_cw = NodeTableReuse(cw)  # shared across hypotheses
+        # host-resident: the oracle reads the single pod's codes right
+        # below, so device residency would just add an unoverlapped
+        # round-trip (plus an attribution reduction nobody consumes)
+        # per hypothesis
+        return cw, replay(cw, chunk=1, filter_only=True, device_resident=False)
+
+    @staticmethod
+    def _active_filters(cw) -> list[tuple[int, str]]:
+        """(row in the codes, name) of the filters that ran for the pod."""
+        return [(f, name) for f, name in enumerate(cw.config.filters())
+                if not cw.host["filter_skip"][name][0]]
+
+    def _fits(self, pod: dict, node_name: str, removed: frozenset[str]) -> bool:
+        """Would `pod` pass all Filter plugins on `node_name` with the pods
+        in `removed` (set of ns/name keys) deleted from the cluster?"""
         cache_key = (node_name, removed)
         hit = self._fit_cache.get(cache_key)
         if hit is not None:
             return hit
 
-        from .replay import replay
-        from ..state.compile import compile_workload
-
-        nodes = self._nodes
-        bound = [
-            (p, p["spec"]["nodeName"]) for p in self._pods_all
-            if (p.get("spec") or {}).get("nodeName") and _pod_key(p) not in removed
-        ]
-        from ..state.compile import NodeTableReuse
-
-        cw = compile_workload(
-            nodes, [pod], self.plugin_config, bound_pods=bound,
-            volumes=self._volumes, reuse=getattr(self, "_fit_cw", None),
-            namespaces=self._namespaces,
-        )
-        self._fit_cw = NodeTableReuse(cw)  # shared across fit hypotheses
-        # host-resident: the oracle reads the single pod's codes right
-        # below, so device residency would just add an unoverlapped
-        # round-trip (plus an attribution reduction nobody consumes)
-        # per fit hypothesis
-        rr = replay(cw, chunk=1, filter_only=True, device_resident=False)
+        TRACER.count("preemption_fit_probes_total")
+        with TRACER.span("preempt_probe"):
+            cw, rr = self._dry_run(pod, removed)
         try:
             j = cw.node_table.names.index(node_name)
         except ValueError:
@@ -218,13 +275,32 @@ class Preemptor:
             # ReadWriteOncePod holder is not among the removed victims)
             self._fit_cache[cache_key] = False
             return False
-        active = [
-            f for f, name in enumerate(cw.config.filters())
-            if not cw.host["filter_skip"][name][0]
-        ]
+        active = [f for f, _ in self._active_filters(cw)]
         ok = bool((rr.codes_of(0)[active, j] == 0).all()) if active else True
         self._fit_cache[cache_key] = ok
         return ok
+
+    def _screen(self, pod: dict, lower_by_node: dict[str, list[dict]]
+                ) -> set[str]:
+        """The nodes of `lower_by_node` that cannot take `pod` even with
+        all their lower-priority pods gone: ONE dry run of the cluster
+        minus every node's potential victims, read through the plugins
+        whose verdict on a node depends on that node alone (module
+        docstring, "The screen")."""
+        removed = frozenset(
+            _pod_key(p) for lower in lower_by_node.values() for p in lower)
+        with TRACER.span("preempt_screen", nodes=len(lower_by_node)):
+            cw, rr = self._dry_run(pod, removed)
+            local = [f for f, name in self._active_filters(cw)
+                     if name in SCREEN_LOCAL_PLUGINS]
+            if not local:
+                return set()
+            refused = (np.asarray(rr.codes_of(0))[local] != 0).any(axis=0)
+            out = {name for name, no in zip(cw.node_table.names,
+                                            refused.tolist())
+                   if no and name in lower_by_node}
+        TRACER.count("preemption_screen_refused_nodes_total", len(out))
+        return out
 
     # ------------------------------------------------------------ algorithm
 
@@ -259,6 +335,11 @@ class Preemptor:
             self._pods_all, GangDirectory(self.store))
         evaluated = [n for n, _ in failed]
         out = PreemptionOutcome(evaluated_nodes=evaluated)
+        TRACER.count("preemption_attempts_total")
+        # touched on every attempt, so that a reader of the counters can
+        # tell "no node was screened out / probed" from "no such counter"
+        TRACER.count("preemption_screen_refused_nodes_total", 0)
+        TRACER.count("preemption_fit_probes_total", 0)
 
         if ((pod.get("spec") or {}).get("preemptionPolicy") or "") == "Never":
             return out
@@ -277,13 +358,29 @@ class Preemptor:
             if nn:
                 by_node.setdefault(nn, []).append(p)
 
+        # a node without a lower-priority pod is no candidate (upstream's
+        # early return); the rest are screened in one pass, and only the
+        # nodes the screen cannot rule out are looked at one by one
+        lower_by_node = {}
+        for node in potential:
+            lower = [
+                p for p in by_node.get(node, ())
+                if _priority(p) < pod_prio
+                and _pod_key(p) not in self._gang_protected
+            ]
+            if lower:
+                lower_by_node[node] = lower
+        screened_out = self._screen(pod, lower_by_node) if lower_by_node else set()
+
         budget = _num_candidates(len(potential), self.min_candidate_pct,
                                  self.min_candidate_abs)
         candidates: list[tuple[str, list[dict], int]] = []
         for node in potential:
             if len(candidates) >= budget:
                 break
-            found = self._victims_on(node, by_node.get(node, []), pod, pod_prio)
+            if node not in lower_by_node or node in screened_out:
+                continue
+            found = self._victims_on(node, lower_by_node[node], pod)
             if found is not None:
                 victims, violations = found
                 candidates.append((node, victims, violations))
@@ -375,26 +472,23 @@ class Preemptor:
             for n in order if n in node_to_victims
         ]
 
-    def _victims_on(self, node: str, node_pods: list[dict], pod: dict,
-                    pod_prio: int) -> tuple[list[dict], int] | None:
+    def _victims_on(self, node: str, lower: list[dict], pod: dict
+                    ) -> tuple[list[dict], int] | None:
         """(minimal victim set on `node`, #PDB-violating victims), or None
-        if removing every lower-priority pod still doesn't make `pod` fit.
+        if removing every one of `lower` (the node's lower-priority pods
+        that no gang protects) still doesn't make `pod` fit.
 
         PDB handling follows upstream SelectVictimsOnNode: split the
         potential victims into PDB-violating and non-violating, reprieve
         the violating ones FIRST (so budget-covered pods are preferred as
         the ones actually evicted), and count the violating pods that
         could not be reprieved."""
-        lower = [
-            p for p in node_pods
-            if _priority(p) < pod_prio
-            and _pod_key(p) not in self._gang_protected
-        ]
         all_removed = frozenset(_pod_key(p) for p in lower)
         if not self._fits(pod, node, all_removed):
             return None
         # reprieve most-important-first (upstream MoreImportantPod order)
-        lower.sort(key=lambda p: (-_priority(p), _creation(p), _pod_key(p)))
+        lower = sorted(
+            lower, key=lambda p: (-_priority(p), _creation(p), _pod_key(p)))
         violating, non_violating = filter_pods_with_pdb_violation(
             lower, self._pdbs or [])
         removed = set(all_removed)

@@ -74,9 +74,12 @@ def default_multipoint_set() -> dict:
 
 def _default_top_level() -> dict:
     """Scheme-defaulted top-level KubeSchedulerConfiguration fields.
-    leaderElection/clientConnection/backoff are config-surface parity only
-    (a single-process simulator neither elects leaders nor rate-limits an
-    apiserver client); they round-trip through GET/apply untouched."""
+    leaderElection/clientConnection are config-surface parity only (a
+    single-process simulator neither elects leaders nor rate-limits an
+    apiserver client); they round-trip through GET/apply untouched.
+    podInitialBackoffSeconds / podMaxBackoffSeconds are honoured: they
+    are the backoff of the scheduling loop's unschedulable set
+    (scheduler/service.py _apply_backoff, framework/unschedulable.py)."""
     return {
         "parallelism": 16,
         "leaderElection": {

@@ -41,6 +41,7 @@ class SchedulerService:
         if engine is not None:
             self._apply_profiles(self._current)
             self._apply_extenders(self._current)
+            self._apply_backoff(self._current)
 
     def register_custom_plugins(self, plugins: list) -> None:
         """WithPlugin analogue: make plugins part of the registry for this
@@ -72,12 +73,14 @@ class SchedulerService:
             if self.engine is not None:
                 self.engine.set_profiles(profile_sets)
                 self._apply_extenders(cfg)
+                self._apply_backoff(cfg)
             self._current = copy.deepcopy(cfg)
         except Exception:
             self._guest_plugins = old_guests
             if self.engine is not None:
                 self._apply_profiles(old)
                 self._apply_extenders(old)
+                self._apply_backoff(old)
             raise
 
     def _parse_all(self, cfg: dict) -> dict:
@@ -103,6 +106,15 @@ class SchedulerService:
 
         extenders = (cfg or {}).get("extenders") or []
         self.engine.set_extenders(ExtenderService(extenders) if extenders else None)
+
+    def _apply_backoff(self, cfg: dict) -> None:
+        """podInitialBackoffSeconds / podMaxBackoffSeconds: how long the
+        scheduling loop keeps a pod it could not place from its next try
+        (framework/unschedulable.py)."""
+        top = cfg or {}
+        initial = float(top.get("podInitialBackoffSeconds") or 1)
+        self.engine.pod_backoff_s = (
+            initial, max(float(top.get("podMaxBackoffSeconds") or 10), initial))
 
     @property
     def extender_service(self):

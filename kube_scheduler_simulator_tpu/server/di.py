@@ -33,6 +33,7 @@ from ..utils.tracing import TRACER
 from ..cluster.store import ADDED, DELETED, MODIFIED, ObjectStore
 from ..config.config import SimulatorConfiguration
 from ..framework.engine import SchedulerEngine
+from ..framework.unschedulable import MOVING_RESOURCES, UnschedulablePods
 from ..scenario.runner import ScenarioService
 from ..scheduler.service import SchedulerService
 from ..services.importer import OneShotImporter
@@ -79,7 +80,14 @@ class SchedulingLoop:
     scenarios, cmd/scheduler's remote store) never count as in flight:
     for them the quiet interval alone batches, and once a burst is under
     way the running pass is the window of the next.  Each close counts
-    loop_window_closed_total{reason}."""
+    loop_window_closed_total{reason}.
+
+    A pod a pass marked Unschedulable waits in the loop's unschedulable
+    set (framework/unschedulable.py) and is in no later pass until a
+    cluster event moves it and its backoff has run out, or the 5-minute
+    flush comes: the loop feeds the set from the store's watches (pods,
+    and the kinds whose events move pods) and wakes when a parked pod
+    comes due, as it wakes for a new one."""
 
     def __init__(self, store: ObjectStore, engine: SchedulerEngine,
                  window_cap: float = WINDOW_CAP_S):
@@ -95,6 +103,8 @@ class SchedulingLoop:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._q = None
+        self.unschedulable = UnschedulablePods()
+        self._moving_qs: dict[str, object] = {}
         # last wave crash ({time, error, traceback}) — the loop survives
         # engine exceptions, but a silently wedged loop is unobservable;
         # /readyz surfaces this and scheduling_loop_crashes_total counts
@@ -104,6 +114,10 @@ class SchedulingLoop:
     def start(self):
         self._q = self.store.watch("pods")
         threading.Thread(target=self._watch, daemon=True).start()
+        for resource in MOVING_RESOURCES:
+            q = self._moving_qs[resource] = self.store.watch(resource)
+            threading.Thread(target=self._watch_moving, args=(resource, q),
+                             daemon=True).start()
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -114,6 +128,9 @@ class SchedulingLoop:
         if self._q is not None:
             self.store.unwatch("pods", self._q)
             self._q.put(None)
+        for resource, q in self._moving_qs.items():
+            self.store.unwatch(resource, q)
+            q.put(None)
 
     def kick(self):
         with self._cond:
@@ -140,6 +157,7 @@ class SchedulingLoop:
             if ev is None:
                 return
             _, event_type, obj = ev
+            self._note("pods", event_type, obj)
             if event_type == ADDED and not ((obj.get("spec") or {}).get("nodeName")):
                 # where the store hands a pending pod to the loop: the
                 # stamp the wave's queue_wait_* counters measure from
@@ -155,14 +173,37 @@ class SchedulingLoop:
             elif event_type == DELETED:
                 self.engine.forget_arrival(obj)
 
+    def _watch_moving(self, resource: str, q) -> None:
+        """Events of a kind that can help an Unschedulable pod."""
+        while not self._stop.is_set():
+            ev = q.get()
+            if ev is None:
+                return
+            self._note(resource, ev[1], ev[2])
+
+    def _note(self, resource: str, event_type: str, obj: dict) -> None:
+        if self.unschedulable.note(resource, event_type, obj):
+            with self._cond:  # the idle loop reckons its sleep anew
+                self._cond.notify_all()
+
+    def _await_work(self) -> None:
+        """Sleep until a pending pod has arrived, a parked pod has come
+        due (it is then pending again, and the wake-up is its), or stop."""
+        with self._cond:
+            while not (self._wake or self._stop.is_set()):
+                due_in = self.unschedulable.due_in()
+                if due_in is not None and due_in <= 0:
+                    self._wake = bool(self.unschedulable.release_due())
+                else:
+                    self._cond.wait(due_in)
+
     def _run(self):
         # loop_idle / loop_debounce / loop_pass cover this thread end to
         # end: under a profile every instant of it carries a kss: span
         with TRACER.session_scope(getattr(self.engine, "session", None)):
             while True:
-                with TRACER.span("loop_idle"), self._cond:
-                    self._cond.wait_for(
-                        lambda: self._wake or self._stop.is_set())
+                with TRACER.span("loop_idle"):
+                    self._await_work()
                 with TRACER.span("loop_debounce"):
                     reason = self._hold_window()
                 if reason is None:
@@ -193,7 +234,11 @@ class SchedulingLoop:
 
     def _pass(self):
         try:
-            n_bound = self.engine.schedule_pending()
+            # a pass that a new pod woke takes the parked pods that have
+            # come due meanwhile with it
+            self.unschedulable.release_due()
+            with self.engine.queued_by(self.unschedulable):
+                n_bound = self.engine.schedule_pending()
             if n_bound and not self._heap_settled:
                 # the session's first pass built the rows of every bound
                 # pod and traced its scan: all of it is kept, none of it

@@ -249,6 +249,24 @@ _HELP: dict[str, str] = {
     "scan_compile_cache_entries":
         "Compiled scan executables currently held by the process-level "
         "LRU cache (framework/replay._ScanCacheRegistry).",
+    "preemption_attempts_total":
+        "PostFilter runs of DefaultPreemption: one per pod a pass found "
+        "no feasible node for (framework/preemption.py).",
+    "preemption_screen_refused_nodes_total":
+        "Candidate nodes the batched dry run ruled out: refused, with all "
+        "their lower-priority pods gone, by a plugin that reads the node "
+        "and its own pods only.",
+    "preemption_fit_probes_total":
+        "Per-node dry runs (one compile_workload + one filter-only replay "
+        "each) of the nodes the batched screen could not rule out, and of "
+        "the reprieve loop.",
+    "pods_unschedulable_parked_total":
+        "Pods the scheduling loop's pass left marked Unschedulable and "
+        "parked in the unschedulable set (framework/unschedulable.py).",
+    "pods_requeued_total":
+        "Parked pods handed back to the pending list, by reason: event (a "
+        "cluster event moved it after its backoff), backoff (moved, then "
+        "waited the backoff out), flush (5 minutes parked).",
 }
 
 _NAME_SANITIZE_RE = re.compile(r"[^a-zA-Z0-9_:]")

@@ -6,6 +6,7 @@ recorder.go + replayer.go, driven end-to-end)."""
 import json
 import os
 import signal
+import socket
 import subprocess
 import sys
 import time
@@ -24,7 +25,15 @@ def _api(port, method, path, body=None):
         return json.loads(raw) if raw else None
 
 
-def _wait_up(port, timeout=60):
+def _free_port() -> int:
+    """A port nobody listens on: a fixed one can belong to a simulator an
+    earlier run left behind, which would answer _wait_up in our place."""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _wait_up(port, timeout=120):
     deadline = time.time() + timeout
     while time.time() < deadline:
         try:
@@ -44,7 +53,7 @@ def _env(**extra):
 
 def test_record_then_replay_roundtrip(tmp_path):
     record = tmp_path / "record.jsonl"
-    port_a, port_b = 18231, 18232
+    port_a, port_b = _free_port(), _free_port()
 
     sim_a = subprocess.Popen(
         [sys.executable, "-m", "kube_scheduler_simulator_tpu.cmd.simulator"],
@@ -71,7 +80,7 @@ def test_record_then_replay_roundtrip(tmp_path):
 
         # wait until the live scheduler binds the pod, then let the
         # recorder flush (its interval is 5s; SIGTERM also flushes)
-        deadline = time.time() + 60
+        deadline = time.time() + 120  # the first pass compiles, under load
         while time.time() < deadline:
             pod = _api(port_a, "GET", "/api/v1/pods/default/rec-pod")
             if (pod.get("spec") or {}).get("nodeName"):
@@ -96,7 +105,7 @@ def test_record_then_replay_roundtrip(tmp_path):
                      RECORD_FILE_PATH=str(record)),
             cwd=str(tmp_path),
             stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-        _wait_up(port_b, timeout=90)
+        _wait_up(port_b)
         nodes = _api(port_b, "GET", "/api/v1/nodes")["items"]
         assert [n["metadata"]["name"] for n in nodes] == ["rec-node"]
         deadline = time.time() + 60
@@ -127,7 +136,7 @@ def test_external_scheduler_mode(tmp_path):
     standalone cmd/scheduler process drives scheduling over the HTTP API
     (--once), writing the result annotations back through the remote
     store."""
-    port = 18233
+    port = _free_port()
     sim = subprocess.Popen(
         [sys.executable, "-m", "kube_scheduler_simulator_tpu.cmd.simulator"],
         env=_env(PORT=port, EXTERNAL_SCHEDULER_ENABLED="1"),
