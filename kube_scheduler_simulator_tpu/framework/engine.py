@@ -2360,7 +2360,7 @@ class SchedulerEngine:
                 extender_service=self.extender_service,
                 # the dry runs patch this pass's node table
                 reuse=getattr(self, "_last_cw", None),
-            ).preempt(pod, failed)
+            ).preempt(pod, failed, failed_pass=(cw, pod_idx))
             self.result_store.add_post_filter_result(
                 ns, name, outcome.nominated_node, PLUGIN_NAME,
                 outcome.evaluated_nodes)

@@ -22,7 +22,8 @@ pkg/scheduler/framework/plugins/defaultpreemption (v1.32):
      victims most-important-first (priority desc, earlier creation
      first), keeping each one that still lets the pod fit — the rest are
      the victim set.  The first of those dry runs is made for every node
-     at once (`_screen`, below);
+     at once, and only for the nodes that an EMPTY node's Fit check does
+     not already refuse (`_screen`, below);
   4. candidate selection (upstream pickOneNodeForPreemption): fewest PDB
      violations first (PodDisruptionBudgets are storable even though they
      are outside the 7 synced GVRs — the real scheduler honors any PDBs
@@ -46,6 +47,24 @@ PLUGINS).  A node one of those refuses in the screen is refused under its
 own hypothesis too, and is out — exactly.  A node that passes the screen,
 or that only a plugin outside the set refuses there, goes through the
 per-node dry run and the reprieve loop as before.
+
+Before that dry run, the static rule.  NodeResourcesFit refuses node n
+when `requests > allocatable[n] - requested[n]` in some resource or
+`num_pods[n] + 1 > allowed_pods[n]`, with requested, num_pods >= 0, and an
+eviction hypothesis only ever lowers requested and num_pods: a node the
+check refuses at requested = 0, num_pods = 0 is refused under every
+hypothesis, the screen's included (NodeResourcesFit is in SCREEN_LOCAL_
+PLUGINS).  That is noderesources.fit_refuses_empty over the host arrays
+the failed pass compiled from (cw.host["fit"]): no upload, no scan, no
+read-back.  Such a node leaves `lower_by_node` before the dry run, and the
+dry run is made only if a node is left.  It moves from "screened out by
+the dry run" to "screened out before it" and nothing else changes:
+`potential`, and with it the candidate budget, are what they were, and so
+are the candidates, the victims, the nominated node and evaluated_nodes.
+The rule applies where NodeResourcesFit ran for the pod (enabled, and not
+skipped); elsewhere no node is hopeless.  It reads the failed pass's node
+table, as upstream's dry run reads the cycle's snapshot; the dry run reads
+the store as it is now.
 
 Documented divergences from upstream (also in docs/SEMANTICS.md):
 candidate search starts at node 0 instead of a random offset, and the
@@ -250,10 +269,10 @@ class Preemptor:
         return cw, replay(cw, chunk=1, filter_only=True, device_resident=False)
 
     @staticmethod
-    def _active_filters(cw) -> list[tuple[int, str]]:
+    def _active_filters(cw, pod_idx: int = 0) -> list[tuple[int, str]]:
         """(row in the codes, name) of the filters that ran for the pod."""
         return [(f, name) for f, name in enumerate(cw.config.filters())
-                if not cw.host["filter_skip"][name][0]]
+                if not cw.host["filter_skip"][name][pod_idx]]
 
     def _fits(self, pod: dict, node_name: str, removed: frozenset[str]) -> bool:
         """Would `pod` pass all Filter plugins on `node_name` with the pods
@@ -280,8 +299,30 @@ class Preemptor:
         self._fit_cache[cache_key] = ok
         return ok
 
-    def _screen(self, pod: dict, lower_by_node: dict[str, list[dict]]
-                ) -> set[str]:
+    @classmethod
+    def _hopeless(cls, failed_pass, nodes) -> set[str]:
+        """Those of `nodes` that NodeResourcesFit refuses the pod even when
+        EMPTY (module docstring, "the static rule"), from the host arrays
+        of failed_pass = (the pass's CompiledWorkload, the pod's row in
+        it)."""
+        from ..plugins.noderesources import NAME_FIT, fit_refuses_empty
+
+        if failed_pass is None:
+            return set()
+        cw, pod_idx = failed_pass
+        if NAME_FIT not in (
+                name for _, name in cls._active_filters(cw, pod_idx)):
+            return set()
+        static, requests = cw.host["fit"]
+        refused = fit_refuses_empty(static, requests[pod_idx])
+        if not refused.any():
+            return set()
+        idx = cw.node_table.name_idx
+        return {node for node in nodes
+                if (j := idx.get(node)) is not None and refused[j]}
+
+    def _dry_run_refused(self, pod: dict,
+                         lower_by_node: dict[str, list[dict]]) -> set[str]:
         """The nodes of `lower_by_node` that cannot take `pod` even with
         all their lower-priority pods gone: ONE dry run of the cluster
         minus every node's potential victims, read through the plugins
@@ -289,7 +330,8 @@ class Preemptor:
         docstring, "The screen")."""
         removed = frozenset(
             _pod_key(p) for lower in lower_by_node.values() for p in lower)
-        with TRACER.span("preempt_screen", nodes=len(lower_by_node)):
+        TRACER.count("preemption_screen_dry_runs_total")
+        with TRACER.span("preempt_screen_dry_run", nodes=len(lower_by_node)):
             cw, rr = self._dry_run(pod, removed)
             local = [f for f, name in self._active_filters(cw)
                      if name in SCREEN_LOCAL_PLUGINS]
@@ -302,20 +344,46 @@ class Preemptor:
         TRACER.count("preemption_screen_refused_nodes_total", len(out))
         return out
 
+    def _screen(self, pod: dict, lower_by_node: dict[str, list[dict]],
+                failed_pass) -> dict[str, list[dict]]:
+        """`lower_by_node` (node -> its lower-priority pods) less the nodes
+        that cannot take `pod` even with all of those gone, and less the
+        pods a gang protects: first the static rule, on host arrays alone;
+        the cluster's snapshot and the batched dry run only if it leaves a
+        node (module docstring, "The screen")."""
+        with TRACER.span("preempt_screen", nodes=len(lower_by_node)):
+            hopeless = self._hopeless(failed_pass, lower_by_node)
+            TRACER.count("preemption_static_refused_nodes_total",
+                         len(hopeless))
+            if len(hopeless) == len(lower_by_node):
+                return {}
+            self._snapshot_cluster()
+            left = {}
+            for node, lower in lower_by_node.items():
+                if node in hopeless:
+                    continue
+                lower = [p for p in lower
+                         if _pod_key(p) not in self._gang_protected]
+                if lower:
+                    left[node] = lower
+            refused = self._dry_run_refused(pod, left) if left else ()
+        return {node: lower for node, lower in left.items()
+                if node not in refused}
+
     # ------------------------------------------------------------ algorithm
 
-    def preempt(self, pod: dict, failed: list[tuple[str, str | None]]) -> PreemptionOutcome:
-        """failed: (node name, first failing plugin or None) for every node
-        evaluated in the failed scheduling cycle."""
+    def _snapshot_cluster(self) -> None:
+        """What only a dry run or a candidate reads: the store's nodes,
+        volumes, namespaces and PDBs as they are now, and the gang members
+        that no preemption may evict."""
         from ..cluster.store import list_shared
+        from .gang import GangDirectory, preemption_protected
 
         def _shared(resource):
             # read-only snapshot, no per-object deep copies
             return list_shared(self.store, resource)
 
-        self._fit_cache.clear()
         self._nodes = _shared("nodes")
-        self._pods_all = _shared("pods")
         self._volumes = {
             "pvcs": _shared("persistentvolumeclaims"),
             "pvs": _shared("persistentvolumes"),
@@ -329,17 +397,28 @@ class Preemptor:
         # gang quorum guard (docs/gang-scheduling.md): bound PodGroup
         # members whose eviction would drop a running group below its
         # minMember are never preemption victims
-        from .gang import GangDirectory, preemption_protected
-
         self._gang_protected = preemption_protected(
             self._pods_all, GangDirectory(self.store))
-        evaluated = [n for n, _ in failed]
-        out = PreemptionOutcome(evaluated_nodes=evaluated)
+
+    def preempt(self, pod: dict, failed: list[tuple[str, str | None]],
+                failed_pass=None) -> PreemptionOutcome:
+        """failed: (node name, first failing plugin or None) for every node
+        evaluated in the failed scheduling cycle.  failed_pass: (that
+        cycle's CompiledWorkload, the pod's row in it), whose host arrays
+        the static rule reads; None: no node is taken for hopeless, and
+        every node with a lower-priority pod is dry-run."""
+        from ..cluster.store import list_shared
+
+        self._fit_cache.clear()
+        out = PreemptionOutcome(evaluated_nodes=[n for n, _ in failed])
         TRACER.count("preemption_attempts_total")
         # touched on every attempt, so that a reader of the counters can
         # tell "no node was screened out / probed" from "no such counter"
-        TRACER.count("preemption_screen_refused_nodes_total", 0)
-        TRACER.count("preemption_fit_probes_total", 0)
+        for counter in ("preemption_static_refused_nodes_total",
+                        "preemption_screen_dry_runs_total",
+                        "preemption_screen_refused_nodes_total",
+                        "preemption_fit_probes_total"):
+            TRACER.count(counter, 0)
 
         if ((pod.get("spec") or {}).get("preemptionPolicy") or "") == "Never":
             return out
@@ -352,25 +431,24 @@ class Preemptor:
         if not potential:
             return out
 
+        # from here on an attempt reads what it uses and no more: the
+        # pods, for the nodes that hold a lower-priority one; the static
+        # rule on those; the rest of the cluster only if a node is left
+        self._pods_all = list_shared(self.store, "pods")
         by_node: dict[str, list[dict]] = {}
         for p in self._pods_all:
             nn = (p.get("spec") or {}).get("nodeName")
-            if nn:
+            if nn and _priority(p) < pod_prio:
                 by_node.setdefault(nn, []).append(p)
-
         # a node without a lower-priority pod is no candidate (upstream's
-        # early return); the rest are screened in one pass, and only the
-        # nodes the screen cannot rule out are looked at one by one
-        lower_by_node = {}
-        for node in potential:
-            lower = [
-                p for p in by_node.get(node, ())
-                if _priority(p) < pod_prio
-                and _pod_key(p) not in self._gang_protected
-            ]
-            if lower:
-                lower_by_node[node] = lower
-        screened_out = self._screen(pod, lower_by_node) if lower_by_node else set()
+        # early return); the rest are screened, and only the nodes the
+        # screen cannot rule out are looked at one by one
+        lower_by_node = {node: by_node[node] for node in potential
+                         if node in by_node}
+        if lower_by_node:
+            lower_by_node = self._screen(pod, lower_by_node, failed_pass)
+        if not lower_by_node:
+            return out
 
         budget = _num_candidates(len(potential), self.min_candidate_pct,
                                  self.min_candidate_abs)
@@ -378,7 +456,7 @@ class Preemptor:
         for node in potential:
             if len(candidates) >= budget:
                 break
-            if node not in lower_by_node or node in screened_out:
+            if node not in lower_by_node:
                 continue
             found = self._victims_on(node, lower_by_node[node], pod)
             if found is not None:

@@ -100,6 +100,22 @@ def fit_filter(static: FitStatic, pod: FitPodXS, carry) -> jnp.ndarray:
     return res_code + jnp.where(too_many, 1, 0).astype(jnp.int32)
 
 
+def fit_refuses_empty(static: FitStatic, requests: np.ndarray) -> np.ndarray:
+    """[N] bool: fit_filter(static, pod, carry) != 0 on a carry of zeros,
+    in host numpy over the arrays build_fit was given (no device is read).
+
+    The carry only enters fit_filter as `allocatable - requested` and
+    `num_pods + 1` with requested, num_pods >= 0, so a node refused here is
+    refused under every carry: removing pods from it cannot help
+    (framework/preemption.py, "The screen").  Kept beside fit_filter: the
+    two are one expression (tests/test_fit_refuses_empty.py)."""
+    requests = np.asarray(requests)
+    insufficient = ((requests[None, :] > static.allocatable)
+                    & ~static.ignored[None, :]).any(axis=1)
+    # a pod that requests nothing is checked against the pod count only
+    return (insufficient & bool(requests.any())) | (1 > static.allowed_pods)
+
+
 def decode_fit_filter(code: int, schema: ResourceSchema) -> str:
     reasons = []
     if code & 1:

@@ -196,6 +196,9 @@ def compile_workload(
             fit_args=config.args.get("NodeResourcesFit"))
         statics["core"] = fit_static
         xs["core"] = fit_xs
+        # the fit check's host arrays, for PostFilter's static screen
+        # (framework/preemption.py _hopeless): references, no copy
+        host["fit"] = (fit_static, requests)
         from ..plugins.base import CoreCarry
 
         init_carry["core"] = CoreCarry(

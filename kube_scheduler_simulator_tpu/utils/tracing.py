@@ -252,6 +252,14 @@ _HELP: dict[str, str] = {
     "preemption_attempts_total":
         "PostFilter runs of DefaultPreemption: one per pod a pass found "
         "no feasible node for (framework/preemption.py).",
+    "preemption_static_refused_nodes_total":
+        "Nodes holding a lower-priority pod that PostFilter took out "
+        "before any dry run: NodeResourcesFit refuses the pod there even "
+        "on the empty node.",
+    "preemption_screen_dry_runs_total":
+        "Batched dry runs (one compile_workload + one filter-only replay "
+        "over every node) PostFilter made: none where the static rule "
+        "left no node.",
     "preemption_screen_refused_nodes_total":
         "Candidate nodes the batched dry run ruled out: refused, with all "
         "their lower-priority pods gone, by a plugin that reads the node "

@@ -10,7 +10,10 @@ three created, the churn pod awaited and read in full.
     reference in int32/float32 (the control) differs;
   * what the served path did on the way: two passes of one pod a cycle
     (the parked churn pod in no later pass), no per-node preemption probe,
-    one screen a cycle once a measured pod is bound;
+    one screen a cycle once a measured pod is bound, and in it no dry run:
+    every node that holds a pod is too small for the churn pod even when
+    empty (the static rule, PR 33), so the cycle's compile_workload calls
+    are its two passes' own;
   * the reference by itself: the churn node may sort anywhere, and what it
     refuses (NotCovered).
 """
@@ -44,7 +47,10 @@ PARAMS = json.loads(
 RESOURCE = {"Node": "nodes", "Pod": "pods", "Service": "services"}
 COUNTERS = ("scheduling_work_passes_total", "scheduling_pass_pods_total",
             "preemption_attempts_total", "preemption_fit_probes_total",
-            "pods_unschedulable_parked_total", "node_table_builds_total")
+            "pods_unschedulable_parked_total", "node_table_builds_total",
+            "preemption_static_refused_nodes_total",
+            "preemption_screen_dry_runs_total",
+            "preemption_screen_refused_nodes_total")
 FIT = ("Too many pods, Insufficient cpu, Insufficient memory")
 
 
@@ -101,6 +107,9 @@ def _serve(dep, cycles: int):
                                              "nodes": dep.nodes})[0] == 200
         assert _req(srv.port, "POST", path, {"pods": dep.initial_pods})[0] == 200
         before = TRACER.counter_totals()
+        spans_before = TRACER.snapshot()["spans"]
+        rebuilds_before = TRACER.labeled_totals(
+            "bound_carry_rebuilds_total", "reason")
         for k in range(cycles):
             for obj in live:
                 assert _req(srv.port, "DELETE", _obj_path(obj))[0] == 200
@@ -116,10 +125,28 @@ def _serve(dep, cycles: int):
             measured.append(_read_decided(
                 srv.port, pod["metadata"]["namespace"], pod["metadata"]["name"]))
         after = TRACER.counter_totals()
+        spans_after = TRACER.snapshot()["spans"]
+        rebuilds_after = TRACER.labeled_totals(
+            "bound_carry_rebuilds_total", "reason")
         services = _req(srv.port, "GET", "/api/v1/services")[1]["items"]
+        initial_on = [
+            _req(srv.port, "GET", f"/api/v1/pods/{p['metadata']['namespace']}"
+                 f"/{p['metadata']['name']}")[1]["spec"]["nodeName"]
+            for p in dep.initial_pods]
     finally:
         srv.shutdown()
     growth = {k: after.get(k, 0) - before.get(k, 0) for k in COUNTERS}
+    for name in ("compile_workload", "preempt_screen",
+                 "preempt_screen_dry_run", "replay_and_decode_stream"):
+        growth[f"span:{name}"] = (
+            (spans_after.get(name) or {}).get("count", 0)
+            - (spans_before.get(name) or {}).get("count", 0))
+    growth["uncarried"] = (rebuilds_after.get("uncarried", 0)
+                           - rebuilds_before.get("uncarried", 0))
+    # the nodes that held a pod when cycle k's churn pod was tried
+    growth["holding"] = sum(
+        len(set(initial_on) | {m["spec"]["nodeName"] for m in measured[:k]})
+        for k in range(cycles))
     assert [s["metadata"]["name"] for s in services] == [
         live[2]["metadata"]["name"]], "one churn service lives at a time"
     return measured, churned, pods, growth
@@ -179,6 +206,19 @@ def test_served_under_churn_equals_the_reference(seed, initial):
     assert growth["preemption_fit_probes_total"] == 0
     assert growth["pods_unschedulable_parked_total"] == cycles
     assert growth["node_table_builds_total"] >= cycles
+    # PostFilter: the screen's span opens on every attempt that has a node
+    # holding a pod; the static rule takes every such node (9 CPU fits no
+    # 4-CPU node), so no dry run: no third compile_workload, no filter-only
+    # replay, no throw-away carry
+    with_candidates = cycles if initial else cycles - 1
+    assert growth["span:preempt_screen"] == with_candidates
+    assert growth["preemption_static_refused_nodes_total"] == growth["holding"] > 0
+    assert growth["preemption_screen_dry_runs_total"] == 0
+    assert growth["preemption_screen_refused_nodes_total"] == 0
+    assert growth["span:preempt_screen_dry_run"] == 0
+    assert growth["span:compile_workload"] == 2 * cycles
+    assert growth["span:replay_and_decode_stream"] == 2 * cycles
+    assert growth["uncarried"] == 0
 
 
 # ---- the reference by itself ---------------------------------------------

@@ -11,6 +11,14 @@ status.nominatedNodeName and the same postfilter-result bytes.  One
 cluster's refusal is cross-node (required anti-affinity over a zone whose
 matching pods sit on other nodes): the screen, which removes every node's
 victims at once, must not decide it, and hands it to the per-node probe.
+
+Since PR 33 the screen starts with a static rule (a node too small even
+when EMPTY leaves before the batched dry run).  The same outcome must come
+out three ways: the per-node search, the screen without the rule (PR 32's)
+and the screen with it — on clusters that mix hopeless nodes, nodes only
+the dry run refuses and real candidates, and on one with more candidates
+than the budget, where a rule that shrank `potential` would pick another
+node.
 """
 
 from __future__ import annotations
@@ -31,6 +39,11 @@ from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 ZONE = "topology.kubernetes.io/zone"
 PROBES, SCREENED = ("preemption_fit_probes_total",
                     "preemption_screen_refused_nodes_total")
+STATIC, DRY_RUNS = ("preemption_static_refused_nodes_total",
+                    "preemption_screen_dry_runs_total")
+
+
+_PREEMPT = pre.Preemptor.preempt   # a test patches a spy over it, per run
 
 
 def _per_node_preempt(self, pod, failed):
@@ -148,20 +161,28 @@ def _anti_affinity_cluster() -> tuple[list[dict], list[dict], dict]:
     return nodes, bound, preemptor
 
 
-def _run(nodes, bound, preemptor, per_node: bool, monkeypatch):
-    """One engine pass over the preemptor -> what preemption did."""
+def _run(nodes, bound, preemptor, per_node: bool, monkeypatch,
+         static_rule: bool = True, cfg: PluginSetConfig | None = None):
+    """One engine pass over the preemptor -> what preemption did.
+    static_rule=False: the screen as of PR 32, every node with a
+    lower-priority pod in the batched dry run (no failed pass is handed
+    to the search, so it takes no node for hopeless)."""
     store = ObjectStore()
     for n in nodes:
         store.create("nodes", copy.deepcopy(n))
     for p in bound:
         store.create("pods", copy.deepcopy(p))
     store.create("pods", copy.deepcopy(preemptor))
-    engine = SchedulerEngine(store)
+    engine = SchedulerEngine(store, plugin_config=cfg)
     outcomes = []
-    search = _per_node_preempt if per_node else pre.Preemptor.preempt
+    search = _per_node_preempt if per_node else _PREEMPT
 
-    def spy(self, pod, failed):
-        out = search(self, pod, failed)
+    def spy(self, pod, failed, failed_pass=None):
+        if per_node or not static_rule:
+            out = search(self, pod, failed)
+        else:
+            assert failed_pass is not None, "the engine handed no pass over"
+            out = search(self, pod, failed, failed_pass=failed_pass)
         outcomes.append((out.nominated_node,
                          [pre._pod_key(v) for v in out.victims],
                          list(out.evaluated_nodes)))
@@ -183,6 +204,8 @@ def _run(nodes, bound, preemptor, per_node: bool, monkeypatch):
             ann.POST_FILTER_RESULT),
         "probes": after.get(PROBES, 0) - before.get(PROBES, 0),
         "screened": after.get(SCREENED, 0) - before.get(SCREENED, 0),
+        "static": after.get(STATIC, 0) - before.get(STATIC, 0),
+        "dry_runs": after.get(DRY_RUNS, 0) - before.get(DRY_RUNS, 0),
     }
 
 
@@ -206,6 +229,116 @@ def test_screen_equals_the_per_node_search(seed, monkeypatch):
     # the screen did work the per-node search did a node at a time
     assert new["probes"] < old["probes"]
     assert old["screened"] == 0
+
+
+def _mixed_cluster(seed: int) -> tuple[list[dict], list[dict], dict]:
+    """For a preemptor of 3 CPU and priority 50, in shuffled node order:
+    hopeless nodes (1-2 CPU, a low pod each), nodes only the dry run
+    refuses (4-6 CPU, a priority-90 pod that leaves less than 3 CPU and a
+    low pod beside it), real candidates (4-8 CPU filled by low pods of
+    mixed priorities) and nodes without a lower-priority pod."""
+    rng = np.random.default_rng(seed)
+    kinds = (["hopeless"] * int(rng.integers(2, 5))
+             + ["dry-run"] * int(rng.integers(2, 4))
+             + ["candidate"] * int(rng.integers(2, 5))
+             + ["no-lower"] * int(rng.integers(1, 3)))
+    rng.shuffle(kinds)
+    nodes, bound = [], []
+    for j, kind in enumerate(kinds):
+        name = f"n{j:02d}"
+        stamp = f"2024-01-01T00:00:{int(rng.integers(10, 59))}Z"
+        if kind == "hopeless":
+            cpu = int(rng.integers(1, 3))
+            bound.append(_pod(f"low-{j}", 500, int(rng.choice([0, 10])),
+                              node=name, created=stamp))
+        elif kind == "dry-run":
+            cpu = int(rng.integers(4, 7))
+            bound.append(_pod(f"high-{j}", (cpu - 2) * 1000, 90, node=name))
+            bound.append(_pod(f"low-{j}", 1500, 0, node=name, created=stamp))
+        elif kind == "candidate":
+            cpu = int(rng.integers(4, 9))
+            left = cpu * 1000
+            for i in range(int(rng.integers(1, 4))):
+                want = left if i == 2 else int(rng.integers(2, 5)) * 500
+                want = min(want, left)
+                if not want:
+                    break
+                left -= want
+                bound.append(_pod(f"low-{j}-{i}", want,
+                                  int(rng.choice([0, 10, 20])), node=name,
+                                  created=stamp))
+            if left:  # no room as it stands
+                bound.append(_pod(f"fill-{j}", left, 20, node=name))
+        else:
+            cpu = 4
+            bound.append(_pod(f"high-{j}", 3500, 90, node=name))
+        nodes.append(_node(name, cpu, f"z{j % 3}"))
+    return nodes, bound, _pod("preemptor", 3000, 50)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 5, 13, 2147483777])
+def test_static_rule_dry_run_and_per_node_search_agree(seed, monkeypatch):
+    nodes, bound, preemptor = _mixed_cluster(seed)
+    new = _run(nodes, bound, preemptor, False, monkeypatch)
+    pr32 = _run(nodes, bound, preemptor, False, monkeypatch, static_rule=False)
+    old = _run(nodes, bound, preemptor, True, monkeypatch)
+    _same(new, pr32)
+    _same(new, old)
+    assert new["outcomes"][0][0] and new["outcomes"][0][1]
+    # every kind of node was there, and each went where it should: the
+    # hopeless ones never reached the dry run, which refused the rest
+    hopeless = sum(1 for n in nodes
+                   if int(n["status"]["allocatable"]["cpu"]) < 3)
+    assert hopeless and new["static"] == hopeless
+    assert new["screened"] > 0 and new["dry_runs"] == 1
+    assert pr32["static"] == 0
+    assert pr32["screened"] == new["static"] + new["screened"]
+    assert new["probes"] == pr32["probes"] < old["probes"]
+
+
+def _over_budget_cluster() -> tuple[list[dict], list[dict], dict]:
+    """25 nodes, every one refused by Fit as it stands, so `potential` is
+    25 and a budget of 20% is 5 candidates.  In node order: 10 hopeless
+    (2 CPU), 3 only the dry run refuses, 6 candidates whose lone victim
+    has priority 40, 40, 40, 40, 10, 0, then 6 without a lower pod.  With
+    5 candidates the fifth wins (lowest victim priority of those looked
+    at); had the hopeless nodes left `potential`, the budget would be 3
+    and the first would win; a search blind to the budget takes the
+    sixth."""
+    nodes, bound = [], []
+    for j in range(25):
+        name = f"n{j:02d}"
+        nodes.append(_node(name, 2 if j < 10 else 4, f"z{j % 3}"))
+        if j < 10:
+            bound.append(_pod(f"low-{j}", 1000, 0, node=name))
+        elif j < 13:
+            bound.append(_pod(f"high-{j}", 2000, 90, node=name))
+            bound.append(_pod(f"low-{j}", 1500, 0, node=name))
+        elif j < 19:
+            bound.append(_pod(f"victim-{j}", 3500,
+                              (40, 40, 40, 40, 10, 0)[j - 13], node=name))
+        else:
+            bound.append(_pod(f"high-{j}", 3500, 90, node=name))
+    return nodes, bound, _pod("preemptor", 3000, 50)
+
+
+def test_hopeless_nodes_still_count_toward_the_candidate_budget(monkeypatch):
+    nodes, bound, preemptor = _over_budget_cluster()
+    cfg = PluginSetConfig(args={"DefaultPreemption": {
+        "minCandidateNodesPercentage": 20, "minCandidateNodesAbsolute": 1}})
+    runs = [_run(nodes, bound, preemptor, per_node, monkeypatch,
+                 static_rule=rule, cfg=cfg)
+            for per_node, rule in ((False, True), (False, False), (True, True))]
+    new, pr32, old = runs
+    _same(new, pr32)
+    _same(new, old)
+    assert new["outcomes"][0][:2] == ("n17", ["default/victim-17"])
+    assert len(new["outcomes"][0][2]) == 25
+    assert (new["static"], new["screened"], new["dry_runs"]) == (10, 3, 1)
+    assert (pr32["static"], pr32["screened"], pr32["dry_runs"]) == (0, 13, 1)
+    # five candidates looked at, one probe to admit each and one to find
+    # its lone victim cannot be reprieved
+    assert new["probes"] == pr32["probes"] == 10
 
 
 def test_some_seed_screens_a_node_out(monkeypatch):

@@ -23,10 +23,12 @@ from kube_scheduler_simulator_tpu.utils import tracing
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
 DOCS = Path(__file__).resolve().parent.parent / "docs"
-SPANS = ("postfilter", "preempt_screen", "preempt_probe")
+SPANS = ("postfilter", "preempt_screen", "preempt_probe",
+         "preempt_screen_dry_run")
 COUNTERS = ("preemption_attempts_total", "preemption_screen_refused_nodes_total",
             "preemption_fit_probes_total", "pods_unschedulable_parked_total",
-            "pods_requeued_total")
+            "pods_requeued_total", "preemption_static_refused_nodes_total",
+            "preemption_screen_dry_runs_total")
 
 
 def _service(name: str, ns: str = "default") -> dict:
@@ -125,30 +127,86 @@ def _pod(name: str, cpu: str, prio: int, node: str | None = None) -> dict:
     return p
 
 
-def test_spans_and_counters_where_the_docs_say():
-    """n0 is too small even emptied (screened out); n1 admits the pod once
-    its low pod goes (probed, nominated); n2 holds no lower pod (no look)."""
+def _attempt(nodes, pods, preemptor_cpu: str):
+    """One pass over a preemptor of priority 50 -> (counter totals, span
+    aggregates, the preemptor as stored)."""
     store = ObjectStore()
-    for name, cpu in (("n0", "2"), ("n1", "4"), ("n2", "4")):
+    for name, cpu in nodes:
         store.create("nodes", _node(name, cpu))
-    for name, cpu, prio, node in (("low-0", "1", 0, "n0"), ("low-1", "3", 0, "n1"),
-                                  ("high-2", "3", 90, "n2")):
+    for name, cpu, prio, node in pods:
         store.create("pods", _pod(name, cpu, prio, node))
-    store.create("pods", _pod("preemptor", "3", 50))
+    store.create("pods", _pod("preemptor", preemptor_cpu, 50))
     engine = SchedulerEngine(store)
     TRACER.reset()
     engine.schedule_pending()
     engine.close()
-    totals = TRACER.counter_totals()
+    return (TRACER.counter_totals(), TRACER.snapshot()["spans"],
+            store.get("pods", "preemptor", "default"))
+
+
+def test_spans_and_counters_where_the_docs_say():
+    """n0 is too small even when empty (the static rule, no dry run for it);
+    n1 admits the pod once its low pod goes (probed, nominated); n2 holds
+    no lower pod (no look); n3 is large enough empty, but its high pod
+    stays (refused by the batched dry run)."""
+    totals, spans, me = _attempt(
+        (("n0", "2"), ("n1", "4"), ("n2", "4"), ("n3", "4")),
+        (("low-0", "1", 0, "n0"), ("low-1", "3", 0, "n1"),
+         ("high-2", "3", 90, "n2"), ("high-3", "2", 90, "n3"),
+         ("low-3", "1", 0, "n3")), "3")
     assert totals["preemption_attempts_total"] == 1
-    assert totals["preemption_screen_refused_nodes_total"] == 1   # n0
+    assert totals["preemption_static_refused_nodes_total"] == 1   # n0
+    assert totals["preemption_screen_dry_runs_total"] == 1
+    assert totals["preemption_screen_refused_nodes_total"] == 1   # n3
     # n1: all lower pods gone -> fits; reprieve low-1 -> does not
     assert totals["preemption_fit_probes_total"] == 2
-    spans = TRACER.snapshot()["spans"]
     assert spans["postfilter"]["count"] == 1
     assert spans["preempt_screen"]["count"] == 1
+    assert spans["preempt_screen_dry_run"]["count"] == 1
     assert spans["preempt_probe"]["count"] == 2
-    assert store.get("pods", "preemptor", "default")["spec"]["nodeName"] == "n1"
+    assert me["spec"]["nodeName"] == "n1"
+
+
+def test_an_attempt_whose_every_candidate_is_hopeless_makes_no_dry_run():
+    """Three nodes hold a lower-priority pod and none is large enough even
+    when empty: the screen's span opens, the static rule takes all three,
+    and the pass's own compile_workload and replay stay the attempt's
+    only ones."""
+    totals, spans, me = _attempt(
+        (("n0", "4"), ("n1", "4"), ("n2", "2"), ("n3", "4")),
+        (("low-0", "3", 0, "n0"), ("low-1", "1", 0, "n1"),
+         ("low-2", "1", 10, "n2"), ("high-3", "1", 90, "n3")), "9")
+    assert totals["preemption_attempts_total"] == 1
+    assert totals["preemption_static_refused_nodes_total"] == 3
+    assert totals["preemption_screen_dry_runs_total"] == 0
+    assert totals["preemption_screen_refused_nodes_total"] == 0
+    assert totals["preemption_fit_probes_total"] == 0
+    assert spans["preempt_screen"]["count"] == 1
+    assert "preempt_screen_dry_run" not in spans
+    assert "preempt_probe" not in spans
+    # one compile_workload, one replay: the failed pass's own
+    assert spans["compile_workload"]["count"] == 1
+    assert spans["replay_and_decode_stream"]["count"] == 1
+    assert not me["spec"].get("nodeName")
+    assert not (me.get("status") or {}).get("nominatedNodeName")
+    entries = json.loads(me["metadata"]["annotations"][
+        "kube-scheduler-simulator.sigs.k8s.io/postfilter-result"])
+    assert entries == {n: {} for n in ("n0", "n1", "n2", "n3")}
+
+
+def test_an_attempt_with_one_survivor_makes_exactly_one_dry_run():
+    """n1 is the one node an empty Fit check admits: one batched dry run,
+    over n1 alone (the hopeless nodes' pods stay in its cluster)."""
+    totals, spans, me = _attempt(
+        (("n0", "2"), ("n1", "4"), ("n2", "1")),
+        (("low-0", "1", 0, "n0"), ("low-1", "3", 0, "n1"),
+         ("low-2", "1", 0, "n2")), "3")
+    assert totals["preemption_static_refused_nodes_total"] == 2
+    assert totals["preemption_screen_dry_runs_total"] == 1
+    assert totals["preemption_screen_refused_nodes_total"] == 0
+    assert spans["preempt_screen"]["count"] == 1
+    assert spans["preempt_screen_dry_run"]["count"] == 1
+    assert me["spec"]["nodeName"] == "n1"
 
 
 def test_an_attempt_without_candidates_reports_zero_probes():
