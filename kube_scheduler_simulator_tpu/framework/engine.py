@@ -280,6 +280,9 @@ class _WaveCommitter:
         # the worker inherits the engine's session scope (its own thread:
         # thread-local scopes don't cross the boundary by themselves)
         self._session = getattr(engine, "session", None)
+        # ... and the wave's trace id: its commits, and the decisions
+        # they stamp, belong to the request that caused the wave
+        self._trace = TRACER.current_trace()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="commit-stream")
         self._thread.start()
@@ -363,7 +366,8 @@ class _WaveCommitter:
     # ---------------------------------------------- worker-thread side
 
     def _run(self) -> None:
-        with TRACER.session_scope(self._session):
+        with TRACER.session_scope(self._session), \
+                TRACER.trace_scope(self._trace):
             while True:
                 item = self._q.get()
                 if item is None:
@@ -507,6 +511,12 @@ class SchedulerEngine:
         # event, stamped by the scheduling loop's watch thread
         # (note_arrival) and taken by the first wave that takes the pod
         self._arrivals: dict[tuple[str, str], float] = {}
+        # the session's decision stamps (services/resourcewatcher.py
+        # DecisionStamps, set by the DIContainer): every bind and
+        # Unschedulable mark is stamped before the store publishes it, so
+        # the watch stream and the pod's first read can say how long the
+        # decision took to leave; None for direct engine use
+        self.decisions = None
         # the caller's unschedulable set inside queued_by() (framework/
         # unschedulable.py), and the pods the running pass's waves have
         # taken; both None for direct engine use
@@ -1000,9 +1010,13 @@ class SchedulerEngine:
                         meta.get("name", ""))] = time.perf_counter()
 
     def forget_arrival(self, pod: dict) -> None:
+        """The loop's watch thread saw the pod's DELETED event: neither
+        its queue-wait stamp nor its decision stamp has a taker now."""
         meta = pod.get("metadata") or {}
-        self._arrivals.pop((meta.get("namespace") or "default",
-                            meta.get("name", "")), None)
+        ns, name = meta.get("namespace") or "default", meta.get("name", "")
+        self._arrivals.pop((ns, name), None)
+        if self.decisions is not None:
+            self.decisions.forget(ns, name)
 
     def _count_pass(self, pending: list[dict], now: float) -> None:
         """A wave that takes pods counts itself and them
@@ -2794,7 +2808,15 @@ class SchedulerEngine:
 
         return mutate
 
+    def _stamp_decisions(self, keys) -> None:
+        """keys: (ns, name) of pods whose bind or Unschedulable mark is
+        about to be written.  BEFORE the write: the watch pump may send
+        the event the moment the store publishes it."""
+        if self.decisions is not None:
+            self.decisions.stamp(keys)
+
     def _bind(self, ns: str, name: str, node_name: str) -> None:
+        self._stamp_decisions([(ns or "default", name)])
         self._update_pod(ns, name, self._bind_mutation(node_name))
 
     def _node_count(self, fresh: bool = False) -> int:
@@ -2844,6 +2866,8 @@ class SchedulerEngine:
                     self._mark_unschedulable(ns, name)
             return bound
         unsched = None if bound == len(items) else self._unschedulable_mutation()
+        self._stamp_decisions([(ns or "default", name)
+                               for ns, name, _node in items])
         self.store.apply_batch("pods", [
             (name, ns, self._bind_mutation(node) if node else unsched)
             for ns, name, node in items
@@ -2875,5 +2899,6 @@ class SchedulerEngine:
 
     def _mark_unschedulable(self, ns: str, name: str,
                             fresh_node_count: bool = False) -> None:
+        self._stamp_decisions([(ns or "default", name)])
         self._update_pod(
             ns, name, self._unschedulable_mutation(fresh_node_count))

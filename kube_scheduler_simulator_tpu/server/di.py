@@ -41,7 +41,7 @@ from ..services.recorder import RecorderService
 from ..services.replayer import ReplayerService
 from ..services.reset import ResetService
 from ..services.resourceapplier import ResourceApplier
-from ..services.resourcewatcher import ResourceWatcherService
+from ..services.resourcewatcher import DecisionStamps, ResourceWatcherService
 from ..services.snapshot import SnapshotService
 from ..services.syncer import SyncerService
 from ..store.reflector import StoreReflector
@@ -284,6 +284,11 @@ class DIContainer:
         self.reflector = StoreReflector(self.store)
         self.engine = SchedulerEngine(self.store, reflector=self.reflector)
         self.engine.session = session
+        # the decision's way out (docs/metrics.md): stamped by the engine
+        # at the commit, closed by this session's watch streams and its
+        # pod reads
+        self.decisions = DecisionStamps()
+        self.engine.decisions = self.decisions
         initial_scheduler_cfg = self.cfg.initial_scheduler_config()
         self.scheduler_service = SchedulerService(self.engine, initial_scheduler_cfg)
         self.snapshot_service = SnapshotService(self.store, self.scheduler_service)

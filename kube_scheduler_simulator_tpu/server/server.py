@@ -60,6 +60,7 @@ import contextlib
 import json
 import re
 import threading
+import time
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
@@ -67,6 +68,7 @@ from urllib.parse import parse_qs, urlparse
 from ..cluster.store import ApiError
 from ..services.resourcewatcher import StreamWriter, WATCH_PARAMS
 from ..services.snapshot import SnapshotOptions
+from ..utils.tracing import TRACER
 from .di import DIContainer
 from .sessions import SessionManager, StreamRegistry
 
@@ -170,21 +172,25 @@ def _make_handler(server: SimulatorServer):
                 self.send_header("Access-Control-Allow-Headers", "Content-Type")
 
         def _json(self, code: int, obj=None, headers=None):
-            body = b"" if obj is None else json.dumps(obj).encode()
-            self.send_response(code)
-            self._cors()
-            self.send_header("Content-Type", "application/json")
-            self.send_header("Content-Length", str(len(body)))
-            for k, v in (headers or {}).items():
-                self.send_header(k, str(v))
-            # echo the request's trace id (minted or client-supplied)
-            # so the submitter can later query /api/v1/trace?trace_id=
-            tid = getattr(self, "trace_id", None)
-            if tid:
-                self.send_header("X-KSS-Trace-Id", tid)
-            self.end_headers()
-            if body:
-                self.wfile.write(body)
+            # two children of whichever request span is open: a full pod
+            # read is megabytes through both (docs/metrics.md)
+            with TRACER.span("http_encode"):
+                body = b"" if obj is None else json.dumps(obj).encode()
+            with TRACER.span("http_send"):
+                self.send_response(code)
+                self._cors()
+                self.send_header("Content-Type", "application/json")
+                self.send_header("Content-Length", str(len(body)))
+                for k, v in (headers or {}).items():
+                    self.send_header(k, str(v))
+                # echo the request's trace id (minted or client-supplied)
+                # so the submitter can later query /api/v1/trace?trace_id=
+                tid = getattr(self, "trace_id", None)
+                if tid:
+                    self.send_header("X-KSS-Trace-Id", tid)
+                self.end_headers()
+                if body:
+                    self.wfile.write(body)
 
         def _body(self):
             length = int(self.headers.get("Content-Length") or 0)
@@ -253,7 +259,6 @@ def _make_handler(server: SimulatorServer):
                 # session-scoped observability: the prefix pins the
                 # filter; bare /api/v1/trace|metrics take ?session=
                 self.routed_sid = routed_sid
-                from ..utils.tracing import TRACER
 
                 # trace correlation (docs/metrics.md): workload-
                 # submitting requests get a trace id — the client's
@@ -284,7 +289,14 @@ def _make_handler(server: SimulatorServer):
                         with TRACER.span("http_import"):
                             return self._dispatch(method, path, url)
                     if method == "GET" and path.startswith("/api/v1/pods/"):
-                        with TRACER.span("http_pod_read"):
+                        # the first read of a decided pod closes
+                        # decision_to_read and runs under the trace id
+                        # of the wave that decided it
+                        ns, _, name = path[len("/api/v1/pods/"):].rpartition("/")
+                        tid = self.di.decisions.first_read(
+                            ns, name, time.perf_counter())
+                        with TRACER.trace_scope(tid), \
+                                TRACER.span("http_pod_read"):
                             return self._dispatch(method, path, url)
                     return self._dispatch(method, path, url)
             except ApiError as e:
@@ -309,8 +321,6 @@ def _make_handler(server: SimulatorServer):
 
                 shed, retry = CONTROLS.shed_state(self.sess.id)
                 if shed:
-                    from ..utils.tracing import TRACER
-
                     # drain the body first: answering while a client is
                     # still sending a snapshot resets its connection
                     # (EPIPE instead of the 429), and on keep-alive the
@@ -336,8 +346,6 @@ def _make_handler(server: SimulatorServer):
             if path in ("/healthz", "/readyz") and method == "GET":
                 return self._health(path)
             if path == "/api/v1/metrics" and method == "GET":
-                from ..utils.tracing import TRACER
-
                 sid = self._session_filter(url)
                 return self._json(200, TRACER.snapshot(session=sid))
             if path == "/api/v1/metrics/stream" and method == "GET":
@@ -456,7 +464,8 @@ def _make_handler(server: SimulatorServer):
             def write_chunk(data: bytes):
                 self.wfile.write(f"{len(data):X}\r\n".encode() + data + b"\r\n")
 
-            stream = StreamWriter(write_chunk, self.wfile.flush)
+            stream = StreamWriter(write_chunk, self.wfile.flush,
+                                  decisions=self.di.decisions)
             # server shutdown and session eviction both fire this stop,
             # so the watch ends promptly instead of pumping a dead store
             stop = threading.Event()
@@ -488,8 +497,6 @@ def _make_handler(server: SimulatorServer):
             return self._json(200, result)
 
         def _metrics_text(self):
-            from ..utils.tracing import TRACER
-
             body = TRACER.prometheus_text().encode()
             self.send_response(200)
             self._cors()
@@ -504,7 +511,7 @@ def _make_handler(server: SimulatorServer):
             scheduling (additive observability, SURVEY.md §5).  Invalid
             state transitions (double start, stop without start) are a
             409 Conflict with a JSON error body, never a 500."""
-            from ..utils.tracing import TRACER, ProfileStateError
+            from ..utils.tracing import ProfileStateError
 
             body = self._body() or {}
             action = body.get("action")
@@ -536,8 +543,6 @@ def _make_handler(server: SimulatorServer):
             plus metadata of recently stored dumps (wave aborts write
             theirs to KSS_TPU_BLACKBOX_DIR)."""
             from ..utils.blackbox import BLACKBOX
-            from ..utils.tracing import TRACER
-
             sid = self._session_filter(url)
             doc = BLACKBOX.bundle("request", session=sid,
                                   device=server.owns_device)
@@ -592,8 +597,6 @@ def _make_handler(server: SimulatorServer):
             # a silently-truncating span ring defeats the history /
             # provenance claims: surface evictions the moment they start
             # (KSS_TPU_TRACER_CAPACITY grows the ring)
-            from ..utils.tracing import TRACER
-
             dropped = TRACER.dropped_events()
             if dropped:
                 body["tracerDroppedEvents"] = int(dropped)
@@ -614,8 +617,6 @@ def _make_handler(server: SimulatorServer):
             docs/metrics.md walkthrough reads a pipelined wave).
             session= (or the /api/v1/sessions/<id>/trace alias) keeps
             only spans recorded under that session's scope."""
-            from ..utils.tracing import TRACER
-
             params = parse_qs(url.query)
             limit = None
             v = params.get("limit", [""])[0]
@@ -667,8 +668,6 @@ def _make_handler(server: SimulatorServer):
             `count` events were sent (count=0: unbounded), or the server
             (or this stream's session) shuts down — the inter-event wait
             rides a stop event, never a bare sleep."""
-            from ..utils.tracing import TRACER
-
             params = parse_qs(url.query)
             try:
                 interval = float(params.get("interval", ["5"])[0])

@@ -275,6 +275,28 @@ _HELP: dict[str, str] = {
         "Parked pods handed back to the pending list, by reason: event (a "
         "cluster event moved it after its backoff), backoff (moved, then "
         "waited the backoff out), flush (5 minutes parked).",
+    # the decision's way out (docs/metrics.md): span families
+    "span_decision_delivery":
+        "From the engine writing a pod's bind or Unschedulable mark to the "
+        "first watch stream's socket write of that decision returning "
+        "(timed across threads; not a TraceMe in a profile).",
+    "span_decision_to_read":
+        "From a decision's delivery on a watch stream to the pod's first "
+        "GET reaching its handler: the client's turn-around and the "
+        "network, as the server sees them (not a TraceMe in a profile).",
+    "span_watch_flush":
+        "The watch pump draining a pod's deferred annotations before it "
+        "sends the pod's event (store.materialize_reads).",
+    "span_watch_encode":
+        "Filling and JSON-encoding one watch event (child of watch_write).",
+    "span_watch_send":
+        "Stream lock wait + socket write + flush of one watch event "
+        "(child of watch_write): a client that reads slowly shows here.",
+    "span_http_encode":
+        "JSON-encoding a response body (child of the request's span).",
+    "span_http_send":
+        "Status line, headers and body written to the socket (child of "
+        "the request's span).",
 }
 
 _NAME_SANITIZE_RE = re.compile(r"[^a-zA-Z0-9_:]")
@@ -516,18 +538,23 @@ class Tracer:
         return annotate
 
     def record_span(self, name: str, t0: float, seconds: float,
-                    **attrs) -> None:
+                    session: str | None = None, **attrs) -> None:
         """A span that was timed elsewhere (t0 on time.perf_counter's
         clock) enters the ring and the aggregates after the fact: for
         sources that may not take this lock while they observe (a GC
-        callback; utils/hostevents.py hands its pauses over this way)."""
+        callback; utils/hostevents.py hands its pauses over this way)
+        and for stretches that start on one thread and end on another
+        (services/resourcewatcher.py DecisionStamps).  `session` files
+        it under that session's aggregates as a session scope would."""
+        if session is not None:
+            attrs["session"] = session
         with self._lock:
             self._record_locked({
                 "name": name, "t": time.time(), "seconds": seconds,
                 "ts": round(t0 - self._perf_epoch, 6),
                 "span_id": next(self._ids), "parent_id": None,
                 "tid": self._tid(), **attrs,
-            }, None)
+            }, session)
 
     def _record_locked(self, event: dict, session: str | None) -> None:
         """A finished span enters the ring and the per-name aggregates

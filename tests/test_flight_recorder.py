@@ -214,7 +214,13 @@ def test_cold_read_pays_one_decode_and_fetch_for_its_chunk():
 
 
 def test_perfetto_export_schema_and_pipeline_overlap():
-    _pipelined_wave()
+    # ten chunks, not three: with the scan already compiled (an earlier
+    # test of this file ran the same wave) the replay span is ~40 ms and
+    # the first commit starts ~20 ms into it, the third within ~2 ms of
+    # its end; on a loaded machine the worker thread can be handed the
+    # GIL later than that, and no commit STARTED inside the span.  With
+    # ten the first commit starts with 60% of the span still to go
+    _pipelined_wave(n_pods=160)
     doc = TRACER.perfetto()
     evs = doc["traceEvents"]
     metas = [e for e in evs if e["ph"] == "M"]

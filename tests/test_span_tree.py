@@ -294,12 +294,97 @@ def test_served_requests_and_the_watch_stream_have_spans(live_server):
     t.join(timeout=5)
     snap = TRACER.snapshot()
     for name in ("http_import", "http_pod_create", "http_pod_read",
-                 "watch_write"):
+                 "watch_write", "watch_flush", "watch_encode", "watch_send",
+                 "http_encode", "http_send"):
         assert snap["spans"][name]["count"] >= 1, name
     assert snap["counters"]["watch_bytes_sent_total"] > 0
     # the create's span carries the request's trace id
     ev = [e for e in TRACER.events(4096) if e["name"] == "http_pod_create"]
     assert ev and ev[-1].get("trace_id", "").startswith("t-")
+
+
+def _watch_until_bound(base, name, ready, done):
+    """Hold a watch stream open until `name` shows bound on it."""
+    with urllib.request.urlopen(base + "/api/v1/listwatchresources",
+                                timeout=60) as resp:
+        ready.set()
+        buf = b""
+        while not done.is_set():
+            buf += resp.read1(1 << 16)
+            if (b'"name": "%s"' % name.encode()) in buf \
+                    and b'"nodeName": "' in buf.rpartition(
+                        b'"name": "%s"' % name.encode())[2]:
+                done.set()
+
+
+def test_the_served_tree_past_the_commit(live_server, tmp_path):
+    """docs/metrics.md "The decision's way out": the pump's and the
+    handlers' new spans hang where the tree says, all of them are kss:
+    TraceMe events in a profile, and the two stretches timed across
+    threads are ring and aggregate only."""
+    from jax.profiler import ProfileData
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmark"))
+    from lib import xplane_spans
+
+    _, base = live_server
+    _http(base, "POST", "/api/v1/import",
+          {"nodes": make_nodes(2, seed=61), "pods": []})
+    pod = make_pods(1, seed=63)[0]
+    name = pod["metadata"]["name"] = "past-the-commit"
+    ns = pod["metadata"].setdefault("namespace", "default")
+    ready, done = threading.Event(), threading.Event()
+    t = threading.Thread(target=_watch_until_bound,
+                         args=(base, name, ready, done), daemon=True)
+    t.start()
+    assert ready.wait(20)
+    seen = {e["span_id"] for e in TRACER.events(4096)}
+    TRACER.start_xla_profile(str(tmp_path), python_tracer=False)
+    try:
+        _http(base, "POST", "/api/v1/pods", pod)
+        assert done.wait(120), "the decision never showed on the stream"
+        _http(base, "GET", f"/api/v1/pods/{ns}/{name}")
+        # a request's span closes after its last byte, the pump notes
+        # the delivery after its write returns, the wave's tail runs on
+        # after the commit: the client is back before they are recorded
+        last_in = {"http_pod_read", "wave", "decision_delivery",
+                   "decision_to_read"}
+        deadline = time.time() + 10
+        while not last_in <= {e["name"] for e in TRACER.events(4096)
+                              if e["span_id"] not in seen}:
+            assert time.time() < deadline
+            time.sleep(0.01)
+    finally:
+        TRACER.stop_xla_profile()
+        t.join(timeout=5)
+    evs = [e for e in TRACER.events(4096) if e["span_id"] not in seen]
+    by_id = {e["span_id"]: e for e in evs}
+
+    def parents(child):
+        return {by_id[e["parent_id"]]["name"] if e["parent_id"] in by_id
+                else None for e in evs if e["name"] == child}
+
+    assert parents("watch_encode") == {"watch_write"}
+    assert parents("watch_send") == {"watch_write"}
+    assert parents("watch_flush") == {None}  # a root on the pump's thread
+    assert {"http_pod_create", "http_pod_read"} <= parents("http_encode")
+    assert parents("http_encode") == parents("http_send")
+    # the two retroactive spans: one each, roots, the wave's trace id
+    wave = [e for e in evs if e["name"] == "wave"][-1]
+    for retro in ("decision_delivery", "decision_to_read"):
+        got = [e for e in evs if e["name"] == retro]
+        assert len(got) == 1 and got[0]["parent_id"] is None, (retro, got)
+        assert got[0]["trace_id"] == wave["trace_id"]
+        assert got[0]["session"] == "default"
+    read = [e for e in evs if e["name"] == "http_pod_read"][-1]
+    assert read["trace_id"] == wave["trace_id"]
+    pd = ProfileData.from_file(str(xplane_spans.find_xplane(tmp_path)))
+    kss = {e.name for plane in pd.planes for ln in plane.lines
+           for e in ln.events if e.name.startswith("kss:")}
+    assert {"kss:watch_flush", "kss:watch_write", "kss:watch_encode",
+            "kss:watch_send", "kss:http_encode", "kss:http_send",
+            "kss:http_pod_read"} <= kss, kss
+    assert not {"kss:decision_delivery", "kss:decision_to_read"} & kss
 
 
 def test_profile_route_takes_python_tracer_false(live_server, monkeypatch):

@@ -218,6 +218,9 @@ def test_parked_after_the_mark_and_absent_from_the_next_pass(rig):
     assert _count("pods_unschedulable_parked_total") == 1
     assert len(loop.unschedulable) == 1
     # a direct call is the caller's own queue: it takes every pending pod
+    # (once the loop's pass, whose tail runs on after the bind shows, has
+    # left queued_by: inside it the engine's queue is the loop's)
+    _wait(lambda: engine._queue is None, "the loop's pass never ended")
     assert [p["metadata"]["name"] for p in engine.pending_pods()] == ["big"]
 
 
