@@ -140,16 +140,17 @@ def build(table: NodeTable, pods: list[dict],
         # decoder emits no annotations for skipped scorers).
         host_out.setdefault("static_score_rows", {})[NAME] = (
             np.ascontiguousarray(np.take(pref_mat, pref_idx, axis=0)))
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = NodeAffinityStatic(
         req_rows=np.stack(req_pool),
         pref_rows=pref_mat,
     )
     return static, NodeAffinityXS(
-        req_idx=jnp.asarray(req_idx),
-        pref_idx=jnp.asarray(pref_idx),
-        filter_skip=jnp.asarray(filter_skip),
-        score_skip=jnp.asarray(score_skip),
+        req_idx=req_idx,
+        pref_idx=pref_idx,
+        filter_skip=filter_skip,
+        score_skip=score_skip,
     )
 
 

@@ -155,4 +155,4 @@ def build_custom(plugin: CustomPlugin, table, pods: list[dict], node_manifests: 
         # compact replay reads this host copy instead of transferring the
         # row back from the device (framework/replay.py "host" group)
         host_out.setdefault("static_score_rows", {})[name] = scores
-    return CustomXS(codes=jnp.asarray(codes), scores=jnp.asarray(scores)), msgs
+    return CustomXS(codes=codes, scores=scores), msgs

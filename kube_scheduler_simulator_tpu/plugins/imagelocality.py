@@ -118,7 +118,7 @@ def build(table, nodes: list[dict], pods: list[dict],
         # score_kernel is a pure pass-through of this precompiled row: the
         # compact replay keeps it host-resident ("host" group, no D2H)
         host_out.setdefault("static_score_rows", {})[NAME] = score
-    return ImageXS(score=jnp.asarray(score))
+    return ImageXS(score=score)
 
 
 def score_kernel(sl: ImageXS) -> jnp.ndarray:

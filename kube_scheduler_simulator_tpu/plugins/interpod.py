@@ -271,16 +271,17 @@ def build(table: NodeTable, pods: list[dict], bound,
         dtype=bool,
     )
 
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = InterPodStatic(dom_idx=dom_idx, hard_weight=np.int64(hard_weight))
     xs = InterPodXS(
-        t_matches=jnp.asarray(t_matches),
-        h_req_aff=jnp.asarray(h_req_aff),
-        h_req_anti=jnp.asarray(h_req_anti),
-        h_pref_aff_w=jnp.asarray(h_pref_aff_w),
-        h_pref_anti_w=jnp.asarray(h_pref_anti_w),
-        self_ok=jnp.asarray(self_ok),
-        filter_skip=jnp.asarray(filter_skip),
+        t_matches=t_matches,
+        h_req_aff=h_req_aff,
+        h_req_anti=h_req_anti,
+        h_pref_aff_w=h_pref_aff_w,
+        h_pref_anti_w=h_pref_anti_w,
+        self_ok=self_ok,
+        filter_skip=filter_skip,
     )
 
     # --- bound pods -> the carry's per-(term, domain) counts ---------------
@@ -313,9 +314,9 @@ def assemble_carry(dom: np.ndarray, dom_mats: dict) -> InterPodCarry:
     node-space device carry (one take_along_axis per mat, on host)."""
     safe = np.maximum(dom, 0)
 
-    def to_nodes(mat: np.ndarray) -> jnp.ndarray:
+    def to_nodes(mat: np.ndarray) -> np.ndarray:
         vals = np.take_along_axis(mat, safe, axis=1)
-        return jnp.asarray(np.where(dom >= 0, vals, 0).astype(np.int32))
+        return np.where(dom >= 0, vals, 0).astype(np.int32)
 
     return InterPodCarry(
         matched=to_nodes(dom_mats["matched"]),
@@ -323,8 +324,7 @@ def assemble_carry(dom: np.ndarray, dom_mats: dict) -> InterPodCarry:
         have_req_aff=to_nodes(dom_mats["have_req_aff"]),
         sym_pref_aff=to_nodes(dom_mats["sym_pref_aff"]),
         sym_pref_anti=to_nodes(dom_mats["sym_pref_anti"]),
-        matched_total=jnp.asarray(
-            dom_mats["matched"].sum(axis=1).astype(np.int32)),
+        matched_total=dom_mats["matched"].sum(axis=1).astype(np.int32),
     )
 
 

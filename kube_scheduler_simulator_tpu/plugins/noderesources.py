@@ -75,14 +75,14 @@ class FitPodXS(NamedTuple):
 
 def build_fit(table, schema: ResourceSchema, requests, nonzero,
               fit_args: dict | None = None):
-    # statics leave every build as numpy: compile_workload digests the
-    # host bytes for the scan-cache key, then uploads (upload_statics)
+    # statics and xs leave every build as numpy: compile_workload digests
+    # the host bytes for the scan-cache key, then uploads (upload_tree)
     static = FitStatic(
         allocatable=np.asarray(table.allocatable),
         allowed_pods=np.asarray(table.allowed_pods),
         ignored=fit_ignored_mask(schema, fit_args),
     )
-    xs = FitPodXS(requests=jnp.asarray(requests), nonzero=jnp.asarray(nonzero))
+    xs = FitPodXS(requests=requests, nonzero=nonzero)
     return static, xs
 
 

@@ -116,10 +116,11 @@ def build(vt: VolumeTable, table, pods: list[dict],
     limits = np.stack([vt.csi_limits[d] for d in drivers], axis=1) if drivers else \
         np.zeros((n, 0), dtype=np.int64)
 
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = LimitsStatic(driver_onehot=onehot, limits=limits)
-    xs = LimitsXS(pod_vols=jnp.asarray(pod_vols), filter_skip=jnp.asarray(skip))
-    carry = LimitsCarry(on_node=jnp.asarray(on_node))
+    xs = LimitsXS(pod_vols=pod_vols, filter_skip=skip)
+    carry = LimitsCarry(on_node=on_node)
     return static, xs, carry
 
 

@@ -137,15 +137,16 @@ def build(table, pods: list[dict], bound_pods: list[tuple[dict, str]]):
             else:
                 used_spec[j, intern.s_id(proto, port, ip)] = True
 
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = PortsStatic(sq=np.asarray(intern.sq, dtype=np.int32))
     xs = PortsXS(
-        w_wild=jnp.asarray(w_wild), w_spec=jnp.asarray(w_spec),
-        w_any=jnp.asarray(w_any), filter_skip=jnp.asarray(skip),
+        w_wild=w_wild, w_spec=w_spec,
+        w_any=w_any, filter_skip=skip,
     )
     carry = PortsCarry(
-        used_any=jnp.asarray(used_any), used_wild=jnp.asarray(used_wild),
-        used_spec=jnp.asarray(used_spec),
+        used_any=used_any, used_wild=used_wild,
+        used_spec=used_spec,
     )
     return static, xs, carry
 

@@ -88,7 +88,7 @@ def build_taints(table: NodeTable, pods: list[dict],
         # pass-through): the compact replay keeps it host-resident
         # (framework/replay.py "host" score group) instead of paying D2H
         host_out.setdefault("static_score_rows", {})[NAME_TAINT] = prefer
-    return TaintXS(filter_code=jnp.asarray(code), prefer_count=jnp.asarray(prefer))
+    return TaintXS(filter_code=code, prefer_count=prefer)
 
 
 def build_unschedulable(table: NodeTable, pods: list[dict]) -> UnschedXS:
@@ -100,7 +100,7 @@ def build_unschedulable(table: NodeTable, pods: list[dict]) -> UnschedXS:
         tolerated = tolerations_tolerate(tols, UNSCHEDULABLE_TAINT_KEY, "", "NoSchedule")
         if not tolerated:
             fail[i, unsched_nodes] = True
-    return UnschedXS(fail=jnp.asarray(fail))
+    return UnschedXS(fail=fail)
 
 
 def build_nodename(table: NodeTable, pods: list[dict]) -> NodeNameXS:
@@ -117,7 +117,7 @@ def build_nodename(table: NodeTable, pods: list[dict]) -> NodeNameXS:
         j = name_idx.get(want)
         if j is not None:
             fail[i, j] = False
-    return NodeNameXS(fail=jnp.asarray(fail))
+    return NodeNameXS(fail=fail)
 
 
 # --- device kernels (pure gathers over the precompiled rows) ---

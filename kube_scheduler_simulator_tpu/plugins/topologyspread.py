@@ -269,33 +269,34 @@ def build(table: NodeTable, pods: list[dict]):
         filter_skip[i] = not is_filter[i].any()
         score_skip[i] = not is_score[i].any()
 
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = SpreadStatic(dom_idx=dom_idx, n_groups=n_groups)
     xs = SpreadXS(
-        pm=jnp.asarray(pm),
-        c_id=jnp.asarray(c_id_arr),
-        max_skew=jnp.asarray(max_skew),
-        is_filter=jnp.asarray(is_filter),
-        is_score=jnp.asarray(is_score),
-        weight=jnp.asarray(weight),
-        eligible=jnp.asarray(eligible),
-        md_unsat=jnp.asarray(md_unsat),
-        filter_skip=jnp.asarray(filter_skip),
-        score_skip=jnp.asarray(score_skip),
+        pm=pm,
+        c_id=c_id_arr,
+        max_skew=max_skew,
+        is_filter=is_filter,
+        is_score=is_score,
+        weight=weight,
+        eligible=eligible,
+        md_unsat=md_unsat,
+        filter_skip=filter_skip,
+        score_skip=score_skip,
     )
     counts_dom = np.zeros((n_groups, d_max), dtype=np.int64)
     return static, xs, counts_dom
 
 
-def assemble_counts(static: SpreadStatic, counts_dom: np.ndarray) -> jnp.ndarray:
+def assemble_counts(static: SpreadStatic, counts_dom: np.ndarray) -> np.ndarray:
     """[C, D] domain-space counts (build + host priming) -> node-space
-    [C, N] int32 device carry (value at each node's domain, 0 where the
+    [C, N] int32 carry (value at each node's domain, 0 where the
     node lacks the key).  Node-space keeps the scan step free of the
     TPU-hostile per-step gathers and scatters — see the InterPodCarry
     docstring for the measured effect of the same transformation."""
     dom = np.asarray(static.dom_idx)
     vals = np.take_along_axis(counts_dom, np.maximum(dom, 0), axis=1)
-    return jnp.asarray(np.where(dom >= 0, vals, 0).astype(np.int32))
+    return np.where(dom >= 0, vals, 0).astype(np.int32)
 
 
 def _slot_eligible(pod, m):

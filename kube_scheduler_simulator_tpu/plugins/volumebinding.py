@@ -201,19 +201,20 @@ def build(vt: VolumeTable, table, pods: list[dict], bound_pods=None):
                         sc, table.labels[j]
                     )
 
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = BindingStatic(
         pv_cap=np.asarray(vt.pv_cap), pv_node_ok=np.asarray(vt.pv_node_ok)
     )
     xs = BindingXS(
-        bound_code=jnp.asarray(bound_code),
-        want=jnp.asarray(want),
-        active=jnp.asarray(active),
-        provision_ok=jnp.asarray(provision_ok),
-        filter_skip=jnp.asarray(skip),
+        bound_code=bound_code,
+        want=want,
+        active=active,
+        provision_ok=provision_ok,
+        filter_skip=skip,
     )
     carry = BindingCarry(
-        claimed=jnp.asarray(prime_claims(vt, bound_pods, table.name_idx)))
+        claimed=prime_claims(vt, bound_pods, table.name_idx))
     return static, xs, carry, rejects
 
 

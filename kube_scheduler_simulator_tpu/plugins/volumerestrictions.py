@@ -158,15 +158,16 @@ def build(vt: VolumeTable, table, pods: list[dict],
             if not ro:
                 used_rw[j, d] = True
 
-    # numpy: compile_workload digests, then uploads (upload_statics)
+    # numpy, xs and carry too: compile_workload reads its flags and the
+    # digest off the host bytes, then uploads once (upload_tree)
     static = RestrictionsStatic(strict=np.asarray(strict, dtype=bool))
     xs = RestrictionsXS(
-        w_any=jnp.asarray(w_any), w_rw=jnp.asarray(w_rw),
-        rwop=jnp.asarray(rwop), filter_skip=jnp.asarray(skip),
+        w_any=w_any, w_rw=w_rw,
+        rwop=rwop, filter_skip=skip,
     )
     carry = RestrictionsCarry(
-        used_any=jnp.asarray(used_any), used_rw=jnp.asarray(used_rw),
-        rwop_used=jnp.asarray(rwop_used),
+        used_any=used_any, used_rw=used_rw,
+        rwop_used=rwop_used,
     )
     return static, xs, carry
 

@@ -91,7 +91,7 @@ def build(vt: VolumeTable, table, pods: list[dict]) -> VolumeZoneXS:
         codes = np.zeros((p, n), dtype=np.int32)
         for i, c in per_pod.items():
             codes[i] = c
-    return VolumeZoneXS(codes=jnp.asarray(codes), filter_skip=jnp.asarray(skip))
+    return VolumeZoneXS(codes=codes, filter_skip=skip)
 
 
 def filter_kernel(sl: VolumeZoneXS) -> jnp.ndarray:

@@ -14,17 +14,32 @@ workload ONCE into:
 Already-bound pods (spec.nodeName set + status phase Running, or listed in
 `bound`) are folded into the initial carry exactly like client-go informers
 prime the scheduler's NodeInfo snapshots.
+
+Numpy out of every build, one upload site.  A plugin's `build` returns
+numpy arrays for its statics, its xs and its carry, never a device array:
+on the chip's host every host<->device call costs ~0.2-0.35 ms whatever
+its size, and a pass has 63 such leaves.  compile_workload reads what the
+host needs (the decoder's skip flags, the packing bounds, the statics'
+digest for the scan-cache key) off those numpy leaves, and then sends the
+trees to the device once, in cw_finish's child span cw_upload
+(upload_tree: one buffer per dtype, one jitted dispatch that slices the
+leaves apart; counter workload_h2d_transfers_total).  Past that point
+cw.statics, cw.xs and cw.init_carry are device arrays, and nothing here
+reads one back.  A new build follows the same rule: build in numpy, return
+numpy, keep a host copy in `host` for whatever the decoder needs.
 """
 
 from __future__ import annotations
 
 import hashlib
+import math
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import resources as res
 from .nodes import (
@@ -202,10 +217,7 @@ def compile_workload(
         from ..plugins.base import CoreCarry
 
         init_carry["core"] = CoreCarry(
-            requested=jnp.asarray(req0),
-            nonzero=jnp.asarray(nz0),
-            num_pods=jnp.asarray(np0),
-        )
+            requested=req0, nonzero=nz0, num_pods=np0)
 
     if "NodeAffinity" in enabled:
         with TRACER.span("cw_build_NodeAffinity"):
@@ -292,10 +304,10 @@ def compile_workload(
                 xs["VolumeZone"] = volumezone.build(vt, table, pods)
         if any(any(m is not None for m in msgs) for msgs in rejects.values()):
             host["prefilter_reject"] = rejects
-            xs["force_unsched"] = jnp.asarray(np.asarray([
+            xs["force_unsched"] = np.asarray([
                 any(msgs[i] is not None for msgs in rejects.values())
                 for i in range(p)
-            ], dtype=bool))
+            ], dtype=bool)
     for name, plugin in config.custom.items():
         if name not in enabled:
             continue
@@ -331,16 +343,19 @@ def compile_workload(
             init_carry=init_carry,
             host=host,
         )
+        # every build above handed numpy leaves: the decoder's flags and
+        # the scan-cache key's digest are taken from the host bytes, and
+        # nothing is read back after the upload
         _collect_host_flags(cw)
-        # the one upload site of the statics: every build above handed
-        # numpy leaves, so the scan-cache key's digest is taken from the
-        # host bytes and scan_prepare never fetches them back.  The
-        # statics are node-side tensors: where the digest is the last
-        # pass's on this table, so are the device arrays (one generation;
-        # the jitted step closes over them, nothing donates or writes one)
         digest = host["_statics_fp"] = statics_digest(statics)
-        cw.statics = table.derived.generation(
-            "statics_device", digest, lambda: upload_statics(statics))
+        # the one upload site of a pass.  The statics are node-side
+        # tensors: where the digest is the last pass's on this table, so
+        # are the device arrays (one generation; the jitted step closes
+        # over them, nothing donates or writes one)
+        with TRACER.span("cw_upload"):
+            cw.statics = table.derived.generation(
+                "statics_device", digest, lambda: upload_tree(statics))
+            cw.xs, cw.init_carry = upload_tree((xs, init_carry))
     return cw
 
 
@@ -363,13 +378,45 @@ def statics_digest(statics: dict[str, Any]) -> str:
     return h.hexdigest()
 
 
-def upload_statics(statics: dict[str, Any]) -> dict[str, Any]:
-    """numpy leaves -> device arrays; what is not an array
-    (SpreadStatic.n_groups, a Python int the step reads as a constant)
-    stays as it is."""
-    return jax.tree.map(
-        lambda leaf: jnp.asarray(leaf)
-        if isinstance(leaf, (np.ndarray, np.generic)) else leaf, statics)
+def upload_tree(tree):
+    """A tree of numpy leaves -> the same tree of device arrays, each with
+    the shape, dtype and (non-)weak type jnp.asarray would give it; what
+    is not a numpy array (SpreadStatic.n_groups, a Python int the step
+    reads as a constant) stays as it is.
+
+    What a leaf costs on the chip's host is the call, not the bytes (a
+    pass's 63 leaves are 435 KB): ~0.24 ms a jnp.asarray, ~0.17 ms a leaf
+    of one jax.device_put(tree).  So the leaves travel as one contiguous
+    host buffer per dtype, and one jitted dispatch slices them apart on
+    the device (_unpack; its layout is the same from pass to pass, so it
+    compiles with the scan and never after)."""
+    leaves, treedef = jax.tree.flatten(tree)
+    at = [i for i, leaf in enumerate(leaves)
+          if isinstance(leaf, (np.ndarray, np.generic))]
+    if not at:
+        return tree
+    parts: dict[str, list[np.ndarray]] = {}
+    for i in at:
+        parts.setdefault(leaves[i].dtype.name, []).append(np.ravel(leaves[i]))
+    TRACER.count("workload_h2d_transfers_total", len(parts))
+    bufs = jax.device_put({dt: np.concatenate(p) for dt, p in parts.items()})
+    layout = tuple((leaves[i].dtype.name, leaves[i].shape) for i in at)
+    for i, leaf in zip(at, _unpack(layout, bufs)):
+        leaves[i] = leaf
+    return jax.tree.unflatten(treedef, leaves)
+
+
+@partial(jax.jit, static_argnums=0)
+def _unpack(layout, bufs):
+    """layout: (dtype name, shape) per leaf, in the order the leaves were
+    laid into their dtype's buffer."""
+    offs = dict.fromkeys(bufs, 0)
+    out = []
+    for dt, shape in layout:
+        end = offs[dt] + math.prod(shape)
+        out.append(lax.slice(bufs[dt], (offs[dt],), (end,)).reshape(shape))
+        offs[dt] = end
+    return out
 
 
 # the plugins whose build (with its carry priming) compile_workload wraps
@@ -433,7 +480,7 @@ def _prime_spread_counts(counts_dom, st, pods, bound_carry):
     selector matches, summed over each domain's nodes."""
     if not bound_carry.n:
         return
-    dom_idx = np.asarray(st.dom_idx)
+    dom_idx = st.dom_idx
     # group selectors were interned during build; the bound pods are not
     # part of the queue, so not in x.pm
     for c_id, (gns, _, sel) in enumerate(_spread_groups(pods)):
@@ -453,18 +500,15 @@ def _spread_groups(pods):
 
 
 def _collect_host_flags(cw: CompiledWorkload):
-    """numpy copies of the per-pod skip flags for the annotation decoder."""
+    """The per-pod skip flags for the annotation decoder: the builds' own
+    numpy leaves (cw.xs and cw.statics are not uploaded yet)."""
     skips_filter: dict[str, np.ndarray] = {}
     skips_score: dict[str, np.ndarray] = {}
     p = cw.n_pods
     for name in cw.config.active_plugins():
         x = cw.xs.get(name)
-        skips_filter[name] = (
-            np.asarray(x.filter_skip) if x is not None and hasattr(x, "filter_skip") else np.zeros(p, bool)
-        )
-        skips_score[name] = (
-            np.asarray(x.score_skip) if x is not None and hasattr(x, "score_skip") else np.zeros(p, bool)
-        )
+        skips_filter[name] = getattr(x, "filter_skip", np.zeros(p, bool))
+        skips_score[name] = getattr(x, "score_skip", np.zeros(p, bool))
     cw.host["filter_skip"] = skips_filter
     cw.host["score_skip"] = skips_score
     cw.host["max_filter_code"] = _max_filter_code(cw)
@@ -473,11 +517,7 @@ def _collect_host_flags(cw: CompiledWorkload):
         # mask (framework/replay.py _tsp_ignored_chunk)
         st = cw.statics["PodTopologySpread"]
         x = cw.xs["PodTopologySpread"]
-        cw.host["tsp_ignore"] = (
-            np.asarray(st.dom_idx) < 0,
-            np.asarray(x.c_id),
-            np.asarray(x.is_score),
-        )
+        cw.host["tsp_ignore"] = (st.dom_idx < 0, x.c_id, x.is_score)
     cw.host["score_dtypes"] = tuple(
         _score_dtype(cw, name) for name in cw.config.scorers()
     )
@@ -534,9 +574,8 @@ def _score_dtype(cw: CompiledWorkload, name: str) -> str:
     elif cw.config.is_custom(name) and x is not None and hasattr(x, "scores"):
         rows = x.scores
     if rows is not None:
-        a = np.asarray(rows)
         # NOT np.abs: |int_min| overflows to a negative bound
-        bound = max(int(a.max(initial=0)), -int(a.min(initial=0)))
+        bound = max(int(rows.max(initial=0)), -int(rows.min(initial=0)))
         if bound <= 0x7F:
             return "i8"
         if bound <= 0x7FFF:

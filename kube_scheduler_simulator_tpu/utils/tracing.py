@@ -249,6 +249,10 @@ _HELP: dict[str, str] = {
     "scan_compile_cache_entries":
         "Compiled scan executables currently held by the process-level "
         "LRU cache (framework/replay._ScanCacheRegistry).",
+    "workload_h2d_transfers_total":
+        "Host-to-device buffers compile_workload sent (state/compile.py "
+        "upload_tree): one per dtype of a pass's xs and carry, and of the "
+        "statics where their digest is new on the node table.",
     "preemption_attempts_total":
         "PostFilter runs of DefaultPreemption: one per pod a pass found "
         "no feasible node for (framework/preemption.py).",

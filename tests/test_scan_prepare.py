@@ -27,7 +27,7 @@ from kube_scheduler_simulator_tpu.models.workloads import (
 from kube_scheduler_simulator_tpu.server.sessions import SessionManager
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration
 from kube_scheduler_simulator_tpu.state.compile import (
-    compile_workload, statics_digest, upload_statics)
+    compile_workload, statics_digest, upload_tree)
 from kube_scheduler_simulator_tpu.store.decode import decode_pod_result
 from kube_scheduler_simulator_tpu.utils import hostevents
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
@@ -102,7 +102,7 @@ def test_one_static_byte_dtype_or_shape_changes_the_key(idx, scale):
         # from host bytes, as compile_workload digests them ...
         fp_host = statics_digest(statics)
         # ... and fetched back from the uploaded copy, as the fallback does
-        other = _without_digest(cw, statics=upload_statics(statics))
+        other = _without_digest(cw, statics=upload_tree(statics))
         other_key = _workload_scan_key(other, 16)
         assert other_key[0] == fp_host, what
         assert other_key != key, what

@@ -100,6 +100,9 @@ def test_every_default_plugin_yields_its_build_span(default_wave):
     for name in phases:
         assert name in snap["spans"], name
     assert _seconds(snap, phases) >= 0.9 * _seconds(snap, ["compile_workload"])
+    # the pass's one upload site, inside cw_finish
+    assert snap["spans"]["cw_upload"]["count"] == 1
+    assert _seconds(snap, ["cw_upload"]) <= _seconds(snap, ["cw_finish"])
 
 
 def test_jax_listener_counts_one_backend_compile_per_fresh_function():
@@ -482,6 +485,11 @@ def test_a_steady_pass_builds_no_xla_executable(affinity_terms, prefilled):
     deltas = [e for e in events if e["name"] == "cw_bound_delta"]
     assert len(cw_ids) == 3 and len(deltas) == 6
     assert all(e["parent_id"] in cw_ids for e in deltas)
+    # cw_upload, the pass's one host-to-device site, under cw_finish
+    finish_ids = {e["span_id"] for e in events if e["name"] == "cw_finish"}
+    uploads = [e for e in events if e["name"] == "cw_upload"]
+    assert len(uploads) == 3
+    assert all(e["parent_id"] in finish_ids for e in uploads)
     assert after["bound_rows_built_total"] - warm["bound_rows_built_total"] == 3
     assert (after["bound_rows_carried_total"]
             - warm.get("bound_rows_carried_total", 0)) == 3 * prefilled + 0 + 1 + 2
