@@ -49,6 +49,9 @@ from ..utils.faults import fault_point
 # though the simulator never syncs them).  Services are storable so that a
 # workload that creates them (scheduler_perf's churn ops) has somewhere to
 # put them; nothing in the scheduler reads one yet (docs/SEMANTICS.md).
+# CSINodes are storable because NodeVolumeLimits reads its per-node attach
+# limits from them (state/volumes.py); like the two above they are not
+# part of the watch/record/sync surface.
 RESOURCES: dict[str, tuple[str, bool]] = {
     "namespaces": ("Namespace", False),
     "priorityclasses": ("PriorityClass", False),
@@ -59,6 +62,7 @@ RESOURCES: dict[str, tuple[str, bool]] = {
     "pods": ("Pod", True),
     "poddisruptionbudgets": ("PodDisruptionBudget", True),
     "services": ("Service", True),
+    "csinodes": ("CSINode", False),
 }
 
 # the reference's 7 DefaultGVRs — the watch/record/sync surface
@@ -71,6 +75,7 @@ API_VERSIONS = {
     "priorityclasses": "scheduling.k8s.io/v1",
     "storageclasses": "storage.k8s.io/v1",
     "poddisruptionbudgets": "policy/v1",
+    "csinodes": "storage.k8s.io/v1",
 }
 
 ADDED, MODIFIED, DELETED = "ADDED", "MODIFIED", "DELETED"
@@ -812,3 +817,14 @@ def list_shared(store, resource: str) -> list[dict]:
     if fast:
         return store.list(resource, copy_objects=False)[0]
     return store.list(resource)[0]
+
+
+# compile_workload's `volumes` key -> the stored kind behind it
+_VOLUME_KINDS = (("pvcs", "persistentvolumeclaims"), ("pvs", "persistentvolumes"),
+                 ("storageclasses", "storageclasses"), ("csinodes", "csinodes"))
+
+
+def volume_manifests(store) -> dict[str, list[dict]]:
+    """The manifest lists behind the volume plugin family, shared with the
+    store, as compile_workload's `volumes` takes them."""
+    return {key: list_shared(store, resource) for key, resource in _VOLUME_KINDS}

@@ -26,7 +26,7 @@ import numpy as np
 
 from .replay import filter_rejected_rows, replay
 from .unschedulable import pod_key
-from ..cluster.store import Conflict, NotFound, ObjectStore
+from ..cluster.store import Conflict, NotFound, ObjectStore, volume_manifests
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
 from ..state.compile import compile_workload
@@ -1312,14 +1312,10 @@ class SchedulerEngine:
                 # gang waits for quorum (docs/gang-scheduling.md)
                 bound += self._gang_assumed_bound()
             # volume manifests for the VolumeBinding/Zone/Restrictions/Limits
-            # family; CSINode is not one of the simulator's 7 synced GVRs
-            # (reference: recorder/recorder.go:45-53), so limits come only from
-            # callers using compile_workload directly
-            volumes = {
-                "pvcs": self._list_shared("persistentvolumeclaims"),
-                "pvs": self._list_shared("persistentvolumes"),
-                "storageclasses": self._list_shared("storageclasses"),
-            }
+            # family.  CSINode is not one of the simulator's 7 synced GVRs
+            # (reference: recorder/recorder.go:45-53) but it is a stored
+            # kind: NodeVolumeLimits' per-node attach limits come from it
+            volumes = volume_manifests(self.store)
         with TRACER.span("compile_workload", pods=len(pending), nodes=len(nodes)):
             from ..state.compile import NodeTableReuse
 

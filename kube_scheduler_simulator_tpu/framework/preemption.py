@@ -376,7 +376,7 @@ class Preemptor:
         """What only a dry run or a candidate reads: the store's nodes,
         volumes, namespaces and PDBs as they are now, and the gang members
         that no preemption may evict."""
-        from ..cluster.store import list_shared
+        from ..cluster.store import list_shared, volume_manifests
         from .gang import GangDirectory, preemption_protected
 
         def _shared(resource):
@@ -384,11 +384,7 @@ class Preemptor:
             return list_shared(self.store, resource)
 
         self._nodes = _shared("nodes")
-        self._volumes = {
-            "pvcs": _shared("persistentvolumeclaims"),
-            "pvs": _shared("persistentvolumes"),
-            "storageclasses": _shared("storageclasses"),
-        }
+        self._volumes = volume_manifests(self.store)
         try:
             self._pdbs = _shared("poddisruptionbudgets")
         except KeyError:

@@ -37,6 +37,7 @@ The last chunk is padded; padded steps carry `is_pad` and never bind
 
 from __future__ import annotations
 
+import copy
 import os
 import threading
 import time
@@ -51,7 +52,7 @@ import numpy as np
 
 from .pipeline import build_step
 from ..control import CONTROLS
-from ..state.compile import CompiledWorkload, statics_digest
+from ..state.compile import CompiledWorkload, split_statics, statics_digest
 from ..utils.faults import fault_point
 from ..utils.tracing import TRACER
 
@@ -920,11 +921,14 @@ def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, An
 # compile_workload() (first TPU compile is tens of seconds) — even though
 # successive scheduler waves, and preemption's dry-run hypotheses,
 # produce workloads with byte-identical statics and shapes.  The key
-# therefore hashes the statics CONTENT (the step closure bakes them in as
-# constants) plus the xs/carry shape signature and the plugin-set
-# signature; any mismatch falls through to a fresh compile.  The statics
-# fingerprint is computed once per CompiledWorkload (cached in cw.host),
-# not on every replay() call.
+# therefore hashes the closure statics' CONTENT (the step closure bakes
+# them in as constants) plus the shape signature of xs, carry and the
+# ARGUMENT statics (state/compile.py ARG_STATICS: the volume family's,
+# which the scan takes as its third argument, so that a PV created
+# between two passes is no new executable) and the plugin-set signature;
+# any mismatch falls through to a fresh compile.  The statics fingerprint
+# is computed once per CompiledWorkload (cached in cw.host), not on every
+# replay() call.
 
 
 class CompileQuarantined(RuntimeError):
@@ -1121,15 +1125,16 @@ def scan_cache_stats() -> dict:
 
 
 def _statics_fingerprint(cw: CompiledWorkload) -> str:
-    """The statics' digest for the scan-cache key.  compile_workload
-    takes it from the host bytes before it uploads them; a workload made
-    without it (hand-built in tests and tools) has its statics fetched
-    back and hashed here, once."""
+    """The closure statics' digest for the scan-cache key.
+    compile_workload takes it from the host bytes before it uploads them;
+    a workload made without it (hand-built in tests and tools) has its
+    closure statics fetched back and hashed here, once."""
     fp = cw.host.get("_statics_fp")
     TRACER.inc("scan_key_statics_total",
                source="host" if fp is not None else "fetched")
     if fp is None:
-        fp = cw.host["_statics_fp"] = statics_digest(cw.statics)
+        fp = cw.host["_statics_fp"] = statics_digest(
+            split_statics(cw.statics)[0])
     return fp
 
 
@@ -1148,7 +1153,7 @@ def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
     mesh_sig = tuple(mesh.shape.items()) if mesh is not None else None
     shapes = tuple(
         (str(path), *_leaf_sig(leaf))
-        for tree in (cw.xs, cw.init_carry)
+        for tree in (cw.xs, cw.init_carry, cw.arg_statics())
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
     )
     cfg = cw.config
@@ -1180,15 +1185,24 @@ def _copy_carry(carry):
 
 class _SlimWorkload:
     """Just the fields build_step bakes into the jitted scan — cached
-    closures must not pin per-pod xs tensors or pod manifests."""
+    closures must not pin per-pod xs tensors or pod manifests.  Its
+    statics are the closure statics alone: the argument statics
+    (state/compile.py ARG_STATICS) are not the key's by content, so a
+    cached executable must not hold one; `with_args` is the view a traced
+    scan builds its step from."""
 
     __slots__ = ("config", "statics", "n_nodes", "schema")
 
     def __init__(self, cw: CompiledWorkload):
         self.config = cw.config
-        self.statics = cw.statics
+        self.statics = split_statics(cw.statics)[0]
         self.n_nodes = cw.n_nodes
         self.schema = cw.schema
+
+    def with_args(self, arg_statics: dict[str, Any]) -> "_SlimWorkload":
+        view = copy.copy(self)
+        view.statics = {**self.statics, **arg_statics}
+        return view
 
 
 def _scan_for(cw: CompiledWorkload, chunk: int, unroll: int = 1, mesh=None,
@@ -1198,11 +1212,12 @@ def _scan_for(cw: CompiledWorkload, chunk: int, unroll: int = 1, mesh=None,
            score_dtypes, wide)
 
     def build():
-        step = build_step(_SlimWorkload(cw), out_mode="compact",
-                          pack_mode=pack_mode, score_dtypes=score_dtypes,
-                          wide_raw=wide)
+        slim = _SlimWorkload(cw)
 
-        def scan_chunk(carry, xs_chunk):
+        def scan_chunk(carry, xs_chunk, arg_statics):
+            step = build_step(slim.with_args(arg_statics), out_mode="compact",
+                              pack_mode=pack_mode, score_dtypes=score_dtypes,
+                              wide_raw=wide)
             return jax.lax.scan(step, carry, xs_chunk, unroll=unroll)
 
         return jax.jit(scan_chunk, donate_argnums=(0,))
@@ -1560,6 +1575,7 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
         # copy: the scan donates its carry argument, and cw.init_carry must
         # survive for subsequent replays of the same compiled workload
         carry = _copy_carry(cw.init_carry)
+        arg_statics = cw.arg_statics()
     from concurrent.futures import ThreadPoolExecutor
 
     if not collect:
@@ -1568,7 +1584,7 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
             hi = min(lo + chunk, p)
             xs_chunk = _slice_xs(cw.xs, lo, hi, chunk)
             xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
-            carry, out = scan_jit(carry, xs_chunk)
+            carry, out = scan_jit(carry, xs_chunk, arg_statics)
             outs.append(_TinyOut(out))
         chunks = [_fetch_chunk(o) for o in outs]
 
@@ -1701,7 +1717,7 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
             with TRACER.span("scan_dispatch", lo=lo):
                 xs_chunk = _slice_xs(cw.xs, lo, hi, chunk)
                 xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
-                carry, out = scan_jit(carry, xs_chunk)
+                carry, out = scan_jit(carry, xs_chunk, arg_statics)
                 att_out = (att_ctx.run(out, lo)
                            if device_resident and att_ctx is not None
                            else None)

@@ -14,7 +14,7 @@ a batched pass needs it:
             pending list and parks the ones it leaves Unschedulable;
   moved     by a cluster event upstream's queue moves pods on — a node
             added or updated, a bound pod deleted, a PersistentVolume,
-            PersistentVolumeClaim or StorageClass added or updated, the
+            PersistentVolumeClaim, StorageClass or CSINode added or updated, the
             pod's own spec or labels changed.  The queueing hints of the
             single plugins are not modelled: such an event moves every
             parked pod.  What the scheduler itself writes to a pod (the
@@ -46,7 +46,7 @@ FLUSH_AFTER_S = 300.0
 _RESULT_PREFIX = "kube-scheduler-simulator.sigs.k8s.io/"
 # resources whose ADDED / MODIFIED events move the parked pods
 MOVING_RESOURCES = ("nodes", "persistentvolumes", "persistentvolumeclaims",
-                    "storageclasses")
+                    "storageclasses", "csinodes")
 
 
 def _own_fields(pod: dict) -> tuple:

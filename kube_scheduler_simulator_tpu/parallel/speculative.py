@@ -691,6 +691,13 @@ def replay_speculative_stream(
     device_resident = _resolve_device_resident(device_resident, True,
                                                on_chunk)
     active = set(cw.config.active_plugins())
+    if cw.arg_statics():
+        # speculation_ok admits no plugin with argument statics (the
+        # volume family): the round executables close over what
+        # _SlimWorkload holds, the closure statics, and are keyed by it
+        raise ValueError(
+            f"argument statics {sorted(cw.arg_statics())}: their plugins "
+            "run on the sequential scan only (check speculation_ok)")
     inter: _InteractionOracle | None = None
     if active & LABEL_COUPLED:
         if pods is None:
@@ -1020,7 +1027,8 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
             with TRACER.span("scan_dispatch", lo=lo):
                 xs_chunk = _slice_xs(cw_scan.xs, lo, hi, chunk)
                 xs_chunk["is_pad"] = (jnp.arange(chunk) >= m)
-                carry, out = scan_jit(carry, xs_chunk)
+                carry, out = scan_jit(carry, xs_chunk,
+                                      cw_scan.arg_statics())
             fault_point("replay.decision_fetch")
             with TRACER.span("decision_fetch"):
                 sel = np.asarray(out.selected)

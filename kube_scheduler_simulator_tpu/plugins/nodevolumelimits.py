@@ -11,12 +11,22 @@ Tensorization: CSI volumes (driver, volumeHandle) over PVC-bound PVs are
 interned as c-slots with a driver id; the carry tracks the per-node
 unique-volume bitmap `on_node[N, C]` (a volume shared by two pods counts
 once, matching upstream's unique-volume semantics).  Per-driver counts are
-derived with one masked matmul against the driver one-hot.
+derived with one masked sum against the driver one-hot.
 
-Divergence (documented): volumes a pod acquires through dynamic
-WaitForFirstConsumer provisioning (plugins/volumebinding.py) have no PV at
-evaluation time and are not counted against later pods, and in-tree
-translated / inline ephemeral CSI volumes are not modeled.
+The limits are the cluster's CSINode objects (`csinodes`, a stored kind:
+cluster/store.py), one per node, `spec.drivers[].allocatable.count`; the
+engine hands them to compile_workload with the PVs and claims, so a
+served pass refuses a node exactly as a direct caller's does.  A node
+without a CSINode, or a driver without a count, has no limit.
+
+Divergence (documented, docs/SEMANTICS.md): volumes a pod acquires
+through dynamic WaitForFirstConsumer provisioning
+(plugins/volumebinding.py) have no PV at evaluation time and are not
+counted against later pods; in-tree volumes are not translated to their
+CSI drivers (a CSINode's migrated-plugins annotation is not read), and
+inline ephemeral CSI volumes are not modeled; a node's
+`attachable-volumes-*` allocatable is not a fallback for a missing
+CSINode count (upstream dropped that fallback in v1.29).
 """
 
 from __future__ import annotations
@@ -26,13 +36,15 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..state.volumes import VolumeTable, pod_pvc_keys
+from ..state.volumes import VolumeTable, axis_bucket, pod_pvc_keys
 
 NAME = "NodeVolumeLimits"
 ERR_MAX_VOLUME_COUNT = "node(s) exceed max volume count"
 
 
 class LimitsStatic(NamedTuple):
+    """C is padded (state/volumes.py axis_bucket); a scan ARGUMENT, not a
+    closure constant (state/compile.py ARG_STATICS)."""
     driver_onehot: jnp.ndarray  # [C, D] bool
     limits: jnp.ndarray         # [N, D] int64 (-1 = unlimited)
 
@@ -88,7 +100,9 @@ def build(vt: VolumeTable, table, pods: list[dict],
             c_of(vol)
 
     p, n = len(pods), table.n
-    nc, ndrv = len(vol_id), len(drivers)
+    # the C axis is padded (state/volumes.py axis_bucket): a slot past the
+    # interned volumes is on no node, of no driver and in no pod
+    nc, ndrv = axis_bucket(len(vol_id)), len(drivers)
     pod_vols = np.zeros((p, nc), dtype=bool)
     skip = np.ones(p, dtype=bool)
     for i, pod in enumerate(pods):
@@ -124,11 +138,21 @@ def build(vt: VolumeTable, table, pods: list[dict],
     return static, xs, carry
 
 
+def _per_driver(vols: jnp.ndarray, onehot: jnp.ndarray) -> jnp.ndarray:
+    """[N, C] bool volumes -> [N, D] int64 counts per driver.  A masked
+    sum, not `vols @ onehot` in int64: the TPU compiler has no 64-bit
+    dot (its X64 rewriting refuses one), and D is a handful of drivers.
+    A count is at most C, so int32 holds it."""
+    counts = jnp.sum(vols[:, :, None] & onehot[None, :, :], axis=1,
+                     dtype=jnp.int32)
+    return counts.astype(jnp.int64)
+
+
 def filter_kernel(static: LimitsStatic, sl: LimitsXS, carry: LimitsCarry) -> jnp.ndarray:
     """[N] int32: 1 where a driver limit would be exceeded."""
-    oh = static.driver_onehot.astype(jnp.int64)
-    existing = carry.on_node.astype(jnp.int64) @ oh                   # [N, D]
-    new = (sl.pod_vols[None, :] & ~carry.on_node).astype(jnp.int64) @ oh  # [N, D]
+    existing = _per_driver(carry.on_node, static.driver_onehot)       # [N, D]
+    new = _per_driver(sl.pod_vols[None, :] & ~carry.on_node,
+                      static.driver_onehot)                           # [N, D]
     # upstream checks only drivers the pod ADDS volumes for (returns nil
     # when len(newVolumes) == 0), so a node already over its limit still
     # accepts pods that bring nothing new for that driver

@@ -77,6 +77,8 @@ def decode_filter(code: int, node_idx: int, aux) -> str:
 
 
 class BindingStatic(NamedTuple):
+    """V is padded (state/volumes.py axis_bucket); a scan ARGUMENT, not a
+    closure constant (state/compile.py ARG_STATICS)."""
     pv_cap: jnp.ndarray       # [V] int64
     pv_node_ok: jnp.ndarray   # [V, N] bool
 
@@ -157,7 +159,9 @@ def prime_claims(vt: VolumeTable, bound_pods, name_idx: dict[str, int]) -> np.nd
 
 def build(vt: VolumeTable, table, pods: list[dict], bound_pods=None):
     """-> (BindingStatic, BindingXS, BindingCarry, reject list[str | None])."""
-    p, n, v = len(pods), table.n, vt.n_pvs
+    # V is the table's padded extent (state/volumes.py axis_bucket): the
+    # rows past n_pvs are wanted by no claim and start out claimed
+    p, n, v = len(pods), table.n, vt.pv_cap.shape[0]
     ks: list[int] = []
     classified = []
     for pod in pods:

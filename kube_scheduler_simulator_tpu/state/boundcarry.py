@@ -174,6 +174,10 @@ class BoundCarry:
         self._name_idx = None
         self._ns_key = None         # namespaces the own terms were resolved on
         self._namespaces = None
+        # axis -> the padded extent the volume family's V and C axes had
+        # in this carry's last pass (state/volumes.py axis_bucket): not a
+        # row, so no rebuild clears it
+        self.volume_axes: dict[str, int] = {}
         self._clear()
 
     # ------------------------------------------------------------ state

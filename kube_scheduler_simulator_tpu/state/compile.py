@@ -62,6 +62,24 @@ from ..plugins import (
 
 VOLUME_PLUGINS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding", "VolumeZone")
 
+# The plugins whose statics reach the jitted scan as ARGUMENTS: the scan
+# cache keys them by shape and dtype, like xs and carry, and they are
+# uploaded with these every pass.  Every other plugin's statics are
+# closure constants of the scan, keyed by content (statics_digest), and
+# are uploaded once per content per node table.  The volume family's are
+# arguments because they change with the cluster's volume objects (a PV,
+# a claim or a CSINode created between two passes), which a served
+# cluster creates as fast as pods; the rest change with nodes or the
+# configuration only (docs/wave-pipeline.md, "Statics: closure or
+# argument").  VolumeZone has no statics.
+ARG_STATICS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding")
+
+
+def split_statics(statics: dict[str, Any]) -> tuple[dict, dict]:
+    """-> (closure statics, argument statics) of one statics dict."""
+    return ({k: v for k, v in statics.items() if k not in ARG_STATICS},
+            {k: v for k, v in statics.items() if k in ARG_STATICS})
+
 
 @dataclass
 class CompiledWorkload:
@@ -82,6 +100,10 @@ class CompiledWorkload:
     @property
     def n_nodes(self) -> int:
         return self.node_table.n
+
+    def arg_statics(self) -> dict[str, Any]:
+        """The statics a jitted scan takes as its third argument."""
+        return split_statics(self.statics)[1]
 
 
 class NodeTableReuse:
@@ -267,6 +289,7 @@ def compile_workload(
         rejects: dict[str, list[str | None]] = {}
         # the bound pods with volumes: none of the family reads another
         bound_vols = bound_carry.volume_rows()
+        TRACER.count("volume_bound_rows_walked_total", len(bound_vols))
         if "VolumeRestrictions" in enabled:
             with TRACER.span("cw_build_VolumeRestrictions"):
                 st, x, carry = volumerestrictions.build(vt, table, pods,
@@ -302,6 +325,10 @@ def compile_workload(
         if "VolumeZone" in enabled:
             with TRACER.span("cw_build_VolumeZone"):
                 xs["VolumeZone"] = volumezone.build(vt, table, pods)
+        axes = {"pv": vt.pv_cap.shape[0]}
+        if "NodeVolumeLimits" in statics:
+            axes["csi"] = statics["NodeVolumeLimits"].driver_onehot.shape[0]
+        _count_rebuckets(bound_carry, axes)
         if any(any(m is not None for m in msgs) for msgs in rejects.values()):
             host["prefilter_reject"] = rejects
             xs["force_unsched"] = np.asarray([
@@ -347,23 +374,30 @@ def compile_workload(
         # the scan-cache key's digest are taken from the host bytes, and
         # nothing is read back after the upload
         _collect_host_flags(cw)
-        digest = host["_statics_fp"] = statics_digest(statics)
-        # the one upload site of a pass.  The statics are node-side
-        # tensors: where the digest is the last pass's on this table, so
-        # are the device arrays (one generation; the jitted step closes
-        # over them, nothing donates or writes one)
+        closure, args = split_statics(statics)
+        digest = host["_statics_fp"] = statics_digest(closure)
+        # the one upload site of a pass.  The closure statics are
+        # node-side tensors: where the digest is the last pass's on this
+        # table, so are the device arrays (one generation; the jitted step
+        # closes over them, nothing donates or writes one).  The argument
+        # statics travel with xs and carry, whatever they hold
+        TRACER.count("volume_static_args_bytes_total",
+                     sum(leaf.nbytes for leaf in jax.tree.leaves(args)))
         with TRACER.span("cw_upload"):
-            cw.statics = table.derived.generation(
-                "statics_device", digest, lambda: upload_tree(statics))
-            cw.xs, cw.init_carry = upload_tree((xs, init_carry))
+            on_device = table.derived.generation(
+                "statics_device", digest, lambda: upload_tree(closure))
+            cw.xs, cw.init_carry, args = upload_tree((xs, init_carry, args))
+            cw.statics = {**on_device, **args}
     return cw
 
 
 def statics_digest(statics: dict[str, Any]) -> str:
-    """The statics' part of the scan-cache key (framework/replay.py
-    _workload_scan_key): SHA-1 over name + shape + dtype + bytes of every
-    leaf, plugins in sorted-name order.  Equal statics share a compiled
-    scan (the jitted step closes over them), unequal ones never do.  On
+    """The CLOSURE statics' part of the scan-cache key (framework/
+    replay.py _workload_scan_key; the argument statics, ARG_STATICS, are
+    keyed there by shape and dtype and are not to be handed here): SHA-1
+    over name + shape + dtype + bytes of every leaf, plugins in
+    sorted-name order.  Equal statics share a compiled scan (the jitted
+    step closes over them), unequal ones never do.  On
     host leaves this reads no device; on a workload's device statics it
     fetches each leaf back (replay's fallback for a workload that
     compile_workload did not make)."""
@@ -461,6 +495,19 @@ def _node_delta(old_key, node_key, cols):
         if len(changed) > delta_max:
             return None
     return np.asarray(changed, dtype=np.int64) if changed else None
+
+
+def _count_rebuckets(bound_carry: BoundCarry, axes: dict[str, int]) -> None:
+    """volume_axis_rebuckets_total{axis}: a padded volume axis grew past
+    the extent this carry's last pass ran at, which is the one volume
+    event that still compiles a scan.  A throw-away carry (a dry run, a
+    direct caller) has no last pass and counts nothing."""
+    last = bound_carry.volume_axes
+    for axis, extent in axes.items():
+        # + 0 too: a series that reads 0 says the axes are padded
+        TRACER.inc("volume_axis_rebuckets_total",
+                   int(extent > last.get(axis, extent)), axis=axis)
+    bound_carry.volume_axes = axes
 
 
 def _missing_pvc_message(vt, pod: dict) -> str | None:

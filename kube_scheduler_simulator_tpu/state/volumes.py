@@ -27,6 +27,7 @@ import numpy as np
 from .nodes import NodeTable
 from .selectors import label_selector_matches, node_selector_matches
 from ..utils.quantity import parse_quantity
+from ..utils.tracing import TRACER
 
 # PVC annotation predating spec.storageClassName (still honored upstream)
 BETA_STORAGE_CLASS_ANN = "volume.beta.kubernetes.io/storage-class"
@@ -75,6 +76,23 @@ class PVCInfo:
     selector: dict | None
 
 
+# smallest non-empty extent of a padded volume axis
+AXIS_FLOOR = 64
+
+
+def axis_bucket(n: int) -> int:
+    """The padded extent of a volume axis that holds n entries: the V axis
+    (PVs in the cluster) and the C axis (distinct CSI volumes among bound
+    and pending pods) are array axes of the family's scan arguments, xs
+    and carry, so an extent that followed the count would be a new
+    executable with every PV created.  0 stays 0 (a cluster without
+    volumes keeps its empty axes and the kernels' static no-volume
+    branches); else the power of two >= n, at least AXIS_FLOOR."""
+    if n <= 0:
+        return 0
+    return max(AXIS_FLOOR, 1 << (n - 1).bit_length())
+
+
 @dataclass
 class VolumeTable:
     pvcs: dict[str, PVCInfo]
@@ -82,6 +100,9 @@ class VolumeTable:
     pv_index: dict[str, int]
     classes: dict[str, StorageClassInfo]
     default_class: str | None
+    # The three PV arrays are padded along V to axis_bucket(n_pvs): a row
+    # past n_pvs is no PV anyone can claim (claimed, of capacity 0, OK on
+    # no node).
     # dense, [V, N]: PV node-affinity evaluated against every node
     pv_node_ok: np.ndarray
     pv_cap: np.ndarray             # [V] int64
@@ -183,10 +204,18 @@ def build_volume_table(
         _key(pvc): _parse_pvc(pvc, classes, default_class) for pvc in (pvcs or [])
     }
 
+    TRACER.inc("volume_manifests_parsed_total", len(pv_infos), kind="pv")
+    TRACER.inc("volume_manifests_parsed_total", len(pvcs or ()), kind="pvc")
+    TRACER.inc("volume_manifests_parsed_total", len(csinodes or ()),
+               kind="csinode")
+    TRACER.gauge("volume_table_pvs", len(pv_infos))
+
     v, n = len(pv_infos), node_table.n
-    pv_node_ok = np.ones((v, n), dtype=bool)
-    pv_cap = np.zeros(v, dtype=np.int64)
-    pv_claimed0 = np.zeros(v, dtype=bool)
+    v_pad = axis_bucket(v)
+    pv_node_ok = np.zeros((v_pad, n), dtype=bool)
+    pv_node_ok[:v] = True
+    pv_cap = np.zeros(v_pad, dtype=np.int64)
+    pv_claimed0 = np.ones(v_pad, dtype=bool)
     for i, pv in enumerate(pv_infos):
         pv_cap[i] = pv.capacity
         pv_claimed0[i] = pv.claim_ref is not None

@@ -253,6 +253,25 @@ _HELP: dict[str, str] = {
         "Host-to-device buffers compile_workload sent (state/compile.py "
         "upload_tree): one per dtype of a pass's xs and carry, and of the "
         "statics where their digest is new on the node table.",
+    "volume_manifests_parsed_total":
+        "PersistentVolume, PersistentVolumeClaim and CSINode manifests "
+        "build_volume_table parsed (kind=pv|pvc|csinode): the cluster's "
+        "whole volume state, once per pass with a volume plugin enabled.",
+    "volume_bound_rows_walked_total":
+        "Bound pods with volumes that the volume family's builds resolved "
+        "pod -> claim -> PV, counted once per pass (not once per plugin).",
+    "volume_axis_rebuckets_total":
+        "Passes in which a padded volume axis (axis=pv: PVs in the "
+        "cluster; axis=csi: distinct CSI volumes of limited drivers) "
+        "outgrew the extent of the session's last pass: the one volume "
+        "event that compiles a new scan.",
+    "volume_static_args_bytes_total":
+        "Bytes of the volume family's statics handed to the scan as "
+        "arguments (state/compile.py ARG_STATICS), uploaded with xs and "
+        "carry every pass.",
+    "volume_table_pvs":
+        "PersistentVolumes in the last pass's volume table (the V axis "
+        "before padding).",
     "preemption_attempts_total":
         "PostFilter runs of DefaultPreemption: one per pod a pass found "
         "no feasible node for (framework/preemption.py).",

@@ -37,7 +37,8 @@ from generators import scheduler_perf, scheduler_perf_unique_label  # noqa: E402
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration  # noqa: E402
 from kube_scheduler_simulator_tpu.plugins import imagelocality  # noqa: E402
 from kube_scheduler_simulator_tpu.server.sessions import SessionManager  # noqa: E402
-from kube_scheduler_simulator_tpu.state.compile import compile_workload  # noqa: E402
+from kube_scheduler_simulator_tpu.state.compile import (  # noqa: E402
+    compile_workload, split_statics)
 from kube_scheduler_simulator_tpu.state.nodes import NodeDerived  # noqa: E402
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER  # noqa: E402
 
@@ -458,12 +459,17 @@ def test_equal_digest_hands_back_the_same_device_arrays():
     a = compile_workload(dep.nodes, [dep.measured_pod()], **kw)
     b = compile_workload(dep.nodes, [dep.measured_pod()], reuse=a, **kw)
     assert b.host["_statics_fp"] == a.host["_statics_fp"]
-    la, lb = jax.tree.leaves(a.statics), jax.tree.leaves(b.statics)
+    # the closure statics; the argument statics (the volume family's) go
+    # with xs and the carry
+    la, lb = (jax.tree.leaves(split_statics(cw.statics)[0]) for cw in (a, b))
     assert len(la) == len(lb) and any(isinstance(x, jax.Array) for x in la)
     assert all(x is y for x, y in zip(la, lb))
-    # xs and the carry are never kept: each pass uploads its own
-    assert not any(x is y for x, y in zip(jax.tree.leaves(a.init_carry),
-                                          jax.tree.leaves(b.init_carry)))
+    # xs, the carry and the argument statics are never kept: each pass
+    # uploads its own
+    assert a.arg_statics()
+    assert not any(x is y for x, y in zip(
+        jax.tree.leaves((a.init_carry, a.arg_statics())),
+        jax.tree.leaves((b.init_carry, b.arg_statics()))))
     # a pod whose nodeSelector adds a NodeAffinity row changes the digest
     other = dep.measured_pod()
     other["spec"]["nodeSelector"] = {"kubernetes.io/os": "linux"}

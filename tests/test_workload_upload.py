@@ -153,10 +153,13 @@ def _assert_same_leaf(got, want, where):
 
 def test_uploaded_trees_equal_the_per_leaf_route(monkeypatch, workload):
     cw, handed = _compile_recording(monkeypatch, workload)
-    # the statics' tree (a fresh node table: its one generation is made
-    # here), then the pass's own
+    # the closure statics' tree (a fresh node table: its one generation is
+    # made here), then the pass's own, the argument statics with it
     assert len(handed) == 2
-    statics, (xs, init_carry) = handed
+    statics, (xs, init_carry, arg_statics) = handed
+    assert set(arg_statics) <= set(compile_mod.ARG_STATICS)
+    assert not set(statics) & set(compile_mod.ARG_STATICS)
+    statics = {**statics, **arg_statics}
     for tree in handed:
         for leaf in jax.tree.leaves(tree):
             assert not isinstance(leaf, jax.Array), (
