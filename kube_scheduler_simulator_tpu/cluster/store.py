@@ -820,11 +820,11 @@ def list_shared(store, resource: str) -> list[dict]:
 
 
 # compile_workload's `volumes` key -> the stored kind behind it
-_VOLUME_KINDS = (("pvcs", "persistentvolumeclaims"), ("pvs", "persistentvolumes"),
-                 ("storageclasses", "storageclasses"), ("csinodes", "csinodes"))
+VOLUME_KINDS = (("pvcs", "persistentvolumeclaims"), ("pvs", "persistentvolumes"),
+                ("storageclasses", "storageclasses"), ("csinodes", "csinodes"))
 
 
 def volume_manifests(store) -> dict[str, list[dict]]:
     """The manifest lists behind the volume plugin family, shared with the
     store, as compile_workload's `volumes` takes them."""
-    return {key: list_shared(store, resource) for key, resource in _VOLUME_KINDS}
+    return {key: list_shared(store, resource) for key, resource in VOLUME_KINDS}

@@ -527,6 +527,7 @@ class SchedulerEngine:
         self.pod_backoff_s: tuple[float, float] = (1.0, 10.0)
         self._pending_idx = None
         self._bound_carry = None
+        self._volume_carry = None
         self.result_store = result_store or ResultStore()
         self.reflector = reflector or StoreReflector(store)
         if RESULT_STORE_KEY not in self.reflector.result_stores:
@@ -760,6 +761,9 @@ class SchedulerEngine:
         if self._bound_carry is not None:
             self._bound_carry.close()
             self._bound_carry = None
+        if self._volume_carry is not None:
+            self._volume_carry.close()
+            self._volume_carry = None
         pool = getattr(self, "_reflect_pool", None)
         if pool is not None:
             pool.shutdown(wait=True)
@@ -773,6 +777,16 @@ class SchedulerEngine:
             from ..state.boundcarry import BoundCarry, BoundFeed
 
             carry = self._bound_carry = BoundCarry(BoundFeed(self.store))
+        return carry
+
+    def _volume_state_carry(self):
+        """Lazily built VolumeCarry fed by the store's watches on the four
+        volume kinds: the pass that has a bound carry has this one."""
+        carry = self._volume_carry
+        if carry is None:
+            from ..state.volumecarry import VolumeCarry, VolumeFeed
+
+            carry = self._volume_carry = VolumeCarry(VolumeFeed(self.store))
         return carry
 
     def _pod_bank(self, pods_all):
@@ -1311,18 +1325,26 @@ class SchedulerEngine:
                 # assumed binds: their resources stay reserved while the
                 # gang waits for quorum (docs/gang-scheduling.md)
                 bound += self._gang_assumed_bound()
-            # volume manifests for the VolumeBinding/Zone/Restrictions/Limits
-            # family.  CSINode is not one of the simulator's 7 synced GVRs
+            # the VolumeBinding/Zone/Restrictions/Limits family's state:
+            # carried beside the bound pods and brought up to date from
+            # the store's watches on the four volume kinds
+            # (state/volumecarry.py), so that a pass does not list every
+            # PV, claim and CSINode; listed where the bound pods are.
+            # CSINode is not one of the simulator's 7 synced GVRs
             # (reference: recorder/recorder.go:45-53) but it is a stored
             # kind: NodeVolumeLimits' per-node attach limits come from it
-            volumes = volume_manifests(self.store)
+            volumes = vcarry = None
+            if carry is not None:
+                vcarry = self._volume_state_carry()
+            else:
+                volumes = volume_manifests(self.store)
         with TRACER.span("compile_workload", pods=len(pending), nodes=len(nodes)):
             from ..state.compile import NodeTableReuse
 
             cw = compile_workload(
                 nodes, pending, self.plugin_config, bound_pods=bound,
-                bound_carry=carry,
-                volumes=volumes, reuse=getattr(self, "_last_cw", None),
+                bound_carry=carry, volumes=volumes, volume_carry=vcarry,
+                reuse=getattr(self, "_last_cw", None),
                 namespaces=self._list_shared("namespaces"),
                 # columnar pod bank (when the store keeps one): request
                 # rows gather from its pre-parsed columns

@@ -74,59 +74,45 @@ def pod_csi_volumes(vt: VolumeTable, pod: dict) -> list[tuple[str, str]]:
     return out
 
 
-def build(vt: VolumeTable, table, pods: list[dict],
-          bound_pods: list[tuple[dict, str]]):
+def build(vt: VolumeTable, table, pods: list[dict], bound):
     """-> (LimitsStatic, LimitsXS, LimitsCarry).  With no CSINode-published
-    limits every dimension is 0 and the kernel can never fail a node."""
+    limits every dimension is 0 and the kernel can never fail a node.
+
+    bound: the bound pods' CSI volumes of drivers with a limit, as the
+    volume carry holds them (state/volumecarry.py NodeSlots): the first
+    slots of the C axis, a slot's driver index its tag, the nodes that
+    hold it the one plane; the pending pods' new volumes follow.  The
+    kernel sums over C, so the order of the axis cannot show."""
     drivers = sorted(vt.csi_limits)
     d_idx = {d: i for i, d in enumerate(drivers)}
-
-    vol_id: dict[tuple[str, str], int] = {}
-    vol_driver: list[int] = []
+    new: dict[tuple[str, str], int] = {}
 
     def c_of(vol: tuple[str, str]) -> int | None:
         if vol[0] not in d_idx:
             return None  # unlimited driver: irrelevant to the filter
-        i = vol_id.get(vol)
-        if i is None:
-            i = vol_id[vol] = len(vol_id)
-            vol_driver.append(d_idx[vol[0]])
-        return i
+        c = bound.slot.get(vol)
+        if c is None:
+            c = new.setdefault(vol, bound.n + len(new))
+        return c
 
-    pod_vol_lists = [pod_csi_volumes(vt, p) for p in pods]
-    bound_vol_lists = [(pod_csi_volumes(vt, bp), nn) for bp, nn in bound_pods]
-    for vols in pod_vol_lists + [v for v, _ in bound_vol_lists]:
-        for vol in vols:
-            c_of(vol)
+    pod_slots = [[c for c in map(c_of, pod_csi_volumes(vt, pod))
+                  if c is not None] for pod in pods]
 
     p, n = len(pods), table.n
     # the C axis is padded (state/volumes.py axis_bucket): a slot past the
     # interned volumes is on no node, of no driver and in no pod
-    nc, ndrv = axis_bucket(len(vol_id)), len(drivers)
+    nc, ndrv = axis_bucket(bound.n + len(new)), len(drivers)
     pod_vols = np.zeros((p, nc), dtype=bool)
     skip = np.ones(p, dtype=bool)
     for i, pod in enumerate(pods):
         if pod_pvc_keys(pod):
             skip[i] = False  # upstream Skips only pods with no PVC volumes
-        for vol in pod_vol_lists[i]:
-            c = c_of(vol)
-            if c is not None:
-                pod_vols[i, c] = True
-
-    on_node = np.zeros((n, nc), dtype=bool)
-    name_idx = table.name_idx
-    for vols, node_name in bound_vol_lists:
-        j = name_idx.get(node_name)
-        if j is None:
-            continue
-        for vol in vols:
-            c = c_of(vol)
-            if c is not None:
-                on_node[j, c] = True
+        pod_vols[i, pod_slots[i]] = True
 
     onehot = np.zeros((nc, ndrv), dtype=bool)
-    for c, d in enumerate(vol_driver):
-        onehot[c, d] = True
+    onehot[np.arange(bound.n), bound.tags[:bound.n]] = True
+    for vol, c in new.items():
+        onehot[c, d_idx[vol[0]]] = True
     limits = np.stack([vt.csi_limits[d] for d in drivers], axis=1) if drivers else \
         np.zeros((n, 0), dtype=np.int64)
 
@@ -134,7 +120,7 @@ def build(vt: VolumeTable, table, pods: list[dict],
     # digest off the host bytes, then uploads once (upload_tree)
     static = LimitsStatic(driver_onehot=onehot, limits=limits)
     xs = LimitsXS(pod_vols=pod_vols, filter_skip=skip)
-    carry = LimitsCarry(on_node=on_node)
+    carry = LimitsCarry(on_node=bound.plane(0, nc))
     return static, xs, carry
 
 

@@ -158,7 +158,11 @@ def prime_claims(vt: VolumeTable, bound_pods, name_idx: dict[str, int]) -> np.nd
 
 
 def build(vt: VolumeTable, table, pods: list[dict], bound_pods=None):
-    """-> (BindingStatic, BindingXS, BindingCarry, reject list[str | None])."""
+    """-> (BindingStatic, BindingXS, BindingCarry, reject list[str | None]).
+
+    bound_pods: the bound pods prime_claims replays; compile_workload
+    hands the ones with an unbound WaitForFirstConsumer claim
+    (state/volumecarry.py wffc_rows), the others being no-ops there."""
     # V is the table's padded extent (state/volumes.py axis_bucket): the
     # rows past n_pvs are wanted by no claim and start out claimed
     p, n, v = len(pods), table.n, vt.pv_cap.shape[0]

@@ -95,36 +95,36 @@ def pod_rwop_keys(vt: VolumeTable, pod: dict) -> list[str]:
     return out
 
 
-def build(vt: VolumeTable, table, pods: list[dict],
-          bound_pods: list[tuple[dict, str]]):
-    """-> (RestrictionsStatic, RestrictionsXS, RestrictionsCarry)."""
-    disk_id: dict[tuple, int] = {}
-    strict: list[bool] = []
-    rwop_id: dict[str, int] = {}
+def build(vt: VolumeTable, table, pods: list[dict], disks, rwops):
+    """-> (RestrictionsStatic, RestrictionsXS, RestrictionsCarry).
+
+    disks, rwops: the bound pods' inline disks and RWOP claims as the
+    volume carry holds them (state/volumecarry.py NodeSlots): they are the
+    first slots of the D and R axes, their strict flags the tags, the
+    nodes that hold a disk the two planes (any, rw), and every bound RWOP
+    claim is in use; the pending pods' new identities follow.  The kernels
+    reduce over D and R (any), so the order of these axes cannot show."""
+    new_disks: dict[tuple, int] = {}
+    new_rwops: dict[str, int] = {}
 
     def d_of(ident: tuple) -> int:
-        i = disk_id.get(ident)
-        if i is None:
-            i = disk_id[ident] = len(disk_id)
-            strict.append(ident[0] == "aws")
-        return i
+        d = disks.slot.get(ident)
+        if d is None:
+            d = new_disks.setdefault(ident, disks.n + len(new_disks))
+        return d
 
     def r_of(key: str) -> int:
-        return rwop_id.setdefault(key, len(rwop_id))
+        r = rwops.slot.get(key)
+        if r is None:
+            r = new_rwops.setdefault(key, rwops.n + len(new_rwops))
+        return r
 
-    pod_disks = [pod_inline_disks(p) for p in pods]
-    pod_rwops = [pod_rwop_keys(vt, p) for p in pods]
-    bound_disks = [(pod_inline_disks(bp), nn) for bp, nn in bound_pods]
-    bound_rwops = [pod_rwop_keys(vt, bp) for bp, _ in bound_pods]
-    for disks in pod_disks + [d for d, _ in bound_disks]:
-        for ident, _ in disks:
-            d_of(ident)
-    for keys in pod_rwops + bound_rwops:
-        for key in keys:
-            r_of(key)
+    pod_disks = [[(d_of(ident), ro) for ident, ro in pod_inline_disks(pod)]
+                 for pod in pods]
+    pod_rwops = [[r_of(key) for key in pod_rwop_keys(vt, pod)] for pod in pods]
 
-    p, n = len(pods), table.n
-    nd, nr = len(disk_id), len(rwop_id)
+    p = len(pods)
+    nd, nr = disks.n + len(new_disks), rwops.n + len(new_rwops)
     w_any = np.zeros((p, nd), dtype=bool)
     w_rw = np.zeros((p, nd), dtype=bool)
     rwop = np.zeros((p, nr), dtype=bool)
@@ -134,40 +134,26 @@ def build(vt: VolumeTable, table, pods: list[dict],
         # volume (needsRestrictionsCheck) or a ReadWriteOncePod PVC
         if pod_disks[i] or pod_rwops[i]:
             skip[i] = False
-        for ident, ro in pod_disks[i]:
-            d = d_of(ident)
+        for d, ro in pod_disks[i]:
             w_any[i, d] = True
             if not ro:
                 w_rw[i, d] = True
-        for key in pod_rwops[i]:
-            rwop[i, r_of(key)] = True
+        rwop[i, pod_rwops[i]] = True
 
-    used_any = np.zeros((n, nd), dtype=bool)
-    used_rw = np.zeros((n, nd), dtype=bool)
-    rwop_used = np.zeros(nr, dtype=bool)
-    name_idx = table.name_idx
-    for (disks, node_name), keys in zip(bound_disks, bound_rwops):
-        j = name_idx.get(node_name)
-        for key in keys:
-            rwop_used[r_of(key)] = True
-        if j is None:
-            continue
-        for ident, ro in disks:
-            d = d_of(ident)
-            used_any[j, d] = True
-            if not ro:
-                used_rw[j, d] = True
+    strict = np.zeros(nd, dtype=bool)
+    strict[:disks.n] = disks.tags[:disks.n]
+    strict[disks.n:] = [ident[0] == "aws" for ident in new_disks]
 
     # numpy, xs and carry too: compile_workload reads its flags and the
     # digest off the host bytes, then uploads once (upload_tree)
-    static = RestrictionsStatic(strict=np.asarray(strict, dtype=bool))
+    static = RestrictionsStatic(strict=strict)
     xs = RestrictionsXS(
         w_any=w_any, w_rw=w_rw,
         rwop=rwop, filter_skip=skip,
     )
     carry = RestrictionsCarry(
-        used_any=used_any, used_rw=used_rw,
-        rwop_used=rwop_used,
+        used_any=disks.plane(0, nd), used_rw=disks.plane(1, nd),
+        rwop_used=np.arange(nr) < rwops.n,
     )
     return static, xs, carry
 

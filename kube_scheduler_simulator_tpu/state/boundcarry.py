@@ -16,9 +16,11 @@ or deleted since the last one and reads the aggregates:
                     domain counts are folded from
   own terms         per [anti-]affinity term a bound pod carries, per-node
                     sums of multiplicity / weight (the symmetric checks)
-  ports, volumes    the few bound pods with hostPorts / PVC or restricted
-                    inline volumes, in bound order: their builds walk these
-                    instead of every bound pod
+  ports             the few bound pods with hostPorts, in bound order:
+                    the build walks these instead of every bound pod
+  volumes           the bound pods with PVC or restricted inline volumes,
+                    and a journal of the ones added, changed or removed:
+                    state/volumecarry.py resolves those and carries the rest
 
 Every aggregate is an integer sum or a set, so the order rows arrive in
 cannot show: a carry brought up to date by deltas gives the same bytes as
@@ -174,10 +176,10 @@ class BoundCarry:
         self._name_idx = None
         self._ns_key = None         # namespaces the own terms were resolved on
         self._namespaces = None
-        # axis -> the padded extent the volume family's V and C axes had
-        # in this carry's last pass (state/volumes.py axis_bucket): not a
-        # row, so no rebuild clears it
-        self.volume_axes: dict[str, int] = {}
+        # the keys of the volume rows added, changed or removed, for the
+        # one VolumeCarry that follows this carry (it sets and empties the
+        # set; state/volumecarry.py): not a row, so no rebuild clears it
+        self.volume_journal: set | None = None
         self._clear()
 
     # ------------------------------------------------------------ state
@@ -294,6 +296,8 @@ class BoundCarry:
         if spec.get("volumes"):
             if pod_pvc_keys(pod) or volumerestrictions.pod_inline_disks(pod):
                 self._volumes[key] = s
+                if self.volume_journal is not None:
+                    self.volume_journal.add(key)
         self._built += 1
 
     def _remove(self, key) -> None:
@@ -317,7 +321,9 @@ class BoundCarry:
                 del self._own[tk]
         self._need_req.discard(s)
         self._ports.pop(key, None)
-        self._volumes.pop(key, None)
+        if (self._volumes.pop(key, None) is not None
+                and self.volume_journal is not None):
+            self.volume_journal.add(key)
         self._alive[s] = False
         self._pod[s] = self._node_name[s] = self._ident[s] = None
         self._terms[s] = None
@@ -390,6 +396,8 @@ class BoundCarry:
         carry holds: today's full build, counted."""
         if rows is None:
             rows = {key: self._pod[s] for key, s in self._slot.items()}
+        if self.volume_journal is not None:
+            self.volume_journal.update(self._volumes)
         self._clear()
         for key, pod in rows.items():
             self._add(key, pod, pod["spec"]["nodeName"])
@@ -508,10 +516,15 @@ class BoundCarry:
         order."""
         return self._rows_of(self._ports)
 
-    def volume_rows(self) -> list[tuple[dict, str]]:
-        """(pod, node name) of the bound pods with PVC-backed or
-        restricted inline volumes, in bound order."""
-        return self._rows_of(self._volumes)
+    def volume_keys(self):
+        """The keys of the bound pods with PVC-backed or restricted inline
+        volumes; sorted, they are in bound order."""
+        return self._volumes.keys()
+
+    def volume_row(self, key) -> tuple[dict, str] | None:
+        """(pod, node name) of one of them; None when `key` is not."""
+        s = self._volumes.get(key)
+        return None if s is None else (self._pod[s], self._node_name[s])
 
     def rows(self) -> list[tuple[dict, str]]:
         """Every bound pod, in bound order (the list a build without a

@@ -15,6 +15,16 @@ Already-bound pods (spec.nodeName set + status phase Running, or listed in
 `bound`) are folded into the initial carry exactly like client-go informers
 prime the scheduler's NodeInfo snapshots.
 
+ONCE holds across passes too for what a pass does not change: the node
+table is reused or patched by row (reuse=), what is derived of it alone is
+memoised on it (state/nodes.py NodeDerived), the bound pods' rows and
+aggregates are carried and patched from the pod watch (state/boundcarry.py)
+and so is the volume family's state: parsed PVs, claims, classes and
+CSINode counts, their arrays, and what the three builds derive of the
+bound pods (state/volumecarry.py).  A pass parses the manifests that
+changed and builds its pending pods' xs; handed lists instead of carries,
+it seeds throw-away carries from them and runs the same code.
+
 Numpy out of every build, one upload site.  A plugin's `build` returns
 numpy arrays for its statics, its xs and its carry, never a device array:
 on the chip's host every host<->device call costs ~0.2-0.35 ms whatever
@@ -53,7 +63,7 @@ from .boundcarry import BoundCarry, carry_of_list, pod_key, pod_request_rows
 from .resources import ResourceSchema
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
-from .volumes import build_volume_table
+from .volumecarry import VolumeCarry, carry_of_lists
 from ..plugins import registry as reg
 from ..plugins import (
     affinity, imagelocality, interpod, noderesources, nodevolumelimits, ports,
@@ -129,6 +139,7 @@ def compile_workload(
     namespaces: list[dict] | None = None,
     pod_columns=None,
     bound_carry: BoundCarry | None = None,
+    volume_carry: VolumeCarry | None = None,
 ) -> CompiledWorkload:
     """Compile (nodes, queue pods, already-bound pods) into device tensors.
 
@@ -141,6 +152,12 @@ def compile_workload(
     A list is made into a throw-away carry: the tensors are the same.
     volumes: optional {"pvcs": [...], "pvs": [...], "storageclasses": [...],
     "csinodes": [...]} manifest lists backing the volume plugin family.
+    volume_carry: instead of volumes, the family's state kept from pass to
+    pass (state/volumecarry.py): the volume table, the CSINode limits and
+    what the builds derive of the bound pods, brought up to date here from
+    the store's events on the four kinds and from bound_carry's changed
+    rows.  Lists are made into a throw-away carry: the tensors are the
+    same, up to the order of the C, D and R axes.
     reuse: a prior wave's workload — its NodeTable (the expensive per-node
     manifest parse) is reused when the node set, resourceVersions, and the
     discovered resource schema are unchanged (the common case between
@@ -152,7 +169,6 @@ def compile_workload(
     pre-parsed columns by uid instead of re-parsed per wave.
     """
     config = config or reg.PluginSetConfig()
-    volumes = volumes or {}
     with TRACER.span("cw_bound_delta"):
         if bound_carry is None:
             bound_carry = carry_of_list(bound_pods or [], namespaces)
@@ -278,22 +294,18 @@ def compile_workload(
                 topologyspread.assemble_counts(st, counts_dom)
     if any(name in enabled for name in VOLUME_PLUGINS):
         with TRACER.span("cw_volume_table"):
-            vt = build_volume_table(
-                table, volumes.get("pvcs"), volumes.get("pvs"),
-                volumes.get("storageclasses"), volumes.get("csinodes"),
-            )
-            host["volume_table"] = vt
+            if volume_carry is None:
+                volume_carry = carry_of_lists(volumes)
+            # the carry's own table, patched again by its next pass
+            vt = host["volume_table"] = volume_carry.advance(table, bound_carry)
         # per-pod PreFilter rejects (UnschedulableAndUnresolvable), keyed
         # by the plugin whose PreFilter reports them; the earliest enabled
         # prefilter plugin in DEFAULT_ORDER wins at decode time
         rejects: dict[str, list[str | None]] = {}
-        # the bound pods with volumes: none of the family reads another
-        bound_vols = bound_carry.volume_rows()
-        TRACER.count("volume_bound_rows_walked_total", len(bound_vols))
         if "VolumeRestrictions" in enabled:
             with TRACER.span("cw_build_VolumeRestrictions"):
-                st, x, carry = volumerestrictions.build(vt, table, pods,
-                                                        bound_vols)
+                st, x, carry = volumerestrictions.build(
+                    vt, table, pods, volume_carry.disks, volume_carry.rwops)
                 statics["VolumeRestrictions"] = st
                 xs["VolumeRestrictions"] = x
                 init_carry["VolumeRestrictions"] = carry
@@ -305,14 +317,14 @@ def compile_workload(
         if "NodeVolumeLimits" in enabled:
             with TRACER.span("cw_build_NodeVolumeLimits"):
                 st, x, carry = nodevolumelimits.build(vt, table, pods,
-                                                      bound_vols)
+                                                      volume_carry.csi)
                 statics["NodeVolumeLimits"] = st
                 xs["NodeVolumeLimits"] = x
                 init_carry["NodeVolumeLimits"] = carry
         if "VolumeBinding" in enabled:
             with TRACER.span("cw_build_VolumeBinding"):
                 st, x, carry, vb_rejects = volumebinding.build(
-                    vt, table, pods, bound_vols)
+                    vt, table, pods, volume_carry.wffc_rows())
                 statics["VolumeBinding"] = st
                 xs["VolumeBinding"] = x
                 init_carry["VolumeBinding"] = carry
@@ -328,7 +340,7 @@ def compile_workload(
         axes = {"pv": vt.pv_cap.shape[0]}
         if "NodeVolumeLimits" in statics:
             axes["csi"] = statics["NodeVolumeLimits"].driver_onehot.shape[0]
-        _count_rebuckets(bound_carry, axes)
+        _count_rebuckets(volume_carry, axes)
         if any(any(m is not None for m in msgs) for msgs in rejects.values()):
             host["prefilter_reject"] = rejects
             xs["force_unsched"] = np.asarray([
@@ -497,17 +509,17 @@ def _node_delta(old_key, node_key, cols):
     return np.asarray(changed, dtype=np.int64) if changed else None
 
 
-def _count_rebuckets(bound_carry: BoundCarry, axes: dict[str, int]) -> None:
+def _count_rebuckets(volume_carry: VolumeCarry, axes: dict[str, int]) -> None:
     """volume_axis_rebuckets_total{axis}: a padded volume axis grew past
     the extent this carry's last pass ran at, which is the one volume
     event that still compiles a scan.  A throw-away carry (a dry run, a
     direct caller) has no last pass and counts nothing."""
-    last = bound_carry.volume_axes
+    last = volume_carry.axes
     for axis, extent in axes.items():
         # + 0 too: a series that reads 0 says the axes are padded
         TRACER.inc("volume_axis_rebuckets_total",
                    int(extent > last.get(axis, extent)), axis=axis)
-    bound_carry.volume_axes = axes
+    volume_carry.axes = axes
 
 
 def _missing_pvc_message(vt, pod: dict) -> str | None:

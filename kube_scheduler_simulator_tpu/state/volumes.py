@@ -4,7 +4,12 @@ Host-side half of the volume-plugin state split (same design as nodes.py):
 PV/PVC/StorageClass *structure* is static during a replay — the simulator
 has no PV controller, exactly like the reference's KWOK cluster runs no
 volume controllers — so all manifest parsing, selector matching and PV
-node-affinity evaluation happens once here, producing dense numpy arrays.
+node-affinity evaluation happens once, producing dense numpy arrays: once
+a manifest, not once a pass.  This module holds the parsed rows' types,
+the parsers of one manifest and the table they fill; state/volumecarry.py
+keeps that table from pass to pass and patches it from the store's events
+(a served session), or seeds it from lists and throws it away
+(build_volume_table, compile_workload(volumes=...)): one builder.
 The only *dynamic* volume state is which PVs get claimed as pods with
 unbound WaitForFirstConsumer PVCs bind during the replay; that is the
 device-side carry of plugins/volumebinding.py.
@@ -20,14 +25,13 @@ in-tree plugin, including the volume family).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
 
 from .nodes import NodeTable
-from .selectors import label_selector_matches, node_selector_matches
+from .selectors import label_selector_matches
 from ..utils.quantity import parse_quantity
-from ..utils.tracing import TRACER
 
 # PVC annotation predating spec.storageClassName (still honored upstream)
 BETA_STORAGE_CLASS_ANN = "volume.beta.kubernetes.io/storage-class"
@@ -197,60 +201,15 @@ def build_volume_table(
     storage_classes: list[dict] | None,
     csinodes: list[dict] | None,
 ) -> VolumeTable:
-    classes, default_class = parse_storage_classes(storage_classes or [])
-    pv_infos = [_parse_pv(pv) for pv in (pvs or [])]
-    pv_index = {pv.name: i for i, pv in enumerate(pv_infos)}
-    pvc_infos = {
-        _key(pvc): _parse_pvc(pvc, classes, default_class) for pvc in (pvcs or [])
-    }
+    """The table of given manifest lists on a node table, the V axis in
+    the PV list's order: a throw-away volume carry seeded from the lists
+    (state/volumecarry.py; the carried table of a served session is the
+    same code fed by the store's events)."""
+    from .volumecarry import carry_of_lists
 
-    TRACER.inc("volume_manifests_parsed_total", len(pv_infos), kind="pv")
-    TRACER.inc("volume_manifests_parsed_total", len(pvcs or ()), kind="pvc")
-    TRACER.inc("volume_manifests_parsed_total", len(csinodes or ()),
-               kind="csinode")
-    TRACER.gauge("volume_table_pvs", len(pv_infos))
-
-    v, n = len(pv_infos), node_table.n
-    v_pad = axis_bucket(v)
-    pv_node_ok = np.zeros((v_pad, n), dtype=bool)
-    pv_node_ok[:v] = True
-    pv_cap = np.zeros(v_pad, dtype=np.int64)
-    pv_claimed0 = np.ones(v_pad, dtype=bool)
-    for i, pv in enumerate(pv_infos):
-        pv_cap[i] = pv.capacity
-        pv_claimed0[i] = pv.claim_ref is not None
-        if pv.node_affinity is not None:
-            for j in range(n):
-                pv_node_ok[i, j] = node_selector_matches(
-                    pv.node_affinity, node_table.labels[j], node_table.names[j]
-                )
-
-    csi_limits: dict[str, np.ndarray] = {}
-    name_idx = node_table.name_idx
-    for cn in csinodes or []:
-        j = name_idx.get(_meta(cn).get("name", ""))
-        if j is None:
-            continue
-        for drv in ((cn.get("spec") or {}).get("drivers")) or []:
-            count = (drv.get("allocatable") or {}).get("count")
-            if count is None:
-                continue
-            dn = drv.get("name", "")
-            if dn not in csi_limits:
-                csi_limits[dn] = np.full(n, -1, dtype=np.int64)
-            csi_limits[dn][j] = int(count)
-
-    return VolumeTable(
-        pvcs=pvc_infos,
-        pvs=pv_infos,
-        pv_index=pv_index,
-        classes=classes,
-        default_class=default_class,
-        pv_node_ok=pv_node_ok,
-        pv_cap=pv_cap,
-        pv_claimed0=pv_claimed0,
-        csi_limits=csi_limits,
-    )
+    return carry_of_lists({
+        "pvcs": pvcs, "pvs": pvs, "storageclasses": storage_classes,
+        "csinodes": csinodes}).table_on(node_table)
 
 
 def empty_volume_table(node_table: NodeTable) -> VolumeTable:

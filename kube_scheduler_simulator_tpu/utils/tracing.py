@@ -254,12 +254,27 @@ _HELP: dict[str, str] = {
         "upload_tree): one per dtype of a pass's xs and carry, and of the "
         "statics where their digest is new on the node table.",
     "volume_manifests_parsed_total":
-        "PersistentVolume, PersistentVolumeClaim and CSINode manifests "
-        "build_volume_table parsed (kind=pv|pvc|csinode): the cluster's "
-        "whole volume state, once per pass with a volume plugin enabled.",
+        "PersistentVolume, PersistentVolumeClaim and CSINode manifests the "
+        "volume carry parsed (state/volumecarry.py; kind=pv|pvc|csinode): "
+        "the ones the store created or changed since the session's last "
+        "pass; every one of them on a resync, on a StorageClass change "
+        "(the claims) and where compile_workload is handed lists.",
     "volume_bound_rows_walked_total":
-        "Bound pods with volumes that the volume family's builds resolved "
-        "pod -> claim -> PV, counted once per pass (not once per plugin).",
+        "Bound pods with volumes that the volume carry resolved pod -> "
+        "claim -> PV, once for the three builds: the pods bound, changed "
+        "or unbound since the session's last pass and the ones whose "
+        "claim or PV changed; every one of them on a rebuild.",
+    "volume_carry_rebuilds_total":
+        "Times the volume family's carried state was built again instead "
+        "of patched, by reason: resync (a session's first pass, or a watch "
+        "backlog dropped: every manifest parsed, every row resolved), "
+        "nodes (another node table: the per-node arrays derived again "
+        "from the carried rows, nothing parsed), classes (a StorageClass "
+        "change that changes what claims resolve to: the claims parsed, "
+        "the rows resolved again), drivers (another set of CSI drivers "
+        "with a limit: the bound pods' aggregates laid out again), "
+        "uncarried (compile_workload handed manifest lists: a throw-away "
+        "carry seeded from them).",
     "volume_axis_rebuckets_total":
         "Passes in which a padded volume axis (axis=pv: PVs in the "
         "cluster; axis=csi: distinct CSI volumes of limited drivers) "

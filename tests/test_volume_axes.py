@@ -267,8 +267,14 @@ def test_the_family_s_counters_read_the_pass(cluster):
     # pv_node_ok [64, n] + driver_onehot [64, 1] bools, pv_cap [64] +
     # limits [n, 1] int64s, VolumeRestrictions' strict [0]
     assert g["volume_static_args_bytes_total"] == 64 * n + 64 + 8 * 64 + 8 * n
+    # the second pass of a session is a patch (state/volumecarry.py): its
+    # own PV and claim parsed, the row of the pod bound last resolved
     g2, _ = c.one_pass()
-    assert g2["volume_bound_rows_walked_total"] == initial + 1
+    assert g2["volume_manifests_parsed_total:pv"] == 1
+    assert g2["volume_manifests_parsed_total:pvc"] == 1
+    assert g2.get("volume_manifests_parsed_total:csinode", 0) == 0
+    assert g2["volume_bound_rows_walked_total"] == 1
+    assert TRACER.snapshot()["gauges"]["volume_table_pvs"] == initial + 2
     assert g2["volume_static_args_bytes_total"] == g["volume_static_args_bytes_total"]
 
 
