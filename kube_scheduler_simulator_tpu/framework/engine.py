@@ -24,7 +24,8 @@ import time
 
 import numpy as np
 
-from .replay import filter_rejected_rows, replay
+from .replay import (
+    considered_rows, count_narrowed, filter_rejected_rows, replay)
 from .unschedulable import pod_key
 from ..cluster.store import Conflict, NotFound, ObjectStore, volume_manifests
 from ..utils.tracing import TRACER
@@ -319,7 +320,7 @@ class _WaveCommitter:
 
             decode_chunk_into(rr, lo, hi, self.annotations)
         self._q.put((wave, lo, hi, np.asarray(rr.selected[lo:hi]).copy(),
-                     filter_rejected_rows(rr, lo, hi)))
+                     filter_rejected_rows(rr, lo, hi), rr.cw))
 
     def finish(self) -> tuple[int, None]:
         """Replay drained: commit the remaining chunks, settle reflects,
@@ -376,10 +377,10 @@ class _WaveCommitter:
                     continue  # keep draining so finish() never blocks
                 try:
                     t0 = time.perf_counter()
-                    wave, lo, hi, selected, rejected = item
+                    wave, lo, hi, selected, rejected, cw = item
                     with TRACER.span("commit_stream", parent=self.parent_span,
                                      lo=lo, hi=hi):
-                        self._commit(wave, lo, hi, selected, rejected)
+                        self._commit(wave, lo, hi, selected, rejected, cw)
                     self._busy.append((t0, time.perf_counter()))
                 except BaseException as e:  # noqa: BLE001 — finish() re-raises
                     self._exc = e
@@ -400,7 +401,7 @@ class _WaveCommitter:
             else None
         return acc.finish() if acc is not None else None
 
-    def _commit(self, wave, lo: int, hi: int, selected, rejected) -> None:
+    def _commit(self, wave, lo: int, hi: int, selected, rejected, cw) -> None:
         if wave is not None:
             acc = getattr(wave, "_attr_acc", None)
             if acc is not None:
@@ -412,6 +413,7 @@ class _WaveCommitter:
             return  # width-tier re-delivery of an already-committed chunk
         TRACER.count("filter_rejected_nodes_total",
                      int(rejected[max(lo, self._upto) - lo:].sum()))
+        count_narrowed(cw, slice(max(lo, self._upto), hi))
         if self.gang is not None:
             self._selected[lo:hi] = selected
             if self._pod_wave is not None:
@@ -1745,7 +1747,12 @@ class SchedulerEngine:
         # decided pods only: one parked by its gang or by Permit is counted
         # by the cycle that decides it
         refused = filter_rejected_rows(rr, 0, len(pending))
-        n_refused = 0
+        decided: list[int] = []
+
+        def count_decided() -> None:
+            TRACER.count("filter_rejected_nodes_total",
+                         int(refused[decided].sum()))
+            count_narrowed(cw, decided)
         with TRACER.span("commit_and_reflect", pods=len(pending)) as commit_sp:
             for i, pod in enumerate(pending):
                 meta = pod.get("metadata") or {}
@@ -1797,8 +1804,8 @@ class SchedulerEngine:
                         self.reflector.reflect(ns, name, uid=meta.get("uid"))
                         if exclude is not None:
                             exclude.add((ns, name))
-                        TRACER.count("filter_rejected_nodes_total",
-                                     n_refused + int(refused[i]))
+                        decided.append(i)
+                        count_decided()
                         return n_bound, "rejected"
                     self._bind(ns, name, cw.node_table.names[sel])
                     self._run_custom_postbind(priv, cw.node_table.names[sel],
@@ -1814,7 +1821,7 @@ class SchedulerEngine:
                                 cw, rr.codes_of(i), i, pod, ns, name):
                             retry = "preempted"
                     self._mark_unschedulable(ns, name)
-                n_refused += int(refused[i])
+                decided.append(i)
                 reflects.submit(ns, name, meta.get("uid"))
                 if g >= 0 and i == int(gang.last[g]) and gang_admit[g]:
                     # the group's last wave member landed: release its
@@ -1826,7 +1833,7 @@ class SchedulerEngine:
                         n_bound += 1
                         reflects.submit(rec.ns, rec.name, rec.uid)
             reflects.drain()
-        TRACER.count("filter_rejected_nodes_total", n_refused)
+        count_decided()
         TRACER.observe("framework_extension_point_duration_seconds",
                        commit_sp.seconds, extension_point="bind")
         return n_bound, retry
@@ -2547,6 +2554,10 @@ class SchedulerEngine:
         filter_map: dict[str, dict[str, str]] = {}
         for j in range(n):
             entry: dict[str, str] = {}
+            if active and codes[active[0][0], j] < 0:
+                # outside the pod's PreFilterResult: no Filter, no hook
+                eff_feasible[j] = False
+                continue
             for f, nm, before, after in active:
                 if before is not None and before(pod, names[j]) is not None:
                     eff_feasible[j] = False
@@ -2770,8 +2781,12 @@ class SchedulerEngine:
                     if self._run_postfilter(cw, codes, i, pod, ns, name):
                         retry = "preempted"
                 self._mark_unschedulable(ns, name)
+            # rr1 is row 0 of a one-pod result over the whole queue's cw:
+            # the pod's own row of the host tables is i
             TRACER.count("filter_rejected_nodes_total",
-                         int(filter_rejected_rows(rr1, 0, 1)[0]))
+                         0 if pf_reject else
+                         int(considered_rows(cw, i, i + 1)[0]) - count)
+            count_narrowed(cw, [i])
             self.reflector.reflect(ns, name, uid=meta.get("uid"))
         return n_bound, retry
 

@@ -22,6 +22,12 @@ Fidelity notes
     failing plugin per node — all masks are computed here (cheaper than
     branching on TPU) and the stop-at-first-fail truncation is
     reconstructed by the annotation decoder (store/decode.py).
+  * A PreFilterResult (upstream: Filter runs on the nodes it names and
+    on no other) is a per-pod mask of CONSIDERED nodes (`considered_nodes`):
+    a node outside it is neither feasible nor evaluated, which is not the
+    same as refused — NOT_EVALUATED in a full StepOut's codes, a word of
+    its own in the packed layout (`pack_filter_codes`) — and the decoder
+    writes no filter-result entry for it.
   * Scoring runs only when >1 node is feasible (upstream schedulePod
     returns early on a single feasible node); on device we always compute
     and the decoder drops the results, but selection respects it.
@@ -42,12 +48,19 @@ from ..plugins import (
     affinity, imagelocality, interpod, noderesources, nodevolumelimits, ports,
     taints, topologyspread, volumebinding, volumerestrictions, volumezone,
 )
+from ..plugins.base import PF_ALL
 from ..plugins.registry import PLUGIN_REGISTRY
 from ..state.compile import CompiledWorkload
 
+# a full StepOut's filter code, in every plugin's row, of a node outside
+# the pod's PreFilterResult: no Filter plugin ran there
+NOT_EVALUATED = -1
+
 
 class StepOut(NamedTuple):
-    filter_codes: jnp.ndarray  # [F, N] int32, 0 == pass (already skip-masked)
+    filter_codes: jnp.ndarray  # [F, N] int32, 0 == pass (already
+    #   skip-masked); NOT_EVALUATED in all F rows of a node outside the
+    #   pod's PreFilterResult
     score_raw: jnp.ndarray     # [S, N] int32
     score_final: jnp.ndarray   # [S, N] int32 (normalized x weight)
     selected: jnp.ndarray      # int32, -1 == unschedulable
@@ -86,7 +99,8 @@ class CompactOut(NamedTuple):
 
 # packed-filter layouts: mode -> (dtype, code bits, ff bits).
 # Layout (LSB first): [code][first_fail_idx + 1].  A word of 0 means
-# "all filter plugins passed".
+# "all filter plugins passed"; first_fail_idx + 1 == n_filters + 1 (with
+# code 0) means "outside the pod's PreFilterResult: no plugin ran".
 PACK_MODES = {
     "p8": (jnp.uint8, 5, 3),
     "p16": (jnp.uint16, 8, 8),
@@ -95,11 +109,16 @@ PACK_MODES = {
 }
 
 
-def choose_pack_mode(max_code: int, n_filters: int) -> str:
+def choose_pack_mode(max_code: int, n_filters: int,
+                     narrowed: bool = False) -> str:
+    """narrowed: some pod of the workload has a PreFilterResult, so the
+    word needs a first-fail value past the last filter's."""
     for mode in ("p8", "p16", "p32", "p64"):
         _, code_bits, ff_bits = PACK_MODES[mode]
-        # the packed word stores first_fail_idx + 1, max value n_filters
-        if max_code < (1 << code_bits) and n_filters < (1 << ff_bits):
+        # the packed word stores first_fail_idx + 1, max value n_filters,
+        # and n_filters + 1 for a node that was not evaluated
+        if (max_code < (1 << code_bits)
+                and n_filters + narrowed < (1 << ff_bits)):
             return mode
     return "p64"
 
@@ -230,8 +249,30 @@ def renormalize(name: str, cw, carry, sl, raw, feasible):
     return raw  # no ScoreExtensions
 
 
+def considered_nodes(cw, sl) -> jnp.ndarray | None:
+    """The pod's merged PreFilterResult as an [N] bool mask of the nodes
+    Filter runs on, or None when no PreFilter plugin of this pass narrows
+    any pod (a trace-time fact: the `pf_nodes` leaves then have no
+    columns, plugins/base.py prefilter_rows).  Upstream
+    PreFilterResult.Merge: the intersection of the plugins' node sets, a
+    plugin without a result standing for all nodes."""
+    n = cw.n_nodes
+    mask = None
+    for name in cw.config.prefilters():
+        rows = getattr(sl.get(name), "pf_nodes", None)
+        if rows is None or rows.shape[-1] == 0:
+            continue
+        named = (jnp.arange(n, dtype=rows.dtype)[None, :]
+                 == rows[:, None]).any(axis=0)
+        own = (rows[0] == PF_ALL) | named
+        mask = own if mask is None else mask & own
+    return mask
+
+
 def _filter_phase(cw, carry, sl, filter_names):
-    """filters in config order -> ([F, N] codes, [N] feasible)."""
+    """filters in config order -> ([F, N] codes, [N] feasible, [N]
+    considered or None).  A node the PreFilterResult leaves out is not
+    feasible and carries NOT_EVALUATED in every plugin's row."""
     n = cw.n_nodes
     codes = []
     feasible = jnp.ones(n, dtype=bool)
@@ -244,7 +285,12 @@ def _filter_phase(cw, carry, sl, filter_names):
         codes.append(code)
         feasible = feasible & (code == 0)
     filter_codes = jnp.stack(codes) if codes else jnp.zeros((0, n), dtype=jnp.int32)
-    return filter_codes, feasible
+    considered = considered_nodes(cw, sl)
+    if considered is not None:
+        feasible = feasible & considered
+        filter_codes = jnp.where(considered[None, :], filter_codes,
+                                 NOT_EVALUATED)
+    return filter_codes, feasible, considered
 
 
 def _score_phase(cw, carry, sl, weights, score_names, feasible):
@@ -279,11 +325,12 @@ def _score_phase(cw, carry, sl, weights, score_names, feasible):
 def _eval_phase(cw: CompiledWorkload, carry, sl, weights, filter_names, score_names):
     """filter -> score -> normalize -> weight. Returns
     (filter_codes [F,N], score_raw [S,N], score_final [S,N], feasible [N],
-    total [N] with infeasible forced to -1)."""
-    filter_codes, feasible = _filter_phase(cw, carry, sl, filter_names)
+    total [N] with infeasible forced to -1, considered [N] or None)."""
+    filter_codes, feasible, considered = _filter_phase(
+        cw, carry, sl, filter_names)
     score_raw, score_final, total = _score_phase(
         cw, carry, sl, weights, score_names, feasible)
-    return filter_codes, score_raw, score_final, feasible, total
+    return filter_codes, score_raw, score_final, feasible, total, considered
 
 
 def _bind_phase(cw: CompiledWorkload, carry, sl, selected):
@@ -337,9 +384,11 @@ def _prefilter_reject(cw, carry, sl) -> jnp.ndarray:
     return code
 
 
-def pack_filter_codes(filter_codes: jnp.ndarray, n: int, mode: str) -> jnp.ndarray:
+def pack_filter_codes(filter_codes: jnp.ndarray, n: int, mode: str,
+                      considered: jnp.ndarray | None = None) -> jnp.ndarray:
     """[F, N] codes -> [N] packed first-fail word (see PACK_MODES): 0 =
-    all pass, else (first_fail_idx + 1) << code_bits | code."""
+    all pass, else (first_fail_idx + 1) << code_bits | code; outside
+    `considered` (a PreFilterResult's mask), (F + 1) << code_bits."""
     dtype, code_bits, _ = PACK_MODES[mode]
     acc_dtype = jnp.int64 if mode == "p64" else jnp.int32
     if filter_codes.shape[0] == 0:
@@ -354,6 +403,10 @@ def pack_filter_codes(filter_codes: jnp.ndarray, n: int, mode: str) -> jnp.ndarr
             ((ff.astype(acc_dtype) + 1) << code_bits) | code_at.astype(acc_dtype),
             0,
         )
+    if considered is not None:
+        packed = jnp.where(
+            considered, packed,
+            jnp.asarray(filter_codes.shape[0] + 1, acc_dtype) << code_bits)
     return packed.astype(dtype)
 
 
@@ -376,7 +429,8 @@ def build_step(cw, out_mode: str = "full", pack_mode: str = "p16",
     weights = jnp.asarray([cfg.weight(n) for n in score_names], dtype=jnp.int64)
 
     def step(carry: dict[str, Any], sl: dict[str, Any]):
-        filter_codes, score_raw, score_final, feasible, total = _eval_phase(
+        (filter_codes, score_raw, score_final, feasible, total,
+         considered) = _eval_phase(
             cw, carry, sl, weights, filter_names, score_names
         )
         reject = _prefilter_reject(cw, carry, sl)
@@ -420,7 +474,8 @@ def build_step(cw, out_mode: str = "full", pack_mode: str = "p16",
                 full = jnp.stack(groups["i32"])
                 ovf = jnp.any(full != raw32.astype(full.dtype))
             out: Any = CompactOut(
-                packed_filter=pack_filter_codes(filter_codes, n, pack_mode),
+                packed_filter=pack_filter_codes(filter_codes, n, pack_mode,
+                                                considered),
                 raw8=raw8,
                 raw16=raw16,
                 raw32=raw32,
@@ -465,7 +520,7 @@ def build_phased(cw: CompiledWorkload):
     weights = jnp.asarray([cfg.weight(n) for n in score_names], dtype=jnp.int64)
 
     def eval_fn(carry, sl):
-        filter_codes, score_raw, score_final, feasible, total = _eval_phase(
+        filter_codes, score_raw, score_final, feasible, total, _ = _eval_phase(
             cw, carry, sl, weights, filter_names, score_names
         )
         reject = _prefilter_reject(cw, carry, sl)

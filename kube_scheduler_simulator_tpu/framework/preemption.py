@@ -14,7 +14,9 @@ pkg/scheduler/framework/plugins/defaultpreemption (v1.32):
      PodTopologySpread, InterPodAffinity, and NodePorts).  Nodes rejected
      by node-property plugins (NodeName, NodeUnschedulable, NodeAffinity,
      TaintToleration) are UnschedulableAndUnresolvable upstream and are
-     skipped;
+     skipped; so are the nodes outside the pod's PreFilterResult, on
+     which no Filter ran (upstream's absent-nodes status, "node(s) didn't
+     satisfy plugin(s) [NodeAffinity]");
   3. per candidate node: a node that holds no lower-priority pod is no
      candidate, without a look (upstream SelectVictimsOnNode: "No
      preemption victims found for incoming pod"); otherwise dry-run with
@@ -198,12 +200,16 @@ def filter_pods_with_pdb_violation(pods: list[dict], pdbs: list[dict]
 
 def first_fail_plugins(codes: np.ndarray, active_names: list[str]) -> list[str | None]:
     """Per node, the first filter plugin (upstream order) that rejected it,
-    or None if the node passed.  codes: [F, N] over the ACTIVE filters."""
+    or None if the node passed or lies outside the pod's PreFilterResult
+    (NOT_EVALUATED: no plugin ran there, and upstream gives such a node
+    UnschedulableAndUnresolvable without an entry of its own, so it is
+    neither a candidate nor an evaluated node).  codes: [F, N] over the
+    ACTIVE filters."""
     if codes.ndim != 2 or not codes.shape[1]:
         return []
     if not active_names:
         return [None] * codes.shape[1]
-    failed = np.asarray(codes) != 0
+    failed = np.asarray(codes) > 0
     first = np.where(failed.any(axis=0), failed.argmax(axis=0), -1)
     names = [*active_names, None]  # -1 -> None
     return [names[f] for f in first.tolist()]

@@ -513,6 +513,14 @@ class ReplayResult:
             if f:
                 idx = np.clip(ffp - 1, 0, f - 1)[:, None, :]
                 np.put_along_axis(codes, idx, np.where(ffp > 0, code, 0)[:, None, :], axis=1)
+                # outside the pod's PreFilterResult (pipeline.py
+                # pack_filter_codes): what a full StepOut says there
+                skipped = ffp > f
+                if skipped.any():
+                    from .pipeline import NOT_EVALUATED
+
+                    codes[np.broadcast_to(skipped[:, None, :],
+                                          codes.shape)] = NOT_EVALUATED
             feasible = ffp == 0
             d = {"codes": codes, "feasible": feasible}
             self._recon_ci, self._recon = ci, d
@@ -750,19 +758,20 @@ class ChunkAttribution:
 
     def _tally(self, lo: int, hi: int, ffp: np.ndarray,
                score_arr_of) -> None:
-        """ffp: [m, N] first-fail words (0 == all active filters pass);
+        """ffp: [m, N] first-fail words (0 == all active filters pass,
+        F + 1 == outside the pod's PreFilterResult: no plugin ran);
         score_arr_of(s) -> [m, N] raw column for scorer s (any integer
         dtype; sums accumulate in int64)."""
         out = self.out
         f_count = len(self.filters)
         m = hi - lo
         if f_count:
-            # per-pod histogram of first-fail values 0..F, one bincount
-            flat = (np.arange(m, dtype=np.int64)[:, None] * (f_count + 1)
+            # per-pod histogram of first-fail values 0..F+1, one bincount
+            flat = (np.arange(m, dtype=np.int64)[:, None] * (f_count + 2)
                     + ffp).ravel()
-            counts = np.bincount(flat, minlength=m * (f_count + 1)) \
-                .reshape(m, f_count + 1)
-            rejects = counts[:, 1:]                        # [m, F]
+            counts = np.bincount(flat, minlength=m * (f_count + 2)) \
+                .reshape(m, f_count + 2)
+            rejects = counts[:, 1:f_count + 1]             # [m, F]
             # plugin f ran on a node iff ffp == 0 or ffp > f:
             # all-pass nodes + nodes whose first fail is at a later index
             suff = np.cumsum(rejects[:, ::-1], axis=1)[:, ::-1]
@@ -832,13 +841,41 @@ class ChunkAttribution:
 
 def filter_rejected_rows(rr: ReplayResult, lo: int, hi: int) -> np.ndarray:
     """[hi-lo] int64: the nodes a Filter plugin refused, for each of pods
-    lo..hi — the nodes less feasible_count, and 0 for a pod whose cycle a
+    lo..hi — the nodes Filter ran on (all of them, or the pod's
+    PreFilterResult) less feasible_count, and 0 for a pod whose cycle a
     PreFilter reject ended before any Filter ran.  Both are decision rows
     the wave has already fetched: the engine counts
     filter_rejected_nodes_total from this at commit without a device read."""
     feasible = np.asarray(rr.feasible_count[lo:hi], dtype=np.int64)
     ran = np.asarray(rr.prefilter_reject[lo:hi]) == 0
-    return np.where(ran, rr.cw.n_nodes - feasible, 0)
+    return np.where(ran, considered_rows(rr.cw, lo, hi) - feasible, 0)
+
+
+def considered_rows(cw: CompiledWorkload, lo: int, hi: int) -> np.ndarray:
+    """[hi-lo] int64: how many nodes Filter runs on for each of pods
+    lo..hi — the pod's PreFilterResult (state/compile.py
+    _collect_prefilter_results), every node where it has none."""
+    counts = cw.host.get("considered_count")
+    if counts is None:
+        return np.full(hi - lo, cw.n_nodes, dtype=np.int64)
+    return counts[lo:hi]
+
+
+def count_narrowed(cw: CompiledWorkload, rows) -> None:
+    """prefilter_narrowed_pods_total / prefilter_considered_nodes_total
+    for the pods `rows` (a slice or a list of rows of cw) as their cycle
+    is decided: the pods a PreFilterResult narrowed, and the nodes their
+    Filter phase ran on (0 and 0 where no pod is narrowed: a series that
+    reads 0 says so)."""
+    narrowed = cw.host.get("prefilter_narrowed")
+    if narrowed is None:
+        pods = nodes = 0
+    else:
+        narrowed = narrowed[rows]
+        pods = int(narrowed.sum())
+        nodes = int(cw.host["considered_count"][rows][narrowed].sum())
+    TRACER.count("prefilter_narrowed_pods_total", pods)
+    TRACER.count("prefilter_considered_nodes_total", nodes)
 
 
 def plugin_attribution(rr: ReplayResult) -> dict | None:
@@ -890,6 +927,8 @@ def plugin_attribution(rr: ReplayResult) -> dict | None:
         any_fail = fail.any(axis=1)
         first = np.argmax(fail, axis=1)                     # [P, N]
         ffp_full = np.where(any_fail, first + 1, 0).astype(np.int64)
+        # NOT_EVALUATED rows: outside the pod's PreFilterResult
+        ffp_full[codes[:, 0, :] < 0] = codes.shape[1] + 1
     else:
         # no filter plugins: argmax over the empty axis would raise —
         # every node passes, first-fail is uniformly 0
@@ -1519,6 +1558,7 @@ def _compact_plan(cw: CompiledWorkload, wide: str | None):
     pack_mode = choose_pack_mode(
         cw.host.get("max_filter_code", 1 << 62),
         len(cw.config.filters()),
+        narrowed="prefilter_json" in cw.host,
     )
     score_dtypes = cw.host.get(
         "score_dtypes", tuple("i16" for _ in cw.config.scorers()))

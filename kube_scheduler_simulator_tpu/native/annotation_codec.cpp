@@ -504,8 +504,10 @@ const FilterCache& filter_cache_for(const Ctx& ctx, const uint8_t* active) {
     return *cache;
 }
 
-// fail_buf[j]: first-fail exec idx (f = all active passed); code_buf[j]:
-// the failing plugin's code (only read when fail_buf[j] < f).
+// fail_buf[j]: first-fail exec idx (f = all active passed; f + 1 = the
+// node lies outside the pod's PreFilterResult, no plugin ran and the node
+// gets no entry); code_buf[j]: the failing plugin's code (only read when
+// fail_buf[j] < f).
 // n_fail picks the emit strategy: when failures are rare, maximal runs
 // of consecutive all-pass nodes memcpy straight out of the cached `cat`
 // (one big copy per run); when failures are dense the runs are short
@@ -531,6 +533,10 @@ char* emit_filter_blob(const Ctx& ctx, const FilterCache& fc,
     while (si < n && ff.any_active) {
         int32_t j = ctx.sorted_nodes[si];
         int32_t fail_at = fail_buf[j];
+        if (fail_at > f) {  // not evaluated: no entry
+            ++si;
+            continue;
+        }
         if (fail_at == f && use_runs) {
             // maximal run of consecutive all-pass nodes -> one memcpy of
             // the cached ",node":{...passed...}" bytes (skip the leading
@@ -648,13 +654,15 @@ char* ctx_encode_filter(void* p, const int32_t* codes, const uint8_t* active,
     for (int32_t j = 0; j < n; ++j) {
         int32_t fail_at = f, code = 0;
         for (int32_t pf = 0; pf < f; ++pf) {
-            if (active[pf] && codes[(size_t)pf * n + j] != 0) {
-                fail_at = pf; code = codes[(size_t)pf * n + j]; break;
-            }
+            int32_t c = codes[(size_t)pf * n + j];
+            // a negative code (pipeline.py NOT_EVALUATED, in every row):
+            // outside the pod's PreFilterResult
+            if (c < 0) { fail_at = f + 1; break; }
+            if (active[pf] && c != 0) { fail_at = pf; code = c; break; }
         }
         fail_buf[j] = fail_at;
         code_buf[j] = code;
-        n_fail += (fail_at != f);
+        n_fail += (fail_at < f);
     }
     return emit_filter_blob(ctx, filter_cache_for(ctx, active),
                             fail_buf.data(), code_buf.data(), n_fail,
@@ -669,7 +677,8 @@ char* ctx_encode_filter(void* p, const int32_t* codes, const uint8_t* active,
 // materialization from the decode hot path entirely.
 //
 //   packed:     [N] little-endian words, elem size pack_elem (1/2/4/8);
-//               word = code | (first_fail_idx+1) << code_bits; 0 = pass
+//               word = code | (first_fail_idx+1) << code_bits; 0 = pass;
+//               first_fail_idx+1 == f+1: outside the PreFilterResult
 //   score_cols: [S] pointers to this pod's raw column, elem size
 //               score_elem[q] (1/2/4/8), signed
 //   ignored:    [N] PodTopologySpread score-ignore mask (NULL = none)
@@ -732,7 +741,10 @@ int32_t decode_one(
         int32_t ffp = (int32_t)(w >> code_bits);
         int32_t code = (int32_t)(w & code_mask);
         feas_buf[j] = (ffp == 0);  // replay.py recon: feasible = ffp == 0
-        if (ffp > 0 && ffp <= f && code != 0 && active[ffp - 1]) {
+        if (ffp > f) {
+            fail_buf[j] = f + 1;  // not evaluated (pipeline.py pack_filter_codes)
+            code_buf[j] = 0;
+        } else if (ffp > 0 && code != 0 && active[ffp - 1]) {
             fail_buf[j] = ffp - 1;
             code_buf[j] = code;
             ++n_fail;

@@ -428,8 +428,9 @@ def _sparse_round_fn(cw: CompiledWorkload, base_key, batch: int,
 
     def build():
         def one(carry, sl):
-            codes, feasible = _filter_phase(slim, carry, sl, filter_names)
-            packed = pack_filter_codes(codes, n, pack_mode)
+            codes, feasible, considered = _filter_phase(
+                slim, carry, sl, filter_names)
+            packed = pack_filter_codes(codes, n, pack_mode, considered)
             reject = _prefilter_reject(slim, carry, sl)
             count = jnp.sum(feasible, dtype=jnp.int32)
             count = jnp.where(reject > 0, 0, count)

@@ -13,6 +13,13 @@ Recording semantics (reference shim):
 * PreFilter returns Skip when the pod has neither nodeSelector nor required
   affinity -> its Filter is skipped by the framework (no filter-result
   entries for this plugin on any node).
+* PreFilter returns a PreFilterResult when every required term names
+  nodes by `matchFields: metadata.name In [...]` (`prefilter_node_names`):
+  per term the intersection of those value sets, over terms the union.
+  The framework then runs Filter on those nodes only
+  (framework/pipeline.py `considered_nodes`); the names are recorded in
+  the prefilter-result annotation, sorted.  An empty union rejects the pod
+  (UnschedulableAndUnresolvable, ERR_CONFLICT).
 * PreScore returns Skip when the pod has no preferred terms -> no
   score-result entries.
 * ScoreExtensions: DefaultNormalizeScore(100, reverse=false).
@@ -25,7 +32,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
-from .base import default_normalize_score
+from .base import default_normalize_score, prefilter_rows
 from ..state.nodes import NodeTable
 from ..state.selectors import (
     match_labels_rows,
@@ -36,6 +43,7 @@ from ..state.selectors import (
 
 NAME = "NodeAffinity"
 ERR_REASON = "node(s) didn't match Pod's node affinity/selector"
+ERR_CONFLICT = "pod affinity terms conflict"  # upstream errReasonConflict
 
 
 class NodeAffinityStatic(NamedTuple):
@@ -53,6 +61,46 @@ class NodeAffinityXS(NamedTuple):
     pref_idx: jnp.ndarray       # [P] int32 into static.pref_rows
     filter_skip: jnp.ndarray    # [P] bool (PreFilter returned Skip)
     score_skip: jnp.ndarray     # [P] bool (PreScore returned Skip)
+    pf_nodes: jnp.ndarray       # [P, K] int32 PreFilterResult (base.prefilter_rows)
+
+
+def _is_name_in(req: dict) -> bool:
+    return req.get("key") == "metadata.name" and req.get("operator") == "In"
+
+
+def prefilter_node_names(required: dict | None) -> frozenset[str] | None:
+    """upstream v1.32 NodeAffinity.PreFilter's node-name narrowing: None
+    when some term carries no `metadata.name In` field requirement (the
+    terms are ORed, so every node stays eligible) or there is no term;
+    else the union over terms of the intersection of each term's value
+    sets.  An EMPTY set means the terms conflict."""
+    terms = (required or {}).get("nodeSelectorTerms") or []
+    if not terms:
+        return None
+    names: set[str] = set()
+    for term in terms:
+        term_names: set[str] | None = None
+        for req in term.get("matchFields") or []:
+            if _is_name_in(req):
+                values = set(req.get("values") or [])
+                term_names = (values if term_names is None
+                              else term_names & values)
+        if term_names is None:
+            return None
+        names |= term_names
+    return frozenset(names)
+
+
+def _names_decide(required: dict) -> bool:
+    """Whether `required` says nothing beyond its PreFilterResult: every
+    term is made of `metadata.name In` field requirements alone (what the
+    DaemonSet controller writes).  A node then matches iff it is one of
+    the names, so on the nodes the framework still asks about, the Filter
+    passes."""
+    return all(
+        term.get("matchFields") and not term.get("matchExpressions")
+        and all(_is_name_in(req) for req in term["matchFields"])
+        for term in required.get("nodeSelectorTerms") or [])
 
 
 def build(table: NodeTable, pods: list[dict],
@@ -87,6 +135,8 @@ def build(table: NodeTable, pods: list[dict],
     pref_by_key: dict[str, int] = {}
     req_idx = np.zeros(p, dtype=np.int32)
     pref_idx = np.zeros(p, dtype=np.int32)
+    narrowed: list[frozenset[str] | None] = [None] * p
+    conflicts: list[str | None] = [None] * p
     for i, pod in enumerate(pods):
         spec = pod.get("spec") or {}
         node_sel = spec.get("nodeSelector") or {}
@@ -94,8 +144,19 @@ def build(table: NodeTable, pods: list[dict],
         required = aff.get("requiredDuringSchedulingIgnoredDuringExecution")
         preferred = aff.get("preferredDuringSchedulingIgnoredDuringExecution") or []
 
+        names = prefilter_node_names(required) if required else None
+        if names is not None and not names:
+            conflicts[i] = ERR_CONFLICT
+        else:
+            narrowed[i] = names
         if not node_sel and not required and added_req_row is None:
             filter_skip[i] = True
+        elif (names and not node_sel and added_req_row is None
+              and _names_decide(required)):
+            # the identity row: the match row of such a pod would be its
+            # names again, a closure constant that differs from pod to
+            # pod (a new executable a pass for a DaemonSet's rollout)
+            pass
         else:
             key = spec_key(node_sel, required)
             j = req_by_key.get(key)
@@ -146,11 +207,17 @@ def build(table: NodeTable, pods: list[dict],
         req_rows=np.stack(req_pool),
         pref_rows=pref_mat,
     )
+    if host_out is not None:
+        if any(nm is not None for nm in narrowed):
+            host_out.setdefault("prefilter_result", {})[NAME] = narrowed
+        if any(msg is not None for msg in conflicts):
+            host_out.setdefault("prefilter_reject", {})[NAME] = conflicts
     return static, NodeAffinityXS(
         req_idx=req_idx,
         pref_idx=pref_idx,
         filter_skip=filter_skip,
         score_skip=score_skip,
+        pf_nodes=prefilter_rows(narrowed, table),
     )
 
 

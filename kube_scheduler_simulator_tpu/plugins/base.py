@@ -18,6 +18,14 @@ status.Message(); pass records "passed", resultstore/store.go:27-28).
 
 The scheduling cycle composes these python-side at trace time, so XLA sees
 one fused program per pod step; there is no plugin dispatch on device.
+
+A PreFilter that narrows the cycle (upstream framework.PreFilterResult:
+"run Filter on these node names only") hands the framework, per pod, the
+names as a row of node indices: its xs carries a leaf `pf_nodes`
+([P, K] int32, `prefilter_rows`), and its build leaves the names
+themselves in host_out["prefilter_result"][plugin] for the annotation.
+The framework intersects the plugins' rows (PreFilterResult.Merge) in
+framework/pipeline.py `considered_nodes`.
 """
 
 from __future__ import annotations
@@ -25,8 +33,45 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 MAX_NODE_SCORE = 100  # upstream framework.MaxNodeScore
+
+# a `pf_nodes` row: PF_ALL in every slot = the plugin returned no
+# PreFilterResult for the pod (upstream AllNodes()); else node indices,
+# padded with PF_PAD (a name that is no node leaves only padding)
+PF_ALL = -2
+PF_PAD = -1
+
+
+def prefilter_rows(names_by_pod: list, table) -> np.ndarray:
+    """Per-pod PreFilterResult node names -> the [P, K] int32 `pf_nodes`
+    leaf.  names_by_pod[i] is None (all nodes) or an iterable of node
+    names; a name the node table (state/nodes.py NodeTable) does not hold
+    is dropped, as upstream's findNodesThatFitPod drops it.  K is 0 when
+    no pod is narrowed (the step then has no considered-nodes work at
+    all), else the power of two >= the longest row: a queue that names
+    one node a pod is one shape whatever the names are.  (Where K
+    happens to equal the node count, the shape rules that find a leaf's
+    node axis by its extent — parallel/mesh.py, the speculative rounds'
+    candidate gather — take this one for node-sized: the mesh then
+    shards it, which changes no value, and the gather's copy is read by
+    nothing, the score phase having no use for it.)"""
+    if all(names is None for names in names_by_pod):
+        return np.zeros((len(names_by_pod), 0), dtype=np.int32)
+    name_idx = table.name_idx
+    rows = [None if names is None else
+            sorted(j for j in (name_idx.get(nm) for nm in names)
+                   if j is not None)
+            for names in names_by_pod]
+    longest = max(len(r) for r in rows if r is not None)
+    k = 1 << max(longest - 1, 0).bit_length()
+    out = np.full((len(rows), k), PF_ALL, dtype=np.int32)
+    for i, r in enumerate(rows):
+        if r is not None:
+            out[i] = PF_PAD
+            out[i, :len(r)] = r
+    return out
 
 
 class CoreCarry(NamedTuple):

@@ -301,7 +301,11 @@ class SequentialScheduler:
         from ..plugins.volumerestrictions import ERR_RWOP_CONFLICT, pod_rwop_keys
 
         for name in self.config.prefilters():
-            if name == "VolumeRestrictions":
+            if name == "NodeAffinity":
+                names = self._affinity_node_names(pod)
+                if names is not None and not names:
+                    return name, "pod affinity terms conflict"
+            elif name == "VolumeRestrictions":
                 for key in self._pod_pvcs(pod):
                     if key not in self.vt.pvcs:
                         pvc_name = key.split("/", 1)[1]
@@ -316,6 +320,41 @@ class SequentialScheduler:
                 if reject is not None:
                     return name, reject
         return None
+
+    @staticmethod
+    def _affinity_node_names(pod) -> set[str] | None:
+        """upstream v1.32 NodeAffinity.PreFilter: the node names the pod's
+        required terms pin it to — per term the intersection of the value
+        sets of its `metadata.name In` field requirements, over terms the
+        union — or None where a term has no such requirement (or there is
+        no term): every node stays eligible.  An empty set: the terms
+        conflict."""
+        required = (((_spec(pod).get("affinity") or {}).get("nodeAffinity"))
+                    or {}).get("requiredDuringSchedulingIgnoredDuringExecution")
+        terms = (required or {}).get("nodeSelectorTerms") or []
+        if not terms:
+            return None
+        union: set[str] = set()
+        for term in terms:
+            pinned = [set(r.get("values") or [])
+                      for r in term.get("matchFields") or []
+                      if r.get("key") == "metadata.name"
+                      and r.get("operator") == "In"]
+            if not pinned:
+                return None
+            union |= set.intersection(*pinned)
+        return union
+
+    def _prefilter_results(self, pod) -> dict[str, list[str]]:
+        """plugin -> the sorted node names of the PreFilterResult it
+        returns for the pod (upstream's order is a set's; sorted here,
+        docs/SEMANTICS.md).  Only NodeAffinity returns one."""
+        out = {}
+        if "NodeAffinity" in self.config.prefilters():
+            names = self._affinity_node_names(pod)
+            if names:
+                out["NodeAffinity"] = sorted(names)
+        return out
 
     def _filter_skip(self, name, pod) -> bool:
         if name == "NodePorts":
@@ -841,9 +880,13 @@ class SequentialScheduler:
                     break
                 pf[nm] = "" if self._filter_skip(nm, pod) else ann.SUCCESS_MESSAGE
             empty = ann.marshal({})
+            # results returned before the rejecting plugin are on record
+            returned = {nm: names
+                        for nm, names in self._prefilter_results(pod).items()
+                        if nm in pf and nm != rej_name}
             return {
                 ann.PRE_FILTER_STATUS_RESULT: ann.marshal(pf),
-                ann.PRE_FILTER_RESULT: empty,
+                ann.PRE_FILTER_RESULT: ann.marshal(returned),
                 ann.FILTER_RESULT: empty,
                 ann.POST_FILTER_RESULT: empty,
                 ann.PRE_SCORE_RESULT: empty,
@@ -865,7 +908,16 @@ class SequentialScheduler:
         active = [n for n in cfg.filters() if not self._filter_skip(n, pod)]
         filter_map: dict[str, dict[str, str]] = {}
         feasible: list[int] = []
-        for j in range(self.n):
+        # upstream findNodesThatFitPod: with a PreFilterResult, Filter runs
+        # on the nodes it names (those that exist) and on no other; several
+        # plugins' results intersect (PreFilterResult.Merge)
+        narrowed = self._prefilter_results(pod)
+        considered = range(self.n)
+        if narrowed:
+            merged = set.intersection(*(set(v) for v in narrowed.values()))
+            considered = sorted(self._name_idx[nm] for nm in merged
+                                if nm in self._name_idx)
+        for j in considered:
             entry = {}
             ok = True
             for name in active:
@@ -924,7 +976,7 @@ class SequentialScheduler:
 
         annotations = {
             ann.PRE_FILTER_STATUS_RESULT: ann.marshal(prefilter_status),
-            ann.PRE_FILTER_RESULT: ann.marshal({}),
+            ann.PRE_FILTER_RESULT: ann.marshal(narrowed),
             ann.FILTER_RESULT: ann.marshal(filter_map),
             ann.POST_FILTER_RESULT: ann.marshal({}),
             ann.PRE_SCORE_RESULT: ann.marshal(prescore),

@@ -257,6 +257,12 @@ def compile_workload(
         init_carry["core"] = CoreCarry(
             requested=req0, nonzero=nz0, num_pods=np0)
 
+    # per-pod PreFilter rejects (UnschedulableAndUnresolvable), keyed by
+    # the plugin whose PreFilter reports them; the earliest enabled
+    # prefilter plugin in DEFAULT_ORDER wins at decode time.  A build
+    # that takes host_out adds its own (NodeAffinity's conflicting terms)
+    rejects: dict[str, list[str | None]] = host.setdefault(
+        "prefilter_reject", {})
     if "NodeAffinity" in enabled:
         with TRACER.span("cw_build_NodeAffinity"):
             st, x = affinity.build(
@@ -298,10 +304,6 @@ def compile_workload(
                 volume_carry = carry_of_lists(volumes)
             # the carry's own table, patched again by its next pass
             vt = host["volume_table"] = volume_carry.advance(table, bound_carry)
-        # per-pod PreFilter rejects (UnschedulableAndUnresolvable), keyed
-        # by the plugin whose PreFilter reports them; the earliest enabled
-        # prefilter plugin in DEFAULT_ORDER wins at decode time
-        rejects: dict[str, list[str | None]] = {}
         if "VolumeRestrictions" in enabled:
             with TRACER.span("cw_build_VolumeRestrictions"):
                 st, x, carry = volumerestrictions.build(
@@ -341,12 +343,13 @@ def compile_workload(
         if "NodeVolumeLimits" in statics:
             axes["csi"] = statics["NodeVolumeLimits"].driver_onehot.shape[0]
         _count_rebuckets(volume_carry, axes)
-        if any(any(m is not None for m in msgs) for msgs in rejects.values()):
-            host["prefilter_reject"] = rejects
-            xs["force_unsched"] = np.asarray([
-                any(msgs[i] is not None for msgs in rejects.values())
-                for i in range(p)
-            ], dtype=bool)
+    if any(any(m is not None for m in msgs) for msgs in rejects.values()):
+        xs["force_unsched"] = np.asarray([
+            any(msgs[i] is not None for msgs in rejects.values())
+            for i in range(p)
+        ], dtype=bool)
+    else:
+        del host["prefilter_reject"]
     for name, plugin in config.custom.items():
         if name not in enabled:
             continue
@@ -570,6 +573,7 @@ def _collect_host_flags(cw: CompiledWorkload):
         skips_score[name] = getattr(x, "score_skip", np.zeros(p, bool))
     cw.host["filter_skip"] = skips_filter
     cw.host["score_skip"] = skips_score
+    _collect_prefilter_results(cw)
     cw.host["max_filter_code"] = _max_filter_code(cw)
     if "PodTopologySpread" in cw.config.scorers():
         # static inputs for the host-side recompute of the score-ignore
@@ -580,6 +584,39 @@ def _collect_host_flags(cw: CompiledWorkload):
     cw.host["score_dtypes"] = tuple(
         _score_dtype(cw, name) for name in cw.config.scorers()
     )
+
+
+def _collect_prefilter_results(cw: CompiledWorkload):
+    """What the builds' PreFilterResults (host["prefilter_result"]: plugin
+    -> per pod a set of node names, or None) come to for the host's
+    readers, where any pod has one: host["prefilter_json"], per pod the
+    prefilter-result annotation (None: the constant {}), the names sorted
+    (upstream's sets.UnsortedList has no order: docs/SEMANTICS.md);
+    host["prefilter_narrowed"], [P] bool, the pods that have one; and
+    host["considered_count"], [P], how many nodes Filter runs on — the
+    plugins' sets intersected (upstream PreFilterResult.Merge), less the
+    names that are no node; every node for a pod without a result."""
+    results = cw.host.get("prefilter_result")
+    if not results:
+        return
+    from ..store.annotations import marshal
+
+    p, idx = cw.n_pods, cw.node_table.name_idx
+    rendered: list[str | None] = [None] * p
+    counts = np.full(p, cw.n_nodes, dtype=np.int64)
+    for i in range(p):
+        own = {name: names[i] for name, names in results.items()
+               if names[i] is not None}
+        if not own:  # most pods of a mixed queue
+            continue
+        rendered[i] = marshal({name: sorted(names)
+                               for name, names in own.items()})
+        merged = frozenset.intersection(*own.values())
+        counts[i] = sum(1 for nm in merged if nm in idx)
+    cw.host["prefilter_json"] = rendered
+    cw.host["prefilter_narrowed"] = np.asarray(
+        [r is not None for r in rendered], dtype=bool)
+    cw.host["considered_count"] = counts
 
 
 # static per-plugin bound on the filter codes each kernel can emit — lets

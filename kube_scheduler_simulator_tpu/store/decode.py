@@ -120,9 +120,15 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
                 break
             pf[name] = "" if fskip[name][hi] else ann.SUCCESS_MESSAGE
         empty = _marshal_small({})
+        # a PreFilterResult returned before the rejecting plugin ran is
+        # on record (the shim stores it as the plugin returns)
+        narrowed = {
+            name: sorted(names[hi])
+            for name, names in cw.host.get("prefilter_result", {}).items()
+            if name in pf and name != rej_name and names[hi] is not None}
         return {
             ann.PRE_FILTER_STATUS_RESULT: _marshal_small(pf),
-            ann.PRE_FILTER_RESULT: empty,
+            ann.PRE_FILTER_RESULT: ann.marshal(narrowed) if narrowed else empty,
             ann.FILTER_RESULT: empty,
             ann.POST_FILTER_RESULT: empty,
             ann.PRE_SCORE_RESULT: empty,
@@ -157,7 +163,7 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
             for name in cfg.prescorers():
                 prescore[name] = "" if sskip[name][hi] else ann.SUCCESS_MESSAGE
         return _assemble(cw, cfg, names, rr, i, prefilter_status, prescore,
-                         filter_json, score_json, final_json)
+                         filter_json, score_json, final_json, host_index)
 
     # --- filter (stop at first fail per node) ---------------------------
     active = [
@@ -166,8 +172,10 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
     codes = rr.codes_of(i)  # [F, N]
     # the refusals the filter blob below renders: nodes some active plugin
     # failed (these rungs hold the codes on the host; the fused native
-    # rungs count in C, in the walk that emits the entries)
-    refused = (codes[[f for f, _ in active]] != 0).any(axis=0)
+    # rungs count in C, in the walk that emits the entries).  A negative
+    # code (pipeline.NOT_EVALUATED) is no refusal: the node lies outside
+    # the pod's PreFilterResult and gets no entry
+    refused = (codes[[f for f, _ in active]] > 0).any(axis=0)
     TRACER.count("decode_filter_failed_entries_total", int(refused.sum()))
 
     filter_json: str | None = None
@@ -182,6 +190,8 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
             entry = {}
             for f, name in active:
                 c = int(codes[f, n])
+                if c < 0:
+                    break
                 if c == 0:
                     entry[name] = ann.PASSED_FILTER_MESSAGE
                 else:
@@ -235,7 +245,8 @@ def decode_pod_result(rr: ReplayResult, i: int, feasible_override=None,
         cw, cfg, names, rr, i, prefilter_status, prescore,
         filter_json if filter_json is not None else ann.marshal(filter_map),
         score_json if score_json is not None else ann.marshal(score_map),
-        final_json if final_json is not None else ann.marshal(final_map))
+        final_json if final_json is not None else ann.marshal(final_map),
+        host_index)
 
 
 _MARSHAL_CACHE: dict = {}
@@ -256,7 +267,8 @@ def _marshal_small(d: dict) -> str:
 
 def _assemble(cw, cfg, names, rr, i: int, prefilter_status: dict,
               prescore: dict, filter_json: str, score_json: str | None,
-              final_json: str | None) -> dict[str, str]:
+              final_json: str | None,
+              host_index: int | None = None) -> dict[str, str]:
     """Bind-phase maps + the 13-key annotation dict (both decode paths)."""
     sel = int(rr.selected[i])
     scheduled = sel >= 0
@@ -272,9 +284,16 @@ def _assemble(cw, cfg, names, rr, i: int, prefilter_status: dict,
         prebind["VolumeBinding"] = ann.SUCCESS_MESSAGE
 
     empty = _marshal_small({})
+    # the PreFilterResults' node names, rendered at compile time
+    # (state/compile.py _collect_prefilter_results); i is the pod's row of
+    # rr, and of the host tables except on the extender path's one-row
+    # results
+    narrowed = cw.host.get("prefilter_json")
+    if narrowed is not None:
+        narrowed = narrowed[i if host_index is None else host_index]
     return {
         ann.PRE_FILTER_STATUS_RESULT: _marshal_small(prefilter_status),
-        ann.PRE_FILTER_RESULT: empty,
+        ann.PRE_FILTER_RESULT: narrowed or empty,
         ann.FILTER_RESULT: filter_json,
         ann.POST_FILTER_RESULT: empty,
         ann.PRE_SCORE_RESULT: _marshal_small(prescore),
