@@ -37,6 +37,19 @@ leaves apart; counter workload_h2d_transfers_total).  Past that point
 cw.statics, cw.xs and cw.init_carry are device arrays, and nothing here
 reads one back.  A new build follows the same rule: build in numpy, return
 numpy, keep a host copy in `host` for whatever the decoder needs.
+
+Two leaves do not travel every pass.  The volume family's pv_node_ok
+[V, N] and on_node [N, C] are 41 MB each at 8,192 x 5,000 and change by a
+row and a bit: a session's volume carry keeps them on the device
+(state/resident.py) and, between the builds and the upload, hands
+compile_workload the payload of a patch in their place (a few KB that
+ride in the same buffers); after the upload a jitted dispatch an array
+makes this pass's device array from the last pass's (child span
+cw_resident_patch).  The builds still return the numpy arrays, and the
+digest, the flags and host["volume_table"] still read host bytes.  A
+throw-away carry, an empty axis, and whatever the carry's journal cannot
+say (another node table, a bucket outgrown, a resync) upload whole
+through the same upload_tree.
 """
 
 from __future__ import annotations
@@ -74,7 +87,9 @@ VOLUME_PLUGINS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding", "Vo
 
 # The plugins whose statics reach the jitted scan as ARGUMENTS: the scan
 # cache keys them by shape and dtype, like xs and carry, and they are
-# uploaded with these every pass.  Every other plugin's statics are
+# uploaded with these every pass; but for the one that is cluster-sized,
+# VolumeBinding's pv_node_ok [V, N], which a carried session keeps on the
+# device and patches (state/resident.py).  Every other plugin's statics are
 # closure constants of the scan, keyed by content (statics_digest), and
 # are uploaded once per content per node table.  The volume family's are
 # arguments because they change with the cluster's volume objects (a PV,
@@ -395,15 +410,48 @@ def compile_workload(
         # node-side tensors: where the digest is the last pass's on this
         # table, so are the device arrays (one generation; the jitted step
         # closes over them, nothing donates or writes one).  The argument
-        # statics travel with xs and carry, whatever they hold
-        TRACER.count("volume_static_args_bytes_total",
-                     sum(leaf.nbytes for leaf in jax.tree.leaves(args)))
+        # statics travel with xs and carry, whatever they hold; a carried
+        # session's two cluster-sized leaves travel as the patch of what
+        # changed in them, where the carry's journal can say it
         with TRACER.span("cw_upload"):
             on_device = table.derived.generation(
                 "statics_device", digest, lambda: upload_tree(closure))
+            resident = _resident_leaves(volume_carry, args, init_carry)
+            _swap_resident(resident, "outgoing", args, init_carry)
+            TRACER.count("volume_static_args_bytes_total",
+                         sum(leaf.nbytes for leaf in jax.tree.leaves(args)))
             cw.xs, cw.init_carry, args = upload_tree((xs, init_carry, args))
+            if resident:
+                with TRACER.span("cw_resident_patch"):
+                    _swap_resident(resident, "incoming", args, cw.init_carry)
             cw.statics = {**on_device, **args}
     return cw
+
+
+def _resident_leaves(volume_carry: VolumeCarry | None, args: dict,
+                     init_carry: dict) -> list:
+    """(which tree: 0 the argument statics, 1 the carry; plugin, field,
+    its RowsResident / CellsResident) of the leaves that a carried session
+    keeps on the device: VolumeBinding's pv_node_ok [V, N] and
+    NodeVolumeLimits' on_node [N, C].  None for a throw-away carry, which
+    has no next pass to patch for, and for an empty axis, which has
+    nothing to keep."""
+    if volume_carry is None or volume_carry.feed is None:
+        return []
+    trees = (args, init_carry)
+    return [(t, name, leaf, kept) for t, name, leaf, kept in (
+        (0, "VolumeBinding", "pv_node_ok", volume_carry.pv_ok_dev),
+        (1, "NodeVolumeLimits", "on_node", volume_carry.on_node_dev))
+        if name in trees[t] and getattr(trees[t][name], leaf).size]
+
+
+def _swap_resident(resident: list, how: str, *trees: dict) -> None:
+    """Put into each resident leaf's place what its `outgoing` (before the
+    upload) or `incoming` (after it) makes of what is there."""
+    for t, name, leaf, kept in resident:
+        owner = trees[t][name]
+        trees[t][name] = owner._replace(
+            **{leaf: getattr(kept, how)(getattr(owner, leaf))})
 
 
 def statics_digest(statics: dict[str, Any]) -> str:

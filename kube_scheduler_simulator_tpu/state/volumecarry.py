@@ -28,6 +28,18 @@ last pass.  A VolumeCarry holds
 
 so a pass builds only its pending pods' xs against them.
 
+The two cluster-sized arrays, pv_node_ok [V, N] and on_node [N, C] (csi's
+plane), also stay on the DEVICE from pass to pass (state/resident.py:
+pv_ok_dev, on_node_dev).  The numpy arrays here remain the truth; every
+site that writes one tells its journal where (_patch_pv: rows moved, a
+row written or cleared; _lay_pvs: its gather; NodeSlots.add / sub /
+_release: cells), and the pass's upload sends the patch and not the
+array.  What a journal cannot say drops the device copy and the next
+pass uploads whole: a resync (_seed), another node table (_derive),
+another set of drivers (_place_rows), a bucket that grew (_lay_pvs to
+another extent, NodeSlots._reserve: seen as another shape at the upload),
+more written than a payload holds.
+
 The V axis is the store's key order (PV names sorted), as a listing gives
 it: VolumeBinding breaks ties between equal-capacity PVs by lowest index,
 so the order is observable, and a PV created mid-table is inserted there
@@ -64,6 +76,7 @@ from ..plugins import nodevolumelimits, volumebinding, volumerestrictions
 from ..utils.tracing import TRACER
 from . import volumes as vol
 from .boundcarry import _RESYNC_BACKLOG, BoundCarry
+from .resident import CellsResident, RowsResident
 from .nodes import NodeTable
 from .selectors import node_selector_matches
 
@@ -123,9 +136,11 @@ class NodeSlots:
     counted: per slot the rows that name it, per (slot, node, plane) the
     rows that put it there, so a volume on a node by two pods counts once
     and stays when one of them leaves.  A slot nobody names is filled by
-    the last one: the slots stay dense, and columns past n stay clear."""
+    the last one: the slots stay dense, and columns past n stay clear.
+    `wrote(node, slot)` is told every cell of a plane that changes."""
 
-    def __init__(self, planes: int, n_nodes: int = 0):
+    def __init__(self, planes: int, n_nodes: int = 0, wrote=None):
+        self._wrote = wrote or (lambda j, s: None)
         self.slot: dict = {}                    # identity -> slot
         self._idents: list = []                 # slot -> identity
         self._refs: list[int] = []              # slot -> rows naming it
@@ -174,6 +189,7 @@ class NodeSlots:
                 held[k] += 1
                 if held[k] == 1:
                     self._bits[k][j, s] = True
+                    self._wrote(j, s)
 
     def sub(self, ident, j: int | None, planes=()) -> None:
         """Take back one add() with the same arguments."""
@@ -185,6 +201,7 @@ class NodeSlots:
                     held[k] -= 1
                     if not held[k]:
                         self._bits[k][j, s] = False
+                        self._wrote(j, s)
             if not any(held):
                 del self._held[s][j]
         self._refs[s] -= 1
@@ -202,6 +219,11 @@ class NodeSlots:
             for b in self._bits:
                 b[:, s] = b[:, last]
                 b[:, last] = False
+            # column s was clear (nobody named it): the cells that changed
+            # are the last slot's, in both columns
+            for j in self._held[s]:
+                self._wrote(j, s)
+                self._wrote(j, last)
         self._idents.pop()
         self._refs.pop()
         self._held.pop()
@@ -267,6 +289,14 @@ class VolumeCarry:
         self._name_idx = None
         self._bound: BoundCarry | None = None
         self._touched: set = set()      # the bound carry's journal, once followed
+        # the two cluster-sized arrays as the device holds them
+        # (state/resident.py): vt.pv_node_ok and csi's plane, each with the
+        # journal of what this carry wrote to it since the last pass.  A
+        # throw-away carry has no next pass: compile_workload makes
+        # nothing of it resident, and a journal without a device copy
+        # records nothing
+        self.pv_ok_dev = RowsResident()
+        self.on_node_dev = CellsResident()
         self._seed(listed or {})
 
     # ---------------------------------------------------- the parsed rows
@@ -295,6 +325,8 @@ class VolumeCarry:
                    kind="csinode")
         self._table = None              # nothing derived yet
         self._forget_rows()
+        self.pv_ok_dev.drop("resync")
+        self.on_node_dev.drop("resync")
 
     def _parse_claims(self) -> None:
         vt = self.vt
@@ -345,13 +377,14 @@ class VolumeCarry:
     def _derive(self, table: NodeTable) -> None:
         """Everything that is per node index, from the carried rows."""
         self._table, self._name_idx = table, table.name_idx
+        self.pv_ok_dev.drop("nodes")
         self._lay_pvs(self._pv_keys, self.vt.pvs, None)
         limits = self.vt.csi_limits = {}
         self._driver_refs: dict[str, int] = {}
         for row in self._csi_rows.values():
             self._set_limits(row, +1)
         self._drivers = tuple(sorted(limits))
-        self._place_rows()
+        self._place_rows("nodes")
 
     def _patch(self, changes: dict) -> None:
         classes = changes.get("storageclasses")
@@ -373,7 +406,7 @@ class VolumeCarry:
             # by the driver's index: another set of drivers, another axis
             self._drivers = drivers
             TRACER.inc("volume_carry_rebuilds_total", reason="drivers")
-            self._place_rows()
+            self._place_rows("drivers")
 
     # ------------------------------------------------- classes and claims
 
@@ -420,6 +453,10 @@ class VolumeCarry:
         vt, table = self.vt, self._table
         v, n = len(infos), table.n
         extent = vol.axis_bucket(v)
+        if src is not None and extent == vt.pv_node_ok.shape[0]:
+            self.pv_ok_dev.relaid(src, v)
+        else:
+            self.pv_ok_dev.drop("bucket")
         # a row past v is no PV anyone can claim: claimed, of capacity 0,
         # OK on no node
         ok = np.zeros((extent, n), dtype=bool)
@@ -491,6 +528,8 @@ class VolumeCarry:
                 for a, padding in arrays:
                     _shift_rows(a, i + 1, v, -1)
                     a[v - 1] = padding
+                self.pv_ok_dev.moved(i + 1, v, -1)
+                self.pv_ok_dev.wrote(v - 1, cleared=True)
             return present
         info = self._parsed_pv(pv)
         if present:
@@ -501,6 +540,8 @@ class VolumeCarry:
             vt.pvs.insert(i, info)
             for a, _ in arrays:
                 _shift_rows(a, i, v, +1)
+            self.pv_ok_dev.moved(i, v, +1)
+        self.pv_ok_dev.wrote(i)
         vt.pv_cap[i] = info.capacity
         vt.pv_claimed0[i] = info.claim_ref is not None
         if info.node_affinity is None:
@@ -537,12 +578,15 @@ class VolumeCarry:
 
     # ------------------------------------------------- the bound pods' rows
 
-    def _place_rows(self) -> None:
+    def _place_rows(self, why: str) -> None:
         """The three aggregates again from the resolved rows: another node
-        table, or another set of drivers with a limit."""
+        table (why: "nodes"), or another set of drivers with a limit
+        ("drivers")."""
         n = self._table.n
         self._d_idx = {d: i for i, d in enumerate(self._drivers)}
-        self.csi = NodeSlots(1, n)      # (driver, handle); tag: driver index
+        self.on_node_dev.drop(why)
+        # (driver, handle); tag: driver index
+        self.csi = NodeSlots(1, n, wrote=self.on_node_dev.wrote)
         self.disks = NodeSlots(2, n)    # inline disk; planes any, rw; tag: strict
         self.rwops = NodeSlots(0)       # RWOP claim key
         for key in sorted(self._rows):

@@ -281,9 +281,24 @@ _HELP: dict[str, str] = {
         "outgrew the extent of the session's last pass: the one volume "
         "event that compiles a new scan.",
     "volume_static_args_bytes_total":
-        "Bytes of the volume family's statics handed to the scan as "
-        "arguments (state/compile.py ARG_STATICS), uploaded with xs and "
-        "carry every pass.",
+        "Bytes that travel to the device for the volume family's statics "
+        "handed to the scan as arguments (state/compile.py ARG_STATICS), "
+        "with xs and carry every pass: pv_node_ok [V, N] whole, or its "
+        "patch's payload where a carried session keeps it on the device "
+        "(state/resident.py).",
+    "volume_resident_patches_total":
+        "Device-resident volume arrays (VolumeBinding's pv_node_ok [V, N], "
+        "NodeVolumeLimits' on_node [N, C]) that a pass brought up to date "
+        "with a jitted patch of the rows and cells the volume carry wrote "
+        "since the session's last pass (state/resident.py): one an array "
+        "a pass, none for an array nothing was written to.",
+    "volume_resident_uploads_total":
+        "Device-resident volume arrays a pass sent whole instead, by "
+        "reason: first (a session's first pass), resync, nodes (another "
+        "node table), drivers (another set of CSI drivers with a limit), "
+        "bucket (an axis grew: another shape), overflow (more rows or "
+        "cells written than a patch's payload holds).  A throw-away "
+        "carry and an empty axis keep nothing and count nothing.",
     "volume_table_pvs":
         "PersistentVolumes in the last pass's volume table (the V axis "
         "before padding).",

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from kube_scheduler_simulator_tpu.plugins import nodevolumelimits, volumebinding
+from kube_scheduler_simulator_tpu.state import resident
 
 N, V, C, D = 5000, 8192, 8192, 1
 
@@ -92,3 +93,20 @@ def test_volume_binding_kernels_compile_for_v5e(one_chip, no_persistent_cache):
         return code, volumebinding.bind_update(static, xs, carry, selected)
 
     _compile(step, one_chip, static, xs, carry, _s((), jnp.int32))
+
+
+@pytest.mark.parametrize("kept, shape", [
+    (resident.RowsResident, (V, N)),      # pv_node_ok: old[src], fresh rows set
+    (resident.CellsResident, (N, C)),     # on_node: cells set
+])
+def test_the_resident_patches_compile_for_v5e(kept, shape, one_chip,
+                                              no_persistent_cache):
+    """state/resident.py's two patches at the cell's shapes: a new array
+    of the old one's size and nothing beside it (no donation, no
+    cluster-sized temporary)."""
+    like = _s(shape, jnp.bool_)
+    compiled = _compile(kept.patch_fn, one_chip, like, kept()._shapes(like))
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == V * N
+    assert memory.alias_size_in_bytes == 0
+    assert memory.temp_size_in_bytes < V * N

@@ -205,6 +205,35 @@ def test_upload_tree_leaf_kinds():
     assert upload_tree({"n": 1, "s": "x"}) == {"n": 1, "s": "x"}
 
 
+@pytest.mark.parametrize("shape", [(3,), (4, 5), (2, 0)])
+def test_a_device_leaf_stays_and_is_not_packed(monkeypatch, shape):
+    """A leaf that is on the device already (a carried session's resident
+    arrays, state/resident.py) is returned as the same object, and the
+    packed buffers hold the numpy leaves' bytes alone."""
+    resident = jnp.ones(shape, dtype=bool)
+    tree = {"kept": resident, "flags": np.array([True, False]),
+            "rows": np.arange(6, dtype=np.int32).reshape(2, 3)}
+    sent = []
+    real_put = jax.device_put
+
+    def recording_put(bufs, *a, **kw):
+        sent.append(bufs)
+        return real_put(bufs, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", recording_put)
+    before = TRACER.counter_totals().get("workload_h2d_transfers_total", 0)
+    got = upload_tree(tree)
+    assert got["kept"] is resident
+    assert len(sent) == 1
+    assert {dt: buf.nbytes for dt, buf in sent[0].items()} == {
+        "bool": 2, "int32": 24}
+    assert TRACER.counter_totals()["workload_h2d_transfers_total"] - before == 2
+    np.testing.assert_array_equal(np.asarray(got["rows"]), tree["rows"])
+    # a tree of device leaves alone sends nothing
+    assert upload_tree({"kept": resident})["kept"] is resident
+    assert len(sent) == 1
+
+
 # ------------------------------------------- nothing is read back, one site
 
 
