@@ -43,7 +43,11 @@ class NodeDerived:
     image_states, taint_max, name_idx (one value a table); image_row,
     taint_rows, taints_tolerated, dom_idx (one value a fragment, at most
     ROW_CAP a kind, least recently used out); statics_device (one
-    generation: the last pass's uploaded statics under their digest).
+    generation: the last pass's uploaded statics under their digest);
+    codec_ctx (one generation: the native codec's context under the
+    profile's lineup, weights, schema and custom message tables,
+    store/native_decode.py shared_context; None where the LUTs cannot
+    express the lineup).
     Arrays are handed out read-only: every consumer copies them into its
     own [P, N] block."""
 

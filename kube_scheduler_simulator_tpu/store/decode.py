@@ -34,17 +34,17 @@ from ..plugins.registry import PLUGIN_REGISTRY
 
 
 def _native_ctx(cw):
-    """Per-workload native-codec context; None disables the fast path
-    (or set KSS_TPU_DISABLE_NATIVE=1 to force the Python encoder)."""
+    """The native-codec context this workload decodes through: the one
+    its node table's memo keeps for its profile
+    (native_decode.shared_context), looked up once a cw; None disables
+    the fast path (or set KSS_TPU_DISABLE_NATIVE=1 to force the Python
+    encoder)."""
     if os.environ.get("KSS_TPU_DISABLE_NATIVE") == "1":
         return None
     if "_native_ctx" not in cw.host:
         from . import native_decode
 
-        try:
-            cw.host["_native_ctx"] = native_decode.build_context(cw)
-        except Exception:
-            cw.host["_native_ctx"] = None
+        cw.host["_native_ctx"] = native_decode.shared_context(cw)
     return cw.host["_native_ctx"]
 
 _DECODERS = {

@@ -1,10 +1,14 @@
 """Native fast path for the annotation decoder.
 
-Builds per-workload context (name arrays, sorted orders, message LUTs) for
-native/annotation_codec.cpp and encodes the three heavy blobs
-(filter-result, score-result, finalscore-result) in C++.  Used by
-store/decode.py when the native codec is available; output is
-byte-identical to the Python path (asserted by tests/test_native_codec.py).
+Builds the context (name arrays, sorted orders, message LUTs) that
+native/annotation_codec.cpp encodes the three heavy blobs (filter-result,
+score-result, finalscore-result) from.  The context is a value derived
+from the node table and the profile: it is kept on the table's memo
+(shared_context) and shared by every pass that compiles against that
+table; what a pass adds, its pods' plugin-ran / score-skip rows, is kept
+on the pass's own cw (pass_rows).  Used by store/decode.py when the
+native codec is available; output is byte-identical to the Python path
+(asserted by tests/test_native_codec.py, tests/test_codec_ctx_carry.py).
 """
 
 from __future__ import annotations
@@ -22,6 +26,7 @@ from ..plugins import (
     volumebinding, volumerestrictions, volumezone,
 )
 from ..plugins.noderesources import decode_fit_filter
+from ..utils.tracing import TRACER
 
 _MAX_FIT_LUT_BITS = 16
 
@@ -31,8 +36,73 @@ def _c_str_array(strings: list[bytes]):
     return arr
 
 
+def context_key(cw) -> tuple:
+    """Everything build_context reads beside the node table: the filter
+    and scorer lineups, the scorers' weights, the resource schema's
+    columns (NodeResourcesFit's LUT renders resource names; schema.n is
+    their count) and the interned message tables of the custom plugins
+    in the lineup."""
+    filter_names = cw.config.filters()
+    score_names = cw.config.scorers()
+    custom = cw.host.get("custom_msgs", {})
+    return (tuple(filter_names), tuple(score_names),
+            tuple(cw.config.weight(nm) for nm in score_names),
+            tuple(cw.schema.columns),
+            tuple((nm, tuple(custom[nm])) for nm in filter_names
+                  if nm in custom))
+
+
+def shared_context(cw):
+    """The codec context of cw's node table and profile, or None where
+    the library is missing or the LUTs cannot express the lineup.  One
+    generation on the table's memo (state/nodes.py NodeDerived, kind
+    codec_ctx): a pass on an unchanged table under an unchanged profile
+    is a hit, a None is carried like a context (the lineup is probed once
+    a table, whether the build returned None or raised), and the C side
+    is freed when the memo lets go: with the table on any node change, or
+    when another key replaces the generation.  Nothing of a pass is on
+    it, so passes may decode on it at once."""
+    def make():
+        with TRACER.span("codec_ctx_build", nodes=cw.node_table.n):
+            # a build that raises is carried as None, like one that
+            # declines the lineup: the Python encoder serves
+            try:
+                return build_context(cw)
+            except Exception:
+                return None
+
+    return cw.node_table.derived.generation(
+        "codec_ctx", context_key(cw), make)
+
+
+def pass_rows(cw) -> tuple[np.ndarray, np.ndarray]:
+    """(active [P, F], sskip [P, S]) uint8: which Filter plugins ran and
+    which scorers were skipped for each pod of this pass, in lineup
+    order; a row slice hands C a contiguous pointer.  Built once a cw and
+    kept in cw.host."""
+    rows = cw.host.get("_codec_rows")
+    if rows is None:
+        def columns(flags, names):
+            if not names:
+                return np.zeros((cw.n_pods, 0), bool)
+            return np.stack([np.asarray(flags[nm], bool) for nm in names],
+                            axis=1)
+
+        rows = cw.host["_codec_rows"] = (
+            np.ascontiguousarray(
+                ~columns(cw.host.get("filter_skip", {}), cw.config.filters()),
+                np.uint8),
+            np.ascontiguousarray(
+                columns(cw.host.get("score_skip", {}), cw.config.scorers()),
+                np.uint8))
+    return rows
+
+
 def build_context(cw):
-    """-> context dict or None when a plugin's messages can't be LUT'd."""
+    """-> a fresh _NativeCtx from cw's node table, profile, schema and
+    custom message tables (context_key), or None when the library is
+    missing or a plugin's messages can't be LUT'd.  Nothing of cw's pods
+    is read; decoders take the one shared_context keeps."""
     lib = get_lib()
     if lib is None:
         return None
@@ -66,7 +136,7 @@ def build_context(cw):
             lut = [ports.ERR_NODE_PORTS.encode()]
             per_node.append(0)
         elif name == "TaintToleration":
-            stride = max((len(t) for t in table.taints), default=0)
+            stride = table.max_taints
             if stride == 0:
                 lut = [b""] * n  # never indexed (no taints -> no failures)
                 stride = 1
@@ -149,35 +219,23 @@ def build_context(cw):
         _i32p(lut_off_arr), _u8p(per_node_arr),
         _i32p(kinds), _i64p(weights), int(topologyspread._BIG),
     )
-    ctx = _NativeCtx(lib, cptr, n)
-    # per-pod plugin-ran / score-skip rows for the fused path (row slices
-    # hand C a contiguous [F]/[S] uint8 pointer without per-pod rebuilds)
-    fskip = cw.host.get("filter_skip", {})
-    sskip = cw.host.get("score_skip", {})
-    p = cw.n_pods
-    ctx.active_rows = np.ascontiguousarray(
-        ~np.stack([np.asarray(fskip[nm], bool) for nm in filter_names], axis=1)
-        if filter_names else np.zeros((p, 0), bool), np.uint8)
-    ctx.sskip_rows = np.ascontiguousarray(
-        np.stack([np.asarray(sskip[nm], bool) for nm in score_names], axis=1)
-        if score_names else np.zeros((p, 0), bool), np.uint8)
-    ctx.has_tsp_score = "PodTopologySpread" in score_names
-    return ctx
+    return _NativeCtx(lib, cptr, n, "PodTopologySpread" in score_names)
 
 
 class _NativeCtx:
-    """Owns one C-side codec context; freed with the workload."""
+    """Owns one C-side codec context; freed with the table's memo (or
+    with the last cw or in-flight _ChunkHandle that still holds it).
+    Immutable once built: the C side only reads it, so any number of
+    passes and threads may decode on it at once."""
 
-    __slots__ = ("lib", "ptr", "n", "active_rows", "sskip_rows",
-                 "has_tsp_score", "take", "peek", "__weakref__")
+    __slots__ = ("lib", "ptr", "n", "has_tsp_score", "take", "peek",
+                 "__weakref__")
 
-    def __init__(self, lib, ptr, n):
+    def __init__(self, lib, ptr, n, has_tsp_score):
         self.lib = lib
         self.ptr = ptr
         self.n = n
-        self.active_rows = None
-        self.sskip_rows = None
-        self.has_tsp_score = False
+        self.has_tsp_score = has_tsp_score
         # blob -> str builder: plain memcpy when the ctx proves every
         # emitted byte ASCII, else the UTF-8-validating decode
         all_ascii = lib.ctx_all_ascii(ptr)
@@ -303,8 +361,9 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
     elem = packed.dtype.itemsize
     packed_ptr = packed.ctypes.data + r_lo * n * elem
 
-    active = ctx.active_rows[lo:hi]   # [c, F], contiguous row slice
-    sskip = ctx.sskip_rows[lo:hi]     # [c, S]
+    active_rows, sskip_rows = pass_rows(rr.cw)
+    active = active_rows[lo:hi]   # [c, F], contiguous row slice
+    sskip = sskip_rows[lo:hi]     # [c, S]
     want = np.ascontiguousarray(
         np.asarray(rr.feasible_count[lo:hi]) > 1, np.uint8)
 
@@ -450,12 +509,13 @@ def decode_pod_fused(ctx: _NativeCtx, rr, i: int, hi: int,
         ig_row = _tsp_ignored_cached(rr, ci, packed.shape[0])[r]
         ignored_ptr = _u8p(ig_row)
 
+    active_rows, sskip_rows = pass_rows(rr.cw)
     out_blobs = (ctypes.c_void_p * 3)()
     out_lens = (ctypes.c_int64 * 3)()
     failed_entries = ctx.lib.ctx_decode_pod(
         ctx.ptr,
         prow.ctypes.data_as(ctypes.c_void_p), packed.dtype.itemsize, code_bits,
-        _u8p(ctx.active_rows[hi]), _u8p(ctx.sskip_rows[hi]),
+        _u8p(active_rows[hi]), _u8p(sskip_rows[hi]),
         col_ptrs, col_elem, ignored_ptr, 1 if want_scores else 0,
         out_blobs, out_lens,
     )

@@ -214,7 +214,15 @@ def _localize_ndarrays(root) -> None:
     handed over — preload-TSan cannot see that happens-before and would
     report every input read as a race against the device memset.  The
     copy keeps the codec's OWN concurrency (worker pool, arenas, caches,
-    output arrays) fully checked."""
+    output arrays) fully checked.  A replay result's compact chunks stay
+    on the device until their first read (framework/replay.py
+    CompactChunks.materialize): they are fetched here first, or the
+    first decode would hand the codec the very pages this step exists
+    to replace."""
+    compact = getattr(root, "_compact", None)
+    if compact is not None:
+        for ci in range(len(compact.packed)):
+            compact.materialize(ci)
     seen: set[int] = set()
     stack = [root]
     while stack:

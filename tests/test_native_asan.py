@@ -18,7 +18,10 @@ import pytest
 
 pytestmark = pytest.mark.slow
 
-_SUITE = ["tests/test_native_codec.py", "tests/test_chunk_decode.py"]
+_SUITE = ["tests/test_native_codec.py", "tests/test_chunk_decode.py",
+          # a context shared by passes and freed with its table (PR 41):
+          # a use after free or a double free shows here
+          "tests/test_codec_ctx_carry.py"]
 
 
 def _toolchain_lib(name: str) -> str | None:

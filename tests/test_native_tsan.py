@@ -2,15 +2,17 @@
 
 The chunk-granular decoder owns real hand-rolled concurrency: a
 persistent work-stealing worker pool, per-call output arenas, and
-per-thread FilterCaches that survive across chunks.  This runs the
-4-thread concurrent-chunk soak from test_chunk_decode.py against a
-`-fsanitize=thread` build of the codec in a subprocess, with the TSan
+per-thread FilterCaches that survive across chunks; and one codec
+context is read by every pass on its node table at once (PR 41).  This
+runs the 4-thread concurrent-chunk soak from test_chunk_decode.py and
+the two-passes-on-one-context soak from test_codec_ctx_carry.py against
+a `-fsanitize=thread` build of the codec in a subprocess, with the TSan
 runtime preloaded ahead of an uninstrumented Python.
 
 Two harness accommodations keep the check honest (see
 kube_scheduler_simulator_tpu/native/tsan_suppressions.txt):
-KSS_TPU_TSAN_LOCALIZE=1 makes the soak copy the replay buffers to
-main-thread-owned memory first (preload-TSan cannot see jax's device
+KSS_TPU_TSAN_LOCALIZE=1 makes the soaks fetch and copy the replay buffers
+to main-thread-owned memory first (preload-TSan cannot see jax's device
 sync, so codec reads of XLA-allocated pages would all report), and the
 suppressions file silences XLA's own internally-synchronized thread
 pool.  Races between codec threads have no frames in either and fail
@@ -24,6 +26,13 @@ import sys
 import pytest
 
 pytestmark = pytest.mark.slow
+
+_SUITE = [
+    "tests/test_chunk_decode.py::test_chunk_decode_threaded_soak",
+    # one context, two passes, four threads: the C side only reads it
+    "tests/test_codec_ctx_carry.py"
+    "::test_two_threads_decoding_two_passes_at_once_give_the_serial_bytes",
+]
 
 _SUPPRESSIONS = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -69,8 +78,7 @@ def test_chunk_decode_soak_under_tsan(tmp_path):
         JAX_PLATFORMS="cpu",
     )
     r = subprocess.run(
-        [sys.executable, "-m", "pytest",
-         "tests/test_chunk_decode.py::test_chunk_decode_threaded_soak",
+        [sys.executable, "-m", "pytest", *_SUITE,
          "-q", "-p", "no:cacheprovider"],
         cwd=repo, env=env, capture_output=True, text=True, timeout=1800)
     tail = (r.stdout + "\n" + r.stderr)[-4000:]
