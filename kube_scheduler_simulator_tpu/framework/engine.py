@@ -21,6 +21,7 @@ import copy
 import functools
 import os
 import time
+from typing import NamedTuple
 
 import numpy as np
 
@@ -28,11 +29,12 @@ from .replay import (
     considered_rows, count_narrowed, filter_rejected_rows, replay)
 from .unschedulable import pod_key
 from ..cluster.store import Conflict, NotFound, ObjectStore, volume_manifests
+from ..utils.env import env_int
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
 from ..state.compile import compile_workload
 from ..store import annotations as ann
-from ..store.decode import decode_pod_result
+from ..store.decode import decode_chunk_into, decode_pod_result
 from ..store.reflector import StoreReflector
 from ..store.resultstore import ResultStore
 
@@ -127,8 +129,6 @@ class _GangCtx:
 
     def __init__(self, gp_name: str, pending: list[dict], directory,
                  parked_counts: dict):
-        import numpy as np
-
         from .gang import group_key_of
 
         self.gp_name = gp_name
@@ -192,6 +192,15 @@ _GANG_NONE = _NoGang()
 # stepping down after a structural device fault is provably lossless —
 # it trades wall time (host fetch, eager decode) for survival
 _RESIDENCY_MODES = ("device_resident", "host_resident", "eager_decode")
+
+
+class WavePlan(NamedTuple):
+    """What a wave does, decided once (SchedulerEngine._wave_plan): the
+    three columns of the table at the head of docs/wave-pipeline.md."""
+
+    scan: str     # speculative | sequential | host_loop
+    commit: str   # streamed | post_pass | host_loop
+    results: str  # device_lazy | host_lazy | by_chunk | by_pod
 
 
 class _WaveAbort(Exception):
@@ -291,8 +300,6 @@ class _WaveCommitter:
     # ---------------------------------------------- replay-thread side
 
     def on_chunk(self, rr, lo: int, hi: int) -> None:
-        import numpy as np
-
         wave = None
         if self.lazy:
             # chunk HANDOFF only: no decode on the replay thread — a
@@ -316,8 +323,6 @@ class _WaveCommitter:
             # the WHOLE chunk goes down in one call: decode_chunk_into
             # routes it through the chunk-granular native decode (one
             # GIL-released C call per chunk, C-side worker pool)
-            from ..store.decode import decode_chunk_into
-
             decode_chunk_into(rr, lo, hi, self.annotations)
         self._q.put((wave, lo, hi, np.asarray(rr.selected[lo:hi]).copy(),
                      filter_rejected_rows(rr, lo, hi), rr.cw))
@@ -499,7 +504,7 @@ class SchedulerEngine:
                  result_store: ResultStore | None = None,
                  plugin_config: PluginSetConfig | None = None,
                  chunk: int = 512, mesh=None, unroll: int = 2,
-                 pipeline_commit: bool = True):
+                 pipeline_commit: bool = True, residency_floor: int = 0):
         self.store = store
         # chunk-pipelined commit (docs/wave-pipeline.md): commit each
         # decoded chunk on a worker thread while the device scans later
@@ -573,12 +578,16 @@ class SchedulerEngine:
         # injectable for tests (forced-conflict soak asserts the backoff
         # schedule without waiting out real 100ms x 3^n sleeps)
         self._retry_sleep = time.sleep
-        # wave failure protocol (docs/fault-injection.md): the engine's
-        # own degradation-ladder level ON TOP of the env floor
-        # (KSS_TPU_HOST_RESIDENT/KSS_TPU_EAGER_DECODE) — 0 device,
-        # 1 host, 2 eager — and the consecutive-good-waves counter
-        # driving probe-based recovery back up the ladder
-        self._residency = 0
+        # wave failure protocol (docs/fault-injection.md): the rung of
+        # the degradation ladder this engine's waves run on — 0 device,
+        # 1 host, 2 eager; moved by _degrade and _wave_recovered_ok and by
+        # nothing else — and the consecutive-good-waves counter driving
+        # probe-based recovery back up the ladder.  residency_floor is
+        # the tests' pin (the parity suites' reference rungs): the ladder
+        # starts there and recovery never climbs above it; no server
+        # passes it
+        self._residency_floor = residency_floor
+        self._residency = residency_floor
         self._resid_ok_waves = 0
         # multi-session serving (server/sessions.py): the owning
         # session's id, or None for direct engine use.  schedule_pending
@@ -1067,36 +1076,22 @@ class SchedulerEngine:
 
     # ------------------------------------------------ failure protocol
 
-    @staticmethod
-    def _env_int(name: str, default: int) -> int:
-        from ..utils.env import env_int
-
-        return env_int(name, default)
-
-    @staticmethod
-    def _env_residency_floor() -> int:
-        """The ladder level the environment pins as a floor: the engine
-        may degrade BELOW it but never recovers above it."""
-        if os.environ.get("KSS_TPU_EAGER_DECODE") == "1":
-            return 2
-        if os.environ.get("KSS_TPU_HOST_RESIDENT") == "1":
-            return 1
-        return 0
-
-    def _effective_residency(self) -> int:
-        return max(self._env_residency_floor(), self._residency)
-
     def result_mode(self) -> str:
         """The wave's current result-residency rung (device_resident /
         host_resident / eager_decode) — surfaced per session on
         /api/v1/sessions and /readyz (docs/fault-injection.md)."""
-        return _RESIDENCY_MODES[self._effective_residency()]
+        return _RESIDENCY_MODES[self._residency]
+
+    @property
+    def degraded(self) -> bool:
+        """The ladder stands below where the engine started."""
+        return self._residency > self._residency_floor
 
     def _degrade(self, seam: str) -> bool:
         """Step one rung down the ladder after a structural device
         fault.  False when already at the bottom (eager decode has no
         device dependency left to shed)."""
-        cur = self._effective_residency()
+        cur = self._residency
         if cur >= len(_RESIDENCY_MODES) - 1:
             return False
         self._residency = cur + 1
@@ -1119,30 +1114,25 @@ class SchedulerEngine:
     def _wave_recovered_ok(self) -> None:
         """Probe-based recovery: after KSS_TPU_DEGRADE_PROBE_WAVES
         consecutive clean waves at a degraded rung, step back UP one
-        level (never above the env floor).  The next wave is the probe:
+        level (never above the floor).  The next wave is the probe:
         if it faults structurally again, _degrade steps straight back
         down and the counter restarts."""
-        if self._residency <= 0:
-            return
-        floor = self._env_residency_floor()
-        cur = self._effective_residency()
-        if cur <= floor:
-            self._residency = 0  # env already enforces this rung
+        cur = self._residency
+        if cur <= self._residency_floor:
             return
         self._resid_ok_waves += 1
-        if self._resid_ok_waves < self._env_int(
+        if self._resid_ok_waves < env_int(
                 "KSS_TPU_DEGRADE_PROBE_WAVES", 8):
             return
         self._resid_ok_waves = 0
-        new = max(cur - 1, floor)
-        self._residency = 0 if new <= floor else new
+        self._residency = cur - 1
         TRACER.inc("wave_degradations_total",
                    **{"from": _RESIDENCY_MODES[cur],
-                      "to": _RESIDENCY_MODES[new]})
+                      "to": _RESIDENCY_MODES[cur - 1]})
         from ..utils.blackbox import BLACKBOX
 
         BLACKBOX.record("recover", from_mode=_RESIDENCY_MODES[cur],
-                        to_mode=_RESIDENCY_MODES[new])
+                        to_mode=_RESIDENCY_MODES[cur - 1])
 
     def _profile_wave_run(self, pending: list[dict],
                           exclude: set[tuple[str, str]] | None = None
@@ -1177,9 +1167,9 @@ class SchedulerEngine:
             # baseline this wave's post-mortem computes deltas against
             BLACKBOX.wave_start(self.session, pods=len(pending),
                                 mode=self.result_mode())
-            if (self._effective_residency() == 0
+            if (self._residency == 0
                     and materialize_failure_streak(self.session)
-                    >= self._env_int("KSS_TPU_MATERIALIZE_FAIL_LIMIT", 3)):
+                    >= env_int("KSS_TPU_MATERIALIZE_FAIL_LIMIT", 3)):
                 # repeated on-demand D2H failures are a structural device
                 # signal even though they surface on the READ path: step to
                 # host-resident fetch so new waves stop pinning chunks that
@@ -1188,7 +1178,7 @@ class SchedulerEngine:
                 if self._degrade("replay.materialize"):
                     reset_materialize_failures(self.session)
         bound = 0
-        retries_left = self._env_int("KSS_TPU_WAVE_MAX_RETRIES", 3)
+        retries_left = env_int("KSS_TPU_WAVE_MAX_RETRIES", 3)
         delay = 0.02
         while True:
             try:
@@ -1251,18 +1241,6 @@ class SchedulerEngine:
             BLACKBOX.record("wave.end", bound=bound + b,
                             retry=retry or None)
             return bound + b, retry
-
-    def _guarded_replay(self, stage: str, pending: list, fn):
-        """Run one replay under the failure protocol's classification:
-        nothing was committed yet on these paths (the sequential/
-        speculative commits happen in _finish_wave AFTER the replay
-        drains), so a fault retries the whole FILTERED pending list —
-        retrying the filtered list (not the caller's raw one) keeps
-        gate marks and gang-prescreen rejections single-shot."""
-        try:
-            return fn()
-        except BaseException as e:
-            raise _WaveAbort(e, pending, 0, stage) from e
 
     def _profile_wave_attempt(self, pending: list[dict],
                               exclude: set[tuple[str, str]] | None = None
@@ -1353,15 +1331,20 @@ class SchedulerEngine:
                 pod_columns=self._pod_bank(pods_all),
             )
             self._last_cw = NodeTableReuse(cw)
-        if self._needs_host_path():
+        # the Coscheduling plugin's name where the vectorized quorum pass
+        # stands in for its per-pod Permit calls this wave
+        # (docs/gang-scheduling.md): the plan and the speculative stream
+        # leave it out of the profile
+        vectorized = gp is not None and self._gang_vectorized()
+        ignore = frozenset({gp.name}) if vectorized else frozenset()
+        plan = self._wave_plan(ignore)
+        if plan.scan == "host_loop":
             # gangs route through the per-pod Permit machinery here
             # (the Coscheduling plugin stays in the lifecycle set)
             return self._schedule_host_path(cw, pending)
-
-        if gp is not None and self._gang_vectorized():
+        if vectorized:
             # setting the wave ctx removes the gang plugin from the
-            # custom-lifecycle set: the quorum pass below replaces its
-            # per-pod Permit calls on both batched commit paths (the
+            # custom-lifecycle set on both batched commit paths (the
             # falsy sentinel keeps gang-free waves on the plain code)
             ctx = (_GangCtx(gp.name, pending, gang_dir,
                             self._gang_parked_counts())
@@ -1371,281 +1354,161 @@ class SchedulerEngine:
         # a live cluster's node count need not divide the mesh's "nodes"
         # extent; shard only waves where it does and run the rest
         # unsharded (shard_workload would reject the shape) — speculative
-        # dp batching below tolerates mesh=None
+        # dp batching tolerates mesh=None
         mesh = self.mesh
         if mesh is not None:
             from ..parallel.mesh import can_shard
 
             if not can_shard(cw.n_nodes, mesh):
                 mesh = None
+        return self._device_wave(plan, cw, mesh, pending, exclude, ignore)
 
-        from ..store.decode import decode_chunk_into
-
+    def _wave_plan(self, ignore: frozenset = frozenset()) -> WavePlan:
+        """The three decisions of a wave, taken ONCE, after
+        compile_workload, from what the engine observes (the table at the
+        head of docs/wave-pipeline.md, row by row); the executor, the
+        committer and _finish_wave's caller read the value and ask
+        nothing again.  ignore: the gang plugin's name where the
+        vectorized quorum pass handles it this wave (row 11) — it then is
+        no lifecycle plugin and speculation_ok ignores it: its PreFilter
+        ran in the prescreen, admission happens in the quorum pass at
+        commit, it neither filters nor scores on device."""
+        if self._needs_host_path():
+            return WavePlan("host_loop", "host_loop", "by_pod")
+        # _gang_vectorized: the gang plugin is the only lifecycle plugin
+        lifecycle = not ignore and bool(self._custom_lifecycle_plugins())
+        observers = bool(self._extenders_map())
+        # speculative multi-pod rounds, for profiles that admit exact
+        # batching (the stock default profile does not: it enables the
+        # volume family).  KSS_TPU_SPECULATIVE=0 pins the sequential scan:
+        # the parity baseline the golden suite diffs against
+        scan = "sequential"
         if (os.environ.get("KSS_TPU_SPECULATIVE", "1") != "0"
-                and self.extender_service is None
-                and not self._custom_lifecycle_plugins()):
-            # speculative multi-pod rounds, for profiles that admit exact
-            # batching (the stock default profile does not: it enables
-            # the volume family; docs/wave-pipeline.md has the table) — a
-            # single device suffices (a mesh additionally fans the batch
-            # over its "dp" axis; this uses the divisibility-checked
-            # mesh).  KSS_TPU_SPECULATIVE=0 pins the sequential scan: the
-            # parity baseline the golden suite diffs against.  The engine's
-            # vectorized gang plugin is ignored by the eligibility check
-            # (its PreFilter ran in the prescreen, admission happens in
-            # the quorum pass at commit — it neither filters nor scores
-            # on device)
+                and self.extender_service is None and not lifecycle):
             from ..parallel.speculative import speculation_ok
 
-            ignore = (frozenset({gp.name})
-                      if gp is not None and self._gang_wave is not None
-                      else frozenset())
             if speculation_ok(self.plugin_config, have_manifests=True,
                               ignore=ignore):
-                return self._speculative_wave(cw, mesh, pending, exclude,
-                                              len(nodes), ignore)
-
-        if self._custom_lifecycle_plugins():
-            # a custom Reserve/Permit/PreBind can reject mid-wave and abort
-            # the rest — decode per pod so an aborted wave wastes nothing.
+                scan = "speculative"
+        # the sequential post-pass where after_cycle observers see each
+        # pod's annotations in order, a custom Reserve / Permit / PreBind
+        # can reject and abort the wave, or a PostFilter (preemption)
+        # mutates the store mid-commit and requests retry waves
+        streamed = (self.pipeline_commit and not observers and not lifecycle
+                    and not self.plugin_config.postfilters())
+        if lifecycle:
+            # decode per pod so an aborted wave wastes nothing, fetched
             # host-resident: the lifecycle loop consumes every pod's
             # annotations in order, so deferring the D2H would just move
             # the whole transfer out of the scan-overlap window
-            def _lc_replay():
-                with TRACER.span("device_replay", pods=len(pending),
-                                 nodes=len(nodes)) as sp:
-                    rr = replay(
-                        cw, chunk=min(self.chunk, max(len(pending), 1)),
-                        mesh=mesh, unroll=self.unroll,
-                        device_resident=False)
-                return rr, sp.seconds
+            results = "by_pod"
+        elif (self._residency < 2 and not observers
+              and hasattr(self.reflector, "defer_supported")
+              and self.reflector.defer_supported()):
+            # lazy (store/lazy.py): the commit consumes tensor-level
+            # decisions only, so the decode waits for the first read —
+            # unless observers want the bytes in the wave or the store /
+            # reflector pair cannot make deferred results transparent to
+            # readers (no read hooks, no batch surface: the remote HTTP
+            # cluster client) — and so do the heavy tensors, on the
+            # device, while the ladder stands on its top rung
+            results = "device_lazy" if self._residency == 0 else "host_lazy"
+        else:
+            results = "by_chunk"
+        return WavePlan(scan, "streamed" if streamed else "post_pass",
+                        results)
 
-            rr, replay_seconds = self._guarded_replay(
-                "device_replay", pending, _lc_replay)
-            all_annotations = _LazyDecode(rr)
-            self._record_attribution(rr, replay_seconds)
-            return self._finish_wave(cw, rr, all_annotations, pending, exclude)
-
-        if self._can_stream_commit():
-            # chunk-pipelined commit (docs/wave-pipeline.md): a worker
-            # thread runs the commit phase for each decoded chunk (result
-            # -store puts, batched binds/unschedulable marks, reflect
-            # submissions, pod order preserved) while the device scans
-            # later chunks — instead of the whole wave idling through a
-            # sequential post-pass after the replay drains.  In lazy
-            # mode the worker consumes tensor-level decisions only and
-            # the decode leaves the critical path entirely.
-            committer = _WaveCommitter(self, cw.node_table.names, pending,
-                                       gang=self._gang_wave,
-                                       lazy=self._wave_lazy_ok())
-            try:
-                with TRACER.span("replay_and_decode_stream",
-                                 pods=len(pending), nodes=len(nodes)) as sp:
-                    # the worker's commit_stream spans parent under the
-                    # wave's replay span across the thread boundary.
-                    # Lazy waves keep results DEVICE-resident: on_chunk
-                    # is a handoff, the commit consumes decision rows
-                    # only, and the heavy tensors never cross in-wave
-                    # (unless the degradation ladder stepped to host)
-                    committer.parent_span = sp.id
-                    rr = replay(cw, chunk=min(self.chunk, max(len(pending), 1)),
-                                mesh=mesh, unroll=self.unroll,
-                                on_chunk=committer.on_chunk,
-                                device_resident=(
-                                    committer.lazy
-                                    and self._effective_residency() == 0))
-            except BaseException as e:
-                # abort BEFORE reading the watermark: committed chunks
-                # stand (binds/parks through the last gang-cut), queued
-                # chunks drop — then hand the failure protocol the
-                # settled commit boundary so only the suffix retries
-                committer.abort()
-                raise _WaveAbort(e, pending[committer._upto:],
-                                 committer.n_bound, "replay_stream") from e
-            try:
-                result = committer.finish()
-            except BaseException as e:
-                raise _WaveAbort(e, pending[committer._upto:],
-                                 committer.n_bound, "commit_stream") from e
-            self._record_attribution(rr, sp.seconds,
-                                     att=committer.attribution())
-            return result
-
-        if self._wave_lazy_ok():
-            # sequential post-pass, lazy: the replay streams only the
-            # per-pod decision rows (device-resident results — no heavy
-            # tensor D2H, no on_chunk decode); the commit below deposits
-            # LazyWave handles and defers the reflect — first read
-            # materializes D2H + decode (store/lazy.py)
-            from ..store.lazy import LazyWave
-
-            def _lazy_replay():
-                with TRACER.span("replay_and_decode_stream",
-                                 pods=len(pending), nodes=len(nodes)) as sp:
-                    rr = replay(
-                        cw, chunk=min(self.chunk, max(len(pending), 1)),
-                        mesh=mesh, unroll=self.unroll,
-                        device_resident=self._effective_residency() == 0)
-                return rr, sp.seconds
-
-            rr, replay_seconds = self._guarded_replay(
-                "replay_stream", pending, _lazy_replay)
-            self._record_attribution(rr, replay_seconds)
-            return self._finish_wave(
-                cw, rr, None, pending, exclude,
-                lazy_wave=LazyWave(rr, len(pending), sealed=True))
-
-        # stream: each chunk decodes (chunk-granular native call, or the
-        # host thread pool on the fallback ladder) as soon as its
-        # transfer lands, overlapping the device's later chunks
-        all_annotations = [None] * len(pending)
-
-        def _eager_replay():
-            with TRACER.span("replay_and_decode_stream", pods=len(pending),
-                             nodes=len(nodes)) as sp:
-                rr = replay(
-                    cw, chunk=min(self.chunk, max(len(pending), 1)),
-                    mesh=mesh, unroll=self.unroll,
-                    on_chunk=lambda rr_, lo, hi: decode_chunk_into(
-                        rr_, lo, hi, all_annotations))
-            return rr, sp.seconds
-
-        rr, replay_seconds = self._guarded_replay(
-            "replay_stream", pending, _eager_replay)
-        self._record_attribution(rr, replay_seconds)
-        return self._finish_wave(cw, rr, all_annotations, pending, exclude)
-
-    def _speculative_wave(self, cw, mesh, pending,
-                          exclude: set[tuple[str, str]] | None,
-                          n_nodes: int, ignore: frozenset = frozenset()
-                          ) -> tuple[int, str | None]:
-        """The wave for profiles that admit exact batching
-        (docs/wave-pipeline.md speculative-wave stage; the stock default
-        profile is not one of them): vmapped rounds of B queued pods against
-        the frozen carry, a conflict oracle accepting the provably
-        non-interfering prefix, accepted results streamed to the commit
-        worker on the standard chunk grid — so lazy decode, device
-        residency, the gang-cut watermark and the wave failure
-        protocol's uncommitted-suffix retry all compose unchanged.  A
-        contention collapse hands the wave's remainder to the
-        sequential chunked scan in-stream (parallel/speculative.py)."""
-        from ..parallel.speculative import replay_speculative_stream
-        from ..store.decode import decode_chunk_into
-
-        namespaces = self._list_shared("namespaces")
+    def _device_wave(self, plan: WavePlan, cw, mesh, pending: list[dict],
+                     exclude: set[tuple[str, str]] | None,
+                     ignore: frozenset = frozenset()
+                     ) -> tuple[int, str | None]:
+        """Every wave the device scans, run as its plan says: the replay
+        (the chunked sequential scan, or the speculative rounds, which
+        deliver on the same chunk grid through the same on_chunk
+        contract, so lazy decode, device residency, the gang-cut
+        watermark and the uncommitted-suffix retry compose unchanged),
+        the chunk consumer, one span, one abort protocol, the commit."""
+        lazy = plan.results in ("device_lazy", "host_lazy")
         gang = self._gang_wave if self._gang_wave else None
-        chunk = min(self.chunk, max(len(pending), 1))
-        if self._can_stream_commit():
+        committer = all_annotations = on_chunk = None
+        if plan.commit == "streamed":
+            # chunk-pipelined commit (docs/wave-pipeline.md): a worker
+            # thread runs the commit phase for each chunk (result-store
+            # puts, batched binds / unschedulable marks, reflect
+            # submissions, pod order preserved) while the device scans
+            # later chunks; on a lazy wave on_chunk is a handoff and the
+            # worker consumes decision rows only
             committer = _WaveCommitter(self, cw.node_table.names, pending,
-                                       gang=gang, lazy=self._wave_lazy_ok())
-            try:
-                with TRACER.span("replay_and_decode_stream",
-                                 pods=len(pending), nodes=n_nodes,
-                                 mode="speculative") as sp:
-                    committer.parent_span = sp.id
-                    rr, _stats = replay_speculative_stream(
-                        cw, mesh, chunk=chunk, unroll=self.unroll,
-                        pods=pending, namespaces=namespaces,
-                        on_chunk=committer.on_chunk,
-                        device_resident=(
-                            committer.lazy
-                            and self._effective_residency() == 0),
-                        gang=gang, ignore=ignore)
-            except BaseException as e:
-                # abort BEFORE reading the watermark: committed chunks
-                # stand, queued chunks drop — then hand the failure
-                # protocol the settled commit boundary so only the
-                # suffix retries (same shape as the scan stream)
-                committer.abort()
-                raise _WaveAbort(e, pending[committer._upto:],
-                                 committer.n_bound,
-                                 "speculative_replay") from e
-            try:
-                result = committer.finish()
-            except BaseException as e:
-                raise _WaveAbort(e, pending[committer._upto:],
-                                 committer.n_bound, "commit_stream") from e
-            self._record_attribution(rr, sp.seconds,
-                                     att=committer.attribution())
-            return result
-        # sequential-commit shell (pipeline_commit=False, postfilter
-        # preemption, plugin-extender observers): run the stream without
-        # the worker, commit through the shared post-pass.  Eager waves
-        # decode chunk-by-chunk DURING the stream — the pooled chunk
-        # decoder overlapped with later rounds — never one whole-wave
-        # decode_chunk_into(0, P) call on the commit thread
-        lazy = self._wave_lazy_ok()
-        all_annotations = None
-        on_chunk = None
-        if not lazy:
+                                       gang=gang, lazy=lazy)
+            on_chunk = committer.on_chunk
+        elif plan.results == "by_chunk":
+            # each chunk decodes (chunk-granular native call, or the host
+            # thread pool on the fallback ladder) as soon as its transfer
+            # lands, overlapping the device's later chunks — never one
+            # whole-wave decode on the commit thread
             all_annotations = [None] * len(pending)
 
             def on_chunk(rr_, lo, hi):
                 decode_chunk_into(rr_, lo, hi, all_annotations)
 
-        def _spec_replay():
-            with TRACER.span("replay_and_decode_stream", pods=len(pending),
-                             nodes=n_nodes, mode="speculative") as sp:
-                rr, _stats = replay_speculative_stream(
-                    cw, mesh, chunk=chunk, unroll=self.unroll,
-                    pods=pending, namespaces=namespaces, on_chunk=on_chunk,
-                    device_resident=(lazy
-                                     and self._effective_residency() == 0),
-                    gang=gang, ignore=ignore)
-            return rr, sp.seconds
+        # self.chunk as it is: the callee clamps it to the queue's length
+        kw = dict(chunk=self.chunk, unroll=self.unroll, on_chunk=on_chunk,
+                  device_resident=plan.results == "device_lazy")
+        span, stage, attrs = "replay_and_decode_stream", "replay_stream", {}
+        if plan.scan == "speculative":
+            from ..parallel.speculative import replay_speculative_stream
 
-        rr, spec_seconds = self._guarded_replay(
-            "speculative_replay", pending, _spec_replay)
-        self._record_attribution(rr, spec_seconds)
+            stage, attrs = "speculative_replay", {"mode": "speculative"}
+            kw.update(pods=pending, gang=gang, ignore=ignore,
+                      namespaces=self._list_shared("namespaces"))
+        elif plan.results == "by_pod":
+            span = stage = "device_replay"
+        try:
+            with TRACER.span(span, pods=len(pending),
+                             nodes=self._wave_node_count, **attrs) as sp:
+                if committer is not None:
+                    # the worker's commit_stream spans parent under the
+                    # wave's replay span across the thread boundary
+                    committer.parent_span = sp.id
+                if plan.scan == "speculative":
+                    rr, _stats = replay_speculative_stream(cw, mesh, **kw)
+                else:
+                    rr = replay(cw, mesh=mesh, **kw)
+        except BaseException as e:
+            if committer is None:
+                # nothing was committed yet (_finish_wave commits AFTER
+                # the replay drains), so a fault retries the whole
+                # FILTERED pending list — not the caller's raw one: gate
+                # marks and gang-prescreen rejections stay single-shot
+                raise _WaveAbort(e, pending, 0, stage) from e
+            # abort BEFORE reading the watermark: committed chunks
+            # stand (binds/parks through the last gang-cut), queued
+            # chunks drop — then hand the failure protocol the
+            # settled commit boundary so only the suffix retries
+            committer.abort()
+            raise _WaveAbort(e, pending[committer._upto:],
+                             committer.n_bound, stage) from e
+        if committer is not None:
+            try:
+                result = committer.finish()
+            except BaseException as e:
+                raise _WaveAbort(e, pending[committer._upto:],
+                                 committer.n_bound, "commit_stream") from e
+            self._record_attribution(rr, sp.seconds,
+                                     att=committer.attribution())
+            return result
+        self._record_attribution(rr, sp.seconds)
+        lazy_wave = None
         if lazy:
+            # the commit deposits LazyWave handles and defers the
+            # reflect: the first read materializes D2H + decode
             from ..store.lazy import LazyWave
 
-            return self._finish_wave(
-                cw, rr, None, pending, exclude,
-                lazy_wave=LazyWave(rr, len(pending), sealed=True))
-        return self._finish_wave(cw, rr, all_annotations, pending, exclude)
-
-    def _wave_lazy_ok(self) -> bool:
-        """True when this wave may defer annotation decode to first read
-        (store/lazy.py): lazy is the default on the batched tensor paths
-        — the commit consumes tensor-level decisions only, so decoding
-        on the critical path buys nothing, and the heavy replay tensors
-        stay DEVICE-resident until a cold read (framework/replay.py
-        device-residency; KSS_TPU_HOST_RESIDENT=1 keeps lazy decode but
-        fetches to host in-wave) — and turns off when
-
-          * KSS_TPU_EAGER_DECODE=1 (the golden/parity baseline mode);
-          * plugin-extender observers are registered (after_cycle sees
-            each pod's decoded annotations during the wave);
-          * the store/reflector pair cannot make deferred results
-            transparent to readers (no read hooks / no batch surface —
-            e.g. the remote HTTP cluster client).
-
-        The host-interleaved and custom-lifecycle paths decode per pod
-        regardless (their cycles consume annotations inline).  The
-        degradation ladder's bottom rung (docs/fault-injection.md)
-        forces eager decode the same way the env baseline does."""
-        if os.environ.get("KSS_TPU_EAGER_DECODE") == "1":
-            return False
-        if self._effective_residency() >= 2:
-            return False
-        if self._extenders_map():
-            return False
-        return self.reflector.defer_supported() \
-            if hasattr(self.reflector, "defer_supported") else False
-
-    def _can_stream_commit(self) -> bool:
-        """True when nothing in the configuration forces the sequential
-        post-pass: no plugin-extender observers (after_cycle sees each
-        pod's annotations in order), no custom lifecycle (Reserve/Permit/
-        PreBind can reject and abort the wave), and no PostFilter
-        (preemption mutates the store mid-commit and requests retry
-        waves).  Extender webhooks already forced the host path before
-        this point."""
-        return (self.pipeline_commit
-                and not self._extenders_map()
-                and not self._custom_lifecycle_plugins()
-                and not self.plugin_config.postfilters())
+            lazy_wave = LazyWave(rr, len(pending), sealed=True)
+        elif plan.results == "by_pod":
+            all_annotations = _LazyDecode(rr)
+        return self._finish_wave(cw, rr, all_annotations, pending, exclude,
+                                 lazy_wave=lazy_wave)
 
     def _record_attribution(self, rr, replay_seconds: float,
                             att: dict | None = None) -> None:
@@ -1766,7 +1629,7 @@ class SchedulerEngine:
                 # cycle (hooks and plugins must not reach shared manifests)
                 priv = copy.deepcopy(pod) if emap or has_lc else pod
                 if emap:
-                    # extender observers force eager waves (_wave_lazy_ok)
+                    # extender observers force eager waves (_wave_plan)
                     for hook in emap.values():
                         hook.after_cycle(priv, annotations, self.result_store)
                 sel = int(rr.selected[i])
@@ -2445,8 +2308,6 @@ class SchedulerEngine:
     def _webhook_filter(self, pod, names, name_to_idx, feasible) -> bool:
         """Extender filter verbs narrow `feasible` in place; returns True
         on an unignorable extender error."""
-        import numpy as np
-
         extenders = self.extender_service.extenders if self.extender_service else []
         for idx, ext in enumerate(extenders):
             if not ext.filter_verb or not feasible.any():
@@ -2496,8 +2357,6 @@ class SchedulerEngine:
         return False
 
     def _webhook_prioritize(self, pod, names, name_to_idx, feasible, total) -> None:
-        import numpy as np
-
         extenders = self.extender_service.extenders if self.extender_service else []
         for idx, ext in enumerate(extenders):
             if not ext.prioritize_verb or feasible.sum() <= 1:
@@ -2531,8 +2390,6 @@ class SchedulerEngine:
         After-rewrites change the framework outcome only (an own-failure
         rewritten to success lets LATER plugins run and record).
         Returns (eff_feasible [N] bool, filter_map for the record)."""
-        import numpy as np
-
         from ..scheduler.debuggable import has_hook
         from ..store.decode import decode_filter_message
 
@@ -2585,8 +2442,6 @@ class SchedulerEngine:
         (the store's AddNormalizedScoreResult runs before AfterNormalize);
         the framework total additionally reflects AfterNormalize."""
         import jax.numpy as jnp
-        import numpy as np
-
         from .pipeline import renormalize
         from ..scheduler.debuggable import has_hook
 
@@ -2632,8 +2487,6 @@ class SchedulerEngine:
     def _host_pod_loop(self, cw, pending, eval_fn, bind_fn, carry, names,
                        name_to_idx, postfilter_on) -> tuple[int, str | None]:
         import jax
-        import numpy as np
-
         from .replay import ReplayResult
 
         from ..scheduler.debuggable import has_hook

@@ -71,7 +71,7 @@ class StepOut(NamedTuple):
 
 
 class CompactOut(NamedTuple):
-    """Transfer-optimized step output (framework/replay.py collect path).
+    """Transfer-optimized step output (what framework/replay.py's scan emits).
 
     The annotation decoder only ever needs, per node, the FIRST failing
     filter plugin and its code (the framework stops at the first failure;

@@ -27,9 +27,10 @@ tensors don't cross — the wave fetches only per-pod DECISION ROWS
 jit'd per-chunk attribution sums) and the heavy packed/raw arrays stay
 live in device memory, materializing per chunk on first cold read
 (_CompactChunks.host, memoized + exactly-once) with an LRU spill budget
-(KSS_TPU_DEVICE_RESULT_BUDGET_MB) bounding HBM across waves.
-KSS_TPU_HOST_RESIDENT=1 / KSS_TPU_EAGER_DECODE=1 are the bit-identical
-host-fetch parity rungs.
+(KSS_TPU_DEVICE_RESULT_BUDGET_MB) bounding HBM across waves.  The
+host-resident fetch (device_resident=False) is the bit-identical rung the
+engine's degradation ladder steps down to and the parity suites compare
+against.
 
 The last chunk is padded; padded steps carry `is_pad` and never bind
 (pipeline masks their selection to -1).
@@ -1465,33 +1466,23 @@ class _DeviceAttribution:
                         out.feasible_count, fskip_c, sskip_c, m)
 
 
-def _resolve_device_resident(device_resident: bool | None, collect: bool,
+def _resolve_device_resident(device_resident: bool | None,
                              on_chunk) -> bool:
-    """Result-residency mode for one replay: device-resident is the
-    default whenever no streaming consumer decodes in-wave (on_chunk is
-    None, or the caller — the lazy streaming committer — asked for it
-    explicitly).  KSS_TPU_EAGER_DECODE=1 and KSS_TPU_HOST_RESIDENT=1
-    force the host-resident fetch engine-wide: the bit-identical parity
-    rungs (docs/wave-pipeline.md device-residency stage)."""
-    if not collect:
-        return False
-    if os.environ.get("KSS_TPU_EAGER_DECODE") == "1":
-        return False
-    if os.environ.get("KSS_TPU_HOST_RESIDENT") == "1":
-        return False
+    """Result-residency mode for one replay: what the caller says (the
+    engine passes its wave plan's), and where it says nothing (None),
+    device-resident whenever no streaming consumer decodes in-wave
+    (on_chunk is None)."""
     if device_resident is None:
         return on_chunk is None
     return bool(device_resident)
 
 
-def replay(cw: CompiledWorkload, chunk: int = 512, collect: bool = True,
+def replay(cw: CompiledWorkload, chunk: int = 512,
            unroll: int = 1, filter_only: bool = False,
            mesh=None, on_chunk=None,
            device_resident: bool | None = None) -> ReplayResult:
     """Run the full queue; returns host-side result arrays.
 
-    collect=False skips device->host transfer of the per-node tensors
-    (keeps selected/feasible only) — the benchmark's pure-throughput mode.
     unroll: lax.scan unroll factor — trades compile time for lower
     per-iteration overhead (the step's ops are tiny [N] vector ops, so
     fixed per-op cost dominates; unrolling lets XLA pipeline iterations).
@@ -1517,11 +1508,9 @@ def replay(cw: CompiledWorkload, chunk: int = 512, collect: bool = True,
     device_resident: keep the heavy compact tensors as live device
     arrays and fetch only per-pod decision rows in-wave (the default
     when no on_chunk consumer decodes in-wave); a cold read performs the
-    memoized D2H per chunk.  None = auto; KSS_TPU_EAGER_DECODE=1 /
-    KSS_TPU_HOST_RESIDENT=1 force the host-resident fetch regardless.
+    memoized D2H per chunk.  None = auto.
     """
-    device_resident = _resolve_device_resident(device_resident, collect,
-                                               on_chunk)
+    device_resident = _resolve_device_resident(device_resident, on_chunk)
     if mesh is not None:
         from ..parallel.mesh import shard_workload
 
@@ -1542,7 +1531,7 @@ def replay(cw: CompiledWorkload, chunk: int = 512, collect: bool = True,
     tiers = (("i64",) if "i64" in cw.host.get("score_dtypes", ())
              else (None, "i32", "i64"))
     for wide in tiers:
-        result = _replay_run(cw, chunk, collect, unroll, mesh, wide=wide,
+        result = _replay_run(cw, chunk, unroll, mesh, wide=wide,
                              on_chunk=on_chunk,
                              device_resident=device_resident)
         if result is not None:
@@ -1587,19 +1576,7 @@ def _compact_plan(cw: CompiledWorkload, wide: str | None):
 _MAX_INFLIGHT = 4
 
 
-class _TinyOut:
-    """collect=False holder: keeps ONLY the per-pod scalars referenced so
-    the chunk's big result buffers free as soon as the device is done."""
-
-    _fields = ("selected", "feasible_count", "prefilter_reject")
-
-    def __init__(self, out):
-        self.selected = out.selected
-        self.feasible_count = out.feasible_count
-        self.prefilter_reject = out.prefilter_reject
-
-
-def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
+def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
                 mesh, wide: str | None, on_chunk=None,
                 device_resident: bool = False) -> ReplayResult | None:
     p = cw.n_pods
@@ -1618,29 +1595,7 @@ def _replay_run(cw: CompiledWorkload, chunk: int, collect: bool, unroll: int,
         arg_statics = cw.arg_statics()
     from concurrent.futures import ThreadPoolExecutor
 
-    if not collect:
-        outs: list = []
-        for lo in range(0, p, chunk):
-            hi = min(lo + chunk, p)
-            xs_chunk = _slice_xs(cw.xs, lo, hi, chunk)
-            xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
-            carry, out = scan_jit(carry, xs_chunk, arg_statics)
-            outs.append(_TinyOut(out))
-        chunks = [_fetch_chunk(o) for o in outs]
-
-        def cat(field: str) -> np.ndarray:
-            pieces = [c[field] for c in chunks]
-            if not pieces:
-                return np.zeros((0,), dtype=np.int32)
-            return np.concatenate(pieces, axis=0)[:p]
-
-        return ReplayResult(
-            cw=cw, selected=cat("selected"),
-            feasible_count=cat("feasible_count"),
-            prefilter_reject=cat("prefilter_reject"),
-        )
-
-    # collect: chunks are ingested in dispatch order the moment their
+    # chunks are ingested in dispatch order the moment their
     # fetch lands, so a caller's on_chunk(rr, lo, hi) can decode pods
     # lo..hi while the device is still running later chunks (the host
     # decode overlaps device compute; dispatch stays ahead by up to

@@ -689,8 +689,7 @@ def replay_speculative_stream(
     Returns (rr, stats): rr is bit-identical to replay(cw) / the
     sequential oracle; stats records rounds, acceptance and fallback.
     Caller must have checked speculation_ok(cw.config, ...)."""
-    device_resident = _resolve_device_resident(device_resident, True,
-                                               on_chunk)
+    device_resident = _resolve_device_resident(device_resident, on_chunk)
     active = set(cw.config.active_plugins())
     if cw.arg_statics():
         # speculation_ok admits no plugin with argument statics (the

@@ -193,7 +193,7 @@ class SimulationSession:
             # recovery steps back up)
             "resultMode": (engine.result_mode()
                            if hasattr(engine, "result_mode") else None),
-            "degraded": bool(getattr(engine, "_residency", 0)),
+            "degraded": bool(getattr(engine, "degraded", False)),
             # rolling SLO window (utils/blackbox.py, docs/metrics.md):
             # p50/p99 wave latency + cycles/s over the last
             # KSS_TPU_SLO_WINDOW waves; None before the first wave

@@ -490,79 +490,6 @@ def _decode_chunk_into(rr, lo: int, hi: int, out: list, base: int) -> None:
             out[i - base] = a
 
 
-def decode_release_batches(rr, lo: int, hi: int, on_pod=None,
-                           batch: int = 64) -> None:
-    """Decode pods lo..hi in small compact-chunk-aligned batches,
-    releasing each batch's annotations after on_pod(i, ann) — the
-    reflector-style consumer (the reference's reflector PATCHes the
-    annotations out and holds nothing, storereflector.go:87-161): holding a
-    whole replay chunk's strings before releasing pays ~1.3 GB of
-    first-touch page faults at the 5k-node shape, a harness transient
-    rather than decoder cost.  Batches never straddle a compact chunk.
-
-    On the chunk-granular native path the batches PIPELINE: batch k+1's
-    GIL-released C decode runs on a pool thread while this thread builds
-    batch k's strs and fires on_pod — on a 2-core host that hides most
-    of the C wall time behind the (GIL-bound) str assembly.  Pod order
-    of on_pod calls is preserved."""
-    cc = getattr(rr, "_compact", None)
-    ranges: list[tuple[int, int]] = []
-    s0 = lo
-    while s0 < hi:
-        s1 = min(s0 + batch, hi)
-        if cc is not None:
-            s1 = min(s1, (s0 // cc.chunk + 1) * cc.chunk)
-        ranges.append((s0, s1))
-        s0 = s1
-
-    ctx = _native_ctx(rr.cw) if cc is not None else None
-    if ctx is not None:
-        from . import native_decode
-
-        pool = _decode_pool()
-
-        def start(r):
-            return pool.submit(
-                native_decode.decode_chunk_start, ctx, rr, r[0], r[1],
-                _chunk_skip_mask(rr, *r))
-
-        fut = start(ranges[0]) if ranges else None
-        try:
-            for k, (b0, b1) in enumerate(ranges):
-                fault_point("decode.chunk")
-                handle = fut.result()
-                fut = start(ranges[k + 1]) if k + 1 < len(ranges) else None
-                triples = native_decode.decode_chunk_take(handle)
-                _count_native_chunk(handle, b1 - b0)
-                sink: list = [None] * (b1 - b0)
-                _assemble_chunk(rr, b0, b1, triples, sink, b0)
-                if on_pod is not None:
-                    for j, a in enumerate(sink):
-                        if a is not None:
-                            on_pod(b0 + j, a)
-        except BaseException as e:
-            if isinstance(e, Exception):
-                TRACER.inc("decode_failures_total", path="native_chunk")
-            if fut is not None:  # don't leak the in-flight arena
-                try:
-                    fut.result().discard()
-                # best-effort arena release on an already-raising path
-                # (the original error re-raises below)
-                # kss-analyze: allow(swallowed-exception)
-                except Exception:
-                    pass
-            raise
-        return
-
-    for b0, b1 in ranges:
-        sink = [None] * (b1 - b0)
-        decode_chunk_into(rr, b0, b1, sink, base=b0)
-        if on_pod is not None:
-            for j, a in enumerate(sink):
-                if a is not None:
-                    on_pod(b0 + j, a)
-
-
 def decode_all_parallel(rr: ReplayResult,
                         n: int | None = None) -> list[dict[str, str]]:
     """Decode pods 0..n across a thread pool, chunk by chunk.

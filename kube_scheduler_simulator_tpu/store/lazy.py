@@ -34,10 +34,10 @@ build fresh CompiledWorkloads; `NodeTableReuse` shares only the
 immutable node table), so deferred decode is bit-identical to eager
 decode of the same wave; a cold read first performs the chunk's
 memoized D2H (`d2h_fetch` span under `decode_lazy`), then the one
-GIL-released chunk decode.  `KSS_TPU_EAGER_DECODE=1` disables deferral
-engine-wide (the golden/parity baseline); `KSS_TPU_HOST_RESIDENT=1`
-keeps the lazy decode but fetches the compact tensors to host in-wave
-(the PR 9 behavior, the middle parity rung).
+GIL-released chunk decode.  Which waves defer, and where their tensors
+wait, is the engine's wave plan (framework/engine.py `_wave_plan`): the
+degradation ladder's middle rung keeps the lazy decode but fetches the
+compact tensors to host in-wave, its bottom rung decodes in the wave.
 """
 
 from __future__ import annotations

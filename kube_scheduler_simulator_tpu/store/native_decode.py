@@ -337,9 +337,7 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
     call covering pods lo..hi (a range inside ONE compact replay chunk).
     The C side iterates the pods with its worker pool and emits every
     pod's three heavy blobs into a per-call arena.  Runs fine on a helper
-    thread (ctypes drops the GIL for the call) — decode_release_batches
-    pipelines the NEXT batch's C decode under the current batch's
-    str-building this way.
+    thread (ctypes drops the GIL for the call).
 
     skip: optional [hi-lo] uint8 — pods Python's prefilter-reject
     early-out owns; the C side leaves their slots empty."""

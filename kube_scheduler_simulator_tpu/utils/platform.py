@@ -1,4 +1,4 @@
-"""Host-platform helpers: CPU forcing for tests, allocator tuning.
+"""Host-platform helpers: CPU forcing for tests, malloc huge pages.
 
 Stock JAX honours `JAX_PLATFORMS=cpu` by itself, so process mains need
 no guard.  force_cpu() is for callers that must pin the CPU backend from
@@ -78,29 +78,3 @@ def ensure_malloc_hugepages() -> bool:
         os.execve(sys.executable, argv, env)
     except OSError:
         return False
-
-
-def tune_host_allocator() -> bool:
-    """Keep glibc from returning freed large blocks to the kernel.
-
-    The annotation product cycles multi-MB JSON strings; above the default
-    mmap threshold (128 KiB) each one is mmap'd and munmap'd, so every
-    build page-faults fresh pages — ruinous on hosts whose first-touch
-    bandwidth collapses at high resident set (the one-core CPU host this
-    was tuned on: ~10x past ~8 GB).  Raising the thresholds
-    makes the arena REUSE freed pages: steady-state string churn touches
-    already-backed memory and never faults.  For BATCH processes (the
-    bench, one-shot replays) only — with trim disabled a long-lived
-    server would hold its peak heap forever.  Returns True when applied
-    (glibc only; silently a no-op elsewhere)."""
-    import ctypes
-
-    try:
-        libc = ctypes.CDLL(None, use_errno=True)
-        mallopt = libc.mallopt
-    except (OSError, AttributeError):
-        return False
-    M_TRIM_THRESHOLD, M_MMAP_THRESHOLD = -1, -3
-    ok = mallopt(M_MMAP_THRESHOLD, 1 << 30)   # strings stay in the arena
-    ok &= mallopt(M_TRIM_THRESHOLD, 1 << 30)  # arena keeps freed pages
-    return bool(ok)

@@ -180,6 +180,10 @@ def test_unbalanced_span_and_bad_names():
                for f in res["findings"])
     # the with-managed span is fine
     assert not any("ok_span" in f.detail for f in res["findings"])
+    # a name picked into a variable is read through its literals
+    assert any(f.rule == "metric-name" and "bad-picked.span" in f.detail
+               for f in res["findings"])
+    assert not any("ok_picked_span" in f.detail for f in res["findings"])
 
 
 # ------------------------------------------------- the repo at HEAD

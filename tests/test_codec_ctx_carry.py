@@ -449,22 +449,6 @@ def test_missing_library_is_none(monkeypatch):
     assert _native_ctx(cw) is None and len(out[0]) == 13
 
 
-def test_release_batches_pipeline_on_the_shared_context():
-    """decode_release_batches starts batch k+1's C decode on a pool
-    thread while batch k's strs are built: on a context two passes share
-    each pass's batches still render their own rows."""
-    from kube_scheduler_simulator_tpu.store.decode import decode_release_batches
-
-    nodes = _nodes()
-    cw1, rr1 = _pass(nodes, make_pods(N_PODS, seed=12, with_spread=True))
-    cw2, rr2 = _pass(nodes, make_pods(N_PODS, seed=13), reuse=cw1)
-    assert _native_ctx(cw1) is _native_ctx(cw2)
-    for rr in (rr2, rr1):
-        got: dict = {}
-        decode_release_batches(rr, 0, N_PODS, on_pod=got.__setitem__, batch=5)
-        assert [got[i] for i in range(N_PODS)] == _native(rr)
-
-
 # --- (f) two passes at once on one context --------------------------------
 
 def test_two_threads_decoding_two_passes_at_once_give_the_serial_bytes():

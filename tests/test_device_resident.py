@@ -4,10 +4,11 @@ stage): decision-only in-wave fetch, on-demand D2H materialization.
 The parity rule extends PR 9's (docs/wave-pipeline.md): whatever a
 reader observes — pod annotations, result-history, bind order,
 attribution tallies — must be bit-identical across the three residency
-rungs: the device-resident default, KSS_TPU_HOST_RESIDENT=1 (lazy
-decode, in-wave host fetch — the PR 9 behavior) and
-KSS_TPU_EAGER_DECODE=1 (full eager), including waves run on a mesh and
-chunks spilled to host by the KSS_TPU_DEVICE_RESULT_BUDGET_MB budget.
+rungs: the device-resident default, host-resident (lazy decode,
+in-wave host fetch — the PR 9 behavior) and eager decode, the
+degradation ladder's two lower rungs, pinned on the engines built here
+by `residency_floor` — including waves run on a mesh and chunks spilled
+to host by the KSS_TPU_DEVICE_RESULT_BUDGET_MB budget.
 """
 
 from __future__ import annotations
@@ -37,16 +38,13 @@ ENABLED = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
 replay_mod = sys.modules["kube_scheduler_simulator_tpu.framework.replay"]
 
 
-def _mode(monkeypatch, mode: str) -> None:
-    monkeypatch.delenv("KSS_TPU_EAGER_DECODE", raising=False)
-    monkeypatch.delenv("KSS_TPU_HOST_RESIDENT", raising=False)
+# the ladder's rungs, as SchedulerEngine(residency_floor=) takes them
+RUNGS = {"device": 0, "host": 1, "eager": 2}
+
+
+@pytest.fixture(autouse=True)
+def _no_budget(monkeypatch):
     monkeypatch.delenv("KSS_TPU_DEVICE_RESULT_BUDGET_MB", raising=False)
-    if mode == "eager":
-        monkeypatch.setenv("KSS_TPU_EAGER_DECODE", "1")
-    elif mode == "host":
-        monkeypatch.setenv("KSS_TPU_HOST_RESIDENT", "1")
-    else:
-        assert mode == "device"
 
 
 def _mixed_workload():
@@ -72,8 +70,10 @@ def _mixed_workload():
     return nodes, pods
 
 
-def _run_wave(nodes, pods, pipeline=True, chunk=16, mesh=None):
-    """Schedule once; -> (engine, store, bound, bind_order)."""
+def _run_wave(nodes, pods, mode="device", pipeline=True, chunk=16,
+              mesh=None):
+    """Schedule once on the given rung; -> (engine, store, bound,
+    bind_order)."""
     store = ObjectStore()
     for n in nodes:
         store.create("nodes", copy.deepcopy(n))
@@ -82,7 +82,7 @@ def _run_wave(nodes, pods, pipeline=True, chunk=16, mesh=None):
     q = store.watch("pods")
     engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
         enabled=list(ENABLED)), chunk=chunk, pipeline_commit=pipeline,
-        mesh=mesh)
+        mesh=mesh, residency_floor=RUNGS[mode])
     bound = engine.schedule_pending()
     bind_order, seen = [], set()
     while True:
@@ -125,9 +125,8 @@ def test_three_rung_byte_parity(monkeypatch, pipeline):
     nodes, pods = _mixed_workload()
     results = {}
     for mode in ("device", "host", "eager"):
-        _mode(monkeypatch, mode)
         TRACER.reset()
-        engine, store, bound, order = _run_wave(nodes, pods,
+        engine, store, bound, order = _run_wave(nodes, pods, mode,
                                                 pipeline=pipeline)
         if mode == "device":
             # residency really happened: the wave itself moved only
@@ -151,11 +150,9 @@ def test_mesh_sharded_wave_parity(monkeypatch):
     from kube_scheduler_simulator_tpu.parallel.mesh import make_mesh
 
     nodes, pods = _mixed_workload()
-    _mode(monkeypatch, "eager")
-    _, store_e, bound_e, _ = _run_wave(nodes, pods)
+    _, store_e, bound_e, _ = _run_wave(nodes, pods, "eager")
     baseline = _read_all(store_e)
 
-    _mode(monkeypatch, "device")
     mesh = make_mesh(8, dp=1)
     engine, store, bound, _ = _run_wave(nodes, pods, mesh=mesh)
     assert bound == bound_e
@@ -171,11 +168,9 @@ def test_replay_level_mesh_attribution_parity(monkeypatch):
     nodes, pods = _mixed_workload()
     cfg = PluginSetConfig(enabled=list(ENABLED))
     cw = compile_workload(nodes, pods, cfg)
-    _mode(monkeypatch, "device")
     rr_mesh = replay(cw, chunk=16, mesh=make_mesh(8, dp=1))
     att_mesh = plugin_attribution(rr_mesh)
-    _mode(monkeypatch, "host")
-    rr_host = replay(cw, chunk=16)
+    rr_host = replay(cw, chunk=16, device_resident=False)
     att_host = plugin_attribution(rr_host)
     assert att_mesh == att_host
     # and the device fold really was the source: no chunk materialized
@@ -190,7 +185,6 @@ def test_attribution_device_fold_matches_host_tally(monkeypatch):
     nodes, pods = _mixed_workload()
     cfg = PluginSetConfig(enabled=list(ENABLED))
     cw = compile_workload(nodes, pods, cfg)
-    _mode(monkeypatch, "device")
     rr = replay(cw, chunk=16)
     cc = rr._compact
     assert any(a is not None for a in cc.att)
@@ -212,7 +206,6 @@ def test_width_tier_rerun_with_device_chunks(monkeypatch):
     retained chunks release their budget accounting."""
     nodes, pods, cfg = baseline_config(4, scale=0.02, seed=11)
     cw = compile_workload(nodes, pods, cfg)
-    _mode(monkeypatch, "device")
 
     real_fetch = replay_mod._fetch_decisions
     state = {"fired": False, "count": 0}
@@ -256,11 +249,9 @@ def test_concurrent_cold_reads_one_d2h_per_chunk(monkeypatch):
     host/device boundary EXACTLY once (one d2h_fetch span per chunk;
     concurrent readers wait on the materialize owner)."""
     nodes, pods = _mixed_workload()
-    _mode(monkeypatch, "eager")
-    _, store_e, _, _ = _run_wave(nodes, pods)
+    _, store_e, _, _ = _run_wave(nodes, pods, "eager")
     baseline = _read_all(store_e)
 
-    _mode(monkeypatch, "device")
     engine, store, _, _ = _run_wave(nodes, pods, chunk=16)
     n_chunks = (len(pods) + 15) // 16
     TRACER.reset()
@@ -308,11 +299,9 @@ def test_spill_then_read_round_trip(monkeypatch):
     host on the background writer; reads after the spill return the
     eager bytes, and the spill taps record."""
     nodes, pods = _mixed_workload()
-    _mode(monkeypatch, "eager")
-    _, store_e, _, _ = _run_wave(nodes, pods)
+    _, store_e, _, _ = _run_wave(nodes, pods, "eager")
     baseline = _read_all(store_e)
 
-    _mode(monkeypatch, "device")
     monkeypatch.setenv("KSS_TPU_DEVICE_RESULT_BUDGET_MB", "0")
     TRACER.reset()
     engine, store, _, _ = _run_wave(nodes, pods, chunk=16)
@@ -334,7 +323,6 @@ def test_budget_taps_and_exposition(monkeypatch):
     from kube_scheduler_simulator_tpu.utils.tracing import validate_exposition
 
     nodes, pods = _mixed_workload()
-    _mode(monkeypatch, "device")
     engine, store, _, _ = _run_wave(nodes, pods, chunk=16)
     TRACER.reset()
     store.get("pods", pods[0]["metadata"]["name"], "default")   # cold

@@ -55,8 +55,8 @@ def _cluster(n_nodes=3, n_pods=20):
     return s
 
 
-def _engine(store, chunk=8):
-    eng = SchedulerEngine(store, chunk=chunk)
+def _engine(store, chunk=8, **kw):
+    eng = SchedulerEngine(store, chunk=chunk, **kw)
     eng._retry_sleep = lambda _d: None  # no real backoff in tests
     return eng
 
@@ -70,9 +70,9 @@ def _state(store):
     return out
 
 
-def _reference(n_nodes=3, n_pods=20, chunk=8):
+def _reference(n_nodes=3, n_pods=20, chunk=8, **kw):
     s = _cluster(n_nodes, n_pods)
-    assert _engine(s, chunk).schedule_pending() == n_pods
+    assert _engine(s, chunk, **kw).schedule_pending() == n_pods
     return _state(s)
 
 
@@ -268,15 +268,16 @@ def test_probe_recovery_steps_back_up(monkeypatch):
                        "to": "device_resident"}) >= 1
 
 
-def test_env_floor_caps_recovery(monkeypatch):
-    monkeypatch.setenv("KSS_TPU_HOST_RESIDENT", "1")
-    eng = _engine(_cluster(n_pods=2))
-    assert eng.result_mode() == "host_resident"
+def test_floor_caps_recovery(monkeypatch):
+    eng = _engine(_cluster(n_pods=2), residency_floor=1)
+    assert eng.result_mode() == "host_resident" and not eng.degraded
     assert eng._degrade("test") is True
-    assert eng.result_mode() == "eager_decode"
+    assert eng.result_mode() == "eager_decode" and eng.degraded
     monkeypatch.setenv("KSS_TPU_DEGRADE_PROBE_WAVES", "1")
     eng._wave_recovered_ok()
-    # recovery lands on the env floor, never above it
+    # recovery lands on the floor, never above it
+    assert eng.result_mode() == "host_resident" and not eng.degraded
+    eng._wave_recovered_ok()
     assert eng.result_mode() == "host_resident"
 
 
@@ -324,7 +325,7 @@ def test_transient_fault_after_full_commit_keeps_bind_count():
     eng = SchedulerEngine(s, chunk=8, plugin_config=PluginSetConfig(
         enabled=["NodeResourcesFit", "NodeAffinity"]))
     eng._retry_sleep = lambda _d: None
-    assert eng._can_stream_commit()
+    assert eng._wave_plan().commit == "streamed"
     real = eng.reflector.reflect_batch
     calls = {"n": 0}
 
@@ -376,14 +377,8 @@ def test_compile_quarantine_contains_key_not_process():
 
 
 def test_decode_fault_is_visible_and_heals_on_reread():
-    import os
-
     # eager reference bytes for the same workload
-    os.environ["KSS_TPU_EAGER_DECODE"] = "1"
-    try:
-        ref = _reference()
-    finally:
-        del os.environ["KSS_TPU_EAGER_DECODE"]
+    ref = _reference(residency_floor=2)
     s = _cluster()
     eng = _engine(s)
     assert eng.schedule_pending() == 20  # lazy: decode deferred to read

@@ -127,14 +127,14 @@ def _pipelined_wave(n_pods=48, n_nodes=6, chunk=16, pipeline=True):
     for p in make_pods(n_pods, seed=12):
         store.create("pods", p)
     # no PostFilter in the lineup so the wave takes the streaming-commit
-    # path (_can_stream_commit; the default set's preemption forces the
+    # path (_wave_plan; the default set's preemption forces the
     # sequential post-pass, as pipeline=False does here)
     cfg = PluginSetConfig(enabled=[
         "NodeResourcesFit", "NodeResourcesBalancedAllocation",
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=chunk,
                              pipeline_commit=pipeline)
-    assert engine._can_stream_commit() is pipeline
+    assert (engine._wave_plan().commit == "streamed") is pipeline
     bound = engine.schedule_pending()
     assert bound > 0
     return TRACER.events(limit=1000)
@@ -588,7 +588,6 @@ def test_mid_chunk_exception_leaves_tracer_balanced(monkeypatch):
     # what this test pins — still surfaces the raise (with retries on,
     # the one-shot poison heals via the uncommitted-suffix retry:
     # tests/test_faults.py covers that)
-    monkeypatch.setenv("KSS_TPU_EAGER_DECODE", "1")
     monkeypatch.setenv("KSS_TPU_WAVE_MAX_RETRIES", "0")
     store = ObjectStore()
     for n in make_nodes(6, seed=31):
@@ -599,8 +598,8 @@ def test_mid_chunk_exception_leaves_tracer_balanced(monkeypatch):
         "NodeResourcesFit", "NodeResourcesBalancedAllocation",
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=16,
-                             pipeline_commit=True)
-    assert engine._can_stream_commit()
+                             pipeline_commit=True, residency_floor=2)
+    assert engine._wave_plan().commit == "streamed"
 
     real = engine.result_store.put_decoded
     calls = {"n": 0}

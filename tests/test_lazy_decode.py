@@ -4,10 +4,11 @@ decode under concurrent cold reads, and the flight-recorder taps.
 
 The parity rule (docs/wave-pipeline.md lazy-decode stage): whatever a
 reader observes — pod annotations, result-history, bind order, parked
-gangs — must be bit-identical between the default lazy mode,
-KSS_TPU_EAGER_DECODE=1, and lazy over the pure-Python decoder rung
-(KSS_TPU_DISABLE_NATIVE=1), including pods nobody reads until after a
-later wave has overwritten their result-store entry.
+gangs — must be bit-identical between the default lazy mode, eager
+decode (the degradation ladder's bottom rung, pinned on the engines
+built here by `residency_floor=2`), and lazy over the pure-Python
+decoder rung (KSS_TPU_DISABLE_NATIVE=1), including pods nobody reads
+until after a later wave has overwritten their result-store entry.
 """
 
 from __future__ import annotations
@@ -31,15 +32,14 @@ ENABLED = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
            "NodeAffinity", "TaintToleration", "VolumeBinding"]
 
 
-def _mode(monkeypatch, mode: str) -> None:
-    monkeypatch.delenv("KSS_TPU_EAGER_DECODE", raising=False)
+def _mode(monkeypatch, mode: str) -> int:
+    """-> the rung to pin on the mode's engines (residency_floor)."""
     monkeypatch.delenv("KSS_TPU_DISABLE_NATIVE", raising=False)
-    if mode == "eager":
-        monkeypatch.setenv("KSS_TPU_EAGER_DECODE", "1")
-    elif mode == "lazy_python":
+    if mode == "lazy_python":
         monkeypatch.setenv("KSS_TPU_DISABLE_NATIVE", "1")
     else:
-        assert mode == "lazy"
+        assert mode in ("lazy", "eager")
+    return 2 if mode == "eager" else 0
 
 
 def _mixed_workload():
@@ -63,8 +63,9 @@ def _mixed_workload():
     return nodes, pods
 
 
-def _run_wave(nodes, pods, pipeline=True, chunk=16):
-    """Schedule once; -> (engine, store, bound, bind_order)."""
+def _run_wave(nodes, pods, pipeline=True, chunk=16, rung=0):
+    """Schedule once on the given rung; -> (engine, store, bound,
+    bind_order)."""
     store = ObjectStore()
     for n in nodes:
         store.create("nodes", copy.deepcopy(n))
@@ -72,7 +73,8 @@ def _run_wave(nodes, pods, pipeline=True, chunk=16):
         store.create("pods", copy.deepcopy(p))
     q = store.watch("pods")
     engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
-        enabled=list(ENABLED)), chunk=chunk, pipeline_commit=pipeline)
+        enabled=list(ENABLED)), chunk=chunk, pipeline_commit=pipeline,
+        residency_floor=rung)
     bound = engine.schedule_pending()
     bind_order, seen = [], set()
     while True:
@@ -112,9 +114,8 @@ def test_lazy_eager_parity_mixed_wave(monkeypatch, pipeline):
     nodes, pods = _mixed_workload()
     results = {}
     for mode in ("lazy", "eager", "lazy_python"):
-        _mode(monkeypatch, mode)
-        engine, store, bound, order = _run_wave(nodes, pods,
-                                                pipeline=pipeline)
+        engine, store, bound, order = _run_wave(
+            nodes, pods, pipeline=pipeline, rung=_mode(monkeypatch, mode))
         if mode.startswith("lazy"):
             # deferral really happened: shared reads see no annotations
             assert not any((p["metadata"].get("annotations") or {})
@@ -152,7 +153,7 @@ def test_lazy_gang_wave_parity(monkeypatch):
                 "9999999m"
     plain = make_pods(30, seed=23, with_affinity=True, with_tolerations=True)
 
-    def run():
+    def run(rung):
         store = ObjectStore()
         ensure_podgroup_resource(store)
         for n in nodes:
@@ -166,15 +167,14 @@ def test_lazy_gang_wave_parity(monkeypatch):
                      "Coscheduling"],
             custom={"Coscheduling": Coscheduling()},
         )
-        engine = SchedulerEngine(store, plugin_config=cfg, chunk=8)
+        engine = SchedulerEngine(store, plugin_config=cfg, chunk=8,
+                                 residency_floor=rung)
         bound = engine.schedule_pending()
         parked = sorted(engine.gang_parked)
         return bound, parked, _read_all(store)
 
-    _mode(monkeypatch, "lazy")
-    bound_l, parked_l, anns_l = run()
-    _mode(monkeypatch, "eager")
-    bound_e, parked_e, anns_e = run()
+    bound_l, parked_l, anns_l = run(_mode(monkeypatch, "lazy"))
+    bound_e, parked_e, anns_e = run(_mode(monkeypatch, "eager"))
     assert bound_l == bound_e
     assert parked_l == parked_e and len(parked_l) == 3
     _assert_same(anns_l, anns_e, "lazy vs eager gang wave")
@@ -196,7 +196,7 @@ def test_unread_pods_survive_later_wave_overwrite(monkeypatch):
                   "status": {"allocatable": {"cpu": "8", "memory": "16Gi",
                                              "pods": "10"}}}
 
-    def run():
+    def run(rung):
         store = ObjectStore()
         for n in nodes:
             store.create("nodes", copy.deepcopy(n))
@@ -204,16 +204,15 @@ def test_unread_pods_survive_later_wave_overwrite(monkeypatch):
             store.create("pods", copy.deepcopy(p))
         engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
             enabled=["NodeResourcesFit",
-                     "NodeResourcesBalancedAllocation"]))
+                     "NodeResourcesBalancedAllocation"]),
+            residency_floor=rung)
         b1 = engine.schedule_pending()   # capacity for 2: rest pending
         store.create("nodes", copy.deepcopy(extra_node))
         b2 = engine.schedule_pending()   # retried pods get a 2nd record
         return store, b1, b2
 
-    _mode(monkeypatch, "lazy")
-    store_l, b1_l, b2_l = run()
-    _mode(monkeypatch, "eager")
-    store_e, b1_e, b2_e = run()
+    store_l, b1_l, b2_l = run(_mode(monkeypatch, "lazy"))
+    store_e, b1_e, b2_e = run(_mode(monkeypatch, "eager"))
     assert (b1_l, b2_l) == (b1_e, b2_e) and b2_l > 0
     anns_l, anns_e = _read_all(store_l), _read_all(store_e)
     _assert_same(anns_l, anns_e, "overwrite-before-read")
@@ -230,8 +229,8 @@ def test_concurrent_first_reads_decode_each_chunk_once(monkeypatch):
     concurrent readers of a chunk wait on the owner instead of decoding
     again)."""
     nodes, pods = _mixed_workload()
-    _mode(monkeypatch, "eager")
-    _, store_e, _, _ = _run_wave(nodes, pods)
+    _, store_e, _, _ = _run_wave(nodes, pods,
+                                 rung=_mode(monkeypatch, "eager"))
     baseline = _read_all(store_e)
 
     _mode(monkeypatch, "lazy")

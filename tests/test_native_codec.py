@@ -148,8 +148,9 @@ def test_fused_decode_on_device_layout_strides(monkeypatch):
 
 def test_decode_chunk_into_base_offset():
     """decode_chunk_into with a chunk-local sink (base=lo) fills the same
-    annotations as the whole-queue list — the bench's release-after-build
-    consumer depends on it."""
+    annotations as the whole-queue list, which a range over several
+    compact chunks splits on their boundaries — the lazy read
+    (store/lazy.py) decodes one chunk into such a sink."""
     nodes, pods, cfg = baseline_config(1, scale=0.05, seed=1)
     cw = compile_workload(nodes, pods, cfg)
     rr = replay(cw, chunk=4)
@@ -162,22 +163,6 @@ def test_decode_chunk_into_base_offset():
         sink = [None] * (hi - lo)
         decode_chunk_into(rr, lo, hi, sink, base=lo)
         assert sink == whole[lo:hi]
-
-
-def test_decode_release_batches_aligns_to_compact_chunks():
-    """The release-style consumer never straddles a compact chunk (pool
-    workers would thrash the single-slot recon cache) and decodes every
-    pod byte-identically to decode_pod_result."""
-    from kube_scheduler_simulator_tpu.store.decode import decode_release_batches
-
-    nodes, pods, cfg = baseline_config(2, scale=0.06, seed=9)
-    cw = compile_workload(nodes, pods, cfg)
-    rr = replay(cw, chunk=10)  # chunk NOT a multiple of the 64 batch
-    got: dict = {}
-    decode_release_batches(rr, 0, len(pods), on_pod=got.__setitem__)
-    assert sorted(got) == list(range(len(pods)))
-    for i in (0, 9, 10, len(pods) - 1):
-        assert got[i] == decode_pod_result(rr, i)
 
 
 def test_empty_active_mask_on_reused_cache_slot():

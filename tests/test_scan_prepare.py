@@ -236,8 +236,6 @@ def test_replay_twice_on_one_workload(idx, scale):
 def test_width_tier_rerun_replays_the_same_init_carry(monkeypatch):
     """The wider rerun starts from cw.init_carry again after the first
     tier's scan was donated its copy."""
-    monkeypatch.delenv("KSS_TPU_EAGER_DECODE", raising=False)
-    monkeypatch.delenv("KSS_TPU_HOST_RESIDENT", raising=False)
     cw, (_, pods, _) = _cw(4, 0.02, seed=11)
     plain = _decoded(replay(cw, chunk=32, device_resident=True), len(pods))
     real_fetch = replay_mod._fetch_decisions

@@ -167,7 +167,6 @@ def test_manager_create_after_shutdown_refused():
 
 
 def test_two_sessions_bit_identical_and_isolated(monkeypatch):
-    monkeypatch.delenv("KSS_TPU_EAGER_DECODE", raising=False)
     nodes = make_nodes(8, seed=3, taint_fraction=0.25)
     pods = make_pods(24, seed=4, with_affinity=True, with_tolerations=True,
                      with_spread=True)
@@ -242,8 +241,6 @@ def test_per_session_budget_spills_only_the_fat_session(monkeypatch):
     (device_chunks_spilled_total{session=...}) while a small co-resident
     session's device-resident chunks stay put and its warm reads stay
     D2H-free."""
-    monkeypatch.delenv("KSS_TPU_EAGER_DECODE", raising=False)
-    monkeypatch.delenv("KSS_TPU_HOST_RESIDENT", raising=False)
     gc.collect()  # drop other tests' dead budget entries (weakref-kept)
     monkeypatch.setenv("KSS_TPU_DEVICE_RESULT_BUDGET_MB", "1")
     mgr = _mgr(max_sessions=4)

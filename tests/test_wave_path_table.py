@@ -5,11 +5,13 @@ The engine chooses scan (speculative rounds / sequential scan / host
 loop), commit (streamed by the chunk worker / sequential post-pass /
 host loop) and result residency (device-resident lazy / host-resident
 lazy / decoded in the wave) from what it observes of the profile, the
-extenders, the reflector and the residency ladder
-(`SchedulerEngine._profile_wave_attempt`, `_speculative_wave`).  Each
-case builds a small engine with one such observation, runs one wave and
-asserts the path from the spans and counters the program already emits.
-A refactor of the shells (ROADMAP C2) must keep every row."""
+extenders, the reflector and the residency ladder, once a wave
+(`SchedulerEngine._wave_plan`), and one executor runs what it chose
+(`_device_wave`; the host loop is `_schedule_host_path`).  Each case
+builds a small engine with one such observation and runs one wave:
+`test_wave_path` asserts the path from the spans and counters the
+program already emits, `test_wave_plan` the `(scan, commit, results)`
+value the plan method returned, once, for that wave."""
 
 from __future__ import annotations
 
@@ -19,7 +21,8 @@ from typing import Callable
 import pytest
 
 from kube_scheduler_simulator_tpu.cluster.store import ObjectStore
-from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
+from kube_scheduler_simulator_tpu.framework.engine import (
+    SchedulerEngine, WavePlan)
 from kube_scheduler_simulator_tpu.models.workloads import (
     make_gang_workload, make_nodes, make_pods)
 from kube_scheduler_simulator_tpu.plugins.coscheduling import (
@@ -84,9 +87,11 @@ def _no_defer(engine):
     engine.reflector.defer_supported = lambda: False
 
 
-def _ladder_rung(rung):
+def _degraded(steps):
+    # how a server reaches a lower rung: structural faults step the ladder
     def tweak(engine):
-        engine._residency = rung  # where _degrade() leaves the ladder
+        for _ in range(steps):
+            assert engine._degrade("test")
     return tweak
 
 
@@ -105,6 +110,8 @@ class Row:
     env: dict = field(default_factory=dict)
     engine_kw: dict = field(default_factory=dict)
     gang: bool = False
+    # what _wave_plan must return: (scan, commit, results)
+    plan: tuple = ()
     # the path it must take
     span: str = "replay_and_decode_stream"
     speculative: bool = False
@@ -117,49 +124,76 @@ def _enabled(names):
     return lambda: PluginSetConfig(enabled=list(names))
 
 
+HOST_LOOP = ("host_loop", "host_loop", "by_pod")
+
 ROWS = [
     # the stock server and every cell of BENCHMARK.json: DefaultPreemption
     # refuses the streaming committer, the volume family speculation
-    Row("row01_default_profile"),
-    Row("row02_webhook_extenders", tweak=_webhook,
+    Row("row01_default_profile",
+        plan=("sequential", "post_pass", "device_lazy")),
+    Row("row02_webhook_extenders", tweak=_webhook, plan=HOST_LOOP,
         span="host_path_wave", commit="host_loop", results="in_wave"),
     Row("row03_plugin_extender_intercepts_cycle",
         config=_enabled(["NodeResourcesFit"]), tweak=_extender(Interceptor()),
+        plan=HOST_LOOP,
         span="host_path_wave", commit="host_loop", results="in_wave"),
     Row("row04_custom_normalize_score", config=_custom(Normalizer()),
+        plan=HOST_LOOP,
         span="host_path_wave", commit="host_loop", results="in_wave"),
     Row("row05_custom_lifecycle_plugin", config=_custom(Reserver()),
+        plan=("sequential", "post_pass", "by_pod"),
         span="device_replay", results="in_wave"),
     Row("row06_observer_on_default_profile", tweak=_extender(Observer()),
+        plan=("sequential", "post_pass", "by_chunk"),
         results="in_wave"),
     Row("row06_observer_on_batchable_profile", config=_enabled(BATCHABLE),
-        tweak=_extender(Observer()), speculative=True, results="in_wave"),
+        tweak=_extender(Observer()),
+        plan=("speculative", "post_pass", "by_chunk"),
+        speculative=True, results="in_wave"),
     Row("row07_postfilter_in_batchable_profile",
-        config=_enabled(BATCHABLE + ["DefaultPreemption"])),
+        config=_enabled(BATCHABLE + ["DefaultPreemption"]),
+        plan=("sequential", "post_pass", "device_lazy")),
     Row("row08_batchable_profile", config=_enabled(BATCHABLE),
+        plan=("speculative", "streamed", "device_lazy"),
         speculative=True, commit="streamed"),
     Row("row09_volume_family_without_postfilter",
         config=_enabled(["NodeResourcesFit", "VolumeBinding"]),
+        plan=("sequential", "streamed", "device_lazy"),
         commit="streamed"),
     Row("row10_reflector_cannot_defer_default_profile", tweak=_no_defer,
+        plan=("sequential", "post_pass", "by_chunk"),
         results="in_wave"),
     Row("row10_reflector_cannot_defer_batchable_profile",
         config=_enabled(BATCHABLE), tweak=_no_defer,
+        plan=("speculative", "streamed", "by_chunk"),
         speculative=True, commit="streamed", results="in_wave"),
     Row("row11_gang_plugin_alone_on_batchable_profile",
         config=_custom(Coscheduling(), base=BATCHABLE), gang=True,
+        plan=("speculative", "streamed", "device_lazy"),
         speculative=True, commit="streamed"),
-    Row("row12_rung_host_resident", env={"KSS_TPU_HOST_RESIDENT": "1"},
+    # the rungs: pinned by the tests' floor, and reached as a server
+    # reaches them, by the ladder stepping down
+    Row("row12_rung_host_resident", engine_kw={"residency_floor": 1},
+        plan=("sequential", "post_pass", "host_lazy"),
         results="host_lazy", mode="host_resident"),
-    Row("row12_rung_host_resident_by_degradation", tweak=_ladder_rung(1),
+    Row("row12_rung_host_resident_by_degradation", tweak=_degraded(1),
+        plan=("sequential", "post_pass", "host_lazy"),
         results="host_lazy", mode="host_resident"),
-    Row("row13_rung_eager_decode", env={"KSS_TPU_EAGER_DECODE": "1"},
+    Row("row13_rung_eager_decode", engine_kw={"residency_floor": 2},
+        plan=("sequential", "post_pass", "by_chunk"),
+        results="in_wave", mode="eager_decode"),
+    Row("row13_rung_eager_decode_by_degradation", tweak=_degraded(2),
+        plan=("sequential", "post_pass", "by_chunk"),
         results="in_wave", mode="eager_decode"),
     # the two pins tests and parity baselines set; no server sets them
     Row("pin_speculative_off", config=_enabled(BATCHABLE),
-        env={"KSS_TPU_SPECULATIVE": "0"}, commit="streamed"),
+        env={"KSS_TPU_SPECULATIVE": "0"},
+        plan=("sequential", "streamed", "device_lazy"),
+        commit="streamed"),
     Row("pin_pipeline_commit_off", config=_enabled(BATCHABLE),
-        engine_kw={"pipeline_commit": False}, speculative=True),
+        engine_kw={"pipeline_commit": False},
+        plan=("speculative", "post_pass", "device_lazy"),
+        speculative=True),
 ]
 
 
@@ -172,11 +206,9 @@ def _decoded_in_wave():
     return sum(s["value"] for s in series)
 
 
-@pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
-def test_wave_path(row, monkeypatch):
-    for name in ("KSS_TPU_SPECULATIVE", "KSS_TPU_EAGER_DECODE",
-                 "KSS_TPU_HOST_RESIDENT"):
-        monkeypatch.delenv(name, raising=False)
+def _engine(row, monkeypatch):
+    """The row's store, pods and engine, one wave's worth."""
+    monkeypatch.delenv("KSS_TPU_SPECULATIVE", raising=False)
     for name, value in row.env.items():
         monkeypatch.setenv(name, value)
     store = ObjectStore()
@@ -195,6 +227,29 @@ def test_wave_path(row, monkeypatch):
                              **row.engine_kw)
     if row.tweak is not None:
         row.tweak(engine)
+    return store, pods, engine
+
+
+@pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
+def test_wave_plan(row, monkeypatch):
+    """The plan method's value for the row's engine: taken once for the
+    wave, and what the table says."""
+    _store, pods, engine = _engine(row, monkeypatch)
+    plans = []
+    decide = engine._wave_plan
+
+    def recorded(*args):
+        plans.append(decide(*args))
+        return plans[-1]
+
+    monkeypatch.setattr(engine, "_wave_plan", recorded)
+    assert engine.schedule_pending() == len(pods)
+    assert plans == [WavePlan(*row.plan)]
+
+
+@pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
+def test_wave_path(row, monkeypatch):
+    store, pods, engine = _engine(row, monkeypatch)
 
     TRACER.reset()
     assert engine.schedule_pending() == len(pods)

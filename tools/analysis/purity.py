@@ -34,6 +34,7 @@ HOT_PATH_ROOTS: list[tuple[str, str]] = [
     ("framework.engine", "SchedulerEngine._schedule_wave"),
     ("framework.engine", "SchedulerEngine._profile_wave_run"),
     ("framework.engine", "SchedulerEngine._profile_wave_attempt"),
+    ("framework.engine", "SchedulerEngine._device_wave"),
     ("framework.engine", "_WaveCommitter.on_chunk"),
     ("framework.engine", "_WaveCommitter._commit"),
     ("framework.replay", "*"),
@@ -68,12 +69,12 @@ HOT_PATH_ROOTS: list[tuple[str, str]] = [
     ("server.sessions", "SimulationSession.touch"),
     ("server.sessions", "SimulationSession.register_stream"),
     ("server.sessions", "SimulationSession.unregister_stream"),
-    # speculative default wave (PR 13): the streaming round loop, its
-    # conflict-oracle host walk and the engine shell run inside every
-    # wave — they must stay free of per-pod Python loops and eager
-    # host syncs on the compact groups (the accumulator emits whole
-    # chunks through gather_to_host, the one sanctioned crossing)
-    ("framework.engine", "SchedulerEngine._speculative_wave"),
+    # speculative waves (PR 13): the streaming round loop and its
+    # conflict-oracle host walk run inside every such wave (the engine's
+    # side is _device_wave, above) — they must stay free of per-pod
+    # Python loops and eager host syncs on the compact groups (the
+    # accumulator emits whole chunks through gather_to_host, the one
+    # sanctioned crossing)
     ("parallel.speculative", "replay_speculative_stream"),
     ("parallel.speculative", "_spec_run"),
     ("parallel.speculative", "_interaction_cut"),

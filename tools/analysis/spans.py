@@ -15,7 +15,10 @@ exposition rules PR 5's `validate_exposition()` enforces at scrape time
 Runtime sanitization would *silently rename* a bad name, so the check is
 static: the name a reader greps for must be the name exported.  Span
 names additionally feed `span_<name>_seconds_total` families and pass
-through the same gate.
+through the same gate.  A name passed as a plain variable (the wave
+executor picks its one span's name from the plan) is read through the
+module's assignments of string literals to that variable: every literal
+it can hold is checked.
 """
 
 from __future__ import annotations
@@ -34,6 +37,25 @@ TRACER_BASES = {"TRACER", "tracer", "_tracer"}
 METRIC_METHODS = {"count", "inc", "inc_process", "observe"}
 
 
+def _assigned_literals(tree: ast.AST) -> dict[str, set[str]]:
+    """name -> every string literal the module assigns to it (`a = "x"`,
+    `a = b = "x"`, `a, b = "x", "y"`)."""
+    out: dict[str, set[str]] = {}
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Assign):
+            continue
+        for target in node.targets:
+            pairs = [(target, node.value)]
+            if isinstance(target, ast.Tuple) \
+                    and isinstance(node.value, ast.Tuple):
+                pairs = list(zip(target.elts, node.value.elts))
+            for t, v in pairs:
+                if isinstance(t, ast.Name) and isinstance(v, ast.Constant) \
+                        and isinstance(v.value, str):
+                    out.setdefault(t.id, set()).add(v.value)
+    return out
+
+
 class SpanAnalyzer:
     def __init__(self, modules: list[Module]):
         self.modules = modules
@@ -47,6 +69,7 @@ class SpanAnalyzer:
     def _check_module(self, mod: Module) -> list[Finding]:
         out: list[Finding] = []
         with_contexts: set[int] = set()   # id() of calls used as with-items
+        literals = _assigned_literals(mod.tree)
         for node in ast.walk(mod.tree):
             if isinstance(node, (ast.With, ast.AsyncWith)):
                 for item in node.items:
@@ -68,9 +91,9 @@ class SpanAnalyzer:
                         message=f"{base}.span(...) outside a `with`: the "
                                 "span end is not guaranteed on exception "
                                 "paths"))
-                self._check_name(node, mod, out, span=True)
+                self._check_name(node, mod, out, True, literals)
             elif method in METRIC_METHODS:
-                self._check_name(node, mod, out, span=False)
+                self._check_name(node, mod, out, False, literals)
         return out
 
     @staticmethod
@@ -93,10 +116,15 @@ class SpanAnalyzer:
             return call.args[0].value
         return None
 
-    def _check_name(self, call: ast.Call, mod: Module,
-                    out: list[Finding], span: bool) -> None:
+    def _check_name(self, call: ast.Call, mod: Module, out: list[Finding],
+                    span: bool, literals: dict[str, set[str]]) -> None:
         name = self._span_name(call)
-        if name is not None and not _METRIC_NAME_RE.match(name):
+        names = [name] if name is not None else []
+        if name is None and call.args and isinstance(call.args[0], ast.Name):
+            names = sorted(literals.get(call.args[0].id, ()))
+        for name in names:
+            if _METRIC_NAME_RE.match(name):
+                continue
             kind = "span" if span else "metric"
             out.append(Finding(
                 rule="metric-name", path=mod.path, qualname=name,
