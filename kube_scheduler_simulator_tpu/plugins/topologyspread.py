@@ -31,14 +31,20 @@ Modeled knobs: matchLabelKeys (merged into the selector per incoming pod,
 effective_constraints), minDomains (global minimum forced to 0 when fewer
 eligible domains exist), nodeAffinityPolicy (default Honor) and
 nodeTaintsPolicy (default Ignore) for the min-match domain eligibility.
-Remaining simplifications (documented in docs/SEMANTICS.md):
-system-default constraints derived from service/replicaset owners are not
-modeled; the inclusion policies filter the min-match DOMAIN set but not
-the per-domain pod counting (upstream also excludes filtered-out nodes'
-pods from TpPairToMatchNum — differs only on clusters where some nodes of
-a domain are excluded while others aren't); #domains for the normalizing
-weight is computed over all nodes with the key rather than the
-affinity-filtered subset.
+Remaining simplifications (docs/SEMANTICS.md, "PodTopologySpread knobs",
+has the same three in the same order, each with whether
+benchmark/reference/node_inclusion.py, which counts as upstream does, can
+see it in `sched_perf_nodeinclusion_5k`: none of them, hostname domains):
+1. the inclusion policies filter the min-match DOMAIN set but not the
+   per-domain pod counting (upstream also excludes filtered-out nodes'
+   pods from TpPairToMatchNum — differs only on clusters where some nodes
+   of a domain are excluded while others aren't; with one node a domain
+   it cannot show);
+2. system-default constraints derived from service/replicaset owners are
+   not modeled (upstream applies them only to a pod without constraints);
+3. #domains for the normalizing weight is computed over all nodes with
+   the key rather than the affinity-filtered subset (ScheduleAnyway
+   scoring only).
 """
 
 from __future__ import annotations
