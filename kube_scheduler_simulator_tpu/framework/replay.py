@@ -53,7 +53,9 @@ import numpy as np
 
 from .pipeline import build_step
 from ..control import CONTROLS
-from ..state.compile import CompiledWorkload, split_statics, statics_digest
+from ..state.compile import (
+    CompiledWorkload, attribution_skip_masks, statics_digest)
+from ..state.packed import Packed, unpack_leaves
 from ..utils.faults import fault_point
 from ..utils.tracing import TRACER
 
@@ -942,11 +944,16 @@ def plugin_attribution(rr: ReplayResult) -> dict | None:
 
 
 def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, Any]:
+    """Rows lo:hi of every leaf of `xs`, padded with zeros to pad_to rows:
+    an eager device op or two a leaf, for the routes that hold xs as
+    leaves (a mesh, the speculative rounds, a pass of many chunks)."""
     def cut(a):
         piece = a[lo:hi]
+        TRACER.count("pass_device_dispatches_total")
         if pad_to > piece.shape[0]:
             pad_width = [(0, pad_to - piece.shape[0])] + [(0, 0)] * (piece.ndim - 1)
             piece = jnp.pad(piece, pad_width)
+            TRACER.count("pass_device_dispatches_total")
         return piece
 
     return jax.tree.map(cut, xs)
@@ -961,14 +968,31 @@ def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, An
 # compile_workload() (first TPU compile is tens of seconds) — even though
 # successive scheduler waves, and preemption's dry-run hypotheses,
 # produce workloads with byte-identical statics and shapes.  The key
-# therefore hashes the closure statics' CONTENT (the step closure bakes
-# them in as constants) plus the shape signature of xs, carry and the
-# ARGUMENT statics (state/compile.py ARG_STATICS: the volume family's,
-# which the scan takes as its third argument, so that a PV created
-# between two passes is no new executable) and the plugin-set signature;
-# any mismatch falls through to a fresh compile.  The statics fingerprint
-# is computed once per CompiledWorkload (cached in cw.host), not on every
-# replay() call.
+# (_workload_scan_key) therefore hashes the closure statics' CONTENT (the
+# step closure bakes them in as constants) plus the path, shape and dtype
+# of every leaf of xs, carry and the ARGUMENT statics (state/compile.py
+# ARG_STATICS: the volume family's, which the scan takes as arguments, so
+# that a PV created between two passes is no new executable) and the
+# plugin-set signature; any mismatch falls through to a fresh compile.
+# It reads those off the leaves, or off the packed layout that stands for
+# them, and is the same either way.  The statics fingerprint is computed
+# once per CompiledWorkload (cached in cw.host), not on every replay()
+# call.
+#
+# Two executables are built over one key, both from the same build_step on
+# the same leaves.  What a chunk's call takes and returns:
+#   _scan_for         (carry, xs_chunk, arg_statics) -> (carry, out): the
+#                     workload's trees as device arrays, the chunk cut and
+#                     padded by the caller (_slice_xs), the carry donated.
+#                     For a mesh (the leaves are sharded one by one) and
+#                     for the speculative rounds' fallback
+#   _packed_scan_for  (the pass's packed buffers, the leaves that are
+#                     device arrays already) -> (out, attribution sums):
+#                     the sequential scan of a pass of ONE chunk (every
+#                     served pass) of a workload that compile_workload
+#                     made.  The unpack, the carry and the attribution
+#                     reduction happen inside it; nothing is donated; its
+#                     key adds the layout
 
 
 class CompileQuarantined(RuntimeError):
@@ -1173,14 +1197,14 @@ def _statics_fingerprint(cw: CompiledWorkload) -> str:
     TRACER.inc("scan_key_statics_total",
                source="host" if fp is not None else "fetched")
     if fp is None:
-        fp = cw.host["_statics_fp"] = statics_digest(
-            split_statics(cw.statics)[0])
+        fp = cw.host["_statics_fp"] = statics_digest(cw.closure_statics())
     return fp
 
 
 def _leaf_sig(leaf) -> tuple:
-    # an array's own metadata: np.asarray(leaf) would fetch a device
-    # array (and gather a sharded one) to learn what it already says
+    # an array's own metadata (or a Packed's, which stands for one):
+    # np.asarray(leaf) would fetch a device array (and gather a sharded
+    # one) to learn what it already says
     if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
         return tuple(leaf.shape), str(leaf.dtype)
     a = np.asarray(leaf)
@@ -1191,9 +1215,13 @@ def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
     import json
 
     mesh_sig = tuple(mesh.shape.items()) if mesh is not None else None
+    # the packed layout says what the leaves would: asking for them would
+    # unpack a workload that the sequential scan takes packed
+    trees = (cw.packed.tree[:3] if cw.packed is not None
+             else (cw.xs, cw.init_carry, cw.arg_statics()))
     shapes = tuple(
         (str(path), *_leaf_sig(leaf))
-        for tree in (cw.xs, cw.init_carry, cw.arg_statics())
+        for tree in trees
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
     )
     cfg = cw.config
@@ -1215,7 +1243,9 @@ def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
 @jax.jit
 def _copy_carry(carry):
     """A fresh device copy of a carry tree in ONE dispatch (a jnp.array
-    per leaf is 18 of them under the default profile).  jnp.copy puts a
+    per leaf is 18 of them under the default profile), for the routes
+    that hold the carry as leaves; the packed route cuts its carry out of
+    the pass's buffers, which nothing donates.  jnp.copy puts a
     real copy into the jaxpr: a jitted identity would forward its input
     buffers, and the scan's donation would then invalidate the
     workload's own init_carry.  jax.jit caches by the leaves' shapes,
@@ -1235,7 +1265,7 @@ class _SlimWorkload:
 
     def __init__(self, cw: CompiledWorkload):
         self.config = cw.config
-        self.statics = split_statics(cw.statics)[0]
+        self.statics = cw.closure_statics()
         self.n_nodes = cw.n_nodes
         self.schema = cw.schema
 
@@ -1263,6 +1293,60 @@ def _scan_for(cw: CompiledWorkload, chunk: int, unroll: int = 1, mesh=None,
         return jax.jit(scan_chunk, donate_argnums=(0,))
 
     return _SCAN_CACHE.get_or_build(key, build)
+
+
+def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
+                     score_dtypes: tuple, wide, att_plan: tuple | None):
+    """-> (the cached executable of cw's sequential scan as ONE chunk over
+    cw.packed, its arguments).
+
+    scan_pass(bufs, rest) -> (out, att)
+      bufs    the pass's upload, a device buffer a dtype
+      rest    the leaves that never were in it (a carried session's
+              resident arrays, state/resident.py)
+      -> the chunk's compact output and the attribution sums (att_plan,
+      _att_plan; or None).  The carry is cut out of bufs and not handed
+      back (an output buffer costs the host what a dispatch does, and no
+      chunk follows); nothing is donated, so a second replay and the
+      width-tier rerun start from the same carry."""
+    packed = cw.packed
+    leaves, treedef = jax.tree.flatten(packed.tree)
+    # a leaf's place in the layout; None for one of `rest`
+    places = tuple(leaf.k if isinstance(leaf, Packed) else None
+                   for leaf in leaves)
+    layout = packed.layout
+    chunk = cw.n_pods
+    key = (*_workload_scan_key(cw, chunk), unroll, "packed", pack_mode,
+           score_dtypes, wide, layout, places, att_plan)
+
+    def build():
+        # nothing of `packed` but what is static: a cached closure must
+        # not pin a pass's buffers
+        slim = _SlimWorkload(cw)
+        n = cw.n_nodes
+        picks = tuple(k for k in places if k is not None)
+
+        def scan_pass(bufs, rest):
+            cut, own = iter(unpack_leaves(layout, picks, bufs)), iter(rest)
+            xs, carry, arg_statics, (fskip, sskip) = jax.tree.unflatten(
+                treedef, [next(own) if k is None else next(cut)
+                          for k in places])
+            xs["is_pad"] = jnp.zeros(chunk, jnp.bool_)
+            step = build_step(slim.with_args(arg_statics), out_mode="compact",
+                              pack_mode=pack_mode, score_dtypes=score_dtypes,
+                              wide_raw=wide)
+            _, out = jax.lax.scan(step, carry, xs, unroll=unroll)
+            att = None
+            if att_plan is not None:
+                att = _build_att_fn(chunk, n, *att_plan)(
+                    out.packed_filter, out.raw8, out.raw16, out.raw32,
+                    out.feasible_count, fskip, sskip, np.int32(chunk))
+            return out, att
+
+        return jax.jit(scan_pass)
+
+    rest = [leaf for leaf in leaves if not isinstance(leaf, Packed)]
+    return _SCAN_CACHE.get_or_build(key, build), (packed.bufs, rest)
 
 
 def _fetch_chunk(out) -> dict[str, np.ndarray]:
@@ -1418,47 +1502,54 @@ def _build_att_fn(chunk: int, n: int, code_bits: int, n_filters: int,
     return attribution_reduction
 
 
+def _att_plan(cw: CompiledWorkload, pack_mode: str,
+              score_cols: tuple) -> tuple | None:
+    """_build_att_fn's arguments after (chunk, n) for this workload, or
+    None where the profile has neither a filter nor a scorer."""
+    from .pipeline import PACK_MODES
+
+    n_filters = len(cw.config.filters())
+    if not (n_filters or cw.config.scorers()):
+        return None
+    dev_groups = tuple((s, g, r) for s, (g, r) in enumerate(score_cols)
+                       if g != "host")
+    want_pack = any(g == "host" for g, _r in score_cols)
+    return PACK_MODES[pack_mode][1], n_filters, dev_groups, want_pack
+
+
 class _DeviceAttribution:
-    """Per-replay-run context for the on-device attribution reduction:
-    pads the per-pod PreFilter/score skip masks to the chunk grid, puts
-    them on device ONCE, and runs the cached jit'd per-chunk sums whose
-    outputs ride the decision-row fetch (cc.att)."""
+    """Per-replay-run context for the on-device attribution reduction
+    where the scan holds its workload as leaves (a mesh, the speculative
+    rounds; the packed scan runs the reduction inside its own executable,
+    the masks riding in the pass's bool buffer): pads the per-pod
+    PreFilter/score skip masks to the chunk grid, puts them on device
+    ONCE, and runs the cached jit'd per-chunk sums whose outputs ride the
+    decision-row fetch (cc.att)."""
 
     __slots__ = ("enabled", "chunk", "p", "fskip_dev", "sskip_dev", "_fn")
 
     def __init__(self, cw: CompiledWorkload, chunk: int, pack_mode: str,
                  score_cols: tuple):
-        from .pipeline import PACK_MODES
-
-        f_names = cw.config.filters()
-        s_names = cw.config.scorers()
-        self.enabled = bool(f_names or s_names)
+        plan = _att_plan(cw, pack_mode, score_cols)
+        self.enabled = plan is not None
         if not self.enabled:
             return
-        dev_groups = tuple((s, g, r) for s, (g, r) in enumerate(score_cols)
-                           if g != "host")
-        want_pack = any(g == "host" for g, _r in score_cols)
         p = cw.n_pods
         self.p = p
         self.chunk = chunk
         ppad = max(1, -(-p // chunk)) * chunk
         # pad rows read as "skipped": they contribute nothing even
         # before the valid mask cuts them
-        fmat = np.ones((len(f_names), ppad), np.bool_)
-        fskip = cw.host.get("filter_skip", {})
-        for f, nm in enumerate(f_names):
-            fmat[f, :p] = np.asarray(fskip.get(nm, np.zeros(p)), bool)
-        smat = np.ones((max(len(s_names), 1), ppad), np.bool_)
-        sskip = cw.host.get("score_skip", {})
-        for s, nm in enumerate(s_names):
-            smat[s, :p] = np.asarray(sskip.get(nm, np.zeros(p)), bool)
-        self.fskip_dev = jnp.asarray(fmat)
-        self.sskip_dev = jnp.asarray(smat)
-        self._fn = _att_fn_for(chunk, cw.n_nodes,
-                               PACK_MODES[pack_mode][1], len(f_names),
-                               dev_groups, want_pack)
+        fskip, sskip = (np.pad(mask, ((0, 0), (0, ppad - p)),
+                               constant_values=True)
+                        for mask in attribution_skip_masks(cw))
+        TRACER.count("pass_device_dispatches_total", 2)
+        self.fskip_dev = jnp.asarray(fskip)
+        self.sskip_dev = jnp.asarray(sskip)
+        self._fn = _att_fn_for(chunk, cw.n_nodes, *plan)
 
     def run(self, out, lo: int):
+        TRACER.count("pass_device_dispatches_total", 3)
         fskip_c = self.fskip_dev[:, lo:lo + self.chunk]
         sskip_c = self.sskip_dev[:, lo:lo + self.chunk]
         m = np.int32(min(lo + self.chunk, self.p) - lo)
@@ -1576,23 +1667,83 @@ def _compact_plan(cw: CompiledWorkload, wide: str | None):
 _MAX_INFLIGHT = 4
 
 
+def _leaves_dispatch(cw: CompiledWorkload, chunk: int, unroll: int, mesh,
+                     wide, device_resident: bool, pack_mode: str,
+                     score_dtypes: tuple, score_cols: tuple):
+    """A chunk's dispatch where the workload is held as leaves (a mesh
+    sharded each of them; a workload compile_workload did not make; a
+    pass of more than one chunk):
+    (dispatch(carry, lo, hi) -> (carry, out, attribution sums), the first
+    chunk's carry)."""
+    scan_jit = _scan_for(cw, chunk, unroll, mesh, pack_mode=pack_mode,
+                         score_dtypes=score_dtypes, wide=wide)
+    # copy: the scan donates its carry argument, and cw.init_carry must
+    # survive for subsequent replays of the same compiled workload
+    TRACER.count("pass_device_dispatches_total")
+    carry = _copy_carry(cw.init_carry)
+    xs, arg_statics = cw.xs, cw.arg_statics()
+    att_ctx = (_DeviceAttribution(cw, chunk, pack_mode, score_cols)
+               if device_resident else None)
+    if att_ctx is not None and not att_ctx.enabled:
+        att_ctx = None
+
+    def dispatch(carry, lo: int, hi: int):
+        xs_chunk = _slice_xs(xs, lo, hi, chunk)
+        TRACER.count("pass_device_dispatches_total", 3)
+        xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
+        carry, out = scan_jit(carry, xs_chunk, arg_statics)
+        return carry, out, (att_ctx.run(out, lo) if att_ctx is not None
+                            else None)
+
+    return dispatch, carry
+
+
+def _packed_dispatch(cw: CompiledWorkload, unroll: int, wide,
+                     device_resident: bool, pack_mode: str,
+                     score_dtypes: tuple, score_cols: tuple):
+    """The dispatch of a pass of one chunk over the buffers as
+    compile_workload uploaded them: ONE call, of the executable
+    _packed_scan_for describes.  Same return as _leaves_dispatch; the
+    carry is in the buffers and stays there."""
+    att_plan = (_att_plan(cw, pack_mode, score_cols) if device_resident
+                else None)
+    scan, args = _packed_scan_for(cw, unroll, pack_mode, score_dtypes, wide,
+                                  att_plan)
+
+    def dispatch(carry, lo: int, hi: int):
+        TRACER.count("pass_device_dispatches_total")
+        return (carry, *scan(*args))
+
+    return dispatch, None
+
+
 def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
                 mesh, wide: str | None, on_chunk=None,
                 device_resident: bool = False) -> ReplayResult | None:
     p = cw.n_pods
     chunk = min(chunk, max(p, 1))
+    # which route depends on what the replay is handed, nothing else:
+    # compile_workload's upload as it was sent, and a pass of one chunk
+    # (every served pass) -> the packed scan.  Leaves (parallel/mesh.py
+    # shard_workload's copy, a hand-built workload) -> the scan over
+    # leaves; and so does a pass of more chunks, whose leaves are unpacked
+    # once: over the buffers it would cut the whole pass's xs out again
+    # in every chunk, and compile a second executable (the carry in) of
+    # minutes on the chip where the leaves route compiles one
+    packed = cw.packed is not None and p <= chunk
+    TRACER.inc("replay_route_total", route="packed" if packed else "leaves")
     # scan_prepare: everything between the replay span's start and the
-    # first dispatch that is not a chunk's own (the scan-cache key with
-    # its statics fingerprint, the carry copy)
+    # first chunk's dispatch: the compact plan, the scan-cache key with
+    # its statics fingerprint and the registry's lookup; on the leaves
+    # route also the carry's copy and the skip masks' upload
     with TRACER.span("scan_prepare"):
-        pack_mode, score_dtypes, score_cols = _compact_plan(cw, wide)
-        scan_jit = _scan_for(cw, chunk, unroll, mesh, pack_mode=pack_mode,
-                             score_dtypes=score_dtypes, wide=wide)
-
-        # copy: the scan donates its carry argument, and cw.init_carry must
-        # survive for subsequent replays of the same compiled workload
-        carry = _copy_carry(cw.init_carry)
-        arg_statics = cw.arg_statics()
+        pack_mode, score_dtypes, score_cols = plan = _compact_plan(cw, wide)
+        if packed:
+            dispatch, carry = _packed_dispatch(
+                cw, unroll, wide, device_resident, *plan)
+        else:
+            dispatch, carry = _leaves_dispatch(
+                cw, chunk, unroll, mesh, wide, device_resident, *plan)
     from concurrent.futures import ThreadPoolExecutor
 
     # chunks are ingested in dispatch order the moment their
@@ -1614,11 +1765,6 @@ def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
         prefilter_reject=prefilter_reject, compact=compact,
     )
     check_overflow = wide != "i64"
-    with TRACER.span("scan_prepare"):  # the skip masks go to the device
-        att_ctx = (_DeviceAttribution(cw, chunk, pack_mode, score_cols)
-                   if device_resident else None)
-    if att_ctx is not None and not att_ctx.enabled:
-        att_ctx = None
 
     def ingest(c: dict, lo: int, dev_out) -> bool:
         if check_overflow and c["raw_overflow"].any():
@@ -1706,16 +1852,12 @@ def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
         for lo in range(0, p, chunk):
             hi = min(lo + chunk, p)
             fault_point("replay.scan_dispatch")
-            # scan_dispatch: slice the chunk's xs and call the jitted
-            # scan (+ the attribution reduction): tracing, lowering and
-            # the XLA compile on a miss, then the enqueue
+            # scan_dispatch: the chunk's call (packed: one executable
+            # that cuts its own leaves; leaves: the eager slices, the
+            # scan, the attribution reduction): tracing, lowering and the
+            # XLA compile on a miss, then the enqueue
             with TRACER.span("scan_dispatch", lo=lo):
-                xs_chunk = _slice_xs(cw.xs, lo, hi, chunk)
-                xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
-                carry, out = scan_jit(carry, xs_chunk, arg_statics)
-                att_out = (att_ctx.run(out, lo)
-                           if device_resident and att_ctx is not None
-                           else None)
+                carry, out, att_out = dispatch(carry, lo, hi)
             # dispatch returns immediately; a fetch thread blocks on this
             # chunk's transfer while the device runs later chunks.  In
             # device-resident mode that transfer is the decision rows +

@@ -891,8 +891,13 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
         fill -= chunk
         ingest_chunk(heads)
 
-    # copy: the commit/scan fold donates its carry argument, and
-    # cw.init_carry must survive for later replays of the same workload
+    # the rounds gather and place xs batch by batch, so they hold the
+    # workload as leaves (unpacked here, once, where compile_workload
+    # left it packed).  copy: the commit/scan fold donates its carry
+    # argument, and cw.init_carry must survive for later replays of the
+    # same workload
+    TRACER.inc("replay_route_total", route="leaves")
+    TRACER.count("pass_device_dispatches_total")
     carry = _copy_carry(cw.init_carry)
     stats = _SpecStats()
     cw_scan = None       # mesh-sharded clone, built on first scan round

@@ -202,7 +202,7 @@ def build(table: NodeTable, pods: list[dict],
         host_out.setdefault("static_score_rows", {})[NAME] = (
             np.ascontiguousarray(np.take(pref_mat, pref_idx, axis=0)))
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = NodeAffinityStatic(
         req_rows=np.stack(req_pool),
         pref_rows=pref_mat,

@@ -272,7 +272,7 @@ def build(table: NodeTable, pods: list[dict], bound,
     )
 
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = InterPodStatic(dom_idx=dom_idx, hard_weight=np.int64(hard_weight))
     xs = InterPodXS(
         t_matches=t_matches,

@@ -76,7 +76,7 @@ class FitPodXS(NamedTuple):
 def build_fit(table, schema: ResourceSchema, requests, nonzero,
               fit_args: dict | None = None):
     # statics and xs leave every build as numpy: compile_workload digests
-    # the host bytes for the scan-cache key, then uploads (upload_tree)
+    # the host bytes for the scan-cache key, then uploads (pack_tree)
     static = FitStatic(
         allocatable=np.asarray(table.allocatable),
         allowed_pods=np.asarray(table.allowed_pods),

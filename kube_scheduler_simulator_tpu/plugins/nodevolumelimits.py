@@ -117,7 +117,7 @@ def build(vt: VolumeTable, table, pods: list[dict], bound):
         np.zeros((n, 0), dtype=np.int64)
 
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = LimitsStatic(driver_onehot=onehot, limits=limits)
     xs = LimitsXS(pod_vols=pod_vols, filter_skip=skip)
     carry = LimitsCarry(on_node=bound.plane(0, nc))

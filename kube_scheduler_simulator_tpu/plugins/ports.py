@@ -138,7 +138,7 @@ def build(table, pods: list[dict], bound_pods: list[tuple[dict, str]]):
                 used_spec[j, intern.s_id(proto, port, ip)] = True
 
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = PortsStatic(sq=np.asarray(intern.sq, dtype=np.int32))
     xs = PortsXS(
         w_wild=w_wild, w_spec=w_spec,

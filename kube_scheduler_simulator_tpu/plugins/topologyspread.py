@@ -276,7 +276,7 @@ def build(table: NodeTable, pods: list[dict]):
         score_skip[i] = not is_score[i].any()
 
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = SpreadStatic(dom_idx=dom_idx, n_groups=n_groups)
     xs = SpreadXS(
         pm=pm,

@@ -210,7 +210,7 @@ def build(vt: VolumeTable, table, pods: list[dict], bound_pods=None):
                     )
 
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = BindingStatic(
         pv_cap=np.asarray(vt.pv_cap), pv_node_ok=np.asarray(vt.pv_node_ok)
     )

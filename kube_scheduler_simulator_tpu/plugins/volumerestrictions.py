@@ -145,7 +145,7 @@ def build(vt: VolumeTable, table, pods: list[dict], disks, rwops):
     strict[disks.n:] = [ident[0] == "aws" for ident in new_disks]
 
     # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (upload_tree)
+    # digest off the host bytes, then uploads once (pack_tree)
     static = RestrictionsStatic(strict=strict)
     xs = RestrictionsXS(
         w_any=w_any, w_rw=w_rw,

@@ -31,12 +31,22 @@ on the chip's host every host<->device call costs ~0.2-0.35 ms whatever
 its size, and a pass has 63 such leaves.  compile_workload reads what the
 host needs (the decoder's skip flags, the packing bounds, the statics'
 digest for the scan-cache key) off those numpy leaves, and then sends the
-trees to the device once, in cw_finish's child span cw_upload
-(upload_tree: one buffer per dtype, one jitted dispatch that slices the
-leaves apart; counter workload_h2d_transfers_total).  Past that point
-cw.statics, cw.xs and cw.init_carry are device arrays, and nothing here
-reads one back.  A new build follows the same rule: build in numpy, return
-numpy, keep a host copy in `host` for whatever the decoder needs.
+trees to the device once, in cw_finish's child span cw_upload (pack_tree:
+one buffer per dtype; counter workload_h2d_transfers_total).  The pass's
+own trees (xs, carry, argument statics, the attribution's skip masks)
+STAY as they were sent (cw.packed, a PackedPass): a device buffer costs
+the host ~0.055 ms to be handed out of a jitted call, so the sequential
+scan of a pass of one chunk takes the five buffers and cuts its leaves
+out of them inside its own executable (framework/replay.py
+_packed_scan_for).  cw.xs, cw.init_carry and cw.statics still read as
+trees of device arrays for whoever needs leaves (a mesh shards each, the
+speculative rounds gather batches, the host-interleaved path indexes
+pods, a pass of many chunks slices each chunk): unpacked on first
+access, by one jitted dispatch, memoised on the workload.  The closure
+statics of a changed node table are unpacked at once (upload_tree): the
+jitted step closes over those arrays.  Nothing here reads a device array
+back.  A new build follows the same rule: build in numpy, return numpy,
+keep a host copy in `host` for whatever the decoder needs.
 
 Two leaves do not travel every pass.  The volume family's pv_node_ok
 [V, N] and on_node [N, C] are 41 MB each at 8,192 x 5,000 and change by a
@@ -45,24 +55,23 @@ row and a bit: a session's volume carry keeps them on the device
 compile_workload the payload of a patch in their place (a few KB that
 ride in the same buffers); after the upload a jitted dispatch an array
 makes this pass's device array from the last pass's (child span
-cw_resident_patch).  The builds still return the numpy arrays, and the
-digest, the flags and host["volume_table"] still read host bytes.  A
-throw-away carry, an empty axis, and whatever the carry's journal cannot
-say (another node table, a bucket outgrown, a resync) upload whole
-through the same upload_tree.
+cw_resident_patch), and the scan takes the two arrays as arguments of
+their own beside the buffers.  The builds still return the numpy arrays,
+and the digest, the flags and host["volume_table"] still read host bytes.
+What the carry's journal cannot say (another node table, a bucket
+outgrown, a resync) goes whole in a transfer of its own, beside a payload
+that writes nothing, so that the buffers keep one layout; a throw-away
+carry's and an empty axis's are leaves like any other.
 """
 
 from __future__ import annotations
 
 import hashlib
-import math
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any
 
 import jax
 import numpy as np
-from jax import lax
 
 from . import resources as res
 from .nodes import (
@@ -73,6 +82,7 @@ from .nodes import (
     patch_node_table_columnar,
 )
 from .boundcarry import BoundCarry, carry_of_list, pod_key, pod_request_rows
+from .packed import PackedPass, pack_tree, upload_tree
 from .resources import ResourceSchema
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
@@ -117,6 +127,14 @@ class CompiledWorkload:
     xs: dict[str, Any]                  # plugin name -> per-pod pytree (leading axis P)
     init_carry: dict[str, Any]          # carry component name -> pytree
     host: dict[str, Any] = field(default_factory=dict)  # numpy skip flags etc.
+    # what compile_workload uploaded, as it was uploaded (PackedPass): the
+    # sequential scan of a one-chunk pass takes it whole, and xs /
+    # init_carry / the argument statics are unpacked from it on first
+    # access.  None for a workload
+    # built any other way (dataclasses.replace, parallel/mesh.py
+    # shard_workload, by hand): its trees are what it was given
+    packed: PackedPass | None = field(default=None, init=False,
+                                      repr=False, compare=False)
 
     @property
     def n_pods(self) -> int:
@@ -126,9 +144,40 @@ class CompiledWorkload:
     def n_nodes(self) -> int:
         return self.node_table.n
 
+    def closure_statics(self) -> dict[str, Any]:
+        """The statics a jitted scan closes over; asks no unpack."""
+        return split_statics(self.__dict__["_statics"])[0]
+
     def arg_statics(self) -> dict[str, Any]:
-        """The statics a jitted scan takes as its third argument."""
+        """The statics a jitted scan takes as an argument."""
         return split_statics(self.statics)[1]
+
+    def _unpack(self) -> None:
+        own = self.__dict__
+        own["_xs"], own["_init_carry"], args = self.packed.take(
+            self.packed.tree[:3])
+        own["_statics"] = {**own["_statics"], **args}
+
+
+def _unpacked_on_first_access(name: str) -> property:
+    """A CompiledWorkload field that, on a workload compile_workload made,
+    is a tree of device arrays only once somebody reads it (one jitted
+    dispatch for the three trees, memoised on the workload)."""
+    slot = "_" + name
+
+    def get(self):
+        if self.packed is not None and self.__dict__.get("_xs") is None:
+            self._unpack()
+        return self.__dict__[slot]
+
+    def put(self, tree):
+        self.__dict__[slot] = tree
+
+    return property(get, put)
+
+
+for _name in ("statics", "xs", "init_carry"):
+    setattr(CompiledWorkload, _name, _unpacked_on_first_access(_name))
 
 
 class NodeTableReuse:
@@ -409,22 +458,34 @@ def compile_workload(
         # the one upload site of a pass.  The closure statics are
         # node-side tensors: where the digest is the last pass's on this
         # table, so are the device arrays (one generation; the jitted step
-        # closes over them, nothing donates or writes one).  The argument
-        # statics travel with xs and carry, whatever they hold; a carried
-        # session's two cluster-sized leaves travel as the patch of what
-        # changed in them, where the carry's journal can say it
+        # closes over them, nothing donates or writes one).  xs, the carry
+        # and the argument statics travel every pass, whatever they hold,
+        # with the attribution's skip masks, and stay as they travelled
+        # (cw.packed): the one-chunk sequential scan unpacks them inside
+        # its own executable.  A carried session's two cluster-sized leaves are
+        # device arrays already; what rides in their place is the payload
+        # of a patch
         with TRACER.span("cw_upload"):
-            on_device = table.derived.generation(
+            cw.statics = table.derived.generation(
                 "statics_device", digest, lambda: upload_tree(closure))
             resident = _resident_leaves(volume_carry, args, init_carry)
-            _swap_resident(resident, "outgoing", args, init_carry)
+            _swap_resident(resident, (args, init_carry),
+                           lambda kept, host: kept.outgoing(host))
             TRACER.count("volume_static_args_bytes_total",
-                         sum(leaf.nbytes for leaf in jax.tree.leaves(args)))
-            cw.xs, cw.init_carry, args = upload_tree((xs, init_carry, args))
+                         sum(leaf.nbytes for leaf in jax.tree.leaves(args))
+                         + sum(kept.whole_nbytes for t, _n, _l, kept
+                               in resident if t == 0))
+            cw.packed = pack_tree(
+                (xs, init_carry, args, attribution_skip_masks(cw)))
+            cw.xs = cw.init_carry = None        # unpacked on first access
             if resident:
+                # ... and in the payload's place, the array brought up to
+                # date from it
+                packed = cw.packed
                 with TRACER.span("cw_resident_patch"):
-                    _swap_resident(resident, "incoming", args, cw.init_carry)
-            cw.statics = {**on_device, **args}
+                    _swap_resident(
+                        resident, (packed.tree[2], packed.tree[1]),
+                        lambda kept, rode: kept.incoming(packed, rode))
     return cw
 
 
@@ -445,13 +506,13 @@ def _resident_leaves(volume_carry: VolumeCarry | None, args: dict,
         if name in trees[t] and getattr(trees[t][name], leaf).size]
 
 
-def _swap_resident(resident: list, how: str, *trees: dict) -> None:
-    """Put into each resident leaf's place what its `outgoing` (before the
-    upload) or `incoming` (after it) makes of what is there."""
+def _swap_resident(resident: list, trees: tuple, put) -> None:
+    """Put into each resident leaf's place in `trees` (the argument
+    statics, the carry) what `put(its resident, what is there)` makes."""
     for t, name, leaf, kept in resident:
         owner = trees[t][name]
         trees[t][name] = owner._replace(
-            **{leaf: getattr(kept, how)(getattr(owner, leaf))})
+            **{leaf: put(kept, getattr(owner, leaf))})
 
 
 def statics_digest(statics: dict[str, Any]) -> str:
@@ -473,47 +534,6 @@ def statics_digest(statics: dict[str, Any]) -> str:
             h.update(str(a.dtype).encode())
             h.update(a.tobytes())
     return h.hexdigest()
-
-
-def upload_tree(tree):
-    """A tree of numpy leaves -> the same tree of device arrays, each with
-    the shape, dtype and (non-)weak type jnp.asarray would give it; what
-    is not a numpy array (SpreadStatic.n_groups, a Python int the step
-    reads as a constant) stays as it is.
-
-    What a leaf costs on the chip's host is the call, not the bytes (a
-    pass's 63 leaves are 435 KB): ~0.24 ms a jnp.asarray, ~0.17 ms a leaf
-    of one jax.device_put(tree).  So the leaves travel as one contiguous
-    host buffer per dtype, and one jitted dispatch slices them apart on
-    the device (_unpack; its layout is the same from pass to pass, so it
-    compiles with the scan and never after)."""
-    leaves, treedef = jax.tree.flatten(tree)
-    at = [i for i, leaf in enumerate(leaves)
-          if isinstance(leaf, (np.ndarray, np.generic))]
-    if not at:
-        return tree
-    parts: dict[str, list[np.ndarray]] = {}
-    for i in at:
-        parts.setdefault(leaves[i].dtype.name, []).append(np.ravel(leaves[i]))
-    TRACER.count("workload_h2d_transfers_total", len(parts))
-    bufs = jax.device_put({dt: np.concatenate(p) for dt, p in parts.items()})
-    layout = tuple((leaves[i].dtype.name, leaves[i].shape) for i in at)
-    for i, leaf in zip(at, _unpack(layout, bufs)):
-        leaves[i] = leaf
-    return jax.tree.unflatten(treedef, leaves)
-
-
-@partial(jax.jit, static_argnums=0)
-def _unpack(layout, bufs):
-    """layout: (dtype name, shape) per leaf, in the order the leaves were
-    laid into their dtype's buffer."""
-    offs = dict.fromkeys(bufs, 0)
-    out = []
-    for dt, shape in layout:
-        end = offs[dt] + math.prod(shape)
-        out.append(lax.slice(bufs[dt], (offs[dt],), (end,)).reshape(shape))
-        offs[dt] = end
-    return out
 
 
 # the plugins whose build (with its carry priming) compile_workload wraps
@@ -632,6 +652,24 @@ def _collect_host_flags(cw: CompiledWorkload):
     cw.host["score_dtypes"] = tuple(
         _score_dtype(cw, name) for name in cw.config.scorers()
     )
+
+
+def attribution_skip_masks(cw: CompiledWorkload) -> tuple:
+    """([F, P], [max(S, 1), P]) bool: per filter and per scorer, the pods
+    whose PreFilter / PreScore skipped it, from the decoder's host flags.
+    The on-device attribution reduction (framework/replay.py) leaves such
+    a pod out of the plugin's sums; the masks ride in the pass's bool
+    buffer."""
+    p = cw.n_pods
+
+    def rows(names, flags, least):
+        mat = np.zeros((max(len(names), least), p), np.bool_)
+        for i, name in enumerate(names):
+            mat[i] = np.asarray(flags.get(name, False), bool)
+        return mat
+
+    return (rows(cw.config.filters(), cw.host.get("filter_skip", {}), 0),
+            rows(cw.config.scorers(), cw.host.get("score_skip", {}), 1))
 
 
 def _collect_prefilter_results(cw: CompiledWorkload):

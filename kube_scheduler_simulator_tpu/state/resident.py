@@ -6,7 +6,9 @@ row and a bit.  The truth stays on the host: the carry writes its numpy
 arrays as before, and tells a journal here WHERE it wrote.  At the pass's
 one upload site (state/compile.py, span cw_upload) the journal becomes a
 small payload that rides in the pass's packed buffers in the leaf's
-place, and one jitted dispatch makes the new device array from the old:
+place (state/packed.py; the scan takes the array itself as an argument
+of its own beside them), and one jitted dispatch, which cuts the payload
+out of those buffers itself, makes the new device array from the old:
 
   RowsResident    new = old[src] with the rows written afresh set from the
                   payload: src [V] says which old row each row is (-1: a
@@ -23,15 +25,16 @@ setting it once), so their shapes are the arrays' buckets': the patch is
 compiled when an array is first made resident and never after.
 
 What a journal cannot say drops the device copy, and the next pass sends
-the array whole through the same upload (volume_resident_uploads_total
-{reason}): first | resync | nodes | drivers | bucket (another shape) |
-overflow (more written than a payload holds: an import).  Nothing is
-donated: an earlier pass's CompiledWorkload may hold the generation
+the array whole, in a transfer of its own beside a payload that writes
+nothing (volume_resident_uploads_total{reason}): first | resync | nodes |
+drivers | bucket (another shape) | overflow (more written than a payload
+holds: an import).  Nothing is donated: an earlier pass's CompiledWorkload may hold the generation
 before, which is freed with its last holder.
 """
 
 from __future__ import annotations
 
+import math
 from functools import lru_cache
 from typing import NamedTuple
 
@@ -40,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.tracing import TRACER
+from .packed import PackedPass, unpack_leaves
 
 ROWS_MAX = 8        # rows written afresh that one patch carries
 CELLS_MAX = 256     # cells that one patch carries
@@ -68,11 +72,29 @@ def _patch_cells(old, patch: CellsPatch):
     return old.at[patch.at[0], patch.at[1]].set(patch.values)
 
 
-@lru_cache(maxsize=16)
-def _compiled(fn, old: jax.ShapeDtypeStruct, patch: NamedTuple):
-    """fn compiled for an array and a patch of these shapes: made when an
-    array becomes resident, so that no patched pass meets the compile."""
-    return jax.jit(fn).lower(old, patch).compile()
+def _patch_program(fn, layout: tuple, patch: type, picks: tuple):
+    """(run(old, bufs) -> new: fn applied to the payload that lies at
+    leaves `picks` of a pass's packed buffers (state/packed.py: `layout`),
+    cut out of them inside the same executable; the buffers' shapes)."""
+    def run(old, bufs):
+        return fn(old, patch(*unpack_leaves(layout, picks, bufs)))
+
+    sizes: dict[str, int] = {}
+    for dt, shape in layout:
+        sizes[dt] = sizes.get(dt, 0) + math.prod(shape)
+    return run, {dt: jax.ShapeDtypeStruct((n,), np.dtype(dt))
+                 for dt, n in sizes.items()}
+
+
+@lru_cache(maxsize=64)
+def _compiled(fn, old: jax.ShapeDtypeStruct, layout: tuple, patch: type,
+              picks: tuple):
+    """_patch_program compiled for an array of this shape.  Made when an
+    array becomes resident, so that no patched pass of the same layout
+    meets the compile; a pass of another layout (another pod count: its
+    scan compiles too) compiles its own."""
+    run, bufs = _patch_program(fn, layout, patch, picks)
+    return jax.jit(run).lower(old, bufs).compile()
 
 
 class _Resident:
@@ -83,7 +105,7 @@ class _Resident:
     def __init__(self):
         self.dev: jax.Array | None = None   # None: the next pass sends whole
         self.why = "first"                  # ... and counts this reason
-        self._run = None
+        self._whole: np.ndarray | None = None   # outgoing() -> incoming()
 
     def drop(self, why: str) -> None:
         """The journal cannot follow what happens next."""
@@ -91,9 +113,13 @@ class _Resident:
             self.dev, self.why = None, why
             self._forget()
 
-    def outgoing(self, host: np.ndarray):
-        """What travels in the leaf's place this pass: the patch's
-        payload, numpy leaves, or the array itself."""
+    def outgoing(self, host: np.ndarray) -> NamedTuple:
+        """What rides in the leaf's place in the pass's packed buffers: the
+        patch's payload, numpy leaves.  Always one, of the bucket's fixed
+        shapes, so that the buffers have one layout (and the scan that
+        takes them whole one executable) whether the array is patched or
+        sent whole; where it is sent whole, by incoming() and in a transfer
+        of its own, the payload writes nothing."""
         if self.dev is not None and self.dev.shape != host.shape:
             self.drop("bucket")
         if self.dev is not None:
@@ -102,17 +128,30 @@ class _Resident:
                 return patch
             self.drop("overflow")
         TRACER.inc("volume_resident_uploads_total", reason=self.why)
-        return host
+        self._whole = host
+        return self._payload(host)      # of a journal that holds nothing
 
-    def incoming(self, sent) -> jax.Array:
-        """`sent` is outgoing()'s value as the upload returned it -> the
-        device array of this pass."""
-        if isinstance(sent, jax.Array):
-            self.dev, self.why = sent, None
-            like = jax.ShapeDtypeStruct(sent.shape, sent.dtype)
-            self._run = _compiled(self.patch_fn, like, self._shapes(like))
-        elif self._written():
-            self.dev = self._run(self.dev, sent)
+    @property
+    def whole_nbytes(self) -> int:
+        """The bytes this pass sends beside the payload."""
+        return 0 if self._whole is None else self._whole.nbytes
+
+    def incoming(self, packed: PackedPass, rode: NamedTuple) -> jax.Array:
+        """`rode` is outgoing()'s payload as it lies in the pass's upload,
+        a Packed a leaf -> the device array of this pass."""
+        picks = tuple(leaf.k for leaf in rode)
+        if self._whole is not None:
+            TRACER.count("workload_h2d_transfers_total")
+            TRACER.count("pass_device_dispatches_total")
+            # a copy: the carry goes on writing into its array, and a
+            # device_put may alias the host's memory (the CPU backend does)
+            self.dev, self.why = jnp.array(self._whole), None
+            self._whole = None
+        like = jax.ShapeDtypeStruct(self.dev.shape, self.dev.dtype)
+        run = _compiled(self.patch_fn, like, packed.layout, type(rode), picks)
+        if self._written():
+            TRACER.count("pass_device_dispatches_total")
+            self.dev = run(self.dev, packed.bufs)
             TRACER.count("volume_resident_patches_total")
         self._forget()
         return self.dev
@@ -158,12 +197,6 @@ class RowsResident(_Resident):
             new[:v] = np.where(src >= 0, old[np.maximum(src, 0)], _FRESH)
             self._src = new
 
-    def _shapes(self, like) -> RowsPatch:
-        v, n = like.shape
-        return RowsPatch(src=jax.ShapeDtypeStruct((v,), np.int32),
-                         rows=jax.ShapeDtypeStruct((ROWS_MAX,), np.int32),
-                         fresh=jax.ShapeDtypeStruct((ROWS_MAX, n), like.dtype))
-
     def _payload(self, host: np.ndarray) -> RowsPatch | None:
         src = self._src if self._src is not None else np.arange(
             host.shape[0], dtype=np.int32)
@@ -195,10 +228,6 @@ class CellsResident(_Resident):
             self._cells.add((j, s))
             if len(self._cells) > CELLS_MAX:
                 self.drop("overflow")
-
-    def _shapes(self, like) -> CellsPatch:
-        return CellsPatch(at=jax.ShapeDtypeStruct((2, CELLS_MAX), np.int32),
-                          values=jax.ShapeDtypeStruct((CELLS_MAX,), like.dtype))
 
     def _payload(self, host: np.ndarray) -> CellsPatch | None:
         # a slot past the extent is no column of this pass's plane; padded

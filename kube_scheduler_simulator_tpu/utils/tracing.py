@@ -250,9 +250,22 @@ _HELP: dict[str, str] = {
         "Compiled scan executables currently held by the process-level "
         "LRU cache (framework/replay._ScanCacheRegistry).",
     "workload_h2d_transfers_total":
-        "Host-to-device buffers compile_workload sent (state/compile.py "
-        "upload_tree): one per dtype of a pass's xs and carry, and of the "
-        "statics where their digest is new on the node table.",
+        "Host-to-device buffers compile_workload sent (state/packed.py "
+        "pack_tree): one per dtype of a pass's xs and carry, and of the "
+        "statics where their digest is new on the node table; one more "
+        "for a resident volume array sent whole.",
+    "pass_device_dispatches_total":
+        "Calls that handed the runtime a transfer, a jitted call or an "
+        "eager jnp op on a pass's way from cw_upload's start to the "
+        "scan's enqueue (state/packed.py pack_tree / PackedPass.take, "
+        "state/resident.py, framework/replay.py): 2 a steady one-pod pass "
+        "over the packed buffers, ~60 where every leaf is sliced apart.",
+    "replay_route_total":
+        "Scans by how they held the workload (route=packed|leaves): "
+        "packed, compile_workload's upload taken whole by the executable "
+        "of a one-chunk sequential scan; leaves, a tree of device arrays "
+        "(a mesh, the speculative rounds, a pass of many chunks, a "
+        "hand-built workload).",
     "volume_manifests_parsed_total":
         "PersistentVolume, PersistentVolumeClaim and CSINode manifests the "
         "volume carry parsed (state/volumecarry.py; kind=pv|pvc|csinode): "

@@ -1,12 +1,21 @@
-"""scan_prepare asks the device nothing (framework/replay.py, PR 29).
+"""scan_prepare asks the device nothing (framework/replay.py, PR 29), and
+since PR 44 hands it nothing either where the scan takes the pass's
+buffers as compile_workload uploaded them.
 
-The scan-cache key is built from array metadata and from a digest that
+The scan-cache key is built from array metadata (a leaf's own, or the
+packed layout's, which says the same) and from a digest that
 compile_workload takes over the statics' host bytes before it uploads
-them; the carry copy is one jitted dispatch.  Held here: the digest is
-the one the fetch-and-hash fallback computes and is as discriminating,
-nothing in scan_prepare converts a device array to numpy, the workload's
-own init_carry survives the donated scan, and the counter that says which
-way a key's digest came.
+them; the first chunk's carry is cut out of the pass's buffers inside the
+scan's executable, and where the workload is held as leaves (a mesh, the
+speculative rounds) the carry copy is one jitted dispatch.  Held here: the
+digest is the one the fetch-and-hash fallback computes and is as
+discriminating, the key is the same on both routes, nothing in
+scan_prepare converts a device array to numpy, the workload's own
+init_carry survives the donated scan on either route and under the
+width-tier rerun, a steady one-pod pass is two dispatches over the packed
+route, a mesh, the speculative rounds or a pass of many chunks read leaves
+that are unpacked once, and the counter that says which way a key's digest
+came.
 """
 
 from __future__ import annotations
@@ -24,15 +33,21 @@ from kube_scheduler_simulator_tpu.framework.replay import (
     _copy_carry, _statics_fingerprint, _workload_scan_key, replay)
 from kube_scheduler_simulator_tpu.models.workloads import (
     baseline_config, make_nodes, make_pods)
+from kube_scheduler_simulator_tpu.parallel.mesh import make_mesh
+from kube_scheduler_simulator_tpu.parallel.speculative import (
+    replay_speculative)
+from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.server.sessions import SessionManager
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration
 from kube_scheduler_simulator_tpu.state.compile import (
-    compile_workload, statics_digest, upload_tree)
+    compile_workload, statics_digest)
+from kube_scheduler_simulator_tpu.state.packed import upload_tree
 from kube_scheduler_simulator_tpu.store.decode import decode_pod_result
 from kube_scheduler_simulator_tpu.utils import hostevents
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
 replay_mod = sys.modules["kube_scheduler_simulator_tpu.framework.replay"]
+packed_mod = sys.modules["kube_scheduler_simulator_tpu.state.packed"]
 
 # the parity suite's three constraint profiles (tests/test_parity.py):
 # NodeAffinity + taints, + PodTopologySpread, + InterPodAffinity
@@ -77,6 +92,20 @@ def test_digest_from_host_bytes_is_the_fetched_one(idx, scale):
     # equal statics from a second compile share the key
     again, _ = _cw(idx, scale)
     assert _workload_scan_key(again, 16) == _workload_scan_key(cw, 16)
+
+
+@pytest.mark.parametrize("idx,scale", PROFILES)
+def test_key_is_the_same_on_both_routes(idx, scale):
+    """The packed layout carries the paths, shapes and dtypes that the
+    leaves would: a cached executable is shared by the same workloads."""
+    cw, _ = _cw(idx, scale)
+    assert cw.packed is not None
+    key = _workload_scan_key(cw, 16)
+    assert cw.__dict__["_xs"] is None, "the key unpacked the workload"
+    as_leaves = dataclasses.replace(cw)
+    assert as_leaves.packed is None
+    assert _workload_scan_key(as_leaves, 16) == key
+    assert _workload_scan_key(cw, 16) == key    # unpacked by now: the same
 
 
 def _core_variants(core):
@@ -233,29 +262,143 @@ def test_replay_twice_on_one_workload(idx, scale):
         np.testing.assert_array_equal(np.asarray(leaf), want)
 
 
-def test_width_tier_rerun_replays_the_same_init_carry(monkeypatch):
-    """The wider rerun starts from cw.init_carry again after the first
-    tier's scan was donated its copy."""
+@pytest.mark.parametrize("route,chunk,nth", [
+    ("packed", 4096, 2), ("many_chunks", 32, 3), ("leaves", 32, 3)])
+def test_width_tier_rerun_replays_the_same_init_carry(monkeypatch, route,
+                                                      chunk, nth):
+    """The wider rerun starts from cw.init_carry again: over the packed
+    buffers (a pass of one chunk) the first tier cut its carry out of
+    buffers that nothing donates; over leaves (a pass of many chunks,
+    unpacked once; a hand-held workload) the first tier's scan was donated
+    a copy."""
     cw, (_, pods, _) = _cw(4, 0.02, seed=11)
-    plain = _decoded(replay(cw, chunk=32, device_resident=True), len(pods))
+    if route == "leaves":
+        cw = dataclasses.replace(cw)
+    assert (cw.packed is not None) == (route != "leaves")
+    plain = _decoded(replay(cw, chunk=chunk, device_resident=True), len(pods))
     real_fetch = replay_mod._fetch_decisions
     state = {"count": 0}
 
     def inject_overflow(out_dev, att):
         c = real_fetch(out_dev, att)
         state["count"] += 1
-        if state["count"] == 3:
+        if state["count"] == nth:
             c["raw_overflow"] = np.asarray(True)
         return c
 
     monkeypatch.setattr(replay_mod, "_fetch_decisions", inject_overflow)
+    state["count"] = 1 if route == "packed" else 0
     before = TRACER.counter_totals().get("replay_width_retries_total", 0)
-    rr = replay(cw, chunk=32, device_resident=True)
+    rr = replay(cw, chunk=chunk, device_resident=True)
     assert TRACER.counter_totals().get(
         "replay_width_retries_total", 0) - before >= 1
     assert _decoded(rr, len(pods)) == plain
+    if route != "leaves":
+        assert (cw.__dict__["_xs"] is None) == (route == "packed")
+        assert not any(b.is_deleted() for b in cw.packed.bufs.values())
     for leaf in jax.tree.leaves(cw.init_carry):
         np.asarray(leaf)                  # not deleted by either donation
+
+
+# ------------------------------------------------ the route, the dispatches
+
+
+def _routes() -> dict[str, float]:
+    return TRACER.labeled_totals("replay_route_total", "route")
+
+
+class _UnpackSpy:
+    """Counts the dispatches that hand a packed workload's leaves out as
+    device arrays of their own (state/packed.py PackedPass.take)."""
+
+    def __init__(self, monkeypatch):
+        self.calls = 0
+        real = packed_mod._unpack
+
+        def counted(*args):
+            self.calls += 1
+            return real(*args)
+
+        monkeypatch.setattr(packed_mod, "_unpack", counted)
+
+
+def _spec_workload():
+    nodes = make_nodes(24, seed=9, taint_fraction=0.2)
+    pods = make_pods(20, seed=10, with_affinity=True, with_tolerations=True)
+    cfg = PluginSetConfig(enabled=[
+        "NodeResourcesFit", "NodeResourcesBalancedAllocation",
+        "NodeAffinity", "TaintToleration", "NodeUnschedulable", "NodeName"])
+    return compile_workload(nodes, pods, cfg), pods
+
+
+@pytest.mark.parametrize("how", ["mesh", "speculative", "many_chunks"])
+def test_a_mesh_the_speculative_rounds_or_many_chunks_read_leaves_unpacked_once(
+        monkeypatch, how):
+    cw, pods = _spec_workload()
+    base = _decoded(replay(cw, chunk=64), len(pods))
+    assert cw.__dict__["_xs"] is None
+
+    def run():
+        if how == "mesh":
+            return replay(cw, chunk=8, mesh=make_mesh(8, dp=1))
+        if how == "many_chunks":
+            return replay(cw, chunk=8)
+        return replay_speculative(cw, None, batch=4)[0]
+
+    spy = _UnpackSpy(monkeypatch)
+    before = _routes()
+    first = run()
+    assert spy.calls == 1, "the workload's leaves are one dispatch"
+    assert cw.__dict__["_xs"] is not None
+    second = run()
+    assert spy.calls == 1, "unpacked again"
+    after = _routes()
+    assert after.get("leaves", 0) - before.get("leaves", 0) == 2
+    assert after.get("packed", 0) == before.get("packed", 0)
+    assert _decoded(first, len(pods)) == base == _decoded(second, len(pods))
+    # and the one-chunk scan of the same workload still takes it packed
+    assert _decoded(replay(cw, chunk=64), len(pods)) == base
+    assert _routes().get("packed", 0) - after.get("packed", 0) == 1
+    assert spy.calls == 1
+
+
+def test_steady_one_pod_pass_is_two_dispatches_over_the_packed_route():
+    """One pod a pass in a served session under the default profile: the
+    pass's one transfer and the chunk's one call, where the parent made
+    ~60 (an unpack, a carry copy, two mask uploads, 45 slices of xs, ...)."""
+    mgr = SessionManager(cfg=SimulatorConfiguration(port=0),
+                         start_scheduler=False, idle_ttl=0, max_sessions=2)
+    try:
+        sess = mgr.create("dispatches")
+        for n in make_nodes(8, seed=41):
+            sess.di.store.create("nodes", n)
+
+        def counts():
+            snap = TRACER.snapshot(session="dispatches")
+            routes = {"packed": 0.0, "leaves": 0.0}
+            for series in TRACER.snapshot()["labeled_counters"].get(
+                    "replay_route_total", []):
+                if series["labels"].get("session") == "dispatches":
+                    routes[series["labels"]["route"]] += series["value"]
+            return (snap["counters"].get("pass_device_dispatches_total", 0),
+                    snap["counters"].get("scheduling_work_passes_total", 0),
+                    routes)
+
+        rises = []
+        for pod in make_pods(3, seed=42):
+            before = counts()
+            sess.di.store.create("pods", pod)
+            assert sess.di.engine.schedule_pending() == 1
+            after = counts()
+            assert after[1] - before[1] == 1
+            assert after[2]["packed"] - before[2]["packed"] == 1
+            assert after[2]["leaves"] == 0
+            rises.append(after[0] - before[0])
+        assert rises[0] > rises[1], rises      # the statics, once a table
+        assert rises[1] == rises[2] == 2, rises
+        assert rises[0] <= 12, rises
+    finally:
+        mgr.shutdown()
 
 
 # ------------------------------------------------------------ the counter

@@ -6,7 +6,10 @@ un-narrowed one.  Compiled here for a DESCRIBED v5e (the TPU's compiler is
 installed, no chip is attached), as tests/test_tpu_compile_volumes.py does
 for the volume family: what the chip's compiler refuses shows without chip
 time.  A compile that passes is not a chip run: no result and no time is
-read.
+read.  Since PR 44 also the executable the cell actually calls: the scan
+over the pass's packed buffers (framework/replay.py _packed_scan_for),
+with every leaf cut out of its dtype's buffer, the carry with them, and
+the attribution reduction inside it.
 
 The topology is described inside a fixture (never at import time: only one
 process may load the TPU's library, and every xdist worker imports every
@@ -25,9 +28,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from kube_scheduler_simulator_tpu.framework.pipeline import build_step
+import sys
+
 from kube_scheduler_simulator_tpu.framework.replay import _compact_plan
 from kube_scheduler_simulator_tpu.state.compile import (
     compile_workload, split_statics)
+
+replay_mod = sys.modules["kube_scheduler_simulator_tpu.framework.replay"]
 
 N = 15000  # + the named node
 
@@ -104,4 +111,40 @@ def test_narrowed_step_compiles_for_v5e_at_15001_nodes(one_chip,
     compiled = jax.jit(scan_chunk).lower(*placed).compile()
     # nothing of the step is [K, N] wider than its inputs: the mask is one
     # compare against the row's one index
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * (N + 1) * 8
+
+
+def test_packed_scan_compiles_for_v5e_at_15001_nodes(one_chip,
+                                                     no_persistent_cache,
+                                                     monkeypatch):
+    """A pass of three pods, one chunk: every leaf cut out of its dtype's
+    buffer (the int64 one in its emulated layout), the carry with them,
+    the scan and the attribution reduction in one executable."""
+    nodes = [_node(f"node-{i:05d}") for i in range(N)]
+    nodes.append(_node("scheduler-perf-node"))
+    cw = compile_workload(nodes, [_pod("narrowed", "scheduler-perf-node"),
+                                  _pod("plain", None),
+                                  _pod("too", "scheduler-perf-node")])
+    assert cw.packed is not None and set(cw.packed.bufs) >= {"bool", "int64"}
+    # the closure statics as host constants (a described device holds no
+    # array), made jax constants where the step is traced
+    cw.statics = jax.tree.map(np.asarray, cw.closure_statics())
+    with_args = replay_mod._SlimWorkload.with_args
+
+    def traced_constants(self, arg_statics):
+        view = with_args(self, arg_statics)
+        view.statics = {**jax.tree.map(jnp.asarray, self.statics),
+                        **arg_statics}
+        return view
+
+    monkeypatch.setattr(replay_mod._SlimWorkload, "with_args",
+                        traced_constants)
+    pack_mode, score_dtypes, score_cols = _compact_plan(cw, None)
+    scan, (bufs, rest) = replay_mod._packed_scan_for(
+        cw, 1, pack_mode, score_dtypes, None,
+        replay_mod._att_plan(cw, pack_mode, score_cols))
+    assert not rest
+    placed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), bufs)
+    compiled = scan.fn.lower(placed, rest).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * (N + 1) * 8

@@ -18,11 +18,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from kube_scheduler_simulator_tpu.plugins import nodevolumelimits, volumebinding
 from kube_scheduler_simulator_tpu.state import resident
+from kube_scheduler_simulator_tpu.state.packed import pack_tree
 
 N, V, C, D = 5000, 8192, 8192, 1
 
@@ -105,7 +107,14 @@ def test_the_resident_patches_compile_for_v5e(kept, shape, one_chip,
     of the old one's size and nothing beside it (no donation, no
     cluster-sized temporary)."""
     like = _s(shape, jnp.bool_)
-    compiled = _compile(kept.patch_fn, one_chip, like, kept()._shapes(like))
+    # the payload as it rides in a pass's packed buffers (here alone in
+    # them): the patch cuts it out inside its own executable
+    payload = kept()._payload(np.zeros(shape, np.bool_))
+    packed = pack_tree(payload)
+    run, bufs = resident._patch_program(
+        kept.patch_fn, packed.layout, type(payload),
+        tuple(leaf.k for leaf in packed.tree))
+    compiled = _compile(run, one_chip, like, bufs)
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == V * N
     assert memory.alias_size_in_bytes == 0

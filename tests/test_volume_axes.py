@@ -265,8 +265,13 @@ def test_the_family_s_counters_read_the_pass(cluster):
     assert g["volume_bound_rows_walked_total"] == initial
     assert TRACER.snapshot()["gauges"]["volume_table_pvs"] == initial + 1
     # pv_node_ok [64, n] + driver_onehot [64, 1] bools, pv_cap [64] +
-    # limits [n, 1] int64s, VolumeRestrictions' strict [0]
-    assert g["volume_static_args_bytes_total"] == 64 * n + 64 + 8 * 64 + 8 * n
+    # limits [n, 1] int64s, VolumeRestrictions' strict [0]; and, since the
+    # pass's buffers have one layout whether pv_node_ok is sent whole or
+    # patched (PR 44), the payload of a patch that writes nothing: src
+    # [64] and rows [8] int32s, 8 rows of n bools
+    payload = 4 * 64 + 4 * 8 + 8 * n
+    assert g["volume_static_args_bytes_total"] == (
+        64 * n + 64 + 8 * 64 + 8 * n + payload)
     # the second pass of a session is a patch (state/volumecarry.py): its
     # own PV and claim parsed, the row of the pod bound last resolved
     g2, _ = c.one_pass()
@@ -278,7 +283,7 @@ def test_the_family_s_counters_read_the_pass(cluster):
     # ... and pv_node_ok stays on the device (state/resident.py): what
     # travels in its place is src [64] and rows [8] int32s, 8 fresh rows
     assert g2["volume_static_args_bytes_total"] == (
-        64 + 8 * 64 + 8 * n + 4 * 64 + 4 * 8 + 8 * n)
+        64 + 8 * 64 + 8 * n + payload)
 
 
 def test_argument_statics_are_the_family_s_and_travel_with_the_pass():

@@ -1,19 +1,27 @@
 """A pass's xs, carry and statics reach the device through one site
-(state/compile.py upload_tree, PR 35).
+(state/compile.py over state/packed.py pack_tree, PR 35), and stay as they
+were sent (PR 44).
 
 Every build hands numpy leaves; compile_workload reads the decoder's flags
 and the scan-cache key's digest off those host bytes and then uploads the
-trees once: one contiguous buffer per dtype, one jitted dispatch that
-slices them apart.  Held here: what reaches the device is, leaf for leaf,
-what a jnp.asarray of the same numpy leaf gives (bytes, shape, dtype,
-weak type), the scan-cache key does not see the route, nothing under
-compile_workload converts a device array to numpy or uploads a leaf on
-its own, a steady pass makes at most five transfers, and the workload's
-init_carry survives the donated scan.
+trees once: one contiguous buffer per dtype.  The sequential scan takes
+those buffers as they are and slices them apart inside its own executable
+(framework/replay.py _packed_scan_for); whoever asks the workload for
+leaves (a mesh, the speculative rounds, the host-interleaved path) gets
+them unpacked on first access by one jitted dispatch.  Held here: what
+reaches the device is, leaf for leaf, what a jnp.asarray of the same numpy
+leaf gives (bytes, shape, dtype, weak type), the scan-cache key does not
+see the route, nothing under compile_workload converts a device array to
+numpy or uploads a leaf on its own, a steady pass makes at most five
+transfers and a dozen dispatches, the packed route's result (a pass of one
+chunk) and a many-chunk pass's over the lazily unpacked leaves are
+byte-equal to a hand-held workload's, and no replay consumes the
+workload's packed carry.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import sys
 
 import jax
@@ -29,12 +37,12 @@ from kube_scheduler_simulator_tpu.models.workloads import (
 from kube_scheduler_simulator_tpu.plugins.custom import CustomPlugin
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.server.sessions import SessionManager
-from kube_scheduler_simulator_tpu.state.compile import (
-    compile_workload, upload_tree)
+from kube_scheduler_simulator_tpu.state.compile import compile_workload
+from kube_scheduler_simulator_tpu.state.packed import pack_tree, upload_tree
 from kube_scheduler_simulator_tpu.store.decode import decode_pod_result
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
-from test_scan_prepare import _FetchSpy
+from test_scan_prepare import _FetchSpy, _routes
 from test_volumes import node, pod, pv, pvc, sc
 
 compile_mod = sys.modules["kube_scheduler_simulator_tpu.state.compile"]
@@ -122,18 +130,32 @@ def _per_leaf(tree):
         if isinstance(leaf, (np.ndarray, np.generic)) else leaf, tree)
 
 
-def _compile_recording(monkeypatch, kwargs, route=upload_tree):
-    """compile_workload, with every tree handed to the upload site kept."""
+def _compile_recording(monkeypatch, kwargs):
+    """compile_workload, with every tree handed to the upload site kept:
+    the closure statics' (a fresh node table: its one generation is made
+    here), then the pass's own (xs, carry, argument statics, skip masks)."""
     handed = []
 
-    def recording(tree):
-        handed.append(tree)
-        return route(tree)
+    def recording(route):
+        def record(tree):
+            handed.append(tree)
+            return route(tree)
+        return record
 
     with monkeypatch.context() as patch:
-        patch.setattr(compile_mod, "upload_tree", recording)
+        patch.setattr(compile_mod, "upload_tree", recording(upload_tree))
+        patch.setattr(compile_mod, "pack_tree", recording(pack_tree))
         cw = compile_workload(**kwargs)
     return cw, handed
+
+
+def _as_leaves(cw, handed):
+    """The same workload as the parent held it: one jnp.asarray a numpy
+    leaf, nothing packed."""
+    statics, (xs, init_carry, arg_statics, _masks) = handed
+    return dataclasses.replace(
+        cw, xs=_per_leaf(xs), init_carry=_per_leaf(init_carry),
+        statics=_per_leaf({**statics, **arg_statics}))
 
 
 def _assert_same_leaf(got, want, where):
@@ -153,10 +175,10 @@ def _assert_same_leaf(got, want, where):
 
 def test_uploaded_trees_equal_the_per_leaf_route(monkeypatch, workload):
     cw, handed = _compile_recording(monkeypatch, workload)
-    # the closure statics' tree (a fresh node table: its one generation is
-    # made here), then the pass's own, the argument statics with it
     assert len(handed) == 2
-    statics, (xs, init_carry, arg_statics) = handed
+    statics, (xs, init_carry, arg_statics, _masks) = handed
+    assert cw.packed is not None and cw.__dict__["_xs"] is None, (
+        "compile_workload unpacked what it uploaded")
     assert set(arg_statics) <= set(compile_mod.ARG_STATICS)
     assert not set(statics) & set(compile_mod.ARG_STATICS)
     statics = {**statics, **arg_statics}
@@ -178,10 +200,14 @@ def test_uploaded_trees_equal_the_per_leaf_route(monkeypatch, workload):
 
 
 def test_scan_key_does_not_see_the_route(monkeypatch, workload):
-    cw, _ = _compile_recording(monkeypatch, workload)
-    old, _ = _compile_recording(monkeypatch, workload, route=_per_leaf)
-    assert _workload_scan_key(cw, 16) == _workload_scan_key(old, 16)
-    assert cw.host["_statics_fp"] == old.host["_statics_fp"]
+    cw, handed = _compile_recording(monkeypatch, workload)
+    old = _as_leaves(cw, handed)
+    assert old.packed is None
+    key = _workload_scan_key(cw, 16)
+    assert cw.__dict__["_xs"] is None, "the key unpacked the workload"
+    assert key == _workload_scan_key(old, 16)
+    cw.xs                                   # unpacked: the key stays
+    assert _workload_scan_key(cw, 16) == key
 
 
 def test_upload_tree_leaf_kinds():
@@ -306,22 +332,101 @@ def test_steady_pass_makes_at_most_five_transfers():
         mgr.shutdown()
 
 
+# ------------------------------- the packed route equals the leaves route
+
+
+def _result_bytes(rr) -> dict:
+    """Everything a ReplayResult holds of the scan, as bytes: the decision
+    rows, every compact tensor of every chunk (pad rows too), the
+    attribution sums that rode the fetch."""
+    cc = rr._compact
+    out = {name: np.asarray(getattr(rr, name)).tobytes()
+           for name in ("selected", "feasible_count", "prefilter_reject")}
+    for group in cc.GROUPS:
+        for ci, chunk in enumerate(getattr(cc, group)):
+            a = np.asarray(chunk)
+            out[f"{group}[{ci}]"] = (a.shape, str(a.dtype), a.tobytes())
+    for ci, att in enumerate(cc.att):
+        for name, a in sorted((att or {}).items()):
+            out[f"att[{ci}].{name}"] = (a.shape, str(a.dtype), a.tobytes())
+    return out
+
+
+def _both_routes(cw, **how):
+    """replay(cw) as compile_workload made it (over its packed buffers
+    where the pass is one chunk, else over leaves unpacked once), then
+    over the same workload held as leaves from the start -> the two
+    results' bytes."""
+    assert cw.packed is not None and cw.__dict__["_xs"] is None
+    one_chunk = cw.n_pods <= how["chunk"]
+    before = _routes()
+    made = replay(cw, **how)
+    after = _routes()
+    took = {r: after.get(r, 0) - before.get(r, 0) for r in ("packed", "leaves")}
+    if one_chunk:
+        assert cw.__dict__["_xs"] is None, "the packed route unpacked"
+        assert took["packed"] >= 1 and took["leaves"] == 0
+    else:
+        assert cw.__dict__["_xs"] is not None
+        assert took["leaves"] >= 1 and took["packed"] == 0
+    as_leaves = dataclasses.replace(cw)
+    assert as_leaves.packed is None
+    leaves = replay(as_leaves, **how)
+    assert _routes().get("leaves", 0) - after.get("leaves", 0) >= 1
+    assert _routes().get("packed", 0) == after.get("packed", 0)
+    return _result_bytes(made), _result_bytes(leaves)
+
+
+# (pods in the pass, chunk).  One chunk, the packed route: one pod; a whole
+# chunk of two; the fixture's five pods.  More chunks, where a packed
+# workload's leaves are unpacked once: a carried chunk and a last, short
+# one with a pad row; four carried chunks on the grid
+CHUNKINGS = {"p=1": (1, 4), "p=chunk": (2, 2), "p=5,chunk=512": (5, 512),
+             "p=chunk+1": (3, 2), "p=2chunk+3": (5, 1)}
+
+
+@pytest.mark.parametrize("chunking", list(CHUNKINGS))
+def test_packed_route_equals_the_leaves_route(workload, chunking):
+    p, chunk = CHUNKINGS[chunking]
+    assert len(workload["pods"]) >= p
+    cw = compile_workload(**{**workload, "pods": workload["pods"][:p]})
+    packed, leaves = _both_routes(cw, chunk=chunk, device_resident=True)
+    assert any(key.startswith("att[") for key in packed)
+    assert packed.keys() == leaves.keys()
+    for key in packed:
+        assert packed[key] == leaves[key], key
+
+
+def test_packed_route_equals_the_leaves_route_fetched_whole(workload):
+    """The host-resident rung: no attribution reduction in the executable,
+    every compact tensor fetched."""
+    cw = compile_workload(**workload)
+    packed, leaves = _both_routes(cw, chunk=512, device_resident=False)
+    assert not any(key.startswith("att[") for key in packed)
+    assert packed == leaves
+
+
 # -------------------------------------------------- the carry survives
 
 
-def test_replay_twice_on_one_uploaded_workload(workload):
-    """The unpack's outputs are the workload's own buffers: the donated
-    scan must take a copy of init_carry, not them."""
-    cw = compile_workload(**workload)
+@pytest.mark.parametrize("chunk", [512, 2])
+def test_replay_twice_on_one_uploaded_workload(monkeypatch, workload, chunk):
+    """The pass's buffers are the workload's own and nothing is donated
+    them: one chunk cuts its carry out of them inside the scan; chunks of
+    two read leaves unpacked from them once, and donate a copy."""
+    cw, (_, (xs, init_carry, _args, _masks)) = _compile_recording(
+        monkeypatch, workload)
     n = len(workload["pods"])
-    carry0 = [np.asarray(leaf).copy() for leaf in jax.tree.leaves(cw.init_carry)]
-    xs0 = [np.asarray(leaf).copy() for leaf in jax.tree.leaves(cw.xs)]
-    first = replay(cw, chunk=16, device_resident=True)
-    second = replay(cw, chunk=16, device_resident=True)
+    first = replay(cw, chunk=chunk, device_resident=True)
+    second = replay(cw, chunk=chunk, device_resident=True)
+    assert (cw.__dict__["_xs"] is None) == (n <= chunk)
     np.testing.assert_array_equal(first.selected, second.selected)
     assert ([decode_pod_result(first, i) for i in range(n)]
             == [decode_pod_result(second, i) for i in range(n)])
-    for leaf, want in zip(jax.tree.leaves(cw.init_carry), carry0):
-        np.testing.assert_array_equal(np.asarray(leaf), want)
-    for leaf, want in zip(jax.tree.leaves(cw.xs), xs0):
-        np.testing.assert_array_equal(np.asarray(leaf), want)
+    assert _result_bytes(first) == _result_bytes(second)
+    for buf in cw.packed.bufs.values():
+        assert not buf.is_deleted()
+    # ... and what they hold is what was handed to the upload
+    for got, want in ((cw.init_carry, init_carry), (cw.xs, xs)):
+        for leaf, host in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(leaf), host)
