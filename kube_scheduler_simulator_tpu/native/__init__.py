@@ -125,6 +125,7 @@ def _build_and_load():
         P(ctypes.c_uint8),
         ctypes.c_int32,
         P(ctypes.c_void_p), P(ctypes.c_int64),
+        ctypes.c_int64, P(ctypes.c_void_p), P(ctypes.c_int64),
     ]
     lib.ctx_decode_chunk.restype = ctypes.c_void_p
     lib.ctx_decode_chunk.argtypes = [
@@ -135,6 +136,8 @@ def _build_and_load():
         P(ctypes.c_void_p), P(ctypes.c_int64), P(ctypes.c_int32),
         P(ctypes.c_uint8), P(ctypes.c_uint8), P(ctypes.c_uint8),
         ctypes.c_int32,
+        P(ctypes.c_int64), P(ctypes.c_int64),
+        ctypes.c_int64, ctypes.c_int64,
         P(ctypes.c_int64), P(ctypes.c_int64),
         P(ctypes.c_double), P(ctypes.c_int64),
     ]
@@ -245,6 +248,15 @@ def peek_string_ascii(addr: int, length: int) -> str:
     if not _ASCII_TAKE_OK:
         return peek_string(addr, length)
     return _ascii_take(addr, length)
+
+
+def take_sized_bytes(lib, ptr, length: int) -> bytes:
+    """take_sized_string for a buffer that is wanted as bytes (a wire
+    form): one copy, and the buffer freed."""
+    try:
+        return ctypes.string_at(ptr, length)
+    finally:
+        lib.codec_free(ptr)
 
 
 def get_lib():

@@ -106,6 +106,41 @@ void append_quoted_int(std::string& out, long long v) {
     out.push_back('"');
 }
 
+// The WIRE FORM of a value: the bytes json.dumps(value) gives under
+// ensure_ascii, less the two surrounding quotes.  The value is a blob this
+// codec wrote, so pure ASCII (a context that is not all_ascii makes no
+// wire forms): every quote and backslash doubles, and what json.dumps
+// escapes besides (the controls and DEL) is spelt as Python spells it.
+void append_wire(std::string& out, const char* s, size_t len) {
+    for (size_t i = 0; i < len; ++i) {
+        unsigned char c = (unsigned char)s[i];
+        switch (c) {
+            case '"': out += "\\\""; break;
+            case '\\': out += "\\\\"; break;
+            case '\b': out += "\\b"; break;
+            case '\f': out += "\\f"; break;
+            case '\n': out += "\\n"; break;
+            case '\r': out += "\\r"; break;
+            case '\t': out += "\\t"; break;
+            default:
+                if (c < 0x20 || c == 0x7f) {
+                    char buf[8];
+                    snprintf(buf, sizeof buf, "\\u%04x", c);
+                    out += buf;
+                } else {
+                    out.push_back((char)c);
+                }
+        }
+    }
+}
+
+std::string wire_of(const std::string& s) {
+    std::string out;
+    out.reserve(s.size() + s.size() / 4);
+    append_wire(out, s.data(), s.size());
+    return out;
+}
+
 }  // namespace
 
 extern "C" {
@@ -304,10 +339,17 @@ struct Ctx {
     std::vector<std::string> filter_key;  // per filter pf: `"Name":`
     std::vector<std::string> score_key;   // per scorer q: `"Name":`
     std::vector<std::string> lut;         // escaped messages, quotes included
+    // the wire twins (wire_of) of the fragments an emit loop copies: a
+    // blob's wire form is assembled from them beside the blob, fragment
+    // for fragment, and never escaped as a whole
+    std::vector<std::string> node_key_w, lut_w;
     std::vector<int32_t> lut_off;
     std::vector<uint8_t> per_node;
     size_t max_msg = 0;                   // longest LUT message (reserve hint)
+    size_t max_msg_w = 0;
     size_t sum_node_key = 0;              // Σ node_key sizes (cap computation)
+    size_t sum_node_key_w = 0;
+    size_t max_node_key = 0;              // longest node key (a blob's length bound)
     // score finalization (the host mirror of framework/hostnorm.py):
     // kind 0 = passthrough, 1 = default, 2 = default-reverse,
     // 3 = PodTopologySpread, 4 = InterPodAffinity
@@ -338,6 +380,49 @@ inline void put(char*& w, const char* s, size_t len) {
     w += len;
 }
 
+// a blob's writer and, where w is set, its wire twin's
+struct Out2 {
+    char* a = nullptr;
+    char* w = nullptr;
+};
+inline void put2(Out2& o, const std::string& a, const std::string& w) {
+    put(o.a, a);
+    if (o.w) put(o.w, w);
+}
+// a byte json.dumps copies as it is: braces, commas, digits
+inline void putc2(Out2& o, char c) {
+    *o.a++ = c;
+    if (o.w) *o.w++ = c;
+}
+
+// one emitted blob; w is its wire form, quotes included, where one was
+// asked for and the blob came out at least wire_min_len long.  Both
+// buffers are malloc's, and the caller's to free
+struct Blob2 {
+    char* a = nullptr;
+    int64_t a_len = 0;
+    char* w = nullptr;
+    int64_t w_len = 0;
+};
+
+// close both writers; a twin shorter than the caller's threshold is freed
+void finish2(Out2& o, char* buf, char* wbuf, int64_t wire_min_len,
+             Blob2& out) {
+    *o.a = 0;
+    out.a = buf;
+    out.a_len = (int64_t)(o.a - buf);
+    out.w = nullptr;
+    out.w_len = 0;
+    if (!wbuf) return;
+    if (out.a_len < wire_min_len) {
+        std::free(wbuf);
+        return;
+    }
+    *o.w++ = '"';
+    out.w = wbuf;
+    out.w_len = (int64_t)(o.w - wbuf);
+}
+
 std::string escaped_key(const char* name) {
     std::string out;
     append_escaped(out, name);
@@ -355,10 +440,10 @@ namespace {
 // fragment construction and the emit loop are one implementation so the
 // byte contract cannot diverge between them.
 struct FilterFrags {
-    struct Frag { std::string head, tail; bool used = false; };
-    std::string all_pass;
+    struct Frag { std::string head, tail, head_w, tail_w; bool used = false; };
+    std::string all_pass, all_pass_w;
     std::vector<Frag> frag;
-    size_t max_frag = 0;
+    size_t max_frag = 0, max_frag_w = 0;
     bool any_active = false;
 };
 
@@ -383,6 +468,7 @@ struct FilterCache {
     // a SHARED (not per-node) message LUT: a failing node then emits as
     // key + ONE suffix memcpy instead of three puts
     std::vector<std::string> suffix;      // indexed lut_off[pf] + code-1
+    std::vector<std::string> suffix_w;    // their wire twins
     std::vector<uint8_t> suffix_ok;       // same indexing; 0 = per-node LUT
 };
 
@@ -428,11 +514,18 @@ void build_filter_frags(const Ctx& ctx, const uint8_t* active, FilterFrags& ff) 
             frst = false;
         }
         fr.tail.push_back('}');
+        fr.head_w = wire_of(fr.head);
+        fr.tail_w = wire_of(fr.tail);
     }
+    ff.all_pass_w = wire_of(ff.all_pass);
     ff.max_frag = ff.all_pass.size();
-    for (const FilterFrags::Frag& fr : ff.frag) if (fr.used)
+    ff.max_frag_w = ff.all_pass_w.size();
+    for (const FilterFrags::Frag& fr : ff.frag) if (fr.used) {
         ff.max_frag = std::max(ff.max_frag,
                                fr.head.size() + ctx.max_msg + fr.tail.size());
+        ff.max_frag_w = std::max(
+            ff.max_frag_w, fr.head_w.size() + ctx.max_msg_w + fr.tail_w.size());
+    }
 }
 
 // thread_local: ctx_decode_pod runs from a decode thread pool; each
@@ -475,6 +568,7 @@ const FilterCache& filter_cache_for(const Ctx& ctx, const uint8_t* active) {
         cache->cat.clear();
         cache->off.clear();
         cache->suffix.clear();
+        cache->suffix_w.clear();
         cache->suffix_ok.clear();
         return *cache;
     }
@@ -492,12 +586,14 @@ const FilterCache& filter_cache_for(const Ctx& ctx, const uint8_t* active) {
     }
     int32_t total = ctx.lut_off.empty() ? 0 : ctx.lut_off.back();
     cache->suffix.assign(total, {});
+    cache->suffix_w.assign(total, {});
     cache->suffix_ok.assign(total, 0);
     for (int32_t pf = 0; pf < ctx.f; ++pf) {
         if (!active[pf] || ctx.per_node[pf]) continue;
         const FilterFrags::Frag& fr = cache->ff.frag[pf];
         for (int32_t c = ctx.lut_off[pf]; c < ctx.lut_off[pf + 1]; ++c) {
             cache->suffix[c] = fr.head + ctx.lut[c] + fr.tail;
+            cache->suffix_w[c] = fr.head_w + ctx.lut_w[c] + fr.tail_w;
             cache->suffix_ok[c] = 1;
         }
     }
@@ -516,16 +612,27 @@ const FilterCache& filter_cache_for(const Ctx& ctx, const uint8_t* active) {
 // the small L1-resident fragments — so the per-node path is kept, with
 // the pre-rendered (plugin, code) suffix turning a failing node into
 // two memcpys.
-char* emit_filter_blob(const Ctx& ctx, const FilterCache& fc,
-                       const int32_t* fail_buf, const int32_t* code_buf,
-                       int32_t n_fail, int64_t* out_len) {
+// wire_min_len >= 0 asks for the blob's wire form beside it (out.w; see
+// finish2): every fragment the blob copies has its twin, so the twin is
+// the same walk's second memcpy and no pass over the finished blob.
+void emit_filter_blob(const Ctx& ctx, const FilterCache& fc,
+                      const int32_t* fail_buf, const int32_t* code_buf,
+                      int32_t n_fail, int64_t wire_min_len, Blob2& out) {
     const FilterFrags& ff = fc.ff;
     const int32_t n = ctx.n, f = ctx.f;
     size_t cap = 3 + (ff.any_active
         ? ctx.sum_node_key + (size_t)n * (1 + ff.max_frag) : 0);
+    Out2 o;
     char* buf = (char*)std::malloc(cap);
-    char* w = buf;
-    *w++ = '{';
+    o.a = buf;
+    char* wbuf = nullptr;
+    if (wire_min_len >= 0) {
+        wbuf = (char*)std::malloc(5 + (ff.any_active
+            ? ctx.sum_node_key_w + (size_t)n * (1 + ff.max_frag_w) : 0));
+        o.w = wbuf;
+        *o.w++ = '"';
+    }
+    putc2(o, '{');
     bool first_node = true;
     // mean all-pass run length >= ~128 nodes before the cat walk pays
     const bool use_runs = fc.valid && n_fail * 128 < n;
@@ -540,48 +647,53 @@ char* emit_filter_blob(const Ctx& ctx, const FilterCache& fc,
         if (fail_at == f && use_runs) {
             // maximal run of consecutive all-pass nodes -> one memcpy of
             // the cached ",node":{...passed...}" bytes (skip the leading
-            // comma at blob start)
+            // comma at blob start); the twin has no cat of its own and
+            // takes the run node by node from the small fragments
             int32_t run_end = si + 1;
             while (run_end < n && fail_buf[ctx.sorted_nodes[run_end]] == f)
                 ++run_end;
             const char* src = fc.cat.data() + fc.off[si];
             size_t len = fc.off[run_end] - fc.off[si];
-            if (first_node) { ++src; --len; first_node = false; }
-            put(w, src, len);
+            if (first_node) { ++src; --len; }
+            put(o.a, src, len);
+            if (o.w) {
+                for (int32_t k = si; k < run_end; ++k) {
+                    if (!(first_node && k == si)) *o.w++ = ',';
+                    put(o.w, ctx.node_key_w[ctx.sorted_nodes[k]]);
+                    put(o.w, ff.all_pass_w);
+                }
+            }
+            first_node = false;
             si = run_end;
             continue;
         }
-        if (!first_node) *w++ = ',';
+        if (!first_node) putc2(o, ',');
         first_node = false;
-        put(w, ctx.node_key[j]);
+        put2(o, ctx.node_key[j], ctx.node_key_w[j]);
         if (fail_at == f) {
-            put(w, ff.all_pass);
+            put2(o, ff.all_pass, ff.all_pass_w);
             ++si;
             continue;
         }
         int32_t base = ctx.lut_off[fail_at];
         int32_t code = code_buf[j];
         if (fc.valid && fc.suffix_ok[base + (code - 1)]) {
-            put(w, fc.suffix[base + (code - 1)]);
+            put2(o, fc.suffix[base + (code - 1)], fc.suffix_w[base + (code - 1)]);
             ++si;
             continue;
         }
         const FilterFrags::Frag& fr = ff.frag[fail_at];
-        put(w, fr.head);
+        put2(o, fr.head, fr.head_w);
         int32_t span = ctx.lut_off[fail_at + 1] - ctx.lut_off[fail_at];
-        if (ctx.per_node[fail_at]) {
-            int32_t stride = span / n;
-            put(w, ctx.lut[base + (size_t)j * stride + (code - 1)]);
-        } else {
-            put(w, ctx.lut[base + (code - 1)]);
-        }
-        put(w, fr.tail);
+        size_t at = ctx.per_node[fail_at]
+            ? base + (size_t)j * (span / n) + (code - 1)
+            : (size_t)base + (code - 1);
+        put2(o, ctx.lut[at], ctx.lut_w[at]);
+        put2(o, fr.tail, fr.tail_w);
         ++si;
     }
-    *w++ = '}';
-    *w = 0;
-    *out_len = (int64_t)(w - buf);
-    return buf;
+    putc2(o, '}');
+    finish2(o, buf, wbuf, wire_min_len, out);
 }
 
 }  // namespace
@@ -610,9 +722,14 @@ void* codec_ctx_new(
     ctx->sorted_filters.assign(sorted_filters, sorted_filters + f);
     ctx->sorted_scores.assign(sorted_scores, sorted_scores + s);
     ctx->node_key.reserve(n);
+    ctx->node_key_w.reserve(n);
     for (int32_t j = 0; j < n; ++j) {
         ctx->node_key.push_back(escaped_key(node_names[j]));
+        ctx->node_key_w.push_back(wire_of(ctx->node_key.back()));
         ctx->sum_node_key += ctx->node_key.back().size();
+        ctx->sum_node_key_w += ctx->node_key_w.back().size();
+        ctx->max_node_key = std::max(ctx->max_node_key,
+                                     ctx->node_key.back().size());
     }
     ctx->filter_key.reserve(f);
     for (int32_t pf = 0; pf < f; ++pf) ctx->filter_key.push_back(escaped_key(filter_names[pf]));
@@ -622,10 +739,13 @@ void* codec_ctx_new(
     ctx->per_node.assign(per_node, per_node + f);
     int32_t total = ctx->lut_off.empty() ? 0 : ctx->lut_off.back();
     ctx->lut.reserve(total);
+    ctx->lut_w.reserve(total);
     for (int32_t i = 0; i < total; ++i) {
         std::string m;
         append_escaped(m, lut_flat[i]);
         ctx->max_msg = std::max(ctx->max_msg, m.size());
+        ctx->lut_w.push_back(wire_of(m));
+        ctx->max_msg_w = std::max(ctx->max_msg_w, ctx->lut_w.back().size());
         ctx->lut.push_back(std::move(m));
     }
     ctx->score_kind.assign(score_kind, score_kind + s);
@@ -664,9 +784,11 @@ char* ctx_encode_filter(void* p, const int32_t* codes, const uint8_t* active,
         code_buf[j] = code;
         n_fail += (fail_at < f);
     }
-    return emit_filter_blob(ctx, filter_cache_for(ctx, active),
-                            fail_buf.data(), code_buf.data(), n_fail,
-                            out_len);
+    Blob2 blob;
+    emit_filter_blob(ctx, filter_cache_for(ctx, active), fail_buf.data(),
+                     code_buf.data(), n_fail, -1, blob);
+    *out_len = blob.a_len;
+    return blob.a;
 }
 
 // Fused per-pod decode from the COMPACT replay layout: reads the packed
@@ -685,6 +807,11 @@ char* ctx_encode_filter(void* p, const int32_t* codes, const uint8_t* active,
 //   want_scores: feasible_count > 1 (upstream skips scoring otherwise)
 //   out_blobs/out_lens: filter-result, score-result, finalscore-result;
 //               score slots are NULL when want_scores is 0
+//   wire_min_len / out_wire / out_wire_lens: a blob at least that long
+//               brings its wire form, json.dumps(blob)'s bytes (NULL
+//               where it is shorter, the threshold is negative or the
+//               context is not all ASCII): the caller's, to free with
+//               codec_free like the blobs
 namespace {
 
 inline int64_t floordiv(int64_t a, int64_t b) {
@@ -716,6 +843,10 @@ inline int64_t read_score(const void* col, int32_t elem, int32_t j) {
 // chunk, pods iterated by the worker pool).  Runs on any thread; all
 // scratch state is thread_local.  Returns the refusals it rendered into
 // the filter blob (nodes whose entry ends at a failure message).
+// out[0..2]: filter-result, score-result, finalscore-result (the score
+// slots stay empty when want_scores is 0).  wire_min_len >= 0: a blob
+// that comes out at least that long brings its wire form (Blob2.w); a
+// blob whose entries cannot add up to it is not given a twin to write.
 int32_t decode_one(
     const Ctx& ctx,
     const void* packed, int32_t pack_elem, int32_t code_bits,
@@ -724,9 +855,11 @@ int32_t decode_one(
     const void* const* score_cols, const int32_t* score_elem,
     const uint8_t* ignored,
     int32_t want_scores,
-    char** out_blobs, int64_t* out_lens) {
+    int64_t wire_min_len, Blob2* out) {
     const int32_t n = ctx.n, f = ctx.f, s = ctx.s;
     const uint64_t code_mask = (code_bits >= 64) ? ~0ull : ((1ull << code_bits) - 1);
+    // a twin is assembled from ASCII fragments' twins
+    if (!ctx.all_ascii) wire_min_len = -1;
 
     thread_local std::vector<uint8_t> feas_buf;
     thread_local std::vector<int32_t> fail_buf;   // first-fail exec idx, f = pass
@@ -736,11 +869,14 @@ int32_t decode_one(
     code_buf.resize(n);
 
     int32_t n_fail = 0;
+    int64_t n_entries = 0, n_feas = 0;
     for (int32_t j = 0; j < n; ++j) {
         uint64_t w = read_packed(packed, pack_elem, j);
         int32_t ffp = (int32_t)(w >> code_bits);
         int32_t code = (int32_t)(w & code_mask);
         feas_buf[j] = (ffp == 0);  // replay.py recon: feasible = ffp == 0
+        n_feas += (ffp == 0);
+        n_entries += (ffp <= f);
         if (ffp > f) {
             fail_buf[j] = f + 1;  // not evaluated (pipeline.py pack_filter_codes)
             code_buf[j] = 0;
@@ -754,11 +890,17 @@ int32_t decode_one(
         }
     }
 
-    out_blobs[0] = emit_filter_blob(ctx, filter_cache_for(ctx, active),
-                                    fail_buf.data(), code_buf.data(), n_fail,
-                                    &out_lens[0]);
-    out_blobs[1] = out_blobs[2] = nullptr;
-    out_lens[1] = out_lens[2] = 0;
+    const FilterCache& fc = filter_cache_for(ctx, active);
+    // the longest the blob can come out: its entries, each at its longest
+    auto reaches = [&](int64_t entries, size_t entry_max) {
+        return wire_min_len >= 0
+            && 3 + entries * (int64_t)(1 + ctx.max_node_key + entry_max)
+               >= wire_min_len;
+    };
+    emit_filter_blob(ctx, fc, fail_buf.data(), code_buf.data(), n_fail,
+                     reaches(n_entries, fc.ff.max_frag) ? wire_min_len : -1,
+                     out[0]);
+    out[1] = out[2] = Blob2();
     if (!want_scores) return n_fail;
 
     // ---- distinct-tuple pass (hostnorm mirrors) ------------------------
@@ -772,11 +914,12 @@ int32_t decode_one(
     // and emit = node key + two memcpys per node.  Byte-identical to the
     // per-node math (the 0 floors below replicate the per-node loops'
     // accumulator init values); measured ~3x on the score/final side.
-    std::vector<std::string> prefix;
+    std::vector<std::string> prefix, prefix_w;
     std::vector<int32_t> act;
     prefix.reserve(s);
+    prefix_w.reserve(s);
     act.reserve(s);
-    size_t row_fixed = 3;
+    size_t row_fixed = 3, row_fixed_w = 3;
     for (int32_t k = 0; k < s; ++k) {
         int32_t q = ctx.sorted_scores[k];
         if (sskip[q]) continue;
@@ -784,23 +927,41 @@ int32_t decode_one(
         pre += ctx.score_key[q];
         pre.push_back('"');
         row_fixed += pre.size() + 21;
+        prefix_w.push_back(wire_of(pre));
+        row_fixed_w += prefix_w.back().size() + 22;  // the closing quote's backslash
         prefix.push_back(std::move(pre));
         act.push_back(q);
     }
 
     size_t cap = 3 + (act.empty() ? 0 : ctx.sum_node_key + (size_t)n * (1 + row_fixed));
+    // score-result and finalscore-result share their keys and differ in
+    // digits: one bound decides for both twins
+    const bool twins = !act.empty() && reaches(n_feas, row_fixed);
+    Out2 so, fo;
     char* sbuf = (char*)std::malloc(cap);
     char* fbuf = (char*)std::malloc(cap);
-    char* sw = sbuf;
-    char* fw = fbuf;
-    *sw++ = '{';
-    *fw++ = '{';
+    so.a = sbuf;
+    fo.a = fbuf;
+    char* swbuf = nullptr;
+    char* fwbuf = nullptr;
+    if (twins) {
+        size_t cap_w = 5 + ctx.sum_node_key_w + (size_t)n * (1 + row_fixed_w);
+        swbuf = (char*)std::malloc(cap_w);
+        fwbuf = (char*)std::malloc(cap_w);
+        so.w = swbuf;
+        fo.w = fwbuf;
+        *so.w++ = '"';
+        *fo.w++ = '"';
+    }
+    putc2(so, '{');
+    putc2(fo, '{');
     bool first_node = true;
     if (!act.empty()) {
         const size_t kvals = act.size();
         struct Entry {
             uint64_t hash; uint32_t val_off;
             uint32_t s_off, s_len, f_off, f_len;
+            uint32_t sw_off, sw_len, fw_off, fw_len;  // the rows' twins
             uint8_t ig;
         };
         thread_local std::vector<Entry> entries;
@@ -808,11 +969,13 @@ int32_t decode_one(
         thread_local std::vector<int64_t> val_store;
         thread_local std::vector<int32_t> ent_of;  // node -> entry id (-1 infeasible)
         thread_local std::vector<int64_t> vals;
-        thread_local std::string scr_s, scr_f;
+        thread_local std::string scr_s, scr_f, scr_sw, scr_fw;
         entries.clear();
         val_store.clear();
         scr_s.clear();
         scr_f.clear();
+        scr_sw.clear();
+        scr_fw.clear();
         table.assign(256, 0);  // grows 4x at 1/2 load
         size_t tmask = table.size() - 1;
         ent_of.assign(n, -1);
@@ -913,6 +1076,8 @@ int32_t decode_one(
         for (Entry& e : entries) {
             e.s_off = (uint32_t)scr_s.size();
             e.f_off = (uint32_t)scr_f.size();
+            e.sw_off = (uint32_t)scr_sw.size();
+            e.fw_off = (uint32_t)scr_fw.size();
             for (size_t k = 0; k < kvals; ++k) {
                 int32_t q = act[k];
                 int64_t raw = val_store[e.val_off + k];
@@ -920,6 +1085,11 @@ int32_t decode_one(
                 auto rs = std::to_chars(num, num + 24, (long long)raw);
                 scr_s.append(num, rs.ptr - num);
                 scr_s.push_back('"');
+                if (twins) {
+                    scr_sw += prefix_w[k];
+                    scr_sw.append(num, rs.ptr - num);
+                    scr_sw += "\\\"";
+                }
 
                 int64_t normed;
                 const Red& r = red[k];
@@ -957,32 +1127,45 @@ int32_t decode_one(
                                         (long long)(normed * ctx.score_weight[q]));
                 scr_f.append(num, rf.ptr - num);
                 scr_f.push_back('"');
+                if (twins) {
+                    scr_fw += prefix_w[k];
+                    scr_fw.append(num, rf.ptr - num);
+                    scr_fw += "\\\"";
+                }
             }
             scr_s.push_back('}');
             scr_f.push_back('}');
             e.s_len = (uint32_t)(scr_s.size() - e.s_off);
             e.f_len = (uint32_t)(scr_f.size() - e.f_off);
+            if (twins) {
+                scr_sw.push_back('}');
+                scr_fw.push_back('}');
+                e.sw_len = (uint32_t)(scr_sw.size() - e.sw_off);
+                e.fw_len = (uint32_t)(scr_fw.size() - e.fw_off);
+            }
         }
 
         // pass 4: emit = node key + two row-suffix memcpys per node
         for (int32_t si = 0; si < n; ++si) {
             int32_t j = ctx.sorted_nodes[si];
             if (ent_of[j] < 0) continue;
-            if (!first_node) { *sw++ = ','; *fw++ = ','; }
+            if (!first_node) { putc2(so, ','); putc2(fo, ','); }
             first_node = false;
-            put(sw, ctx.node_key[j]);
-            put(fw, ctx.node_key[j]);
+            put2(so, ctx.node_key[j], ctx.node_key_w[j]);
+            put2(fo, ctx.node_key[j], ctx.node_key_w[j]);
             const Entry& e = entries[ent_of[j]];
-            put(sw, scr_s.data() + e.s_off, e.s_len);
-            put(fw, scr_f.data() + e.f_off, e.f_len);
+            put(so.a, scr_s.data() + e.s_off, e.s_len);
+            put(fo.a, scr_f.data() + e.f_off, e.f_len);
+            if (twins) {
+                put(so.w, scr_sw.data() + e.sw_off, e.sw_len);
+                put(fo.w, scr_fw.data() + e.fw_off, e.fw_len);
+            }
         }
     }
-    *sw++ = '}'; *sw = 0;
-    *fw++ = '}'; *fw = 0;
-    out_blobs[1] = sbuf;
-    out_lens[1] = (int64_t)(sw - sbuf);
-    out_blobs[2] = fbuf;
-    out_lens[2] = (int64_t)(fw - fbuf);
+    putc2(so, '}');
+    putc2(fo, '}');
+    finish2(so, sbuf, swbuf, wire_min_len, out[1]);
+    finish2(fo, fbuf, fwbuf, wire_min_len, out[2]);
     return n_fail;
 }
 
@@ -1080,10 +1263,19 @@ int32_t ctx_decode_pod(
     const void* const* score_cols, const int32_t* score_elem,
     const uint8_t* ignored,
     int32_t want_scores,
-    char** out_blobs, int64_t* out_lens) {
-    return decode_one(*(const Ctx*)p, packed, pack_elem, code_bits, active,
-                      sskip, score_cols, score_elem, ignored, want_scores,
-                      out_blobs, out_lens);
+    char** out_blobs, int64_t* out_lens,
+    int64_t wire_min_len, char** out_wire, int64_t* out_wire_lens) {
+    Blob2 blobs[3];
+    int32_t failed = decode_one(*(const Ctx*)p, packed, pack_elem, code_bits,
+                                active, sskip, score_cols, score_elem, ignored,
+                                want_scores, wire_min_len, blobs);
+    for (int b = 0; b < 3; ++b) {
+        out_blobs[b] = blobs[b].a;
+        out_lens[b] = blobs[b].a_len;
+        out_wire[b] = blobs[b].w;
+        out_wire_lens[b] = blobs[b].w_len;
+    }
+    return failed;
 }
 
 // One call per replay chunk; the GIL is released for the whole call.
@@ -1102,6 +1294,12 @@ int32_t ctx_decode_pod(
 //   n_threads:    workers incl. the caller (clamped to [1, 16])
 //   out_ptrs/out_lens: [c*3] blob addresses/lengths (0 = absent); valid
 //                 until chunk_arena_free of the returned arena
+//   wire_min_len: a blob at least this long brings its wire form (< 0:
+//                 none is made); wire_budget: blob + wire bytes the call
+//                 may hand out as wire forms: what the caller keeps is
+//                 bounded, and a pod past the budget brings none
+//   out_wptrs/out_wlens: [c*3] wire-form addresses and lengths (0 =
+//                 none); the arena's, valid as long as the blobs
 //   thread_seconds: out, summed worker busy time (tracer counter)
 //   failed_entries: out, refusals rendered into the range's filter blobs
 void* ctx_decode_chunk(
@@ -1119,6 +1317,10 @@ void* ctx_decode_chunk(
     int32_t n_threads,
     int64_t* out_ptrs,
     int64_t* out_lens,
+    int64_t wire_min_len,
+    int64_t wire_budget,
+    int64_t* out_wptrs,
+    int64_t* out_wlens,
     double* thread_seconds,
     int64_t* failed_entries) {
     const Ctx& ctx = *(const Ctx*)p;
@@ -1127,6 +1329,8 @@ void* ctx_decode_chunk(
     arena->blobs.reserve((size_t)c * 3);
     std::memset(out_ptrs, 0, (size_t)c * 3 * sizeof(int64_t));
     std::memset(out_lens, 0, (size_t)c * 3 * sizeof(int64_t));
+    std::memset(out_wptrs, 0, (size_t)c * 3 * sizeof(int64_t));
+    std::memset(out_wlens, 0, (size_t)c * 3 * sizeof(int64_t));
 
     if (n_threads < 1) n_threads = 1;
     if (n_threads > 16) n_threads = 16;
@@ -1135,6 +1339,7 @@ void* ctx_decode_chunk(
     std::atomic<int32_t> next{0};
     std::atomic<long long> busy_ns{0};
     std::atomic<long long> failed{0};
+    std::atomic<long long> budget{wire_budget};
     std::mutex merge_m;
 
     auto work = [&](int) {
@@ -1149,8 +1354,7 @@ void* ctx_decode_chunk(
                 cols[q] = col_base[q]
                     ? (const char*)col_base[q] + (int64_t)i * col_stride[q]
                     : nullptr;
-            char* blobs[3];
-            int64_t lens[3];
+            Blob2 blobs[3];
             failed += decode_one(ctx,
                        (const char*)packed + (size_t)i * n * pack_elem,
                        pack_elem, code_bits,
@@ -1159,17 +1363,26 @@ void* ctx_decode_chunk(
                        cols.data(), col_elem,
                        ignored ? ignored + (size_t)i * n : nullptr,
                        want_scores[i] ? 1 : 0,
-                       blobs, lens);
+                       budget.load(std::memory_order_relaxed) > 0
+                           ? wire_min_len : -1,
+                       blobs);
             for (int b = 0; b < 3; ++b) {
-                if (!blobs[b]) continue;
+                if (!blobs[b].a) continue;
                 // emit caps are upper bounds (21 bytes per numeric
                 // field); trim so the arena holds ~actual blob bytes
                 // for the whole chunk, not the slack
-                char* t = (char*)std::realloc(blobs[b], (size_t)lens[b] + 1);
-                if (t) blobs[b] = t;
-                local.push_back(blobs[b]);
-                out_ptrs[(size_t)i * 3 + b] = (int64_t)(intptr_t)blobs[b];
-                out_lens[(size_t)i * 3 + b] = lens[b];
+                char* t = (char*)std::realloc(blobs[b].a,
+                                              (size_t)blobs[b].a_len + 1);
+                if (t) blobs[b].a = t;
+                local.push_back(blobs[b].a);
+                out_ptrs[(size_t)i * 3 + b] = (int64_t)(intptr_t)blobs[b].a;
+                out_lens[(size_t)i * 3 + b] = blobs[b].a_len;
+                if (!blobs[b].w) continue;
+                budget.fetch_sub(blobs[b].a_len + blobs[b].w_len,
+                                 std::memory_order_relaxed);
+                local.push_back(blobs[b].w);
+                out_wptrs[(size_t)i * 3 + b] = (int64_t)(intptr_t)blobs[b].w;
+                out_wlens[(size_t)i * 3 + b] = blobs[b].w_len;
             }
         }
         busy_ns.fetch_add(std::chrono::duration_cast<std::chrono::nanoseconds>(

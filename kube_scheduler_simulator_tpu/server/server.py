@@ -68,6 +68,7 @@ from urllib.parse import parse_qs, urlparse
 from ..cluster.store import ApiError
 from ..services.resourcewatcher import StreamWriter, WATCH_PARAMS
 from ..services.snapshot import SnapshotOptions
+from ..utils import wireform
 from ..utils.tracing import TRACER
 from .di import DIContainer
 from .sessions import SessionManager, StreamRegistry
@@ -178,14 +179,21 @@ def _make_handler(server: SimulatorServer):
 
         def _json(self, code: int, obj=None, headers=None):
             # two children of whichever request span is open: a full pod
-            # read is megabytes through both (docs/metrics.md)
+            # read is megabytes through both (docs/metrics.md).  The
+            # body is json.dumps(obj).encode(), in pieces where obj
+            # carries values whose escaped bytes are kept
+            # (utils/wireform.py): they go to the socket one after
+            # another, not through a concatenation
             with TRACER.span("http_encode"):
-                body = b"" if obj is None else json.dumps(obj).encode()
+                body = (() if obj is None
+                        else wireform.body_parts(obj, "read")
+                        or (json.dumps(obj).encode(),))
             with TRACER.span("http_send"):
                 self.send_response(code)
                 self._cors()
                 self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(body)))
+                self.send_header("Content-Length",
+                                 str(sum(map(len, body))))
                 for k, v in (headers or {}).items():
                     self.send_header(k, str(v))
                 # echo the request's trace id (minted or client-supplied)
@@ -194,8 +202,8 @@ def _make_handler(server: SimulatorServer):
                 if tid:
                     self.send_header("X-KSS-Trace-Id", tid)
                 self.end_headers()
-                if body:
-                    self.wfile.write(body)
+                for part in body:
+                    self.wfile.write(part)
 
         def _body(self):
             length = int(self.headers.get("Content-Length") or 0)

@@ -19,6 +19,7 @@ import time
 from itertools import islice
 
 from ..cluster.store import ObjectStore, RESOURCES, ADDED, DEFAULT_GVRS
+from ..utils import wireform
 from ..utils.tracing import TRACER
 
 # wire protocol: per-kind *LastResourceVersion query params a client passes
@@ -189,8 +190,18 @@ class StreamWriter:
                 fill = getattr(obj, "fill", None)
                 if fill is not None:
                     fill()
-                data = json.dumps({"kind": kind, "eventType": event_type,
-                                   "obj": obj}).encode()
+                # the same bytes either way: where obj carries values
+                # whose escaped bytes are kept (utils/wireform.py) they
+                # are spliced into the event, not escaped again
+                parts = wireform.body_parts(obj, "watch")
+                if parts is None:
+                    data = json.dumps({"kind": kind, "eventType": event_type,
+                                       "obj": obj}).encode()
+                else:
+                    head = json.dumps({"kind": kind,
+                                       "eventType": event_type})[:-1]
+                    data = b"".join(
+                        [head.encode(), b', "obj": ', *parts, b"}"])
             with TRACER.span("watch_send"), self._lock:
                 try:
                     self._write(data)

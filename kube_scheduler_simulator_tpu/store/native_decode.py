@@ -18,8 +18,8 @@ import ctypes
 import numpy as np
 
 from ..native import (
-    get_lib, peek_string, peek_string_ascii, take_sized_string,
-    take_sized_string_ascii,
+    get_lib, peek_string, peek_string_ascii, take_sized_bytes,
+    take_sized_string, take_sized_string_ascii,
 )
 from ..plugins import (
     affinity, interpod, nodevolumelimits, ports, taints, topologyspread,
@@ -27,6 +27,7 @@ from ..plugins import (
 )
 from ..plugins.noderesources import decode_fit_filter
 from ..utils.tracing import TRACER
+from ..utils import wireform
 
 _MAX_FIT_LUT_BITS = 16
 
@@ -306,15 +307,20 @@ class _ChunkHandle:
     into strs and frees the arena; dropping it without take leaks the
     arena (callers always pair the two)."""
 
-    __slots__ = ("ctx", "arena", "out_ptrs", "out_lens", "skip", "c",
-                 "thread_seconds", "failed_entries", "_keep")
+    __slots__ = ("ctx", "arena", "out_ptrs", "out_lens", "out_wptrs",
+                 "out_wlens", "skip", "c", "thread_seconds",
+                 "failed_entries", "_keep")
 
-    def __init__(self, ctx, arena, out_ptrs, out_lens, skip, c,
-                 thread_seconds, failed_entries, keep):
+    def __init__(self, ctx, arena, out_ptrs, out_lens, out_wptrs, out_wlens,
+                 skip, c, thread_seconds, failed_entries, keep):
         self.ctx = ctx
         self.arena = arena
         self.out_ptrs = out_ptrs
         self.out_lens = out_lens
+        # slot (3 * pod + blob) -> the heavy blob's wire form, in the
+        # arena like the blob (0 = none)
+        self.out_wptrs = out_wptrs
+        self.out_wlens = out_wlens
         self.skip = skip
         self.c = c
         self.thread_seconds = thread_seconds
@@ -407,6 +413,8 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
 
     out_ptrs = np.zeros(c * 3, np.int64)
     out_lens = np.zeros(c * 3, np.int64)
+    out_wptrs = np.zeros(c * 3, np.int64)
+    out_wlens = np.zeros(c * 3, np.int64)
     tsec = ctypes.c_double()
     failed = ctypes.c_int64()
     if n_threads is None:
@@ -420,20 +428,29 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
         col_base, col_stride, col_elem,
         ig_ptr, _u8p(want), _u8p(skip) if skip is not None else None,
         n_threads,
-        _i64p(out_ptrs), _i64p(out_lens), ctypes.byref(tsec),
-        ctypes.byref(failed))
-    return _ChunkHandle(ctx, arena, out_ptrs, out_lens, skip, c,
-                        float(tsec.value), int(failed.value), keep_alive)
+        _i64p(out_ptrs), _i64p(out_lens),
+        # a call hands out at most what the registry may hold: the forms
+        # of a 512-pod chunk would push each other out unread
+        wireform.WIRE_MIN_LEN, wireform.WIRE_CAP_BYTES,
+        _i64p(out_wptrs), _i64p(out_wlens),
+        ctypes.byref(tsec), ctypes.byref(failed))
+    return _ChunkHandle(ctx, arena, out_ptrs, out_lens, out_wptrs, out_wlens,
+                        skip, c, float(tsec.value), int(failed.value),
+                        keep_alive)
 
 
 def decode_chunk_take(handle: _ChunkHandle) -> list:
     """Blob strs from a decode_chunk_start handle; frees the arena.
     triples[i] is (filter_json, score_json | None, finalscore_json |
-    None), or None where the skip mask was set."""
+    None), or None where the skip mask was set.  A heavy blob's wire
+    form is kept under the str's identity (utils/wireform.py)."""
     ctx = handle.ctx
     peek = ctx.peek
     skip = handle.skip
     out_ptrs, out_lens = handle.out_ptrs, handle.out_lens
+    out_wptrs, out_wlens = handle.out_wptrs, handle.out_wlens
+    any_wire = bool(out_wptrs.any())
+    wired: list = []
     try:
         triples: list = []
         for i in range(handle.c):
@@ -447,8 +464,15 @@ def decode_chunk_take(handle: _ChunkHandle) -> list:
             fnj = (peek(int(out_ptrs[b + 2]), int(out_lens[b + 2]))
                    if out_ptrs[b + 2] else None)
             triples.append((fj, sj, fnj))
+            if any_wire:
+                wired.append([
+                    (blob, ctypes.string_at(int(out_wptrs[b + k]),
+                                            int(out_wlens[b + k])))
+                    for k, blob in enumerate((fj, sj, fnj))
+                    if out_wptrs[b + k]])
     finally:
         handle.discard()
+    wireform.keep_native(wired)
     return triples
 
 
@@ -510,20 +534,22 @@ def decode_pod_fused(ctx: _NativeCtx, rr, i: int, hi: int,
     active_rows, sskip_rows = pass_rows(rr.cw)
     out_blobs = (ctypes.c_void_p * 3)()
     out_lens = (ctypes.c_int64 * 3)()
+    out_wire = (ctypes.c_void_p * 3)()
+    out_wire_lens = (ctypes.c_int64 * 3)()
     failed_entries = ctx.lib.ctx_decode_pod(
         ctx.ptr,
         prow.ctypes.data_as(ctypes.c_void_p), packed.dtype.itemsize, code_bits,
         _u8p(active_rows[hi]), _u8p(sskip_rows[hi]),
         col_ptrs, col_elem, ignored_ptr, 1 if want_scores else 0,
         out_blobs, out_lens,
+        wireform.WIRE_MIN_LEN, out_wire, out_wire_lens,
     )
-    filter_json = ctx.take(ctx.lib, out_blobs[0], out_lens[0])
-    score_json = final_json = None
-    if out_blobs[1]:
-        score_json = ctx.take(ctx.lib, out_blobs[1], out_lens[1])
-    if out_blobs[2]:
-        final_json = ctx.take(ctx.lib, out_blobs[2], out_lens[2])
-    return filter_json, score_json, final_json, failed_entries
+    wired = [(k, take_sized_bytes(ctx.lib, out_wire[k], out_wire_lens[k]))
+             for k in range(3) if out_wire[k]]
+    blobs = [ctx.take(ctx.lib, out_blobs[k], out_lens[k])
+             if out_blobs[k] else None for k in range(3)]
+    wireform.keep_native([[(blobs[k], wire) for k, wire in wired]])
+    return blobs[0], blobs[1], blobs[2], failed_entries
 
 
 def encode_string_map(d: dict[str, str]) -> str | None:

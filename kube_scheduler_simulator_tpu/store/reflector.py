@@ -26,6 +26,7 @@ from . import annotations as ann
 from ..cluster.store import Conflict, NotFound, ObjectStore
 from ..utils.faults import fault_point
 from ..utils.retry import retry_with_exponential_backoff
+from ..utils import wireform
 from ..utils.tracing import TRACER
 
 RESULT_HISTORY_LIMIT = ann.TOTAL_ANNOTATION_SIZE_LIMIT
@@ -347,10 +348,18 @@ class LazyReflections:
         store lock — the mutate callbacks only merge and splice (the
         PR 2 off-lock rule, same as reflect_batch's prepare phase)."""
         prepared = []
+        # a reader's drain (the watch stream's periodic one) is followed
+        # by the pods' events: their heavy values get the wire forms they
+        # came without, as in _apply; a listing's or an export's flush of
+        # a whole keyspace stops at what the registry can hold
+        wire_budget = wireform.WIRE_CAP_BYTES
         for key, recs in taken:
             sets = []
             for rec in recs:
                 result_set = rec.result_set()
+                if wire_budget > 0:
+                    wire_budget -= wireform.make_missing(
+                        result_set.values(), wire_budget)
                 hist_rec = None
                 skip_history = False
                 try:
@@ -397,7 +406,14 @@ class LazyReflections:
     def _apply(self, key: tuple[str, str], recs: list[_PendingRecord]) -> None:
         """reflect()'s per-pod semantics for a queue of deferred
         records: uid guard per record, annotation merge + history
-        append in record order, ONE conflict-retried update."""
+        append in record order, ONE conflict-retried update.
+
+        The records materialize first (`decode_lazy`, where a chunk is
+        cold); what follows is the span `reflect_write_back`, a sibling
+        of the decode under whichever reader drains (`http_pod_read`,
+        `watch_flush`): the merge, the history, the wire forms of the
+        heavy values that came without one (utils/wireform.py) and the
+        store's update, whose event wakes the watch's pump."""
         namespace, name = key
 
         def attempt() -> tuple[bool, Exception | None]:
@@ -415,27 +431,31 @@ class LazyReflections:
                     if not (r.uid and cur_uid not in (None, r.uid))]
             if not live:
                 return True, None
-            pod = dict(cur)
-            meta = dict(cur.get("metadata") or {})
-            annotations = dict(meta.get("annotations") or {})
-            meta["annotations"] = annotations
-            pod["metadata"] = meta
-            for rec in live:
-                result_set = rec.result_set()
-                annotations.update(result_set)
-                try:
-                    update_result_history(pod, result_set)
-                except ValueError as e:
-                    import sys
+            result_sets = [rec.result_set() for rec in live]
+            with TRACER.span("reflect_write_back"):
+                pod = dict(cur)
+                meta = dict(cur.get("metadata") or {})
+                annotations = dict(meta.get("annotations") or {})
+                meta["annotations"] = annotations
+                pod["metadata"] = meta
+                for result_set in result_sets:
+                    annotations.update(result_set)
+                    try:
+                        update_result_history(pod, result_set)
+                    except ValueError as e:
+                        import sys
 
-                    print(f"reflector: result-history not updated: {e}",
-                          file=sys.stderr)
-            try:
-                self.store.update("pods", pod, owned=True)
-            except NotFound:
-                return True, None
-            except Conflict:
-                return False, None  # re-fetch and retry
+                        print(f"reflector: result-history not updated: {e}",
+                              file=sys.stderr)
+                # this pod is being read: its GET and its reflect event
+                # splice what is kept here and by the decoder
+                wireform.make_missing(annotations.values())
+                try:
+                    self.store.update("pods", pod, owned=True)
+                except NotFound:
+                    return True, None
+                except Conflict:
+                    return False, None  # re-fetch and retry
             return True, None
 
         retry_with_exponential_backoff(attempt, stop=self.stop)

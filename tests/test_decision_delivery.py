@@ -328,6 +328,48 @@ def test_a_decode_for_the_watch_sits_under_watch_flush(served):
                for d in decodes if d["parent_id"] in by_id)
 
 
+def test_the_write_back_is_a_span_beside_the_decode(served):
+    """The stretch between the decode's end and the encode's start has a
+    span of its own (PR 45): `reflect_write_back`, a sibling of
+    `decode_lazy` under whichever reader drained the pod's record, on
+    that reader's thread, after the decode and before its parent's
+    encode."""
+    _, base, port = served
+    seen = {e["span_id"] for e in TRACER.events(4096)}
+    with Watch(port) as w:
+        for i in range(4):
+            _cycle(base, w, _pod(f"wb-{i}"), trace_id=f"t-way-out-wb-{i}")
+    evs = [e for e in TRACER.events(4096) if e["span_id"] not in seen]
+    by_id = {e["span_id"]: e for e in evs}
+    backs = [e for e in evs if e["name"] == "reflect_write_back"]
+    # (a record the stream's four-a-second drain took went through the
+    # batched write and has no such span)
+    assert backs, "no decided pod's read wrote anything back"
+    under = set()
+    for b in backs:
+        parent = by_id.get(b["parent_id"])
+        if parent is None:  # the stream's four-a-second drain: no span
+            continue
+        under.add(parent["name"])
+        assert parent["name"] in ("watch_flush", "http_pod_read"), (b, parent)
+        assert b["tid"] == parent["tid"]
+        kids = [e for e in evs if e["parent_id"] == parent["span_id"]]
+        for d in kids:
+            if d["name"] == "decode_lazy":
+                assert d["ts"] + d["seconds"] <= b["ts"] + 1e-4, (d, b)
+            if d["name"] in ("http_encode", "watch_encode"):
+                assert b["ts"] + b["seconds"] <= d["ts"] + 1e-4, (b, d)
+    assert under, "no write-back ran under a reader's span"
+    # the four children of the read and of the pump's write are where
+    # they were
+    for child, parent in (("http_encode", "http_pod_read"),
+                          ("http_send", "http_pod_read"),
+                          ("watch_encode", "watch_write"),
+                          ("watch_send", "watch_write")):
+        assert parent in {by_id[e["parent_id"]]["name"] for e in evs
+                          if e["name"] == child and e["parent_id"] in by_id}
+
+
 # ------------------------------------------------- the map, on its own
 
 def _bound(name, node="n0"):

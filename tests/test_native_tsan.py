@@ -32,6 +32,9 @@ _SUITE = [
     # one context, two passes, four threads: the C side only reads it
     "tests/test_codec_ctx_carry.py"
     "::test_two_threads_decoding_two_passes_at_once_give_the_serial_bytes",
+    # a read and a pump racing on one pod, four pods at once: the arena
+    # holds the wire forms beside the blobs (PR 45)
+    "tests/test_wire_form.py::test_racing_read_and_pump_soak",
 ]
 
 _SUPPRESSIONS = os.path.join(

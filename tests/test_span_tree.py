@@ -353,9 +353,12 @@ def test_the_served_tree_past_the_commit(live_server, tmp_path):
         last_in = {"http_pod_read", "wave", "decision_delivery",
                    "decision_to_read"}
         deadline = time.time() + 10
-        while not last_in <= {e["name"] for e in TRACER.events(4096)
-                              if e["span_id"] not in seen}:
-            assert time.time() < deadline
+        while True:
+            missing = last_in - {e["name"] for e in TRACER.events(4096)
+                                 if e["span_id"] not in seen}
+            if not missing:
+                break
+            assert time.time() < deadline, f"never recorded: {missing}"
             time.sleep(0.01)
     finally:
         TRACER.stop_xla_profile()
@@ -372,6 +375,12 @@ def test_the_served_tree_past_the_commit(live_server, tmp_path):
     assert parents("watch_flush") == {None}  # a root on the pump's thread
     assert {"http_pod_create", "http_pod_read"} <= parents("http_encode")
     assert parents("http_encode") == parents("http_send")
+    # the reflector's write-back (PR 45): under whichever reader drained
+    # the pod's record, the pump ahead of an event or the GET's handler
+    # (none where the stream's four-a-second drain took this one pod's
+    # record first: its batched write has no span, and
+    # tests/test_decision_delivery.py holds that a reader's has)
+    assert parents("reflect_write_back") <= {"watch_flush", "http_pod_read"}
     # the two retroactive spans: one each, roots, the wave's trace id
     wave = [e for e in evs if e["name"] == "wave"][-1]
     for retro in ("decision_delivery", "decision_to_read"):
