@@ -109,7 +109,7 @@ class _SessState:
     """Controller-internal per-session memory (streaks, baselines)."""
 
     __slots__ = ("spec_mode", "hi_streak", "lo_streak", "mid_streak",
-                 "accepted", "rolled", "spilled", "calm_ticks",
+                 "accepted", "rolled", "rounds", "spilled", "calm_ticks",
                  "breach_streak", "ok_streak", "waves_total")
 
     def __init__(self):
@@ -119,6 +119,7 @@ class _SessState:
         self.mid_streak = 0
         self.accepted = 0.0    # counter baselines from the previous tick
         self.rolled = 0.0
+        self.rounds = 0.0
         self.spilled = 0.0
         self.calm_ticks = 0
         self.breach_streak = 0
@@ -254,7 +255,7 @@ class Autopilot:
                 if hist_idx >= 0:
                     evd["historyIndex"] = hist_idx
                 self._plan_speculative(plan, sid, st, accepted, rolled,
-                                       evd)
+                                       planes["rounds"], evd)
                 spill_d = spilled.get(sid, 0.0) - st.spilled
                 st.spilled = spilled.get(sid, 0.0)
                 if limit is not None and limit > 0:
@@ -277,14 +278,22 @@ class Autopilot:
 
     # ------------------------------------------------- effector: spec
 
-    def _plan_speculative(self, plan, sid, st, accepted, rolled,
+    def _plan_speculative(self, plan, sid, st, accepted, rolled, rounds,
                           evd) -> None:
         a_d = accepted.get(sid, 0.0) - st.accepted
         r_d = rolled.get(sid, 0.0) - st.rolled
+        n_d = rounds.get(sid, 0.0) - st.rounds
         st.accepted = accepted.get(sid, 0.0)
         st.rolled = rolled.get(sid, 0.0)
+        st.rounds = rounds.get(sid, 0.0)
+        # a round's first pod is accepted whatever the contention
+        # (parallel/speculative.py): it is no evidence.  A session served
+        # one pod a pass read 1.00 for ever, went aggressive on nothing and
+        # paid an 11-12 s compile of the wider sparse round mid-session
+        # (my chip run, PR 46: pass ~26 of baseline_c3_1k.interactive_profile)
+        a_d = max(a_d - n_d, 0.0)
         if a_d + r_d <= 0:
-            return   # no rounds since the last tick: no evidence
+            return   # no contested pod since the last tick: no evidence
         frac = a_d / (a_d + r_d)
         if frac >= _SPEC_HI:
             st.hi_streak += 1
@@ -297,7 +306,7 @@ class Autopilot:
             st.mid_streak += 1
         want = st.spec_mode
         reason = (f"accept fraction {frac:.2f} over "
-                  f"{int(a_d + r_d)} round(s)")
+                  f"{int(a_d + r_d)} contested pod(s)")
         if st.hi_streak >= HYSTERESIS_TICKS:
             want = "aggressive"
         elif st.lo_streak >= HYSTERESIS_TICKS:

@@ -319,6 +319,17 @@ class _WaveCommitter:
                 w._attr_acc = ChunkAttribution(rr)
                 self._waves.append(w)
             wave = self._waves[-1]
+            if hi >= len(self.pending):
+                # the wave's last chunk: chunks arrive in ascending order
+                # and only without the overflow flag, so this run's
+                # tensors are whole and no rerun follows it.  Sealed here,
+                # before the worker binds the chunk's pods, a reader that
+                # follows the bind event finds the deferred record ready;
+                # sealed at finish() only, it found a bound pod without
+                # annotations and asked again (a pass of one pod over
+                # HTTP: 161 of 188 reads on the CPU rehearsal of
+                # baseline_c3_1k.interactive_profile)
+                wave.seal()
         else:
             # the WHOLE chunk goes down in one call: decode_chunk_into
             # routes it through the chunk-granular native decode (one

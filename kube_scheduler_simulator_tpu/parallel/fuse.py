@@ -17,7 +17,8 @@ process-level compile-cache registry (framework/replay._SCAN_CACHE)
 keyed by statics CONTENT fingerprint + xs/carry shape signature +
 plugin-config signature + chunk (+ rung, width tier, candidate cap).
 Two streams that resolve the same key hold the SAME jitted callable —
-the only per-session state entering the call is (carry, xs).  Stacking
+the only per-session state entering the call is (carry, xs, the
+argument statics).  Stacking
 those pytrees and running `jax.jit(jax.vmap(solo_fn))` evaluates the
 identical integer program per row, so every session's outputs — and
 therefore its annotations, bind order and result history — are
@@ -214,7 +215,7 @@ class FuseCoordinator:
     def dispatch(self, stream: _Stream, key, solo_fn, args):
         """Run one round's device call, fused with whatever
         shape-compatible batch-mates arrive inside the window.  `args`
-        is the solo call's argument tuple ((carry, xs)); the return
+        is the solo call's argument tuple ((carry, xs, arg_statics)); the return
         value is exactly `solo_fn(*args)` — same pytree, same bytes.
         `key` extends the stream's family with everything else the solo
         executable was cached under (round kind + rung), so only calls

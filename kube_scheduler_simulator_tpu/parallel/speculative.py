@@ -333,18 +333,17 @@ def _eval_fn(cw: CompiledWorkload, base_key, batch: int, pack_mode: str,
     slim = _SlimWorkload(cw)
 
     def build():
-        step = build_step(slim, out_mode="compact", pack_mode=pack_mode,
-                          score_dtypes=score_dtypes, wide_raw=wide)
+        def dense_round(carry, xs, arg_statics):
+            step = build_step(slim.with_args(arg_statics), out_mode="compact",
+                              pack_mode=pack_mode, score_dtypes=score_dtypes,
+                              wide_raw=wide)
 
-        def eval_only(carry, sl):
-            _, out = step(carry, sl)
-            return out
+            def eval_only(carry, sl):
+                _, out = step(carry, sl)
+                return out
 
-        tiled = _tiled_vmap(eval_only, batch, (None, 0))
-
-        def dense_round(carry, xs):
             with jax.named_scope("kss_speculative_round"):
-                return tiled(carry, xs)
+                return _tiled_vmap(eval_only, batch, (None, 0))(carry, xs)
 
         return jax.jit(dense_round)
 
@@ -427,7 +426,7 @@ def _sparse_round_fn(cw: CompiledWorkload, base_key, batch: int,
     n = cw.n_nodes
 
     def build():
-        def one(carry, sl):
+        def one(slim, carry, sl):
             codes, feasible, considered = _filter_phase(
                 slim, carry, sl, filter_names)
             packed = pack_filter_codes(codes, n, pack_mode, considered)
@@ -443,7 +442,8 @@ def _sparse_round_fn(cw: CompiledWorkload, base_key, batch: int,
             # every sparse-eligible plugin (SAFE_SPECULATIVE) reads its
             # node-axis statics/carry rows positionally, so gather ALL
             # entries — NodeAffinity keeps its match rows in statics
-            # ([U, N] pools the xs index into), not in per-pod xs
+            # ([U, N] pools the xs index into, handed to the round as
+            # arguments), not in per-pod xs
             g_statics = {k: jax.tree.map(lambda x: _take_nodes(x, cand, n), v)
                          for k, v in slim.statics.items()}
             g_carry = {k: jax.tree.map(lambda x: _take_nodes(x, cand, n), v)
@@ -494,10 +494,13 @@ def _sparse_round_fn(cw: CompiledWorkload, base_key, batch: int,
                                  .astype(full.dtype)))
             return packed, reject, count, raw8, raw16, raw32, ovf, selected
 
-        def round_fn(carry, xs):
+        def round_fn(carry, xs, arg_statics):
+            view = slim.with_args(arg_statics)
             with jax.named_scope("kss_speculative_round"):
                 (packed, reject, counts, raw8, raw16, raw32, ovf,
-                 selected) = _tiled_vmap(one, batch, (None, 0))(carry, xs)
+                 selected) = _tiled_vmap(
+                     lambda carry, sl: one(view, carry, sl), batch,
+                     (None, 0))(carry, xs)
             k_dev = _oracle_core(packed, reject, selected, batch)
             return (packed, reject, counts, raw8, raw16, raw32, ovf,
                     selected, k_dev)
@@ -508,8 +511,8 @@ def _sparse_round_fn(cw: CompiledWorkload, base_key, batch: int,
 
 
 def _commit_fn(cw: CompiledWorkload, base_key, batch: int):
-    """Cached jitted (carry, xs_batch, selected, accept) -> carry with
-    every accepted bind applied.  Core-only workloads (the carry holds
+    """Cached jitted (carry, xs_batch, selected, accept, arg_statics) ->
+    carry with every accepted bind applied.  Core-only workloads (the carry holds
     nothing but "core") fold all binds in ONE scatter-add — accepted
     pods bind distinct nodes (the dirty-node rule), so one batched
     scatter == the sequential fold of core_bind_update.  Anything with
@@ -523,7 +526,7 @@ def _commit_fn(cw: CompiledWorkload, base_key, batch: int):
 
     def build():
         if core_only:
-            def commit(carry, xs_batch, selected, accept):
+            def commit(carry, xs_batch, selected, accept, arg_statics):
                 core_batch = xs_batch["core"]
                 core = carry["core"]
                 bound = accept & (selected >= 0)
@@ -544,19 +547,20 @@ def _commit_fn(cw: CompiledWorkload, base_key, batch: int):
         else:
             from ..framework.pipeline import _bind_phase
 
-            def commit(carry, xs_batch, selected, accept):
+            def commit(carry, xs_batch, selected, accept, arg_statics):
                 sel = jnp.where(accept, selected, jnp.int32(-1))
+                view = slim.with_args(arg_statics)
 
                 def body(c, t):
                     sl, s = t
-                    return _bind_phase(slim, c, sl, s), None
+                    return _bind_phase(view, c, sl, s), None
 
                 out, _ = jax.lax.scan(body, carry, (xs_batch, sel))
                 return out
 
-        def bind_fold(carry, xs_batch, selected, accept):
+        def bind_fold(carry, xs_batch, selected, accept, arg_statics):
             with jax.named_scope("kss_bind_fold"):
-                return commit(carry, xs_batch, selected, accept)
+                return commit(carry, xs_batch, selected, accept, arg_statics)
 
         return jax.jit(bind_fold, donate_argnums=(0,))
 
@@ -691,13 +695,6 @@ def replay_speculative_stream(
     Caller must have checked speculation_ok(cw.config, ...)."""
     device_resident = _resolve_device_resident(device_resident, on_chunk)
     active = set(cw.config.active_plugins())
-    if cw.arg_statics():
-        # speculation_ok admits no plugin with argument statics (the
-        # volume family): the round executables close over what
-        # _SlimWorkload holds, the closure statics, and are keyed by it
-        raise ValueError(
-            f"argument statics {sorted(cw.arg_statics())}: their plugins "
-            "run on the sequential scan only (check speculation_ok)")
     inter: _InteractionOracle | None = None
     if active & LABEL_COUPLED:
         if pods is None:
@@ -776,8 +773,12 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
 
     p = cw.n_pods
     chunk = min(chunk, max(p, 1))
-    pack_mode, score_dtypes, score_cols = _compact_plan(cw, wide)
-    base_key = _workload_scan_key(cw, chunk, mesh)
+    # scan_prepare, as in the sequential replay: the compact plan and the
+    # scan-cache key with its statics fingerprint here; below, the
+    # workload's unpack and the carry's copy
+    with TRACER.span("scan_prepare"):
+        pack_mode, score_dtypes, score_cols = _compact_plan(cw, wide)
+        base_key = _workload_scan_key(cw, chunk, mesh)
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
     ladder = _batch_ladder(chunk, dp, batch)
     adaptive = batch is None and len(ladder) > 1
@@ -898,7 +899,13 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
     # same workload
     TRACER.inc("replay_route_total", route="leaves")
     TRACER.count("pass_device_dispatches_total")
-    carry = _copy_carry(cw.init_carry)
+    with TRACER.span("scan_prepare"):
+        carry = _copy_carry(cw.init_carry)
+        # what the round executables take beside carry and xs
+        # (state/compile.py ARG_STATICS: NodeAffinity's match rows here;
+        # the volume family never speculates): keyed by shape, so another
+        # pod's terms are the same executables
+        arg_statics = cw.arg_statics()
     stats = _SpecStats()
     cw_scan = None       # mesh-sharded clone, built on first scan round
     scan_jit = None
@@ -968,8 +975,8 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
         def make():
             ev, orc = eval_for(b), oracle_for(b)
 
-            def both(carry_in, xs_in):
-                outs = ev(carry_in, xs_in)
+            def both(carry_in, xs_in, args_in):
+                outs = ev(carry_in, xs_in, args_in)
                 return outs, orc(outs.packed_filter, outs.prefilter_reject,
                                  outs.selected)
 
@@ -985,9 +992,9 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
         executable was cached under, so only calls to the same compiled
         program ever stack."""
         if fuse_stream is None or fuse_stream.closed:
-            return fn(carry_in, xs_in)
+            return fn(carry_in, xs_in, arg_statics)
         return FUSE.dispatch(fuse_stream, (fuse_stream.family, kind, b),
-                             fn, (carry_in, xs_in))
+                             fn, (carry_in, xs_in, arg_statics))
 
     def place_batch(xs_batch):
         if mesh is None:
@@ -1125,7 +1132,7 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
             feasible_count[lo:lo + k] = fc[:k]
             prefilter_reject[lo:lo + k] = rej[:k]
             accept = jnp.arange(b) < k
-            carry = commit_for(b)(carry, xs, sel_dev, accept)
+            carry = commit_for(b)(carry, xs, sel_dev, accept, arg_statics)
             if k == m == chunk and fill == 0 and lo % chunk == 0:
                 # a fully-accepted top-rung round at an aligned position
                 # IS a grid chunk: ingest its outputs directly — no

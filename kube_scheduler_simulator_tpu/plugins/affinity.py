@@ -34,6 +34,7 @@ import numpy as np
 
 from .base import default_normalize_score, prefilter_rows
 from ..state.nodes import NodeTable
+from ..utils.tracing import TRACER
 from ..state.selectors import (
     match_labels_rows,
     node_selector_rows,
@@ -50,10 +51,27 @@ class NodeAffinityStatic(NamedTuple):
     """Unique match rows, shared across pods.  Pods stamped from one
     template dedup to the same row, so device residency is [U, N] +
     [V, N] (U/V = unique specs) instead of two dense [P, N] tensors —
-    the per-pod xs are just row indices the kernels gather."""
+    the per-pod xs are just row indices the kernels gather.
+
+    The rows change with the QUEUE (another pod, other terms), so they
+    reach the jitted scan as arguments, keyed by shape and dtype
+    (state/compile.py ARG_STATICS), and U / V are padded to a power of
+    two, at least AXIS_FLOOR: a pass of one pod has one layout whether or
+    not the pod carries terms, and another pod's terms are no new
+    executable.  Nothing gathers a pad row."""
 
     req_rows: jnp.ndarray       # [U, N] bool  (row 0 = all-True)
     pref_rows: jnp.ndarray      # [V, N] int32 (row 0 = zeros)
+
+
+AXIS_FLOOR = 2
+
+
+def _stack_padded(pool: list[np.ndarray]) -> np.ndarray:
+    """The pool's rows as [U, N], U the next power of two (at least
+    AXIS_FLOOR); the pad rows repeat row 0."""
+    extent = max(AXIS_FLOOR, 1 << (len(pool) - 1).bit_length())
+    return np.stack(pool + [pool[0]] * (extent - len(pool)))
 
 
 class NodeAffinityXS(NamedTuple):
@@ -103,6 +121,55 @@ def _names_decide(required: dict) -> bool:
         for term in required.get("nodeSelectorTerms") or [])
 
 
+def _required_row(table: NodeTable, node_sel: dict,
+                  required: dict | None) -> np.ndarray:
+    """[N] bool: the nodes that match the pod's nodeSelector AND its
+    required terms; by spec from the node table's memo, so a spec seen
+    before on this table is a lookup and not a walk over the nodes."""
+    def make():
+        TRACER.count("affinity_rows_built_total")
+        idx = table.label_index
+        row = np.ones(table.n, dtype=bool)
+        if node_sel:
+            row &= match_labels_rows(node_sel, idx)
+        if required:
+            row &= node_selector_rows(required, idx)
+        return row
+
+    return table.derived.row("affinity_required", spec_key(node_sel, required),
+                             make)
+
+
+def _term_row(table: NodeTable, preference: dict) -> np.ndarray:
+    """[N] bool: the nodes one preferred term's `preference` matches, from
+    the memo by the term alone: terms that differ in weight share it."""
+    def make():
+        TRACER.count("affinity_rows_built_total")
+        return node_selector_term_rows(preference, table.label_index)
+
+    return table.derived.row("affinity_term", spec_key(preference), make)
+
+
+def _preferred_row(table: NodeTable, preferred: list[dict]) -> np.ndarray:
+    """[N] int32: per node the summed weights of the matching terms."""
+    row = np.zeros(table.n, dtype=np.int32)
+    for term in preferred:
+        row += int(term.get("weight", 0)) * _term_row(
+            table, term.get("preference") or {})
+    return row
+
+
+def _count_rebuckets(table: NodeTable, axes: dict[str, int]) -> None:
+    """affinity_axis_rebuckets_total{axis}: this pass's padded U / V is
+    not the extent of the last pass on this node table, which is another
+    layout of the pass's buffers and so another scan executable."""
+    last = table.derived.swap("affinity_axes", axes) or axes
+    for axis, extent in axes.items():
+        # + 0 too: a series that reads 0 says the axes are padded
+        TRACER.inc("affinity_axis_rebuckets_total",
+                   int(extent != last[axis]), axis=axis)
+
+
 def build(table: NodeTable, pods: list[dict],
           args: dict | None = None,
           host_out: dict | None = None
@@ -114,18 +181,11 @@ def build(table: NodeTable, pods: list[dict],
     # addedAffinity (NodeAffinityArgs): admin-configured affinity ANDed
     # onto every pod (upstream node_affinity.go); with it present,
     # PreFilter/PreScore never Skip
-    idx = table.label_index  # columnar: one vector op per expression
-
     added = (args or {}).get("addedAffinity") or {}
     added_req = added.get("requiredDuringSchedulingIgnoredDuringExecution")
     added_pref = added.get("preferredDuringSchedulingIgnoredDuringExecution") or []
-    added_req_row = node_selector_rows(added_req, idx) if added_req else None
-    added_pref_row = None
-    if added_pref:
-        added_pref_row = np.zeros(n, dtype=np.int32)
-        for t in added_pref:
-            added_pref_row += int(t.get("weight", 0)) * node_selector_term_rows(
-                t.get("preference") or {}, idx)
+    added_req_row = _required_row(table, {}, added_req) if added_req else None
+    added_pref_row = _preferred_row(table, added_pref) if added_pref else None
 
     # row 0 of each pool is the identity row — what skipped pods gather
     # (their kernel output is masked by the skip flag downstream)
@@ -161,13 +221,9 @@ def build(table: NodeTable, pods: list[dict],
             key = spec_key(node_sel, required)
             j = req_by_key.get(key)
             if j is None:
-                row = np.ones(n, dtype=bool)
-                if node_sel:
-                    row &= match_labels_rows(node_sel, idx)
-                if required:
-                    row &= node_selector_rows(required, idx)
+                row = _required_row(table, node_sel, required)
                 if added_req_row is not None:
-                    row &= added_req_row
+                    row = row & added_req_row
                 j = len(req_pool)
                 req_pool.append(row)
                 req_by_key[key] = j
@@ -179,10 +235,7 @@ def build(table: NodeTable, pods: list[dict],
             key = spec_key(preferred)
             j = pref_by_key.get(key)
             if j is None:
-                row = np.zeros(n, dtype=np.int32)
-                for term in preferred:
-                    row += int(term.get("weight", 0)) * node_selector_term_rows(
-                        term.get("preference") or {}, idx)
+                row = _preferred_row(table, preferred)
                 if added_pref_row is not None:
                     row += added_pref_row
                 j = len(pref_pool)
@@ -190,23 +243,29 @@ def build(table: NodeTable, pods: list[dict],
                 pref_by_key[key] = j
             pref_idx[i] = j
 
-    pref_mat = np.stack(pref_pool)
-    if host_out is not None and not score_skip.all():
+    pref_mat = _stack_padded(pref_pool)
+    if host_out is not None:
         # the raw score IS the precompiled row (score_kernel is a pure
         # gather), so the compact replay never transfers it back from the
         # device — the decoder reads this host copy directly
         # (framework/replay.py "host" score group).  Materialized [P, N]
         # int32, C-contiguous: the native decoder indexes it by raw
-        # pointer.  Skipped-for-every-pod scoring stashes nothing (the
-        # decoder emits no annotations for skipped scorers).
+        # pointer.  A pass in which every pod's scoring is skipped stashes
+        # zeros nobody reads (np.zeros is COW-cheap, as VolumeBinding's in
+        # state/compile.py): the score group, and with it the compact
+        # layout and the executable, is then the same whether or not the
+        # pass's pods carry preferred terms
         host_out.setdefault("static_score_rows", {})[NAME] = (
+            np.zeros((p, n), dtype=np.int32) if score_skip.all() else
             np.ascontiguousarray(np.take(pref_mat, pref_idx, axis=0)))
     # numpy, xs and carry too: compile_workload reads its flags and the
     # digest off the host bytes, then uploads once (pack_tree)
     static = NodeAffinityStatic(
-        req_rows=np.stack(req_pool),
+        req_rows=_stack_padded(req_pool),
         pref_rows=pref_mat,
     )
+    _count_rebuckets(table, {"req": static.req_rows.shape[0],
+                             "pref": pref_mat.shape[0]})
     if host_out is not None:
         if any(nm is not None for nm in narrowed):
             host_out.setdefault("prefilter_result", {})[NAME] = narrowed

@@ -104,10 +104,19 @@ VOLUME_PLUGINS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding", "Vo
 # are uploaded once per content per node table.  The volume family's are
 # arguments because they change with the cluster's volume objects (a PV,
 # a claim or a CSINode created between two passes), which a served
-# cluster creates as fast as pods; the rest change with nodes or the
-# configuration only (docs/wave-pipeline.md, "Statics: closure or
-# argument").  VolumeZone has no statics.
-ARG_STATICS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding")
+# cluster creates as fast as pods.  NodeAffinity's are arguments because
+# they change with the QUEUE: req_rows [U, N] / pref_rows [V, N] hold a
+# row per node-affinity spec among the pass's pods, so as closure
+# constants every pod with other terms was a new digest and a new
+# executable; U and V are padded (plugins/affinity.py AXIS_FLOOR), the
+# rows come from the node table's memo.  The rest change with nodes or
+# the configuration only, with two exceptions that are still closure
+# constants and cost a compile per distinct set of topology keys in a
+# pass: PodTopologySpread's and InterPodAffinity's dom_idx [C, N] / [T, N]
+# (docs/wave-pipeline.md, "Statics: closure or argument").  VolumeZone
+# has no statics.
+ARG_STATICS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding",
+               "NodeAffinity")
 
 
 def split_statics(statics: dict[str, Any]) -> tuple[dict, dict]:
@@ -472,7 +481,8 @@ def compile_workload(
             _swap_resident(resident, (args, init_carry),
                            lambda kept, host: kept.outgoing(host))
             TRACER.count("volume_static_args_bytes_total",
-                         sum(leaf.nbytes for leaf in jax.tree.leaves(args))
+                         sum(leaf.nbytes for leaf in jax.tree.leaves(
+                             [args.get(name) for name in VOLUME_PLUGINS]))
                          + sum(kept.whole_nbytes for t, _n, _l, kept
                                in resident if t == 0))
             cw.packed = pack_tree(

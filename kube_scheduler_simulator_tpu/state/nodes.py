@@ -41,24 +41,36 @@ class NodeDerived:
 
     Kinds (the `kind` label of node_derived_{hits,misses,evictions}_total):
     image_states, taint_max, name_idx (one value a table); image_row,
-    taint_rows, taints_tolerated, dom_idx (one value a fragment, at most
-    ROW_CAP a kind, least recently used out); statics_device (one
+    taint_rows, taints_tolerated, dom_idx, affinity_required (NodeAffinity's
+    [N] match row of a nodeSelector + required terms), affinity_term (the
+    [N] match row of one preferred term, whatever its weight) (one value a
+    fragment, at most ROW_CAP a kind, least recently used out);
+    statics_device (one
     generation: the last pass's uploaded statics under their digest);
     codec_ctx (one generation: the native codec's context under the
     profile's lineup, weights, schema and custom message tables,
     store/native_decode.py shared_context; None where the LUTs cannot
     express the lineup).
     Arrays are handed out read-only: every consumer copies them into its
-    own [P, N] block."""
+    own [P, N] block.  `swap` is no memo: it keeps one note a kind from
+    pass to pass on this table (what the last pass's padded affinity axes
+    were) and counts nothing."""
 
     ROW_CAP = 256
 
-    __slots__ = ("_values", "_rows", "_lock")
+    __slots__ = ("_values", "_rows", "_notes", "_lock")
 
     def __init__(self):
         self._values: dict[str, object] = {}
         self._rows: dict[str, OrderedDict] = {}
+        self._notes: dict[str, object] = {}
         self._lock = threading.Lock()
+
+    def swap(self, kind: str, note):
+        """Leave `note` for the next pass on this table -> the last pass's
+        (None on the table's first pass)."""
+        last, self._notes[kind] = self._notes.get(kind), note
+        return last
 
     def once(self, kind: str, make):
         """The table's one value of `kind`."""

@@ -587,6 +587,7 @@ class HistoryFeeder:
                 "speculative_accepted_total", "session"),
             "rolled": TRACER.labeled_totals(
                 "speculative_rolled_back_total", "session"),
+            "rounds": TRACER.session_totals("speculative_rounds_total"),
             "spilled": TRACER.labeled_totals(
                 "device_chunks_spilled_total", "session"),
             "controls": CONTROLS.stats(),

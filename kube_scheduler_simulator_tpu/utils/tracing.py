@@ -293,6 +293,20 @@ _HELP: dict[str, str] = {
         "cluster; axis=csi: distinct CSI volumes of limited drivers) "
         "outgrew the extent of the session's last pass: the one volume "
         "event that compiles a new scan.",
+    "affinity_axis_rebuckets_total":
+        "Passes whose padded NodeAffinity axis (axis=req: the U of "
+        "req_rows [U, N], unique nodeSelector + required specs among the "
+        "pass's pods; axis=pref: the V of pref_rows [V, N]) is not the "
+        "extent of the last pass on this node table: another layout of "
+        "the pass's buffers, so another scan executable.  0 is written "
+        "too (plugins/affinity.py AXIS_FLOOR).",
+    "affinity_rows_built_total":
+        "NodeAffinity match rows built by walking the node table's "
+        "labels: one a nodeSelector + required spec, one a preferred "
+        "term (whatever its weight), the first time this node table "
+        "meets it; a spec or term seen before is a lookup in the table's "
+        "memo (state/nodes.py NodeDerived, kinds affinity_required and "
+        "affinity_term) and builds none.",
     "volume_static_args_bytes_total":
         "Bytes that travel to the device for the volume family's statics "
         "handed to the scan as arguments (state/compile.py ARG_STATICS), "
@@ -816,6 +830,13 @@ class Tracer:
                 val = dict(key).get(label, "")
                 out[val] = out.get(val, 0) + v
         return out
+
+    def session_totals(self, name: str) -> dict[str, float]:
+        """One plain counter (count()) grouped by the session scope it was
+        counted under; what was counted outside any is in no group."""
+        with self._lock:
+            return {sid: c[name] for sid, c in self._scounters.items()
+                    if name in c}
 
     # --------------------------------------------------------- histograms
 

@@ -151,6 +151,35 @@ def test_speculative_effector_hysteresis_no_thrash():
         mgr.shutdown()
 
 
+def test_a_round_s_first_pod_is_no_evidence():
+    """One pod a pass: every round is one pod, accepted whatever the
+    contention.  That reads 1.00 for ever and is evidence of nothing: the
+    profile stays (going aggressive compiled a wider sparse round in the
+    middle of a served session).  Pods beyond a round's first count."""
+    mgr = _mgr(max_sessions=4)
+    ap = Autopilot(mgr, interval=3600, slo_target=0)  # shed effector off
+    try:
+        mgr.create("ap-one")
+
+        def rounds(n: int, accepted: int) -> None:
+            with TRACER.session_scope("ap-one"):
+                TRACER.count("speculative_rounds_total", n)
+                TRACER.inc("speculative_accepted_total", accepted)
+
+        ap.tick()
+        for _ in range(HYSTERESIS_TICKS * 3):
+            rounds(25, 25)          # 25 passes of one pod each
+            ap.tick()
+        assert CONTROLS.spec_overrides("ap-one") == (None, None)
+        assert ap.stats()["decisions"] == 0
+        for _ in range(HYSTERESIS_TICKS):
+            rounds(4, 100)          # 4 rounds, 96 pods beyond their first
+            ap.tick()
+        assert CONTROLS.spec_overrides("ap-one") == (-1, 256)
+    finally:
+        mgr.shutdown()
+
+
 def test_speculative_profile_decays_to_default_on_mid_band():
     """A profile is not forever: a sustained mid-band accept fraction
     (no hi/lo evidence either way) decays the session back to the

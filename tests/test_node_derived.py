@@ -471,9 +471,19 @@ def test_equal_digest_hands_back_the_same_device_arrays():
     assert not any(x is y for x, y in zip(
         jax.tree.leaves((a.init_carry, a.arg_statics())),
         jax.tree.leaves((b.init_carry, b.arg_statics()))))
-    # a pod whose nodeSelector adds a NodeAffinity row changes the digest
+    # a pod whose nodeSelector adds a NodeAffinity row does not change the
+    # digest (the rows are argument statics, tests/test_affinity_arg_statics.py)
+    # ...
+    picky = dep.measured_pod()
+    picky["spec"]["nodeSelector"] = {"kubernetes.io/os": "linux"}
+    e = compile_workload(dep.nodes, [picky], reuse=b, **kw)
+    assert e.host["_statics_fp"] == a.host["_statics_fp"]
+    # ... one whose spread constraint adds a dom_idx row does
     other = dep.measured_pod()
-    other["spec"]["nodeSelector"] = {"kubernetes.io/os": "linux"}
+    other["spec"]["topologySpreadConstraints"] = [{
+        "maxSkew": 1, "topologyKey": "kubernetes.io/hostname",
+        "whenUnsatisfiable": "ScheduleAnyway",
+        "labelSelector": {"matchLabels": {"no": "pod"}}}]
     before = _counts()
     c = compile_workload(dep.nodes, [other], reuse=b, **kw)
     moved = _delta(before, _counts())

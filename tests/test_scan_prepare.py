@@ -40,7 +40,7 @@ from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.server.sessions import SessionManager
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration
 from kube_scheduler_simulator_tpu.state.compile import (
-    compile_workload, statics_digest)
+    compile_workload, split_statics, statics_digest)
 from kube_scheduler_simulator_tpu.state.packed import upload_tree
 from kube_scheduler_simulator_tpu.store.decode import decode_pod_result
 from kube_scheduler_simulator_tpu.utils import hostevents
@@ -129,7 +129,7 @@ def test_one_static_byte_dtype_or_shape_changes_the_key(idx, scale):
     for what, core in _core_variants(cw.statics["core"]):
         statics = {**cw.statics, "core": core}
         # from host bytes, as compile_workload digests them ...
-        fp_host = statics_digest(statics)
+        fp_host = statics_digest(split_statics(statics)[0])
         # ... and fetched back from the uploaded copy, as the fallback does
         other = _without_digest(cw, statics=upload_tree(statics))
         other_key = _workload_scan_key(other, 16)
@@ -157,7 +157,7 @@ def test_key_shapes_read_metadata_as_numpy_would():
     shapes = _workload_scan_key(cw, 16)[2]
     want = tuple(
         (str(path), tuple(np.shape(leaf)), str(np.asarray(leaf).dtype))
-        for tree in (cw.xs, cw.init_carry)
+        for tree in (cw.xs, cw.init_carry, cw.arg_statics())
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0])
     assert shapes == want
     assert replay_mod._leaf_sig(7) == ((), str(np.asarray(7).dtype))
@@ -218,7 +218,8 @@ def test_scan_prepare_converts_no_device_array(monkeypatch, idx, scale):
     spy.by_span.clear()
     assert _workload_scan_key(bare, 16) == key
     assert spy.by_span.get(None, 0) == sum(
-        isinstance(leaf, jax.Array) for leaf in jax.tree.leaves(warm.statics))
+        isinstance(leaf, jax.Array)
+        for leaf in jax.tree.leaves(warm.closure_statics()))
 
 
 # -------------------------------------------------- the carry survives

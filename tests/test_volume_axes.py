@@ -289,8 +289,10 @@ def test_the_family_s_counters_read_the_pass(cluster):
 def test_argument_statics_are_the_family_s_and_travel_with_the_pass():
     nodes, bound, pending, vols = _seeded_cluster(5)
     cw = compile_workload(nodes, pending, VOL_CFG, volumes=vols)
-    assert set(cw.arg_statics()) == set(ARG_STATICS) == {
-        "VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding"}
+    family = {"VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding"}
+    # ... and NodeAffinity's match rows, which change with the queue
+    assert set(ARG_STATICS) == family | {"NodeAffinity"}
+    assert family <= set(cw.arg_statics()) <= set(ARG_STATICS)
     assert all(isinstance(leaf, jax.Array)
                for leaf in jax.tree.leaves(cw.arg_statics()))
     # another cluster's volumes, the same shapes: the same scan

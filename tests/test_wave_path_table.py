@@ -283,3 +283,48 @@ def test_wave_path(row, monkeypatch):
     assert annotated["metadata"]["annotations"]
     assert (_counter("d2h_on_demand_bytes_total") > 0) \
         is (row.results == "device_lazy")
+
+
+# ------------------------------------------- row 8, one pod a pass
+
+# BASELINE config 3's profile, as benchmark cell
+# baseline_c3_1k.interactive_profile posts it: row 8 with no label-coupled
+# plugin, so the rounds take the sparse tail where feasibility allows
+CONFIG_3 = BATCHABLE[:4]
+
+
+def test_row08_serves_one_pod_a_pass():
+    """A UI user's traffic on a batchable profile: every pass is one pod,
+    and every pass is one speculative round of one pod, committed by the
+    streaming worker, whatever node-affinity terms and tolerations the pod
+    carries; after the first passes nothing compiles."""
+    store = ObjectStore()
+    for n in make_nodes(160, seed=21, taint_fraction=0.1):
+        store.create("nodes", n)
+    engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
+        enabled=list(CONFIG_3)), chunk=8)
+    assert engine._wave_plan() == WavePlan(
+        "speculative", "streamed", "device_lazy")
+    pods = make_pods(14, seed=22, with_affinity=True, with_tolerations=True)
+    assert len({str(p["spec"].get("affinity")) for p in pods}) > 4
+    TRACER.reset()
+    misses = []
+    for i, pod in enumerate(pods):
+        store.create("pods", pod)
+        assert engine.schedule_pending() == 1
+        assert _counter("speculative_rounds_total") == i + 1
+        assert _counter("commit_stream_waves_total") == i + 1
+        assert _counter("speculative_fallbacks_total") == 0
+        series = TRACER.snapshot()["labeled_counters"].get(
+            "scan_compile_cache_total", [])
+        misses.append(sum(s["value"] for s in series
+                          if s["labels"].get("result") == "miss"))
+    # the sparse round, and the dense one of a pod more than
+    # KSS_TPU_SPECULATIVE_CANDIDATES nodes take, are met early; then
+    # another pod's terms are no new executable
+    assert misses[-1] == misses[3], misses
+    for pod in pods:
+        meta = pod["metadata"]
+        got = store.get("pods", meta["name"], meta["namespace"])
+        assert got["spec"].get("nodeName")
+        assert got["metadata"]["annotations"]
