@@ -1348,7 +1348,7 @@ class SchedulerEngine:
         # leave it out of the profile
         vectorized = gp is not None and self._gang_vectorized()
         ignore = frozenset({gp.name}) if vectorized else frozenset()
-        plan = self._wave_plan(ignore)
+        plan = self._wave_plan(cw.n_pods, ignore)
         if plan.scan == "host_loop":
             # gangs route through the per-pod Permit machinery here
             # (the Coscheduling plugin stays in the lifecycle set)
@@ -1374,12 +1374,19 @@ class SchedulerEngine:
                 mesh = None
         return self._device_wave(plan, cw, mesh, pending, exclude, ignore)
 
-    def _wave_plan(self, ignore: frozenset = frozenset()) -> WavePlan:
+    def _wave_plan(self, n_pods: int,
+                   ignore: frozenset = frozenset()) -> WavePlan:
         """The three decisions of a wave, taken ONCE, after
         compile_workload, from what the engine observes (the table at the
         head of docs/wave-pipeline.md, row by row); the executor, the
         committer and _finish_wave's caller read the value and ask
-        nothing again.  ignore: the gang plugin's name where the
+        nothing again.  n_pods: how many pods the pass holds (cw.n_pods:
+        the queue after gates, excludes and the gang prescreen) — the
+        rounds are for a pass that holds a batch; a pass of ONE pod has
+        nothing to accept, roll back or cut, so on a batchable profile
+        it takes the sequential scan, one call over the pass's packed
+        buffers (row 9), where a round of one was ~17 dispatches and a
+        discarded probe.  ignore: the gang plugin's name where the
         vectorized quorum pass handles it this wave (row 11) — it then is
         no lifecycle plugin and speculation_ok ignores it: its PreFilter
         ran in the prescreen, admission happens in the quorum pass at
@@ -1391,8 +1398,10 @@ class SchedulerEngine:
         observers = bool(self._extenders_map())
         # speculative multi-pod rounds, for profiles that admit exact
         # batching (the stock default profile does not: it enables the
-        # volume family).  KSS_TPU_SPECULATIVE=0 pins the sequential scan:
-        # the parity baseline the golden suite diffs against
+        # volume family) and passes that hold a batch (no tuned
+        # threshold: at one pod the two scans compute the same thing).
+        # KSS_TPU_SPECULATIVE=0 pins the sequential scan: the parity
+        # baseline the golden suite diffs against
         scan = "sequential"
         if (os.environ.get("KSS_TPU_SPECULATIVE", "1") != "0"
                 and self.extender_service is None and not lifecycle):
@@ -1400,7 +1409,12 @@ class SchedulerEngine:
 
             if speculation_ok(self.plugin_config, have_manifests=True,
                               ignore=ignore):
-                scan = "speculative"
+                if n_pods >= 2:
+                    scan = "speculative"
+                else:
+                    # the pass's zero rounds, counted: a batchable profile
+                    # that has served no batch yet reads 0, not absent
+                    TRACER.count("speculative_rounds_total", 0)
         # the sequential post-pass where after_cycle observers see each
         # pod's annotations in order, a custom Reserve / Permit / PreBind
         # can reject and abort the wave, or a PostFilter (preemption)

@@ -37,7 +37,10 @@ acceptance rules compose:
   equals the sequential state.
 
 The first pod of every round is unconditionally safe, so each round
-commits >= 1 pod and the loop terminates.  The dirty-node test runs ON
+commits >= 1 pod and the loop terminates.  (A queue of ONE pod is
+therefore one step of the scan and nothing else; the engine never hands
+this module one: SchedulerEngine._wave_plan sends a pass of one pod to
+the sequential scan.)  The dirty-node test runs ON
 DEVICE (a [B, B] feasibility-at-selected-nodes gather; only the prefix
 length and the per-pod decision rows cross to host), the interaction
 walk on host over the pod manifests.  Where the win comes from:

@@ -186,7 +186,8 @@ def test_engine_soak_streaming_commit(seed):
         engine = SchedulerEngine(
             store, plugin_config=PluginSetConfig(**cfg_kw), chunk=8,
             pipeline_commit=pipeline)
-        assert (engine._wave_plan().commit == "streamed") == pipeline
+        plan = engine._wave_plan(len(pod_rounds[0]))
+        assert (plan.commit == "streamed") == pipeline
         for r, pods in enumerate(pod_rounds):
             for p in pods:
                 q = {"metadata": dict(p["metadata"]), "spec": dict(p["spec"])}

@@ -134,7 +134,7 @@ def _pipelined_wave(n_pods=48, n_nodes=6, chunk=16, pipeline=True):
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=chunk,
                              pipeline_commit=pipeline)
-    assert (engine._wave_plan().commit == "streamed") is pipeline
+    assert (engine._wave_plan(n_pods).commit == "streamed") is pipeline
     bound = engine.schedule_pending()
     assert bound > 0
     return TRACER.events(limit=1000)
@@ -599,7 +599,7 @@ def test_mid_chunk_exception_leaves_tracer_balanced(monkeypatch):
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=16,
                              pipeline_commit=True, residency_floor=2)
-    assert engine._wave_plan().commit == "streamed"
+    assert engine._wave_plan(48).commit == "streamed"
 
     real = engine.result_store.put_decoded
     calls = {"n": 0}

@@ -110,6 +110,7 @@ class Row:
     env: dict = field(default_factory=dict)
     engine_kw: dict = field(default_factory=dict)
     gang: bool = False
+    pods: int = 12                     # how many the one pass holds
     # what _wave_plan must return: (scan, commit, results)
     plan: tuple = ()
     # the path it must take
@@ -153,12 +154,20 @@ ROWS = [
     Row("row07_postfilter_in_batchable_profile",
         config=_enabled(BATCHABLE + ["DefaultPreemption"]),
         plan=("sequential", "post_pass", "device_lazy")),
+    # the rounds are for a pass that holds a batch ...
     Row("row08_batchable_profile", config=_enabled(BATCHABLE),
         plan=("speculative", "streamed", "device_lazy"),
+        speculative=True, commit="streamed"),
+    Row("row08_batchable_profile_pass_of_two", config=_enabled(BATCHABLE),
+        pods=2, plan=("speculative", "streamed", "device_lazy"),
         speculative=True, commit="streamed"),
     Row("row09_volume_family_without_postfilter",
         config=_enabled(["NodeResourcesFit", "VolumeBinding"]),
         plan=("sequential", "streamed", "device_lazy"),
+        commit="streamed"),
+    # ... a pass of one pod has nothing to speculate on
+    Row("row09_batchable_profile_pass_of_one", config=_enabled(BATCHABLE),
+        pods=1, plan=("sequential", "streamed", "device_lazy"),
         commit="streamed"),
     Row("row10_reflector_cannot_defer_default_profile", tweak=_no_defer,
         plan=("sequential", "post_pass", "by_chunk"),
@@ -214,7 +223,7 @@ def _engine(row, monkeypatch):
     store = ObjectStore()
     for n in make_nodes(6, seed=11):
         store.create("nodes", n)
-    pods = make_pods(12, seed=12)
+    pods = make_pods(row.pods, seed=12)
     if row.gang:
         ensure_podgroup_resource(store)
         pgs, gpods = make_gang_workload(1, 3, seed=13)
@@ -233,18 +242,19 @@ def _engine(row, monkeypatch):
 @pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
 def test_wave_plan(row, monkeypatch):
     """The plan method's value for the row's engine: taken once for the
-    wave, and what the table says."""
+    wave, for the number of pods the pass holds, and what the table
+    says."""
     _store, pods, engine = _engine(row, monkeypatch)
     plans = []
     decide = engine._wave_plan
 
-    def recorded(*args):
-        plans.append(decide(*args))
-        return plans[-1]
+    def recorded(n_pods, *args):
+        plans.append((n_pods, decide(n_pods, *args)))
+        return plans[-1][1]
 
     monkeypatch.setattr(engine, "_wave_plan", recorded)
     assert engine.schedule_pending() == len(pods)
-    assert plans == [WavePlan(*row.plan)]
+    assert plans == [(len(pods), WavePlan(*row.plan))]
 
 
 @pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
@@ -285,46 +295,116 @@ def test_wave_path(row, monkeypatch):
         is (row.results == "device_lazy")
 
 
-# ------------------------------------------- row 8, one pod a pass
+# ------------------------------- a batchable profile, one pod a pass
 
 # BASELINE config 3's profile, as benchmark cell
-# baseline_c3_1k.interactive_profile posts it: row 8 with no label-coupled
-# plugin, so the rounds take the sparse tail where feasibility allows
+# baseline_c3_1k.interactive_profile posts it: no label-coupled plugin,
+# no PostFilter, nothing of the volume family
 CONFIG_3 = BATCHABLE[:4]
 
 
-def test_row08_serves_one_pod_a_pass():
-    """A UI user's traffic on a batchable profile: every pass is one pod,
-    and every pass is one speculative round of one pod, committed by the
-    streaming worker, whatever node-affinity terms and tolerations the pod
-    carries; after the first passes nothing compiles."""
+def _route(route):
+    return TRACER.labeled_totals("replay_route_total", "route").get(route, 0)
+
+
+def _scan_misses():
+    return TRACER.labeled_totals(
+        "scan_compile_cache_total", "result").get("miss", 0)
+
+
+def _config3_engine(n_pods=14):
     store = ObjectStore()
     for n in make_nodes(160, seed=21, taint_fraction=0.1):
         store.create("nodes", n)
     engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
         enabled=list(CONFIG_3)), chunk=8)
-    assert engine._wave_plan() == WavePlan(
-        "speculative", "streamed", "device_lazy")
-    pods = make_pods(14, seed=22, with_affinity=True, with_tolerations=True)
+    pods = make_pods(n_pods, seed=22, with_affinity=True,
+                     with_tolerations=True)
     assert len({str(p["spec"].get("affinity")) for p in pods}) > 4
-    TRACER.reset()
-    misses = []
-    for i, pod in enumerate(pods):
-        store.create("pods", pod)
-        assert engine.schedule_pending() == 1
-        assert _counter("speculative_rounds_total") == i + 1
-        assert _counter("commit_stream_waves_total") == i + 1
-        assert _counter("speculative_fallbacks_total") == 0
-        series = TRACER.snapshot()["labeled_counters"].get(
-            "scan_compile_cache_total", [])
-        misses.append(sum(s["value"] for s in series
-                          if s["labels"].get("result") == "miss"))
-    # the sparse round, and the dense one of a pod more than
-    # KSS_TPU_SPECULATIVE_CANDIDATES nodes take, are met early; then
-    # another pod's terms are no new executable
-    assert misses[-1] == misses[3], misses
+    return store, engine, pods
+
+
+def _decided(store, pods):
+    """pod name -> (spec.nodeName, every annotation), as a reader sees."""
+    out = {}
     for pod in pods:
         meta = pod["metadata"]
         got = store.get("pods", meta["name"], meta["namespace"])
-        assert got["spec"].get("nodeName")
-        assert got["metadata"]["annotations"]
+        out[meta["name"]] = (got["spec"].get("nodeName"),
+                             dict(got["metadata"]["annotations"]))
+    return out
+
+
+def test_row09_serves_one_pod_a_pass():
+    """A UI user's traffic on a batchable profile: every pass is one pod,
+    a pass of one has nothing to speculate on, so every pass is the
+    sequential scan's one call over the pass's packed buffers (the one
+    upload and the one executable: 2 dispatches), committed by the
+    streaming worker, whatever node-affinity terms and tolerations the pod
+    carries; after the first passes nothing compiles."""
+    store, engine, pods = _config3_engine()
+    assert engine._wave_plan(1) == WavePlan(
+        "sequential", "streamed", "device_lazy")
+    TRACER.reset()
+    misses, dispatches = [], []
+    for i, pod in enumerate(pods):
+        store.create("pods", pod)
+        assert engine.schedule_pending() == 1
+        # zero rounds, and counted as zero: the counter is there to read
+        assert TRACER.summary()["counters"]["speculative_rounds_total"] == 0
+        assert _counter("commit_stream_waves_total") == i + 1
+        assert _route("packed") == i + 1 and _route("leaves") == 0
+        dispatches.append(_counter("pass_device_dispatches_total"))
+        misses.append(_scan_misses())
+    # a steady pass is two dispatches (the first also uploads the node
+    # table's statics), and another pod's terms are no new executable:
+    # NodeAffinity's rows are arguments of the scan
+    steady = [b - a for a, b in zip(dispatches[3:], dispatches[4:])]
+    assert steady == [2] * len(steady), dispatches
+    assert misses[-1] == misses[3], misses
+    for node, annotations in _decided(store, pods).values():
+        assert node and annotations
+
+
+def test_row08_two_pods_a_pass_are_a_round():
+    """The same profile and pods, two a pass: a batch, so the rounds, as
+    for any pass of two pods or more."""
+    store, engine, pods = _config3_engine()
+    assert engine._wave_plan(2) == WavePlan(
+        "speculative", "streamed", "device_lazy")
+    TRACER.reset()
+    for i in range(0, len(pods), 2):
+        for pod in pods[i:i + 2]:
+            store.create("pods", pod)
+        assert engine.schedule_pending() == 2
+        assert _counter("speculative_rounds_total") >= i // 2 + 1
+        assert _counter("commit_stream_waves_total") == i // 2 + 1
+        assert _route("packed") == 0
+    for node, annotations in _decided(store, pods).values():
+        assert node and annotations
+
+
+def test_one_pod_a_pass_and_one_pass_of_all_are_byte_equal():
+    """The plan's two answers for one profile give one result: the same
+    pods served one a pass (the sequential scan's one call) and as one
+    pass of 12 (the rounds) carry the same spec.nodeName, the same 13
+    result annotations and the same result history, byte for byte."""
+    store, engine, pods = _config3_engine(12)
+    TRACER.reset()
+    for pod in pods:
+        store.create("pods", pod)
+        assert engine.schedule_pending() == 1
+    assert _counter("speculative_rounds_total") == 0
+    one_a_pass = _decided(store, pods)
+
+    store, engine, pods = _config3_engine(12)
+    for pod in pods:
+        store.create("pods", pod)
+    assert engine.schedule_pending() == 12
+    assert _counter("speculative_rounds_total") > 0
+    one_pass = _decided(store, pods)
+
+    assert all(len(annotations) == 13 + 1
+               for _node, annotations in one_pass.values())
+    differing = sorted(k for k in one_pass if one_pass[k] != one_a_pass[k])
+    assert not differing, differing
