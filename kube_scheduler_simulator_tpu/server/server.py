@@ -554,7 +554,8 @@ def _make_handler(server: SimulatorServer):
             (event ring, open spans, counter deltas since the last wave
             start, armed fault plan, env knobs, device fingerprint)
             plus metadata of recently stored dumps (wave aborts write
-            theirs to KSS_TPU_BLACKBOX_DIR)."""
+            theirs to KSS_TPU_BLACKBOX_DIR) and the kept stall records
+            (docs/metrics.md "Waiting and working")."""
             from ..utils.blackbox import BLACKBOX
             sid = self._session_filter(url)
             doc = BLACKBOX.bundle("request", session=sid,
@@ -568,7 +569,8 @@ def _make_handler(server: SimulatorServer):
                 # the scoped alias leaks nothing: not even another
                 # tenant's dump metadata (cause text, on-disk path)
                 recent = [d for d in recent if d.get("session") == sid]
-            return self._json(200, {"dump": doc, "recent": recent})
+            return self._json(200, {"dump": doc, "recent": recent,
+                                    "stalls": BLACKBOX.stalls(sid)})
 
         def _health(self, path: str):
             """GET /healthz (liveness: the HTTP server answers) and

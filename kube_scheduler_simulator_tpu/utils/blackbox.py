@@ -23,6 +23,14 @@ spills chunks.  This module is the always-on flight-data recorder:
     fingerprint (per-device `memory_stats()`), JSON-immutable.  Wave
     aborts auto-write the bundle to `KSS_TPU_BLACKBOX_DIR` so a crashed
     wave ships its own evidence (docs/fault-injection.md).
+  * **stall records**: a span that closes after `tracing.STALL_S` is
+    kept as a bundle of reason `stall`, in a deque of its own that
+    served cycles cannot push out, with what tells waiting from
+    working: its subtree by name, the thread's compile and GC seconds,
+    its CPU seconds since the watch first saw it stand (read from
+    outside: spans read one clock), the process's lateness and the
+    stacks the watch took while it stood, and a `cause`
+    (docs/metrics.md "Waiting and working; the stall record").
   * `validate_dump()` — the schema check `make blackbox-smoke`, the
     chaos harness and the tests share.
   * `SLOTracker` — rolling per-session p50/p99 wave latency and
@@ -44,11 +52,14 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
+import traceback
 from collections import deque
 
 from . import history as _history
+from . import tracing as _tracing
 from .env import env_float, env_int
 from .history import HISTORY
 from .tracing import TRACER
@@ -73,8 +84,6 @@ def set_enabled(on: bool) -> bool:
     global _ENABLED
     prev = _ENABLED
     _ENABLED = bool(on)
-    from . import tracing as _tracing
-
     _tracing.BLACKBOX_OPEN_SPANS = bool(on)
     return prev
 
@@ -164,6 +173,108 @@ def device_fingerprint() -> dict:
     return out
 
 
+# ------------------------------------------------------ stalls: readings
+
+# the watch's tick (DeviceTelemetry's third cadence), and the age at
+# which an open span gets the baseline its readings start from
+WATCH_S = 0.25
+# a tick that wakes later than this says that for so long NO Python
+# thread of the process got to run (a C call held the GIL, or the whole
+# process was off the CPU)
+LATE_S = 0.05
+# how often the telemetry thread looks whether the black box is back on,
+# where it was turned off at run time and no other leg is due
+OFF_S = 1.0
+# spans whose job is to wait: never a stall, never covering one.
+# loop_idle waits for work; http_import is the import's handler, which
+# waits for the applier's pool to create what the snapshot holds (1-57 s
+# in every benchmark run: 44 records in 36 runs filled the deque)
+EXEMPT = frozenset({"loop_idle", "http_import"})
+STACK_DEPTH = 12
+
+
+def _thread_status(native: int | None) -> dict:
+    """One thread's state letter and its context switches, from
+    /proc/self/task/<native>/status: ONE file, because every read lets
+    go of the GIL and the watch waits a switch interval to get it back
+    while the thread it watches works.  A host without the file leaves
+    them out of the record."""
+    out: dict = {}
+    try:
+        with open(f"/proc/self/task/{native}/status", encoding="ascii",
+                  errors="replace") as fh:
+            lines = fh.read().splitlines()
+    except OSError:
+        return out
+    for line in lines:
+        key, _, val = line.partition(":")
+        if key == "State":
+            out["state"] = val.split()[0]
+        elif key == "voluntary_ctxt_switches":
+            out["voluntary_switches"] = int(val)
+        elif key == "nonvoluntary_ctxt_switches":
+            out["involuntary_switches"] = int(val)
+    return out
+
+
+def _thread_cpu(ident: int) -> float | None:
+    """The CPU seconds of ANOTHER thread, read from outside through its
+    POSIX CPU-time clock; None where the platform has no such clock or
+    the thread is gone."""
+    try:
+        return time.clock_gettime(time.pthread_getcpuclockid(ident))
+    except (AttributeError, OSError, OverflowError):
+        return None
+
+
+def _stacks(first: int | None) -> list[dict]:
+    """The top frames of every thread but the caller's, the thread
+    `first` (an ident) first."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    frames = sys._current_frames()
+    frames.pop(threading.get_ident(), None)
+    out = []
+    for ident in sorted(frames, key=lambda i: i != first)[:32]:
+        lines, f = [], frames[ident]
+        while f is not None and len(lines) < STACK_DEPTH:
+            lines.append(f"{f.f_code.co_filename}:{f.f_lineno} "
+                         f"{f.f_code.co_name}")
+            f = f.f_back
+        out.append({"thread": names.get(ident, str(ident)),
+                    "frames": lines})
+    return out
+
+
+CAUSES = ("compile", "gc", "on_cpu", "process_stopped", "blocked", "unseen")
+
+
+def classify_stall(seconds: float, compile_s: float = 0.0,
+                   gc_s: float = 0.0, late_s: float = 0.0,
+                   since_s: float | None = None,
+                   cpu_since_s: float | None = None) -> str:
+    """A stall's cause: the first of compile, gc, on_cpu,
+    process_stopped whose seconds make up at least half of the stretch
+    they were read over; `blocked` where none does (the thread slept
+    while others ran: the stack taken while it stood names the frame).
+    compile_s, gc_s and late_s are read over the whole span;
+    cpu_since_s, the thread's CPU seconds, over the since_s since the
+    watch first saw the span stand.  Where the watch saw less than half
+    of the span (or none of it), working cannot be told from waiting:
+    no `on_cpu`, and what would have read `blocked` reads `unseen`."""
+    half = seconds / 2
+    seen = (cpu_since_s is not None and since_s is not None
+            and since_s >= half)
+    if compile_s >= half:
+        return "compile"
+    if gc_s >= half:
+        return "gc"
+    if seen and cpu_since_s >= since_s / 2:
+        return "on_cpu"
+    if late_s >= half:
+        return "process_stopped"
+    return "blocked" if seen else "unseen"
+
+
 class BlackBox:
     """The event ring + bundle builder.  One instance per process
     (`BLACKBOX`); events carry the recording thread's tracer session
@@ -184,6 +295,21 @@ class BlackBox:
         # round trip so a dump never aliases live engine state
         self._dumps: deque = deque(maxlen=8)
         self._dump_n = 0  # filename uniquifier, allocated under _mu
+        # stall bundles, in a deque of their own: a served cycle stores
+        # nothing here, so the evidence of a stall outlives the rings
+        self._stalls: deque = deque(maxlen=32)
+        # the watch's notes on spans it has seen standing, by span id,
+        # and its own late ticks: (perf_counter, seconds late, stacks)
+        self._notes: dict[int, dict] = {}
+        self._late: deque = deque(maxlen=256)
+        # when the watch's running wait is due to end (perf_counter):
+        # a span that closes right after the process was let run again
+        # beats the watch to it, and reads the tick's lateness here
+        self.watch_due: float | None = None
+        # False in a server whose engine lives in another process
+        # (DeviceTelemetry.start(device=False)): a bundle made here must
+        # not touch the backend
+        self.owns_device = True
 
     # ---------------------------------------------------------- record
 
@@ -243,11 +369,16 @@ class BlackBox:
     # ------------------------------------------------------------ dump
 
     def bundle(self, reason: str, cause: BaseException | None = None,
-               session: str | None = None, device: bool = True) -> dict:
+               session: str | None = None,
+               device: bool | None = None) -> dict:
         """Build (but do not store) a post-mortem bundle.  device=False
-        (a server whose engine lives in another process) records
-        NO_DEVICE instead of touching the backend."""
+        (a server whose engine lives in another process; None: what
+        `owns_device` says) records NO_DEVICE instead of touching the
+        backend."""
         from .faults import current_plan
+
+        if device is None:
+            device = self.owns_device
 
         plan = current_plan()
         # open spans AT THE TIME OF FAULT: the tracer stashes the
@@ -291,9 +422,11 @@ class BlackBox:
 
     def dump(self, reason: str, cause: BaseException | None = None,
              session: str | None = None, write: bool = False,
-             directory: str | None = None) -> tuple[dict, str | None]:
-        """Snapshot a bundle, store it in the recent-dumps ring, and —
-        when `write` and a directory is available (`directory` arg or
+             directory: str | None = None,
+             stall: dict | None = None) -> tuple[dict, str | None]:
+        """Snapshot a bundle, store it in the recent-dumps ring (one
+        with a `stall` record: in the stalls' own), and — when `write`
+        and a directory is available (`directory` arg or
         KSS_TPU_BLACKBOX_DIR) — persist it to disk.  Returns
         (bundle, path-or-None).  Never raises: a failing dump must not
         mask the fault it describes."""
@@ -303,6 +436,8 @@ class BlackBox:
             doc = {"version": DUMP_VERSION, "reason": reason,
                    "time": time.time(), "session": session,
                    "error": f"bundle failed: {type(e).__name__}: {e}"[:300]}
+        if stall is not None:
+            doc["stall"] = stall
         path = None
         if write:
             d = directory or os.environ.get("KSS_TPU_BLACKBOX_DIR")
@@ -327,7 +462,7 @@ class BlackBox:
                     path = None
         doc["path"] = path
         with self._mu:
-            self._dumps.append(doc)
+            (self._dumps if stall is None else self._stalls).append(doc)
         TRACER.inc("blackbox_dumps_total", reason=reason)
         return doc, path
 
@@ -344,6 +479,199 @@ class BlackBox:
         with self._mu:
             return self._dumps[-1] if self._dumps else None
 
+    # ---------------------------------------------------------- stalls
+
+    def watch_tick(self, late: float = 0.0) -> None:
+        """One tick of the watch on open spans (DeviceTelemetry's
+        thread, every WATCH_S).  `late`: by how much the tick's wait
+        overran.  A span first seen WATCH_S old gets the baseline its
+        readings start from (its thread's CPU clock, read from outside);
+        one seen STALL_S old is noted once, while it still stands: every
+        thread's stack, its own thread's first."""
+        self.watch_due = None
+        if not _ENABLED:
+            return
+        TRACER.count("watchdog_ticks_total")
+        if late > LATE_S:
+            TRACER.count("process_late_seconds_total", late - LATE_S)
+            # a whole tick missed: the stacks right after name the
+            # threads (the one that held the GIL is still at its call)
+            stacks = _stacks(None) if late >= WATCH_S else None
+            with self._mu:
+                self._late.append((time.perf_counter(), late, stacks))
+        standing = [sp for sp in TRACER.open_since(WATCH_S)
+                    if sp["name"] not in EXEMPT]
+        with self._mu:
+            for gone in self._notes.keys() - {sp["span_id"]
+                                              for sp in standing}:
+                del self._notes[gone]
+            notes = {sp["span_id"]: self._notes.get(sp["span_id"])
+                     for sp in standing}
+        if not standing:
+            return
+        natives = {t.ident: t.native_id for t in threading.enumerate()}
+        status: dict = {}  # by thread: nested spans come of age together
+        for sp in standing:
+            note = notes[sp["span_id"]]
+            native = natives.get(sp["ident"])
+            if note is None:
+                # the clocks first and the note kept at once: a span
+                # that closes during the file read finds its baseline
+                # (only of a thread that is still there: the clock's id
+                # is made from the thread's own memory)
+                note = {"t": time.perf_counter(),
+                        "cpu": (_thread_cpu(sp["ident"])
+                                if native is not None else None),
+                        "process_cpu": time.process_time(), "status": {}}
+                with self._mu:
+                    self._notes[sp["span_id"]] = note
+                if native not in status:
+                    status[native] = _thread_status(native)
+                note["status"] = status[native]
+            age = time.perf_counter() - sp["t0_perf"]
+            if "stacks" not in note and age >= _tracing.STALL_S:
+                note["noted_after_s"] = round(age, 6)
+                note["state"] = _thread_status(native).get("state")
+                note["stacks"] = _stacks(sp["ident"])
+
+    def _subtree(self, event: dict) -> tuple[dict, float, dict, bool]:
+        """The span's descendants that the tracer still holds, summed by
+        name (count, seconds); the seconds of it that descendants which
+        stood for STALL_S themselves cover (the topmost ones: nothing
+        twice); those descendants, all of them, {id: name}; and whether
+        the ring rolled over inside the span (the sums then lack the
+        early spans shorter than tracing.LONG_S)."""
+        held, rolled = TRACER.subtree_events(event["ts"])
+        kids: dict[int, list[dict]] = {}
+        for ev in held:
+            if ev.get("parent_id") is not None:
+                kids.setdefault(ev["parent_id"], []).append(ev)
+        by_name: dict[str, dict] = {}
+        covered_s, stalled = 0.0, {}
+        todo = [(event["span_id"], False)]
+        while todo:
+            parent, covered = todo.pop()
+            for ev in kids.get(parent, ()):
+                a = by_name.setdefault(
+                    ev["name"], {"count": 0, "seconds": 0.0})
+                a["count"] += 1
+                a["seconds"] += ev["seconds"]
+                stood = (ev["seconds"] >= _tracing.STALL_S
+                         and ev["name"] not in EXEMPT)
+                if stood:
+                    stalled[ev["span_id"]] = ev["name"]
+                    if not covered:
+                        covered_s += ev["seconds"]
+                todo.append((ev["span_id"], covered or stood))
+        for a in by_name.values():
+            a["seconds"] = round(a["seconds"], 6)
+        return by_name, covered_s, stalled, rolled
+
+    def on_stall(self, event: dict) -> None:
+        """The tracer's hook: a span closed after STALL_S or more, on
+        this thread.  Keeps the record (a bundle of reason `stall`,
+        one line on stderr, the counters), or, where a stalled
+        descendant covers half of the span, names the span as that
+        stall's ancestor.  Never raises into the span's caller."""
+        if not _ENABLED or event["name"] in EXEMPT:
+            return
+        try:
+            self._keep_stall(event)
+        except Exception:  # the evidence failed, not the pass
+            print(f"kss-tpu stall: span={event['name']}: the record failed",
+                  file=sys.stderr)
+            traceback.print_exc()
+
+    def _keep_stall(self, event: dict) -> None:
+        from . import hostevents
+
+        # this thread's clocks first: what follows is not the span's
+        now, cpu_now = time.perf_counter(), time.thread_time()
+        name, seconds = event["name"], event["seconds"]
+        with self._mu:
+            note = self._notes.pop(event["span_id"], None)
+            late = [m for m in self._late if m[0] >= now - seconds]
+        by_name, covered_s, stalled, rolled = self._subtree(event)
+        record = {
+            "span": name, "span_id": event["span_id"], "ts": event["ts"],
+            "seconds": round(seconds, 6),
+            "session": event.get("session"),
+            "trace_id": event.get("trace_id"),
+            "ancestors": TRACER.open_chain(event.get("parent_id")),
+            "descendants": by_name, "short_descendants_lost": rolled,
+        }
+        if covered_s >= seconds / 2:
+            # no stall of its own: the records of the stalls under it
+            # name it as their ancestor
+            with self._mu:
+                kept = [doc["stall"] for doc in self._stalls
+                        if doc["stall"]["span_id"] in stalled]
+                for rec in kept:
+                    rec["ancestors_closed"].append(record)
+            print(f"kss-tpu stall: span={name} seconds={seconds:.3f} "
+                  "ancestor of "
+                  + ",".join(sorted({rec["span"] for rec in kept})),
+                  file=sys.stderr, flush=True)
+            return
+        t0 = now - seconds
+        due = self.watch_due
+        overdue = now - due if due is not None and now - due > LATE_S else 0.0
+        readings = {
+            "compile_s": round(hostevents.compile_seconds_since(t0), 6),
+            "gc_s": round(hostevents.gc_seconds_since(t0), 6),
+            # the late ticks inside the span, and the one that is late
+            # right now and has not woken yet
+            "late_s": round(sum((m[1] for m in late), overdue), 6),
+        }
+        if note is not None:
+            # the watch saw it standing: what has grown since
+            readings["since_s"] = round(now - note["t"], 6)
+            if note["cpu"] is not None:
+                readings["cpu_since_s"] = round(cpu_now - note["cpu"], 6)
+            readings["process_cpu_since_s"] = round(
+                time.process_time() - note["process_cpu"], 6)
+            after = _thread_status(threading.get_native_id())
+            readings.update(
+                (k, after[k] - v) for k, v in note["status"].items()
+                if k != "state" and k in after)
+            # the thread's state letter as the watch read it, not now
+            readings["state"] = note.get("state")
+            record["noted_after_s"] = note.get("noted_after_s")
+            record["stacks"] = note.get("stacks", [])
+        record["late_stacks"] = [m[2] for m in late if m[2]][-2:]
+        record["readings"] = readings
+        record["cause"] = cause = classify_stall(
+            seconds, readings["compile_s"], readings["gc_s"],
+            readings["late_s"], readings.get("since_s"),
+            readings.get("cpu_since_s"))
+        record["ancestors_closed"] = []
+        TRACER.inc("span_stalls_total", span=name, cause=cause)
+        TRACER.count("span_stall_seconds_total", seconds)
+        _, path = self.dump("stall", session=event.get("session"),
+                            write=True, stall=record)
+        print(f"kss-tpu stall: span={name} seconds={seconds:.3f} "
+              f"cause={cause} "
+              + " ".join(f"{k}={readings[k]}" for k in
+                         ("cpu_since_s", "since_s", "compile_s", "gc_s",
+                          "late_s") if k in readings)
+              + f" session={event.get('session')} ancestors="
+              + ",".join(record["ancestors"]) + f" path={path}",
+              file=sys.stderr, flush=True)
+
+    def stalls(self, session: str | None = None) -> list[dict]:
+        """The kept stall records, oldest first (each the `stall` of its
+        bundle, with the bundle's time and path); the bundles themselves
+        are stall_dumps()."""
+        with self._mu:
+            docs = list(self._stalls)
+        return [{**d["stall"], "time": d.get("time"), "path": d.get("path")}
+                for d in docs
+                if session is None or d.get("session") == session]
+
+    def stall_dumps(self) -> list[dict]:
+        with self._mu:
+            return list(self._stalls)
+
     def drop_session(self, session: str | None) -> None:
         """Release a torn-down session's counter baseline (session
         eviction calls this — per-session state must not outlive the
@@ -356,11 +684,16 @@ class BlackBox:
         with self._mu:
             self._ring.clear()
             self._dumps.clear()
+            self._stalls.clear()
+            self._notes.clear()
+            self._late.clear()
+            self.watch_due = None
             self._baselines.clear()
             self._dropped = 0
 
 
 BLACKBOX = BlackBox()
+TRACER.set_stall_hook(BLACKBOX.on_stall)
 
 
 # ------------------------------------------------------- dump validation
@@ -735,17 +1068,23 @@ class DeviceTelemetry:
         device=False — the caller runs no engine, and sampling would
         claim the chip from the process that does; the same thread
         also feeds the telemetry history ring every
-        KSS_TPU_HISTORY_SAMPLE_S seconds (utils/history.py) — two
+        KSS_TPU_HISTORY_SAMPLE_S seconds (utils/history.py) and, every
+        WATCH_S, runs the black box's watch on open spans — three
         cadences, one thread, each with its own next-due clock.  No
-        thread starts when both legs are off.  The whole start decision
+        thread starts when all three legs are off.  The whole start decision
         runs under the lock so two concurrent start() calls can never
         spawn two samplers, and a fresh stop event per thread means a
         racing stop() never leaves a newly started sampler dead."""
+        BLACKBOX.owns_device = device
         if not device:
             interval = 0.0
         elif interval is None:
             interval = env_float("KSS_TPU_HBM_SAMPLE_S", 5.0)
         hist_iv = _history.sample_interval() if _history.enabled() else 0.0
+        if _ENABLED:
+            # 0.0 is a reading; a program without the watch lists neither
+            TRACER.count("process_late_seconds_total", 0)
+            TRACER.count("span_stall_seconds_total", 0)
         t = None
         with self._mu:
             self._refs += 1
@@ -753,7 +1092,7 @@ class DeviceTelemetry:
             # only by the last stop()): an is_alive() check would let a
             # second caller slip in between thread creation and start()
             if self._thread is None:
-                if interval > 0 or hist_iv > 0:
+                if interval > 0 or hist_iv > 0 or _ENABLED:
                     stop = self._stop = threading.Event()
 
                     def loop():
@@ -764,12 +1103,29 @@ class DeviceTelemetry:
                         next_hbm = now + hbm_iv
                         next_hist = now + h_iv
                         while True:
-                            wake = min(next_hbm, next_hist)
-                            if stop.wait(max(wake - time.monotonic(),
-                                             0.01)):
+                            # the third cadence: the watch on open spans
+                            # (BlackBox.watch_tick), off with the black
+                            # box.  Every wake is a tick of it, and how
+                            # far the WAIT overran is its lateness: the
+                            # legs' own work below is not in it
+                            # (turned off at run time with no other
+                            # leg on, it looks again every OFF_S:
+                            # wait() takes no infinite timeout)
+                            watch_iv = WATCH_S if _ENABLED else OFF_S
+                            t_wait = time.monotonic()
+                            timeout = max(min(next_hbm, next_hist,
+                                              t_wait + watch_iv) - t_wait,
+                                          0.01)
+                            BLACKBOX.watch_due = (time.perf_counter()
+                                                   + timeout)
+                            if stop.wait(timeout):
+                                BLACKBOX.watch_due = None
                                 return
                             now = time.monotonic()
-                            # one span a tick: a rhythm in the served
+                            BLACKBOX.watch_tick(now - t_wait - timeout)
+                            if now < next_hbm and now < next_hist:
+                                continue
+                            # one span a sample: a rhythm in the served
                             # latency can be laid beside this thread's
                             with TRACER.span("telemetry_sample"):
                                 if now >= next_hbm:

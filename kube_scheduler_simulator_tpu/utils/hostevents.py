@@ -16,6 +16,11 @@ function, so the listener runs on that thread: the tracer's span stack
 names WHERE the compile happened (the `span` label), and a per-thread
 running total lets a caller bracket one call (`thread_compile_seconds`:
 framework/replay.py times a cached scan's first call with it).
+
+Both also keep their last few thousand events as (end, seconds) marks
+on time.perf_counter's clock, so that a stall record can ask AFTER the
+fact what fell inside a span (`compile_seconds_since`,
+`gc_seconds_since`; utils/blackbox.py).
 """
 
 from __future__ import annotations
@@ -52,6 +57,35 @@ def thread_compile_seconds() -> float:
     return getattr(_tls, "seconds", 0.0)
 
 
+def _seconds_since(marks, t0: float) -> float:
+    """The seconds of the marks that ended after t0 (an event that
+    straddles t0 counts whole)."""
+    while True:
+        try:
+            held = tuple(marks)
+            break
+        except RuntimeError:
+            # mutated during iteration: the GC callback appends inside
+            # any allocation, this tuple's own included
+            continue
+    total = 0.0
+    for t_end, seconds in reversed(held):
+        if t_end <= t0:
+            break
+        total += seconds
+    return total
+
+
+def compile_seconds_since(t0: float) -> float:
+    """thread_compile_seconds()'s growth since perf_counter time t0."""
+    return _seconds_since(getattr(_tls, "marks", ()), t0)
+
+
+def gc_seconds_since(t0: float) -> float:
+    """Seconds inside garbage collections, on any thread, since t0."""
+    return _seconds_since(_gc_marks, t0)
+
+
 def _fun_label(fun_name) -> str:
     name = str(fun_name or "unknown")[:80]
     with _lock:
@@ -68,6 +102,10 @@ def _on_duration(event: str, duration_secs: float, **kw) -> None:
     if stage is None:
         return
     _tls.seconds = getattr(_tls, "seconds", 0.0) + duration_secs
+    marks = getattr(_tls, "marks", None)
+    if marks is None:
+        marks = _tls.marks = deque(maxlen=4096)
+    marks.append((time.perf_counter(), duration_secs))
     TRACER.inc("jax_compile_seconds_total", duration_secs, stage=stage)
     TRACER.inc("jax_compile_events_total", stage=stage)
     if stage == "backend_compile":
@@ -92,6 +130,7 @@ def _on_event(event: str, **kw) -> None:
 _gc_seen = [[0.0, 0] for _ in range(3)]
 _gc_given = [[0.0, 0] for _ in range(3)]
 _gc_full: deque = deque(maxlen=1024)
+_gc_marks: deque = deque(maxlen=4096)
 _gc_t0 = 0.0
 _gc_annotation = None
 
@@ -112,6 +151,7 @@ def _on_gc(phase: str, info: dict) -> None:
     seen = _gc_seen[generation]
     seen[0] += seconds
     seen[1] += 1
+    _gc_marks.append((_gc_t0 + seconds, seconds))
     if generation == 2:
         _gc_full.append((_gc_t0, seconds))
 

@@ -49,6 +49,17 @@ _PREFIX = "kss_tpu"
 # reports no open spans, keeping the KSS_TPU_BLACKBOX=0 A/B honest
 BLACKBOX_OPEN_SPANS = os.environ.get("KSS_TPU_BLACKBOX", "1") != "0"
 
+# a span that stood open this long is a STALL: half of the autopilot's
+# 2 s pass target, the number a stall is measured against.  span() makes
+# the one comparison when it closes; the black box, which registers the
+# hook, keeps the record (docs/metrics.md "Waiting and working")
+STALL_S = 1.0
+# spans this long are kept a second time, in a small ring of their own:
+# the watch streams' thousands of short spans roll the main ring over
+# within a session's first pass, and a stall record sums a span's
+# subtree from what is still held (Tracer.subtree_events)
+LONG_S = 0.01
+
 
 class ProfileStateError(RuntimeError):
     """Invalid XLA-profile state transition (double start, stop without
@@ -179,8 +190,24 @@ _HELP: dict[str, str] = {
         "away shows up here (utils/tracing.py).",
     "blackbox_dumps_total":
         "Post-mortem bundles snapshotted by the wave black box, by "
-        "reason (wave_abort, degradation, chaos_failure, request; "
+        "reason (wave_abort, degradation, chaos_failure, request, stall; "
         "docs/metrics.md post-mortem dumps).",
+    "span_stalls_total":
+        "Spans that closed after STALL_S (1 s) or more and were no "
+        "stalled descendant's ancestor, by span and by cause (compile, "
+        "gc, on_cpu, process_stopped, blocked, unseen): each is kept as "
+        "a bundle of reason stall (docs/metrics.md \"Waiting and "
+        "working\").",
+    "span_stall_seconds_total":
+        "Seconds of the spans span_stalls_total counts; 0 from the "
+        "server's start, so absence says a program without the watch.",
+    "process_late_seconds_total":
+        "Seconds by which the watch's ticks woke later than 50 ms past "
+        "their time: time in which no Python thread of the process got "
+        "to run (a C call held the GIL, or the process was off the CPU).",
+    "watchdog_ticks_total":
+        "Ticks of the black box's watch on open spans (every 0.25 s on "
+        "the telemetry thread).",
     "hbm_bytes_in_use":
         "Device memory currently in use per local device (device "
         "label) and summed across devices (unlabeled), sampled from "
@@ -454,6 +481,7 @@ class Tracer:
             capacity = max(64, env_int("KSS_TPU_TRACER_CAPACITY", 4096))
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=capacity)
+        self._long: deque = deque(maxlen=1024)
         self._agg: dict[str, dict] = {}
         self._counters: dict[str, float] = {}
         # gauges: absolute values set by gauge() (current device-retained
@@ -483,6 +511,10 @@ class Tracer:
         # callback can fire inside any allocation, this lock held —
         # accumulate on their own and hand their totals over here
         self._collectors: list = []
+        # called with the event of every span that closes at STALL_S or
+        # more, on the span's own thread and outside the lock: the black
+        # box registers it (blackbox imports tracing, never the reverse)
+        self._stall_hook = None
         self._epoch = time.time()
         self._perf_epoch = time.perf_counter()
         self._ids = itertools.count(1)
@@ -648,6 +680,8 @@ class Tracer:
             self._counters["tracer_events_dropped_total"] = \
                 self._counters.get("tracer_events_dropped_total", 0) + 1
         self._events.append(event)
+        if event["seconds"] >= LONG_S:
+            self._long.append(event)
         aggs = [self._agg]
         if session is not None:
             aggs.append(self._sagg.setdefault(session, {}))
@@ -664,7 +698,11 @@ class Tracer:
         implicitly, `parent=` parents explicitly across threads (the
         commit worker parents its chunk spans under the wave's replay
         span).  Yields a Span whose .id other threads may use and whose
-        .seconds is set on exit."""
+        .seconds is set on exit.  One clock, perf_counter: a span that
+        stands for STALL_S gets its thread's CPU seconds from the black
+        box's watch, which reads that clock from outside (a read of
+        time.thread_time() here costs 6 us on the benchmark's host and
+        is wrong below ~50 ms there; PERF.md section 6, PR 48)."""
         st = self._stack()
         sp = Span(next(self._ids),
                   parent if parent is not None else (st[-1].id if st else None),
@@ -682,7 +720,8 @@ class Tracer:
                 self._open[sp.id] = {
                     "name": name, "span_id": sp.id,
                     "parent_id": sp.parent_id,
-                    "tid": self._tid(), "t0": time.time(),
+                    "tid": self._tid(), "t0": time.time(), "t0_perf": t0,
+                    "ident": threading.get_ident(),
                     **({"session": session} if session is not None else {}),
                     **({"trace_id": trace_id} if trace_id is not None
                        else {}),
@@ -715,12 +754,15 @@ class Tracer:
             st.pop()
             with self._lock:
                 self._open.pop(sp.id, None)
-                self._record_locked({
+                event = {
                     "name": name, "t": time.time(), "seconds": dt,
                     "ts": round(t0 - self._perf_epoch, 6),
                     "span_id": sp.id, "parent_id": sp.parent_id,
                     "tid": self._tid(), **attrs,
-                }, session)
+                }
+                self._record_locked(event, session)
+            if dt >= STALL_S and self._stall_hook is not None:
+                self._stall_hook(event)
 
     # ---------------------------------------------------------- counters
 
@@ -765,7 +807,32 @@ class Tracer:
         spans.sort(key=lambda s: s["t0"])
         for s in spans:
             s["seconds_so_far"] = round(max(now - s.pop("t0"), 0.0), 6)
+            del s["t0_perf"], s["ident"]
         return spans
+
+    def open_since(self, min_age: float) -> list[dict]:
+        """The open spans at least `min_age` seconds old, as they are kept
+        (t0_perf on time.perf_counter's clock, ident of the thread): the
+        black box's watch asks every quarter of a second, and with
+        nothing old this is one lock hold over a handful of entries."""
+        born_by = time.perf_counter() - min_age
+        with self._lock:
+            return [dict(v) for v in self._open.values()
+                    if v["t0_perf"] <= born_by]
+
+    def open_chain(self, span_id: int | None) -> list[str]:
+        """The names of the open span `span_id` and of its open
+        ancestors, nearest first (a closed span's parent_id names the
+        chain it closed under)."""
+        names = []
+        with self._lock:
+            while span_id is not None and span_id in self._open:
+                names.append(self._open[span_id]["name"])
+                span_id = self._open[span_id]["parent_id"]
+        return names
+
+    def set_stall_hook(self, fn) -> None:
+        self._stall_hook = fn
 
     def dropped_events(self) -> float:
         """Spans evicted from the full ring so far
@@ -874,42 +941,17 @@ class Tracer:
             evs = list(self._events)
         return evs[-limit:]
 
-    # the span names that bound the wave's device window (the replay /
-    # speculative stream holds the device scan) vs its host-side work
-    # (commit, decode, fetch, compile).  commit_stream runs on the
-    # worker DURING the device window — the overlap counter quantifies
-    # how much of the host total was hidden inside it.
-    _DEVICE_WINDOW_SPANS = ("replay_and_decode_stream", "device_replay")
-    _HOST_SPANS = ("compile_workload", "commit_and_reflect",
-                   "commit_stream", "decode_chunk", "decode_lazy",
-                   "d2h_fetch")
-
-    def time_split(self, session: str | None = None) -> dict:
-        """Per-wave device-window vs host-time split, derived from the
-        span aggregates (docs/metrics.md device telemetry): total
-        seconds inside the device-replay window, total host-side
-        commit/decode/compile seconds, the overlapped share (commit
-        work hidden inside the replay window), and the wave count to
-        amortize by.  Cumulative since the last reset; session=<id>
-        reads the per-session aggregates."""
+    def subtree_events(self, since_ts: float) -> tuple[list[dict], bool]:
+        """Every held event that started at `since_ts` (the tracer's
+        clock) or later, from the ring and from the long spans' ring,
+        each once; and whether the main ring has rolled past `since_ts`
+        (short spans from before its oldest event are then lost)."""
         with self._lock:
-            agg = self._sagg.get(session, {}) if session is not None \
-                else self._agg
-            device = sum(agg[n]["total_seconds"]
-                         for n in self._DEVICE_WINDOW_SPANS if n in agg)
-            host = sum(agg[n]["total_seconds"]
-                       for n in self._HOST_SPANS if n in agg)
-            waves = sum(agg[n]["count"]
-                        for n in self._DEVICE_WINDOW_SPANS if n in agg)
-            cnt = (self._scounters.get(session, {}) if session is not None
-                   else self._counters)
-            overlap = cnt.get("commit_stream_overlap_seconds", 0.0)
-        return {
-            "device_window_seconds": round(device, 6),
-            "host_seconds": round(host, 6),
-            "overlapped_seconds": round(float(overlap), 6),
-            "waves": waves,
-        }
+            held = {ev["span_id"]: ev for ev in self._long}
+            held.update((ev["span_id"], ev) for ev in self._events)
+            rolled = (len(self._events) == self._events.maxlen
+                      and self._events[0]["ts"] > since_ts)
+        return [ev for ev in held.values() if ev["ts"] >= since_ts], rolled
 
     def summary(self) -> dict:
         """Back-compat aggregate view: span aggregates + plain counters
@@ -975,7 +1017,6 @@ class Tracer:
                         if any(skey in key for key in series)
                     },
                 }
-            out["time_split"] = self.time_split(session)
             return out
         out = self.summary()
         with self._lock:
@@ -1002,7 +1043,6 @@ class Tracer:
                 }
                 for name, series in sorted(self._hists.items())
             }
-        out["time_split"] = self.time_split()
         return out
 
     # ------------------------------------------------------- prometheus
@@ -1168,6 +1208,7 @@ class Tracer:
     def reset(self) -> None:
         with self._lock:
             self._events.clear()
+            self._long.clear()
             self._agg.clear()
             self._counters.clear()
             self._gauges.clear()

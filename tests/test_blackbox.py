@@ -325,7 +325,8 @@ def test_live_metrics_route_validates_after_full_and_faulted_waves(server):
     assert fams["kss_tpu_hbm_stats_available"]["samples"][0][2] == "0"
     snap = _get(server, "/api/v1/metrics")
     assert snap["gauges"].get("hbm_stats_available") == 0
-    assert "time_split" in snap
+    # the old device/host "time_split" is gone; the pass is the wave span
+    assert "time_split" not in snap and snap["spans"]["wave"]["count"] >= 1
 
     # fault-injected wave through the same live engine
     plan = faults.FaultPlan(

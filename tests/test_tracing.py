@@ -2,12 +2,18 @@
 exposition, engine instrumentation, HTTP endpoints."""
 
 import json
+import threading
+import time
 import urllib.request
+
+import pytest
 
 from kube_scheduler_simulator_tpu.cluster.store import ObjectStore
 from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
 from kube_scheduler_simulator_tpu.models.workloads import make_nodes, make_pods
-from kube_scheduler_simulator_tpu.utils.tracing import TRACER, Tracer
+from kube_scheduler_simulator_tpu.utils import tracing
+from kube_scheduler_simulator_tpu.utils.tracing import (
+    TRACER, Tracer, validate_exposition)
 
 
 def test_tracer_spans_and_counters():
@@ -78,22 +84,127 @@ def test_gauge_session_scope_and_labels():
     assert len(fams["kss_tpu_labeled_g"]["samples"]) == 2
 
 
-def test_open_spans_and_time_split():
+def test_open_spans_listed_while_open():
+    """What is open is listed while it is open, without the fields the
+    watch keeps for itself; the old time_split (six spans' wall seconds
+    summed as "host", the replay span called the "device window") is
+    gone from the snapshot."""
     t = Tracer()
-    with t.span("replay_and_decode_stream"):
+    with t.span("wave"):
         with t.span("inner"):
             open_now = t.open_spans()
     names = [s["name"] for s in open_now]
-    assert names == ["replay_and_decode_stream", "inner"]
+    assert names == ["wave", "inner"]
     assert all(s["seconds_so_far"] >= 0 for s in open_now)
+    assert set(open_now[0]) == {"name", "span_id", "parent_id", "tid",
+                                "seconds_so_far"}
     assert t.open_spans() == []
-    with t.span("commit_and_reflect"):
-        pass
-    split = t.time_split()
-    assert split["waves"] == 1
-    assert split["device_window_seconds"] >= 0
-    assert split["host_seconds"] >= 0
-    assert "time_split" in t.snapshot()
+    snap = t.snapshot()
+    assert snap["spans"]["wave"]["count"] == 1
+    # one clock a span: no CPU reading rides the event or the aggregate
+    assert "cpu_seconds" not in snap["spans"]["wave"]
+    assert "cpu" not in t.events()[-1]
+    assert "time_split" not in snap
+    assert "span_cpu_seconds_total" not in snap["labeled_counters"]
+    validate_exposition(t.prometheus_text())
+
+
+@pytest.mark.parametrize("stand_s, hooked", [(0.0, False), (0.06, True)])
+def test_stall_hook_gets_the_spans_that_stood(monkeypatch, stand_s, hooked):
+    """span() makes one comparison when it closes: a span that stood
+    for STALL_S is handed to the hook, with its event as the ring has
+    it, outside the tracer's lock; a stretch timed elsewhere
+    (record_span) never is."""
+    monkeypatch.setattr(tracing, "STALL_S", 0.05)
+    t = Tracer()
+    got = []
+
+    def hook(event):
+        assert t._lock.acquire(blocking=False), "called under the lock"
+        t._lock.release()
+        got.append(event)
+
+    t.set_stall_hook(hook)
+    with t.session_scope("sa"), t.span("outer"):
+        with t.span("standing", pods=3) as sp:
+            time.sleep(stand_s)
+    t.record_span("elsewhere", time.perf_counter() - 1.0, 1.0)
+    if not hooked:
+        assert got == []
+        return
+    [ev] = [e for e in got if e["name"] == "standing"]
+    assert ev is t.events()[-3] and ev["span_id"] == sp.id
+    assert ev["seconds"] >= stand_s and ev["session"] == "sa"
+    assert ev["pods"] == 3
+    assert [e["name"] for e in got] == ["standing", "outer"]
+
+
+def test_open_since_and_open_chain():
+    """The watch's view of what is open: only spans of an age, with the
+    thread and the perf_counter start the public list leaves out; and a
+    closed span's ancestors by name, nearest first."""
+    t = Tracer()
+    with t.span("loop_pass"):
+        with t.span("wave") as wave:
+            time.sleep(0.03)
+            with t.span("young") as young:
+                old = t.open_since(0.02)
+                assert [s["name"] for s in old] == ["loop_pass", "wave"]
+                assert old[1]["ident"] == threading.get_ident()
+                assert old[1]["t0_perf"] <= time.perf_counter() - 0.02
+                assert t.open_since(60.0) == []
+                assert t.open_chain(young.parent_id) == ["wave", "loop_pass"]
+            assert t.open_chain(young.id) == []  # closed: in no chain
+    assert t.open_chain(wave.id) == [] and t.open_chain(None) == []
+
+
+def test_long_spans_are_held_past_the_ring():
+    """Spans of LONG_S and more are kept a second time: subtree_events
+    still returns them after short spans have rolled the main ring
+    over, each held event once, and says that the ring rolled."""
+    t = Tracer(capacity=64)
+    with t.span("first_pass") as top:
+        since = round(time.perf_counter() - t._perf_epoch, 6) - 1e-3
+        with t.span("tiny"):
+            pass
+        with t.span("slow_build"):
+            time.sleep(2 * tracing.LONG_S)
+        held, rolled = t.subtree_events(since)
+        assert not rolled
+        assert sorted(e["name"] for e in held) == ["slow_build", "tiny"]
+        for _ in range(70):
+            with t.span("pump", parent=0):
+                pass
+        held, rolled = t.subtree_events(since)
+    assert rolled
+    names = [e["name"] for e in held]
+    assert names.count("slow_build") == 1 and "tiny" not in names
+    assert [e for e in held if e["name"] == "slow_build"][0][
+        "parent_id"] == top.id
+    t.reset()
+    assert t.subtree_events(0.0) == ([], False)
+
+
+def test_first_spans_of_two_threads_take_two_tids(monkeypatch):
+    """With the black box off a thread's first _tid() call is the one
+    at its first span's close: it is made under the tracer's lock, so
+    threads that close their first spans together get ids of their
+    own."""
+    monkeypatch.setattr(tracing, "BLACKBOX_OPEN_SPANS", False)
+    t = Tracer()
+    go = threading.Barrier(8)
+
+    def first_span():
+        go.wait()
+        with t.span("first"):
+            pass
+
+    threads = [threading.Thread(target=first_span) for _ in range(8)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert sorted(e["tid"] for e in t.events()) == list(range(1, 9))
 
 
 def test_engine_emits_spans_and_counts():
