@@ -10,8 +10,9 @@ The scan is chunked (default 512 pods per device call) for two reasons:
   * output tensors are [chunk, .., N]; chunking bounds device memory at
     ~chunk x plugins x nodes regardless of queue length;
   * per-chunk host copies overlap with later chunks' device compute
-    (dispatch is async and copy_to_host_async starts each D2H the moment
-    its chunk's results exist), pipelining transfer with TPU evaluate.
+    (dispatch is async; each chunk's blocking fetch runs on a pool thread
+    while the device runs the chunks after it), pipelining transfer with
+    TPU evaluate.
 
 Every result byte crosses the device->host link, so the scan emits
 pipeline.CompactOut instead of the full result tensors: filter codes pack to one int per node (the decoder
@@ -24,7 +25,9 @@ Device residency (docs/wave-pipeline.md device-residency stage): by
 default, when no streaming consumer decodes in-wave, even the compact
 tensors don't cross — the wave fetches only per-pod DECISION ROWS
 (selected / feasible_count / prefilter_reject / raw_overflow, plus the
-jit'd per-chunk attribution sums) and the heavy packed/raw arrays stay
+jit'd per-chunk attribution sums; over the packed route all of them laid
+into ONE buffer by the scan's executable and fetched in one transfer,
+_pack_row / _cut_row) and the heavy packed/raw arrays stay
 live in device memory, materializing per chunk on first cold read
 (_CompactChunks.host, memoized + exactly-once) with an LRU spill budget
 (KSS_TPU_DEVICE_RESULT_BUDGET_MB) bounding HBM across waves.  The
@@ -39,13 +42,14 @@ The last chunk is padded; padded steps carry `is_pad` and never bind
 from __future__ import annotations
 
 import copy
+import math
 import os
 import threading
 import time
 import weakref
 import zlib
 from collections import OrderedDict
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -987,12 +991,13 @@ def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, An
 #                     For a mesh (the leaves are sharded one by one) and
 #                     for the speculative rounds' fallback
 #   _packed_scan_for  (the pass's packed buffers, the leaves that are
-#                     device arrays already) -> (out, attribution sums):
-#                     the sequential scan of a pass of ONE chunk (every
-#                     served pass) of a workload that compile_workload
-#                     made.  The unpack, the carry and the attribution
-#                     reduction happen inside it; nothing is donated; its
-#                     key adds the layout
+#                     device arrays already) -> (the four heavy tensors,
+#                     the decision row): the sequential scan of a pass of
+#                     ONE chunk (every served pass) of a workload that
+#                     compile_workload made.  The unpack, the carry, the
+#                     attribution reduction and the row's packing happen
+#                     inside it; nothing is donated; its key adds the
+#                     layout
 
 
 class CompileQuarantined(RuntimeError):
@@ -1300,15 +1305,24 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
     """-> (the cached executable of cw's sequential scan as ONE chunk over
     cw.packed, its arguments).
 
-    scan_pass(bufs, rest) -> (out, att)
+    scan_pass(bufs, rest) -> (packed_filter, raw8, raw16, raw32, row)
       bufs    the pass's upload, a device buffer a dtype
       rest    the leaves that never were in it (a carried session's
               resident arrays, state/resident.py)
-      -> the chunk's compact output and the attribution sums (att_plan,
-      _att_plan; or None).  The carry is cut out of bufs and not handed
-      back (an output buffer costs the host what a dispatch does, and no
-      chunk follows); nothing is donated, so a second replay and the
-      width-tier rerun start from the same carry."""
+      -> the chunk's four heavy tensors, which stay on the device for the
+      cold read, and ONE int32 buffer that holds everything the pass reads
+      in-wave: the decision fields and every leaf of the attribution sums
+      (att_plan, _att_plan; or none), end to end (_pack_row).  Five output
+      buffers where the fields apiece were thirteen or fourteen: an output
+      buffer costs the host what a dispatch does, and a fetch of one a
+      round trip of ~0.4 ms whatever it carries.  The carry is cut out of
+      bufs and not handed back (no chunk follows); nothing is donated, so
+      a second replay and the width-tier rerun start from the same carry.
+
+    The executable's `row_layout` is what _cut_row cuts the fetched row
+    by.  It is static for an executable (chunk, n, att_plan and
+    CompactOut's dtypes are all in the key) and is worked out when the
+    executable is built, from the scan's abstract outputs."""
     packed = cw.packed
     leaves, treedef = jax.tree.flatten(packed.tree)
     # a leaf's place in the layout; None for one of `rest`
@@ -1318,6 +1332,7 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
     chunk = cw.n_pods
     key = (*_workload_scan_key(cw, chunk), unroll, "packed", pack_mode,
            score_dtypes, wide, layout, places, att_plan)
+    rest = [leaf for leaf in leaves if not isinstance(leaf, Packed)]
 
     def build():
         # nothing of `packed` but what is static: a cached closure must
@@ -1326,7 +1341,7 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
         n = cw.n_nodes
         picks = tuple(k for k in places if k is not None)
 
-        def scan_pass(bufs, rest):
+        def scan_fields(bufs, rest):
             cut, own = iter(unpack_leaves(layout, picks, bufs)), iter(rest)
             xs, carry, arg_statics, (fskip, sskip) = jax.tree.unflatten(
                 treedef, [next(own) if k is None else next(cut)
@@ -1343,10 +1358,95 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
                     out.feasible_count, fskip, sskip, np.int32(chunk))
             return out, att
 
-        return jax.jit(scan_pass)
+        def scan_pass(bufs, rest):
+            out, att = scan_fields(bufs, rest)
+            return (out.packed_filter, out.raw8, out.raw16, out.raw32,
+                    _pack_row(out, att))
 
-    rest = [leaf for leaf in leaves if not isinstance(leaf, Packed)]
+        scan = jax.jit(scan_pass)
+        # one more trace of the step (no compile, no device), once a key
+        scan.row_layout = _row_layout(*jax.eval_shape(
+            scan_fields, *jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                (packed.bufs, rest))))
+        return scan
+
     return _SCAN_CACHE.get_or_build(key, build), (packed.bufs, rest)
+
+
+_DECISION_FIELDS = ("selected", "feasible_count", "prefilter_reject",
+                    "raw_overflow")
+_HEAVY_FIELDS = ("packed_filter", "raw8", "raw16", "raw32")
+
+
+class _PackedOut(NamedTuple):
+    """What a fetch is handed for a chunk of the packed route: the four
+    heavy tensors as CompactOut names them (device arrays; ingest retains
+    them for the cold read) and the decision row with its layout."""
+
+    packed_filter: Any
+    raw8: Any
+    raw16: Any
+    raw32: Any
+    row: Any             # [words] int32, on the device
+    row_layout: tuple    # _row_layout
+
+
+def _row_fields(out, att) -> list:
+    """(key, value) for every field of a decision row in row order: the
+    decision fields, then the attribution sums' leaves as `att.<leaf>`,
+    sorted (the dict a trace builds and the one jax.eval_shape hands back
+    differ in order)."""
+    return ([(f, getattr(out, f)) for f in _DECISION_FIELDS]
+            + [(f"att.{k}", v) for k, v in sorted((att or {}).items())])
+
+
+def _row_layout(out, att) -> tuple:
+    """(key, dtype name, shape) for every field of a decision row, from
+    arrays, tracers or jax.eval_shape's structs."""
+    return tuple((k, str(np.dtype(v.dtype)), tuple(v.shape))
+                 for k, v in _row_fields(out, att))
+
+
+def _pack_row(out, att):
+    """Traced: the fields of _row_fields(out, att) end to end in one
+    int32 buffer, nothing rounded or dropped: an int32 field is its own
+    words, an int64 one two words an element, a narrower one
+    (raw_overflow's bools as 0/1 bytes, feas_packed's uint8s) is padded
+    to whole words and its bytes bitcast four to a word; lowest byte and
+    low word first, which is how _cut_row's ndarray.view reads them
+    back."""
+    words = []
+    for _key, a in _row_fields(out, att):
+        a = a.reshape(-1)
+        if a.dtype == jnp.bool_:
+            a = a.astype(jnp.uint8)
+        per = 4 // a.dtype.itemsize
+        if per > 1:
+            a = jnp.pad(a, (0, -a.size % per)).reshape(-1, per)
+        # a wider field (the filter counts are int64 under x64) comes
+        # out as [size, 2], low word first
+        words.append(jax.lax.bitcast_convert_type(a, jnp.int32).reshape(-1))
+    with jax.named_scope("kss_decision_row"):
+        return jnp.concatenate(words)
+
+
+def _cut_row(row: np.ndarray, row_layout: tuple) -> dict[str, Any]:
+    """A fetched decision row as _fetch_decisions hands a chunk on:
+    every field a view of the row under its own key, dtype and shape, the
+    attribution sums' leaves under "att" where the row holds any."""
+    raw = np.ascontiguousarray(row).view(np.uint8)
+    c: dict[str, Any] = {}
+    at = 0
+    for key, dtype, shape in row_layout:
+        nbytes = math.prod(shape) * np.dtype(dtype).itemsize
+        a = raw[at:at + nbytes].view(dtype).reshape(shape)
+        at += -(-nbytes // 4) * 4
+        if key.startswith("att."):
+            c.setdefault("att", {})[key[4:]] = a
+        else:
+            c[key] = a
+    return c
 
 
 def _fetch_chunk(out) -> dict[str, np.ndarray]:
@@ -1359,14 +1459,17 @@ def _fetch_chunk(out) -> dict[str, np.ndarray]:
     native codec walks raw pointers assuming C order — a strided buffer
     silently decodes neighboring pods' values."""
     fault_point("replay.decision_fetch")
+    if isinstance(out, _PackedOut):
+        c = {f: np.ascontiguousarray(np.asarray(getattr(out, f)))
+             for f in _HEAVY_FIELDS}
+        row = np.asarray(out.row)
+        c["_d2h_bytes"] = sum(a.nbytes for a in c.values()) + row.nbytes
+        c.update(_cut_row(row, out.row_layout))
+        return c
     c = {f: np.ascontiguousarray(np.asarray(getattr(out, f)))
          for f in out._fields}
     c["_d2h_bytes"] = sum(a.nbytes for a in c.values())
     return c
-
-
-_DECISION_FIELDS = ("selected", "feasible_count", "prefilter_reject",
-                    "raw_overflow")
 
 
 def _fetch_decisions(out, att) -> dict[str, np.ndarray]:
@@ -1375,15 +1478,30 @@ def _fetch_decisions(out, att) -> dict[str, np.ndarray]:
     plus the tiny on-device attribution sums — instead of the
     O(chunk x plugins x nodes) compact tensors, which stay live on
     device until a cold read materializes them (docs/wave-pipeline.md
-    device-residency stage)."""
+    device-residency stage).
+
+    Every device array pulled here is a blocking round trip of its own
+    (~0.4 ms on the chip's host whatever it carries), counted in
+    decision_fetch_transfers_total: ONE where the packed scan laid the
+    fields into a row (`out` is a _PackedOut; its attribution sums are in
+    the row and `att` is None), a field apiece over leaves."""
     fault_point("replay.decision_fetch")
+    if isinstance(out, _PackedOut):
+        row = np.asarray(out.row)
+        TRACER.count("decision_fetch_transfers_total")
+        c = _cut_row(row, out.row_layout)
+        c["_d2h_bytes"] = row.nbytes
+        return c
     c = {f: np.ascontiguousarray(np.asarray(getattr(out, f)))
          for f in _DECISION_FIELDS}
     nbytes = sum(a.nbytes for a in c.values())
+    transfers = len(c)
     if att is not None:
         att_host = {k: np.asarray(v) for k, v in att.items()}
         nbytes += sum(a.nbytes for a in att_host.values())
+        transfers += len(att_host)
         c["att"] = att_host
+    TRACER.count("decision_fetch_transfers_total", transfers)
     c["_d2h_bytes"] = nbytes
     return c
 
@@ -1703,8 +1821,9 @@ def _packed_dispatch(cw: CompiledWorkload, unroll: int, wide,
                      score_dtypes: tuple, score_cols: tuple):
     """The dispatch of a pass of one chunk over the buffers as
     compile_workload uploaded them: ONE call, of the executable
-    _packed_scan_for describes.  Same return as _leaves_dispatch; the
-    carry is in the buffers and stays there."""
+    _packed_scan_for describes.  Same return as _leaves_dispatch, but
+    that the chunk's output is a _PackedOut whose row holds the
+    attribution sums too; the carry is in the buffers and stays there."""
     att_plan = (_att_plan(cw, pack_mode, score_cols) if device_resident
                 else None)
     scan, args = _packed_scan_for(cw, unroll, pack_mode, score_dtypes, wide,
@@ -1712,7 +1831,7 @@ def _packed_dispatch(cw: CompiledWorkload, unroll: int, wide,
 
     def dispatch(carry, lo: int, hi: int):
         TRACER.count("pass_device_dispatches_total")
-        return (carry, *scan(*args))
+        return carry, _PackedOut(*scan(*args), scan.row_layout), None
 
     return dispatch, None
 

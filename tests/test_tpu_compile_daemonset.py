@@ -9,7 +9,8 @@ time.  A compile that passes is not a chip run: no result and no time is
 read.  Since PR 44 also the executable the cell actually calls: the scan
 over the pass's packed buffers (framework/replay.py _packed_scan_for),
 with every leaf cut out of its dtype's buffer, the carry with them, and
-the attribution reduction inside it.
+the attribution reduction inside it; since PR 49 also the packing of the
+decision fields and the attribution sums into one int32 row.
 
 The topology is described inside a fixture (never at import time: only one
 process may load the TPU's library, and every xdist worker imports every
@@ -148,3 +149,13 @@ def test_packed_scan_compiles_for_v5e_at_15001_nodes(one_chip,
         a.shape, a.dtype, sharding=one_chip), bufs)
     compiled = scan.fn.lower(placed, rest).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * (N + 1) * 8
+    # five output buffers since PR 49: the four heavy tensors and the one
+    # int32 row of everything the pass fetches (its int64 filter counts
+    # bitcast to words in their emulated layout, the feasibility bitmap's
+    # 1,876 bytes a pod to 469)
+    heavy_and_row = jax.tree.leaves(compiled.out_info)
+    assert len(heavy_and_row) == 5
+    words = sum(-(-int(np.prod(shape)) * np.dtype(dt).itemsize // 4)
+                for _, dt, shape in scan.row_layout)
+    assert (heavy_and_row[4].shape, heavy_and_row[4].dtype) == (
+        (words,), jnp.int32)
