@@ -11,7 +11,10 @@ Two layers, split so the hot paths stay import-light:
     read it.
   * control/autopilot.py — the controller thread that WRITES this
     registry from the observed telemetry planes (SLO windows, accept
-    fractions, spill counters).
+    fractions, spill counters).  One entry is the data plane's own:
+    whether the session's last speculative round found every feasible
+    set inside the candidate cap (`note_spec_narrow`), which decides
+    whether the next round runs the sparse probe at all.
 
 The empty registry is the parity baseline: every accessor returns the
 static-knob default (`None` override, weight 1.0, no shed), so a
@@ -41,12 +44,13 @@ WEIGHT_CAP = 4.0
 class _SessionControls:
     """Mutable per-session knob overrides; None = static default."""
 
-    __slots__ = ("spec_start_rung", "spec_candidates", "budget_weight",
-                 "shed", "retry_after_s")
+    __slots__ = ("spec_start_rung", "spec_candidates", "spec_narrow",
+                 "budget_weight", "shed", "retry_after_s")
 
     def __init__(self):
         self.spec_start_rung: int | None = None   # <0 = top rung
         self.spec_candidates: int | None = None
+        self.spec_narrow: bool = False   # no round seen yet: dense
         self.budget_weight: float = 1.0
         self.shed: bool = False
         self.retry_after_s: int = 1
@@ -91,6 +95,15 @@ class ControlPlane:
                 return None, None
             return ent.spec_start_rung, ent.spec_candidates
 
+    def spec_narrow(self, session: str | None) -> bool:
+        """Whether the session's last speculative round kept every
+        pod's feasible set inside the candidate cap: the next round then
+        runs the sparse probe, else the dense evaluation alone.  False
+        for a session no round has served yet."""
+        with self._mu:
+            ent = self._by_session.get(session)
+            return ent is not None and ent.spec_narrow
+
     def budget_milliweights(self) -> dict:
         """{session: int(weight*1000)} for sessions with a non-default
         weight; integer milli-weights so the equal-split case computes
@@ -107,6 +120,12 @@ class ControlPlane:
             if ent is None:
                 return False, 0
             return ent.shed, ent.retry_after_s
+
+    # ------------------------------------------------ data-plane writes
+
+    def note_spec_narrow(self, session: str | None, narrow: bool) -> None:
+        with self._mu:
+            self._ent(session).spec_narrow = bool(narrow)
 
     # ------------------------------------------------ autopilot writes
 

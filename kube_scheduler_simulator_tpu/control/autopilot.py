@@ -109,7 +109,7 @@ class _SessState:
     """Controller-internal per-session memory (streaks, baselines)."""
 
     __slots__ = ("spec_mode", "hi_streak", "lo_streak", "mid_streak",
-                 "accepted", "rolled", "rounds", "spilled", "calm_ticks",
+                 "accepted", "rolled", "rounds", "wide", "spilled", "calm_ticks",
                  "breach_streak", "ok_streak", "waves_total")
 
     def __init__(self):
@@ -120,6 +120,7 @@ class _SessState:
         self.accepted = 0.0    # counter baselines from the previous tick
         self.rolled = 0.0
         self.rounds = 0.0
+        self.wide = 0.0
         self.spilled = 0.0
         self.calm_ticks = 0
         self.breach_streak = 0
@@ -286,6 +287,9 @@ class Autopilot:
         st.accepted = accepted.get(sid, 0.0)
         st.rolled = rolled.get(sid, 0.0)
         st.rounds = rounds.get(sid, 0.0)
+        wide = TRACER.session_totals("speculative_wide_rounds_total").get(
+            sid, 0.0)
+        w_d, st.wide = wide - st.wide, wide
         # a round's first pod is accepted whatever the contention
         # (parallel/speculative.py): it is no evidence.  A session served
         # one pod a pass read 1.00 for ever, went aggressive on nothing and
@@ -327,6 +331,15 @@ class Autopilot:
         base = env_int("KSS_TPU_SPECULATIVE_CANDIDATES",
                        _SPEC_BASE_CANDIDATES)
         cand = None if mult is None else max(int(base * mult), 16)
+        if w_d * 2 >= n_d > 0:
+            # most of the rounds behind this evidence dropped their sparse
+            # probe for wide feasibility and ran dense: the cap has nothing
+            # to act on, and another cap is another sparse-round executable
+            # for every bucket and rung the session meets, 30-70 s on the
+            # chip in the middle of a session (my chip run, PR 50: cycle 73
+            # of baseline_c3_queue_1k.rollout30_profile's warm-up).  The
+            # start rung still moves: its executables are the ladder's own
+            cand = None
         frm, to = st.spec_mode, want
 
         def apply(sid=sid, st=st, want=want, rung=rung, cand=cand):

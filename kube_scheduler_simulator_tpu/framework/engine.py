@@ -32,7 +32,7 @@ from ..cluster.store import Conflict, NotFound, ObjectStore, volume_manifests
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
-from ..state.compile import compile_workload
+from ..state.compile import POD_CHUNK, compile_workload
 from ..store import annotations as ann
 from ..store.decode import decode_chunk_into, decode_pod_result
 from ..store.reflector import StoreReflector
@@ -514,7 +514,7 @@ class SchedulerEngine:
     def __init__(self, store: ObjectStore, reflector: StoreReflector | None = None,
                  result_store: ResultStore | None = None,
                  plugin_config: PluginSetConfig | None = None,
-                 chunk: int = 512, mesh=None, unroll: int = 2,
+                 chunk: int = POD_CHUNK, mesh=None, unroll: int = 2,
                  pipeline_commit: bool = True, residency_floor: int = 0):
         self.store = store
         # chunk-pipelined commit (docs/wave-pipeline.md): commit each
@@ -552,6 +552,7 @@ class SchedulerEngine:
             self.reflector.add_result_store(self.result_store, RESULT_STORE_KEY)
         self.plugin_config = plugin_config or PluginSetConfig()
         self.chunk = chunk
+        self._last_pod_axis: int | None = None
         # lax.scan unroll for replay waves: the step's [N] ops are tiny,
         # so per-iteration overhead matters
         self.unroll = unroll
@@ -1054,6 +1055,20 @@ class SchedulerEngine:
         if self.decisions is not None:
             self.decisions.forget(ns, name)
 
+    def _count_pod_axis(self, cw) -> None:
+        """The pass's pod axis, counted: the pad rows its bucket holds
+        beside the real pods (pass_pad_rows_total; + 0 too, so that a
+        session that pads none reads 0 and not absent) and whether the
+        bucket is another than the session's last pass ran on
+        (pod_axis_rebuckets_total: another layout of the pass's buffers,
+        so other executables, compiled where the process has not met
+        the bucket under this profile)."""
+        rows = cw.pod_axis
+        TRACER.count("pass_pad_rows_total", rows - cw.n_pods)
+        last, self._last_pod_axis = self._last_pod_axis, rows
+        TRACER.count("pod_axis_rebuckets_total",
+                     int(last is not None and last != rows))
+
     def _count_pass(self, pending: list[dict], now: float) -> None:
         """A wave that takes pods counts itself and them
         (scheduling_work_passes_total, scheduling_pass_pods_total) and,
@@ -1342,6 +1357,7 @@ class SchedulerEngine:
                 pod_columns=self._pod_bank(pods_all),
             )
             self._last_cw = NodeTableReuse(cw)
+        self._count_pod_axis(cw)
         # the Coscheduling plugin's name where the vectorized quorum pass
         # stands in for its per-pod Permit calls this wave
         # (docs/gang-scheduling.md): the plan and the speculative stream

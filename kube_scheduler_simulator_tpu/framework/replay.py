@@ -35,8 +35,11 @@ host-resident fetch (device_resident=False) is the bit-identical rung the
 engine's degradation ladder steps down to and the parity suites compare
 against.
 
-The last chunk is padded; padded steps carry `is_pad` and never bind
-(pipeline masks their selection to -1).
+The pod axis of a pass is a bucket (state/compile.py pod_axis_bucket:
+the next power of two up to the chunk, whole chunks beyond), so the rows
+past the pass's pods are padded; padded steps carry `is_pad` and never
+bind (pipeline masks their selection to -1), and nothing past the scan
+reads them: every consumer works on [lo, hi) of the REAL pods.
 """
 
 from __future__ import annotations
@@ -58,7 +61,8 @@ import numpy as np
 from .pipeline import build_step
 from ..control import CONTROLS
 from ..state.compile import (
-    CompiledWorkload, attribution_skip_masks, statics_digest)
+    POD_CHUNK, CompiledWorkload, attribution_skip_masks, pod_axis_bucket,
+    statics_digest)
 from ..state.packed import Packed, unpack_leaves
 from ..utils.faults import fault_point
 from ..utils.tracing import TRACER
@@ -947,20 +951,32 @@ def plugin_attribution(rr: ReplayResult) -> dict | None:
     return acc.out
 
 
-def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, Any]:
-    """Rows lo:hi of every leaf of `xs`, padded with zeros to pad_to rows:
-    an eager device op or two a leaf, for the routes that hold xs as
-    leaves (a mesh, the speculative rounds, a pass of many chunks)."""
+def _cut_rows(xs, lo, m, pad_to: int):
+    """Traced: rows lo:lo + m of every leaf of `xs` as pad_to rows, the
+    rest zeros, and the flag of the rest."""
     def cut(a):
-        piece = a[lo:hi]
-        TRACER.count("pass_device_dispatches_total")
-        if pad_to > piece.shape[0]:
-            pad_width = [(0, pad_to - piece.shape[0])] + [(0, 0)] * (piece.ndim - 1)
-            piece = jnp.pad(piece, pad_width)
-            TRACER.count("pass_device_dispatches_total")
-        return piece
+        rows = jnp.take(a, lo + jnp.arange(pad_to), axis=0, mode="clip")
+        keep = (jnp.arange(pad_to) < m).reshape((-1,) + (1,) * (a.ndim - 1))
+        return jnp.where(keep, rows, jnp.zeros((), a.dtype))
 
-    return jax.tree.map(cut, xs)
+    out = jax.tree.map(cut, xs)
+    out["is_pad"] = jnp.arange(pad_to) >= m
+    return out
+
+
+# one executable a tree of shapes and pad_to (a bucket, a rung of the
+# rounds' ladder): lo and the count are arguments, so a round that starts
+# anywhere in the pass compiles nothing
+_cut_rows_jit = jax.jit(_cut_rows, static_argnums=(3,))
+
+
+def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, Any]:
+    """Rows lo:hi of every leaf of `xs` as pad_to rows, the rows past
+    hi - lo zeros and flagged in `is_pad` (they never bind): ONE jitted
+    dispatch, for the routes that hold xs as leaves (a mesh, the
+    speculative rounds, a pass of many chunks)."""
+    TRACER.count("pass_device_dispatches_total")
+    return _cut_rows_jit(xs, np.int32(lo), np.int32(hi - lo), pad_to)
 
 
 # jitted scans shared across CompiledWorkload instances — and across
@@ -1329,7 +1345,7 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
     places = tuple(leaf.k if isinstance(leaf, Packed) else None
                    for leaf in leaves)
     layout = packed.layout
-    chunk = cw.n_pods
+    chunk = cw.pod_axis
     key = (*_workload_scan_key(cw, chunk), unroll, "packed", pack_mode,
            score_dtypes, wide, layout, places, att_plan)
     rest = [leaf for leaf in leaves if not isinstance(leaf, Packed)]
@@ -1346,7 +1362,13 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
             xs, carry, arg_statics, (fskip, sskip) = jax.tree.unflatten(
                 treedef, [next(own) if k is None else next(cut)
                           for k in places])
-            xs["is_pad"] = jnp.zeros(chunk, jnp.bool_)
+            # the real pods of the bucket: all of them where the bucket
+            # is too small to hold a pad row and the upload has no flag
+            m = np.int32(chunk)
+            if "is_pad" in xs:
+                m = chunk - jnp.sum(xs["is_pad"], dtype=jnp.int32)
+            else:
+                xs["is_pad"] = jnp.zeros(chunk, jnp.bool_)
             step = build_step(slim.with_args(arg_statics), out_mode="compact",
                               pack_mode=pack_mode, score_dtypes=score_dtypes,
                               wide_raw=wide)
@@ -1355,7 +1377,7 @@ def _packed_scan_for(cw: CompiledWorkload, unroll: int, pack_mode: str,
             if att_plan is not None:
                 att = _build_att_fn(chunk, n, *att_plan)(
                     out.packed_filter, out.raw8, out.raw16, out.raw32,
-                    out.feasible_count, fskip, sskip, np.int32(chunk))
+                    out.feasible_count, fskip, sskip, m)
             return out, att
 
         def scan_pass(bufs, rest):
@@ -1655,12 +1677,10 @@ class _DeviceAttribution:
         p = cw.n_pods
         self.p = p
         self.chunk = chunk
-        ppad = max(1, -(-p // chunk)) * chunk
         # pad rows read as "skipped": they contribute nothing even
         # before the valid mask cuts them
-        fskip, sskip = (np.pad(mask, ((0, 0), (0, ppad - p)),
-                               constant_values=True)
-                        for mask in attribution_skip_masks(cw))
+        fskip, sskip = attribution_skip_masks(
+            cw, max(1, -(-p // chunk)) * chunk)
         TRACER.count("pass_device_dispatches_total", 2)
         self.fskip_dev = jnp.asarray(fskip)
         self.sskip_dev = jnp.asarray(sskip)
@@ -1686,7 +1706,7 @@ def _resolve_device_resident(device_resident: bool | None,
     return bool(device_resident)
 
 
-def replay(cw: CompiledWorkload, chunk: int = 512,
+def replay(cw: CompiledWorkload, chunk: int = POD_CHUNK,
            unroll: int = 1, filter_only: bool = False,
            mesh=None, on_chunk=None,
            device_resident: bool | None = None) -> ReplayResult:
@@ -1807,8 +1827,7 @@ def _leaves_dispatch(cw: CompiledWorkload, chunk: int, unroll: int, mesh,
 
     def dispatch(carry, lo: int, hi: int):
         xs_chunk = _slice_xs(xs, lo, hi, chunk)
-        TRACER.count("pass_device_dispatches_total", 3)
-        xs_chunk["is_pad"] = (jnp.arange(chunk) >= (hi - lo))
+        TRACER.count("pass_device_dispatches_total")
         carry, out = scan_jit(carry, xs_chunk, arg_statics)
         return carry, out, (att_ctx.run(out, lo) if att_ctx is not None
                             else None)
@@ -1836,11 +1855,19 @@ def _packed_dispatch(cw: CompiledWorkload, unroll: int, wide,
     return dispatch, None
 
 
+def pass_chunk(cw: CompiledWorkload, chunk: int) -> int:
+    """The rows one device call of this pass takes: the pass's bucket
+    (pod_axis_bucket), the caller's chunk where the pass is longer.  The
+    sequential scan, the speculative rounds and their fuse family all ask
+    here, so that none computes a pod axis of its own."""
+    return min(chunk, pod_axis_bucket(cw.n_pods, chunk))
+
+
 def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
                 mesh, wide: str | None, on_chunk=None,
                 device_resident: bool = False) -> ReplayResult | None:
     p = cw.n_pods
-    chunk = min(chunk, max(p, 1))
+    chunk = pass_chunk(cw, chunk)
     # which route depends on what the replay is handed, nothing else:
     # compile_workload's upload as it was sent, and a pass of one chunk
     # (every served pass) -> the packed scan.  Leaves (parallel/mesh.py
@@ -1849,13 +1876,15 @@ def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
     # once: over the buffers it would cut the whole pass's xs out again
     # in every chunk, and compile a second executable (the carry in) of
     # minutes on the chip where the leaves route compiles one
-    packed = cw.packed is not None and p <= chunk
+    # (the chunk is then the rows the upload laid out; a caller's chunk
+    # that is no power of two finds another number there and takes leaves)
+    packed = cw.packed is not None and p <= chunk == cw.pod_axis
     TRACER.inc("replay_route_total", route="packed" if packed else "leaves")
     # scan_prepare: everything between the replay span's start and the
     # first chunk's dispatch: the compact plan, the scan-cache key with
     # its statics fingerprint and the registry's lookup; on the leaves
     # route also the carry's copy and the skip masks' upload
-    with TRACER.span("scan_prepare"):
+    with TRACER.span("scan_prepare", bucket=chunk):
         pack_mode, score_dtypes, score_cols = plan = _compact_plan(cw, wide)
         if packed:
             dispatch, carry = _packed_dispatch(

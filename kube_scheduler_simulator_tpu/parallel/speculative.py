@@ -115,9 +115,9 @@ import numpy as np
 from ..framework.replay import (
     ReplayResult, _CompactChunks, _compact_plan, _DeviceAttribution,
     _DEVICE_BUDGET, _resolve_device_resident, _scan_for, _SCAN_CACHE,
-    _copy_carry, _slice_xs, _SlimWorkload, _workload_scan_key)
+    _copy_carry, _slice_xs, _SlimWorkload, _workload_scan_key, pass_chunk)
 from ..control import CONTROLS
-from ..state.compile import CompiledWorkload
+from ..state.compile import POD_CHUNK, CompiledWorkload
 from ..utils.blackbox import BLACKBOX
 from ..utils.env import env_float, env_int
 from ..utils.faults import fault_point
@@ -673,7 +673,7 @@ class _SpecStats:
 
 
 def replay_speculative_stream(
-        cw: CompiledWorkload, mesh=None, chunk: int = 512, unroll: int = 1,
+        cw: CompiledWorkload, mesh=None, chunk: int = POD_CHUNK, unroll: int = 1,
         batch: int | None = None, pods: list[dict] | None = None,
         namespaces: list[dict] | None = None, on_chunk=None,
         device_resident: bool | None = None, gang=None,
@@ -750,7 +750,7 @@ def _fuse_family(cw: CompiledWorkload, chunk: int, mesh, wide,
     stacking precondition.  Note the scan key fingerprints statics
     CONTENT but only xs/carry SHAPES: heterogeneous tenants (different
     pods, same fleet and queue size) fuse — the Gavel framing."""
-    chunk = min(chunk, max(cw.n_pods, 1))
+    chunk = pass_chunk(cw, chunk)
     base_key = _workload_scan_key(cw, chunk, mesh)
     active_eff = set(cw.config.active_plugins()) - set(ignore)
     # the autopilot's per-session candidate cap (control/__init__.py)
@@ -775,11 +775,11 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
     from .mesh import gather_to_host
 
     p = cw.n_pods
-    chunk = min(chunk, max(p, 1))
+    chunk = pass_chunk(cw, chunk)
     # scan_prepare, as in the sequential replay: the compact plan and the
     # scan-cache key with its statics fingerprint here; below, the
     # workload's unpack and the carry's copy
-    with TRACER.span("scan_prepare"):
+    with TRACER.span("scan_prepare", bucket=chunk):
         pack_mode, score_dtypes, score_cols = _compact_plan(cw, wide)
         base_key = _workload_scan_key(cw, chunk, mesh)
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
@@ -902,7 +902,7 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
     # same workload
     TRACER.inc("replay_route_total", route="leaves")
     TRACER.count("pass_device_dispatches_total")
-    with TRACER.span("scan_prepare"):
+    with TRACER.span("scan_prepare", bucket=chunk):
         carry = _copy_carry(cw.init_carry)
         # what the round executables take beside carry and xs
         # (state/compile.py ARG_STATICS: NodeAffinity's match rows here;
@@ -929,8 +929,8 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
     kcand = min(max(ov_kcand if ov_kcand is not None
                     else env_int("KSS_TPU_SPECULATIVE_CANDIDATES", 128),
                     1), n)
-    sparse = _sparse_ok(active_eff) and kcand < n
-    if sparse and adaptive:
+    sparse_ok = _sparse_ok(active_eff) and kcand < n
+    if sparse_ok and adaptive:
         # sparse probes are cheap (dense filters + candidate tail), so
         # start at the TOP rung: a contention-free wave's steady-state
         # rounds are then whole aligned chunks ingested directly (no
@@ -939,6 +939,16 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
         # dense eval keeps the climb-from-8 ramp — its probes cost a
         # full [B, N] evaluation
         rung = len(ladder) - 1
+    # a round runs the sparse probe only where the session's LAST round
+    # (this stream's or an earlier one's) kept every feasible set inside
+    # the cap: a probe that meets a wider set is dropped for the dense
+    # evaluation, and its executable is the dearest of a rung to build
+    # (37-86 s for the v5e against 8-10 for the dense one, PERF.md
+    # section 6, PR 50).  Either kind of round says how wide the sets
+    # were, so the choice follows the queue in both directions, one
+    # round late; a session no round has served yet starts dense
+    session = TRACER.current_session()
+    sparse = sparse_ok and CONTROLS.spec_narrow(session)
     if adaptive and ov_rung is not None:
         # autopilot starting rung (hysteresis lives in the controller;
         # the in-wave climb/drop below still reacts within the wave):
@@ -1041,7 +1051,6 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
             fault_point("replay.scan_dispatch")
             with TRACER.span("scan_dispatch", lo=lo):
                 xs_chunk = _slice_xs(cw_scan.xs, lo, hi, chunk)
-                xs_chunk["is_pad"] = (jnp.arange(chunk) >= m)
                 carry, out = scan_jit(carry, xs_chunk,
                                       cw_scan.arg_statics())
             fault_point("replay.decision_fetch")
@@ -1079,13 +1088,11 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
         b = ladder[rung]
         hi = min(lo + b, p)
         m = hi - lo
-        with TRACER.span("speculative_round", batch=m, rung=b):
+        with TRACER.span("speculative_round", batch=m, rung=b, bucket=chunk):
             fault_point("replay.scan_dispatch")
             # the sequential scan's two seams, under the same two names
             with TRACER.span("scan_dispatch", lo=lo):
-                xs = _slice_xs(cw.xs, lo, hi, b)
-                xs["is_pad"] = (jnp.arange(b) >= m)
-                xs = place_batch(xs)
+                xs = place_batch(_slice_xs(cw.xs, lo, hi, b))
             dense = not sparse
             if sparse:
                 # one fused dispatch per round; a wide-feasibility round
@@ -1121,6 +1128,16 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
                 rows = {"packed": outs.packed_filter, "raw8": outs.raw8,
                         "raw16": outs.raw16, "raw32": outs.raw32,
                         "fc": outs.feasible_count}
+            if sparse_ok:
+                narrow = int(fc[:m].max(initial=0)) <= kcand
+                if not narrow:
+                    # ran dense for wide feasibility, its probe dropped or
+                    # not made: what the autopilot reads before it moves
+                    # the candidate cap
+                    TRACER.count("speculative_wide_rounds_total")
+                if narrow != sparse:
+                    sparse = narrow
+                    CONTROLS.note_spec_narrow(session, narrow)
             with TRACER.span("decision_fetch"):
                 k = min(int(k_dev), m)
             TRACER.count("wave_d2h_bytes_total",

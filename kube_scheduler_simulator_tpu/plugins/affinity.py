@@ -56,21 +56,35 @@ class NodeAffinityStatic(NamedTuple):
     The rows change with the QUEUE (another pod, other terms), so they
     reach the jitted scan as arguments, keyed by shape and dtype
     (state/compile.py ARG_STATICS), and U / V are padded to a power of
-    two, at least AXIS_FLOOR: a pass of one pod has one layout whether or
-    not the pod carries terms, and another pod's terms are no new
-    executable.  Nothing gathers a pad row."""
+    two, at least the pass's floor (_axis_floor: AXIS_FLOOR for one pod,
+    twice the pass's pod axis up to AXIS_FLOOR_MAX): a pass has one
+    layout whether or not its pods carry terms and however many of them
+    share a spec, and another pod's terms are no new executable.  Nothing
+    gathers a pad row."""
 
     req_rows: jnp.ndarray       # [U, N] bool  (row 0 = all-True)
     pref_rows: jnp.ndarray      # [V, N] int32 (row 0 = zeros)
 
 
 AXIS_FLOOR = 2
+AXIS_FLOOR_MAX = 64
 
 
-def _stack_padded(pool: list[np.ndarray]) -> np.ndarray:
+def _axis_floor(pod_axis: int) -> int:
+    """The least U / V of a pass on a pod axis of `pod_axis` rows: room
+    for the identity row and a spec of its own for every pod (p + 1 <=
+    twice the bucket), so that the extent follows the pod axis's bucket
+    and not how many of the pass's pods happen to share a spec; capped,
+    because a row is as long as the cluster has nodes (a pass with more
+    distinct specs than AXIS_FLOOR_MAX - 1 pads to the next power of
+    two, and affinity_axis_rebuckets_total says so)."""
+    return max(AXIS_FLOOR, min(2 * pod_axis, AXIS_FLOOR_MAX))
+
+
+def _stack_padded(pool: list[np.ndarray], floor: int) -> np.ndarray:
     """The pool's rows as [U, N], U the next power of two (at least
-    AXIS_FLOOR); the pad rows repeat row 0."""
-    extent = max(AXIS_FLOOR, 1 << (len(pool) - 1).bit_length())
+    `floor`); the pad rows repeat row 0."""
+    extent = max(floor, 1 << (len(pool) - 1).bit_length())
     return np.stack(pool + [pool[0]] * (extent - len(pool)))
 
 
@@ -172,9 +186,13 @@ def _count_rebuckets(table: NodeTable, axes: dict[str, int]) -> None:
 
 def build(table: NodeTable, pods: list[dict],
           args: dict | None = None,
-          host_out: dict | None = None
+          host_out: dict | None = None,
+          pod_axis: int = 1,
           ) -> tuple[NodeAffinityStatic, NodeAffinityXS]:
+    """pod_axis: the rows of the pass's pod axis (state/compile.py
+    pod_axis_bucket), which the U / V floor follows."""
     n, p = table.n, len(pods)
+    floor = _axis_floor(pod_axis)
     filter_skip = np.zeros(p, dtype=bool)
     score_skip = np.zeros(p, dtype=bool)
 
@@ -243,7 +261,7 @@ def build(table: NodeTable, pods: list[dict],
                 pref_by_key[key] = j
             pref_idx[i] = j
 
-    pref_mat = _stack_padded(pref_pool)
+    pref_mat = _stack_padded(pref_pool, floor)
     if host_out is not None:
         # the raw score IS the precompiled row (score_kernel is a pure
         # gather), so the compact replay never transfers it back from the
@@ -261,7 +279,7 @@ def build(table: NodeTable, pods: list[dict],
     # numpy, xs and carry too: compile_workload reads its flags and the
     # digest off the host bytes, then uploads once (pack_tree)
     static = NodeAffinityStatic(
-        req_rows=_stack_padded(req_pool),
+        req_rows=_stack_padded(req_pool, floor),
         pref_rows=pref_mat,
     )
     _count_rebuckets(table, {"req": static.req_rows.shape[0],

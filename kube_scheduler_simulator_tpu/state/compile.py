@@ -119,6 +119,28 @@ ARG_STATICS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding",
                "NodeAffinity")
 
 
+# the scan's default chunk: the most pods one device call takes
+POD_CHUNK = 512
+
+
+def pod_axis_bucket(p: int, chunk: int = POD_CHUNK) -> int:
+    """The pod axis a pass of `p` pods runs on: the next power of two up
+    to the chunk (1, 2, 4, ..., 512 by default), whole chunks beyond.
+    The ONE rule for the pod axis: compile_workload lays the pass's xs
+    and skip masks out on it, and the scan key, the packed layout, the
+    resident patch's key, the scan's chunk and the speculative ladder's
+    top rung follow from those shapes, so a pass of a count the process
+    has not seen is a compile only where its bucket is new.  The rows
+    past `p` are pad rows (xs["is_pad"]): they never bind and nothing
+    past the scan reads them.  A pass of one pod or of two pads none.
+    Beyond the chunk the bucket is what the scan RUNS on, chunk after
+    chunk; the upload keeps such a pass at its own count and the last
+    chunk is padded when it is cut (framework/replay.py _slice_xs)."""
+    if p <= chunk:
+        return min(1 << max(p - 1, 0).bit_length(), chunk)
+    return -(-p // chunk) * chunk
+
+
 def split_statics(statics: dict[str, Any]) -> tuple[dict, dict]:
     """-> (closure statics, argument statics) of one statics dict."""
     return ({k: v for k, v in statics.items() if k not in ARG_STATICS},
@@ -152,6 +174,13 @@ class CompiledWorkload:
     @property
     def n_nodes(self) -> int:
         return self.node_table.n
+
+    @property
+    def pod_axis(self) -> int:
+        """The rows of xs' leading axis: the bucket of n_pods where
+        compile_workload laid the pass out, n_pods itself for a workload
+        built by hand."""
+        return self.host.get("pod_axis", self.n_pods)
 
     def closure_statics(self) -> dict[str, Any]:
         """The statics a jitted scan closes over; asks no unpack."""
@@ -304,6 +333,15 @@ def compile_workload(
     host: dict[str, Any] = {"node_table": table, "schema": schema,
                             "node_key": node_key}
     p = len(pods)
+    # the pass's pod axis: a pass of one chunk (every served pass, a
+    # burst) is laid out on its bucket at the upload (_pad_pod_axis).  A
+    # longer pass keeps its own count: the scan cuts it into whole chunks
+    # over leaves and pads the last one itself (_slice_xs), and a pad of
+    # up to 511 rows at 5,000 nodes is 2.5 MB a [P, N] leaf that the
+    # unpack of 10,000 pods has no room for on the chip (chip_smoke.py's
+    # wave A ran out of HBM compiling unpack_leaves at 10,240 rows;
+    # my chip run, PR 50)
+    rows = host["pod_axis"] = pod_axis_bucket(p) if p <= POD_CHUNK else p
     enabled = set(config.active_plugins())
     with TRACER.span("cw_core"):
         requests, nonzero = pod_request_rows(pods, schema, pod_columns)
@@ -340,7 +378,7 @@ def compile_workload(
         with TRACER.span("cw_build_NodeAffinity"):
             st, x = affinity.build(
                 table, pods, args=config.args.get("NodeAffinity"),
-                host_out=host)
+                host_out=host, pod_axis=rows)
             statics["NodeAffinity"] = st
             xs["NodeAffinity"] = x
     if "NodePorts" in enabled:
@@ -485,8 +523,10 @@ def compile_workload(
                              [args.get(name) for name in VOLUME_PLUGINS]))
                          + sum(kept.whole_nbytes for t, _n, _l, kept
                                in resident if t == 0))
+            if p <= POD_CHUNK:
+                _pad_pod_axis(xs, p, rows)
             cw.packed = pack_tree(
-                (xs, init_carry, args, attribution_skip_masks(cw)))
+                (xs, init_carry, args, attribution_skip_masks(cw, rows)))
             cw.xs = cw.init_carry = None        # unpacked on first access
             if resident:
                 # ... and in the payload's place, the array brought up to
@@ -497,6 +537,23 @@ def compile_workload(
                         resident, (packed.tree[2], packed.tree[1]),
                         lambda kept, rode: kept.incoming(packed, rode))
     return cw
+
+
+def _pad_pod_axis(xs: dict[str, Any], p: int, rows: int) -> None:
+    """Lay the pass's xs out on the `rows` of its bucket, in place: every
+    leaf padded with rows of zeros, as _slice_xs pads a last chunk, and
+    the pad flag beside them wherever the bucket can hold a pad row (from
+    4 rows on, whether this pass pads or not: one layout and one
+    executable a bucket)."""
+    if rows > p:
+        def pad(a):
+            assert a.shape[0] == p, (a.shape, p)
+            return np.pad(a, [(0, rows - p)] + [(0, 0)] * (a.ndim - 1))
+
+        for name, tree in xs.items():
+            xs[name] = jax.tree.map(pad, tree)
+    if rows > 2:
+        xs["is_pad"] = np.arange(rows) >= p
 
 
 def _resident_leaves(volume_carry: VolumeCarry | None, args: dict,
@@ -664,22 +721,24 @@ def _collect_host_flags(cw: CompiledWorkload):
     )
 
 
-def attribution_skip_masks(cw: CompiledWorkload) -> tuple:
-    """([F, P], [max(S, 1), P]) bool: per filter and per scorer, the pods
-    whose PreFilter / PreScore skipped it, from the decoder's host flags.
-    The on-device attribution reduction (framework/replay.py) leaves such
-    a pod out of the plugin's sums; the masks ride in the pass's bool
-    buffer."""
+def attribution_skip_masks(cw: CompiledWorkload, rows: int) -> tuple:
+    """([F, rows], [max(S, 1), rows]) bool: per filter and per scorer, the
+    pods whose PreFilter / PreScore skipped it, from the decoder's host
+    flags; the rows past the pass's pods (its bucket's pad rows, a last
+    chunk's) read as skipped.  The on-device attribution reduction
+    (framework/replay.py) leaves such a pod out of the plugin's sums; the
+    masks ride in the pass's bool buffer."""
     p = cw.n_pods
 
-    def rows(names, flags, least):
-        mat = np.zeros((max(len(names), least), p), np.bool_)
+    def rows_of(names, flags, least):
+        mat = np.ones((max(len(names), least), rows), np.bool_)
+        mat[:, :p] = False
         for i, name in enumerate(names):
-            mat[i] = np.asarray(flags.get(name, False), bool)
+            mat[i, :p] = np.asarray(flags.get(name, False), bool)
         return mat
 
-    return (rows(cw.config.filters(), cw.host.get("filter_skip", {}), 0),
-            rows(cw.config.scorers(), cw.host.get("score_skip", {}), 1))
+    return (rows_of(cw.config.filters(), cw.host.get("filter_skip", {}), 0),
+            rows_of(cw.config.scorers(), cw.host.get("score_skip", {}), 1))
 
 
 def _collect_prefilter_results(cw: CompiledWorkload):

@@ -239,6 +239,26 @@ _HELP: dict[str, str] = {
         "(scheduling_waves_total counts empty wake-ups too).",
     "scheduling_pass_pods_total":
         "Pods taken by the waves scheduling_work_passes_total counts.",
+    "speculative_wide_rounds_total":
+        "Sparse-eligible speculative rounds that ran the dense evaluation "
+        "because some pod's feasible set was past the candidate cap "
+        "(KSS_TPU_SPECULATIVE_CANDIDATES), their sparse probe dropped or, "
+        "after such a round, not made (parallel/speculative.py: a round "
+        "probes only where the session's last round was inside the cap).  "
+        "A session whose rounds mostly do keeps its "
+        "cap when the autopilot changes its speculative profile "
+        "(control/autopilot.py): only the start rung moves.",
+    "pass_pad_rows_total":
+        "Pad rows of the passes scheduling_work_passes_total counts: the "
+        "rows of a pass's pod axis (state/compile.py pod_axis_bucket: the "
+        "next power of two up to the chunk, whole chunks beyond) past "
+        "its real pods.  They never bind and nothing past the scan reads "
+        "them.  0 is written too.",
+    "pod_axis_rebuckets_total":
+        "Passes whose pod-axis bucket is not the bucket of the session's "
+        "last pass: another layout of the pass's buffers and other "
+        "executables (compiled only where the process has not met the "
+        "bucket under this profile and node table).  0 is written too.",
     "bound_rows_built_total":
         "Bound-pod rows compile_workload built anew (state/boundcarry.py): "
         "in a steady pass, the pods bound or changed since the last one.",

@@ -163,14 +163,21 @@ def test_the_rows_are_argument_statics_and_not_the_digest_s():
 
 # ----------------------------------------------------- padding, and row 0
 
+# U and V follow the pass's pod axis, not how many of its pods share a
+# spec (since PR 50): twice the bucket of the pod count, at most 64, so
+# that a burst's passes have one layout a bucket
 @pytest.mark.parametrize("pods,u,v", [
     ([_pod("p")], 2, 2),
     ([_pod("p", ["ssd"], [(5, "type-0")])], 2, 2),
-    ([_pod("p", ["ssd"]), _pod("q", ["hdd"], [(5, "type-0")])], 4, 2),
+    ([_pod("p", ["ssd"]), _pod("q", ["hdd"], [(5, "type-0")])], 4, 4),
     ([_pod("p", ["ssd"], [(1, "type-0")]), _pod("q", ["hdd"], [(2, "type-0")]),
-      _pod("r", ["ssd", "hdd"], [(3, "type-1")])], 4, 4),
-    ([_pod(f"p{i}", None, [(i + 1, "type-0")]) for i in range(5)], 2, 8),
-], ids=["no_terms", "one_spec", "two_required", "three_each", "five_preferred"])
+      _pod("r", ["ssd", "hdd"], [(3, "type-1")])], 8, 8),
+    ([_pod(f"p{i}", None, [(i + 1, "type-0")]) for i in range(5)], 16, 16),
+    ([_pod(f"p{i}", None, [(i + 1, "type-0")]) for i in range(40)], 64, 64),
+    # more distinct specs than the floor's cap holds: the next power of two
+    ([_pod(f"p{i}", None, [(i + 1, "type-0")]) for i in range(70)], 64, 128),
+], ids=["no_terms", "one_spec", "two_required", "three_each", "five_preferred",
+        "forty_preferred", "seventy_preferred"])
 def test_axes_are_padded_and_row_0_keeps_its_meaning(pods, u, v):
     cw = compile_workload(NODES, pods, CFG)
     st = jax.tree.map(np.asarray, cw.arg_statics()["NodeAffinity"])
@@ -202,13 +209,19 @@ def test_a_pass_that_outgrows_an_axis_is_counted():
     compile_workload(nodes, [_pod("q", ["hdd"], [(4, "type-1")])], CFG,
                      reuse=first)
     assert _labeled("affinity_axis_rebuckets_total") == {"req": 0, "pref": 0}
-    # two required specs in one pass: U 2 -> 4, another layout
+    # two pods in one pass: the pod axis is 2 rows, the floor of U and V
+    # 4, another layout (the pod axis's own: pod_axis_rebuckets_total)
     compile_workload(nodes, [_pod("r", ["ssd"]), _pod("s", ["hdd"])], CFG,
                      reuse=first)
-    assert _labeled("affinity_axis_rebuckets_total") == {"req": 1, "pref": 0}
+    assert _labeled("affinity_axis_rebuckets_total") == {"req": 1, "pref": 1}
     # ... and back: another layout again
     compile_workload(nodes, [_pod("t")], CFG, reuse=first)
-    assert _labeled("affinity_axis_rebuckets_total") == {"req": 2, "pref": 0}
+    assert _labeled("affinity_axis_rebuckets_total") == {"req": 2, "pref": 2}
+    # within one pod axis the specs a pass holds move nothing, up to the
+    # floor: one pod with two required specs' worth of rows is U 2 still
+    compile_workload(nodes, [_pod("u", ["ssd", "hdd"], [(7, "type-0")])],
+                     CFG, reuse=first)
+    assert _labeled("affinity_axis_rebuckets_total") == {"req": 2, "pref": 2}
 
 
 # ------------------------------------------------------- rows from the memo

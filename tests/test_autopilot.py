@@ -212,6 +212,42 @@ def test_speculative_profile_decays_to_default_on_mid_band():
         mgr.shutdown()
 
 
+def test_speculative_effector_keeps_the_cap_of_a_session_that_runs_dense():
+    """A session whose rounds drop their sparse probe for wide
+    feasibility (every pod's feasible set past the candidate cap: an
+    empty mixed cluster) gets the profile's start rung and NOT its cap:
+    the cap has nothing to act on there, and another cap is a compile of
+    the sparse round for every bucket and rung, mid-session."""
+    mgr = _mgr(max_sessions=4)
+    ap = Autopilot(mgr, interval=3600, slo_target=0)
+    try:
+        mgr.create("ap-wide")
+
+        def rounds(n: int, accepted: int, rolled: int, wide: int) -> None:
+            with TRACER.session_scope("ap-wide"):
+                TRACER.count("speculative_rounds_total", n)
+                TRACER.count("speculative_wide_rounds_total", wide)
+            TRACER.inc("speculative_accepted_total", accepted,
+                       session="ap-wide")
+            TRACER.inc("speculative_rolled_back_total", rolled,
+                       session="ap-wide")
+
+        ap.tick()
+        for _ in range(HYSTERESIS_TICKS):
+            rounds(7, 10, 40, 7)
+            ap.tick()
+        # sustained collapse: bottom rung; the cap stays the operator's
+        assert CONTROLS.spec_overrides("ap-wide") == (0, None)
+        for _ in range(HYSTERESIS_TICKS):
+            rounds(4, 104, 2, 1)
+            ap.tick()
+        # sparse rounds in use again: the aggressive profile whole
+        assert CONTROLS.spec_overrides("ap-wide") == (-1, 256)
+    finally:
+        ap.stop()
+        mgr.shutdown()
+
+
 def test_speculative_candidates_scale_operator_baseline(monkeypatch):
     """The profile multipliers scale KSS_TPU_SPECULATIVE_CANDIDATES as
     the operator set it — aggressive on a 512 baseline means 1024,

@@ -110,7 +110,7 @@ def _both_routes(case):
     held = dataclasses.replace(cw)     # a hand-held workload: leaves
     assert held.packed is None
     dispatch, carry = replay_mod._leaves_dispatch(
-        held, cw.n_pods, 1, None, wide, True, *plan)
+        held, cw.pod_axis, 1, None, wide, True, *plan)
     _, out, att = dispatch(carry, 0, cw.n_pods)
     assert isinstance(out, CompactOut)
     return packed, replay_mod._fetch_decisions(out, att), cw
@@ -130,7 +130,10 @@ def test_the_row_hands_on_what_the_leaves_do(case):
     assert set(packed) == set(leaves)
     for f in DECISION_FIELDS:
         _same(packed[f], leaves[f], f)
-        assert packed[f].shape == (cw.n_pods,)
+        # a row a row of the pass's pod axis: its bucket (since PR 50),
+        # the pad rows unbound on both routes
+        assert packed[f].shape == (cw.pod_axis,)
+    assert (packed["selected"][cw.n_pods:] == -1).all()
     want = CASES[case][2]
     if want is not None:
         assert set(leaves.get("att", ())) == want
@@ -158,7 +161,7 @@ def test_the_packed_executable_returns_five_buffers(case):
         replay_mod._att_plan(cw, pack_mode, score_cols))
     outs = scan(*args)
     assert len(outs) == 5 and all(isinstance(a, jax.Array) for a in outs)
-    p, n = cw.n_pods, cw.n_nodes
+    p, n = cw.pod_axis, cw.n_nodes
     assert [a.shape[0] for a in outs[:4]] == [p] * 4
     assert outs[0].shape == (p, n)           # the heavy four stay whole
     row = outs[4]

@@ -261,7 +261,7 @@ def _http(base, method, path, body=None):
         base + path, method=method,
         data=json.dumps(body).encode() if body is not None else None,
         headers={"Content-Type": "application/json"})
-    with urllib.request.urlopen(req, timeout=30) as r:
+    with urllib.request.urlopen(req, timeout=120) as r:
         raw = r.read()
         return r.status, json.loads(raw) if raw else None
 
@@ -309,7 +309,7 @@ def test_served_requests_and_the_watch_stream_have_spans(live_server):
 def _watch_until_bound(base, name, ready, done):
     """Hold a watch stream open until `name` shows bound on it."""
     with urllib.request.urlopen(base + "/api/v1/listwatchresources",
-                                timeout=60) as resp:
+                                timeout=240) as resp:
         ready.set()
         buf = b""
         while not done.is_set():
@@ -340,19 +340,22 @@ def test_the_served_tree_past_the_commit(live_server, tmp_path):
     t = threading.Thread(target=_watch_until_bound,
                          args=(base, name, ready, done), daemon=True)
     t.start()
-    assert ready.wait(20)
+    # every wait below is on an event (the stream is open, the decision
+    # is on it, the spans are recorded); the clocks are backstops sized
+    # for a first pass that compiles beside five other xdist workers
+    assert ready.wait(120)
     seen = {e["span_id"] for e in TRACER.events(4096)}
     TRACER.start_xla_profile(str(tmp_path), python_tracer=False)
     try:
         _http(base, "POST", "/api/v1/pods", pod)
-        assert done.wait(120), "the decision never showed on the stream"
+        assert done.wait(240), "the decision never showed on the stream"
         _http(base, "GET", f"/api/v1/pods/{ns}/{name}")
         # a request's span closes after its last byte, the pump notes
         # the delivery after its write returns, the wave's tail runs on
         # after the commit: the client is back before they are recorded
         last_in = {"http_pod_read", "wave", "decision_delivery",
                    "decision_to_read"}
-        deadline = time.time() + 10
+        deadline = time.time() + 120
         while True:
             missing = last_in - {e["name"] for e in TRACER.events(4096)
                                  if e["span_id"] not in seen}

@@ -300,6 +300,57 @@ def test_sparse_tail_mixed_with_dense_fallback_rounds(monkeypatch):
         assert decode_pod_result(rr, i) == decode_pod_result(base, i), i
 
 
+@pytest.mark.parametrize("queue,probes_built,wide_rounds,ends_narrow", [
+    ("broad", False, "all", False),
+    ("pinned", True, "none", True),
+    ("pinned+broad+pinned", True, "some", True)])
+def test_the_sparse_probe_follows_the_last_round(
+        monkeypatch, queue, probes_built, wide_rounds, ends_narrow):
+    """A round runs the sparse probe only where the session's last round
+    kept every feasible set inside the candidate cap: a queue of broad
+    pods never builds the probe's executable, a queue of slot-pinned pods
+    runs its first round dense and the rest sparse, a mixed one follows
+    the queue both ways; every pod byte-identical to the scan, and the
+    next stream of the session starts where the last round left off."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+    from kube_scheduler_simulator_tpu.models.workloads import (
+        make_slot_pinned_workload)
+    from kube_scheduler_simulator_tpu.parallel import speculative
+    from kube_scheduler_simulator_tpu.utils.tracing import TRACER
+
+    monkeypatch.setenv("KSS_TPU_SPECULATIVE_CANDIDATES", "4")
+    CONTROLS.reset()
+    TRACER.reset()
+    built = []
+    build = speculative._sparse_round_fn
+    monkeypatch.setattr(
+        speculative, "_sparse_round_fn",
+        lambda *a, **kw: built.append(a[2]) or build(*a, **kw))
+    nodes, pinned = make_slot_pinned_workload(20, 16, seed=71)
+    broad = make_pods(12, seed=72)  # feasible on ~all 16 nodes ( > 4 )
+    pods = {"broad": broad, "pinned": pinned,
+            "pinned+broad+pinned": pinned[:10] + broad + pinned[10:]}[queue]
+    cfg = PluginSetConfig(enabled=["NodeResourcesFit",
+                                   "NodeResourcesBalancedAllocation",
+                                   "NodeAffinity"])
+    base = replay(compile_workload(nodes, pods, cfg), chunk=8)
+    rr, stats = replay_speculative(compile_workload(nodes, pods, cfg),
+                                   None, batch=4)
+    for i in range(len(pods)):
+        assert decode_pod_result(rr, i) == decode_pod_result(base, i), i
+    counters = TRACER.summary()["counters"]
+    wide = counters.get("speculative_wide_rounds_total", 0)
+    assert bool(built) is probes_built, built
+    assert wide == {"all": stats["rounds"], "none": 0}.get(wide_rounds, wide)
+    assert 0 < wide < stats["rounds"] or wide_rounds != "some", (wide, stats)
+    assert CONTROLS.spec_narrow(None) is ends_narrow
+    # the session's next stream starts as the last round ended
+    del built[:]
+    replay_speculative(compile_workload(nodes, pods[:4], cfg), None, batch=4)
+    assert bool(built) is (ends_narrow and queue != "broad"), built
+    CONTROLS.reset()
+
+
 def test_wide_i64_tier_keeps_width_through_the_stream(monkeypatch):
     """Compile-proven i64 scores skip straight to the widest tier: the
     stream's eval must receive the tier STRING (review finding: a

@@ -52,12 +52,18 @@ def live_server():
         "spec": {"containers": [
             {"name": "c", "resources": {"requests": {"cpu": "1",
                                                      "memory": "1Gi"}}}]}})
-    # wait for the scheduling loop to bind + reflect
+    # wait for the scheduling loop to bind + reflect: on the event (the
+    # pod shows its node and its sealed results), however long the first
+    # pass's compile takes beside five other xdist workers; the clock is
+    # the backstop of a server that never decides
     import time
 
-    for _ in range(80):
+    backstop = time.time() + 180
+    while time.time() < backstop:
         pod = _get(base, "/api/v1/pods/default/ui-pod")
-        if (pod.get("spec") or {}).get("nodeName"):
+        if ((pod.get("spec") or {}).get("nodeName")
+                and ANN + "result-history" in (
+                    (pod.get("metadata") or {}).get("annotations") or {})):
             break
         time.sleep(0.1)
     yield base
@@ -66,7 +72,7 @@ def live_server():
 
 
 def _get(base, path):
-    with urllib.request.urlopen(base + path, timeout=10) as r:
+    with urllib.request.urlopen(base + path, timeout=60) as r:
         body = r.read()
         return json.loads(body) if body.strip().startswith(b"{") else body
 
@@ -75,7 +81,7 @@ def _post(base, path, obj):
     req = urllib.request.Request(
         base + path, data=json.dumps(obj).encode(), method="POST",
         headers={"Content-Type": "application/json"})
-    with urllib.request.urlopen(req, timeout=10) as r:
+    with urllib.request.urlopen(req, timeout=60) as r:
         return json.loads(r.read())
 
 
