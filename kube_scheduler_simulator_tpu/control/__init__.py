@@ -11,10 +11,16 @@ Two layers, split so the hot paths stay import-light:
     read it.
   * control/autopilot.py — the controller thread that WRITES this
     registry from the observed telemetry planes (SLO windows, accept
-    fractions, spill counters).  One entry is the data plane's own:
-    whether the session's last speculative round found every feasible
-    set inside the candidate cap (`note_spec_narrow`), which decides
-    whether the next round runs the sparse probe at all.
+    fractions, spill counters).  Two entries are the data plane's own,
+    the speculative rounds' record of themselves: whether the session's
+    last round found every feasible set inside the candidate cap
+    (`note_spec_narrow`), which decides whether the next round runs the
+    sparse probe at all; and whether the session's last tried rounds
+    COLLAPSED (`note_spec_collapsed`: the first round of a pass kept a
+    quarter of what it evaluated or less), which sends the session's
+    next batch passes to the sequential scan without a round
+    (SchedulerEngine._wave_plan) until the queue's feasible share has
+    halved (`spec_recheck`).
 
 The empty registry is the parity baseline: every accessor returns the
 static-knob default (`None` override, weight 1.0, no shed), so a
@@ -45,12 +51,15 @@ class _SessionControls:
     """Mutable per-session knob overrides; None = static default."""
 
     __slots__ = ("spec_start_rung", "spec_candidates", "spec_narrow",
-                 "budget_weight", "shed", "retry_after_s")
+                 "spec_collapsed", "budget_weight", "shed", "retry_after_s")
 
     def __init__(self):
         self.spec_start_rung: int | None = None   # <0 = top rung
         self.spec_candidates: int | None = None
         self.spec_narrow: bool = False   # no round seen yet: dense
+        # (profile, feasible share) of the collapsed first round; None:
+        # the rounds are tried
+        self.spec_collapsed: tuple | None = None
         self.budget_weight: float = 1.0
         self.shed: bool = False
         self.retry_after_s: int = 1
@@ -104,6 +113,20 @@ class ControlPlane:
             ent = self._by_session.get(session)
             return ent is not None and ent.spec_narrow
 
+    def spec_collapsed(self, session: str | None, profile) -> float | None:
+        """The median feasible share (feasible nodes / nodes) of the
+        round that collapsed, where the session's last tried speculative
+        rounds collapsed under this profile (PluginSetConfig.signature;
+        another profile's record says nothing of this one); None where
+        the rounds are to be tried: a session no round has served yet,
+        one whose rounds accepted, another profile."""
+        with self._mu:
+            ent = self._by_session.get(session)
+            if ent is None or ent.spec_collapsed is None:
+                return None
+            of_profile, share = ent.spec_collapsed
+            return share if of_profile == profile else None
+
     def budget_milliweights(self) -> dict:
         """{session: int(weight*1000)} for sessions with a non-default
         weight; integer milli-weights so the equal-split case computes
@@ -126,6 +149,33 @@ class ControlPlane:
     def note_spec_narrow(self, session: str | None, narrow: bool) -> None:
         with self._mu:
             self._ent(session).spec_narrow = bool(narrow)
+
+    def note_spec_collapsed(self, session: str | None, profile,
+                            share: float) -> None:
+        """The first round of a one-chunk pass kept a quarter of what it
+        evaluated or less (parallel/speculative.py): `share` is the
+        median feasible share of the round's pods, the quantity the
+        dirty-node rule's acceptance turns on."""
+        with self._mu:
+            self._ent(session).spec_collapsed = (profile, float(share))
+
+    def spec_recheck(self, session: str | None, share: float) -> bool:
+        """A declined pass's median feasible share, read from the scan's
+        own decision row: where it has fallen to half the collapsed
+        round's or less (the cluster filled, the queue turned to pinned
+        pods), the record is cleared and the next batch pass tries the
+        rounds again.  -> whether it was cleared."""
+        with self._mu:
+            ent = self._by_session.get(session)
+            if ent is None or ent.spec_collapsed is None:
+                return False
+            was = ent.spec_collapsed[1]
+            # (a record of 0, most of the round's pods without a node,
+            # has nothing to fall to and stays until the profile changes)
+            if was <= 0 or 2.0 * share > was:
+                return False
+            ent.spec_collapsed = None
+            return True
 
     # ------------------------------------------------ autopilot writes
 

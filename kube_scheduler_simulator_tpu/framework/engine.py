@@ -29,6 +29,7 @@ from .replay import (
     considered_rows, count_narrowed, filter_rejected_rows, replay)
 from .unschedulable import pod_key
 from ..cluster.store import Conflict, NotFound, ObjectStore, volume_manifests
+from ..control import CONTROLS
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
@@ -201,6 +202,10 @@ class WavePlan(NamedTuple):
     scan: str     # speculative | sequential | host_loop
     commit: str   # streamed | post_pass | host_loop
     results: str  # device_lazy | host_lazy | by_chunk | by_pod
+    # a batch pass on a batchable profile that takes the sequential scan
+    # because the session's rounds collapsed (row 9): the executor reads
+    # the pass's feasible share against that record
+    declined: bool = False
 
 
 class _WaveAbort(Exception):
@@ -1406,7 +1411,23 @@ class SchedulerEngine:
         vectorized quorum pass handles it this wave (row 11) — it then is
         no lifecycle plugin and speculation_ok ignores it: its PreFilter
         ran in the prescreen, admission happens in the quorum pass at
-        commit, it neither filters nor scores on device."""
+        commit, it neither filters nor scores on device.
+
+        The fifth observation, beside the profile, the reflector, the
+        rung and n_pods, is the rounds' own record: a pass whose first
+        round kept a quarter of its pods or less gains nothing from the
+        rounds (parallel/speculative.py), and the session remembers it
+        (CONTROLS.spec_collapsed, under the profile that made it).  A
+        batch pass of such a session is DECLINED: the sequential scan
+        from the start, no stream opened, no probe round paid
+        (speculative_declined_passes_total), until a declined pass finds
+        the queue's feasible share halved (_device_wave).  A pass of
+        fewer pods than a round needs to be evidence (MIN_ROUND) is
+        neither: it can set no record, so it follows none and runs its
+        rounds as it always did, on the executables its bucket's first
+        pass of this session built.  Nor is a pass of more than one
+        chunk: it delivers chunk by chunk, cannot start again, and keeps
+        its ladder and its in-stream fallback whatever the record says."""
         if self._needs_host_path():
             return WavePlan("host_loop", "host_loop", "by_pod")
         # _gang_vectorized: the gang plugin is the only lifecycle plugin
@@ -1418,14 +1439,21 @@ class SchedulerEngine:
         # threshold: at one pod the two scans compute the same thing).
         # KSS_TPU_SPECULATIVE=0 pins the sequential scan: the parity
         # baseline the golden suite diffs against
-        scan = "sequential"
+        scan, declined = "sequential", False
         if (os.environ.get("KSS_TPU_SPECULATIVE", "1") != "0"
                 and self.extender_service is None and not lifecycle):
-            from ..parallel.speculative import speculation_ok
+            from ..parallel.speculative import MIN_ROUND, speculation_ok
 
             if speculation_ok(self.plugin_config, have_manifests=True,
                               ignore=ignore):
-                if n_pods >= 2:
+                if (MIN_ROUND <= n_pods <= self.chunk
+                        and CONTROLS.spec_collapsed(
+                            self.session,
+                            self.plugin_config.signature()) is not None):
+                    declined = True
+                    TRACER.inc("speculative_declined_passes_total")
+                    TRACER.count("speculative_rounds_total", 0)
+                elif n_pods >= 2:
                     scan = "speculative"
                 else:
                     # the pass's zero rounds, counted: a batchable profile
@@ -1457,7 +1485,7 @@ class SchedulerEngine:
         else:
             results = "by_chunk"
         return WavePlan(scan, "streamed" if streamed else "post_pass",
-                        results)
+                        results, declined)
 
     def _device_wave(self, plan: WavePlan, cw, mesh, pending: list[dict],
                      exclude: set[tuple[str, str]] | None,
@@ -1497,11 +1525,7 @@ class SchedulerEngine:
                   device_resident=plan.results == "device_lazy")
         span, stage, attrs = "replay_and_decode_stream", "replay_stream", {}
         if plan.scan == "speculative":
-            from ..parallel.speculative import replay_speculative_stream
-
             stage, attrs = "speculative_replay", {"mode": "speculative"}
-            kw.update(pods=pending, gang=gang, ignore=ignore,
-                      namespaces=self._list_shared("namespaces"))
         elif plan.results == "by_pod":
             span = stage = "device_replay"
         try:
@@ -1511,10 +1535,23 @@ class SchedulerEngine:
                     # the worker's commit_stream spans parent under the
                     # wave's replay span across the thread boundary
                     committer.parent_span = sp.id
+                rr = None
                 if plan.scan == "speculative":
-                    rr, _stats = replay_speculative_stream(cw, mesh, **kw)
-                else:
+                    from ..parallel.speculative import (
+                        replay_speculative_stream)
+
+                    rr, _stats = replay_speculative_stream(
+                        cw, mesh, pods=pending, gang=gang, ignore=ignore,
+                        namespaces=self._list_shared("namespaces"), **kw)
+                if rr is None:
+                    # the sequential scan, the packed route's one call for
+                    # a pass of one chunk: the plan's own, or the same pass
+                    # started again where its first speculative round
+                    # collapsed (nothing was delivered, so the committer
+                    # and the abort protocol stand as they were)
                     rr = replay(cw, mesh=mesh, **kw)
+                if plan.declined:
+                    self._recheck_rounds(cw, rr)
         except BaseException as e:
             if committer is None:
                 # nothing was committed yet (_finish_wave commits AFTER
@@ -1550,6 +1587,22 @@ class SchedulerEngine:
             all_annotations = _LazyDecode(rr)
         return self._finish_wave(cw, rr, all_annotations, pending, exclude,
                                  lazy_wave=lazy_wave)
+
+    def _recheck_rounds(self, cw, rr) -> None:
+        """A declined pass's feasible share against the session's record
+        of collapsed rounds: the dirty-node rule accepts long prefixes
+        exactly where feasibility is sparse, and the scan brings every
+        pod's feasible count back in its one decision row, so where the
+        pass's median share has fallen to half the collapsed round's or
+        less the record is cleared and the next batch pass tries the
+        rounds again (speculative_retries_total)."""
+        from ..utils.blackbox import BLACKBOX
+
+        share = float(np.median(rr.feasible_count)) / max(cw.n_nodes, 1)
+        if CONTROLS.spec_recheck(self.session, share):
+            TRACER.inc("speculative_retries_total")
+            BLACKBOX.record("speculative.retry",
+                            feasible_share=round(share, 4))
 
     def _record_attribution(self, rr, replay_seconds: float,
                             att: dict | None = None) -> None:

@@ -1233,8 +1233,6 @@ def _leaf_sig(leaf) -> tuple:
 
 
 def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
-    import json
-
     mesh_sig = tuple(mesh.shape.items()) if mesh is not None else None
     # the packed layout says what the leaves would: asking for them would
     # unpack a workload that the sequential scan takes packed
@@ -1245,19 +1243,7 @@ def _workload_scan_key(cw: CompiledWorkload, chunk: int, mesh=None):
         for tree in trees
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
     )
-    cfg = cw.config
-    cfg_sig = (
-        tuple(cfg.enabled),
-        tuple(sorted((n, cfg.weight(n)) for n in cfg.scorers())),
-        tuple((n, id(p)) for n, p in sorted(cfg.custom.items())),
-        json.dumps(cfg.args, sort_keys=True, default=str),
-        tuple(cw.schema.columns),
-        # per-point overrides change the jitted step's plugin lineup
-        # (filters()/prescorers() are baked into the closure)
-        tuple(sorted((k, tuple(v)) for k, v in cfg.point_enabled.items())),
-        tuple(sorted((k, tuple(sorted(v)))
-                     for k, v in cfg.point_disabled.items())),
-    )
+    cfg_sig = (cw.config.signature(), tuple(cw.schema.columns))
     return (_statics_fingerprint(cw), mesh_sig, shapes, cfg_sig, chunk)
 
 

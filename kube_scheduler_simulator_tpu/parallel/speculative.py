@@ -47,8 +47,21 @@ walk on host over the pod manifests.  Where the win comes from:
 acceptance is long exactly when feasibility is SPARSE (taints, affinity
 pins, zone constraints, tight fit — i.e. realistic packed clusters).
 In a fully relaxed cluster where every pod fits everywhere the rule
-cuts every batch at ~1 — so a CONTENTION-AWARE controller watches the
-observed accept rate: full-accept rounds climb the batch ladder,
+cuts every batch at ~1, and a cluster with room is what most simulated
+clusters are — so the rounds watch their own record and get out of the
+way.  A pass of ONE chunk (every served pass) whose FIRST round holds at
+least MIN_ROUND pods and keeps a quarter of them or less ENDS there:
+nothing has been delivered and cw.init_carry is intact, so the stream
+returns no result and its caller (SchedulerEngine._device_wave) runs the
+same pass as the sequential scan's one packed call, which decides the
+pods the round had accepted again, bit-identically.  The session
+remembers the collapse with the round's median feasible share
+(CONTROLS.note_spec_collapsed), the engine's plan then sends the
+session's batch passes to the scan from the start, and the rounds are
+tried again once a pass's median share has fallen to half of that.
+Mid-pass, and on a pass of more chunks (whose delivered chunks stand), a
+CONTENTION-AWARE controller watches the observed accept rate:
+full-accept rounds climb the batch ladder,
 heavily-cut rounds step it down, and a sustained accept collapse at the
 bottom rung FALLS BACK to the sequential chunked scan for the rest of
 the wave (the same jitted scan the non-speculative path runs, resumed
@@ -98,7 +111,8 @@ Env knobs (docs/environment-variables.md): KSS_TPU_SPECULATIVE=0
 disables the engine default; KSS_TPU_SPECULATIVE_BATCH pins the batch
 (one rung); KSS_TPU_SPECULATIVE_CANDIDATES caps the sparse tail's
 candidate set; KSS_TPU_SPECULATIVE_MIN_ACCEPT /
-KSS_TPU_SPECULATIVE_FALLBACK_ROUNDS tune the scan-fallback trigger;
+KSS_TPU_SPECULATIVE_FALLBACK_ROUNDS tune the mid-pass scan-fallback
+trigger (not the first round's way out, which no variable tunes);
 KSS_TPU_SPECULATIVE_TILE sizes the CPU backend's cache-tiled vmap.
 """
 
@@ -613,6 +627,12 @@ def _accum_fns(shapes_key, chunk: int):
 
 # ------------------------------------------------------------- ladder
 
+# the batch ladder's bottom rung (per dp shard), and the fewest pods a
+# round must hold for its accepted prefix to be evidence of anything: a
+# round of 2-7 pods that keeps one says nothing of the queue
+MIN_ROUND = 8
+
+
 def _batch_ladder(chunk: int, dp: int, pinned: int | None) -> list[int]:
     """Adaptive batch rungs: dp multiples (the dp shards stay balanced)
     growing x4 from 8*dp up to the chunk grid.  Each rung is one extra
@@ -628,7 +648,7 @@ def _batch_ladder(chunk: int, dp: int, pinned: int | None) -> list[int]:
     if pinned is not None:
         return [fit(pinned)]
     rungs: list[int] = []
-    b = 8 * dp
+    b = MIN_ROUND * dp
     while fit(b) < fit(chunk):
         rungs.append(fit(b))
         b *= 4
@@ -650,6 +670,7 @@ class _SpecStats:
         self.rounds: list[tuple[int, int]] = []   # (accepted, round size)
         self.scan_pods = 0
         self.fallback_at: int | None = None
+        self.collapsed = False
         self.final_batch = 0
 
     def as_dict(self, adaptive: bool) -> dict:
@@ -668,6 +689,7 @@ class _SpecStats:
             "accept_rate": round(total / (total + rolled), 4)
                 if total + rolled else None,
             "fallback_at": self.fallback_at,
+            "collapsed": self.collapsed,
             "scan_pods": self.scan_pods,
         }
 
@@ -678,7 +700,7 @@ def replay_speculative_stream(
         namespaces: list[dict] | None = None, on_chunk=None,
         device_resident: bool | None = None, gang=None,
         scan_fallback: bool = True, ignore: frozenset | set = frozenset(),
-) -> tuple[ReplayResult, dict]:
+) -> tuple[ReplayResult | None, dict]:
     """Schedule the whole queue in streaming speculative rounds (module
     doc).  Same consumer contract as framework.replay.replay(): compact
     chunk-grid results, on_chunk(rr, lo, hi) in ascending contiguous
@@ -695,6 +717,10 @@ def replay_speculative_stream(
 
     Returns (rr, stats): rr is bit-identical to replay(cw) / the
     sequential oracle; stats records rounds, acceptance and fallback.
+    rr is None, and stats["collapsed"] true, where the first round of a
+    one-chunk pass collapsed (scan_fallback only; module doc): nothing
+    was delivered to on_chunk, and the caller runs the pass through
+    replay(), as SchedulerEngine._device_wave does.
     Caller must have checked speculation_ok(cw.config, ...)."""
     device_resident = _resolve_device_resident(device_resident, on_chunk)
     active = set(cw.config.active_plugins())
@@ -770,7 +796,7 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
               wide, inter, gang, scan_fallback: bool,
               ignore: frozenset | set = frozenset(),
               fuse_stream=None,
-              ) -> tuple[ReplayResult, dict] | None:
+              ) -> tuple[ReplayResult | None, dict] | None:
     from ..framework.gang import aligned_cut
     from .mesh import gather_to_host
 
@@ -1024,6 +1050,39 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
 
         return jax.tree.map(place, xs_batch)
 
+    # a pass of one chunk delivers once, at its end: until then nothing
+    # is committed and the pass can start again as another route
+    restartable = scan_fallback and p <= chunk
+
+    def tally_round(b: int, m: int, accepted: int, kept: int) -> None:
+        """A round's record: `accepted` is the prefix the rules passed,
+        `kept` what of it stays decided (all of it, or nothing where the
+        round collapsed the pass)."""
+        stats.rounds.append((kept, m))
+        stats.final_batch = b
+        TRACER.count("speculative_rounds_total")
+        TRACER.inc("speculative_accepted_total", kept)
+        if m > kept:
+            TRACER.inc("speculative_rolled_back_total", m - kept)
+        TRACER.observe("speculative_accept_fraction", accepted / m)
+        # black-box round history (utils/blackbox.py): the evidence a
+        # post-mortem needs to explain WHY the controller climbed,
+        # dropped, or fell back — batch size, accept fraction, rung
+        BLACKBOX.record("speculative.round", batch=m, accepted=accepted,
+                        rung=b, accept_fraction=round(accepted / m, 4))
+
+    def hand_over(at: int, **why) -> None:
+        """The rounds end and the sequential scan takes pod `at` on.  No
+        more rounds are dispatched: close the fuse stream NOW
+        (idempotent — the tier loop's finally closes again harmlessly) so
+        partner leaders stop counting this stream as a batch-mate."""
+        if fuse_stream is not None:
+            FUSE.stream_close(fuse_stream)
+        stats.fallback_at = at
+        TRACER.inc("speculative_fallbacks_total")
+        BLACKBOX.record("speculative.fallback", at=at,
+                        rounds=len(stats.rounds), **why)
+
     lo = 0
     while lo < p:
         fault_point("speculative.round")
@@ -1142,6 +1201,20 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
                 k = min(int(k_dev), m)
             TRACER.count("wave_d2h_bytes_total",
                          sel.nbytes + fc.nbytes + rej.nbytes + ovf.nbytes + 4)
+            if (restartable and not stats.rounds and m >= MIN_ROUND
+                    and 4 * k <= m):
+                # the pass's FIRST round kept a quarter of what it
+                # evaluated or less: these rounds do not accept (module
+                # doc).  Nothing was delivered and cw.init_carry is
+                # intact, so the stream ends here and its caller runs the
+                # whole pass as the sequential scan's one call
+                share = float(np.median(fc[:m])) / n
+                tally_round(b, m, k, 0)
+                hand_over(0, restart=True, feasible_share=round(share, 4))
+                stats.collapsed = True
+                CONTROLS.note_spec_collapsed(session, cw.config.signature(),
+                                             share)
+                return None, stats.as_dict(adaptive)
             if inter is not None and k > 1:
                 k = _interaction_cut(inter, sel, lo, k)
             if gang is not None:
@@ -1164,18 +1237,7 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
                 fill += k
                 while fill >= chunk:
                     emit_chunk()
-        stats.rounds.append((k, m))
-        stats.final_batch = b
-        TRACER.count("speculative_rounds_total")
-        TRACER.inc("speculative_accepted_total", k)
-        if m > k:
-            TRACER.inc("speculative_rolled_back_total", m - k)
-        TRACER.observe("speculative_accept_fraction", k / m)
-        # black-box round history (utils/blackbox.py): the evidence a
-        # post-mortem needs to explain WHY the controller climbed,
-        # dropped, or fell back — batch size, accept fraction, rung
-        BLACKBOX.record("speculative.round", batch=m, accepted=k,
-                        rung=b, accept_fraction=round(k / m, 4))
+        tally_round(b, m, k, k)
         lo += k
         # contention-aware controller: full-accept rounds climb the
         # ladder, heavily-cut rounds step down, and a sustained accept
@@ -1192,16 +1254,7 @@ def _spec_run(cw: CompiledWorkload, mesh, chunk: int, unroll: int,
                 low_streak += 1
                 if low_streak >= fallback_rounds:
                     mode = "scan"
-                    # the scan tail dispatches no more rounds: close the
-                    # fuse stream NOW (idempotent — the tier loop's
-                    # finally closes again harmlessly) so partner
-                    # leaders stop counting this stream as a batch-mate
-                    if fuse_stream is not None:
-                        FUSE.stream_close(fuse_stream)
-                    stats.fallback_at = lo
-                    TRACER.inc("speculative_fallbacks_total")
-                    BLACKBOX.record("speculative.fallback", at=lo,
-                                    rounds=len(stats.rounds))
+                    hand_over(lo)
             else:
                 low_streak = 0
 

@@ -13,6 +13,7 @@ framework tensorizes so far.
 
 from __future__ import annotations
 
+import json
 from dataclasses import dataclass, field
 
 
@@ -170,6 +171,29 @@ class PluginSetConfig:
                     seen.add(n)
         order = {n: i for i, n in enumerate(DEFAULT_ORDER)}
         return sorted(out, key=lambda n: order.get(n, 99))
+
+    def signature(self) -> tuple:
+        """Everything of the profile that decides what a pass computes,
+        as one hashable value: two configs with equal signatures run the
+        same plugin lineup with the same weights and args.  The scan
+        cache keys its executables on it, and the session's record of
+        collapsed speculative rounds (control/__init__.py) is kept for
+        the profile that made it: the same profile posted again is the
+        same signature, a differing one is not."""
+        import json
+
+        return (
+            tuple(self.enabled),
+            tuple(sorted((n, self.weight(n)) for n in self.scorers())),
+            tuple((n, id(p)) for n, p in sorted(self.custom.items())),
+            json.dumps(self.args, sort_keys=True, default=str),
+            # per-point overrides change the jitted step's plugin lineup
+            # (filters()/prescorers() are baked into the closure)
+            tuple(sorted((k, tuple(v))
+                         for k, v in self.point_enabled.items())),
+            tuple(sorted((k, tuple(sorted(v)))
+                         for k, v in self.point_disabled.items())),
+        )
 
     def filters(self) -> list[str]:
         return self._point_set(

@@ -181,9 +181,22 @@ _HELP: dict[str, str] = {
         "Accepted fraction of each speculative round's batch "
         "(accepted / round size; 1.0 = the whole batch committed).",
     "speculative_fallbacks_total":
-        "Speculative waves that handed their remainder to the "
-        "sequential chunked scan after a sustained accept-rate collapse "
-        "at the bottom batch rung (docs/wave-pipeline.md).",
+        "Hand-overs of a speculative wave to the sequential scan after "
+        "an accept-rate collapse: the remainder handed to the chunked "
+        "scan in-stream after a sustained collapse at the bottom batch "
+        "rung, or a one-chunk pass whose first round kept a quarter or "
+        "less and that started again as the packed scan "
+        "(docs/wave-pipeline.md).",
+    "speculative_declined_passes_total":
+        "Batch passes on a batchable profile that the wave plan sent to "
+        "the sequential scan from the start, no round run, because the "
+        "session's last tried speculative rounds had collapsed "
+        "(framework/engine.py _wave_plan; docs/wave-pipeline.md row 9).",
+    "speculative_retries_total":
+        "Times a session's record of collapsed speculative rounds was "
+        "cleared because a declined pass's median feasible share had "
+        "fallen to half the collapsed round's or less: the next batch "
+        "pass tries the rounds again.",
     "tracer_events_dropped_total":
         "Span events evicted from the tracer's fixed-size ring because "
         "it was full — a long soak whose trace tail silently scrolled "

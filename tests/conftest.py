@@ -7,6 +7,8 @@ separately dry-runs the multi-chip path; real TPU is reserved for bench).
 import os
 import sys
 
+import pytest
+
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -43,3 +45,14 @@ def pytest_configure(config):
         "markers",
         "slow: long-running / tooling-heavy tests (excluded from tier-1, "
         "which runs -m 'not slow'); e.g. the codec-suite-under-ASan run")
+
+
+@pytest.fixture(autouse=True)
+def _fresh_session_controls():
+    """The per-session control registry is the process's: engines made
+    without a session share the entry of session None, and one test's
+    speculative rounds (narrow, collapsed) must not plan the next's."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+
+    CONTROLS.reset()
+    yield

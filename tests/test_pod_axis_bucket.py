@@ -138,7 +138,8 @@ def test_passes_of_any_count_decide_as_one_a_pass(route, one_a_pass,
     # nothing; the rounds have two accumulator ops, an evaluation, an
     # oracle, a bind fold and a sparse round a rung of the bucket's
     # ladder, and the scan they fall back to when acceptance collapses
-    # (here met by the pass of 7, in the bucket the pass of 5 opened)
+    # (here met by the pass of 7, in the bucket the pass of 5 opened;
+    # the packed one where a batch pass starts again or is declined)
     for rows, by_pass in missed.items():
         if route == "rounds" and rows > 1:
             rungs = len(_batch_ladder(rows, 1, None))
@@ -160,20 +161,31 @@ def test_passes_of_any_count_decide_as_one_a_pass(route, one_a_pass,
     rebuckets = sum(pod_axis_bucket(a, CHUNK) != pod_axis_bucket(b, CHUNK)
                     for a, b in zip(counts, counts[1:]))
     assert _counter("pod_axis_rebuckets_total") == rebuckets
-    assert _labeled("replay_route_total", "route", label) >= len(counts) - 1
     if route == "rounds":
         # a pass of one pod has nothing to speculate on; every other
-        # pod went through a round or the rounds' scan fallback, never a
-        # pad row
+        # pod went through a round, the rounds' scan fallback or, once
+        # the first round of a batch pass had collapsed on this roomy
+        # cluster, the packed scan that pass started again as and the
+        # session's later batch passes were sent to (declined: they open
+        # no stream, so they are no pass over leaves); never a pad row
         spec = TRACER.summary()
         accepted = sum(TRACER.labeled_totals(
             "speculative_accepted_total", "session").values())
         fell_back = sum(TRACER.labeled_totals(
             "speculative_fallbacks_total", "session").values())
+        declined = sum(TRACER.labeled_totals(
+            "speculative_declined_passes_total", "session").values())
         assert accepted <= len(pods) - 1
         assert accepted == len(pods) - 1 or fell_back, spec["counters"]
         assert _counter("speculative_rounds_total") > 0
+        assert declined >= 1, spec["counters"]
+        assert _labeled("replay_route_total", "route", "leaves") \
+            == len(counts) - 1 - declined
+        assert _labeled("replay_route_total", "route", "packed") \
+            >= 1 + 1 + declined
     else:
+        assert _labeled("replay_route_total", "route", label) \
+            >= len(counts) - 1
         assert _counter("speculative_rounds_total") == 0
 
 

@@ -3,7 +3,9 @@
 bytes, bind order, result history, parked gangs — plus the PR 12
 composition (mid-round fault -> uncommitted-suffix retry) and the
 contention scan-fallback (docs/wave-pipeline.md speculative-wave
-stage)."""
+stage), and the way out of rounds that do not accept: a one-chunk pass
+whose first round collapses starts again as the packed scan, and the
+rounds are tried again once the queue's feasible share has halved."""
 
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ from kube_scheduler_simulator_tpu.models.workloads import (
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
+from test_wave_path_table import _route  # noqa: E402
+
 DEFAULT_ENABLED = [
     "NodeResourcesFit", "NodeResourcesBalancedAllocation", "NodeAffinity",
     "TaintToleration", "PodTopologySpread",
@@ -25,7 +29,7 @@ DEFAULT_ENABLED = [
 
 
 def _run_wave(nodes, pods, enabled, monkeypatch, speculative: bool,
-              chunk: int = 16, pgs=(), custom=None, env=()):
+              chunk: int = 16, pgs=(), custom=None, env=(), **engine_kw):
     """One engine pass; returns (state, bind_order, parked) where state
     maps pod name -> (nodeName, ALL annotations — result history
     included)."""
@@ -45,7 +49,8 @@ def _run_wave(nodes, pods, enabled, monkeypatch, speculative: bool,
     for p in pods:
         store.create("pods", p)
     engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
-        enabled=list(enabled), custom=dict(custom or {})), chunk=chunk)
+        enabled=list(enabled), custom=dict(custom or {})), chunk=chunk,
+        **engine_kw)
 
     # bind ORDER: every bind funnels through _commit_pod_batch on the
     # batched paths and _bind on the post-pass/gang-release paths
@@ -190,6 +195,141 @@ def test_contended_wave_falls_back_to_scan_and_matches(monkeypatch):
     assert fallbacks >= 1, "contended wave never engaged the scan fallback"
     seq = _run_wave(nodes, pods, enabled, monkeypatch, False, chunk=16)
     _assert_identical(spec, seq)
+
+
+RELAXED = ["NodeResourcesFit", "NodeResourcesBalancedAllocation"]
+
+
+def _labeled(name, label="session"):
+    return sum(TRACER.labeled_totals(name, label).values())
+
+
+@pytest.mark.parametrize("streamed", [True, False],
+                         ids=["streamed", "post_pass"])
+@pytest.mark.parametrize("count", [8, 9, 30, 32])
+def test_collapsed_first_round_starts_again_as_the_packed_scan(
+        count, streamed, monkeypatch):
+    """A one-chunk pass of pods that fit everywhere: its first round
+    keeps one pod, so the stream ends there and the SAME pass runs as the
+    sequential scan's one packed call (one dispatch, one fetch), under
+    either commit; byte-identical to KSS_TPU_SPECULATIVE=0."""
+    nodes = make_nodes(16, seed=31)
+    pods = make_pods(count, seed=32)
+    kw = dict(chunk=64, pipeline_commit=streamed)
+    TRACER.reset()
+    spec = _run_wave(nodes, pods, RELAXED, monkeypatch, True, **kw)
+    counters = TRACER.counter_totals()
+    assert counters["speculative_rounds_total"] == 1
+    assert _labeled("speculative_fallbacks_total") == 1
+    assert _labeled("speculative_accepted_total") == 0
+    # (16 nodes are inside the candidate cap, so the rounds start dense, at
+    # the ladder's bottom rung: a first round of eight)
+    assert _labeled("speculative_rolled_back_total") == 8
+    assert (_route("leaves"), _route("packed")) == (1, 1)
+    assert counters["decision_fetch_transfers_total"] == 1
+    assert counters.get("commit_stream_waves_total", 0) == int(streamed)
+    assert counters.get("wave_retries_total", 0) == 0
+    seq = _run_wave(nodes, pods, RELAXED, monkeypatch, False, **kw)
+    _assert_identical(spec, seq)
+    assert all(s[0] for s in spec[0].values())  # everything bound
+
+
+def test_a_round_of_fewer_than_eight_is_no_evidence(monkeypatch):
+    """Seven pods that fit everywhere: every round keeps one, and the
+    pass runs its rounds as it always did: three low ones, then the
+    stream's own scan over leaves; the session keeps no record."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+
+    nodes = make_nodes(16, seed=31)
+    pods = make_pods(7, seed=32)
+    TRACER.reset()
+    spec = _run_wave(nodes, pods, RELAXED, monkeypatch, True, chunk=64)
+    assert TRACER.counter_totals()["speculative_rounds_total"] == 3
+    assert _labeled("speculative_fallbacks_total") == 1
+    assert (_route("leaves"), _route("packed")) == (1, 0)
+    assert CONTROLS.spec_collapsed(
+        None, PluginSetConfig(enabled=list(RELAXED)).signature()) is None
+    seq = _run_wave(nodes, pods, RELAXED, monkeypatch, False, chunk=64)
+    _assert_identical(spec, seq)
+
+
+@pytest.mark.parametrize("seam, nth", [("speculative.round", 1),
+                                       ("replay.scan_dispatch", 1),
+                                       ("replay.scan_dispatch", 2)],
+                         ids=["round", "round_dispatch", "restart_dispatch"])
+def test_fault_around_the_restart_retries_and_stays_identical(
+        seam, nth, monkeypatch):
+    """A transient fault before the collapsing round, in it, and at the
+    restarted pass's one dispatch (the seam's second visit): nothing was
+    committed, the whole pass retries, and the result is byte-identical
+    to the fault-free run."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+    from kube_scheduler_simulator_tpu.utils import faults
+
+    nodes = make_nodes(16, seed=31)
+    pods = make_pods(30, seed=32)
+    clean = _run_wave(nodes, pods, RELAXED, monkeypatch, True, chunk=64)
+    CONTROLS.reset()
+    TRACER.reset()
+    plan = faults.FaultPlan([faults.FaultRule(seam, nth=nth,
+                                              error="runtime")], seed=7)
+    with faults.armed(plan):
+        faulted = _run_wave(nodes, pods, RELAXED, monkeypatch, True, chunk=64)
+    assert plan.stats()["rules"][0]["trips"] == 1, "fault never fired"
+    assert TRACER.counter_totals().get("wave_retries_total", 0) >= 1
+    _assert_identical(faulted, clean)
+
+
+def test_rounds_are_tried_again_once_the_feasible_share_has_halved(
+        monkeypatch):
+    """(3) of the mechanism.  A session whose rounds collapsed on a
+    relaxed burst (every node feasible) declines its next batch pass; that
+    pass is of slot-pinned pods (2 feasible nodes of 24), so its scan
+    reads a median share under half the remembered one, the record is
+    cleared (speculative_retries_total 1), and the pass after it runs the
+    rounds, which accept every pod.  Byte-identical to the sequential
+    scan throughout."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+
+    nodes, pinned = make_slot_pinned_workload(24, 24, seed=41)
+    relaxed = make_pods(12, seed=42)
+    enabled = RELAXED + ["NodeAffinity"]
+    bursts = [relaxed, pinned[:12], pinned[12:]]
+
+    def run(spec_on):
+        monkeypatch.setenv("KSS_TPU_SPECULATIVE", "1" if spec_on else "0")
+        CONTROLS.reset()
+        TRACER.reset()
+        store = ObjectStore()
+        for n in nodes:
+            store.create("nodes", n)
+        engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
+            enabled=list(enabled)), chunk=64)
+        engine.session = "retry-test"
+        seen = []
+        for burst in bursts:
+            for p in burst:
+                store.create("pods", p)
+            assert engine.schedule_pending() == len(burst)
+            seen.append((
+                TRACER.counter_totals().get("speculative_rounds_total", 0),
+                _labeled("speculative_declined_passes_total"),
+                _labeled("speculative_retries_total"),
+                _labeled("speculative_accepted_total")))
+        state = {p["metadata"]["name"]: (
+            p["spec"].get("nodeName"), dict(p["metadata"]["annotations"]))
+            for p in store.list("pods")[0]}
+        engine.close()
+        return state, seen
+
+    spec, seen = run(True)
+    # rounds, declined, retries, accepted after each burst
+    assert seen[0] == (1, 0, 0, 0)          # collapsed, restarted
+    assert seen[1] == (1, 1, 1, 0)          # declined; share 2/24 <= 1/2
+    assert seen[2] == (3, 1, 1, 12), seen   # rounds of 8 and 4: all kept
+    seq, _ = run(False)
+    assert spec == seq
+    assert all(node for node, _a in spec.values())
 
 
 def test_sparse_candidate_eval_through_engine(monkeypatch):

@@ -384,6 +384,92 @@ def test_row08_two_pods_a_pass_are_a_round():
         assert node and annotations
 
 
+def _roomy_session(session, enabled=CONFIG_3[:2]):
+    """16 nodes that every pod of make_pods fits: a cluster with room,
+    on which the dirty-node rule cuts every round at one pod."""
+    store = ObjectStore()
+    for n in make_nodes(16, seed=31):
+        store.create("nodes", n)
+    engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
+        enabled=list(enabled)), chunk=64)
+    engine.session = session
+    served = [0]
+
+    def burst(count=12):
+        pods = make_pods(count, seed=32 + served[0])
+        for p in pods:
+            p["metadata"]["name"] += f"-{served[0]}"
+            store.create("pods", p)
+        served[0] += 1
+        assert engine.schedule_pending() == count
+
+    return engine, burst
+
+
+def _declined():
+    return sum(TRACER.labeled_totals(
+        "speculative_declined_passes_total", "session").values())
+
+
+def test_row09_a_session_whose_rounds_collapsed_declines_its_batch_passes():
+    """The plan's fifth observation, the rounds' own record: after a pass
+    whose first round collapsed, the session's batch passes are the
+    sequential scan from the start (row 9: no stream, no round), and
+    counted; a pass too small to be evidence, another session, the
+    session after CONTROLS.drop / reset, and a differing profile try the
+    rounds, as a session does that no round has served yet."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+
+    declined = WavePlan("sequential", "streamed", "device_lazy", True)
+    rounds = WavePlan("speculative", "streamed", "device_lazy")
+    engine, burst = _roomy_session("a")
+    TRACER.reset()
+    assert engine._wave_plan(12) == rounds
+    burst()
+    assert _counter("speculative_rounds_total") == 1      # it collapsed
+    assert (_route("leaves"), _route("packed")) == (1, 1)
+    before = _declined()
+    assert engine._wave_plan(12) == declined
+    assert _declined() == before + 1
+    # the next pass: no round, one packed call, counted once
+    burst()
+    assert _counter("speculative_rounds_total") == 1
+    assert (_route("leaves"), _route("packed")) == (1, 2)
+    assert _declined() == before + 2
+    # (a plan asked for outside a pass, as here, has no session's scope)
+    assert TRACER.labeled_totals(
+        "speculative_declined_passes_total", "session")["a"] == 1
+    # 2-7 pods set no record and follow none, nor does a pass of more
+    # than one chunk (64 here); one pod is row 9 as ever
+    assert engine._wave_plan(7) == rounds
+    assert engine._wave_plan(64) == declined
+    assert engine._wave_plan(65) == rounds
+    assert engine._wave_plan(1) == WavePlan(
+        "sequential", "streamed", "device_lazy")
+    # another session of the process has its own record
+    other, _ = _roomy_session("b")
+    assert other._wave_plan(12) == rounds
+    # the record goes with the session, and with the fail-safe
+    CONTROLS.drop("a")
+    assert engine._wave_plan(12) == rounds
+    burst()
+    assert _counter("speculative_rounds_total") == 2
+    assert engine._wave_plan(12) == declined
+    CONTROLS.reset()
+    assert engine._wave_plan(12) == rounds
+    burst()
+    assert engine._wave_plan(12) == declined
+    # the record is the profile's: a differing one tries the rounds, the
+    # same one posted again (the burst driver posts it every cycle) is
+    # the same signature
+    engine.set_plugin_config(PluginSetConfig(enabled=list(CONFIG_3[:3])))
+    assert engine._wave_plan(12) == rounds
+    engine.set_plugin_config(PluginSetConfig(enabled=list(CONFIG_3[:2])))
+    assert engine._wave_plan(12) == declined
+    engine.close()
+    other.close()
+
+
 def test_one_pod_a_pass_and_one_pass_of_all_are_byte_equal():
     """The plan's two answers for one profile give one result: the same
     pods served one a pass (the sequential scan's one call) and as one
