@@ -33,7 +33,7 @@ from ..control import CONTROLS
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
-from ..state.compile import POD_CHUNK, compile_workload
+from ..state.compile import POD_CHUNK, compile_workload, pod_axis_bucket
 from ..store import annotations as ann
 from ..store.decode import decode_chunk_into, decode_pod_result
 from ..store.reflector import StoreReflector
@@ -558,8 +558,13 @@ class SchedulerEngine:
         self.plugin_config = plugin_config or PluginSetConfig()
         self.chunk = chunk
         self._last_pod_axis: int | None = None
-        # lax.scan unroll for replay waves: the step's [N] ops are tiny,
-        # so per-iteration overhead matters
+        # lax.scan unroll for replay waves of more than one chunk: the
+        # step's [N] ops are tiny, so per-iteration overhead matters.  A
+        # pass of one chunk (every served pass) is not unrolled
+        # (_device_wave): its scan is compiled in the session's first
+        # pass of a bucket, a second copy of the step is 8-9 s more of
+        # that compile at 5,000 nodes with PodTopologySpread, and the
+        # device idles 99.9% of a served cycle
         self.unroll = unroll
         # optional jax.sharding.Mesh with a "nodes" axis: every batched
         # replay shards the node axis across it (parallel/mesh.py)
@@ -1417,17 +1422,22 @@ class SchedulerEngine:
         rung and n_pods, is the rounds' own record: a pass whose first
         round kept a quarter of its pods or less gains nothing from the
         rounds (parallel/speculative.py), and the session remembers it
-        (CONTROLS.spec_collapsed, under the profile that made it).  A
+        (CONTROLS.spec_declines, under the profile that made it).  A
         batch pass of such a session is DECLINED: the sequential scan
         from the start, no stream opened, no probe round paid
         (speculative_declined_passes_total), until a declined pass finds
-        the queue's feasible share halved (_device_wave).  A pass of
-        fewer pods than a round needs to be evidence (MIN_ROUND) is
-        neither: it can set no record, so it follows none and runs its
-        rounds as it always did, on the executables its bucket's first
-        pass of this session built.  Nor is a pass of more than one
-        chunk: it delivers chunk by chunk, cannot start again, and keeps
-        its ladder and its in-stream fallback whatever the record says."""
+        the queue's feasible share halved (_device_wave) and a batch pass
+        comes on a pod-axis bucket the session's rounds have run on: that
+        pass is the probe, on executables that exist.  A pass of
+        fewer pods than a round needs to be evidence (MIN_ROUND) has no
+        round to gain from either: one round that accepts all of it costs
+        the host what the packed scan of the whole pass does (~6 ms against
+        3.4 ms + the steps, PERF.md section 7), it can set no record and
+        would build a bucket's round executables to follow none, so it
+        takes the sequential scan as a pass of one pod does.  A pass of
+        more than one chunk delivers chunk by chunk, cannot start again,
+        and keeps its ladder and its in-stream fallback whatever the
+        record says."""
         if self._needs_host_path():
             return WavePlan("host_loop", "host_loop", "by_pod")
         # _gang_vectorized: the gang plugin is the only lifecycle plugin
@@ -1447,13 +1457,13 @@ class SchedulerEngine:
             if speculation_ok(self.plugin_config, have_manifests=True,
                               ignore=ignore):
                 if (MIN_ROUND <= n_pods <= self.chunk
-                        and CONTROLS.spec_collapsed(
-                            self.session,
-                            self.plugin_config.signature()) is not None):
+                        and CONTROLS.spec_declines(
+                            self.session, self.plugin_config.signature(),
+                            pod_axis_bucket(n_pods, self.chunk))):
                     declined = True
                     TRACER.inc("speculative_declined_passes_total")
                     TRACER.count("speculative_rounds_total", 0)
-                elif n_pods >= 2:
+                elif n_pods >= MIN_ROUND:
                     scan = "speculative"
                 else:
                     # the pass's zero rounds, counted: a batchable profile
@@ -1521,7 +1531,8 @@ class SchedulerEngine:
                 decode_chunk_into(rr_, lo, hi, all_annotations)
 
         # self.chunk as it is: the callee clamps it to the queue's length
-        kw = dict(chunk=self.chunk, unroll=self.unroll, on_chunk=on_chunk,
+        unroll = self.unroll if len(pending) > self.chunk else 1
+        kw = dict(chunk=self.chunk, unroll=unroll, on_chunk=on_chunk,
                   device_resident=plan.results == "device_lazy")
         span, stage, attrs = "replay_and_decode_stream", "replay_stream", {}
         if plan.scan == "speculative":
@@ -1594,8 +1605,8 @@ class SchedulerEngine:
         exactly where feasibility is sparse, and the scan brings every
         pod's feasible count back in its one decision row, so where the
         pass's median share has fallen to half the collapsed round's or
-        less the record is cleared and the next batch pass tries the
-        rounds again (speculative_retries_total)."""
+        less the rounds are asked for again (speculative_retries_total):
+        the next batch pass on a bucket they have run on tries them."""
         from ..utils.blackbox import BLACKBOX
 
         share = float(np.median(rr.feasible_count)) / max(cw.n_nodes, 1)

@@ -203,8 +203,8 @@ def _score_one(name: str, cw: CompiledWorkload, carry, sl, feasible):
         return raw, taints.taint_normalize(raw, feasible)
     if name == "PodTopologySpread":
         raw, ignored = topologyspread.score_kernel(
-            cw.statics["PodTopologySpread"], sl["PodTopologySpread"], carry["PodTopologySpread"]
-        )
+            cw.statics["PodTopologySpread"], sl["PodTopologySpread"],
+            carry["PodTopologySpread"], feasible)
         return raw, topologyspread.normalize(raw, ignored, feasible)
     if name == "InterPodAffinity":
         raw = interpod.score_kernel(
@@ -244,7 +244,7 @@ def renormalize(name: str, cw, carry, sl, raw, feasible):
     if name == "PodTopologySpread":
         _, ignored = topologyspread.score_kernel(
             cw.statics["PodTopologySpread"], sl["PodTopologySpread"],
-            carry["PodTopologySpread"])
+            carry["PodTopologySpread"], feasible)
         return topologyspread.normalize(raw, ignored, feasible)
     return raw  # no ScoreExtensions
 

@@ -570,7 +570,8 @@ class ReplayResult:
         tsp = self.cw.host.get("tsp_ignore")
         if tsp is None:
             return np.zeros((c, n), bool)
-        dom_neg, c_id, is_score = tsp  # [C, N] bool, [P, MC], [P, MC]
+        # [K, N] bool a topology key, [P, MC] each slot's key row, [P, MC]
+        dom_neg, c_id, is_score = tsp
         lo = ci * self._compact.chunk
         hi = min(lo + c, c_id.shape[0])
         out = np.zeros((c, n), bool)

@@ -39,8 +39,8 @@ acceptance rules compose:
 The first pod of every round is unconditionally safe, so each round
 commits >= 1 pod and the loop terminates.  (A queue of ONE pod is
 therefore one step of the scan and nothing else; the engine never hands
-this module one: SchedulerEngine._wave_plan sends a pass of one pod to
-the sequential scan.)  The dirty-node test runs ON
+this module one: SchedulerEngine._wave_plan sends a pass of fewer than
+MIN_ROUND pods to the sequential scan.)  The dirty-node test runs ON
 DEVICE (a [B, B] feasibility-at-selected-nodes gather; only the prefix
 length and the per-pod decision rows cross to host), the interaction
 walk on host over the pod manifests.  Where the win comes from:
@@ -58,7 +58,10 @@ pods the round had accepted again, bit-identically.  The session
 remembers the collapse with the round's median feasible share
 (CONTROLS.note_spec_collapsed), the engine's plan then sends the
 session's batch passes to the scan from the start, and the rounds are
-tried again once a pass's median share has fallen to half of that.
+tried again once a pass's median share has fallen to half of that, on
+the next batch pass whose bucket they have run on (a probe is worth a
+round, not a bucket's compile; of a probe that collapses too the record
+is no more than the share that asked for it).
 Mid-pass, and on a pass of more chunks (whose delivered chunks stand), a
 CONTENTION-AWARE controller watches the observed accept rate:
 full-accept rounds climb the batch ladder,
@@ -736,6 +739,14 @@ def replay_speculative_stream(
         raw = os.environ.get("KSS_TPU_SPECULATIVE_BATCH")
         if raw:
             batch = env_int("KSS_TPU_SPECULATIVE_BATCH", 0) or None
+
+    # the session's record of its rounds (control/__init__.py): which
+    # buckets they have run on, and whether this pass is the probe a
+    # declined pass asked for.  Once a pass, not once a width tier
+    bucket = pass_chunk(cw, chunk)
+    CONTROLS.note_spec_rounds(
+        TRACER.current_session(), cw.config.signature(), bucket,
+        probe=scan_fallback and cw.n_pods <= bucket)
 
     tiers = (("i64",) if "i64" in cw.host.get("score_dtypes", ())
              else (None, "i32", "i64"))

@@ -2,49 +2,64 @@
 
 Upstream v1.32 pkg/scheduler/framework/plugins/podtopologyspread.  The
 dynamic quantity is the number of already-placed pods matching each
-constraint's label selector per topology domain; it lives in the scan carry
-as a dense counts[C, D] matrix where C indexes *unique count groups*
-(namespace, topologyKey, selector) deduplicated across the whole workload
-and D indexes topology domains (distinct label values of the key).
+constraint's label selector PER NODE; it lives in the scan carry as a
+dense counts[C, N] matrix where C indexes *unique count groups*
+(namespace, topologyKey, selector) deduplicated across the pass's pods.
+A pod's per-domain count for one of its constraints is folded from it
+inside the step, over the nodes that constraint counts on (upstream's
+PreFilter / PreScore add a node's pods to a topology pair only where the
+node passes the constraint's inclusion policies and carries every key of
+the pod's constraints of that kind): `_fold`.
 
-Static precompiles:
-  dom_idx[C, N]    domain index of each node for each group key (-1: node
-                   lacks the topology label)
+Statics, all ARGUMENTS of the jitted scan (state/compile.py ARG_STATICS:
+they change with the QUEUE, another pod being another set of groups), on
+padded axes, so that a pass of other groups is no new executable:
+  dom_idx[K, N]    domain index of each node for each distinct topology
+                   key among the pass's groups (-1: node lacks the label;
+                   a pad row is all -1), from NodeTable.domain_row's memo
+  is_hostname[K]   the key is kubernetes.io/hostname (upstream's Score and
+                   PreScore special-case it BY NAME)
+  is_ident[K]      every keyed node is a domain of its own: the fold is
+                   the identity
+  group_key[C]     the dom_idx row of each count group
+  elig_rows[E, N]  the nodes a constraint's inclusion policies keep, one
+                   row a distinct (policies, nodeSelector, required
+                   affinity, tolerations); row 0 keeps every node
+  dom_iota[Dp]     0..Dp-1: Dp bounds the domains of every key that is
+                   not an identity (a zone's 8 -> 8)
+  log_table[N+1]   math.log(sz + 2) for sz = 0..N, float64, built on the
+                   host so that the value is libm's
+Per-pod xs:
   pm[P, C]         does pod p's labels+namespace match group c's selector
   per-pod constraint slots (padded to MAX_CONSTRAINTS): group id, maxSkew,
-                   whenUnsatisfiable, eligibility (node affinity match for
-                   minMatchNum domain filtering), log-normalizing weight.
+                   whenUnsatisfiable, the slot's elig_rows row, minDomains
+                   unsatisfied.
 
 Filter (DoNotSchedule): skew = count(node domain) + selfMatch - min over
-domains present among nodes matching the pod's nodeSelector/affinity;
-fails with "node(s) didn't match pod topology spread constraints" (or the
-"(missing required label)" variant).  Constraints are checked in pod order
-and the first violation wins, as upstream does.
+the domains present among the counted nodes; fails with "node(s) didn't
+match pod topology spread constraints" (or the "(missing required label)"
+variant).  Constraints are checked in pod order and the first violation
+wins, as upstream does.
 
-Score (ScheduleAnyway): sum over constraints of count * log(#domains + 2)
-(topologyNormalizingWeight), Go math.Round'ed; nodes missing any scored
-topology key are ignored (score 0 after normalize).  NormalizeScore:
-score = 100 * (max + min - s) / max over scored feasible nodes, 100 for
-all when max == 0.
+Score (ScheduleAnyway), as upstream's PreScore and Score compute it: a
+node lacking any scored key is ignored (score 0, left out of min and
+max).  Per constraint the weight is log(sz + 2), sz the number of
+FEASIBLE nodes that are not ignored for the hostname key and the number
+of distinct values among them for any other key; the count is the node's
+own matching pods for the hostname key (no inclusion policy: upstream
+counts nodeInfo.Pods in Score) and the folded domain count otherwise; a
+node's raw score is the sum of count * weight + (maxSkew - 1), Go
+math.Round'ed.  NormalizeScore: score = 100 * (max + min - s) / max over
+scored feasible nodes, 100 for all when max == 0.
 
 Modeled knobs: matchLabelKeys (merged into the selector per incoming pod,
 effective_constraints), minDomains (global minimum forced to 0 when fewer
-eligible domains exist), nodeAffinityPolicy (default Honor) and
-nodeTaintsPolicy (default Ignore) for the min-match domain eligibility.
-Remaining simplifications (docs/SEMANTICS.md, "PodTopologySpread knobs",
-has the same three in the same order, each with whether
-benchmark/reference/node_inclusion.py, which counts as upstream does, can
-see it in `sched_perf_nodeinclusion_5k`: none of them, hostname domains):
-1. the inclusion policies filter the min-match DOMAIN set but not the
-   per-domain pod counting (upstream also excludes filtered-out nodes'
-   pods from TpPairToMatchNum — differs only on clusters where some nodes
-   of a domain are excluded while others aren't; with one node a domain
-   it cannot show);
-2. system-default constraints derived from service/replicaset owners are
-   not modeled (upstream applies them only to a pod without constraints);
-3. #domains for the normalizing weight is computed over all nodes with
-   the key rather than the affinity-filtered subset (ScheduleAnyway
-   scoring only).
+counted domains exist), nodeAffinityPolicy (default Honor) and
+nodeTaintsPolicy (default Ignore), per constraint, for both the counting
+and the minimum.  One simplification remains (docs/SEMANTICS.md,
+"PodTopologySpread knobs"): system-default constraints derived from
+service/replicaset owners are not modeled (upstream applies them only to
+a pod without constraints).
 """
 
 from __future__ import annotations
@@ -53,29 +68,43 @@ import json
 import math
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+# the module, not its names: a plugin module imported first imports the
+# state package while it is itself half initialised
+from . import affinity
 from .base import MAX_NODE_SCORE
 from ..state.nodes import NodeTable
 from ..state.selectors import (
+    has_untolerated_do_not_schedule_taint,
     label_selector_matches,
-    match_labels_rows,
-    node_selector_rows,
     spec_key,
 )
+from ..utils.tracing import TRACER
 
 NAME = "PodTopologySpread"
 ERR_SKEW = "node(s) didn't match pod topology spread constraints"
 ERR_MISSING_LABEL = "node(s) didn't match pod topology spread constraints (missing required label)"
+HOSTNAME_KEY = "kubernetes.io/hostname"   # upstream v1.LabelHostname
 
 MAX_CONSTRAINTS = 4
 _BIG = np.int64(1) << 40
+# the least extents of the key axis K and of the domain bound Dp: two
+# keys (a zone and a hostname constraint) and a zone label's 8 values
+KEY_FLOOR = 2
+DOM_FLOOR = 8
 
 
 class SpreadStatic(NamedTuple):
-    dom_idx: jnp.ndarray   # [C, N] int32
-    n_groups: int
+    dom_idx: jnp.ndarray      # [K, N] int32
+    is_hostname: jnp.ndarray  # [K] bool
+    is_ident: jnp.ndarray     # [K] bool
+    group_key: jnp.ndarray    # [C] int32 into dom_idx
+    elig_rows: jnp.ndarray    # [E, N] bool (row 0 = all-True)
+    dom_iota: jnp.ndarray     # [Dp] int32
+    log_table: jnp.ndarray    # [N + 1] float64
 
 
 class SpreadXS(NamedTuple):
@@ -84,12 +113,10 @@ class SpreadXS(NamedTuple):
     max_skew: jnp.ndarray    # [P, MC] int32
     is_filter: jnp.ndarray   # [P, MC] bool (DoNotSchedule)
     is_score: jnp.ndarray    # [P, MC] bool (ScheduleAnyway)
-    weight: jnp.ndarray      # [P, MC] float64 (topologyNormalizingWeight)
-    eligible: jnp.ndarray    # [P, N] bool (node matches pod's selector/
-    #   affinity; [P, MC, N] when any constraint sets a non-default
-    #   nodeAffinityPolicy/nodeTaintsPolicy — per-slot inclusion)
+    elig_idx: jnp.ndarray    # [P, MC] int32 into static.elig_rows: the
+    #   nodes the slot's inclusion policies keep
     md_unsat: jnp.ndarray    # [P, MC] bool — minDomains unsatisfied: fewer
-    #   eligible domains than spec.minDomains -> global minimum becomes 0
+    #   counted domains than spec.minDomains -> global minimum becomes 0
     filter_skip: jnp.ndarray  # [P] bool
     score_skip: jnp.ndarray   # [P] bool
 
@@ -122,12 +149,14 @@ def effective_constraints(pod: dict) -> list[dict]:
     return out
 
 
+def is_hard(c: dict) -> bool:
+    return c.get("whenUnsatisfiable", "DoNotSchedule") == "DoNotSchedule"
+
+
 def _intern_groups(pods: list[dict]):
     """(group_list, per_pod_slots): unique (namespace, topologyKey,
-    selector) count groups over the workload's effective constraints in
-    first-seen order, plus each pod's [(group_id, constraint)] slots.
-    The single interning implementation behind both build() and the
-    engine's bound-pod priming."""
+    selector) count groups over the pass's effective constraints in
+    first-seen order, plus each pod's [(group_id, constraint)] slots."""
     groups: dict[tuple, int] = {}
     group_list: list[tuple[str, str, dict | None]] = []
     per_pod: list[list[tuple[int, dict]]] = []
@@ -145,66 +174,103 @@ def _intern_groups(pods: list[dict]):
     return group_list, per_pod
 
 
-def constraint_groups(pods: list[dict]) -> list[tuple[str, str, dict | None]]:
-    """The group-id space shared by build(), the engine's bound-pod
-    priming (state/compile.py), and the carry layout."""
-    return _intern_groups(pods)[0]
+def _bucket(count: int, floor: int) -> int:
+    """The padded extent of an axis that holds `count` entries: the next
+    power of two, at least `floor`."""
+    return max(floor, 1 << max(count - 1, 0).bit_length())
 
 
-def _node_affinity_eligible(pod: dict, table: NodeTable) -> np.ndarray:
-    """nodeAffinityPolicy: Honor — domains for minMatchNum only count nodes
-    matching the pod's nodeSelector + required node affinity."""
+def inclusion_spec(pod: dict, c: dict) -> tuple:
+    """What of (pod, constraint) decides which nodes the constraint counts
+    on (upstream matchNodeInclusionPolicies): (nodeSelector, required node
+    affinity, tolerations), each None where its policy ignores it or the
+    pod has none; all None keeps every node."""
+    honor_aff = (c.get("nodeAffinityPolicy") or "Honor") == "Honor"
+    honor_taints = (c.get("nodeTaintsPolicy") or "Ignore") == "Honor"
     spec = pod.get("spec") or {}
-    sel = spec.get("nodeSelector") or {}
-    req = (((spec.get("affinity") or {}).get("nodeAffinity")) or {}).get(
-        "requiredDuringSchedulingIgnoredDuringExecution"
+    return (
+        (spec.get("nodeSelector") or None) if honor_aff else None,
+        (((spec.get("affinity") or {}).get("nodeAffinity")) or {}).get(
+            "requiredDuringSchedulingIgnoredDuringExecution")
+        if honor_aff else None,
+        (spec.get("tolerations") or []) if honor_taints else None,
     )
-    out = np.ones(table.n, dtype=bool)
-    if sel:
-        out &= match_labels_rows(sel, table.label_index)
-    if req:
-        out &= node_selector_rows(req, table.label_index)
-    return out
 
 
-def _taints_tolerated_row(pod: dict, table: NodeTable) -> np.ndarray:
-    """nodeTaintsPolicy Honor: a node is excluded when it carries a
-    NoSchedule/NoExecute taint the incoming pod doesn't tolerate
-    (upstream helper.DoNotScheduleTaintsFilterFunc).  One row per
-    distinct tolerations, kept on the table."""
-    from ..state.selectors import has_untolerated_do_not_schedule_taint
-
-    tols = (pod.get("spec") or {}).get("tolerations") or []
+def _inclusion_row(table: NodeTable, incl: tuple, fragment: str):
+    """([N] bool, how many nodes it leaves out) for one inclusion_spec
+    (`fragment`: its spec_key): nodeAffinityPolicy Honor keeps
+    the nodes matching the pod's nodeSelector + required node affinity
+    (NodeAffinity's own memoised row), nodeTaintsPolicy Honor the nodes
+    without a NoSchedule/NoExecute taint the pod does not tolerate
+    (upstream helper.DoNotScheduleTaintsFilterFunc).  By spec from the
+    node table's memo."""
+    node_sel, required, tols = incl
 
     def make():
-        return np.asarray([
-            not has_untolerated_do_not_schedule_taint(table.taints[j], tols)
-            for j in range(table.n)
-        ], dtype=bool)
+        TRACER.inc("spread_rows_built_total", kind="eligible")
+        row = np.ones(table.n, dtype=bool)
+        if node_sel or required:
+            row = row & affinity._required_row(
+                table, node_sel or {}, required)
+        if tols is not None:
+            row = row & np.asarray([
+                not has_untolerated_do_not_schedule_taint(table.taints[j], tols)
+                for j in range(table.n)], dtype=bool)
+        return row, int(table.n - row.sum())
 
-    return table.derived.row("taints_tolerated", spec_key(tols), make)
+    return table.derived.row("spread_eligible", fragment, make)
 
 
-def build(table: NodeTable, pods: list[dict]):
+def _count_rebuckets(table: NodeTable, axes: dict[str, int]) -> None:
+    """spread_axis_rebuckets_total{axis}: a padded axis of this pass
+    (groups C, keys K, rows E, domains Dp) is not the extent of the last
+    pass on this node table, which is another layout of the pass's
+    buffers and so another scan executable."""
+    last = table.derived.swap("spread_axes", axes) or axes
+    for axis, extent in axes.items():
+        # + 0 too: a series that reads 0 says the axes are padded
+        TRACER.inc("spread_axis_rebuckets_total",
+                   int(extent != last[axis]), axis=axis)
+
+
+def build(table: NodeTable, pods: list[dict], pod_axis: int = 1):
+    """-> (SpreadStatic, SpreadXS, the pass's count groups in carry
+    order, the carry's group extent C).  pod_axis: the rows of the pass's
+    pod axis (state/compile.py pod_axis_bucket), which the C / E floor
+    follows as NodeAffinity's U / V does."""
     n, p = table.n, len(pods)
-
-    # unique count groups + per-pod slots over the effective constraints
-    # (single interning implementation — the engine's bound-pod priming
-    # reads the same group-id space via constraint_groups)
+    floor = affinity._axis_floor(pod_axis)
     group_list, per_pod = _intern_groups(pods)
-    n_groups = max(len(group_list), 1)
+    c_ext = _bucket(len(group_list), floor)
 
-    # --- domain indexing per group key -----------------------------------
+    # --- one domain row a distinct topology key --------------------------
     # the row depends only on (node labels, topologyKey): kept on the
     # table (NodeTable.domain_row), shared with InterPodAffinity's terms
-    dom_idx = np.full((n_groups, n), -1, dtype=np.int32)
-    n_domains = np.zeros(n_groups, dtype=np.int64)
+    key_ids: dict[str, int] = {}
+    key_rows: list[np.ndarray] = []
+    group_key = np.zeros(c_ext, dtype=np.int32)
+    hostname, ident, d_fold = [], [], 0
     for c_id, (_, key, _) in enumerate(group_list):
-        dom_idx[c_id], n_domains[c_id] = table.domain_row(key)
-    d_max = max(int(dom_idx.max()) + 1, 1)
+        k = key_ids.get(key)
+        if k is None:
+            k = key_ids[key] = len(key_rows)
+            row, n_domains = table.domain_row(key)
+            key_rows.append(row)
+            hostname.append(key == HOSTNAME_KEY)
+            ident.append(int(n_domains) == int((row >= 0).sum()))
+            if not ident[k]:
+                d_fold = max(d_fold, int(n_domains))
+        group_key[c_id] = k
+    k_ext = _bucket(len(key_rows), KEY_FLOOR)
+    pad = k_ext - len(key_rows)
+    dom_idx = np.stack(key_rows + [np.full(n, -1, dtype=np.int32)] * pad)
+    is_hostname = np.asarray(hostname + [False] * pad, dtype=bool)
+    is_ident = np.asarray(ident + [True] * pad, dtype=bool)
+    d_ext = _bucket(d_fold, DOM_FLOOR)
 
     # --- pod x group selector matches ------------------------------------
-    pm = np.zeros((p, n_groups), dtype=bool)
+    pm = np.zeros((p, c_ext), dtype=bool)
     for i, pod in enumerate(pods):
         pod_ns = (pod.get("metadata") or {}).get("namespace") or "default"
         pod_labels = {k: str(v) for k, v in ((pod.get("metadata") or {}).get("labels") or {}).items()}
@@ -216,126 +282,138 @@ def build(table: NodeTable, pods: list[dict]):
     max_skew = np.ones((p, MAX_CONSTRAINTS), dtype=np.int32)
     is_filter = np.zeros((p, MAX_CONSTRAINTS), dtype=bool)
     is_score = np.zeros((p, MAX_CONSTRAINTS), dtype=bool)
-    weight = np.zeros((p, MAX_CONSTRAINTS), dtype=np.float64)
+    elig_idx = np.zeros((p, MAX_CONSTRAINTS), dtype=np.int32)
     md_unsat = np.zeros((p, MAX_CONSTRAINTS), dtype=bool)
     filter_skip = np.ones(p, dtype=bool)
     score_skip = np.ones(p, dtype=bool)
-    # non-default nodeAffinityPolicy/nodeTaintsPolicy make inclusion
-    # per-constraint -> the eligible tensor grows a slot axis
-    per_slot_eligibility = any(
-        (c.get("nodeAffinityPolicy") or "Honor") != "Honor"
-        or (c.get("nodeTaintsPolicy") or "Ignore") != "Ignore"
-        for slots in per_pod for _, c in slots
-    )
-    eligible = (np.ones((p, MAX_CONSTRAINTS, n), dtype=bool)
-                if per_slot_eligibility else np.ones((p, n), dtype=bool))
-    eligible_rows: dict[str, np.ndarray] = {}  # unique inclusion spec -> [N]
-
-    def slot_eligible_row(pod: dict, c: dict) -> np.ndarray:
-        aff_policy = c.get("nodeAffinityPolicy") or "Honor"
-        taint_policy = c.get("nodeTaintsPolicy") or "Ignore"
-        pspec = pod.get("spec") or {}
-        ek = spec_key(
-            aff_policy, taint_policy,
-            (pspec.get("nodeSelector") or {}) if aff_policy == "Honor" else None,
-            (((pspec.get("affinity") or {}).get("nodeAffinity")) or {}).get(
-                "requiredDuringSchedulingIgnoredDuringExecution")
-            if aff_policy == "Honor" else None,
-            (pspec.get("tolerations") or []) if taint_policy == "Honor" else None,
-        )
-        row = eligible_rows.get(ek)
-        if row is None:
-            row = (_node_affinity_eligible(pod, table)
-                   if aff_policy == "Honor" else np.ones(n, dtype=bool))
-            if taint_policy == "Honor":
-                row = row & _taints_tolerated_row(pod, table)
-            eligible_rows[ek] = row
-        return row
+    # row 0 keeps every node: what a slot without a pod-side selector,
+    # required term or Honor'd taint policy gathers
+    elig_pool: list[np.ndarray] = [np.ones(n, dtype=bool)]
+    elig_by_spec: dict[tuple, tuple[int, int]] = {}
+    excluded_total = 0
 
     for i, slots in enumerate(per_pod):
+        excluded = 0
         for m, (cid, c) in enumerate(slots):
             c_id_arr[i, m] = cid
             max_skew[i, m] = int(c.get("maxSkew", 1))
-            hard = c.get("whenUnsatisfiable", "DoNotSchedule") == "DoNotSchedule"
+            hard = is_hard(c)
             is_filter[i, m] = hard
             is_score[i, m] = not hard
-            weight[i, m] = math.log(float(n_domains[cid]) + 2.0)
-            if hard:
-                row = slot_eligible_row(pods[i], c)
-                if per_slot_eligibility:
-                    eligible[i, m] = row
-                else:
-                    eligible[i] = row
-                md = c.get("minDomains")
-                if md is not None:
-                    doms = np.unique(dom_idx[cid][(dom_idx[cid] >= 0) & row])
-                    # zero eligible domains: upstream's minMatchNum lookup
-                    # errors and the constraint is SKIPPED, not zeroed
-                    md_unsat[i, m] = 0 < len(doms) < int(md)
+            incl = inclusion_spec(pods[i], c)
+            if incl != (None, None, None):
+                ek = spec_key(*incl)
+                hit = elig_by_spec.get(ek)
+                if hit is None:
+                    row, left_out = _inclusion_row(table, incl, ek)
+                    hit = elig_by_spec[ek] = (len(elig_pool), left_out)
+                    elig_pool.append(row)
+                elig_idx[i, m] = hit[0]
+                excluded = max(excluded, hit[1])
+            md = c.get("minDomains")
+            if hard and md is not None:
+                counted = elig_pool[elig_idx[i, m]].copy()
+                for cid2, c2 in slots:
+                    if is_hard(c2):
+                        counted &= key_rows[group_key[cid2]] >= 0
+                doms = np.unique(key_rows[group_key[cid]][counted])
+                # zero counted domains: no node's value is in upstream's
+                # map, so every keyed node passes whatever the minimum is
+                md_unsat[i, m] = 0 < len(doms) < int(md)
         filter_skip[i] = not is_filter[i].any()
         score_skip[i] = not is_score[i].any()
+        excluded_total += excluded
+    # + 0 too: a series that reads 0 says no policy left a node out
+    TRACER.count("spread_excluded_nodes_total", excluded_total)
 
-    # numpy, xs and carry too: compile_workload reads its flags and the
-    # digest off the host bytes, then uploads once (pack_tree)
-    static = SpreadStatic(dom_idx=dom_idx, n_groups=n_groups)
+    e_ext = _bucket(len(elig_pool), floor)
+    elig_rows = np.stack(elig_pool + [elig_pool[0]] * (e_ext - len(elig_pool)))
+    _count_rebuckets(table, {"groups": c_ext, "keys": k_ext, "rows": e_ext,
+                             "domains": d_ext})
+
+    # numpy, xs and carry too: compile_workload reads its flags off the
+    # host bytes, then uploads once (pack_tree)
+    static = SpreadStatic(
+        dom_idx=dom_idx,
+        is_hostname=is_hostname,
+        is_ident=is_ident,
+        group_key=group_key,
+        elig_rows=elig_rows,
+        dom_iota=np.arange(d_ext, dtype=np.int32),
+        # only a ScheduleAnyway slot gathers a weight: a pass without one
+        # carries zeros in the table's place (one layout) and asks the
+        # node table's memo nothing
+        log_table=table.derived.once(
+            "spread_log_table", lambda: np.asarray(
+                [math.log(float(sz + 2)) for sz in range(n + 1)],
+                dtype=np.float64))
+        if is_score.any() else np.zeros(n + 1, dtype=np.float64),
+    )
     xs = SpreadXS(
         pm=pm,
         c_id=c_id_arr,
         max_skew=max_skew,
         is_filter=is_filter,
         is_score=is_score,
-        weight=weight,
-        eligible=eligible,
+        elig_idx=elig_idx,
         md_unsat=md_unsat,
         filter_skip=filter_skip,
         score_skip=score_skip,
     )
-    counts_dom = np.zeros((n_groups, d_max), dtype=np.int64)
-    return static, xs, counts_dom
+    return static, xs, group_list, c_ext
 
 
-def assemble_counts(static: SpreadStatic, counts_dom: np.ndarray) -> np.ndarray:
-    """[C, D] domain-space counts (build + host priming) -> node-space
-    [C, N] int32 carry (value at each node's domain, 0 where the
-    node lacks the key).  Node-space keeps the scan step free of the
-    TPU-hostile per-step gathers and scatters — see the InterPodCarry
-    docstring for the measured effect of the same transformation."""
-    dom = np.asarray(static.dom_idx)
-    vals = np.take_along_axis(counts_dom, np.maximum(dom, 0), axis=1)
-    return np.where(dom >= 0, vals, 0).astype(np.int32)
-
-
-def _slot_eligible(pod, m):
-    """[N] inclusion mask for slot m ([P, MC, N] layout when any
-    constraint sets a non-default inclusion policy, else shared [P, N])."""
-    return pod.eligible[m] if pod.eligible.ndim == 2 else pod.eligible
-
-
-def _per_constraint(static: SpreadStatic, pod, counts, m):
-    """Per-constraint-slot quantities: (active, has_key[N], cnt[N], min_match).
-
-    counts is node-space [C, N]; min-over-present-domains equals the min
-    over eligible keyed NODES of the node-space counts (every present
-    domain is represented by at least one eligible node).  minDomains
-    (spec'd and unsatisfied -> md_unsat at build time) forces the global
-    minimum to 0, upstream getMinMatchNum semantics."""
+def _slot(static: SpreadStatic, pod, counts, m):
+    """Slot m of one pod: (active, its key row k, dom[N], has_key[N], the
+    group's per-node counts [N])."""
     cid = pod.c_id[m]
-    active = cid >= 0
     c = jnp.maximum(cid, 0)
-    dom = static.dom_idx[c]                      # [N]
-    has_key = dom >= 0
-    cnt = counts[c]                              # [N] (0 where key missing)
-    min_match = jnp.min(
-        jnp.where(has_key & _slot_eligible(pod, m), cnt.astype(jnp.int64), _BIG))
-    min_match = jnp.where(pod.md_unsat[m], 0, min_match)
-    return active, has_key, cnt, min_match
+    k = static.group_key[c]
+    dom = static.dom_idx[k]
+    return cid >= 0, k, dom, dom >= 0, counts[c]
+
+
+def _kind_keys(static: SpreadStatic, pod, counts, kind):
+    """[N] bool: the nodes that carry the key of every slot of one kind
+    (`kind` [MC] bool: pod.is_filter or pod.is_score) — upstream's
+    nodeLabelsMatchSpreadConstraints over that kind's constraints."""
+    n = static.dom_idx.shape[1]
+    keyed = jnp.ones(n, dtype=bool)
+    for m in range(MAX_CONSTRAINTS):
+        active, _, _, has_key, _ = _slot(static, pod, counts, m)
+        keyed = keyed & jnp.where(active & kind[m], has_key, True)
+    return keyed
+
+
+def _fold(static: SpreadStatic, k, dom, vals):
+    """[N]: at every node the sum of `vals` over the nodes of its domain
+    (vals is already 0 on the nodes that are not counted).  Two masked
+    reductions over a [Dp, N] one-hot, no scatter and no gather; the
+    identity where every node is a domain of its own."""
+    with jax.named_scope("kss_spread_fold"):
+        onehot = static.dom_iota[:, None] == dom[None, :]           # [Dp, N]
+        per_dom = jnp.sum(jnp.where(onehot, vals[None, :], 0), axis=1)
+        folded = jnp.sum(jnp.where(onehot, per_dom[:, None], 0), axis=0)
+        return jnp.where(static.is_ident[k], vals, folded.astype(vals.dtype))
 
 
 def filter_kernel(static: SpreadStatic, pod, counts) -> jnp.ndarray:
-    """[N] int32: 0 pass; 1+2m missing-label at slot m; 2+2m skew at slot m."""
+    """[N] int32: 0 pass; 1+2m missing-label at slot m; 2+2m skew at slot m.
+
+    counts is per node [C, N].  A slot counts on the nodes its inclusion
+    policies keep that carry every DoNotSchedule key of the pod
+    (upstream calPreFilterState); the minimum over the domains present
+    among them equals the minimum of the folded count over those nodes.
+    minDomains (spec'd and unsatisfied -> md_unsat at build time) forces
+    the global minimum to 0, upstream getMinMatchNum semantics."""
     code = jnp.zeros(static.dom_idx.shape[1], dtype=jnp.int32)
+    keyed = _kind_keys(static, pod, counts, pod.is_filter)
     for m in range(MAX_CONSTRAINTS):
-        active, has_key, cnt, min_match = _per_constraint(static, pod, counts, m)
+        active, k, dom, has_key, per_node = _slot(static, pod, counts, m)
+        counted = static.elig_rows[pod.elig_idx[m]] & keyed & has_key
+        cnt = _fold(static, k, dom, jnp.where(counted, per_node, 0))
+        min_match = jnp.min(jnp.where(counted, cnt.astype(jnp.int64), _BIG))
+        min_match = jnp.where(pod.md_unsat[m], 0, min_match)
         check = active & pod.is_filter[m]
         self_match = pod.pm[jnp.maximum(pod.c_id[m], 0)].astype(jnp.int64)
         skew = cnt + self_match - min_match
@@ -345,15 +423,31 @@ def filter_kernel(static: SpreadStatic, pod, counts) -> jnp.ndarray:
     return code
 
 
-def score_kernel(static: SpreadStatic, pod, counts) -> jnp.ndarray:
+def score_kernel(static: SpreadStatic, pod, counts, feasible):
+    """-> (raw [N] int64, ignored [N] bool).  feasible [N] bool: the
+    pod's filtered nodes, which upstream's PreScore sizes the weights
+    from."""
     n = static.dom_idx.shape[1]
+    keyed = _kind_keys(static, pod, counts, pod.is_score)
+    ignored = ~keyed
+    live = feasible & keyed
+    n_live = jnp.sum(live, dtype=jnp.int32)
     total = jnp.zeros(n, dtype=jnp.float64)
-    ignored = jnp.zeros(n, dtype=bool)
     for m in range(MAX_CONSTRAINTS):
-        active, has_key, cnt, _ = _per_constraint(static, pod, counts, m)
-        scored = active & pod.is_score[m]
-        total = total + jnp.where(scored & has_key, cnt.astype(jnp.float64) * pod.weight[m], 0.0)
-        ignored = ignored | jnp.where(scored, ~has_key, False)
+        active, k, dom, has_key, per_node = _slot(static, pod, counts, m)
+        by_node = static.is_hostname[k]
+        counted = static.elig_rows[pod.elig_idx[m]] & keyed
+        cnt = jnp.where(by_node, per_node,
+                        _fold(static, k, dom, jnp.where(counted, per_node, 0)))
+        with jax.named_scope("kss_spread_weight"):
+            onehot = static.dom_iota[:, None] == dom[None, :]
+            present = jnp.any(onehot & live[None, :], axis=1)
+            sz = jnp.where(by_node | static.is_ident[k], n_live,
+                           jnp.sum(present, dtype=jnp.int32))
+            weight = static.log_table[sz]
+        term = cnt.astype(jnp.float64) * weight + (
+            pod.max_skew[m] - 1).astype(jnp.float64)
+        total = total + jnp.where(active & pod.is_score[m] & keyed, term, 0.0)
     raw = jnp.floor(total + 0.5).astype(jnp.int64)  # Go math.Round for non-negative
     return jnp.where(ignored, 0, raw), ignored
 
@@ -373,14 +467,11 @@ def normalize(raw, ignored, feasible):
 
 
 def bind_update(static: SpreadStatic, pod, counts, sel):
-    """Node-space bind: every node sharing the selected node's domain (per
-    group) takes the pm[c] increment — elementwise, no scatter."""
-    bound = sel >= 0
-    s = jnp.maximum(sel, 0)
-    dom_col = static.dom_idx[:, s]                  # [C]
-    valid = bound & (dom_col >= 0) & pod.pm         # [C]
-    same = (static.dom_idx == dom_col[:, None]) & valid[:, None]  # [C, N]
-    return counts + same.astype(counts.dtype)
+    """The bound pod joins the per-node count of every group whose
+    selector it matches, at the selected node — elementwise, no scatter
+    (sel -1, an unbound or a pad row, meets no node)."""
+    at_sel = jnp.arange(counts.shape[1], dtype=jnp.int32) == sel    # [N]
+    return counts + (pod.pm[:, None] & at_sel[None, :]).astype(counts.dtype)
 
 
 def decode_filter(code: int, node_idx: int, host_aux) -> str:

@@ -15,6 +15,7 @@ lowest-index tie-break divergence is applied here identically.
 
 from __future__ import annotations
 
+import json
 import math
 
 from ..plugins.registry import PluginSetConfig
@@ -567,21 +568,28 @@ class SequentialScheduler:
                 out.append(c)
         return out
 
-    def _count_by_domain(self, ns: str, selector, key: str) -> dict[str, int]:
-        """Existing pods matching (ns, selector) per domain value of key —
-        computed ONCE per scheduling cycle, like upstream's PreFilter
-        building TpPairToMatchNum before the per-node Filter calls."""
-        counts: dict[str, int] = {}
-        for ap, aj in self.assigned:
-            if (_meta(ap).get("namespace") or "default") != ns:
-                continue
-            val = self.labels[aj].get(key)
-            if val is None:
-                continue
-            lab = {k: str(v) for k, v in (_meta(ap).get("labels") or {}).items()}
-            if label_selector_matches(selector, lab):
-                counts[val] = counts.get(val, 0) + 1
+    def _count_by_node(self, ns: str, selector) -> dict[int, int]:
+        """Existing pods matching (ns, selector) per NODE — computed once
+        per scheduling cycle and selector, like upstream's
+        countPodsMatchSelector over each nodeInfo.Pods."""
+        memo = self._cycle.setdefault("spread_per_node", {})
+        mk = (ns, json.dumps(selector, sort_keys=True))
+        counts = memo.get(mk)
+        if counts is None:
+            counts = memo[mk] = {}
+            for ap, aj in self.assigned:
+                if (_meta(ap).get("namespace") or "default") != ns:
+                    continue
+                lab = {k: str(v) for k, v in (_meta(ap).get("labels") or {}).items()}
+                if label_selector_matches(selector, lab):
+                    counts[aj] = counts.get(aj, 0) + 1
         return counts
+
+    def _spread_keyed(self, constraints) -> list[bool]:
+        """Per node: does it carry every topology key of `constraints`
+        (upstream nodeLabelsMatchSpreadConstraints)."""
+        keys = [c.get("topologyKey", "") for c in constraints]
+        return [all(k in self.labels[j] for k in keys) for j in range(self.n)]
 
     def _eligible_nodes(self, pod, c=None):
         """Per-constraint node inclusion (upstream matchNodeInclusionPolicies):
@@ -612,28 +620,32 @@ class SequentialScheduler:
 
     def _spread_prefilter_state(self, pod) -> list[dict]:
         """Per-cycle state for the DoNotSchedule constraints (upstream
-        preFilterState: counts per domain + critical-path min)."""
+        preFilterState: TpPairToMatchNum counted BY NODE, over the nodes
+        that pass the constraint's inclusion policies and carry every
+        DoNotSchedule key, + critical-path min)."""
         if "spread_filter" in self._cycle:
             return self._cycle["spread_filter"]
         ns = _meta(pod).get("namespace") or "default"
         pod_labels = {k: str(v) for k, v in (_meta(pod).get("labels") or {}).items()}
         state = []
-        for c in self._spread_constraints(pod, hard=True):
+        hard = self._spread_constraints(pod, hard=True)
+        keyed = self._spread_keyed(hard)
+        for c in hard:
             eligible = self._eligible_nodes(pod, c)
             key = c.get("topologyKey", "")
             sel = c.get("labelSelector")
-            counts = self._count_by_domain(ns, sel, key)
-            domains = {
-                self.labels[k].get(key)
-                for k in range(self.n)
-                if eligible[k] and key in self.labels[k]
-            }
-            min_match = min((counts.get(d, 0) for d in domains), default=None)
+            per_node = self._count_by_node(ns, sel)
+            counts: dict[str, int] = {}
+            for k in range(self.n):
+                if eligible[k] and keyed[k]:
+                    val = self.labels[k][key]
+                    counts[val] = counts.get(val, 0) + per_node.get(k, 0)
+            min_match = min(counts.values(), default=None)
             md = c.get("minDomains")
-            if md is not None and 0 < len(domains) < int(md):
+            if md is not None and 0 < len(counts) < int(md):
                 # upstream getMinMatchNum: fewer (but nonzero — a zero-
                 # domain key errors upstream and the constraint is
-                # skipped) eligible domains than minDomains -> the global
+                # skipped) counted domains than minDomains -> the global
                 # minimum is treated as 0
                 min_match = 0
             state.append({
@@ -641,26 +653,45 @@ class SequentialScheduler:
                 "max_skew": int(c.get("maxSkew", 1)),
                 "self_match": 1 if label_selector_matches(sel, pod_labels) else 0,
                 "counts": counts,
-                "min_match": min_match,  # None: no eligible domain -> pass
+                "min_match": min_match,  # None: no counted domain -> pass
             })
         self._cycle["spread_filter"] = state
         return state
 
-    def _spread_prescore_state(self, pod) -> list[dict]:
+    def _spread_prescore_state(self, pod) -> dict:
+        """Per-cycle state for the ScheduleAnyway constraints (upstream
+        PreScore over the cycle's FILTERED nodes, self._cycle["feasible"]):
+        the ignored nodes (lacking a scored key), and per constraint the
+        counts (the node's own for the hostname key, by topology pair over
+        the counted nodes otherwise) and topologyNormalizingWeight."""
         if "spread_score" in self._cycle:
             return self._cycle["spread_score"]
         ns = _meta(pod).get("namespace") or "default"
-        state = []
-        for c in self._spread_constraints(pod, hard=False):
+        soft = self._spread_constraints(pod, hard=False)
+        keyed = self._spread_keyed(soft)
+        live = [j for j in self._cycle["feasible"] if keyed[j]]
+        constraints = []
+        for c in soft:
             key = c.get("topologyKey", "")
-            n_domains = len({
-                self.labels[k].get(key) for k in range(self.n) if key in self.labels[k]
-            })
-            state.append({
+            per_node = self._count_by_node(ns, c.get("labelSelector"))
+            if key == "kubernetes.io/hostname":
+                pairs = None
+                size = len(live)
+            else:
+                eligible = self._eligible_nodes(pod, c)
+                pairs = {self.labels[j][key]: 0 for j in live}
+                for k in range(self.n):
+                    if keyed[k] and eligible[k] and self.labels[k][key] in pairs:
+                        pairs[self.labels[k][key]] += per_node.get(k, 0)
+                size = len(pairs)
+            constraints.append({
                 "key": key,
-                "counts": self._count_by_domain(ns, c.get("labelSelector"), key),
-                "weight": math.log(float(n_domains) + 2.0),
+                "max_skew": int(c.get("maxSkew", 1)),
+                "per_node": per_node,
+                "pairs": pairs,
+                "weight": math.log(float(size + 2)),
             })
+        state = {"keyed": keyed, "constraints": constraints}
         self._cycle["spread_score"] = state
         return state
 
@@ -670,7 +701,7 @@ class SequentialScheduler:
             if val is None:
                 return "node(s) didn't match pod topology spread constraints (missing required label)"
             if c["min_match"] is None:
-                # upstream minMatchNum stays MaxInt when no eligible domain
+                # upstream minMatchNum stays MaxInt when no counted domain
                 # exists -> skew is negative -> the constraint passes
                 continue
             skew = c["counts"].get(val, 0) + c["self_match"] - c["min_match"]
@@ -679,18 +710,21 @@ class SequentialScheduler:
         return None
 
     def _spread_score(self, pod, j) -> int:
+        state = self._spread_prescore_state(pod)
+        if not state["keyed"][j]:
+            return 0  # ignored node
         total = 0.0
-        for c in self._spread_prescore_state(pod):
-            val = self.labels[j].get(c["key"])
-            if val is None:
-                return 0  # ignored node
-            total += float(c["counts"].get(val, 0)) * c["weight"]
+        for c in state["constraints"]:
+            if c["pairs"] is None:
+                cnt = c["per_node"].get(j, 0)
+            else:
+                cnt = c["pairs"][self.labels[j][c["key"]]]
+            # upstream scoreForCount
+            total += float(cnt) * c["weight"] + float(c["max_skew"] - 1)
         return int(math.floor(total + 0.5))
 
     def _spread_ignored(self, pod, j) -> bool:
-        return any(
-            c["key"] not in self.labels[j] for c in self._spread_prescore_state(pod)
-        )
+        return not self._spread_prescore_state(pod)["keyed"][j]
 
     def _spread_normalize(self, scores: dict[int, int], pod) -> dict[int, int]:
         scored = {j: s for j, s in scores.items() if not self._spread_ignored(pod, j)}
@@ -940,6 +974,8 @@ class SequentialScheduler:
         if len(feasible) == 1:
             selected = feasible[0]
         elif len(feasible) > 1:
+            # what upstream's PreScore plugins are handed: the filtered nodes
+            self._cycle["feasible"] = feasible
             for name in cfg.prescorers():
                 prescore[name] = "" if self._score_skip(name, pod) else ann.SUCCESS_MESSAGE
             totals = {j: 0 for j in feasible}

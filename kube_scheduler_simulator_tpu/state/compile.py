@@ -104,19 +104,21 @@ VOLUME_PLUGINS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding", "Vo
 # are uploaded once per content per node table.  The volume family's are
 # arguments because they change with the cluster's volume objects (a PV,
 # a claim or a CSINode created between two passes), which a served
-# cluster creates as fast as pods.  NodeAffinity's are arguments because
-# they change with the QUEUE: req_rows [U, N] / pref_rows [V, N] hold a
-# row per node-affinity spec among the pass's pods, so as closure
-# constants every pod with other terms was a new digest and a new
-# executable; U and V are padded (plugins/affinity.py AXIS_FLOOR), the
-# rows come from the node table's memo.  The rest change with nodes or
-# the configuration only, with two exceptions that are still closure
-# constants and cost a compile per distinct set of topology keys in a
-# pass: PodTopologySpread's and InterPodAffinity's dom_idx [C, N] / [T, N]
-# (docs/wave-pipeline.md, "Statics: closure or argument").  VolumeZone
-# has no statics.
+# cluster creates as fast as pods.  NodeAffinity's and PodTopologySpread's
+# are arguments because they change with the QUEUE: req_rows [U, N] /
+# pref_rows [V, N] hold a row per node-affinity spec among the pass's
+# pods, group_key [C] / dom_idx [K, N] / elig_rows [E, N] an entry per
+# count group, topology key and inclusion spec among them, so as closure
+# constants every pod with other terms or another selector was a new
+# digest and a new executable; the axes are padded (plugins/affinity.py
+# AXIS_FLOOR, plugins/topologyspread.py _bucket), the rows come from the
+# node table's memo.  The rest change with nodes or the configuration
+# only, with one exception that is still a closure constant and costs a
+# compile per distinct set of topology keys in a pass: InterPodAffinity's
+# dom_idx [T, N] (docs/wave-pipeline.md, "Statics: closure or argument").
+# VolumeZone has no statics.
 ARG_STATICS = ("VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding",
-               "NodeAffinity")
+               "NodeAffinity", "PodTopologySpread")
 
 
 # the scan's default chunk: the most pods one device call takes
@@ -403,12 +405,12 @@ def compile_workload(
             xs["NodeName"] = taints.build_nodename(table, pods)
     if "PodTopologySpread" in enabled:
         with TRACER.span("cw_build_PodTopologySpread"):
-            st, x, counts_dom = topologyspread.build(table, pods)
+            st, x, groups, c_ext = topologyspread.build(
+                table, pods, pod_axis=rows)
             statics["PodTopologySpread"] = st
             xs["PodTopologySpread"] = x
-            _prime_spread_counts(counts_dom, st, pods, bound_carry)
-            init_carry["PodTopologySpread"] = \
-                topologyspread.assemble_counts(st, counts_dom)
+            init_carry["PodTopologySpread"] = _spread_counts(
+                groups, c_ext, table.n, bound_carry)
     if any(name in enabled for name in VOLUME_PLUGINS):
         with TRACER.span("cw_volume_table"):
             if volume_carry is None:
@@ -670,30 +672,19 @@ def _missing_pvc_message(vt, pod: dict) -> str | None:
     return None
 
 
-def _prime_spread_counts(counts_dom, st, pods, bound_carry):
-    """Fold already-bound pods into the domain-space match counts (in
-    place; topologyspread.assemble_counts converts to node space after):
-    per count group, the carry's per-node count of the bound pods its
-    selector matches, summed over each domain's nodes."""
-    if not bound_carry.n:
-        return
-    dom_idx = st.dom_idx
-    # group selectors were interned during build; the bound pods are not
-    # part of the queue, so not in x.pm
-    for c_id, (gns, _, sel) in enumerate(_spread_groups(pods)):
-        keyed = np.flatnonzero(dom_idx[c_id] >= 0)
-        per_node = bound_carry.match_counts((gns,), sel)
-        # float64 weights hold these counts exactly (< 2**53)
-        counts_dom[c_id] += np.bincount(
-            dom_idx[c_id, keyed], weights=per_node[keyed],
-            minlength=counts_dom.shape[1]).astype(np.int64)
-
-
-def _spread_groups(pods):
-    # MUST intern identically to topologyspread.build (same effective
-    # constraints incl. matchLabelKeys merge) or bound-pod priming would
-    # credit the wrong count groups
-    return topologyspread.constraint_groups(pods)
+def _spread_counts(groups, c_ext: int, n: int, bound_carry) -> np.ndarray:
+    """PodTopologySpread's carry: [C, N] int32, per count group (a pad row
+    past the pass's groups stays 0) and per NODE the bound pods its
+    selector matches, as the bound carry keeps them; the step folds them
+    by domain over the nodes each pod's constraint counts on
+    (plugins/topologyspread.py _fold), and a bind adds one at its node."""
+    counts = np.zeros((c_ext, n), dtype=np.int32)
+    if bound_carry.n:
+        # group selectors were interned during build; the bound pods are
+        # not part of the queue, so not in x.pm
+        for c_id, (gns, _, sel) in enumerate(groups):
+            counts[c_id] = bound_carry.match_counts((gns,), sel)
+    return counts
 
 
 def _collect_host_flags(cw: CompiledWorkload):
@@ -715,7 +706,10 @@ def _collect_host_flags(cw: CompiledWorkload):
         # mask (framework/replay.py _tsp_ignored_chunk)
         st = cw.statics["PodTopologySpread"]
         x = cw.xs["PodTopologySpread"]
-        cw.host["tsp_ignore"] = (st.dom_idx < 0, x.c_id, x.is_score)
+        # per slot the dom_idx row of its group's key (-1: no constraint)
+        key_of_slot = np.where(x.c_id >= 0,
+                               st.group_key[np.maximum(x.c_id, 0)], -1)
+        cw.host["tsp_ignore"] = (st.dom_idx < 0, key_of_slot, x.is_score)
     cw.host["score_dtypes"] = tuple(
         _score_dtype(cw, name) for name in cw.config.scorers()
     )

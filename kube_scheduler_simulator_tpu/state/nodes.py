@@ -41,7 +41,9 @@ class NodeDerived:
 
     Kinds (the `kind` label of node_derived_{hits,misses,evictions}_total):
     image_states, taint_max, name_idx (one value a table); image_row,
-    taint_rows, taints_tolerated, dom_idx, affinity_required (NodeAffinity's
+    taint_rows, spread_eligible (the [N] nodes a spread constraint's
+    inclusion policies keep, with how many they leave out), dom_idx,
+    affinity_required (NodeAffinity's
     [N] match row of a nodeSelector + required terms), affinity_term (the
     [N] match row of one preferred term, whatever its weight) (one value a
     fragment, at most ROW_CAP a kind, least recently used out);
@@ -172,6 +174,7 @@ class NodeTable:
         domains numbered in node order.  PodTopologySpread's count groups
         and InterPodAffinity's terms index the same row."""
         def make():
+            TRACER.inc("spread_rows_built_total", kind="dom_idx")
             labels = self.labels
             vals: dict[str, int] = {}
             row = np.full(self.n, -1, dtype=np.int32)

@@ -10,6 +10,16 @@ framework/replay.py; a resident array's patch, state/resident.py), or
 asks for some of them as device arrays of their own (PackedPass.take: one
 jitted dispatch; upload_tree for a whole tree).  state/compile.py's one
 upload site is the caller.
+
+A leaf of many long rows is the exception (OWN_ROWS, OWN_ELEMS): it
+travels in the same device_put as a buffer of its own and stands in the
+tree as the device array it is.  Cutting `[64, 5000]` out of a flat
+buffer is a relayout a row in the consumer's executable, and the TPU's
+compiler takes its time over each: with four such leaves and two of
+`[32, 5000]` (BASELINE config 4's pass on the bucket of 32 pods) the
+packed scan compiled for a described v5e in 61.6 s, and in 19.4 s with
+the six as arguments (44.9 -> 19.4 on the bucket of 16, 32.8 -> 19.3 on
+8; PERF.md section 6, PR 52), for ~0.17 ms a leaf and a pass.
 """
 
 from __future__ import annotations
@@ -21,6 +31,19 @@ import numpy as np
 from jax import lax
 
 from ..utils.tracing import TRACER
+
+# a numpy leaf of at least this many rows AND elements is a buffer of its
+# own (module doc).  Rows, because the cost is a row's; 16, so that a
+# resident array's payload (state/resident.py: ROWS_MAX rows, which its
+# patch cuts out of the buffers) never is one; 2**16 elements, so that the
+# rows are long: [16, 5000] is, [64, 1000] and [2, 15000] are not
+OWN_ROWS = 16
+OWN_ELEMS = 1 << 16
+
+
+def _own_buffer(leaf: np.ndarray) -> bool:
+    return (leaf.ndim >= 2 and leaf.shape[0] >= OWN_ROWS
+            and leaf.size >= OWN_ELEMS)
 
 
 class Packed:
@@ -40,10 +63,11 @@ class PackedPass:
 
     bufs    dtype name -> the 1-D device buffer of that dtype's leaves
     layout  (dtype name, shape) a leaf, in the order they were laid in
-    tree    the tree that was packed, a Packed in each numpy leaf's place;
-            what was no numpy array (a device array: a resident leaf of
-            state/resident.py; a Python int the step reads as a constant)
-            stays as it was
+    tree    the tree that was packed, a Packed in each numpy leaf's place
+            (a device array in the place of one that travelled as a buffer
+            of its own, _own_buffer); what was no numpy array (a device
+            array: a resident leaf of state/resident.py; a Python int the
+            step reads as a constant) stays as it was
     """
 
     __slots__ = ("bufs", "layout", "tree")
@@ -67,20 +91,32 @@ class PackedPass:
 
 def pack_tree(tree) -> PackedPass:
     """The one transfer of a tree of numpy leaves (counter
-    workload_h2d_transfers_total: a buffer a dtype)."""
+    workload_h2d_transfers_total: a buffer a dtype, and one a leaf of many
+    long rows, _own_buffer)."""
     leaves, treedef = jax.tree.flatten(tree)
-    layout, parts = [], {}
+    layout, parts, own = [], {}, []
     for i, leaf in enumerate(leaves):
-        if isinstance(leaf, (np.ndarray, np.generic)):
+        if isinstance(leaf, np.ndarray) and _own_buffer(leaf):
+            own.append(i)
+        elif isinstance(leaf, (np.ndarray, np.generic)):
             parts.setdefault(leaf.dtype.name, []).append(np.ravel(leaf))
             leaves[i] = Packed(len(layout), leaf.shape, leaf.dtype)
             layout.append((leaf.dtype.name, leaf.shape))
     bufs = {}
-    if parts:
-        TRACER.count("workload_h2d_transfers_total", len(parts))
+    if parts or own:
+        TRACER.count("workload_h2d_transfers_total", len(parts) + len(own))
         TRACER.count("pass_device_dispatches_total")
-        bufs = jax.device_put({dt: np.concatenate(p)
-                               for dt, p in parts.items()})
+        bufs = {dt: np.concatenate(p) for dt, p in parts.items()}
+        if own:
+            # copies, as the concatenated buffers are: a device_put may
+            # alias the host's memory (the CPU backend does), and a build
+            # may have handed a row of the node table's memo
+            bufs, sent = jax.device_put(
+                (bufs, [leaves[i].copy() for i in own]))
+            for i, leaf in zip(own, sent):
+                leaves[i] = leaf
+        else:
+            bufs = jax.device_put(bufs)
     return PackedPass(bufs, tuple(layout), jax.tree.unflatten(treedef, leaves))
 
 

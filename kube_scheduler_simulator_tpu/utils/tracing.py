@@ -193,10 +193,10 @@ _HELP: dict[str, str] = {
         "session's last tried speculative rounds had collapsed "
         "(framework/engine.py _wave_plan; docs/wave-pipeline.md row 9).",
     "speculative_retries_total":
-        "Times a session's record of collapsed speculative rounds was "
-        "cleared because a declined pass's median feasible share had "
-        "fallen to half the collapsed round's or less: the next batch "
-        "pass tries the rounds again.",
+        "Times a declined pass's median feasible share had fallen to "
+        "half the collapsed round's or less and the session asked for "
+        "its speculative rounds again: the next batch pass on a pod-axis "
+        "bucket they have run on tries them.",
     "tracer_events_dropped_total":
         "Span events evicted from the tracer's fixed-size ring because "
         "it was full — a long soak whose trace tail silently scrolled "
@@ -313,7 +313,9 @@ _HELP: dict[str, str] = {
         "Host-to-device buffers compile_workload sent (state/packed.py "
         "pack_tree): one per dtype of a pass's xs and carry, and of the "
         "statics where their digest is new on the node table; one more "
-        "for a resident volume array sent whole.",
+        "for a leaf of many long rows (16 rows and 65,536 elements or "
+        "more: a buffer of its own) and for a resident volume array "
+        "sent whole.",
     "pass_device_dispatches_total":
         "Calls that handed the runtime a transfer, a jitted call or an "
         "eager jnp op on a pass's way from cw_upload's start to the "
@@ -367,6 +369,32 @@ _HELP: dict[str, str] = {
         "meets it; a spec or term seen before is a lookup in the table's "
         "memo (state/nodes.py NodeDerived, kinds affinity_required and "
         "affinity_term) and builds none.",
+    "spread_axis_rebuckets_total":
+        "Passes of which a padded PodTopologySpread axis (axis=groups: the "
+        "C of pm [P, C], group_key [C] and the carry's counts [C, N], "
+        "unique (namespace, topologyKey, selector) groups among the "
+        "pass's pods; axis=keys: the K of dom_idx [K, N]; axis=rows: the "
+        "E of elig_rows [E, N], distinct inclusion specs; axis=domains: "
+        "the Dp of dom_iota, the most domains of a key that is not one "
+        "node a domain) is not the extent of the last pass on this node "
+        "table: another layout of the pass's buffers, so another scan "
+        "executable.  0 is written too (plugins/topologyspread.py "
+        "_bucket).",
+    "spread_rows_built_total":
+        "[N] rows built for PodTopologySpread by walking the node table: "
+        "kind=dom_idx, a topology key's domain row (NodeTable.domain_row, "
+        "which InterPodAffinity's terms share); kind=eligible, the nodes "
+        "a constraint's inclusion policies keep for one (nodeSelector, "
+        "required node affinity, tolerations).  A key or spec this node "
+        "table has met is a lookup in its memo (state/nodes.py "
+        "NodeDerived, kinds dom_idx and spread_eligible) and builds none.",
+    "spread_excluded_nodes_total":
+        "Nodes that the inclusion policies of a pod's topology spread "
+        "constraints leave out of its counting, summed over the pods of "
+        "the passes built: per pod, the most any one of its constraints "
+        "leaves out (0 for a pod without constraints; 0 is written too).  "
+        "Where it is above 0, counting a domain's pods by node (upstream) "
+        "and by domain differ.",
     "volume_static_args_bytes_total":
         "Bytes that travel to the device for the volume family's statics "
         "handed to the scan as arguments (state/compile.py ARG_STATICS), "

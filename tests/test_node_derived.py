@@ -478,12 +478,22 @@ def test_equal_digest_hands_back_the_same_device_arrays():
     picky["spec"]["nodeSelector"] = {"kubernetes.io/os": "linux"}
     e = compile_workload(dep.nodes, [picky], reuse=b, **kw)
     assert e.host["_statics_fp"] == a.host["_statics_fp"]
-    # ... one whose spread constraint adds a dom_idx row does
-    other = dep.measured_pod()
-    other["spec"]["topologySpreadConstraints"] = [{
-        "maxSkew": 1, "topologyKey": "kubernetes.io/hostname",
+    # ... nor does one whose spread constraint adds a dom_idx row (argument
+    # statics too, since PR 52: tests/test_spread_affinity_taints_reference.py)
+    spread = dep.measured_pod()
+    spread["spec"]["topologySpreadConstraints"] = [{
+        "maxSkew": 1, "topologyKey": ZONE,
         "whenUnsatisfiable": "ScheduleAnyway",
         "labelSelector": {"matchLabels": {"no": "pod"}}}]
+    f = compile_workload(dep.nodes, [spread], reuse=b, **kw)
+    assert f.host["_statics_fp"] == a.host["_statics_fp"]
+    # ... one whose inter-pod term adds a dom_idx row over another key does
+    other = dep.measured_pod()
+    other["spec"]["affinity"] = {"podAffinity": {
+        "preferredDuringSchedulingIgnoredDuringExecution": [{
+            "weight": 1, "podAffinityTerm": {
+                "topologyKey": ZONE,
+                "labelSelector": {"matchLabels": {"no": "pod"}}}}]}}
     before = _counts()
     c = compile_workload(dep.nodes, [other], reuse=b, **kw)
     moved = _delta(before, _counts())
@@ -523,8 +533,13 @@ def test_topologyspread_and_interpod_share_one_domain_row():
     row, n_domains = table.domain_row(ZONE)
     assert table.domain_row(ZONE)[0] is row and n_domains == 3
     assert list(table.derived._rows["dom_idx"]) == [ZONE]
-    np.testing.assert_array_equal(
-        np.asarray(cw.statics["PodTopologySpread"].dom_idx)[0], row)
+    st = cw.statics["PodTopologySpread"]
+    np.testing.assert_array_equal(np.asarray(st.dom_idx)[0], row)
+    # one row a KEY, on a padded axis: the pad row keys no node
+    assert np.asarray(st.dom_idx).shape == (2, table.n)
+    assert (np.asarray(st.dom_idx)[1] == -1).all()
+    assert int(np.asarray(st.group_key)[0]) == 0
+    assert not bool(np.asarray(st.is_ident)[0])
     np.testing.assert_array_equal(
         np.asarray(cw.statics["InterPodAffinity"].dom_idx)[0], row)
     # domains are numbered in node order of first appearance

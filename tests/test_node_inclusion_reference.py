@@ -17,8 +17,8 @@ reference for a cluster whose nodes differ (PR 43): clusters shaped like
   * one scan over the whole queue: the device carry's bind decides the
     next pod's skew check;
   * the missing-label message, and a zone-sized domain of which a part is
-    excluded, where upstream counts by node (the reference alone: the
-    program's documented simplification, docs/SEMANTICS.md);
+    excluded, where upstream counts by node: the reference and, since
+    PR 52, the program (it folded counts by domain until then);
   * what the reference refuses (NotCovered), that it imports nothing of
     the program, and that the generator is the seed's function.
 """
@@ -347,10 +347,10 @@ def test_upstream_counts_by_node_in_a_partly_excluded_domain():
     the count as well as out of the minimum (calPreFilterState walks nodes,
     not domains): zone a counts 0, the pod may go to a0.  Under Ignore a1
     counts: zone a holds 1, zone b 0, and a0 is refused for the skew.  The
-    program folds counts by domain whatever node holds the pod
-    (plugins/topologyspread.py, the first of its remaining
-    simplifications): with hostname domains, as in the deployment, a node
-    is its domain and the two agree; this case is the reference's alone."""
+    program keeps its counts by node and folds them per pod and slot over
+    the nodes the slot counts on (plugins/topologyspread.py _fold), so it
+    says the same in both cases; until PR 52 it folded by domain once for
+    all pods and failed the Honor case by construction."""
     nodes = [_node("a0", {HOST: "a0", ZONE: "a"}),
              _node("a1", {HOST: "a1", ZONE: "a"}, tainted=True),
              _node("b0", {HOST: "b0", ZONE: "b"})]
@@ -370,7 +370,11 @@ def test_upstream_counts_by_node_in_a_partly_excluded_domain():
     ignore, node = filter_of(None)
     assert ignore == {"a0": ref.ERR_SKEW, "a1": TAINT_MSG, "b0": "passed"}
     assert node == "b0"
-    # the same cluster spread over hostnames: the program agrees
+    # the program, on both
+    for policy in ("Honor", None):
+        _assert_scan_equals_reference(
+            nodes, [_pod("new", key=ZONE, nodeTaintsPolicy=policy)], [bound])
+    # the same cluster spread over hostnames
     by_host = _pod("old")
     by_host["spec"]["nodeName"] = "a1"
     _assert_scan_equals_reference(

@@ -234,19 +234,22 @@ def test_collapsed_first_round_starts_again_as_the_packed_scan(
     assert all(s[0] for s in spec[0].values())  # everything bound
 
 
-def test_a_round_of_fewer_than_eight_is_no_evidence(monkeypatch):
-    """Seven pods that fit everywhere: every round keeps one, and the
-    pass runs its rounds as it always did: three low ones, then the
-    stream's own scan over leaves; the session keeps no record."""
+def test_a_pass_of_fewer_than_eight_has_no_round_to_gain_from(monkeypatch):
+    """Seven pods that fit everywhere: a round of them could be no
+    evidence and would cost what the scan of all seven does, so the pass
+    is the packed scan's one call as a pass of one pod is (since PR 52;
+    until then three low rounds and the stream's own scan over leaves);
+    no round runs, none is declined, and the session keeps no record."""
     from kube_scheduler_simulator_tpu.control import CONTROLS
 
     nodes = make_nodes(16, seed=31)
     pods = make_pods(7, seed=32)
     TRACER.reset()
     spec = _run_wave(nodes, pods, RELAXED, monkeypatch, True, chunk=64)
-    assert TRACER.counter_totals()["speculative_rounds_total"] == 3
-    assert _labeled("speculative_fallbacks_total") == 1
-    assert (_route("leaves"), _route("packed")) == (1, 0)
+    assert TRACER.counter_totals()["speculative_rounds_total"] == 0
+    assert _labeled("speculative_fallbacks_total") == 0
+    assert _labeled("speculative_declined_passes_total") == 0
+    assert (_route("leaves"), _route("packed")) == (0, 1)
     assert CONTROLS.spec_collapsed(
         None, PluginSetConfig(enabled=list(RELAXED)).signature()) is None
     seq = _run_wave(nodes, pods, RELAXED, monkeypatch, False, chunk=64)
@@ -330,6 +333,98 @@ def test_rounds_are_tried_again_once_the_feasible_share_has_halved(
     seq, _ = run(False)
     assert spec == seq
     assert all(node for node, _a in spec.values())
+
+
+def test_a_probe_waits_for_a_bucket_the_rounds_have_run_on(monkeypatch):
+    """The rounds are tried again on executables that exist.  A session
+    whose rounds collapsed on a burst of 12 (the bucket of 16) and whose
+    declined burst of 20 pinned pods (the bucket of 32) asked for them
+    again serves its next burst on the bucket of 32 declined too, since no
+    round of the session ran there and a probe would compile that bucket's
+    executables first; the burst of 12 after it is the probe, and its
+    rounds accept every pod.  One ask, not one a declined pass."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+
+    nodes, pinned = make_slot_pinned_workload(50, 48, seed=41)
+    enabled = RELAXED + ["NodeAffinity"]
+    bursts = [make_pods(12, seed=42), pinned[:20], pinned[20:38],
+              pinned[38:50]]
+
+    def run(spec_on):
+        monkeypatch.setenv("KSS_TPU_SPECULATIVE", "1" if spec_on else "0")
+        CONTROLS.reset()
+        TRACER.reset()
+        store = ObjectStore()
+        for n in nodes:
+            store.create("nodes", n)
+        engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
+            enabled=list(enabled)), chunk=64)
+        engine.session = "probe-test"
+        seen = []
+        for burst in bursts:
+            for p in burst:
+                store.create("pods", p)
+            assert engine.schedule_pending() == len(burst)
+            seen.append((
+                TRACER.counter_totals().get("speculative_rounds_total", 0),
+                _labeled("speculative_declined_passes_total"),
+                _labeled("speculative_retries_total"),
+                _labeled("speculative_accepted_total")))
+        state = {p["metadata"]["name"]: (
+            p["spec"].get("nodeName"), dict(p["metadata"]["annotations"]))
+            for p in store.list("pods")[0]}
+        engine.close()
+        return state, seen
+
+    spec, seen = run(True)
+    # rounds, declined, retries, accepted after each burst
+    assert seen[0] == (1, 0, 0, 0)          # bucket 16: collapsed
+    assert seen[1] == (1, 1, 1, 0)          # bucket 32: declined, asks
+    assert seen[2] == (1, 2, 1, 0)          # bucket 32: no round ran there
+    assert seen[3] == (3, 2, 1, 12), seen   # bucket 16: the probe, all kept
+    seq, _ = run(False)
+    assert spec == seq
+    assert all(node for node, _a in spec.values())
+
+
+def test_a_probe_that_collapses_records_no_more_than_what_asked_for_it():
+    """The record of a queue whose passes' medians move between two levels
+    (half its pods pinned to half the nodes): the probe's own median may
+    be the high one again, and the record is the share that asked all the
+    same, so the session asks once and not at every turn."""
+    from kube_scheduler_simulator_tpu.control import CONTROLS
+
+    CONTROLS.reset()
+    s, prof = "floor-test", ("profile",)
+    assert not CONTROLS.spec_declines(s, prof, 16)
+    CONTROLS.note_spec_rounds(s, prof, 16, probe=True)
+    CONTROLS.note_spec_collapsed(s, prof, 0.9)
+    assert CONTROLS.spec_declines(s, prof, 16)
+    assert CONTROLS.spec_declines(s, prof, 32)
+    assert not CONTROLS.spec_declines(s, ("another",), 16)
+    assert not CONTROLS.spec_recheck(s, 0.5)        # 2 * 0.5 > 0.9
+    assert CONTROLS.spec_recheck(s, 0.45)
+    assert not CONTROLS.spec_recheck(s, 0.4)        # asked already
+    # the record stands for a bucket no round of the session ran on ...
+    assert CONTROLS.spec_declines(s, prof, 32)
+    assert CONTROLS.spec_collapsed(s, prof) == 0.9
+    # ... a pass of more than one chunk is no probe and clears nothing ...
+    CONTROLS.note_spec_rounds(s, prof, 512, probe=False)
+    assert CONTROLS.spec_declines(s, prof, 32)
+    # ... and the bucket of 16 is the probe's
+    assert not CONTROLS.spec_declines(s, prof, 16)
+    CONTROLS.note_spec_rounds(s, prof, 16, probe=True)
+    assert CONTROLS.spec_collapsed(s, prof) is None
+    CONTROLS.note_spec_collapsed(s, prof, 0.9)
+    assert CONTROLS.spec_collapsed(s, prof) == 0.45
+    assert not CONTROLS.spec_recheck(s, 0.45)
+    assert CONTROLS.spec_recheck(s, 0.22)
+    # rounds that were no probe leave a later collapse its own share
+    CONTROLS.reset()
+    CONTROLS.note_spec_rounds(s, prof, 16, probe=True)
+    CONTROLS.note_spec_collapsed(s, prof, 0.3)
+    assert CONTROLS.spec_collapsed(s, prof) == 0.3
+    CONTROLS.reset()
 
 
 def test_sparse_candidate_eval_through_engine(monkeypatch):

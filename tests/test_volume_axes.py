@@ -290,8 +290,9 @@ def test_argument_statics_are_the_family_s_and_travel_with_the_pass():
     nodes, bound, pending, vols = _seeded_cluster(5)
     cw = compile_workload(nodes, pending, VOL_CFG, volumes=vols)
     family = {"VolumeRestrictions", "NodeVolumeLimits", "VolumeBinding"}
-    # ... and NodeAffinity's match rows, which change with the queue
-    assert set(ARG_STATICS) == family | {"NodeAffinity"}
+    # ... and NodeAffinity's match rows and PodTopologySpread's count
+    # groups, which change with the queue
+    assert set(ARG_STATICS) == family | {"NodeAffinity", "PodTopologySpread"}
     assert family <= set(cw.arg_statics()) <= set(ARG_STATICS)
     assert all(isinstance(leaf, jax.Array)
                for leaf in jax.tree.leaves(cw.arg_statics()))

@@ -158,16 +158,20 @@ ROWS = [
     Row("row08_batchable_profile", config=_enabled(BATCHABLE),
         plan=("speculative", "streamed", "device_lazy"),
         speculative=True, commit="streamed"),
-    Row("row08_batchable_profile_pass_of_two", config=_enabled(BATCHABLE),
-        pods=2, plan=("speculative", "streamed", "device_lazy"),
+    Row("row08_batchable_profile_pass_of_eight", config=_enabled(BATCHABLE),
+        pods=8, plan=("speculative", "streamed", "device_lazy"),
         speculative=True, commit="streamed"),
     Row("row09_volume_family_without_postfilter",
         config=_enabled(["NodeResourcesFit", "VolumeBinding"]),
         plan=("sequential", "streamed", "device_lazy"),
         commit="streamed"),
-    # ... a pass of one pod has nothing to speculate on
+    # ... a pass of one pod has nothing to speculate on, and one of fewer
+    # than a round's least (MIN_ROUND, 8) no round to gain from
     Row("row09_batchable_profile_pass_of_one", config=_enabled(BATCHABLE),
         pods=1, plan=("sequential", "streamed", "device_lazy"),
+        commit="streamed"),
+    Row("row09_batchable_profile_pass_of_seven", config=_enabled(BATCHABLE),
+        pods=7, plan=("sequential", "streamed", "device_lazy"),
         commit="streamed"),
     Row("row10_reflector_cannot_defer_default_profile", tweak=_no_defer,
         plan=("sequential", "post_pass", "by_chunk"),
@@ -366,20 +370,27 @@ def test_row09_serves_one_pod_a_pass():
         assert node and annotations
 
 
-def test_row08_two_pods_a_pass_are_a_round():
-    """The same profile and pods, two a pass: a batch, so the rounds, as
-    for any pass of two pods or more."""
+def test_row09_two_pods_a_pass_are_the_scan_too():
+    """The same profile and pods, two a pass: fewer than a round's least
+    (MIN_ROUND), so the packed scan's one call as for a pass of one (since
+    PR 52; the rounds until then), on the bucket of two: no round, no
+    stream over leaves, and after the first passes nothing compiles."""
     store, engine, pods = _config3_engine()
-    assert engine._wave_plan(2) == WavePlan(
+    assert engine._wave_plan(2) == engine._wave_plan(7) == WavePlan(
+        "sequential", "streamed", "device_lazy")
+    assert engine._wave_plan(8) == WavePlan(
         "speculative", "streamed", "device_lazy")
     TRACER.reset()
+    misses = []
     for i in range(0, len(pods), 2):
         for pod in pods[i:i + 2]:
             store.create("pods", pod)
         assert engine.schedule_pending() == 2
-        assert _counter("speculative_rounds_total") >= i // 2 + 1
+        assert _counter("speculative_rounds_total") == 0
         assert _counter("commit_stream_waves_total") == i // 2 + 1
-        assert _route("packed") == 0
+        assert (_route("packed"), _route("leaves")) == (i // 2 + 1, 0)
+        misses.append(_scan_misses())
+    assert misses[-1] == misses[1], misses
     for node, annotations in _decided(store, pods).values():
         assert node and annotations
 
@@ -439,13 +450,14 @@ def test_row09_a_session_whose_rounds_collapsed_declines_its_batch_passes():
     # (a plan asked for outside a pass, as here, has no session's scope)
     assert TRACER.labeled_totals(
         "speculative_declined_passes_total", "session")["a"] == 1
-    # 2-7 pods set no record and follow none, nor does a pass of more
-    # than one chunk (64 here); one pod is row 9 as ever
-    assert engine._wave_plan(7) == rounds
+    # 1-7 pods are row 9 whatever the record says, and not counted as
+    # declined; a pass of more than one chunk (64 here) keeps its rounds
+    before = _declined()
+    assert engine._wave_plan(7) == engine._wave_plan(1) == WavePlan(
+        "sequential", "streamed", "device_lazy")
+    assert _declined() == before
     assert engine._wave_plan(64) == declined
     assert engine._wave_plan(65) == rounds
-    assert engine._wave_plan(1) == WavePlan(
-        "sequential", "streamed", "device_lazy")
     # another session of the process has its own record
     other, _ = _roomy_session("b")
     assert other._wave_plan(12) == rounds

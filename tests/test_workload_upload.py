@@ -260,6 +260,40 @@ def test_a_device_leaf_stays_and_is_not_packed(monkeypatch, shape):
     assert len(sent) == 1
 
 
+@pytest.mark.parametrize("shape, own", [
+    ((16, 4096), True), ((64, 5000), True), ((32, 5000, 2), True),
+    ((8, 20000), False),        # a resident payload's rows (ROWS_MAX)
+    ((64, 1000), False),        # many rows, short ones
+    ((65536,), False)])
+def test_a_leaf_of_many_long_rows_travels_as_a_buffer_of_its_own(shape, own):
+    """Cutting [64, 5000] out of a flat buffer is a relayout a row in the
+    consumer's executable (state/packed.py): such a leaf is sent in the
+    same device_put as the buffers, stands in the tree as the device
+    array it is, and is a copy of the host's bytes; every other leaf is
+    packed as ever."""
+    from kube_scheduler_simulator_tpu.state.packed import Packed
+
+    big = (np.arange(np.prod(shape)) % 7).astype(np.int32).reshape(shape)
+    tree = {"big": big, "flags": np.array([True, False]),
+            "rows": np.arange(6, dtype=np.int32).reshape(2, 3)}
+    before = TRACER.counter_totals().get("workload_h2d_transfers_total", 0)
+    packed = pack_tree(tree)
+    sent = TRACER.counter_totals()["workload_h2d_transfers_total"] - before
+    leaf = packed.tree["big"]
+    assert isinstance(leaf, Packed) != own
+    assert isinstance(packed.tree["rows"], Packed)
+    assert sent == (3 if own else 2)
+    assert packed.bufs["int32"].size == 6 + (0 if own else big.size)
+    got = packed.take(packed.tree)
+    want = _per_leaf(tree)
+    for key in tree:
+        _assert_same_leaf(got[key], want[key], key)
+    if own:
+        assert got["big"] is leaf
+        big[0] = -1                         # the host goes on writing
+        assert int(np.asarray(leaf).min()) == 0
+
+
 # ------------------------------------------- nothing is read back, one site
 
 
