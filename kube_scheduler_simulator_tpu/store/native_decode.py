@@ -429,9 +429,11 @@ def decode_chunk_start(ctx: _NativeCtx, rr, lo: int, hi: int,
         ig_ptr, _u8p(want), _u8p(skip) if skip is not None else None,
         n_threads,
         _i64p(out_ptrs), _i64p(out_lens),
-        # a call hands out at most what the registry may hold: the forms
-        # of a 512-pod chunk would push each other out unread
-        wireform.WIRE_MIN_LEN, wireform.WIRE_CAP_BYTES,
+        # a call hands out what the registry has room for: a burst's
+        # forms whole, beside those no event has spliced yet; a 512-pod
+        # chunk's up to the unread ceiling, or they would push each
+        # other out unread
+        wireform.WIRE_MIN_LEN, wireform.WIRE_FORMS.decode_budget(),
         _i64p(out_wptrs), _i64p(out_wlens),
         ctypes.byref(tsec), ctypes.byref(failed))
     return _ChunkHandle(ctx, arena, out_ptrs, out_lens, out_wptrs, out_wlens,
@@ -542,6 +544,7 @@ def decode_pod_fused(ctx: _NativeCtx, rr, i: int, hi: int,
         _u8p(active_rows[hi]), _u8p(sskip_rows[hi]),
         col_ptrs, col_elem, ignored_ptr, 1 if want_scores else 0,
         out_blobs, out_lens,
+        # one result: inside any decode budget (WireForms.decode_budget)
         wireform.WIRE_MIN_LEN, out_wire, out_wire_lens,
     )
     wired = [(k, take_sized_bytes(ctx.lib, out_wire[k], out_wire_lens[k]))

@@ -351,15 +351,12 @@ class LazyReflections:
         # a reader's drain (the watch stream's periodic one) is followed
         # by the pods' events: their heavy values get the wire forms they
         # came without, as in _apply; a listing's or an export's flush of
-        # a whole keyspace stops at what the registry can hold
-        wire_budget = wireform.WIRE_CAP_BYTES
+        # a whole keyspace stops where the registry has no room left
         for key, recs in taken:
             sets = []
             for rec in recs:
                 result_set = rec.result_set()
-                if wire_budget > 0:
-                    wire_budget -= wireform.make_missing(
-                        result_set.values(), wire_budget)
+                wireform.make_missing(result_set.values())
                 hist_rec = None
                 skip_history = False
                 try:
@@ -448,7 +445,9 @@ class LazyReflections:
                         print(f"reflector: result-history not updated: {e}",
                               file=sys.stderr)
                 # this pod is being read: its GET and its reflect event
-                # splice what is kept here and by the decoder
+                # splice what the decoder kept, and what is kept here for
+                # a value that came without (none in a burst: the decode
+                # call's forms were admitted whole)
                 wireform.make_missing(annotations.values())
                 try:
                     self.store.update("pods", pod, owned=True)

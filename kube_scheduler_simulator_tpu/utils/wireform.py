@@ -22,7 +22,16 @@ form").  Here the escaped bytes exist once:
     that was PUT, written back by a later wave or recreated carries
     other str objects and finds nothing.  A deep copy keeps the str
     objects (store.get), so the copy a handler holds finds what the
-    stored pod would.  Bounded by WIRE_CAP_BYTES, oldest first.
+    stored pod would.  A form is UNREAD until a watch event has spliced
+    it, SPLICED from then on.  Past WIRE_CAP_BYTES the spliced forms go,
+    oldest first; an unread form is never pushed out by a younger one
+    (a burst's store queue holds every bind before the first reflect
+    event: pod 1's event is built after 29 other write-backs) and goes
+    only when the unread forms alone pass WIRE_UNREAD_CEILING_BYTES:
+    forms nobody will use (no stream attached, a pod never read).
+    What a producer may bring follows from that: a decode call
+    `decode_budget()`, a write-back only `room()`, and it keeps nothing
+    that would push an unread form out.
   * CONSUME.  `body_parts(obj, consumer)` is `json.dumps(obj).encode()`
     in pieces: the object with each kept value swapped for a token is
     encoded as ever (a few KB), cut at the tokens, and the kept bytes
@@ -31,7 +40,8 @@ form").  Here the escaped bytes exist once:
     as it always did.
 
 What is heavy is the value's length, WIRE_MIN_LEN: not its key, not the
-route, not the cluster.  Both numbers are constants; there is no knob.
+route, not the cluster.  The three numbers are constants; there is no
+knob.
 """
 
 from __future__ import annotations
@@ -46,10 +56,19 @@ from .tracing import TRACER
 
 # a value this long is worth a wire form: below it the escape is ~0.1 ms
 WIRE_MIN_LEN = 1 << 16
-# values + wire forms the registry may pin: four or five pods of 5,000
-# nodes' entries (~7 MB each); the read and the reflect event come
-# within milliseconds of the write-back
-WIRE_CAP_BYTES = 32 << 20
+# values + wire forms the registry pins at rest, its spliced forms
+# included: a result of 5,000 nodes' entries is 1.33 MB of values and
+# 1.43 MB of forms, a burst of 30 of them 83 MB, and the burst's read
+# comes after its last event, past the decode of a split window's second
+# pass: 83 MB and half as much again.  A one-pod pass keeps its last ~48
+# results' forms for a late read or a second stream
+WIRE_CAP_BYTES = 128 << 20
+# values + wire forms that NO event has spliced yet: the two passes of a
+# split burst unsent and a third behind them, 3 x 83 MB = 249 MB; a pass
+# of 64 such results (177 MB) whole.  A chunk of 512 of them decoded for
+# one read with no stream attached would bring 1.41 GB: it stops here, at
+# a sixth, and so does a listing's or an export's flush of a keyspace
+WIRE_UNREAD_CEILING_BYTES = 256 << 20
 
 # never on a wire: body_parts swaps it back out.  Random per process, so
 # no stored object can hold it on purpose
@@ -61,46 +80,116 @@ def is_heavy(value) -> bool:
 
 
 class WireForms:
-    """id(value) -> (value, wire form), insertion-ordered, bounded in
-    bytes.  get() is lock-free (one dict read); keep() and the eviction
-    run under the lock."""
+    """id(value) -> (value, wire form) in two insertion-ordered tables,
+    unread and spliced, bounded in bytes: past `cap_bytes` in all the
+    spliced go, oldest first; past `unread_ceiling_bytes` of unread the
+    unread do.  get() is lock-free (two dict reads); keep(), spliced()
+    and the eviction run under the lock."""
 
-    def __init__(self, cap_bytes: int = WIRE_CAP_BYTES):
+    def __init__(self, cap_bytes: int = WIRE_CAP_BYTES,
+                 unread_ceiling_bytes: int = WIRE_UNREAD_CEILING_BYTES):
         self._cap = cap_bytes
+        self._ceiling = unread_ceiling_bytes
         self._mu = threading.Lock()
-        self._ents: OrderedDict[int, tuple] = OrderedDict()
+        self._unread: OrderedDict[int, tuple] = OrderedDict()
+        self._spliced: OrderedDict[int, tuple] = OrderedDict()
         self._bytes = 0
+        self._unread_bytes = 0
 
     def __len__(self) -> int:
-        return len(self._ents)
+        return len(self._unread) + len(self._spliced)
 
     @property
     def pinned_bytes(self) -> int:
         return self._bytes
 
-    def keep(self, value: str, wire) -> None:
+    @property
+    def unread_bytes(self) -> int:
+        return self._unread_bytes
+
+    def room(self) -> int:
+        """Bytes of values + forms a producer may add and push out no
+        unread form."""
+        return max(0, self._ceiling - self._unread_bytes)
+
+    def decode_budget(self) -> int:
+        """What one decode call may bring (the codec's cap argument):
+        the room, so that the call's forms are admitted whole beside
+        every unread one.  Never less than the registry keeps at rest:
+        forms that sat unread while a ceiling of younger ones came are
+        nobody's, and a call that finds no room lets the oldest go."""
+        return min(self._ceiling, max(self.room(), self._cap))
+
+    def keep(self, value: str, wire, spare_unread: bool = False) -> bool:
+        """Pin `wire` under `value`'s identity, unread.  With
+        `spare_unread` the form is kept only where no unread one has to
+        go for it.  -> whether it was kept."""
+        size = len(value) + len(wire)
         with self._mu:
-            old = self._ents.pop(id(value), None)
-            if old is not None:
-                self._bytes -= len(old[0]) + len(old[1])
-            self._ents[id(value)] = (value, wire)
-            self._bytes += len(value) + len(wire)
-            while self._bytes > self._cap and self._ents:
-                _, (v, w) = self._ents.popitem(last=False)
-                self._bytes -= len(v) + len(w)
+            if spare_unread and self._unread_bytes + size > self._ceiling:
+                return False
+            self._drop(id(value))
+            self._unread[id(value)] = (value, wire)
+            self._bytes += size
+            self._unread_bytes += size
+            before = len(self._spliced), len(self._unread)
+            while self._bytes > self._cap and self._spliced:
+                self._drop(next(iter(self._spliced)))
+            while self._unread_bytes > self._ceiling and self._unread:
+                self._drop(next(iter(self._unread)))
+            gone = (before[0] - len(self._spliced),
+                    before[1] - len(self._unread))
+        for state, n in zip(("spliced", "unread"), gone):
+            if n:
+                TRACER.inc("wire_forms_evicted_total", n, state=state)
+        return True
+
+    def _drop(self, key: int) -> None:
+        ent = self._unread.pop(key, None)
+        if ent is not None:
+            self._unread_bytes -= len(ent[0]) + len(ent[1])
+        else:
+            ent = self._spliced.pop(key, None)
+        if ent is not None:
+            self._bytes -= len(ent[0]) + len(ent[1])
+
+    def spliced(self, values) -> None:
+        """A watch event carried these values' forms: from now on they
+        are the first to go."""
+        with self._mu:
+            for value in values:
+                ent = self._unread.get(id(value))
+                if ent is None or ent[0] is not value:
+                    continue
+                # into the second table before out of the first: a get()
+                # between the two finds it in one of them
+                self._spliced[id(value)] = ent
+                del self._unread[id(value)]
+                self._unread_bytes -= len(ent[0]) + len(ent[1])
 
     def get(self, value: str):
-        ent = self._ents.get(id(value))
+        ent = self._unread.get(id(value)) or self._spliced.get(id(value))
         # the entry holds its value, so an id in the table is that value's
         return ent[1] if ent is not None and ent[0] is value else None
 
     def clear(self) -> None:
         with self._mu:
-            self._ents.clear()
-            self._bytes = 0
+            self._unread.clear()
+            self._spliced.clear()
+            self._bytes = self._unread_bytes = 0
 
 
 WIRE_FORMS = WireForms()
+
+
+def _touch() -> None:
+    """A producer ran: the registry's gauges, and the eviction series at
+    0 so that "nothing was evicted" is a reading and not "no such
+    counter"."""
+    for state in ("unread", "spliced"):
+        TRACER.inc("wire_forms_evicted_total", 0, state=state)
+    TRACER.gauge("wire_forms_pinned_bytes", WIRE_FORMS.pinned_bytes)
+    TRACER.gauge("wire_forms_unread_bytes", WIRE_FORMS.unread_bytes)
 
 
 def keep_native(results) -> None:
@@ -114,31 +203,36 @@ def keep_native(results) -> None:
             WIRE_FORMS.keep(value, wire)
         made += bool(pairs)
     TRACER.inc("wire_forms_made_total", made, origin="native")
+    _touch()
 
 
-def make_missing(values, budget: int = WIRE_CAP_BYTES) -> int:
+def make_missing(values) -> int:
     """The write-back's part: every heavy value among `values` (one
     result's) that has no wire form yet gets one, by the encoder
-    json.dumps itself uses, while `budget` bytes last: a batch of
-    write-backs makes no more than the registry can hold.  -> the bytes
-    it kept.  The result counts once, under the origin of its first
-    form: `python` where it brought none."""
+    json.dumps itself uses, while the registry has room: what the codec
+    escaped is not escaped again, and no unread form goes for one made
+    here, so a batch of write-backs (a listing's flush of a keyspace)
+    stops at the ceiling.  -> the bytes it kept.  The result counts
+    once, under the origin of its first form: `python` where it brought
+    none."""
     kept, brought = 0, False
     for value in values:
         if not is_heavy(value):
             continue
         if WIRE_FORMS.get(value) is not None:
             brought = True
-        elif kept < budget:
+        # the form is at least the value's length: no room, no escape
+        elif WIRE_FORMS.room() > 2 * len(value):
             wire = encode_basestring_ascii(value).encode()
-            WIRE_FORMS.keep(value, wire)
-            kept += len(value) + len(wire)
+            if WIRE_FORMS.keep(value, wire, spare_unread=True):
+                kept += len(value) + len(wire)
     if kept and not brought:
         TRACER.inc("wire_forms_made_total", origin="python")
     # a pod was written back: its bodies are about to be built.  Touched
     # here so that "no body fell back" reads 0 and not "no such counter"
     for consumer in ("read", "watch"):
         TRACER.inc("pod_bodies_full_total", 0, consumer=consumer)
+    _touch()
     return kept
 
 
@@ -147,7 +241,9 @@ def body_parts(obj, consumer: str) -> list | None:
     obj's heavy annotation values among them, or None where obj carries
     none (the caller encodes as ever).  A body whose every heavy value
     was spliced counts `pod_bodies_spliced_total{consumer}`; one that
-    had to escape a heavy value itself, `pod_bodies_full_total`."""
+    had to escape a heavy value itself, `pod_bodies_full_total`.  The
+    forms a watch event carried are spliced from then on: the first the
+    registry lets go."""
     meta = obj.get("metadata") if isinstance(obj, dict) else None
     anns = meta.get("annotations") if isinstance(meta, dict) else None
     if not isinstance(anns, dict):
@@ -168,6 +264,8 @@ def body_parts(obj, consumer: str) -> list | None:
         TRACER.inc("pod_bodies_full_total", consumer=consumer)
     else:
         TRACER.inc("pod_bodies_spliced_total", consumer=consumer)
+    if wires and consumer == "watch":
+        WIRE_FORMS.spliced(value for _, value in heavy)
     return parts
 
 

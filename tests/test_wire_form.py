@@ -114,7 +114,8 @@ def _pod(name, cpu="100m"):
 
 
 def _counts() -> dict:
-    """The four series of the two body counters, and the forms made."""
+    """The four series of the two body counters, the forms made and the
+    forms evicted."""
     out = {}
     for how in ("spliced", "full"):
         for consumer, v in TRACER.labeled_totals(
@@ -123,6 +124,9 @@ def _counts() -> dict:
     for origin, v in TRACER.labeled_totals(
             "wire_forms_made_total", "origin").items():
         out["made", origin] = v
+    for state, v in TRACER.labeled_totals(
+            "wire_forms_evicted_total", "state").items():
+        out["evicted", state] = v
     return out
 
 
@@ -414,43 +418,70 @@ def test_a_superseded_pod_is_served_with_its_own_bytes(served):
         di.store.delete("nodes", "big-enough")
 
 
-# --- (d) the cap -------------------------------------------------------
+# --- (d) what the registry keeps and what it lets go --------------------
+
+def _write_back(di, name, i):
+    """A pod whose three heavy values come without a form: the
+    write-back makes them (origin python)."""
+    stored = di.store.create("pods", _pod(name))
+    anns = {f"result-{k}": f"{i}{k}" * (4 * HEAVY) for k in "abc"}
+    di.reflector.lazy_pending().add(
+        "default", name, stored["metadata"]["uid"], [anns])
+    di.store.materialize_reads("pods", name, "default")
+
+
+def _event(di, name) -> None:
+    """The pod's reflect event as one stream builds it, byte for byte."""
+    obj = di.store.get("pods", name, "default", copy_object=False)
+    assert _sent(obj) == _old_event("Pod", "MODIFIED", obj)
+
 
 def test_past_the_cap_the_oldest_go_and_their_reads_are_full(
         served, monkeypatch):
-    """More written-back pods than the registry may pin: oldest first
-    they lose their forms, are still served byte for byte, and count as
-    full; the youngest splice."""
+    """More written-back pods than the registry pins at rest: the forms
+    an event has SPLICED go, oldest first, and their pods are still
+    served byte for byte and count as full; the forms no event has used
+    stay, however young the ones that came after, and splice.  Past the
+    ceiling for forms nobody uses a write-back makes none, and evicts
+    none to make one."""
     di, port = served
-
-    def write_back(name, i):
-        stored = di.store.create("pods", _pod(name))
-        anns = {f"result-{k}": f"{i}{k}" * (4 * HEAVY) for k in "abc"}
-        di.reflector.lazy_pending().add(
-            "default", name, stored["metadata"]["uid"], [anns])
-        di.store.materialize_reads("pods", name, "default")
-
     # what one pod pins: its three values and their forms
-    write_back("capped-probe", 9)
+    _write_back(di, "capped-probe", 9)
     per_pod, per_pod_forms = wireform.WIRE_FORMS.pinned_bytes, 3
     assert len(wireform.WIRE_FORMS) == per_pod_forms
-    # room for two pods and a half
-    reg = wireform.WireForms(cap_bytes=int(2.5 * per_pod))
+    # at rest two pods and a half; unread, four
+    reg = wireform.WireForms(cap_bytes=int(2.5 * per_pod),
+                             unread_ceiling_bytes=int(4.1 * per_pod))
     monkeypatch.setattr(wireform, "WIRE_FORMS", reg)
-    names = [f"capped-{i}" for i in range(5)]
-    for i, name in enumerate(names):
-        write_back(name, i)
-    assert 2 * per_pod_forms <= len(reg) < 3 * per_pod_forms
-    assert reg.pinned_bytes <= 2.5 * per_pod
+    names = [f"capped-{i}" for i in range(7)]
+    before = _counts()
+    for i, name in enumerate(names[:3]):
+        _write_back(di, name, i)
+    for name in names[:2]:
+        _event(di, name)  # pods 0 and 1 are on the stream: spliced
+    assert reg.unread_bytes == per_pod and reg.pinned_bytes == 3 * per_pod
+    for i, name in enumerate(names[3:5], 3):
+        _write_back(di, name, i)
+    # 5 pods' worth is past the cap: both spliced pods' forms went, and
+    # the three unread pods' stay though they alone pass it
+    assert len(reg) == 3 * per_pod_forms
+    assert reg.unread_bytes == reg.pinned_bytes == 3 * per_pod
+    for i, name in enumerate(names[5:], 5):
+        _write_back(di, name, i)
+    # pod 5 fits under the ceiling, pod 6 does not: no form, none evicted
+    assert len(reg) == 4 * per_pod_forms and reg.room() < per_pod
+    assert _grown(before) == {
+        ("made", "python"): 6, ("spliced", "watch"): 2,
+        ("evicted", "spliced"): 2 * per_pod_forms}
     before = _counts()
     for name in names:
         assert _raw(port, "GET", f"/api/v1/pods/default/{name}") \
             == json.dumps(di.store.get("pods", name, "default")).encode()
-    assert _grown(before) == {("full", "read"): 3, ("spliced", "read"): 2}
+    assert _grown(before) == {("full", "read"): 3, ("spliced", "read"): 4}
 
 
 def test_the_registry_is_keyed_by_identity_and_bounded():
-    reg = wireform.WireForms(cap_bytes=100)
+    reg = wireform.WireForms(cap_bytes=100, unread_ceiling_bytes=100)
     a = "a" * 20
     b = "".join(["a"] * 20)  # equal, another object
     assert a == b and a is not b
@@ -460,13 +491,89 @@ def test_the_registry_is_keyed_by_identity_and_bounded():
     assert len(reg) == 1 and reg.pinned_bytes == 20 + 7
     reg.keep(b, b"x" * 60)
     assert reg.get(a) is None and reg.get(b) == b"x" * 60  # oldest first
-    reg.keep("c" * 200, b"")  # larger than the cap itself: nothing stays
-    assert len(reg) == 0 and reg.pinned_bytes == 0
+    reg.keep("c" * 200, b"")  # larger than the ceiling itself: nothing stays
+    assert len(reg) == 0 and reg.pinned_bytes == 0 == reg.unread_bytes
 
 
-def test_a_result_counts_once_and_a_batch_stops_at_its_budget():
+def test_spliced_forms_go_first_and_unread_ones_only_at_the_ceiling():
+    """The two tables of the registry: past the cap a spliced form goes
+    before any unread one, whatever their ages; unread forms alone may
+    pass the cap and go, oldest first, at their ceiling; a form kept
+    with `spare_unread` never pushes one out."""
+    reg = wireform.WireForms(cap_bytes=100, unread_ceiling_bytes=160)
+    old, mid, young, late = ("o" * 30, "m" * 30, "y" * 30, "l" * 30)
+    before = _counts()
+    for v in (old, mid, young):
+        assert reg.keep(v, b"w" * 10)
+    assert reg.pinned_bytes == reg.unread_bytes == 120, "unread: past the cap"
+    reg.spliced([mid, "a value the registry never saw"])
+    assert reg.unread_bytes == 80 and reg.get(mid) == b"w" * 10
+    assert reg.room() == 80 and reg.decode_budget() == 100
+    reg.keep(late, b"w" * 10)  # 160 in all: the spliced one goes, not `old`
+    assert reg.get(mid) is None and reg.get(old) and reg.get(young)
+    assert reg.pinned_bytes == reg.unread_bytes == 120
+    assert not reg.keep("s" * 30, b"w" * 11, spare_unread=True)  # 161
+    assert reg.keep("s" * 30, b"w" * 10, spare_unread=True)      # 160: fits
+    assert reg.room() == 0 and reg.decode_budget() == 100
+    reg.keep("n" * 30, b"w" * 10)  # at the ceiling the oldest unread goes
+    assert reg.get(old) is None and reg.get(young) and len(reg) == 4
+    assert _grown(before) == {("evicted", "spliced"): 1,
+                              ("evicted", "unread"): 1}
+
+
+def test_the_registry_keeps_its_books_under_racing_producers_and_pumps():
+    """Producers keeping, pumps splicing and readers looking up at once,
+    more threads than cores and a short switch interval: the two byte
+    counts are the tables' own sums at the end (a lost update would
+    break them), no bound was passed, and a get() never returned another
+    value's form."""
+    import sys
+
+    reg = wireform.WireForms(cap_bytes=6_000, unread_ceiling_bytes=9_000)
+    errors: list = []
+    stop = time.time() + 1.5
+
+    def actor(k):
+        n = 0
+        try:
+            while time.time() < stop:
+                n += 1
+                value = f"{k}-{n}-" + "v" * (50 + n % 40)
+                wire = b"w" + value.encode()
+                reg.keep(value, wire, spare_unread=bool(n % 3 == 0))
+                got = reg.get(value)
+                if got is not None and got != wire:
+                    errors.append((value, got))
+                if n % 2:
+                    reg.spliced([value])
+                if not (reg.unread_bytes <= 9_000 + 200):
+                    errors.append(("unread", reg.unread_bytes))
+        except Exception as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=actor, args=(k,), daemon=True)
+                   for k in range(min(32, 2 * (os.cpu_count() or 4)))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(was)
+    assert not errors, errors[:3]
+    size = lambda ents: sum(len(v) + len(w) for v, w in ents.values())  # noqa: E731
+    assert reg.unread_bytes == size(reg._unread) <= 9_000
+    assert reg.pinned_bytes == size(reg._unread) + size(reg._spliced)
+    assert reg.pinned_bytes <= 9_000
+
+
+def test_a_result_counts_once_and_a_batch_stops_at_its_budget(monkeypatch):
     """The write-back makes what came without, counts the result under
-    the origin of its first form, and makes no more than its budget."""
+    the origin of its first form, and makes no more than the registry
+    has room for: it stops there, and no unread form goes for one."""
     brought, late, alone = "b" * HEAVY, "l" * HEAVY, "a" * HEAVY
     wireform.WIRE_FORMS.keep(brought, json.dumps(brought).encode())
     before = _counts()
@@ -476,13 +583,17 @@ def test_a_result_counts_once_and_a_batch_stops_at_its_budget():
     assert _grown(before) == {}, "the codec's result, counted there"
     assert wireform.make_missing([alone]) and _grown(before) == {
         ("made", "python"): 1}
+    # room for one value and its form, and a little
+    one = 2 * (HEAVY + 1) + 2
+    reg = wireform.WireForms(cap_bytes=one, unread_ceiling_bytes=one + HEAVY)
+    monkeypatch.setattr(wireform, "WIRE_FORMS", reg)
+    before = _counts()
     more = ["m" * HEAVY + str(i) for i in range(3)]
-    assert wireform.make_missing(more, budget=HEAVY) == 2 * (HEAVY + 1) + 2
-    assert [wireform.WIRE_FORMS.get(v) is not None for v in more] \
-        == [True, False, False]
+    assert wireform.make_missing(more) == one
+    assert [reg.get(v) is not None for v in more] == [True, False, False]
+    assert wireform.make_missing(more[1:]) == 0 and len(reg) == 1
+    assert _grown(before) == {("made", "python"): 1}, "nothing evicted"
 
-
-# --- (e) a read and a pump racing on one pod ---------------------------
 
 class _Part:
     """What the reflector defers for a lazy result: ready, and rendered
@@ -497,6 +608,78 @@ class _Part:
     def result_set(self):
         return self.wave.get(self.i)
 
+
+_BURST: dict = {}
+
+
+def _burst_rr(chunk):
+    if chunk not in _BURST:
+        cw = compile_workload(make_nodes(12, seed=88, taint_fraction=0.3),
+                              make_pods(30, seed=89, with_affinity=True),
+                              PluginSetConfig())
+        _BURST[chunk] = replay(cw, chunk=chunk)
+    return _BURST[chunk]
+
+
+@native
+@pytest.mark.parametrize("passes", [1, 2])
+def test_a_burst_is_escaped_once_and_every_event_splices(monkeypatch, passes):
+    """30 results a cycle in the pump's order: the decode call(s), 30
+    write-backs, and only then the 30 reflect events (the store's queue
+    holds every bind before the first of them).  The registry is sized
+    to the burst as the constants are to the chip's (the cap a burst and
+    a half, the ceiling three), and the burst is 2.5 times what the old
+    cap of four or five results held.  Every form is the codec's, every
+    event splices, no unread form is evicted: not by the write-backs,
+    not by a second pass's decode call that arrives before the first's
+    events are built (passes=2: the split window), and the read of a pod
+    of the first pass still splices.  The next cycle's burst pushes out
+    what was spliced, and nothing else."""
+    rr = _burst_rr(32 // passes)
+    store = ObjectStore()
+    store.add_read_hook(pending := LazyReflections(store))
+
+    def cycle(c):
+        wave = LazyWave(rr, sealed=True)
+        names = [f"burst-{passes}-{c}-{i}" for i in range(30)]
+        for i, name in enumerate(names):
+            stored = store.create("pods", _pod(name))
+            pending.add("default", name, stored["metadata"]["uid"],
+                        [_Part(wave, i)])
+        for name in names:  # the pump meets 30 bind events: 30 flushes
+            store.materialize_reads("pods", name, "default")
+        for name in names:  # and then their 30 reflect events
+            obj = store.get("pods", name, "default", copy_object=False)
+            assert _sent(obj) == _old_event("Pod", "MODIFIED", obj)
+        pod = store.get("pods", names[3], "default")  # the cycle's read
+        assert b"".join(wireform.body_parts(pod, "read")) \
+            == json.dumps(pod).encode()
+
+    cycle(0)  # under the fixture's registry: what one burst pins
+    burst = wireform.WIRE_FORMS.pinned_bytes
+    old_cap = burst * 32 // 83  # 32 MiB beside a burst of 83 MB
+    assert burst > 2.5 * old_cap
+    reg = wireform.WireForms(cap_bytes=burst * 3 // 2,
+                             unread_ceiling_bytes=3 * burst)
+    monkeypatch.setattr(wireform, "WIRE_FORMS", reg)
+    before = _counts()
+    cycle(1)
+    assert reg.pinned_bytes == burst and reg.unread_bytes == 0
+    assert _grown(before) == {("made", "native"): 30,
+                              ("spliced", "watch"): 30,
+                              ("spliced", "read"): 1}
+    before, forms = _counts(), len(reg)
+    cycle(2)
+    # down to the cap and no further: half of cycle 1's forms are left
+    assert burst < reg.pinned_bytes <= burst * 3 // 2
+    assert reg.unread_bytes == 0 and forms < len(reg) < 2 * forms
+    assert _grown(before) == {("made", "native"): 30,
+                              ("spliced", "watch"): 30,
+                              ("spliced", "read"): 1,
+                              ("evicted", "spliced"): 2 * forms - len(reg)}
+
+
+# --- (e) a read and a pump racing on one pod ---------------------------
 
 _ACTOR_JOBS: queue.SimpleQueue = queue.SimpleQueue()
 _ACTORS: list = []
