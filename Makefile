@@ -77,8 +77,8 @@ analyze:
 # wave black-box smoke gate (docs/metrics.md post-mortem dumps): arm a
 # one-rule fault plan via KSS_TPU_FAULT_PLAN, run a wave with the retry
 # budget at 0, and assert a schema-valid post-mortem dump lands in
-# KSS_TPU_BLACKBOX_DIR (fault trip + speculative round history +
-# counter deltas + device fingerprint) — a crashed wave must ship its
+# KSS_TPU_BLACKBOX_DIR (fault trip + protocol action + counter
+# deltas + device fingerprint) — a crashed wave must ship its
 # own evidence
 blackbox-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m tools.blackbox_smoke

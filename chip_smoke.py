@@ -12,10 +12,13 @@ Phases (any failure is a non-zero exit; nothing is reported as null):
            device it is on (GET /api/v1/debug/dump) and FAILS unless that
            is --platform.  Wave A: BASELINE config 4's cluster and queue
            (--nodes x --pods, from --seed) under the default profile — the
-           sequential scan.  Wave B: after PUT /api/v1/reset, the same
-           cluster and queue under the config-4 profile — speculative
-           rounds.  Per wave: the first --prefix pods in queue order
-           byte-equal to reference_impl/sequential.py, cold reads from the
+           sequential scan, committed after the pass.  Wave B: after PUT
+           /api/v1/reset, the same cluster and queue under the config-4
+           profile — the same scan, its commit streamed chunk by chunk;
+           every pass of it is counted by its route (one packed call up to
+           512 pods, the chunked scan over leaves beyond).  Per wave: the
+           first --prefix pods in queue order byte-equal to
+           reference_impl/sequential.py, cold reads from the
            first, a middle and the last replay chunk, engine counters that
            account for every pod, and no hidden rung (no degradation,
            retry, decode failure or loop crash; native chunk decode only;
@@ -68,6 +71,9 @@ PKG = "kube_scheduler_simulator_tpu"
 # JSON per pod at 5,000 nodes): the whole-queue API listing is only taken
 # below this many pod x node cells
 LIST_CELLS_MAX = 2_000_000
+# the pods one device call of the scan takes (state/compile.py POD_CHUNK;
+# this parent imports nothing of the package): a longer pass is chunked
+POD_CHUNK = 512
 
 T0 = time.time()
 
@@ -412,7 +418,11 @@ def run_wave(port: int, tag: str, workload: dict, profile: dict | None,
                delta(c, "scheduling_waves_total"))
         if (now[1] > 0 and waves and waves[-1] != "wave.start"
                 and now == last):
-            break
+            # a short pass can start and end between the two reads above
+            c = counters(port)
+            if now == (delta(c, "pods_scheduled_total"),
+                       delta(c, "scheduling_waves_total")):
+                break
         last = now
         check(not c.get("scheduling_loop_crashes_total"),
               "the scheduling loop crashed (see the server's log)")
@@ -429,13 +439,16 @@ def run_wave(port: int, tag: str, workload: dict, profile: dict | None,
           f"{delta(c, 'pods_unschedulable_total')} pending")
     res.update(bound=bound, unschedulable=n_pods - bound,
                scheduling_passes=delta(c, "scheduling_waves_total"),
-               speculative_rounds=delta(c, "speculative_rounds_total"),
+               commit_stream_waves=delta(c, "commit_stream_waves_total"),
+               replay_routes={r: delta(c, f"replay_route_total{{route={r}}}")
+                              for r in ("packed", "leaves")},
                wave_d2h_bytes=delta(c, "wave_d2h_bytes_total"),
                scan_compiles=delta(c, "scan_compile_cache_total{result=miss}"))
     log(f"wave {tag}: {bound}/{n_pods} bound in "
         f"{res['smoke_seconds']['import_to_idle']}s, "
-        f"{res['scheduling_passes']} pass(es), "
-        f"{res['speculative_rounds']} speculative round(s)")
+        f"{res['scheduling_passes']} pass(es), routes "
+        f"{res['replay_routes']}, "
+        f"{res['commit_stream_waves']} streamed commit(s)")
 
     # prefix parity, byte for byte, bindings included
     want = finish_child(oracle, f"oracle_{tag}", deadline)["pods"]
@@ -485,7 +498,7 @@ def run_wave(port: int, tag: str, workload: dict, profile: dict | None,
     res["no_hidden_rung"] = no_hidden_rung(port, args.platform)
     # where the wave's time went, for whoever reads this run later: the
     # tracer's spans, counters and histograms as the server reports them,
-    # and the black box's timeline (wave/compile/round events)
+    # and the black box's timeline (wave and compile events)
     (out_dir / f"wave_{tag}.metrics.json").write_text(
         json.dumps(ok_api(port, "GET", "/api/v1/metrics")))
     events = [e for e in ok_api(port, "GET", "/api/v1/debug/dump")["dump"]
@@ -496,8 +509,6 @@ def run_wave(port: int, tag: str, workload: dict, profile: dict | None,
     res["passes"] = [{"pods": a["pods"], "bound": b["bound"],
                       "smoke_seconds": round(b["t"] - a["t"], 2)}
                      for a, b in zip(starts, ends)]
-    res["speculative_fallbacks"] = sum(
-        1 for e in events if e["kind"] == "speculative.fallback")
     return res
 
 
@@ -556,8 +567,13 @@ def phase_served(args, out_dir: Path, summary: dict, deadline: float) -> dict:
         port, "b", workload,
         restricted_profile(summary["workloads"]["config4_plugins"]),
         args, out_dir, deadline)
-    check(out["wave_b_config4_profile"]["speculative_rounds"] > 0,
-          "wave B ran no speculative round")
+    b = out["wave_b_config4_profile"]
+    check(b["commit_stream_waves"] > 0, "wave B's commit was not streamed")
+    # no PostFilter, no retry: a pass is one scan, by its length one route
+    long = sum(1 for p in b["passes"] if p["pods"] > POD_CHUNK)
+    check(b["replay_routes"] == {"packed": len(b["passes"]) - long,
+                                 "leaves": long},
+          f"wave B's passes {b['passes']} took routes {b['replay_routes']}")
     out["shed_429s_honoured"] = SHED_429S
     rc = stop(srv)
     log(f"served-path server stopped (rc {rc})")

@@ -1,22 +1,15 @@
 """SLO-driven autopilot: the controller thread behind `CONTROLS`.
 
 The telemetry planes grew eyes everywhere — rolling per-session p50/p99
-wave latency (utils/blackbox.py SLOTracker), per-round speculative
-accept fractions, HBM spill counters, retained-bytes accounting — but
-every policy knob stayed a static `KSS_TPU_*` env var.  This module
-closes the loop (ROADMAP item 4, docs/autopilot.md): a periodic tick
-reads those planes and acts through three effectors, writing ONLY the
-`CONTROLS` registry (control/__init__.py) that the data-plane read
-sites consult:
+wave latency (utils/blackbox.py SLOTracker), HBM spill counters,
+retained-bytes accounting — but every policy knob stayed a static
+`KSS_TPU_*` env var.  This module closes the loop (ROADMAP item 4,
+docs/autopilot.md): a periodic tick reads those planes and acts through
+two effectors, writing ONLY the `CONTROLS` registry
+(control/__init__.py) that the data-plane read sites consult.
+Hysteresis: an effector moves only after HYSTERESIS_TICKS consecutive
+ticks beyond its threshold — one bad wave never thrashes it.
 
-  * speculative tuning — a session whose rolling accept fraction stays
-    high gets the aggressive profile (start at the TOP ladder rung,
-    double the operator's KSS_TPU_SPECULATIVE_CANDIDATES cap); one
-    that keeps collapsing gets the conservative profile (start at the
-    bottom rung, halve the cap); a sustained mid-band fraction decays
-    the profile back to the static default.  Hysteresis: a profile
-    changes only after HYSTERESIS_TICKS consecutive ticks beyond the
-    threshold — one bad wave never thrashes the ladder.
   * HBM rebalancing — sessions observed spilling get a larger share of
     KSS_TPU_DEVICE_RESULT_BUDGET_MB (weight steps up per spilling
     tick); calm sessions decay back toward the equal split, and a
@@ -58,7 +51,7 @@ import time
 from collections import deque
 
 from ..utils.blackbox import BLACKBOX, FEEDER
-from ..utils.env import env_float, env_int, env_switch
+from ..utils.env import env_float, env_switch
 from ..utils.faults import fault_point
 from ..utils.tracing import TRACER
 from . import CONTROLS, QOS_TIERS, WEIGHT_CAP, WEIGHT_FLOOR
@@ -67,18 +60,6 @@ from . import CONTROLS, QOS_TIERS, WEIGHT_CAP, WEIGHT_FLOOR
 # the hysteresis band that keeps one bad wave (or one good one) from
 # thrashing a profile back and forth
 HYSTERESIS_TICKS = 2
-
-# speculative profiles: (start rung, candidate-cap multiplier vs the
-# static KSS_TPU_SPECULATIVE_CANDIDATES default).  rung <0 = top.
-_SPEC_PROFILES = {
-    "default": (None, None),
-    "aggressive": (-1, 2.0),
-    "conservative": (0, 0.5),
-}
-_SPEC_HI = 0.90   # rolling accept fraction at/above: climb
-_SPEC_LO = 0.50   # below: back off
-_SPEC_BASE_CANDIDATES = 128   # KSS_TPU_SPECULATIVE_CANDIDATES default
-_SPEC_MID_TICKS = 4   # mid-band ticks before a profile decays to default
 
 _WEIGHT_STEP = 0.5
 _DONATE_WEIGHT = 0.5   # a no-demand session's share while neighbors spill
@@ -108,20 +89,11 @@ def shed_qos_tiers() -> tuple[str, ...]:
 class _SessState:
     """Controller-internal per-session memory (streaks, baselines)."""
 
-    __slots__ = ("spec_mode", "hi_streak", "lo_streak", "mid_streak",
-                 "accepted", "rolled", "rounds", "wide", "spilled", "calm_ticks",
-                 "breach_streak", "ok_streak", "waves_total")
+    __slots__ = ("spilled", "calm_ticks", "breach_streak", "ok_streak",
+                 "waves_total")
 
     def __init__(self):
-        self.spec_mode = "default"
-        self.hi_streak = 0
-        self.lo_streak = 0
-        self.mid_streak = 0
-        self.accepted = 0.0    # counter baselines from the previous tick
-        self.rolled = 0.0
-        self.rounds = 0.0
-        self.wide = 0.0
-        self.spilled = 0.0
+        self.spilled = 0.0     # counter baseline from the previous tick
         self.calm_ticks = 0
         self.breach_streak = 0
         self.ok_streak = 0
@@ -225,8 +197,6 @@ class Autopilot:
         # hist_idx is -1 and the planes are identical — one code path,
         # the parity baseline unchanged.
         hist_idx, planes = FEEDER.sample()
-        accepted = planes["accepted"]
-        rolled = planes["rolled"]
         spilled = planes["spilled"]
         slo = planes["slo"]
         from ..framework.replay import _DEVICE_BUDGET
@@ -255,8 +225,6 @@ class Autopilot:
                 evd = {"sloWindow": slo.get(sid)}
                 if hist_idx >= 0:
                     evd["historyIndex"] = hist_idx
-                self._plan_speculative(plan, sid, st, accepted, rolled,
-                                       planes["rounds"], evd)
                 spill_d = spilled.get(sid, 0.0) - st.spilled
                 st.spilled = spilled.get(sid, 0.0)
                 if limit is not None and limit > 0:
@@ -276,80 +244,6 @@ class Autopilot:
                 self._decide("evict", None, "idle", "evicted",
                              f"global stress: {evicted} idle session(s)")
         return len(plan)
-
-    # ------------------------------------------------- effector: spec
-
-    def _plan_speculative(self, plan, sid, st, accepted, rolled, rounds,
-                          evd) -> None:
-        a_d = accepted.get(sid, 0.0) - st.accepted
-        r_d = rolled.get(sid, 0.0) - st.rolled
-        n_d = rounds.get(sid, 0.0) - st.rounds
-        st.accepted = accepted.get(sid, 0.0)
-        st.rolled = rolled.get(sid, 0.0)
-        st.rounds = rounds.get(sid, 0.0)
-        wide = TRACER.session_totals("speculative_wide_rounds_total").get(
-            sid, 0.0)
-        w_d, st.wide = wide - st.wide, wide
-        # a round's first pod is accepted whatever the contention
-        # (parallel/speculative.py): it is no evidence.  A session served
-        # one pod a pass read 1.00 for ever, went aggressive on nothing and
-        # paid an 11-12 s compile of the wider sparse round mid-session
-        # (my chip run, PR 46: pass ~26 of baseline_c3_1k.interactive_profile)
-        a_d = max(a_d - n_d, 0.0)
-        if a_d + r_d <= 0:
-            return   # no contested pod since the last tick: no evidence
-        frac = a_d / (a_d + r_d)
-        if frac >= _SPEC_HI:
-            st.hi_streak += 1
-            st.lo_streak = st.mid_streak = 0
-        elif frac < _SPEC_LO:
-            st.lo_streak += 1
-            st.hi_streak = st.mid_streak = 0
-        else:
-            st.hi_streak = st.lo_streak = 0
-            st.mid_streak += 1
-        want = st.spec_mode
-        reason = (f"accept fraction {frac:.2f} over "
-                  f"{int(a_d + r_d)} contested pod(s)")
-        if st.hi_streak >= HYSTERESIS_TICKS:
-            want = "aggressive"
-        elif st.lo_streak >= HYSTERESIS_TICKS:
-            want = "conservative"
-        elif st.mid_streak >= _SPEC_MID_TICKS:
-            # sustained mid-band evidence: the static default fits
-            # again — decay back instead of pinning the last profile
-            # forever (mirrors the budget effector's calm-tick decay)
-            want = "default"
-            reason = (f"accept fraction {frac:.2f} mid-band for "
-                      f"{st.mid_streak} tick(s)")
-        if want == st.spec_mode:
-            return
-        rung, mult = _SPEC_PROFILES[want]
-        # scale the OPERATOR's baseline, not the built-in default —
-        # with KSS_TPU_SPECULATIVE_CANDIDATES=512 aggressive must mean
-        # 1024, not 256
-        base = env_int("KSS_TPU_SPECULATIVE_CANDIDATES",
-                       _SPEC_BASE_CANDIDATES)
-        cand = None if mult is None else max(int(base * mult), 16)
-        if w_d * 2 >= n_d > 0:
-            # most of the rounds behind this evidence dropped their sparse
-            # probe for wide feasibility and ran dense: the cap has nothing
-            # to act on, and another cap is another sparse-round executable
-            # for every bucket and rung the session meets, 30-70 s on the
-            # chip in the middle of a session (my chip run, PR 50: cycle 73
-            # of baseline_c3_queue_1k.rollout30_profile's warm-up).  The
-            # start rung still moves: its executables are the ladder's own
-            cand = None
-        frm, to = st.spec_mode, want
-
-        def apply(sid=sid, st=st, want=want, rung=rung, cand=cand):
-            st.spec_mode = want
-            st.hi_streak = st.lo_streak = st.mid_streak = 0
-            CONTROLS.set_spec(sid, rung, cand)
-
-        plan.append(("speculative", sid, frm, to, reason,
-                     {**evd, "acceptFraction": round(frac, 6),
-                      "rounds": int(a_d + r_d)}, apply))
 
     # ----------------------------------------------- effector: budget
 

@@ -19,7 +19,6 @@ from __future__ import annotations
 import contextlib
 import copy
 import functools
-import os
 import time
 from typing import NamedTuple
 
@@ -29,11 +28,10 @@ from .replay import (
     considered_rows, count_narrowed, filter_rejected_rows, replay)
 from .unschedulable import pod_key
 from ..cluster.store import Conflict, NotFound, ObjectStore, volume_manifests
-from ..control import CONTROLS
 from ..utils.env import env_int
 from ..utils.tracing import TRACER
 from ..plugins.registry import PluginSetConfig
-from ..state.compile import POD_CHUNK, compile_workload, pod_axis_bucket
+from ..state.compile import POD_CHUNK, compile_workload
 from ..store import annotations as ann
 from ..store.decode import decode_chunk_into, decode_pod_result
 from ..store.reflector import StoreReflector
@@ -199,13 +197,9 @@ class WavePlan(NamedTuple):
     """What a wave does, decided once (SchedulerEngine._wave_plan): the
     three columns of the table at the head of docs/wave-pipeline.md."""
 
-    scan: str     # speculative | sequential | host_loop
+    scan: str     # sequential | host_loop
     commit: str   # streamed | post_pass | host_loop
     results: str  # device_lazy | host_lazy | by_chunk | by_pod
-    # a batch pass on a batchable profile that takes the sequential scan
-    # because the session's rounds collapsed (row 9): the executor reads
-    # the pass's feasible share against that record
-    declined: bool = False
 
 
 class _WaveAbort(Exception):
@@ -881,7 +875,7 @@ class SchedulerEngine:
     def schedule_pending(self) -> int:
         """One scheduling wave over all pending pods (plus retry waves for
         pods unblocked by preemption, and re-runs after a custom
-        Reserve/Permit/PreBind rejected a speculative placement). Returns
+        Reserve/Permit/PreBind rejected a placement the scan assumed). Returns
         #bound.  Runs under the owning session's tracer scope (self.session;
         a no-op for direct engine use).
 
@@ -901,8 +895,8 @@ class SchedulerEngine:
         # trace correlation (docs/metrics.md): the wave that drains the
         # submitted work claims the session's pending trace id (noted by
         # the server per workload-submitting request, consume-once) so
-        # every span/event below — wave, speculative rounds, fused
-        # dispatch — carries the id of the HTTP request that caused it.
+        # every span/event below the wave carries the id of the HTTP
+        # request that caused it.
         # trace_scope(None) is a no-op, so direct engine use under an
         # explicit caller-provided trace scope is left untouched.
         with TRACER.session_scope(self.session), \
@@ -1286,7 +1280,7 @@ class SchedulerEngine:
 
         retry == "preempted": preemption nominated a node, run a retry wave.
         retry == "rejected": a custom Reserve/Permit/PreBind rejected a pod
-        AFTER the device replay speculatively folded it into the carry —
+        AFTER the device replay had folded it into the carry —
         the rest of the wave is re-run with upstream-sequential state (the
         rejected pod excluded), so later pods never observe the phantom
         bind (upstream scheduleOne semantics)."""
@@ -1370,11 +1364,11 @@ class SchedulerEngine:
         self._count_pod_axis(cw)
         # the Coscheduling plugin's name where the vectorized quorum pass
         # stands in for its per-pod Permit calls this wave
-        # (docs/gang-scheduling.md): the plan and the speculative stream
-        # leave it out of the profile
+        # (docs/gang-scheduling.md): the plan leaves it out of the
+        # lifecycle set
         vectorized = gp is not None and self._gang_vectorized()
         ignore = frozenset({gp.name}) if vectorized else frozenset()
-        plan = self._wave_plan(cw.n_pods, ignore)
+        plan = self._wave_plan(ignore)
         if plan.scan == "host_loop":
             # gangs route through the per-pod Permit machinery here
             # (the Coscheduling plugin stays in the lifecycle set)
@@ -1390,85 +1384,30 @@ class SchedulerEngine:
 
         # a live cluster's node count need not divide the mesh's "nodes"
         # extent; shard only waves where it does and run the rest
-        # unsharded (shard_workload would reject the shape) — speculative
-        # dp batching tolerates mesh=None
+        # unsharded (shard_workload would reject the shape)
         mesh = self.mesh
         if mesh is not None:
             from ..parallel.mesh import can_shard
 
             if not can_shard(cw.n_nodes, mesh):
                 mesh = None
-        return self._device_wave(plan, cw, mesh, pending, exclude, ignore)
+        return self._device_wave(plan, cw, mesh, pending, exclude)
 
-    def _wave_plan(self, n_pods: int,
-                   ignore: frozenset = frozenset()) -> WavePlan:
+    def _wave_plan(self, ignore: frozenset = frozenset()) -> WavePlan:
         """The three decisions of a wave, taken ONCE, after
         compile_workload, from what the engine observes (the table at the
         head of docs/wave-pipeline.md, row by row); the executor, the
         committer and _finish_wave's caller read the value and ask
-        nothing again.  n_pods: how many pods the pass holds (cw.n_pods:
-        the queue after gates, excludes and the gang prescreen) — the
-        rounds are for a pass that holds a batch; a pass of ONE pod has
-        nothing to accept, roll back or cut, so on a batchable profile
-        it takes the sequential scan, one call over the pass's packed
-        buffers (row 9), where a round of one was ~17 dispatches and a
-        discarded probe.  ignore: the gang plugin's name where the
+        nothing again.  ignore: the gang plugin's name where the
         vectorized quorum pass handles it this wave (row 11) — it then is
-        no lifecycle plugin and speculation_ok ignores it: its PreFilter
-        ran in the prescreen, admission happens in the quorum pass at
-        commit, it neither filters nor scores on device.
-
-        The fifth observation, beside the profile, the reflector, the
-        rung and n_pods, is the rounds' own record: a pass whose first
-        round kept a quarter of its pods or less gains nothing from the
-        rounds (parallel/speculative.py), and the session remembers it
-        (CONTROLS.spec_declines, under the profile that made it).  A
-        batch pass of such a session is DECLINED: the sequential scan
-        from the start, no stream opened, no probe round paid
-        (speculative_declined_passes_total), until a declined pass finds
-        the queue's feasible share halved (_device_wave) and a batch pass
-        comes on a pod-axis bucket the session's rounds have run on: that
-        pass is the probe, on executables that exist.  A pass of
-        fewer pods than a round needs to be evidence (MIN_ROUND) has no
-        round to gain from either: one round that accepts all of it costs
-        the host what the packed scan of the whole pass does (~6 ms against
-        3.4 ms + the steps, PERF.md section 7), it can set no record and
-        would build a bucket's round executables to follow none, so it
-        takes the sequential scan as a pass of one pod does.  A pass of
-        more than one chunk delivers chunk by chunk, cannot start again,
-        and keeps its ladder and its in-stream fallback whatever the
-        record says."""
+        no lifecycle plugin: its PreFilter ran in the prescreen,
+        admission happens in the quorum pass at commit, it neither
+        filters nor scores on device."""
         if self._needs_host_path():
             return WavePlan("host_loop", "host_loop", "by_pod")
         # _gang_vectorized: the gang plugin is the only lifecycle plugin
         lifecycle = not ignore and bool(self._custom_lifecycle_plugins())
         observers = bool(self._extenders_map())
-        # speculative multi-pod rounds, for profiles that admit exact
-        # batching (the stock default profile does not: it enables the
-        # volume family) and passes that hold a batch (no tuned
-        # threshold: at one pod the two scans compute the same thing).
-        # KSS_TPU_SPECULATIVE=0 pins the sequential scan: the parity
-        # baseline the golden suite diffs against
-        scan, declined = "sequential", False
-        if (os.environ.get("KSS_TPU_SPECULATIVE", "1") != "0"
-                and self.extender_service is None and not lifecycle):
-            from ..parallel.speculative import MIN_ROUND, speculation_ok
-
-            if speculation_ok(self.plugin_config, have_manifests=True,
-                              ignore=ignore):
-                if (MIN_ROUND <= n_pods <= self.chunk
-                        and CONTROLS.spec_declines(
-                            self.session, self.plugin_config.signature(),
-                            pod_axis_bucket(n_pods, self.chunk))):
-                    declined = True
-                    TRACER.inc("speculative_declined_passes_total")
-                    TRACER.count("speculative_rounds_total", 0)
-                elif n_pods >= MIN_ROUND:
-                    scan = "speculative"
-                else:
-                    # the pass's zero rounds, counted: a batchable profile
-                    # that has served no batch yet reads 0, not absent
-                    TRACER.count("speculative_rounds_total", 0)
         # the sequential post-pass where after_cycle observers see each
         # pod's annotations in order, a custom Reserve / Permit / PreBind
         # can reject and abort the wave, or a PostFilter (preemption)
@@ -1494,19 +1433,16 @@ class SchedulerEngine:
             results = "device_lazy" if self._residency == 0 else "host_lazy"
         else:
             results = "by_chunk"
-        return WavePlan(scan, "streamed" if streamed else "post_pass",
-                        results, declined)
+        return WavePlan("sequential",
+                        "streamed" if streamed else "post_pass", results)
 
     def _device_wave(self, plan: WavePlan, cw, mesh, pending: list[dict],
-                     exclude: set[tuple[str, str]] | None,
-                     ignore: frozenset = frozenset()
+                     exclude: set[tuple[str, str]] | None
                      ) -> tuple[int, str | None]:
         """Every wave the device scans, run as its plan says: the replay
-        (the chunked sequential scan, or the speculative rounds, which
-        deliver on the same chunk grid through the same on_chunk
-        contract, so lazy decode, device residency, the gang-cut
-        watermark and the uncommitted-suffix retry compose unchanged),
-        the chunk consumer, one span, one abort protocol, the commit."""
+        (the sequential scan, delivering chunk by chunk through
+        on_chunk), the chunk consumer, one span, one abort protocol, the
+        commit."""
         lazy = plan.results in ("device_lazy", "host_lazy")
         gang = self._gang_wave if self._gang_wave else None
         committer = all_annotations = on_chunk = None
@@ -1532,37 +1468,19 @@ class SchedulerEngine:
 
         # self.chunk as it is: the callee clamps it to the queue's length
         unroll = self.unroll if len(pending) > self.chunk else 1
-        kw = dict(chunk=self.chunk, unroll=unroll, on_chunk=on_chunk,
-                  device_resident=plan.results == "device_lazy")
-        span, stage, attrs = "replay_and_decode_stream", "replay_stream", {}
-        if plan.scan == "speculative":
-            stage, attrs = "speculative_replay", {"mode": "speculative"}
-        elif plan.results == "by_pod":
+        span, stage = "replay_and_decode_stream", "replay_stream"
+        if plan.results == "by_pod":
             span = stage = "device_replay"
         try:
             with TRACER.span(span, pods=len(pending),
-                             nodes=self._wave_node_count, **attrs) as sp:
+                             nodes=self._wave_node_count) as sp:
                 if committer is not None:
                     # the worker's commit_stream spans parent under the
                     # wave's replay span across the thread boundary
                     committer.parent_span = sp.id
-                rr = None
-                if plan.scan == "speculative":
-                    from ..parallel.speculative import (
-                        replay_speculative_stream)
-
-                    rr, _stats = replay_speculative_stream(
-                        cw, mesh, pods=pending, gang=gang, ignore=ignore,
-                        namespaces=self._list_shared("namespaces"), **kw)
-                if rr is None:
-                    # the sequential scan, the packed route's one call for
-                    # a pass of one chunk: the plan's own, or the same pass
-                    # started again where its first speculative round
-                    # collapsed (nothing was delivered, so the committer
-                    # and the abort protocol stand as they were)
-                    rr = replay(cw, mesh=mesh, **kw)
-                if plan.declined:
-                    self._recheck_rounds(cw, rr)
+                rr = replay(cw, mesh=mesh, chunk=self.chunk, unroll=unroll,
+                            on_chunk=on_chunk,
+                            device_resident=plan.results == "device_lazy")
         except BaseException as e:
             if committer is None:
                 # nothing was committed yet (_finish_wave commits AFTER
@@ -1598,22 +1516,6 @@ class SchedulerEngine:
             all_annotations = _LazyDecode(rr)
         return self._finish_wave(cw, rr, all_annotations, pending, exclude,
                                  lazy_wave=lazy_wave)
-
-    def _recheck_rounds(self, cw, rr) -> None:
-        """A declined pass's feasible share against the session's record
-        of collapsed rounds: the dirty-node rule accepts long prefixes
-        exactly where feasibility is sparse, and the scan brings every
-        pod's feasible count back in its one decision row, so where the
-        pass's median share has fallen to half the collapsed round's or
-        less the rounds are asked for again (speculative_retries_total):
-        the next batch pass on a bucket they have run on tries them."""
-        from ..utils.blackbox import BLACKBOX
-
-        share = float(np.median(rr.feasible_count)) / max(cw.n_nodes, 1)
-        if CONTROLS.spec_recheck(self.session, share):
-            TRACER.inc("speculative_retries_total")
-            BLACKBOX.record("speculative.retry",
-                            feasible_share=round(share, 4))
 
     def _record_attribution(self, rr, replay_seconds: float,
                             att: dict | None = None) -> None:
@@ -1681,9 +1583,9 @@ class SchedulerEngine:
     def _finish_wave(self, cw, rr, all_annotations, pending,
                      exclude: set[tuple[str, str]] | None,
                      lazy_wave=None) -> tuple[int, str | None]:
-        """Commit + reflect phase of a wave, shared by the scan and
-        speculative replay paths: result-store puts, extender hooks,
-        custom lifecycle, binds, postfilter/preemption, write-backs.
+        """Commit + reflect phase of a post-pass wave: result-store
+        puts, extender hooks, custom lifecycle, binds,
+        postfilter/preemption, write-backs.
 
         lazy_wave: a sealed LazyWave standing in for all_annotations —
         the commit deposits handles and routes write-backs through

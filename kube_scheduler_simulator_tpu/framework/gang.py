@@ -261,33 +261,6 @@ def quorum_slice(gid: np.ndarray, selected: np.ndarray,
     return (admit_np, wave_np, np.asarray(wait_mask))
 
 
-def aligned_cut(gid: np.ndarray, start: np.ndarray, lo: int, k: int,
-                p: int) -> int:
-    """Pull a prospective cut at pod lo+k back to the nearest gang
-    boundary, so gangs stream as ALL-OR-NOTHING prefix units: when the
-    pods on either side of the cut share a group (gangs are contiguous
-    in pending order), the cut retreats to the group's first index and
-    the whole gang re-evaluates next round against the updated carry —
-    exactly the state its members would have seen sequentially, so
-    parity is unaffected; the pullback only keeps a gang's members in
-    one acceptance unit.  A gang larger than the unit (pullback would
-    leave an empty, non-terminating round) is accepted mid-gang instead
-    — the streaming committer's gang-cut watermark still defers its
-    COMMIT until the group is whole, so admission stays atomic.
-
-    Used by the speculative stream's round acceptance (the quorum
-    decision itself remains quorum_slice at commit)."""
-    a = lo + k
-    if k <= 0 or a >= p:
-        return k
-    g = int(gid[a])
-    if g >= 0 and int(gid[a - 1]) == g:
-        pull = int(start[g]) - lo
-        if pull >= 1:
-            return pull
-    return k
-
-
 # ------------------------------------------------------------ preemption
 
 

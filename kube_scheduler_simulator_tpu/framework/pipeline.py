@@ -269,10 +269,12 @@ def considered_nodes(cw, sl) -> jnp.ndarray | None:
     return mask
 
 
-def _filter_phase(cw, carry, sl, filter_names):
-    """filters in config order -> ([F, N] codes, [N] feasible, [N]
-    considered or None).  A node the PreFilterResult leaves out is not
-    feasible and carries NOT_EVALUATED in every plugin's row."""
+def _eval_phase(cw: CompiledWorkload, carry, sl, weights, filter_names, score_names):
+    """filter -> score -> normalize -> weight. Returns
+    (filter_codes [F,N], score_raw [S,N], score_final [S,N], feasible [N],
+    total [N] with infeasible forced to -1, considered [N] or None).  A
+    node the PreFilterResult leaves out is not feasible and carries
+    NOT_EVALUATED in every plugin's row."""
     n = cw.n_nodes
     codes = []
     feasible = jnp.ones(n, dtype=bool)
@@ -290,19 +292,7 @@ def _filter_phase(cw, carry, sl, filter_names):
         feasible = feasible & considered
         filter_codes = jnp.where(considered[None, :], filter_codes,
                                  NOT_EVALUATED)
-    return filter_codes, feasible, considered
 
-
-def _score_phase(cw, carry, sl, weights, score_names, feasible):
-    """score -> normalize -> weight over whatever node set the inputs
-    cover: the full [N] axis on the scan path, or a GATHERED candidate
-    subset (parallel/speculative.py sparse tail — cw/carry/sl node-axis
-    leaves pre-gathered, `feasible` marking the valid rows; the
-    normalizations reduce over the feasible set only, so the subset
-    result is bit-identical to the dense one at those positions).
-    Returns (score_raw [S, n], score_final [S, n], total [n] with
-    infeasible forced to -1)."""
-    n = feasible.shape[0]
     raws, finals = [], []
     total = jnp.zeros(n, dtype=jnp.int64)
     for i, name in enumerate(score_names):
@@ -319,17 +309,6 @@ def _score_phase(cw, carry, sl, weights, score_names, feasible):
     score_raw = jnp.stack(raws) if raws else jnp.zeros((0, n), dtype=jnp.int64)
     score_final = jnp.stack(finals) if finals else jnp.zeros((0, n), dtype=jnp.int64)
     total = jnp.where(feasible, total, jnp.int64(-1))
-    return score_raw, score_final, total
-
-
-def _eval_phase(cw: CompiledWorkload, carry, sl, weights, filter_names, score_names):
-    """filter -> score -> normalize -> weight. Returns
-    (filter_codes [F,N], score_raw [S,N], score_final [S,N], feasible [N],
-    total [N] with infeasible forced to -1, considered [N] or None)."""
-    filter_codes, feasible, considered = _filter_phase(
-        cw, carry, sl, filter_names)
-    score_raw, score_final, total = _score_phase(
-        cw, carry, sl, weights, score_names, feasible)
     return filter_codes, score_raw, score_final, feasible, total, considered
 
 

@@ -903,7 +903,9 @@ def plugin_attribution(rr: ReplayResult) -> dict | None:
                             "sum": raw score sum over those}},
        "prefilter": {name: {"evaluated": pods screened (not skipped),
                             "screened": pods it rejected pre-wave}}}
-    or None when the result is empty / holds neither layout.
+    or None when the result is empty or holds no compact chunks (the
+    host loop's per-pod full-array results: its plugins record real wall
+    time instead).
 
     Semantics mirror the framework: a filter plugin "ran" on (pod, node)
     when no earlier active plugin failed there (stop-at-first-fail);
@@ -914,42 +916,10 @@ def plugin_attribution(rr: ReplayResult) -> dict | None:
     is derived from (docs/metrics.md).  The compact path delegates to
     ChunkAttribution (the streaming committer runs it chunk-at-a-time
     during the wave; this whole-result entry serves everything else)."""
-    cw = rr.cw
-    p = cw.n_pods
-    if p == 0:
-        return None
     cc = rr._compact
-    if cc is not None and cc.packed:
-        return ChunkAttribution(rr).finish()
-    acc = ChunkAttribution(rr)
-    prefilters = cw.config.prefilters()
-    if rr._filter_codes is None and rr._score_raw is None:
-        if not prefilters:
-            return None
-        acc._prefilter()
-        return acc.out
-    # full-array layout (the speculative path): derive the first-fail
-    # index from the per-plugin codes, same stop-at-first-fail rule
-    codes = np.asarray(rr._filter_codes) if rr._filter_codes is not None \
-        else np.zeros((p, 0, cw.n_nodes), np.int32)
-    raw = np.asarray(rr._score_raw) if rr._score_raw is not None \
-        else np.zeros((p, 0, cw.n_nodes), np.int64)
-    if codes.shape[1]:
-        fail = codes != 0                                   # [P, F, N]
-        any_fail = fail.any(axis=1)
-        first = np.argmax(fail, axis=1)                     # [P, N]
-        ffp_full = np.where(any_fail, first + 1, 0).astype(np.int64)
-        # NOT_EVALUATED rows: outside the pod's PreFilterResult
-        ffp_full[codes[:, 0, :] < 0] = codes.shape[1] + 1
-    else:
-        # no filter plugins: argmax over the empty axis would raise —
-        # every node passes, first-fail is uniformly 0
-        ffp_full = np.zeros((p, codes.shape[2]), np.int64)
-    acc._tally(0, p, ffp_full, lambda s: np.asarray(raw[:, s, :], np.int64))
-    if acc.broken:
+    if rr.cw.n_pods == 0 or cc is None or not cc.packed:
         return None
-    acc._prefilter()
-    return acc.out
+    return ChunkAttribution(rr).finish()
 
 
 def _cut_rows(xs, lo, m, pad_to: int):
@@ -965,17 +935,17 @@ def _cut_rows(xs, lo, m, pad_to: int):
     return out
 
 
-# one executable a tree of shapes and pad_to (a bucket, a rung of the
-# rounds' ladder): lo and the count are arguments, so a round that starts
-# anywhere in the pass compiles nothing
+# one executable a tree of shapes and pad_to (a bucket): lo and the count
+# are arguments, so a chunk that starts anywhere in the pass compiles
+# nothing
 _cut_rows_jit = jax.jit(_cut_rows, static_argnums=(3,))
 
 
 def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, Any]:
     """Rows lo:hi of every leaf of `xs` as pad_to rows, the rows past
     hi - lo zeros and flagged in `is_pad` (they never bind): ONE jitted
-    dispatch, for the routes that hold xs as leaves (a mesh, the
-    speculative rounds, a pass of many chunks)."""
+    dispatch, for the routes that hold xs as leaves (a mesh, a pass of
+    many chunks)."""
     TRACER.count("pass_device_dispatches_total")
     return _cut_rows_jit(xs, np.int32(lo), np.int32(hi - lo), pad_to)
 
@@ -1006,7 +976,7 @@ def _slice_xs(xs: dict[str, Any], lo: int, hi: int, pad_to: int) -> dict[str, An
 #                     workload's trees as device arrays, the chunk cut and
 #                     padded by the caller (_slice_xs), the carry donated.
 #                     For a mesh (the leaves are sharded one by one) and
-#                     for the speculative rounds' fallback
+#                     a pass of many chunks
 #   _packed_scan_for  (the pass's packed buffers, the leaves that are
 #                     device arrays already) -> (the four heavy tensors,
 #                     the decision row): the sequential scan of a pass of
@@ -1646,8 +1616,8 @@ def _att_plan(cw: CompiledWorkload, pack_mode: str,
 
 class _DeviceAttribution:
     """Per-replay-run context for the on-device attribution reduction
-    where the scan holds its workload as leaves (a mesh, the speculative
-    rounds; the packed scan runs the reduction inside its own executable,
+    where the scan holds its workload as leaves (a mesh, a pass of many
+    chunks; the packed scan runs the reduction inside its own executable,
     the masks riding in the pass's bool buffer): pads the per-pod
     PreFilter/score skip masks to the chunk grid, puts them on device
     ONCE, and runs the cached jit'd per-chunk sums whose outputs ride the
@@ -1842,19 +1812,13 @@ def _packed_dispatch(cw: CompiledWorkload, unroll: int, wide,
     return dispatch, None
 
 
-def pass_chunk(cw: CompiledWorkload, chunk: int) -> int:
-    """The rows one device call of this pass takes: the pass's bucket
-    (pod_axis_bucket), the caller's chunk where the pass is longer.  The
-    sequential scan, the speculative rounds and their fuse family all ask
-    here, so that none computes a pod axis of its own."""
-    return min(chunk, pod_axis_bucket(cw.n_pods, chunk))
-
-
 def _replay_run(cw: CompiledWorkload, chunk: int, unroll: int,
                 mesh, wide: str | None, on_chunk=None,
                 device_resident: bool = False) -> ReplayResult | None:
     p = cw.n_pods
-    chunk = pass_chunk(cw, chunk)
+    # the rows one device call of this pass takes: the pass's bucket, the
+    # caller's chunk where the pass is longer
+    chunk = min(chunk, pod_axis_bucket(p, chunk))
     # which route depends on what the replay is handed, nothing else:
     # compile_workload's upload as it was sent, and a pass of one chunk
     # (every served pass) -> the packed scan.  Leaves (parallel/mesh.py

@@ -310,11 +310,9 @@ def make_slot_pinned_workload(
     and every pod carries a REQUIRED nodeAffinity pin to one slot —
     the Tesserae-style placement shape where each job owns a reserved
     node group (PAPERS.md).  Feasibility is SPARSE (slot_size nodes per
-    pod) and pods of different slots never interact, which makes this
-    the low-contention scenario for the speculative wave
-    (tests/test_speculative_engine.py): the conflict oracle accepts
-    near-whole batches, so the wave runs in ~ceil(P/B) device steps.  Scoring stays real:
-    slot_size > 1 keeps feasible_count above the single-node early-out.
+    pod) and pods of different slots never interact
+    (tests/test_scan_parity_matrix.py).  Scoring stays real: slot_size
+    > 1 keeps feasible_count above the single-node early-out.
     -> (nodes, pods)."""
     nodes = make_nodes(n_nodes, seed=seed)
     n_slots = max(n_nodes // max(slot_size, 1), 1)

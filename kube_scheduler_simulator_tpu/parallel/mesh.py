@@ -12,10 +12,11 @@ Axes:
             parallel, and the only cross-shard traffic XLA must insert is
             the argmax/max/min reductions of host selection and score
             normalization (all-reduce over ICI).
-  "dp"    — speculative pod-batch axis.  Scheduling is sequential across
-            pods (each bind mutates state), but scoring a *batch* of queued
-            pods against the same frozen state is pure fan-out; vmap over
-            the batch, shard it over "dp".
+  "dp"    — pod-batch axis.  Scheduling is sequential across pods (each
+            bind mutates state), but scoring a *batch* of queued pods
+            against the same frozen state is pure fan-out; batched_step
+            vmaps over the batch and shards it over "dp".  The scan
+            replicates over it.
 
 Domain-count carries (counts[C, D], interpod [T, D]) are small and stay
 replicated; their scatter updates are cheap everywhere.
@@ -140,10 +141,11 @@ def sharded_step(cw: CompiledWorkload, mesh: Mesh | None = None):
     return jax.jit(step)
 
 
-def speculative_scores(cw: CompiledWorkload, mesh: Mesh | None = None):
-    """Batched speculative evaluation: score a pod minibatch against one
-    frozen state.  Returns f(carry, xs_batch) -> StepOut batch; used for
-    lookahead/what-if APIs and the dp shard of the dryrun.
+def batched_step(cw: CompiledWorkload, mesh: Mesh | None = None):
+    """Batched what-if evaluation: score a pod minibatch against one
+    frozen state, binding nothing.  Returns f(carry, xs_batch) -> StepOut
+    batch; used by the dp shard of the multi-chip dry run
+    (__graft_entry__.py).
 
     With a mesh, the minibatch axis is explicitly placed over "dp" (and
     inner node axes over "nodes") before the call, so each dp slice of the

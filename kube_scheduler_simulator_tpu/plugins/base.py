@@ -52,11 +52,9 @@ def prefilter_rows(names_by_pod: list, table) -> np.ndarray:
     no pod is narrowed (the step then has no considered-nodes work at
     all), else the power of two >= the longest row: a queue that names
     one node a pod is one shape whatever the names are.  (Where K
-    happens to equal the node count, the shape rules that find a leaf's
-    node axis by its extent — parallel/mesh.py, the speculative rounds'
-    candidate gather — take this one for node-sized: the mesh then
-    shards it, which changes no value, and the gather's copy is read by
-    nothing, the score phase having no use for it.)"""
+    happens to equal the node count, the shape rule that finds a leaf's
+    node axis by its extent — parallel/mesh.py — takes this one for
+    node-sized: the mesh then shards it, which changes no value.)"""
     if all(names is None for names in names_by_pod):
         return np.zeros((len(names_by_pod), 0), dtype=np.int32)
     name_idx = table.name_idx

@@ -176,10 +176,8 @@ class PluginSetConfig:
         """Everything of the profile that decides what a pass computes,
         as one hashable value: two configs with equal signatures run the
         same plugin lineup with the same weights and args.  The scan
-        cache keys its executables on it, and the session's record of
-        collapsed speculative rounds (control/__init__.py) is kept for
-        the profile that made it: the same profile posted again is the
-        same signature, a differing one is not."""
+        cache keys its executables on it: the same profile posted again
+        is the same signature, a differing one is not."""
         import json
 
         return (

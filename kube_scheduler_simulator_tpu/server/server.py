@@ -39,8 +39,7 @@ Observability surface (docs/metrics.md):
 Trace correlation: every workload-submitting request is stamped with a
 trace id (inbound X-KSS-Trace-Id honored, minted otherwise, echoed
 back on the response) that the next scheduling wave claims — one id
-ties the HTTP request to its wave, speculative rounds and fused
-dispatches across every surface above.
+ties the HTTP request to its wave across every surface above.
   GET  /api/v1/debug/dump       -> wave black-box post-mortem bundle
                                    (?session=; utils/blackbox.py)
   POST /api/v1/profile          -> XLA profile start/stop (409 on bad state)

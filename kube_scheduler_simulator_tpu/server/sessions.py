@@ -58,27 +58,6 @@ DEFAULT_SESSION = "default"
 _SESSION_ID_RE = re.compile(r"^[a-zA-Z0-9][a-zA-Z0-9._-]{0,63}$")
 
 
-def speculative_commit_rates(tracer) -> dict[str, dict]:
-    """Per-session speculative commit rate from the flight recorder's
-    session-labeled counters: {session ("" = sessionless direct engine
-    use): {accepted, rolledBack, acceptRate}}.  Sessions that never ran
-    a speculative round are absent.  Shared by /api/v1/sessions stats
-    and `bench --serve` — the measured baseline for cross-session wave
-    batching (ROADMAP item 1 stretch)."""
-    accepted = tracer.labeled_totals("speculative_accepted_total", "session")
-    rolled = tracer.labeled_totals("speculative_rolled_back_total", "session")
-    out: dict[str, dict] = {}
-    for sid in sorted(set(accepted) | set(rolled)):
-        a = accepted.get(sid, 0)
-        r = rolled.get(sid, 0)
-        out[sid] = {
-            "accepted": int(a),
-            "rolledBack": int(r),
-            "acceptRate": round(a / (a + r), 4) if a + r else None,
-        }
-    return out
-
-
 class SessionError(ApiError):
     status = 400
     reason = "BadRequest"
@@ -294,7 +273,6 @@ class SessionManager:
         """Process-shell view: admission knobs + the shared pieces."""
         from ..control.autopilot import autopilot_enabled
         from ..framework.replay import _DEVICE_BUDGET, scan_cache_stats
-        from ..parallel.fuse import FUSE
         from ..utils.tracing import TRACER
 
         retained = {
@@ -315,13 +293,6 @@ class SessionManager:
             "deviceResultBudgetMb": (None if limit is None
                                      else limit // (1 << 20)),
             "deviceChunksRetained": retained,
-            # per-session speculative commit rate (docs/metrics.md):
-            # accepted / (accepted + rolled back) since process start —
-            # the admission signal cross-session fused dispatch reads
-            "speculative": speculative_commit_rates(TRACER),
-            # cross-session fused dispatch (parallel/fuse.py): knob
-            # state + lifetime outcome tallies (docs/api.md)
-            "fuse": FUSE.stats(),
             # closed-loop control plane (docs/autopilot.md): controller
             # tick/decision tallies when the server runs one, else just
             # the (normally empty) override registry

@@ -40,8 +40,8 @@ scan of a pass of one chunk takes the five buffers and cuts its leaves
 out of them inside its own executable (framework/replay.py
 _packed_scan_for).  cw.xs, cw.init_carry and cw.statics still read as
 trees of device arrays for whoever needs leaves (a mesh shards each, the
-speculative rounds gather batches, the host-interleaved path indexes
-pods, a pass of many chunks slices each chunk): unpacked on first
+host-interleaved path indexes pods, a pass of many chunks slices each
+chunk): unpacked on first
 access, by one jitted dispatch, memoised on the workload.  The closure
 statics of a changed node table are unpacked at once (upload_tree): the
 jitted step closes over those arrays.  Nothing here reads a device array
@@ -130,8 +130,8 @@ def pod_axis_bucket(p: int, chunk: int = POD_CHUNK) -> int:
     to the chunk (1, 2, 4, ..., 512 by default), whole chunks beyond.
     The ONE rule for the pod axis: compile_workload lays the pass's xs
     and skip masks out on it, and the scan key, the packed layout, the
-    resident patch's key, the scan's chunk and the speculative ladder's
-    top rung follow from those shapes, so a pass of a count the process
+    resident patch's key and the scan's chunk follow from those shapes,
+    so a pass of a count the process
     has not seen is a compile only where its bucket is new.  The rows
     past `p` are pad rows (xs["is_pad"]): they never bind and nothing
     past the scan reads them.  A pass of one pod or of two pads none.

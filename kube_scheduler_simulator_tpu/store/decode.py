@@ -482,7 +482,7 @@ def _decode_chunk_into(rr, lo: int, hi: int, out: list, base: int) -> None:
             # native path reads the compact arrays directly — warming recon
             # for it would re-create exactly the [C,F,N]/[C,S,N]
             # materialization it avoids.  (full-array results — the
-            # speculative path — need no recon)
+            # host loop's — need no recon)
             rr._chunk_recon(lo // cc.chunk, scores=True)
         for i, a in zip(range(lo, hi),
                         _decode_pool().map(lambda i: decode_pod_result(rr, i),

@@ -47,10 +47,6 @@ import time
 
 from ..utils.tracing import TRACER
 
-# chunk granularity when the ReplayResult holds full arrays (the
-# speculative path) instead of compact chunks
-_FALLBACK_CHUNK = 512
-
 
 class LazyWave:
     """One committed wave's deferred annotations.
@@ -65,8 +61,7 @@ class LazyWave:
     def __init__(self, rr, n_pods: int | None = None, sealed: bool = False):
         self.rr = rr
         self.n = rr.cw.n_pods if n_pods is None else n_pods
-        cc = getattr(rr, "_compact", None)
-        self.chunk = cc.chunk if cc is not None else _FALLBACK_CHUNK
+        self.chunk = rr._compact.chunk
         self._mu = threading.Lock()
         self._chunks: dict[int, list] = {}
         self._inflight: dict[int, threading.Event] = {}

@@ -1,15 +1,13 @@
 """Wave black box: crash-consistent post-mortem capture + device telemetry.
 
 The engine's own behavior is its least observable part exactly when it
-matters most: the degradation ladder (PR 12) and the speculative round
-loop (PR 13) make load-bearing decisions — retry, degrade, fall back to
-the sequential scan — whose evidence evaporates the moment they fire,
-and nothing ever read device memory even though the HBM budget actively
-spills chunks.  This module is the always-on flight-data recorder:
+matters most: the degradation ladder (PR 12) makes load-bearing
+decisions — retry, degrade — whose evidence evaporates the moment they
+fire, and nothing ever read device memory even though the HBM budget
+actively spills chunks.  This module is the always-on flight-data recorder:
 
   * `BlackBox` — a fixed-size, lock-light ring of structured engine
-    events (wave start/end, speculative rounds with batch size / accept
-    fraction / ladder rung, fault trips with seam + classification,
+    events (wave start/end, fault trips with seam + classification,
     degradation transitions, retry suffixes, budget spills, compile
     builds/quarantines, session admission/eviction).  Recording is one
     short lock hold and a dict append; `KSS_TPU_BLACKBOX=0` turns it
@@ -44,8 +42,7 @@ spills chunks.  This module is the always-on flight-data recorder:
 
 Import discipline: this module depends only on utils.tracing,
 utils.history and utils.env — everything above it (engine, replay,
-speculative, faults, sessions) records INTO it, never the other way
-around.
+faults, sessions) records INTO it, never the other way around.
 """
 
 from __future__ import annotations
@@ -703,14 +700,12 @@ _REQUIRED_KEYS = ("version", "reason", "time", "events", "open_spans",
                   "counter_deltas", "env", "device")
 
 
-def validate_dump(doc: dict, require_fault: bool = False,
-                  require_rounds: bool = False) -> dict:
+def validate_dump(doc: dict, require_fault: bool = False) -> dict:
     """Schema check for a post-mortem bundle — shared by the tests,
     `make blackbox-smoke` and the chaos harness.  Raises ValueError
     with the first violation; returns {kinds: {kind: count}} on
     success.  `require_fault` additionally asserts a fault trip with
-    seam + classification and a cause; `require_rounds` asserts the
-    speculative round history survived into the dump."""
+    seam + classification and a cause."""
     for k in _REQUIRED_KEYS:
         if k not in doc:
             raise ValueError(f"dump missing key {k!r}")
@@ -727,11 +722,6 @@ def validate_dump(doc: dict, require_fault: bool = False,
             for field in ("seam", "classification", "error"):
                 if field not in ev:
                     raise ValueError(f"fault.trip missing {field!r}: {ev!r}")
-        if ev["kind"] == "speculative.round":
-            for field in ("batch", "accepted", "rung", "accept_fraction"):
-                if field not in ev:
-                    raise ValueError(
-                        f"speculative.round missing {field!r}: {ev!r}")
         if ev["kind"] == "autopilot.decide":
             # every autopilot decision is structured evidence
             # (control/autopilot.py): which effector moved which
@@ -787,8 +777,6 @@ def validate_dump(doc: dict, require_fault: bool = False,
                              "(wave.retry / wave.abort / degrade)")
         if not doc["counter_deltas"]:
             raise ValueError("dump has empty counter deltas for the wave")
-    if require_rounds and not kinds.get("speculative.round"):
-        raise ValueError("dump has no speculative.round history")
     return {"kinds": kinds}
 
 
@@ -888,7 +876,7 @@ class HistoryFeeder:
     sample (utils/history.py).
 
     gather() reads every plane ONCE into plain dicts — SLO windows,
-    per-session speculative/spill counter totals, the control-plane
+    per-session spill counter totals, the control-plane
     override state — and sample() derives the ring columns from them.
     The autopilot plans FROM the same returned dicts, so a decision's
     evidence cites a ring index whose values match what the effector
@@ -902,8 +890,8 @@ class HistoryFeeder:
     """
 
     # plain (unlabeled) counters whose per-sample deltas become global
-    # columns; the labeled speculative/spill families are summed from
-    # the per-session planes instead
+    # columns; the labeled spill family is summed from the per-session
+    # plane instead
     _PLAIN = ("pods_scheduled_total", "pods_unschedulable_total",
               "scheduling_waves_total")
 
@@ -916,11 +904,6 @@ class HistoryFeeder:
 
         return {
             "slo": SLO.snapshot(),
-            "accepted": TRACER.labeled_totals(
-                "speculative_accepted_total", "session"),
-            "rolled": TRACER.labeled_totals(
-                "speculative_rolled_back_total", "session"),
-            "rounds": TRACER.session_totals("speculative_rounds_total"),
             "spilled": TRACER.labeled_totals(
                 "device_chunks_spilled_total", "session"),
             "controls": CONTROLS.stats(),
@@ -934,35 +917,15 @@ class HistoryFeeder:
             return -1, planes
         totals = TRACER.counter_totals()
         values: dict[str, float] = {}
-        sums = {
-            "speculative_accepted_total":
-                sum(planes["accepted"].values()),
-            "speculative_rolled_back_total":
-                sum(planes["rolled"].values()),
-            "device_chunks_spilled_total":
-                sum(planes["spilled"].values()),
-        }
+        current = {name: float(totals.get(name, 0.0)) for name in self._PLAIN}
+        current["device_chunks_spilled_total"] = sum(
+            planes["spilled"].values())
         with self._mu:
-            for name in self._PLAIN:
-                cur = float(totals.get(name, 0.0))
+            for name, cur in current.items():
                 values[name] = cur - self._base.get(name, 0.0)
                 self._base[name] = cur
-            for name, cur in sums.items():
-                values[name] = cur - self._base.get(name, 0.0)
-                self._base[name] = cur
-            # per-session accept fraction / spill delta this sample
-            # (baselines keyed per session; a torn-down session's keys
-            # are pruned when its counters vanish from the planes)
-            for sid in set(planes["accepted"]) | set(planes["rolled"]):
-                a = planes["accepted"].get(sid, 0.0)
-                r = planes["rolled"].get(sid, 0.0)
-                a_d = a - self._base.get(f"a\x00{sid}", 0.0)
-                r_d = r - self._base.get(f"r\x00{sid}", 0.0)
-                self._base[f"a\x00{sid}"] = a
-                self._base[f"r\x00{sid}"] = r
-                if a_d + r_d > 0:
-                    values[f"spec.accept{{session={sid}}}"] = round(
-                        a_d / (a_d + r_d), 6)
+            # per-session spill delta this sample (baselines keyed per
+            # session)
             for sid, sp in planes["spilled"].items():
                 sp_d = sp - self._base.get(f"s\x00{sid}", 0.0)
                 self._base[f"s\x00{sid}"] = sp

@@ -62,12 +62,6 @@ from .tracing import TRACER
 SEAMS = (
     "replay.scan_dispatch",    # per-chunk device dispatch (framework/replay.py)
     "replay.decision_fetch",   # per-chunk D2H fetch (decisions or full outputs)
-    "speculative.round",       # per-round top of the speculative stream
-                               # (parallel/speculative.py)
-    "fuse.dispatch",           # cross-session fused dispatch, fired on the
-                               # REQUESTING thread before it joins a batch
-                               # so a trip faults one session only
-                               # (parallel/fuse.py)
     "replay.materialize",      # on-demand D2H of a device-resident chunk
     "replay.budget_spill",     # background HBM-budget spill of a chunk
     "decode.chunk",            # native/python chunk decode (store/decode.py)

@@ -83,16 +83,9 @@ BUCKETS: dict[str, tuple[float, ...]] = {
     "scheduling_attempt_duration_seconds": _exp_buckets(0.001, 2, 15),
     "framework_extension_point_duration_seconds": _exp_buckets(0.0001, 2, 12),
     "plugin_execution_duration_seconds": _exp_buckets(1e-5, 1.5, 20),
-    # accept FRACTION per speculative round — a ratio in (0, 1], not a
-    # duration: linear decile buckets (docs/metrics.md)
-    "speculative_accept_fraction": tuple(i / 10 for i in range(1, 11)),
     # XLA scan compiles run ~0.1s (warm shapes) to tens of seconds (cold
     # giant meshes): a wider exponential ladder than the attempt buckets
     "scan_compile_seconds": _exp_buckets(0.01, 2, 14),
-    # sessions sharing one fused device dispatch — a small integer
-    # (1 = ran solo), not a duration (parallel/fuse.py, docs/metrics.md)
-    "fused_sessions_per_dispatch": (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0,
-                                    16.0),
 }
 _DEFAULT_BUCKETS = _exp_buckets(0.001, 2, 15)
 
@@ -170,33 +163,6 @@ _HELP: dict[str, str] = {
     "scheduling_loop_crashes_total":
         "Scheduling-loop waves that raised (the loop stays alive; the "
         "last crash is surfaced on /readyz).",
-    "speculative_accepted_total":
-        "Pods accepted by the speculative conflict oracle (committed as "
-        "part of a round's non-interfering prefix).",
-    "speculative_rolled_back_total":
-        "Pod evaluations rolled into the next round (rejected by the "
-        "dirty-node / interaction / gang-boundary cut; a pod may roll "
-        "more than once before it commits).",
-    "speculative_accept_fraction":
-        "Accepted fraction of each speculative round's batch "
-        "(accepted / round size; 1.0 = the whole batch committed).",
-    "speculative_fallbacks_total":
-        "Hand-overs of a speculative wave to the sequential scan after "
-        "an accept-rate collapse: the remainder handed to the chunked "
-        "scan in-stream after a sustained collapse at the bottom batch "
-        "rung, or a one-chunk pass whose first round kept a quarter or "
-        "less and that started again as the packed scan "
-        "(docs/wave-pipeline.md).",
-    "speculative_declined_passes_total":
-        "Batch passes on a batchable profile that the wave plan sent to "
-        "the sequential scan from the start, no round run, because the "
-        "session's last tried speculative rounds had collapsed "
-        "(framework/engine.py _wave_plan; docs/wave-pipeline.md row 9).",
-    "speculative_retries_total":
-        "Times a declined pass's median feasible share had fallen to "
-        "half the collapsed round's or less and the session asked for "
-        "its speculative rounds again: the next batch pass on a pod-axis "
-        "bucket they have run on tries them.",
     "tracer_events_dropped_total":
         "Span events evicted from the tracer's fixed-size ring because "
         "it was full — a long soak whose trace tail silently scrolled "
@@ -252,15 +218,6 @@ _HELP: dict[str, str] = {
         "(scheduling_waves_total counts empty wake-ups too).",
     "scheduling_pass_pods_total":
         "Pods taken by the waves scheduling_work_passes_total counts.",
-    "speculative_wide_rounds_total":
-        "Sparse-eligible speculative rounds that ran the dense evaluation "
-        "because some pod's feasible set was past the candidate cap "
-        "(KSS_TPU_SPECULATIVE_CANDIDATES), their sparse probe dropped or, "
-        "after such a round, not made (parallel/speculative.py: a round "
-        "probes only where the session's last round was inside the cap).  "
-        "A session whose rounds mostly do keeps its "
-        "cap when the autopilot changes its speculative profile "
-        "(control/autopilot.py): only the start rung moves.",
     "pass_pad_rows_total":
         "Pad rows of the passes scheduling_work_passes_total counts: the "
         "rows of a pass's pod axis (state/compile.py pod_axis_bucket: the "
@@ -326,8 +283,7 @@ _HELP: dict[str, str] = {
         "Scans by how they held the workload (route=packed|leaves): "
         "packed, compile_workload's upload taken whole by the executable "
         "of a one-chunk sequential scan; leaves, a tree of device arrays "
-        "(a mesh, the speculative rounds, a pass of many chunks, a "
-        "hand-built workload).",
+        "(a mesh, a pass of many chunks, a hand-built workload).",
     "volume_manifests_parsed_total":
         "PersistentVolume, PersistentVolumeClaim and CSINode manifests the "
         "volume carry parsed (state/volumecarry.py; kind=pv|pvc|csinode): "
@@ -641,10 +597,9 @@ class Tracer:
     def trace_scope(self, trace_id: str | None):
         """Correlate everything recorded on this thread under one trace
         id: spans and black-box events gain a trace_id attr, so one id
-        ties an HTTP request to the wave, speculative rounds, and fused
-        dispatches it caused.  Propagates exactly like session_scope;
-        None is a no-op scope (an enclosing scope, if any, stays
-        active)."""
+        ties an HTTP request to the wave it caused.  Propagates exactly
+        like session_scope; None is a no-op scope (an enclosing scope, if
+        any, stays active)."""
         if trace_id is None:
             yield
             return
@@ -949,8 +904,8 @@ class Tracer:
     def labeled_totals(self, name: str, label: str) -> dict[str, float]:
         """Sum one labeled counter's series grouped by `label`'s value
         (series without the label fold under "").  Powers the
-        per-session speculative accept-rate surface on /api/v1/sessions
-        and `bench --serve` without a full snapshot()."""
+        per-session spill plane of the history feeder and the
+        autopilot's decision counts without a full snapshot()."""
         out: dict[str, float] = {}
         with self._lock:
             series = self._lcounters.get(name, {})
@@ -958,13 +913,6 @@ class Tracer:
                 val = dict(key).get(label, "")
                 out[val] = out.get(val, 0) + v
         return out
-
-    def session_totals(self, name: str) -> dict[str, float]:
-        """One plain counter (count()) grouped by the session scope it was
-        counted under; what was counted outside any is in no group."""
-        with self._lock:
-            return {sid: c[name] for sid, c in self._scounters.items()
-                    if name in c}
 
     # --------------------------------------------------------- histograms
 
@@ -1198,7 +1146,7 @@ class Tracer:
         commit worker's commit_stream spans visibly overlap the
         replay_and_decode_stream parent on another track —
         docs/metrics.md walkthrough).  Black-box events (wave faults,
-        autopilot decisions, speculative rounds) ride along as instant
+        autopilot decisions) ride along as instant
         ("ph": "i") events on the same timeline, so a chrome://tracing
         load shows WHAT happened inline with WHERE the wave was."""
         with self._lock:
@@ -1220,17 +1168,10 @@ class Tracer:
         if trace_id is not None:
             # ?trace_id= filtering: the causal slice of ONE request —
             # spans and instants stamped with that id, across sessions
-            # (a fused dispatch lists every participant's trace id)
             tid_s = str(trace_id)
-
-            def _matches(ev: dict) -> bool:
-                if ev.get("trace_id") == tid_s:
-                    return True
-                traces = ev.get("traces")
-                return isinstance(traces, (list, tuple)) and tid_s in traces
-
-            evs = [ev for ev in evs if _matches(ev)]
-            instants = [ev for ev in instants if _matches(ev)]
+            evs = [ev for ev in evs if ev.get("trace_id") == tid_s]
+            instants = [ev for ev in instants
+                        if ev.get("trace_id") == tid_s]
         if limit is not None:
             evs = evs[-limit:] if limit > 0 else []  # evs[-0:] is ALL
             instants = instants[-limit:] if limit > 0 else []

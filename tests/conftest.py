@@ -49,9 +49,10 @@ def pytest_configure(config):
 
 @pytest.fixture(autouse=True)
 def _fresh_session_controls():
-    """The per-session control registry is the process's: engines made
-    without a session share the entry of session None, and one test's
-    speculative rounds (narrow, collapsed) must not plan the next's."""
+    """The per-session control registry is the process's: servers of
+    successive tests all serve a session named "default", and a shed the
+    autopilot opened on one test's slow first pass must not answer the
+    next test's requests with 429."""
     from kube_scheduler_simulator_tpu.control import CONTROLS
 
     CONTROLS.reset()

@@ -17,7 +17,6 @@ import pytest
 from kube_scheduler_simulator_tpu.framework.replay import (
     _workload_scan_key, replay)
 from kube_scheduler_simulator_tpu.models.workloads import make_nodes, make_pods
-from kube_scheduler_simulator_tpu.parallel.speculative import replay_speculative
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.reference_impl.sequential import (
     SequentialScheduler)
@@ -106,16 +105,10 @@ def _run_chunked(cw):
     return replay(cw, chunk=2, device_resident=True)
 
 
-def _run_speculative(cw):
-    return replay_speculative(cw, None)[0]
-
-
 ROUTES = [
     ("packed_one_chunk", _run_packed, (ONE_A, ONE_B, ONE_C), "packed"),
     ("leaves", _run_leaves, (ONE_A, ONE_B, ONE_C), "leaves"),
     ("sequential_chunks", _run_chunked, (THREE_A, THREE_B), "leaves"),
-    ("speculative", _run_speculative, (ONE_A, ONE_B, ONE_C), "leaves"),
-    ("speculative_batch", _run_speculative, (THREE_A, THREE_B), "leaves"),
 ]
 
 
@@ -246,23 +239,6 @@ def test_a_spec_seen_before_on_this_table_walks_no_node():
     moved[0]["metadata"]["labels"]["disktype"] = "nvme"
     compile_workload(moved, [_pod("s", ["ssd"], [(9, "type-1")])], CFG)
     assert _counter("affinity_rows_built_total") == 5
-
-
-# ------------------------------------- a mixed queue against the reference
-
-@pytest.mark.parametrize("route", ["scan", "speculative"])
-def test_forty_mixed_pods_equal_the_sequential_reference(route):
-    nodes = make_nodes(24, seed=9, taint_fraction=0.2)
-    pods = make_pods(40, seed=10, with_affinity=True, with_tolerations=True)
-    cw = compile_workload(nodes, pods, CFG)
-    rr = (replay(cw, chunk=16) if route == "scan"
-          else replay_speculative(cw, None, batch=8)[0])
-    want = SequentialScheduler(nodes, pods, CFG).schedule_all()
-    assert any("affinity" in p["spec"] for p in pods)
-    for i, (anns, sel) in enumerate(want):
-        got = decode_pod_result(rr, i)
-        for key, value in anns.items():
-            assert got[key] == value, f"pod {i} {key}"
 
 
 # ------------------------------------------------------------- the records

@@ -2,8 +2,8 @@
 
 Covers the closed loop end to end: the fail-safe env switch, qos
 admission, each effector driven with synthetic telemetry through
-direct tick() calls (speculative hysteresis without thrash, HBM weight
-raise/decay/donate, shed with the 0.8x recovery band), the weighted
+direct tick() calls (HBM weight raise/decay/donate, shed with the 0.8x
+recovery band), the weighted
 budget-share enforcement spilling only the fat session's own chunks,
 the HTTP 429 + Retry-After contract through a real server, byte-parity
 of a scheduling run with the controls registry empty vs populated for
@@ -25,8 +25,7 @@ import pytest
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration
 from kube_scheduler_simulator_tpu.control import CONTROLS, QOS_TIERS
 from kube_scheduler_simulator_tpu.control.autopilot import (
-    HYSTERESIS_TICKS, _SPEC_MID_TICKS, Autopilot, autopilot_enabled,
-    shed_qos_tiers)
+    HYSTERESIS_TICKS, Autopilot, autopilot_enabled, shed_qos_tiers)
 from kube_scheduler_simulator_tpu.framework.replay import _DeviceResultBudget
 from kube_scheduler_simulator_tpu.models.workloads import (
     make_churn_workload, make_nodes, make_pods)
@@ -101,178 +100,6 @@ def test_session_qos_validated_on_create():
         briefs = {sid: qos for sid, qos, _t, _b in mgr.sessions_brief()}
         assert briefs["q-crit"] == "critical"
         assert all(q in QOS_TIERS for q in briefs.values())
-    finally:
-        mgr.shutdown()
-
-
-# ------------------------------------------- effector: speculative tuning
-
-
-def test_speculative_effector_hysteresis_no_thrash():
-    mgr = _mgr(max_sessions=4)
-    ap = Autopilot(mgr, interval=3600, slo_target=0)  # shed effector off
-    try:
-        mgr.create("ap-spec")
-
-        def rounds(accepted: int, rolled: int) -> None:
-            if accepted:
-                TRACER.inc("speculative_accepted_total", accepted,
-                           session="ap-spec")
-            if rolled:
-                TRACER.inc("speculative_rolled_back_total", rolled,
-                           session="ap-spec")
-
-        ap.tick()   # baseline tick: no evidence, no decision
-        assert CONTROLS.spec_overrides("ap-spec") == (None, None)
-        rounds(90, 10)
-        ap.tick()   # streak 1 of HYSTERESIS_TICKS: still default
-        assert CONTROLS.spec_overrides("ap-spec") == (None, None)
-        rounds(95, 5)
-        ap.tick()
-        # sustained high accept fraction: top rung, doubled candidates
-        assert CONTROLS.spec_overrides("ap-spec") == (-1, 256)
-
-        # alternating good/bad waves never build a streak: no thrash
-        for _ in range(HYSTERESIS_TICKS * 2):
-            rounds(10, 90)
-            ap.tick()
-            rounds(90, 10)
-            ap.tick()
-        assert CONTROLS.spec_overrides("ap-spec") == (-1, 256)
-
-        rounds(10, 90)
-        ap.tick()
-        rounds(5, 95)
-        ap.tick()
-        # sustained collapse: bottom rung, halved candidates
-        assert CONTROLS.spec_overrides("ap-spec") == (0, 64)
-        assert ap.stats()["decisions"] == 2
-    finally:
-        mgr.shutdown()
-
-
-def test_a_round_s_first_pod_is_no_evidence():
-    """One pod a pass: every round is one pod, accepted whatever the
-    contention.  That reads 1.00 for ever and is evidence of nothing: the
-    profile stays (going aggressive compiled a wider sparse round in the
-    middle of a served session).  Pods beyond a round's first count."""
-    mgr = _mgr(max_sessions=4)
-    ap = Autopilot(mgr, interval=3600, slo_target=0)  # shed effector off
-    try:
-        mgr.create("ap-one")
-
-        def rounds(n: int, accepted: int) -> None:
-            with TRACER.session_scope("ap-one"):
-                TRACER.count("speculative_rounds_total", n)
-                TRACER.inc("speculative_accepted_total", accepted)
-
-        ap.tick()
-        for _ in range(HYSTERESIS_TICKS * 3):
-            rounds(25, 25)          # 25 passes of one pod each
-            ap.tick()
-        assert CONTROLS.spec_overrides("ap-one") == (None, None)
-        assert ap.stats()["decisions"] == 0
-        for _ in range(HYSTERESIS_TICKS):
-            rounds(4, 100)          # 4 rounds, 96 pods beyond their first
-            ap.tick()
-        assert CONTROLS.spec_overrides("ap-one") == (-1, 256)
-    finally:
-        mgr.shutdown()
-
-
-def test_speculative_profile_decays_to_default_on_mid_band():
-    """A profile is not forever: a sustained mid-band accept fraction
-    (no hi/lo evidence either way) decays the session back to the
-    static default, mirroring the budget effector's calm-tick decay."""
-    mgr = _mgr(max_sessions=4)
-    ap = Autopilot(mgr, interval=3600, slo_target=0)
-    try:
-        mgr.create("ap-mid")
-
-        def rounds(accepted: int, rolled: int) -> None:
-            TRACER.inc("speculative_accepted_total", accepted,
-                       session="ap-mid")
-            TRACER.inc("speculative_rolled_back_total", rolled,
-                       session="ap-mid")
-
-        ap.tick()   # baseline
-        for _ in range(HYSTERESIS_TICKS):
-            rounds(95, 5)
-            ap.tick()
-        assert CONTROLS.spec_overrides("ap-mid") == (-1, 256)
-        # mid-band rounds: no transition until the decay streak fills
-        for _ in range(_SPEC_MID_TICKS - 1):
-            rounds(70, 30)
-            ap.tick()
-            assert CONTROLS.spec_overrides("ap-mid") == (-1, 256)
-        rounds(70, 30)
-        ap.tick()
-        assert CONTROLS.spec_overrides("ap-mid") == (None, None)
-    finally:
-        mgr.shutdown()
-
-
-def test_speculative_effector_keeps_the_cap_of_a_session_that_runs_dense():
-    """A session whose rounds drop their sparse probe for wide
-    feasibility (every pod's feasible set past the candidate cap: an
-    empty mixed cluster) gets the profile's start rung and NOT its cap:
-    the cap has nothing to act on there, and another cap is a compile of
-    the sparse round for every bucket and rung, mid-session."""
-    mgr = _mgr(max_sessions=4)
-    ap = Autopilot(mgr, interval=3600, slo_target=0)
-    try:
-        mgr.create("ap-wide")
-
-        def rounds(n: int, accepted: int, rolled: int, wide: int) -> None:
-            with TRACER.session_scope("ap-wide"):
-                TRACER.count("speculative_rounds_total", n)
-                TRACER.count("speculative_wide_rounds_total", wide)
-            TRACER.inc("speculative_accepted_total", accepted,
-                       session="ap-wide")
-            TRACER.inc("speculative_rolled_back_total", rolled,
-                       session="ap-wide")
-
-        ap.tick()
-        for _ in range(HYSTERESIS_TICKS):
-            rounds(7, 10, 40, 7)
-            ap.tick()
-        # sustained collapse: bottom rung; the cap stays the operator's
-        assert CONTROLS.spec_overrides("ap-wide") == (0, None)
-        for _ in range(HYSTERESIS_TICKS):
-            rounds(4, 104, 2, 1)
-            ap.tick()
-        # sparse rounds in use again: the aggressive profile whole
-        assert CONTROLS.spec_overrides("ap-wide") == (-1, 256)
-    finally:
-        ap.stop()
-        mgr.shutdown()
-
-
-def test_speculative_candidates_scale_operator_baseline(monkeypatch):
-    """The profile multipliers scale KSS_TPU_SPECULATIVE_CANDIDATES as
-    the operator set it — aggressive on a 512 baseline means 1024,
-    never a silent cut back to 2x the built-in 128."""
-    monkeypatch.setenv("KSS_TPU_SPECULATIVE_CANDIDATES", "512")
-    mgr = _mgr(max_sessions=4)
-    ap = Autopilot(mgr, interval=3600, slo_target=0)
-    try:
-        mgr.create("ap-env")
-
-        def rounds(accepted: int, rolled: int) -> None:
-            TRACER.inc("speculative_accepted_total", accepted,
-                       session="ap-env")
-            TRACER.inc("speculative_rolled_back_total", rolled,
-                       session="ap-env")
-
-        ap.tick()   # baseline
-        for _ in range(HYSTERESIS_TICKS):
-            rounds(95, 5)
-            ap.tick()
-        assert CONTROLS.spec_overrides("ap-env") == (-1, 1024)
-        for _ in range(HYSTERESIS_TICKS):
-            rounds(5, 95)
-            ap.tick()
-        assert CONTROLS.spec_overrides("ap-env") == (0, 256)
     finally:
         mgr.shutdown()
 
@@ -694,16 +521,15 @@ def test_parity_empty_registry_vs_unrelated_overrides():
                     for p in sess.di.store.list("pods")[0]}
 
         baseline = run("par-a")
-        CONTROLS.set_spec("par-other", -1, 256)
         CONTROLS.set_budget_weight("par-other", 3.0)
         CONTROLS.set_shed("par-other", True, 9)
         contended = run("par-b")
         assert contended == baseline
-        # the aggressive profile applied to the RUNNING session is also
-        # byte-invariant: rung/kcand only repartition the same rounds
-        CONTROLS.set_spec("par-c", -1, 256)
-        aggressive = run("par-c")
-        assert aggressive == baseline
+        # a raised budget weight on the RUNNING session is byte-invariant
+        # too: the weight only decides which chunks spill
+        CONTROLS.set_budget_weight("par-c", 3.0)
+        weighted = run("par-c")
+        assert weighted == baseline
     finally:
         mgr.shutdown()
 

@@ -54,8 +54,13 @@ def test_chip_smoke_cpu_rehearsal(tmp_path):
         assert w["prefix_parity"] == {"pods": 8, "keys": 13,
                                       "mismatches": 0, "ok": True}
         assert w["no_hidden_rung"]["result_mode"] == "device_resident"
-    assert served["wave_a_default_profile"]["speculative_rounds"] == 0
-    assert served["wave_b_config4_profile"]["speculative_rounds"] > 0
+    # the default profile commits after the pass; config 4's has no
+    # PostFilter, so its commit is streamed, and each of its passes (all
+    # of one chunk at this size) is the packed scan's one call
+    assert served["wave_a_default_profile"]["commit_stream_waves"] == 0
+    b = served["wave_b_config4_profile"]
+    assert b["commit_stream_waves"] == len(b["passes"]) > 0
+    assert b["replay_routes"] == {"packed": len(b["passes"]), "leaves": 0}
     assert all(c["ok"] for c in summary["gate"]["configs"].values())
     assert summary["external"]["bound"] == 20
     assert summary["external"]["server_device"]["available"] is False

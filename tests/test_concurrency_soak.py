@@ -153,7 +153,7 @@ def test_soak_external_writes_during_streaming_commit():
     engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
         enabled=["NodeResourcesFit", "NodeResourcesBalancedAllocation",
                  "TaintToleration"]), chunk=8)
-    assert engine._wave_plan(1).commit == "streamed"
+    assert engine._wave_plan().commit == "streamed"
     from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
     waves_before = TRACER.summary()["counters"].get(

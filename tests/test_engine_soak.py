@@ -119,9 +119,9 @@ def test_engine_soak(seed):
 @pytest.mark.parametrize("seed", [3, 11])
 def test_engine_soak_dp_mesh(seed):
     """The soak's config churn / priority mix / deletion rounds, run on a
-    dp>1 mesh: waves route through the speculative path when the active
-    plugin set qualifies and must land in the same invariant-clean state
-    as the scan engine on an identical store."""
+    dp>1 mesh: the scan shards the node axis, replicates over dp, and
+    must land in the same invariant-clean state as the unsharded engine
+    on an identical store."""
     from kube_scheduler_simulator_tpu.parallel.mesh import make_mesh
 
     rng = np.random.default_rng(seed)
@@ -186,7 +186,7 @@ def test_engine_soak_streaming_commit(seed):
         engine = SchedulerEngine(
             store, plugin_config=PluginSetConfig(**cfg_kw), chunk=8,
             pipeline_commit=pipeline)
-        plan = engine._wave_plan(len(pod_rounds[0]))
+        plan = engine._wave_plan()
         assert (plan.commit == "streamed") == pipeline
         for r, pods in enumerate(pod_rounds):
             for p in pods:

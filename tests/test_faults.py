@@ -325,7 +325,7 @@ def test_transient_fault_after_full_commit_keeps_bind_count():
     eng = SchedulerEngine(s, chunk=8, plugin_config=PluginSetConfig(
         enabled=["NodeResourcesFit", "NodeAffinity"]))
     eng._retry_sleep = lambda _d: None
-    assert eng._wave_plan(20).commit == "streamed"
+    assert eng._wave_plan().commit == "streamed"
     real = eng.reflector.reflect_batch
     calls = {"n": 0}
 

@@ -134,7 +134,7 @@ def _pipelined_wave(n_pods=48, n_nodes=6, chunk=16, pipeline=True):
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=chunk,
                              pipeline_commit=pipeline)
-    assert (engine._wave_plan(n_pods).commit == "streamed") is pipeline
+    assert (engine._wave_plan().commit == "streamed") is pipeline
     bound = engine.schedule_pending()
     assert bound > 0
     return TRACER.events(limit=1000)
@@ -317,32 +317,6 @@ def test_plugin_attribution_matches_annotations():
     for name, d in att["prefilter"].items():
         assert 0 <= d["evaluated"] <= cw.n_pods
         assert d["screened"] == 0  # this workload has no prefilter rejects
-
-
-def test_attribution_full_array_layout_without_filters():
-    """The full-array (speculative) layout with ZERO filter plugins must
-    still attribute scores/prefilters — argmax over the empty filter
-    axis used to raise and silently drop the whole wave's attribution."""
-    import types
-
-    import numpy as np
-
-    nodes = make_nodes(4, seed=23)
-    pods = make_pods(6, seed=24)
-    cfg = PluginSetConfig(enabled=["NodeResourcesBalancedAllocation"])
-    cw = compile_workload(nodes, pods, cfg)
-    p, n = cw.n_pods, cw.n_nodes
-    s = len(cfg.scorers())
-    raw = np.arange(p * s * n, dtype=np.int64).reshape(p, s, n)
-    rr = types.SimpleNamespace(
-        cw=cw, _compact=None, _filter_codes=None, _score_raw=raw,
-        prefilter_reject=np.zeros(p, np.int64),
-        feasible_count=np.full(p, n, np.int32))
-    att = plugin_attribution(rr)
-    assert att is not None and not att["filter"]
-    for i, name in enumerate(cfg.scorers()):
-        assert att["score"][name]["sum"] == int(raw[:, i, :].sum())
-        assert att["score"][name]["evaluated"] == p * n
 
 
 def test_attribution_changes_no_annotation_bytes():
@@ -599,7 +573,7 @@ def test_mid_chunk_exception_leaves_tracer_balanced(monkeypatch):
         "NodeAffinity", "TaintToleration", "PodTopologySpread"])
     engine = SchedulerEngine(store, plugin_config=cfg, chunk=16,
                              pipeline_commit=True, residency_floor=2)
-    assert engine._wave_plan(48).commit == "streamed"
+    assert engine._wave_plan().commit == "streamed"
 
     real = engine.result_store.put_decoded
     calls = {"n": 0}

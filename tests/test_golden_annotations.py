@@ -279,7 +279,7 @@ def test_pipelined_commit_parity_with_sequential_postpass():
         q = store.watch("pods")
         engine = SchedulerEngine(store, plugin_config=PluginSetConfig(**cfg_kw),
                                  chunk=16, pipeline_commit=pipeline)
-        assert (engine._wave_plan(len(pods)).commit == "streamed") == pipeline
+        assert (engine._wave_plan().commit == "streamed") == pipeline
         bound = engine.schedule_pending()
         bind_order, seen = [], set()
         while True:

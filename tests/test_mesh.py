@@ -11,7 +11,7 @@ from kube_scheduler_simulator_tpu.framework.pipeline import build_step
 from kube_scheduler_simulator_tpu.framework.replay import replay
 from kube_scheduler_simulator_tpu.models.workloads import make_nodes, make_pods
 from kube_scheduler_simulator_tpu.parallel.mesh import (
-    make_mesh, shard_workload, sharded_step, speculative_scores)
+    batched_step, make_mesh, shard_workload, sharded_step)
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.state.compile import compile_workload
 
@@ -52,7 +52,7 @@ def test_sharded_dp_mesh_matches_unsharded():
     base_sel = [int(s) for s in baseline.selected]
 
     cw = compile_workload(nodes, pods, cfg)
-    mesh = make_mesh(8, dp=2)  # 2-way speculative batch x 4-way node shard
+    mesh = make_mesh(8, dp=2)  # 2-way pod batch x 4-way node shard
     cw = shard_workload(cw, mesh)
     step = sharded_step(cw, mesh)
     assert _scan_selections(cw, step) == base_sel
@@ -130,7 +130,7 @@ def test_make_mesh_rejects_non_divisible_dp():
     assert make_mesh(8, dp=2).shape == {"dp": 2, "nodes": 4}
 
 
-def test_speculative_batch_consistent_with_step():
+def test_batched_step_consistent_with_step():
     nodes, pods, cfg = _workload(n_nodes=8, n_pods=4, seed=82)
     cw = compile_workload(nodes, pods, cfg)
     step = build_step(cw)
@@ -143,7 +143,7 @@ def test_speculative_batch_consistent_with_step():
         _, out = step(cw.init_carry, sl)
         singles.append(int(out.selected))
 
-    batched = speculative_scores(cw)
+    batched = batched_step(cw)
     xs_batch = jax.tree.map(lambda a: a if hasattr(a, "ndim") and a.ndim else a, cw.xs)
     xs_batch["is_pad"] = jnp.zeros((cw.n_pods,), dtype=bool)
     outs = batched(cw.init_carry, xs_batch)

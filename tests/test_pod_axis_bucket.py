@@ -1,7 +1,8 @@
 """The pod axis of a pass is a bucket (state/compile.py pod_axis_bucket):
 the next power of two up to the chunk, whole chunks beyond.  Held here,
-over the three routes a pass can take (the packed sequential scan, the
-scan over leaves, the speculative rounds): passes of any count decide
+over the routes a pass can take (the packed sequential scan under the
+streamed commit and under the post-pass, the scan over leaves): passes
+of any count decide
 every pod byte for byte as the same pods served one a pass; a count in a
 bucket the route has met compiles nothing; the counters count real pods
 and pad rows apart; and a carried session's resident patch has one
@@ -17,8 +18,6 @@ from kube_scheduler_simulator_tpu.cluster.store import ObjectStore, list_shared
 from kube_scheduler_simulator_tpu.framework.engine import SchedulerEngine
 from kube_scheduler_simulator_tpu.models.workloads import make_nodes, make_pods
 from kube_scheduler_simulator_tpu.parallel.mesh import make_mesh
-from kube_scheduler_simulator_tpu.parallel.speculative import (
-    MIN_ROUND, _batch_ladder)
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.state import resident
 from kube_scheduler_simulator_tpu.state.compile import (
@@ -27,7 +26,7 @@ from kube_scheduler_simulator_tpu.utils import tracing
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
 # BASELINE config 3's profile (benchmark cells baseline_c3_1k.* and
-# baseline_c3_queue_1k.*): batchable, so a pass of two or more speculates
+# baseline_c3_queue_1k.*): no PostFilter, so the commit is streamed
 from test_wave_path_table import CONFIG_3, _decided  # noqa: E402
 
 CHUNK = 64
@@ -102,21 +101,16 @@ def _misses():
 
 
 ROUTES = {
-    # route -> (environment, engine keywords, replay_route_total's label)
-    "packed": ({"KSS_TPU_SPECULATIVE": "0"}, {}, "packed"),
-    "leaves": ({"KSS_TPU_SPECULATIVE": "0"},
-               {"mesh": lambda: make_mesh(2, dp=1)}, "leaves"),
-    "rounds": ({}, {}, "leaves"),
+    # route -> (engine keywords, replay_route_total's label)
+    "packed": ({}, "packed"),
+    "leaves": ({"mesh": lambda: make_mesh(2, dp=1)}, "leaves"),
+    "post_pass": ({"pipeline_commit": lambda: False}, "packed"),
 }
 
 
 @pytest.mark.parametrize("route", list(ROUTES))
-def test_passes_of_any_count_decide_as_one_a_pass(route, one_a_pass,
-                                                  monkeypatch):
-    env, kw, label = ROUTES[route]
-    monkeypatch.delenv("KSS_TPU_SPECULATIVE", raising=False)
-    for name, value in env.items():
-        monkeypatch.setenv(name, value)
+def test_passes_of_any_count_decide_as_one_a_pass(route, one_a_pass):
+    kw, label = ROUTES[route]
     store, pods = _cluster(), _pods()
     engine = SchedulerEngine(
         store, plugin_config=PluginSetConfig(enabled=list(CONFIG_3)),
@@ -134,19 +128,10 @@ def test_passes_of_any_count_decide_as_one_a_pass(route, one_a_pass,
     engine.close()
     assert at == len(pods)
     assert sorted(missed) == [1, 2, 4, 8, 16, 32, 64]
-    # the executables a route builds are a bucket's, not a count's: the
-    # scans have one, and a count in a bucket they have met compiles
-    # nothing; the rounds have two accumulator ops, an evaluation, an
-    # oracle, a bind fold and a sparse round a rung of the bucket's
-    # ladder, and the scan they fall back to when acceptance collapses
-    # (the packed one where a batch pass starts again or is declined,
-    # and for every pass of fewer than MIN_ROUND pods)
+    # the executable a route builds is a bucket's, not a count's: a count
+    # in a bucket the route has met compiles nothing
     for rows, by_pass in missed.items():
-        if route == "rounds" and rows > 1:
-            rungs = len(_batch_ladder(rows, 1, None))
-            assert sum(by_pass) <= 2 + 4 * rungs + 1, (rows, by_pass)
-        else:
-            assert by_pass[0] <= 1 and not any(by_pass[1:]), (rows, by_pass)
+        assert by_pass[0] <= 1 and not any(by_pass[1:]), (rows, by_pass)
     # ... every pod of every pass is decided byte for byte as alone ...
     got = _decided(store, pods)
     for name, (node, annotations) in one_a_pass.items():
@@ -162,35 +147,10 @@ def test_passes_of_any_count_decide_as_one_a_pass(route, one_a_pass,
     rebuckets = sum(pod_axis_bucket(a, CHUNK) != pod_axis_bucket(b, CHUNK)
                     for a, b in zip(counts, counts[1:]))
     assert _counter("pod_axis_rebuckets_total") == rebuckets
-    if route == "rounds":
-        # a pass of fewer than a round's least has no round to gain from
-        # (MIN_ROUND; the packed scan, as a pass of one); every other pod
-        # went through a round, the rounds' scan fallback or, once the
-        # first round of a batch pass had collapsed on this roomy
-        # cluster, the packed scan that pass started again as and the
-        # session's later batch passes were sent to (declined: they open
-        # no stream, so they are no pass over leaves); never a pad row
-        spec = TRACER.summary()
-        accepted = sum(TRACER.labeled_totals(
-            "speculative_accepted_total", "session").values())
-        fell_back = sum(TRACER.labeled_totals(
-            "speculative_fallbacks_total", "session").values())
-        declined = sum(TRACER.labeled_totals(
-            "speculative_declined_passes_total", "session").values())
-        small = sum(c < MIN_ROUND for c in counts)
-        batch = sum(c for c in counts if c >= MIN_ROUND)
-        assert accepted <= batch
-        assert accepted == batch or fell_back or declined, spec["counters"]
-        assert _counter("speculative_rounds_total") > 0
-        assert declined >= 1, spec["counters"]
-        assert _labeled("replay_route_total", "route", "leaves") \
-            == len(counts) - small - declined
-        assert _labeled("replay_route_total", "route", "packed") \
-            >= small + 1 + declined
-    else:
-        assert _labeled("replay_route_total", "route", label) \
-            >= len(counts) - 1
-        assert _counter("speculative_rounds_total") == 0
+    assert _labeled("replay_route_total", "route", label) \
+        >= len(counts) - 1
+    assert (_counter("commit_stream_waves_total") > 0) \
+        is (route != "post_pass")
 
 
 def test_a_resident_patch_has_one_executable_a_bucket():

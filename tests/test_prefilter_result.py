@@ -3,8 +3,9 @@ pod names by `matchFields: metadata.name In [...]`, Filter runs on those
 alone, and the names are recorded in the prefilter-result annotation.
 
 Every branch of upstream v1.32 `NodeAffinity.PreFilter` (docs/SEMANTICS.md,
-"PreFilterResult"), the sequential scan and the speculative rounds alike,
-against reference_impl/sequential.py: all 13 annotations byte for byte.
+"PreFilterResult") against reference_impl/sequential.py: all 13
+annotations byte for byte (tests/test_scan_parity_matrix.py runs the same
+queue over every route of the scan).
 """
 
 from __future__ import annotations
@@ -20,8 +21,6 @@ from kube_scheduler_simulator_tpu.framework.pipeline import (
     NOT_EVALUATED, PACK_MODES)
 from kube_scheduler_simulator_tpu.framework.replay import (
     filter_rejected_rows, plugin_attribution, replay)
-from kube_scheduler_simulator_tpu.parallel.speculative import (
-    replay_speculative)
 from kube_scheduler_simulator_tpu.plugins import affinity
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.reference_impl.sequential import (
@@ -124,28 +123,16 @@ def _scan(cfg):
     return replay(compile_workload(NODES, _queue(), cfg), chunk=4)
 
 
-def _speculative(cfg):
-    rr, _ = replay_speculative(compile_workload(NODES, _queue(), cfg),
-                               None, batch=4)
-    return rr
-
-
-@pytest.mark.parametrize("path", ["scan", "speculative"])
-def test_every_branch_matches_the_sequential_reference(path):
-    if path == "scan":
-        cfg, rr = None, _scan(None)   # the default profile
-    else:
-        cfg = PluginSetConfig(enabled=SAFE_CFG)
-        rr = _speculative(cfg)
+def test_every_branch_matches_the_sequential_reference():
+    rr = _scan(None)   # the default profile
     pods = _queue()
-    oracle = SequentialScheduler(NODES, pods, cfg).schedule_all()
-    decoded = (decode_all_parallel(rr) if path == "scan" else
-               [decode_pod_result(rr, i) for i in range(len(pods))])
+    oracle = SequentialScheduler(NODES, pods, None).schedule_all()
+    decoded = decode_all_parallel(rr)
     for i, (want, sel) in enumerate(oracle):
         who = pods[i]["metadata"]["name"]
         for key, value in want.items():
-            assert decoded[i][key] == value, (path, who, key)
-        assert int(rr.selected[i]) == sel, (path, who)
+            assert decoded[i][key] == value, (who, key)
+        assert int(rr.selected[i]) == sel, who
 
 
 @pytest.mark.parametrize("branch", sorted(BRANCHES))

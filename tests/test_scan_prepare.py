@@ -34,8 +34,6 @@ from kube_scheduler_simulator_tpu.framework.replay import (
 from kube_scheduler_simulator_tpu.models.workloads import (
     baseline_config, make_nodes, make_pods)
 from kube_scheduler_simulator_tpu.parallel.mesh import make_mesh
-from kube_scheduler_simulator_tpu.parallel.speculative import (
-    replay_speculative)
 from kube_scheduler_simulator_tpu.plugins.registry import PluginSetConfig
 from kube_scheduler_simulator_tpu.server.sessions import SessionManager
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration
@@ -332,9 +330,8 @@ def _spec_workload():
     return compile_workload(nodes, pods, cfg), pods
 
 
-@pytest.mark.parametrize("how", ["mesh", "speculative", "many_chunks"])
-def test_a_mesh_the_speculative_rounds_or_many_chunks_read_leaves_unpacked_once(
-        monkeypatch, how):
+@pytest.mark.parametrize("how", ["mesh", "many_chunks"])
+def test_a_mesh_or_many_chunks_read_leaves_unpacked_once(monkeypatch, how):
     cw, pods = _spec_workload()
     base = _decoded(replay(cw, chunk=64), len(pods))
     assert cw.__dict__["_xs"] is None
@@ -342,9 +339,7 @@ def test_a_mesh_the_speculative_rounds_or_many_chunks_read_leaves_unpacked_once(
     def run():
         if how == "mesh":
             return replay(cw, chunk=8, mesh=make_mesh(8, dp=1))
-        if how == "many_chunks":
-            return replay(cw, chunk=8)
-        return replay_speculative(cw, None, batch=4)[0]
+        return replay(cw, chunk=8)
 
     spy = _UnpackSpy(monkeypatch)
     before = _routes()

@@ -28,8 +28,6 @@ from kube_scheduler_simulator_tpu.utils.tracing import (
 
 WAVE_CHILDREN = ("wave_setup", "compile_workload", "replay_and_decode_stream",
                  "commit_and_reflect", "wave_finish")
-SPECULATIVE_SET = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
-                   "NodeAffinity", "TaintToleration", "PodTopologySpread"]
 
 
 def _wave(plugin_config=None, nodes=50, pods=12, seed=31):
@@ -75,17 +73,6 @@ def test_wave_children_cover_the_sequential_wave(default_wave):
     assert wave and wave[-1]["pods"] == 12 and wave[-1]["nodes"] == 50
     assert snap["counters"]["scheduling_work_passes_total"] == 1
     assert snap["counters"]["scheduling_pass_pods_total"] == 12
-
-
-def test_wave_children_cover_the_speculative_wave(monkeypatch):
-    monkeypatch.setenv("KSS_TPU_SPECULATIVE", "1")
-    _, snap = _wave(PluginSetConfig(enabled=list(SPECULATIVE_SET)))
-    spans = snap["spans"]
-    assert spans["speculative_round"]["count"] >= 1
-    assert _seconds(snap, WAVE_CHILDREN) >= 0.95 * _seconds(snap, ["wave"])
-    # the speculative round's dispatch and fetch carry the same two names
-    assert spans["scan_dispatch"]["count"] >= 1
-    assert spans["decision_fetch"]["count"] >= 1
 
 
 def test_every_default_plugin_yields_its_build_span(default_wave):

@@ -8,8 +8,8 @@ hostnames.
   * served in bursts over HTTP under the POSTED profile: all 13
     annotations + spec.nodeName byte for byte; the same reference in
     int32/float32 (the control) differs;
-  * one pass over the whole queue, and the speculative rounds, against
-    the same reference;
+  * one pass over the whole queue, in chunks and as one packed call,
+    against the same reference;
   * a zone of which the pod's node affinity excludes a part: upstream
     counts the zone's pods BY NODE, on the nodes the pod's required term
     keeps, and so do the reference and the program (the parent's
@@ -49,8 +49,6 @@ from reference.default_profile import Narrow32, NotCovered  # noqa: E402
 from kube_scheduler_simulator_tpu.config.config import SimulatorConfiguration  # noqa: E402
 from kube_scheduler_simulator_tpu.framework.replay import (  # noqa: E402
     _workload_scan_key, replay)
-from kube_scheduler_simulator_tpu.parallel.speculative import (  # noqa: E402
-    replay_speculative)
 from kube_scheduler_simulator_tpu.scheduler.convert import parse_plugin_set  # noqa: E402
 from kube_scheduler_simulator_tpu.server.di import DIContainer  # noqa: E402
 from kube_scheduler_simulator_tpu.server.server import SimulatorServer  # noqa: E402
@@ -260,8 +258,6 @@ def _replayed_of(cw, rr):
 ROUTES = {
     "sequential_scan": lambda cw: replay(cw, chunk=16),
     "packed_one_chunk": lambda cw: replay(cw, device_resident=True),
-    "speculative_rounds": lambda cw: replay_speculative(
-        cw, None, pods=cw.pods)[0],
 }
 
 

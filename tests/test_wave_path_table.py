@@ -1,8 +1,7 @@
 """Which wave path serves which profile: the table at the head of
 docs/wave-pipeline.md, one case per row, read off what a wave emits.
 
-The engine chooses scan (speculative rounds / sequential scan / host
-loop), commit (streamed by the chunk worker / sequential post-pass /
+The engine chooses scan (sequential scan / host loop), commit (streamed by the chunk worker / sequential post-pass /
 host loop) and result residency (device-resident lazy / host-resident
 lazy / decoded in the wave) from what it observes of the profile, the
 extenders, the reflector and the residency ladder, once a wave
@@ -33,8 +32,8 @@ from kube_scheduler_simulator_tpu.scheduler.debuggable import PluginExtender
 from kube_scheduler_simulator_tpu.scheduler.extender import ExtenderService
 from kube_scheduler_simulator_tpu.utils.tracing import TRACER
 
-# an in-tree set that admits exact batching (speculation_ok) and has no
-# PostFilter: BASELINE config 4's plugins
+# an in-tree set with no PostFilter and nothing of the volume family:
+# BASELINE config 4's plugins
 BATCHABLE = ["NodeResourcesFit", "NodeResourcesBalancedAllocation",
              "NodeAffinity", "TaintToleration", "PodTopologySpread"]
 
@@ -115,7 +114,7 @@ class Row:
     plan: tuple = ()
     # the path it must take
     span: str = "replay_and_decode_stream"
-    speculative: bool = False
+    route: str | None = None           # replay_route_total's, where held
     commit: str = "post_pass"          # streamed | post_pass | host_loop
     results: str = "device_lazy"       # device_lazy | host_lazy | in_wave
     mode: str = "device_resident"
@@ -128,8 +127,8 @@ def _enabled(names):
 HOST_LOOP = ("host_loop", "host_loop", "by_pod")
 
 ROWS = [
-    # the stock server and every cell of BENCHMARK.json: DefaultPreemption
-    # refuses the streaming committer, the volume family speculation
+    # the stock server and eight cells of BENCHMARK.json: DefaultPreemption
+    # refuses the streaming committer
     Row("row01_default_profile",
         plan=("sequential", "post_pass", "device_lazy")),
     Row("row02_webhook_extenders", tweak=_webhook, plan=HOST_LOOP,
@@ -149,41 +148,40 @@ ROWS = [
         results="in_wave"),
     Row("row06_observer_on_batchable_profile", config=_enabled(BATCHABLE),
         tweak=_extender(Observer()),
-        plan=("speculative", "post_pass", "by_chunk"),
-        speculative=True, results="in_wave"),
+        plan=("sequential", "post_pass", "by_chunk"),
+        results="in_wave"),
     Row("row07_postfilter_in_batchable_profile",
         config=_enabled(BATCHABLE + ["DefaultPreemption"]),
         plan=("sequential", "post_pass", "device_lazy")),
-    # the rounds are for a pass that holds a batch ...
-    Row("row08_batchable_profile", config=_enabled(BATCHABLE),
-        plan=("speculative", "streamed", "device_lazy"),
-        speculative=True, commit="streamed"),
-    Row("row08_batchable_profile_pass_of_eight", config=_enabled(BATCHABLE),
-        pods=8, plan=("speculative", "streamed", "device_lazy"),
-        speculative=True, commit="streamed"),
     Row("row09_volume_family_without_postfilter",
         config=_enabled(["NodeResourcesFit", "VolumeBinding"]),
         plan=("sequential", "streamed", "device_lazy"),
         commit="streamed"),
-    # ... a pass of one pod has nothing to speculate on, and one of fewer
-    # than a round's least (MIN_ROUND, 8) no round to gain from
+    # whatever the pass holds: more than a chunk (8 here) is the chunked
+    # scan over leaves, a chunk or less the packed scan's one call
+    Row("row09_batchable_profile", config=_enabled(BATCHABLE),
+        plan=("sequential", "streamed", "device_lazy"),
+        route="leaves", commit="streamed"),
+    Row("row09_batchable_profile_pass_of_eight", config=_enabled(BATCHABLE),
+        pods=8, plan=("sequential", "streamed", "device_lazy"),
+        route="packed", commit="streamed"),
     Row("row09_batchable_profile_pass_of_one", config=_enabled(BATCHABLE),
         pods=1, plan=("sequential", "streamed", "device_lazy"),
-        commit="streamed"),
+        route="packed", commit="streamed"),
     Row("row09_batchable_profile_pass_of_seven", config=_enabled(BATCHABLE),
         pods=7, plan=("sequential", "streamed", "device_lazy"),
-        commit="streamed"),
+        route="packed", commit="streamed"),
     Row("row10_reflector_cannot_defer_default_profile", tweak=_no_defer,
         plan=("sequential", "post_pass", "by_chunk"),
         results="in_wave"),
     Row("row10_reflector_cannot_defer_batchable_profile",
         config=_enabled(BATCHABLE), tweak=_no_defer,
-        plan=("speculative", "streamed", "by_chunk"),
-        speculative=True, commit="streamed", results="in_wave"),
+        plan=("sequential", "streamed", "by_chunk"),
+        commit="streamed", results="in_wave"),
     Row("row11_gang_plugin_alone_on_batchable_profile",
         config=_custom(Coscheduling(), base=BATCHABLE), gang=True,
-        plan=("speculative", "streamed", "device_lazy"),
-        speculative=True, commit="streamed"),
+        plan=("sequential", "streamed", "device_lazy"),
+        commit="streamed"),
     # the rungs: pinned by the tests' floor, and reached as a server
     # reaches them, by the ladder stepping down
     Row("row12_rung_host_resident", engine_kw={"residency_floor": 1},
@@ -198,15 +196,11 @@ ROWS = [
     Row("row13_rung_eager_decode_by_degradation", tweak=_degraded(2),
         plan=("sequential", "post_pass", "by_chunk"),
         results="in_wave", mode="eager_decode"),
-    # the two pins tests and parity baselines set; no server sets them
-    Row("pin_speculative_off", config=_enabled(BATCHABLE),
-        env={"KSS_TPU_SPECULATIVE": "0"},
-        plan=("sequential", "streamed", "device_lazy"),
-        commit="streamed"),
+    # the pin tests and parity baselines set beside residency_floor; no
+    # server sets it
     Row("pin_pipeline_commit_off", config=_enabled(BATCHABLE),
         engine_kw={"pipeline_commit": False},
-        plan=("speculative", "post_pass", "device_lazy"),
-        speculative=True),
+        plan=("sequential", "post_pass", "device_lazy")),
 ]
 
 
@@ -221,7 +215,6 @@ def _decoded_in_wave():
 
 def _engine(row, monkeypatch):
     """The row's store, pods and engine, one wave's worth."""
-    monkeypatch.delenv("KSS_TPU_SPECULATIVE", raising=False)
     for name, value in row.env.items():
         monkeypatch.setenv(name, value)
     store = ObjectStore()
@@ -246,19 +239,18 @@ def _engine(row, monkeypatch):
 @pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
 def test_wave_plan(row, monkeypatch):
     """The plan method's value for the row's engine: taken once for the
-    wave, for the number of pods the pass holds, and what the table
-    says."""
+    wave, and what the table says."""
     _store, pods, engine = _engine(row, monkeypatch)
     plans = []
     decide = engine._wave_plan
 
-    def recorded(n_pods, *args):
-        plans.append((n_pods, decide(n_pods, *args)))
-        return plans[-1][1]
+    def recorded(*args):
+        plans.append(decide(*args))
+        return plans[-1]
 
     monkeypatch.setattr(engine, "_wave_plan", recorded)
     assert engine.schedule_pending() == len(pods)
-    assert plans == [(len(pods), WavePlan(*row.plan))]
+    assert plans == [WavePlan(*row.plan)]
 
 
 @pytest.mark.parametrize("row", ROWS, ids=[r.id for r in ROWS])
@@ -271,8 +263,9 @@ def test_wave_path(row, monkeypatch):
 
     opened = [e for e in events if e["name"] in WAVE_SPANS]
     assert [e["name"] for e in opened] == [row.span]
-    assert (opened[0].get("mode") == "speculative") is row.speculative
-    assert (_counter("speculative_rounds_total") > 0) is row.speculative
+    if row.route is not None:
+        assert TRACER.labeled_totals("replay_route_total", "route") \
+            == {row.route: 1}
 
     names = {e["name"] for e in events}
     assert (_counter("commit_stream_waves_total") == 1) \
@@ -341,21 +334,18 @@ def _decided(store, pods):
 
 def test_row09_serves_one_pod_a_pass():
     """A UI user's traffic on a batchable profile: every pass is one pod,
-    a pass of one has nothing to speculate on, so every pass is the
-    sequential scan's one call over the pass's packed buffers (the one
+    so every pass is the sequential scan's one call over the pass's packed buffers (the one
     upload and the one executable: 2 dispatches), committed by the
     streaming worker, whatever node-affinity terms and tolerations the pod
     carries; after the first passes nothing compiles."""
     store, engine, pods = _config3_engine()
-    assert engine._wave_plan(1) == WavePlan(
+    assert engine._wave_plan() == WavePlan(
         "sequential", "streamed", "device_lazy")
     TRACER.reset()
     misses, dispatches = [], []
     for i, pod in enumerate(pods):
         store.create("pods", pod)
         assert engine.schedule_pending() == 1
-        # zero rounds, and counted as zero: the counter is there to read
-        assert TRACER.summary()["counters"]["speculative_rounds_total"] == 0
         assert _counter("commit_stream_waves_total") == i + 1
         assert _route("packed") == i + 1 and _route("leaves") == 0
         dispatches.append(_counter("pass_device_dispatches_total"))
@@ -371,22 +361,16 @@ def test_row09_serves_one_pod_a_pass():
 
 
 def test_row09_two_pods_a_pass_are_the_scan_too():
-    """The same profile and pods, two a pass: fewer than a round's least
-    (MIN_ROUND), so the packed scan's one call as for a pass of one (since
-    PR 52; the rounds until then), on the bucket of two: no round, no
-    stream over leaves, and after the first passes nothing compiles."""
+    """The same profile and pods, two a pass: the packed scan's one call
+    as for a pass of one, on the bucket of two: nothing over leaves, and
+    after the first passes nothing compiles."""
     store, engine, pods = _config3_engine()
-    assert engine._wave_plan(2) == engine._wave_plan(7) == WavePlan(
-        "sequential", "streamed", "device_lazy")
-    assert engine._wave_plan(8) == WavePlan(
-        "speculative", "streamed", "device_lazy")
     TRACER.reset()
     misses = []
     for i in range(0, len(pods), 2):
         for pod in pods[i:i + 2]:
             store.create("pods", pod)
         assert engine.schedule_pending() == 2
-        assert _counter("speculative_rounds_total") == 0
         assert _counter("commit_stream_waves_total") == i // 2 + 1
         assert (_route("packed"), _route("leaves")) == (i // 2 + 1, 0)
         misses.append(_scan_misses())
@@ -395,111 +379,25 @@ def test_row09_two_pods_a_pass_are_the_scan_too():
         assert node and annotations
 
 
-def _roomy_session(session, enabled=CONFIG_3[:2]):
-    """16 nodes that every pod of make_pods fits: a cluster with room,
-    on which the dirty-node rule cuts every round at one pod."""
-    store = ObjectStore()
-    for n in make_nodes(16, seed=31):
-        store.create("nodes", n)
-    engine = SchedulerEngine(store, plugin_config=PluginSetConfig(
-        enabled=list(enabled)), chunk=64)
-    engine.session = session
-    served = [0]
-
-    def burst(count=12):
-        pods = make_pods(count, seed=32 + served[0])
-        for p in pods:
-            p["metadata"]["name"] += f"-{served[0]}"
-            store.create("pods", p)
-        served[0] += 1
-        assert engine.schedule_pending() == count
-
-    return engine, burst
-
-
-def _declined():
-    return sum(TRACER.labeled_totals(
-        "speculative_declined_passes_total", "session").values())
-
-
-def test_row09_a_session_whose_rounds_collapsed_declines_its_batch_passes():
-    """The plan's fifth observation, the rounds' own record: after a pass
-    whose first round collapsed, the session's batch passes are the
-    sequential scan from the start (row 9: no stream, no round), and
-    counted; a pass too small to be evidence, another session, the
-    session after CONTROLS.drop / reset, and a differing profile try the
-    rounds, as a session does that no round has served yet."""
-    from kube_scheduler_simulator_tpu.control import CONTROLS
-
-    declined = WavePlan("sequential", "streamed", "device_lazy", True)
-    rounds = WavePlan("speculative", "streamed", "device_lazy")
-    engine, burst = _roomy_session("a")
-    TRACER.reset()
-    assert engine._wave_plan(12) == rounds
-    burst()
-    assert _counter("speculative_rounds_total") == 1      # it collapsed
-    assert (_route("leaves"), _route("packed")) == (1, 1)
-    before = _declined()
-    assert engine._wave_plan(12) == declined
-    assert _declined() == before + 1
-    # the next pass: no round, one packed call, counted once
-    burst()
-    assert _counter("speculative_rounds_total") == 1
-    assert (_route("leaves"), _route("packed")) == (1, 2)
-    assert _declined() == before + 2
-    # (a plan asked for outside a pass, as here, has no session's scope)
-    assert TRACER.labeled_totals(
-        "speculative_declined_passes_total", "session")["a"] == 1
-    # 1-7 pods are row 9 whatever the record says, and not counted as
-    # declined; a pass of more than one chunk (64 here) keeps its rounds
-    before = _declined()
-    assert engine._wave_plan(7) == engine._wave_plan(1) == WavePlan(
-        "sequential", "streamed", "device_lazy")
-    assert _declined() == before
-    assert engine._wave_plan(64) == declined
-    assert engine._wave_plan(65) == rounds
-    # another session of the process has its own record
-    other, _ = _roomy_session("b")
-    assert other._wave_plan(12) == rounds
-    # the record goes with the session, and with the fail-safe
-    CONTROLS.drop("a")
-    assert engine._wave_plan(12) == rounds
-    burst()
-    assert _counter("speculative_rounds_total") == 2
-    assert engine._wave_plan(12) == declined
-    CONTROLS.reset()
-    assert engine._wave_plan(12) == rounds
-    burst()
-    assert engine._wave_plan(12) == declined
-    # the record is the profile's: a differing one tries the rounds, the
-    # same one posted again (the burst driver posts it every cycle) is
-    # the same signature
-    engine.set_plugin_config(PluginSetConfig(enabled=list(CONFIG_3[:3])))
-    assert engine._wave_plan(12) == rounds
-    engine.set_plugin_config(PluginSetConfig(enabled=list(CONFIG_3[:2])))
-    assert engine._wave_plan(12) == declined
-    engine.close()
-    other.close()
-
-
 def test_one_pod_a_pass_and_one_pass_of_all_are_byte_equal():
-    """The plan's two answers for one profile give one result: the same
-    pods served one a pass (the sequential scan's one call) and as one
-    pass of 12 (the rounds) carry the same spec.nodeName, the same 13
-    result annotations and the same result history, byte for byte."""
+    """The scan's two routes for one profile give one result: the same
+    pods served one a pass (the packed scan's one call each) and as one
+    pass of 12 (two chunks of 8 over leaves) carry the same
+    spec.nodeName, the same 13 result annotations and the same result
+    history, byte for byte."""
     store, engine, pods = _config3_engine(12)
     TRACER.reset()
     for pod in pods:
         store.create("pods", pod)
         assert engine.schedule_pending() == 1
-    assert _counter("speculative_rounds_total") == 0
+    assert (_route("packed"), _route("leaves")) == (12, 0)
     one_a_pass = _decided(store, pods)
 
     store, engine, pods = _config3_engine(12)
     for pod in pods:
         store.create("pods", pod)
     assert engine.schedule_pending() == 12
-    assert _counter("speculative_rounds_total") > 0
+    assert (_route("packed"), _route("leaves")) == (12, 1)
     one_pass = _decided(store, pods)
 
     assert all(len(annotations) == 13 + 1

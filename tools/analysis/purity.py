@@ -69,22 +69,6 @@ HOT_PATH_ROOTS: list[tuple[str, str]] = [
     ("server.sessions", "SimulationSession.touch"),
     ("server.sessions", "SimulationSession.register_stream"),
     ("server.sessions", "SimulationSession.unregister_stream"),
-    # speculative waves (PR 13): the streaming round loop and its
-    # conflict-oracle host walk run inside every such wave (the engine's
-    # side is _device_wave, above) — they must stay free of per-pod
-    # Python loops and eager host syncs on the compact groups (the
-    # accumulator emits whole chunks through gather_to_host, the one
-    # sanctioned crossing)
-    ("parallel.speculative", "replay_speculative_stream"),
-    ("parallel.speculative", "_spec_run"),
-    ("parallel.speculative", "_interaction_cut"),
-    ("framework.gang", "aligned_cut"),
-    # cross-session fused dispatch (PR 16): the coordinator's join/
-    # stack/split path runs inside every speculative round of every
-    # session — it must stay free of per-pod loops, eager host syncs on
-    # stacked device pytrees, and (via the lock rules) device calls
-    # under the coordinator condition
-    ("parallel.fuse", "*"),
     # columnar data plane (PR 17): the node-table build/patch and the
     # column read surface run once per wave over up to 100k-node arrays
     # — a per-ROW Python loop here (columnar-row-loop below) undoes the

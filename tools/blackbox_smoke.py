@@ -9,7 +9,6 @@ checked by utils.blackbox.validate_dump, which requires:
   * the fault trip on the record (seam + error + classification) and a
     classified cause;
   * the protocol's action (wave.abort here);
-  * the speculative round history that preceded the fault;
   * non-empty counter deltas for the failing wave;
   * a device fingerprint with an explicit hbm_available flag.
 
@@ -41,11 +40,9 @@ def main() -> int:
     os.environ["KSS_TPU_FAULT_PLAN"] = "@" + plan_path
     os.environ["KSS_TPU_BLACKBOX_DIR"] = dump_dir
     os.environ["KSS_TPU_WAVE_MAX_RETRIES"] = "0"
-    # pin the toggles the assertions depend on: an inherited
-    # KSS_TPU_SPECULATIVE=0 (the parity lever) or KSS_TPU_BLACKBOX=0
-    # must not fail `make test` spuriously — the smoke asserts the
-    # default-configuration behavior
-    os.environ["KSS_TPU_SPECULATIVE"] = "1"
+    # pin the toggle the assertions depend on: an inherited
+    # KSS_TPU_BLACKBOX=0 must not fail `make test` spuriously — the
+    # smoke asserts the default-configuration behavior
     os.environ["KSS_TPU_BLACKBOX"] = "1"
 
     from kube_scheduler_simulator_tpu.cluster.store import ObjectStore
@@ -84,7 +81,7 @@ def main() -> int:
     with open(files[-1], encoding="utf-8") as fh:
         doc = json.load(fh)
     try:
-        res = validate_dump(doc, require_fault=True, require_rounds=True)
+        res = validate_dump(doc, require_fault=True)
     except ValueError as e:
         print(f"blackbox-smoke: FAIL — malformed dump {files[-1]}: {e}",
               file=sys.stderr)

@@ -78,17 +78,6 @@ def _plan_for(seed: int, target: str):
         # transient scan/fetch faults: heal via uncommitted-suffix retry
         FaultRule("replay.scan_dispatch", nth=rng.randint(1, 3),
                   error="runtime", times=1, sessions=[target]),
-        # mid-round speculative fault (the default wave's own seam):
-        # committed round chunks stand through the gang-cut watermark,
-        # the uncommitted suffix retries recompiled against current
-        # store state — byte parity with the fault-free run must hold
-        FaultRule("speculative.round", nth=rng.randint(1, 2),
-                  error="runtime", times=1, sessions=[target]),
-        # fused-dispatch fault: fires on the requesting thread BEFORE it
-        # joins a batch, so only the target's wave aborts and retries —
-        # batch-mates (the neighbor) must be untouched (parallel/fuse.py)
-        FaultRule("fuse.dispatch", nth=rng.randint(1, 2),
-                  error="runtime", times=1, sessions=[target]),
         FaultRule("replay.decision_fetch", p=0.15, error="io", times=2,
                   sessions=[target]),
         # structural fault: steps the degradation ladder down a rung
